@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from production_stack_tpu.engine.jax_compat import set_mesh
 from production_stack_tpu.engine.config import CacheConfig, ModelConfig
 from production_stack_tpu.parallel import shardings as ln
 from production_stack_tpu.parallel.shardings import ShardingRules, logical_to_sharding
@@ -75,7 +74,7 @@ def init_kv_cache(
     def _zeros():
         return jnp.zeros(shape, dt)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         # stackcheck: disable=jit-cache-hygiene — one-shot pool
         # allocation at engine startup: the wrapper exists only to apply
         # out_shardings and is called exactly once, so there is no trace

@@ -172,17 +172,21 @@ class EngineStatsCollector:
         # utilization, phase throughput, HBM occupancy, compile events
         perf = s.get("perf")
         if perf:
-            yield gauge(
-                "vllm:model_flops_utilization",
-                "Model FLOPs utilization over the accounting window "
-                "(goodput: live tokens only, padding waste excluded)",
-                perf["mfu"],
-            )
-            yield gauge(
-                "vllm:hbm_bandwidth_utilization",
-                "Estimated HBM bandwidth utilization over the window",
-                perf["hbm_bw_util"],
-            )
+            # utilization gauges exist only where the device's peaks are
+            # known (perf_accounting.DEVICE_PEAKS or --perf-peak-*)
+            if perf["mfu"] is not None:
+                yield gauge(
+                    "vllm:model_flops_utilization",
+                    "Model FLOPs utilization over the accounting window "
+                    "(goodput: live tokens only, padding waste excluded)",
+                    perf["mfu"],
+                )
+            if perf["hbm_bw_util"] is not None:
+                yield gauge(
+                    "vllm:hbm_bandwidth_utilization",
+                    "Estimated HBM bandwidth utilization over the window",
+                    perf["hbm_bw_util"],
+                )
             tps = GaugeMetricFamily(
                 "vllm:tokens_per_second",
                 "Live (unpadded) tokens per second by phase",
@@ -205,13 +209,14 @@ class EngineStatsCollector:
             # collective bytes are per-chip wire traffic derived from the
             # sharding degree + model geometry, costed against the
             # per-chip ICI link bandwidth
-            yield gauge(
-                "vllm:ici_bandwidth_utilization",
-                "Estimated per-chip ICI bandwidth utilization over the "
-                "window (collective bytes from the sharding spec + model "
-                "geometry vs the per-chip link peak)",
-                perf.get("ici_bw_util", 0.0),
-            )
+            if perf.get("ici_bw_util") is not None:
+                yield gauge(
+                    "vllm:ici_bandwidth_utilization",
+                    "Estimated per-chip ICI bandwidth utilization over the "
+                    "window (collective bytes from the sharding spec + "
+                    "model geometry vs the per-chip link peak)",
+                    perf["ici_bw_util"],
+                )
             coll = CounterMetricFamily(
                 "vllm:collective_bytes",
                 "Estimated per-chip collective bytes on the ICI by op "

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from production_stack_tpu.engine.jax_compat import set_mesh
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.model_runner import ModelRunner, _make_lora
 from production_stack_tpu.engine.quant import maybe_quantize
@@ -110,7 +109,7 @@ class StagedModelRunner:
         if params is not None:
             return params
         full_rules = rules_for_model(self.cfg, self.mesh)
-        with set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             # LAYERS→stage rule shards the stacked layer axis across stage
             # devices, so each stage's slice already lives on its submesh
             return init_or_load(self.cfg, self.mesh, full_rules,
@@ -190,7 +189,7 @@ class StagedModelRunner:
             if s > 0:
                 x = jax.device_put(
                     x, _replicated(self.submeshes[s]))
-            with set_mesh(self.submeshes[s]):
+            with jax.set_mesh(self.submeshes[s]):
                 runner.kv, x = self._prefill_steps[s](
                     runner.params, runner.kv, x, *common, *sample_args,
                     lora_bank=runner.lora_bank if use_lora else None,
@@ -256,7 +255,7 @@ class StagedModelRunner:
                 if s > 0:
                     x = jax.device_put(
                     x, _replicated(self.submeshes[s]))
-                with set_mesh(self.submeshes[s]):
+                with jax.set_mesh(self.submeshes[s]):
                     if is_last:
                         (runner.kv, new_counts), x = self._decode_steps[s](
                             runner.params, runner.kv, x,
@@ -433,7 +432,7 @@ class StagedModelRunner:
         for s, runner in enumerate(self.stages):
             if s > 0:
                 x = jax.device_put(x, _replicated(self.submeshes[s]))
-            with set_mesh(self.submeshes[s]):
+            with jax.set_mesh(self.submeshes[s]):
                 x = self._pooled_stage_fns[s](
                     runner.params, x, jnp.asarray(positions)
                 )
@@ -471,7 +470,7 @@ class StagedModelRunner:
 
             self._seqlp_tail_fn = jax.jit(_tail)
         sub = self.submeshes[-1]
-        with set_mesh(sub):
+        with jax.set_mesh(sub):
             out = self._seqlp_tail_fn(
                 last.params, hidden,
                 jax.device_put(jnp.asarray(tokens), _replicated(sub)),

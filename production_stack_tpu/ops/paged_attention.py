@@ -2,13 +2,14 @@
 
 Cache layout (single fused buffer): ``(L, N, block_size, 2*KH, D)``.
 
-Why this layout (all measured on v5e):
+Why this layout:
 - ONE buffer + ONE scatter per layer keeps the donated pool aliased through
   the scan carry (two carried buffers, or two scatters, cost a full pool
   copy per step);
 - a token's K+V for all heads is one contiguous ``(2*KH, D)`` slab — the
-  exact bf16 (16, 128) tile at KH=8 — so Pallas writes/reads slice only
-  leading dims and one DMA moves K and V together;
+  exact bf16 (16, 128) tile at KH=8, a quarter tile per shard at TP=4
+  (padded to a whole tile in VMEM, not in HBM) — so Pallas writes/reads
+  slice only leading dims and one DMA moves K and V together;
 - the head dim is grouped per tensor-parallel shard: ``[K_shard0, V_shard0,
   K_shard1, V_shard1, ...]`` so a NamedSharding split over the 2*KH dim
   hands each shard its own `[K_local, V_local]` halves.

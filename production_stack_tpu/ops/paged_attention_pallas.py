@@ -9,12 +9,11 @@ Three kernels over the fused cache layout ``(L, N, block_size, 2*KH, D)``
   softmax batched over heads.
 - ``paged_prefill_attention_pallas``: one grid cell per query tile of a
   single sequence's chunk; same windowed context walk with causal masking —
-  this replaces the XLA dynamic-slice + gather path whose per-layer cost is
-  ~8 ms on a multi-GiB pool (measured v5e).
+  this replaces the XLA dynamic-slice + gather path over the whole pool.
 - ``kv_cache_write_pallas``: scatters T new tokens into the pool as T async
-  ``(2KH, D)``-slab DMAs on a semaphore ring — the XLA scatter costs a flat
-  ~0.65 ms/layer; this is ~10-20 µs. The cache is aliased input→output, so
-  the donated pool is updated in place.
+  ``(2KH, D)``-slab DMAs on a semaphore ring, in place of an XLA scatter.
+  The cache is aliased input→output, so the donated pool is updated in
+  place.
 
 All kernels take the layer index as a scalar so the full multi-layer pool
 never gets sliced/copied. Grid cells execute sequentially on a TensorCore —
@@ -34,14 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# renamed TPUCompilerParams -> CompilerParams across pallas releases; the
-# old class also lacks has_side_effects (the aliased output keeps the
-# kernel live there, so dropping the knob is safe)
-def _compiler_params(has_side_effects: bool):
-    if hasattr(pltpu, "CompilerParams"):
-        return pltpu.CompilerParams(has_side_effects=has_side_effects)
-    return pltpu.TPUCompilerParams()
 
 NEG_INF = -1e30
 
@@ -72,9 +63,9 @@ def _decode_kernel(
 ):
     """Batched paged decode attention.
 
-    Grid cells run SEQUENTIALLY on a TensorCore (measured: per-cell
-    overhead dominates at one sequence per cell — 192 seqs x 28 layers x 16
-    fused steps ≈ 86k cell executions per dispatch). Each cell therefore
+    Grid cells run SEQUENTIALLY on a TensorCore, and at one sequence per
+    cell a fused dispatch is 192 seqs x 28 layers x 16 steps ≈ 86k cell
+    executions. Each cell therefore
     handles SPB sequences: their window DMAs are all in flight together
     (SPB x W parallel copies) and the QK^T / PV matmuls batch over the
     sequence dim — batch dims at position 0 on both operands, the layout
@@ -103,9 +94,9 @@ def _decode_kernel(
     # sequence's tail over-read is bounded by bs, not the whole window —
     # at ctx≈150/bs=16/W=8 the old per-window predication streamed
     # ceil(150/128)*128 = 256 tokens/seq; per-block streams
-    # ceil(150/16)*16 = 160 (roofline.md's 1.8x attention over-read,
-    # VERDICT r3 #4). This kernel is HBM-bound: skipped traffic is pure
-    # win. wait() uses the same predicate so waits match issues exactly.
+    # ceil(150/16)*16 = 160 (docs/roofline.md). This kernel is HBM-bound:
+    # skipped traffic is pure win. wait() uses the same predicate so waits
+    # match issues exactly.
     def seq_active(s, w):
         return w * win_tokens < cl_ref[base + s]
 
@@ -213,9 +204,14 @@ def _decode_kernel(
 def _pick_seqs_per_cell(B: int, bs: int, KH2: int, D: int, windows: int,
                         itemsize: int) -> int:
     """Largest SPB dividing B whose double-buffered window scratch fits a
-    VMEM budget (~8 MB, half the scoped limit)."""
+    VMEM budget (~8 MB, half the scoped limit). In VMEM a token's
+    (2KH, D) slab is padded to whole sublane tiles (16 rows of bf16), so
+    a head-sharded pool (2KH = 4 per shard at KH=8, TP=4) occupies four
+    times its HBM bytes there."""
     budget = 8 * 1024 * 1024
-    per_seq = 2 * windows * bs * KH2 * D * itemsize
+    sublanes = 32 // itemsize
+    rows = -(-KH2 // sublanes) * sublanes
+    per_seq = 2 * windows * bs * rows * D * itemsize
     spb = max(budget // per_seq, 1)
     while spb > 1 and B % spb:
         spb -= 1
@@ -548,5 +544,5 @@ def kv_cache_write_pallas(
         grid_spec=grid_spec,
         interpret=interpret,
         input_output_aliases={3: 0},  # kv_hbm input → output buffer
-        compiler_params=_compiler_params(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
     )(slot_mapping, layer_arr, newkv, kv_cache)

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from production_stack_tpu.engine.jax_compat import shard_map
 
 
 def _stage_body(layer_fn: Callable, params_stage, x):
@@ -102,7 +101,7 @@ def pipelined_forward(
     # called at trace time under the caller's jit (pp_runner compiles it
     # into per-stage step programs), so this shard_map is constructed
     # once per enclosing trace, not per dispatch
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(stage_specs, P()),

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from production_stack_tpu.engine.jax_compat import shard_map
 
 NEG_INF = -1e30
 
@@ -101,7 +100,7 @@ def ring_causal_attention(
     # stackcheck: disable=jit-cache-hygiene — ring_causal_attention runs
     # at trace time inside a jitted model forward; the shard_map is part
     # of the enclosing trace and is never rebuilt per dispatch
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attention_local, axis_name=axis_name,
                           soft_cap=soft_cap),
         mesh=mesh,

@@ -20,10 +20,8 @@ durable, append-only JSONL history (docs/observability.md "Perf ledger
   producer schemas, sharing the envelope {ts, kind, fingerprint,
   fingerprint_id, marks}. Engine records carry the windowed
   goodput/costmodel marks journaled every ``--perf-ledger-interval``
-  seconds and once on drain; bench records carry the artifact's
-  summary marks, including ``infra_failure`` runs (status + failure
-  class + claim telemetry) so a pool outage leaves a dated hole in the
-  trajectory instead of silence.
+  seconds and once on drain; bench records carry an artifact's
+  summary marks, or a status + failure class with no marks.
 * :func:`read_records` / :func:`group_by_cohort` /
   :func:`last_known_good` — the consumer side used by
   ``tools/perfdiff.py``, the CI gate, stacktop ``--history`` and the
@@ -119,12 +117,11 @@ def engine_snapshot_record(ts: float, fp: Mapping, marks: Mapping, *,
 
 
 def bench_record(ts: float, fp: Mapping, artifact: Mapping) -> Dict:
-    """One bench run — ok or infra_failure — in the shared schema.
+    """One bench run in the shared schema.
 
     Successful runs carry the headline marks (value tok/s/chip plus the
-    scenario summaries); infra failures carry status/failure_class and
-    the claim telemetry (attempts, total wait, pool state) so the
-    trajectory records *why* the mark is missing."""
+    scenario summaries); any other status carries its failure_class and
+    no marks, so the trajectory records *why* the mark is missing."""
     rec = _envelope(BENCH_KIND, ts, fp)
     status = str(artifact.get("status", "ok"))
     rec["status"] = status
@@ -139,9 +136,6 @@ def bench_record(ts: float, fp: Mapping, artifact: Mapping) -> Dict:
                         marks[f"{name}.{key}"] = block[key]
     else:
         rec["failure_class"] = str(artifact.get("failure_class", "unknown"))
-        for key in ("attempts", "claim_window_s", "pool_state"):
-            if artifact.get(key) is not None:
-                rec[key] = artifact[key]
     rec["marks"] = marks
     return rec
 

@@ -6,7 +6,6 @@ import dataclasses
 
 import jax
 
-from production_stack_tpu.engine.jax_compat import set_mesh
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -43,7 +42,7 @@ def setup():
 def naive_greedy(cfg, params, prompt, n_tokens, mesh):
     """Reference: full dense forward each step, argmax."""
     toks = list(prompt)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for _ in range(n_tokens):
             logits = jax.jit(llama.forward_dense, static_argnums=0)(
                 cfg, params, jnp.asarray([toks], jnp.int32)

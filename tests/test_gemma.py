@@ -14,7 +14,6 @@ import dataclasses
 import numpy as np
 import pytest
 
-from production_stack_tpu.engine.jax_compat import set_mesh
 
 torch = pytest.importorskip("torch")
 transformers = pytest.importorskip("transformers")
@@ -86,7 +85,7 @@ def test_logits_match_hf(family_ckpt):
     with torch.no_grad():
         ref = hf(toks).logits.numpy()
     mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_or_load(cfg, mesh)
     got = np.asarray(llama.forward_dense(cfg, params, jnp.asarray(toks.numpy())))
     np.testing.assert_allclose(got, ref, atol=3e-5, rtol=1e-4)

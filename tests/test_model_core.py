@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from production_stack_tpu.engine.jax_compat import set_mesh
 from production_stack_tpu.engine.config import EngineConfig, ModelConfig
 from production_stack_tpu.engine.weights import init_or_load
 from production_stack_tpu.models import llama
@@ -105,14 +104,14 @@ def test_dense_forward_tp_invariance(tiny_setup):
         np.random.default_rng(2).integers(0, cfg.vocab_size, (2, 12)), jnp.int32
     )
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sharded = jax.jit(llama.forward_dense, static_argnums=0)(cfg, params, tokens)
 
     single = build_mesh(MeshConfig(data=1, tensor=1), devices=jax.devices()[:1])
     params_local = jax.device_put(
         jax.tree.map(np.asarray, params), jax.devices()[0]
     )
-    with set_mesh(single):
+    with jax.set_mesh(single):
         local = jax.jit(llama.forward_dense, static_argnums=0)(cfg, params_local, tokens)
 
     np.testing.assert_allclose(
@@ -141,7 +140,7 @@ def test_qwen2_bias_engine_matches_dense():
     )["offline-0"]
 
     toks = list(prompt)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for _ in range(6):
             logits = jax.jit(llama.forward_dense, static_argnums=0)(
                 cfg.model, params, jnp.asarray([toks], jnp.int32)
@@ -155,7 +154,7 @@ def test_mixtral_moe_forward_runs():
     mesh = build_mesh(MeshConfig(data=1, tensor=4, expert=2))
     params = init_or_load(cfg, mesh, seed=0)
     tokens = jnp.asarray([[1, 2, 3, 4, 5]], jnp.int32)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits = jax.jit(llama.forward_dense, static_argnums=0)(cfg, params, tokens)
     assert logits.shape == (1, 5, cfg.vocab_size)
     assert bool(jnp.isfinite(logits).all())

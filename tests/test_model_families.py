@@ -18,7 +18,6 @@ transformers = pytest.importorskip("transformers")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from production_stack_tpu.engine.jax_compat import set_mesh
 from production_stack_tpu.engine.config import (  # noqa: E402
     CacheConfig,
     EngineConfig,
@@ -87,7 +86,7 @@ def test_logits_match_hf(family_ckpt):
     with torch.no_grad():
         ref = hf(toks).logits.numpy()
     mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_or_load(cfg, mesh)
     got = np.asarray(llama.forward_dense(cfg, params, jnp.asarray(toks.numpy())))
     np.testing.assert_allclose(got, ref, atol=3e-5, rtol=1e-4)

@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from production_stack_tpu.engine.jax_compat import set_mesh
 from production_stack_tpu.engine.config import ModelConfig
 from production_stack_tpu.engine.weights import init_or_load
 from production_stack_tpu.models import llama
@@ -45,14 +44,14 @@ def test_moe_forward_sharded_over_expert_axis():
     mesh = build_mesh(MeshConfig(data=1, tensor=2, expert=2))
     params = init_or_load(cfg, mesh, seed=0)
     tokens = jnp.asarray([[1, 2, 3, 4, 5, 6, 7, 8]], jnp.int32)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sharded = jax.jit(llama.forward_dense, static_argnums=0)(cfg, params, tokens)
 
     single = build_mesh(MeshConfig(data=1, tensor=1),
                         devices=jax.devices()[:1])
     params_local = jax.device_put(jax.tree.map(np.asarray, params),
                                   jax.devices()[0])
-    with set_mesh(single):
+    with jax.set_mesh(single):
         local = jax.jit(llama.forward_dense, static_argnums=0)(
             cfg, params_local, tokens
         )

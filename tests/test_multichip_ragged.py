@@ -16,8 +16,7 @@ speculative-verify traffic. Plus:
 - the ICI roofline arithmetic in PerfAccountant: per-chip collective
   bytes derived from the sharding spec + model geometry, the per-axis
   roofline breakdown in the /debug/perf snapshot, and ``from_runner``'s
-  chips/tensor-parallel derivation;
-- the jax_compat mesh-context shim resolves on the oldest CI jax.
+  chips/tensor-parallel derivation.
 
 Runs on the XLA-forced 8-device CPU host platform (tests/conftest.py),
 same lever the CI tier uses — no TPU required.
@@ -36,7 +35,7 @@ from production_stack_tpu.engine.config import (
     SchedulerConfig,
 )
 from production_stack_tpu.engine.perf_accounting import (
-    V5E_PEAK_ICI_GBPS,
+    DEVICE_PEAKS,
     PerfAccountant,
 )
 from production_stack_tpu.engine.sampling import SamplingParams
@@ -199,11 +198,16 @@ def test_zero_unexpected_recompiles_after_warmup_tp4():
 
 # ---- ICI roofline accounting (unit) ---------------------------------------
 
+V5E_TFLOPS, V5E_HBM_GBPS, V5E_PEAK_ICI_GBPS = DEVICE_PEAKS["TPU v5 lite"]
+
+
 def _accountant(tp, n_chips=None):
     cfg = dataclasses.replace(SHARDABLE, dtype="bfloat16")
     return PerfAccountant(cfg, param_count=1000, param_bytes=2000,
                           window=60.0, n_chips=n_chips or tp,
-                          tensor_parallel=tp)
+                          tensor_parallel=tp, peak_tflops=V5E_TFLOPS,
+                          peak_hbm_gbps=V5E_HBM_GBPS,
+                          peak_ici_gbps=V5E_PEAK_ICI_GBPS)
 
 
 def test_collective_bytes_formulas():
@@ -265,41 +269,3 @@ def test_from_runner_derives_chips_and_tp():
     assert eng.perf.tp == 4
     snap = eng.perf.snapshot()
     assert snap["chips"] == 4 and snap["tensor_parallel"] == 4
-
-
-# ---- jax_compat mesh-context shim -----------------------------------------
-
-def test_jax_compat_mesh_context_resolves_and_enters():
-    """set_mesh/use_mesh resolve to ONE working context manager on every
-    jax the CI matrix runs — newest (jax.set_mesh), intermediate
-    (jax.sharding.use_mesh), or oldest (the Mesh object itself)."""
-    from production_stack_tpu.engine import jax_compat
-    from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
-
-    assert jax_compat.set_mesh is jax_compat.use_mesh
-    resolved = jax_compat._resolve_mesh_context()
-    assert resolved is jax_compat.set_mesh
-    mesh = build_mesh(MeshConfig(data=1, tensor=4))
-    with jax_compat.set_mesh(mesh):
-        pass  # entering and leaving must work on this jax
-    # the oldest-jax fallback is always a valid context manager too
-    with jax_compat._mesh_is_context(mesh):
-        pass
-
-
-def test_jax_compat_prefers_newest_api(monkeypatch):
-    """Resolution order is pinned: jax.set_mesh wins over
-    jax.sharding.use_mesh wins over mesh-as-context."""
-    from production_stack_tpu.engine import jax_compat
-
-    sentinel_new = object()
-    monkeypatch.setattr(jax, "set_mesh", sentinel_new, raising=False)
-    assert jax_compat._resolve_mesh_context() is sentinel_new
-    monkeypatch.delattr(jax, "set_mesh", raising=False)
-    sentinel_use = object()
-    monkeypatch.setattr(jax.sharding, "use_mesh", sentinel_use,
-                        raising=False)
-    assert jax_compat._resolve_mesh_context() is sentinel_use
-    monkeypatch.delattr(jax.sharding, "use_mesh", raising=False)
-    assert (jax_compat._resolve_mesh_context()
-            is jax_compat._mesh_is_context)

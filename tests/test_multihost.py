@@ -334,7 +334,7 @@ def test_server_refuses_multihost_with_pipeline_stages():
     })
     proc = subprocess.run(
         [sys.executable, "-m", "production_stack_tpu.engine.server",
-         "--model", "tiny-llama", "--platform", "cpu",
+         "--model", "tiny-llama",
          "--num-processes", "2", "--process-id", "0",
          "--distributed-coordinator", "127.0.0.1:1",
          "--pipeline-parallel-size", "2"],
@@ -354,7 +354,7 @@ def test_real_server_two_process_group_serves_completions():
 
     coord, control, lport, fport = (_free_port() for _ in range(4))
     base = [sys.executable, "-m", "production_stack_tpu.engine.server",
-            "--model", "tiny-llama", "--platform", "cpu",
+            "--model", "tiny-llama",
             "--num-blocks", "128", "--max-num-seqs", "4",
             "--tensor-parallel-size", "2", "--data-parallel-size", "2"]
     follower = subprocess.Popen(

@@ -16,6 +16,7 @@ from production_stack_tpu.engine.config import (
     CacheConfig,
     EngineConfig,
     ModelConfig,
+    PerfConfig,
     SchedulerConfig,
 )
 from production_stack_tpu.engine.perf_accounting import (
@@ -96,10 +97,11 @@ def test_perf_accountant_window_trim_keeps_totals():
 
 def test_perf_accountant_empty_window_rates_are_zero():
     acc = make_accountant()
-    assert acc._window_rates(0.0) == {
-        "mfu": 0.0, "hbm_bw_util": 0.0, "ici_bw_util": 0.0,
-        "prefill_tps": 0.0, "decode_tps": 0.0,
-    }
+    rates = acc._window_rates(0.0)
+    assert rates["mfu"] == 0.0 and rates["hbm_bw_util"] == 0.0
+    assert rates["prefill_tps"] == 0.0 and rates["decode_tps"] == 0.0
+    # no ICI peak was given: that axis has no utilization, not 0%
+    assert rates["ici_bw_util"] is None
 
 
 def test_compile_events_and_steady_state_marking():
@@ -170,6 +172,8 @@ def make_server() -> EngineServer:
             prefill_buckets=(32, 64),
         ),
         mesh=MeshConfig(data=1, tensor=1),
+        # the CPU has no entry in DEVICE_PEAKS: utilization needs peaks
+        perf=PerfConfig(peak_tflops=1e-3, peak_hbm_gbps=1.0),
     )
     return EngineServer(cfg)
 
@@ -230,6 +234,39 @@ def test_debug_perf_and_metrics_after_traffic(server):
         assert "vllm:hbm_bytes_used" in text  # 0 on CPU, but exported
 
     asyncio.run(_with_client(server, fn))
+
+
+def test_unknown_device_kind_exports_no_utilization(server):
+    """No peaks for the device (the CPU is not in DEVICE_PEAKS) and none
+    given: token rates and compile events are reported, utilization is
+    not — a v5e-relative "MFU" of a CPU run is not a utilization."""
+    import dataclasses
+
+    from production_stack_tpu.engine.perf_accounting import DEVICE_PEAKS
+
+    assert "cpu" not in DEVICE_PEAKS
+    cfg = dataclasses.replace(server.config, perf=PerfConfig())
+    acct = PerfAccountant.from_runner(cfg, server.engine.runner)
+    acct.record_decode(live_seqs=2, steps=1, ctx_tokens=8)
+    kept, server.engine.perf = server.engine.perf, acct
+
+    async def fn(client):
+        perf = await (await client.get("/debug/perf")).json()
+        assert perf["model_flops_utilization"] is None
+        assert perf["hbm_bandwidth_utilization"] is None
+        assert perf["rooflines"]["flop"]["peak_per_s"] is None
+        assert perf["rooflines"]["flop"]["achieved_per_s"] > 0
+        assert perf["tokens_per_second"]["decode"] > 0
+        text = await (await client.get("/metrics")).text()
+        assert "vllm:model_flops_utilization" not in text
+        assert "vllm:hbm_bandwidth_utilization" not in text
+        assert "vllm:ici_bandwidth_utilization" not in text
+        assert _metric_value(text, "vllm:tokens_per_second") > 0
+
+    try:
+        asyncio.run(_with_client(server, fn))
+    finally:
+        server.engine.perf = kept
 
 
 def test_unexpected_recompile_after_steady(server):

@@ -5,7 +5,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from production_stack_tpu.engine.jax_compat import set_mesh
 from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
 from production_stack_tpu.parallel.pipeline import (
     pipelined_forward,
@@ -39,7 +38,7 @@ def test_pipelined_matches_sequential():
 
     mesh = build_mesh(MeshConfig(data=1, stage=4, tensor=2))
     staged = split_layers_into_stages(params, 4)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(
             lambda p, xx: pipelined_forward(layer_fn, p, xx, mesh, "stage")
         )(staged, x)
@@ -64,7 +63,7 @@ def test_single_stage_degenerates():
             h = layer_fn(jax.tree.map(lambda a: a[i], params), h)
         return h
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = pipelined_forward(layer_fn, staged, x, mesh, "stage")
     want = jax.vmap(seq_forward)(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -4,7 +4,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from production_stack_tpu.engine.jax_compat import set_mesh
 from production_stack_tpu.ops.attention import dense_causal_attention
 from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
 from production_stack_tpu.parallel.ring_attention import ring_causal_attention
@@ -18,7 +17,7 @@ def test_ring_matches_dense_causal():
     k = jnp.asarray(rng.standard_normal((B, S, KH, D)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, S, KH, D)), jnp.float32)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(
             lambda q, k, v: ring_causal_attention(q, k, v, mesh, "seq")
         )(q, k, v)
@@ -33,7 +32,7 @@ def test_ring_single_shard_degenerates():
     q = jnp.asarray(rng.standard_normal((1, 8, 4, 8)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((1, 8, 2, 8)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((1, 8, 2, 8)), jnp.float32)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = ring_causal_attention(q, k, v, mesh, "seq")
     want = dense_causal_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),

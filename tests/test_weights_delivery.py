@@ -11,7 +11,6 @@ import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from production_stack_tpu.engine.jax_compat import set_mesh
 
 
 def test_orbax_roundtrip_sharded(tmp_path, mesh8):
@@ -30,7 +29,7 @@ def test_orbax_roundtrip_sharded(tmp_path, mesh8):
         weights_path=None,
     )
     rules = rules_for_model(cfg, mesh8)
-    with set_mesh(mesh8):
+    with jax.set_mesh(mesh8):
         params = init_or_load(cfg, mesh8, rules, seed=3)
     path = str(tmp_path / "ckpt")
     save_orbax(params, path)
