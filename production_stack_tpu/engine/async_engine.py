@@ -11,9 +11,9 @@ from __future__ import annotations
 
 import asyncio
 import concurrent.futures
+import logging
 import queue
 import threading
-import time
 import uuid
 from typing import AsyncIterator, Optional, Sequence as Seq
 
@@ -62,19 +62,22 @@ class AsyncEngine:
 
     # -- worker thread -------------------------------------------------------
     def _worker(self) -> None:
+        # every moment of this loop belongs to a phase of the engine's
+        # step clock (engine/tracing.py): idle and intake here, the rest
+        # inside engine.step(), deliver after it
+        clock = self.engine.clock
         while self.running:
             self._drain_intake(block=not self.engine.has_unfinished())
             if self.paused or not self.engine.has_unfinished():
+                clock.end_step()  # no step ran: flush idle and intake
                 continue
-            t_step = time.monotonic()
+            clock.begin_step()
             try:
                 outputs = self.engine.step()
             except Exception as e:
                 # a step failure must not kill the worker thread: every
                 # open stream would hang forever. Fail the in-flight
                 # requests and keep serving.
-                import logging
-
                 logging.getLogger(__name__).exception("engine.step failed")
                 err = ValueError(f"engine step failed: {e}")
                 if self.loop is not None:
@@ -84,23 +87,31 @@ class AsyncEngine:
                         )
                 for rid in self.engine.live_request_ids():
                     self.engine.abort_request(rid)
+                clock.end_step()
                 continue
             self.step_count += 1
+            if outputs and self.loop is not None:
+                clock.enter("deliver")
+                self.loop.call_soon_threadsafe(self._deliver, outputs)
+            step_seconds = clock.end_step()
             if self.step_observer is not None:
                 try:
-                    self.step_observer(time.monotonic() - t_step)
+                    self.step_observer(step_seconds)
                 except Exception:
                     logging.getLogger(__name__).debug(
                         "step_observer hook failed", exc_info=True)
-            if outputs and self.loop is not None:
-                self.loop.call_soon_threadsafe(self._deliver, outputs)
 
     def _drain_intake(self, block: bool) -> None:
+        clock = self.engine.clock
+        clock.enter("idle" if block else "intake")
         try:
             item = self.intake.get(timeout=0.05 if block else 0)
         except queue.Empty:
             return
         while True:
+            # per item: a "call" may run whole steps (warm-up), which end
+            # with no phase open
+            clock.enter("intake")
             kind, payload = item
             if kind == "add":
                 # 4-tuple (legacy) or 5-tuple with the tenant identity

@@ -30,6 +30,7 @@ from production_stack_tpu.engine.sequence import (
     SequenceStatus,
 )
 from production_stack_tpu.engine.tokenizer import get_tokenizer
+from production_stack_tpu.engine.tracing import StepClock
 from production_stack_tpu.parallel.mesh import build_mesh
 from production_stack_tpu.tenancy import split_shares
 
@@ -87,6 +88,9 @@ class LLMEngine:
                                             num_blocks)
         else:
             self.runner = ModelRunner(config, self.mesh, params, num_blocks)
+        # where this thread's time goes, always on (engine/tracing.py);
+        # the runner switches its own phases (snapshot, commit, launch)
+        self.clock = self.runner.clock = StepClock()
         self.scheduler = Scheduler(
             config.scheduler, config.cache, self.runner.num_blocks,
             max_model_len=config.model.max_model_len,
@@ -247,6 +251,7 @@ class LLMEngine:
         # padding-waste signal the bucketed path hid in bucket geometry
         self.ragged_dispatches = 0
         self.ragged_live_tokens = 0
+        self.decode_dispatches = 0  # decode_multi dispatches
         # goodput accounting + compile tracking (perf_accounting.py); the
         # staged PP runner exposes no single param tree or jit programs to
         # wrap, so it only gets dispatch accounting
@@ -403,9 +408,20 @@ class LLMEngine:
 
     # -- the step ------------------------------------------------------------
     def step(self) -> list[RequestOutput]:
+        if self.clock.in_step:  # the async worker opened it
+            return self._step()
+        self.clock.begin_step()
+        try:
+            return self._step()
+        finally:
+            self.clock.end_step()
+
+    def _step(self) -> list[RequestOutput]:
+        self.clock.enter("schedule")
         # land finished warm-tier fetches first so their sequences become
         # schedulable in THIS step's decision
         self._poll_prefetches()
+        self.scheduler.step_num = self.clock.step_num
         out = self.scheduler.schedule()
         if out.is_empty:
             outputs = self._resolve_pending_ragged()
@@ -417,9 +433,10 @@ class LLMEngine:
                 # wait trades a busy-spin for latency no request observes.
                 # Time spent here is the NON-overlapped share of prefetch
                 # (the bench's prefetch-overlap fraction reads it).
-                t0 = time.monotonic()
+                t0 = self.clock.enter("prefetch_wait")
                 self._prefetcher.wait_any(0.002)
-                self.prefetch_stall_seconds += time.monotonic() - t0
+                self.prefetch_stall_seconds += (
+                    self.clock.enter("postprocess") - t0)
             return outputs
         if out.prefills:
             if self.attention_impl == "ragged" and not out.prefills[0].ring:
@@ -441,6 +458,7 @@ class LLMEngine:
         decodes = [s for s in out.decodes
                    if s.status is SequenceStatus.RUNNING]
         if decodes:
+            self.clock.enter("build")
             if self._spec is not None and self._propose_spec_drafts(decodes):
                 # drafts ride the packed stream as prefill-shaped spans;
                 # verification is fused in the same ragged dispatch
@@ -506,13 +524,20 @@ class LLMEngine:
                 self._spec.update(seq, k, 0)
         return any_drafts
 
+    def _fetch(self, result_dev) -> tuple:
+        """Block on a dispatch's results: the step clock's `wait` phase.
+        Returns (the results on the host, the seconds blocked)."""
+        t0 = self.clock.enter("wait")
+        fetched = jax.device_get(result_dev)
+        return fetched, self.clock.enter("postprocess") - t0
+
     def _resolve_pending_prefill(self) -> list[RequestOutput]:
         """Fetch + postprocess the previous prefill dispatch (if any)."""
         if self._pending_prefill is None:
             return []
         prefills, result_dev = self._pending_prefill
         self._pending_prefill = None
-        fetched = jax.device_get(result_dev)
+        fetched, _ = self._fetch(result_dev)
         if isinstance(fetched, (tuple, list)):  # (sampled, *logprob arrays)
             fetched = tuple(np.asarray(x) for x in fetched)
         else:  # staged PP runner: bare sampled tokens
@@ -659,6 +684,7 @@ class LLMEngine:
         seq = sp.seq
         n = sp.chunk_len
         n_seq = self.mesh.shape[AXIS_SEQ]
+        self.clock.enter("build")
         # pad to a power of two (one compile per size class), then up to a
         # multiple of the seq axis so shard_map can split it
         S = max(2 * n_seq, 1 << (n - 1).bit_length())
@@ -669,7 +695,8 @@ class LLMEngine:
         slot_mapping = np.full(S, -1, np.int32)
         slot_mapping[:n] = slot_mapping_for(seq.block_ids, 0, n, bs)
         s = seq.sampling
-        t_dispatch = time.monotonic()
+        self.clock.describe("prefill", rows=1, tokens=n)
+        t_call = self.clock.enter("snapshot")
         result = self.runner.prefill_ring(
             tokens, positions, slot_mapping,
             np.asarray([n - 1], np.int32),
@@ -686,8 +713,8 @@ class LLMEngine:
                 if seq.token_ctrl is not None else None
             ),
         )
+        dispatch_s = self.clock.enter("postprocess") - t_call
         if self.perf is not None:
-            dispatch_s = time.monotonic() - t_dispatch
             entries = [(seq, "prefill", n, n)]
             self.perf.record_prefill(n, n, 1, seconds=dispatch_s,
                                      tenants=self._tenant_map(entries))
@@ -700,7 +727,7 @@ class LLMEngine:
         if seq.output_token_ids:
             return []  # preemption-recompute: newest token still pending
         token = int(result[0][0])
-        seq.first_token_time = time.monotonic()
+        self._stamp_first_token(seq)
         seq.output_token_ids.append(token)
         self.total_output_tokens += 1
         lp_lists = (
@@ -745,6 +772,7 @@ class LLMEngine:
             outputs.extend(self._run_prefill_ring(prefills[0]))
             return outputs
         bs = self.config.cache.block_size
+        self.clock.enter("build")
         # batch-dim padded to the next power of two: inactive rows skip
         # attention but still pay QKV/MLP, so padding 2 live 512-token
         # chunks to P=8 would burn 4x the prefill FLOPs (measured: the
@@ -809,7 +837,9 @@ class LLMEngine:
                     c_ids[i], c_vals[i], c_mode[i] = sp.seq.token_ctrl
             ctrl = (c_ids, c_vals, c_mode)
         use_grammar = bool((g_ids >= 0).any())
-        t_dispatch = time.monotonic()
+        self.clock.describe("prefill", rows=len(prefills),
+                            tokens=sum(sp.chunk_len for sp in prefills))
+        t_call = self.clock.enter("snapshot")
         sampled_dev = self.runner.prefill(
             tokens, positions, tables, context_lens, slot_mapping.reshape(-1),
             last_idx, temps, top_ps, top_ks, seeds, greedy_only=greedy_only,
@@ -818,8 +848,8 @@ class LLMEngine:
             g_ids=g_ids if use_grammar else None,
             fetch=False,
         )
+        dispatch_s = self.clock.enter("postprocess") - t_call
         if self.perf is not None:
-            dispatch_s = time.monotonic() - t_dispatch
             entries = [(sp.seq, "prefill", sp.chunk_len, sp.chunk_len)
                        for sp in prefills]
             self.perf.record_prefill(
@@ -863,7 +893,7 @@ class LLMEngine:
             if seq.status.is_finished:
                 continue  # aborted while the dispatch was in flight
             token = int(sampled[i])
-            seq.first_token_time = time.monotonic()
+            self._stamp_first_token(seq)
             seq.output_token_ids.append(token)
             if seq.grammar_slot >= 0 and seq.fsm is not None:
                 seq.fsm_state = int(seq.fsm.trans[0, token])
@@ -896,6 +926,7 @@ class LLMEngine:
                     if not sp.seq.status.is_finished]
         if not decodes and not prefills:
             return outputs
+        self.clock.enter("build")
         if self._spec is not None and not proposed:
             # pendings are resolved: token histories are complete, so the
             # scheduler's budget grants can become concrete drafts now
@@ -1035,7 +1066,8 @@ class LLMEngine:
             self._count_reset_slots.clear()
         use_controls = any(s.token_ctrl is not None for s in seqs_in_step)
         use_grammar = bool((self._g_ids >= 0).any())
-        t_dispatch = time.monotonic()
+        self.clock.describe("ragged", rows=len(seqs_in_step), tokens=cu)
+        t_call = self.clock.enter("snapshot")
         result_dev = self.runner.ragged_step(
             self._r_tokens, self._r_positions, self._block_tables,
             self._context_lens, self._r_cu, self._r_slot_mapping,
@@ -1054,10 +1086,10 @@ class LLMEngine:
                         if self._spec is not None else None),
             fetch=False,
         )
+        dispatch_s = self.clock.enter("postprocess") - t_call
         if self.perf is not None:
             # draft/verify spans are prefill-shaped work with zero goodput;
             # accepted tokens land as decode goodput at resolve time
-            dispatch_s = time.monotonic() - t_dispatch
             self.perf.record_ragged(p_tokens, p_ctx, p_rows,
                                     len(decodes), d_ctx,
                                     spec_tokens=sp_tokens, spec_ctx=sp_ctx,
@@ -1116,16 +1148,13 @@ class LLMEngine:
             return []
         pending = self._pending_ragged
         self._pending_ragged = None
-        t_fetch = time.monotonic()
-        fetched = tuple(
-            np.asarray(x) for x in jax.device_get(pending["result"])
-        )
+        fetched, fetch_s = self._fetch(pending["result"])
+        fetched = tuple(np.asarray(x) for x in fetched)
         if self.perf is not None:
             # the blocking result fetch is dispatch wall time too — billed
             # by the same live-token shares so conservation spans the
             # dispatch/resolve split
             entries = pending.get("tenant_entries") or []
-            fetch_s = time.monotonic() - t_fetch
             tmap = self._tenant_map(entries)
             if tmap:
                 self.perf.attribute_seconds(
@@ -1171,7 +1200,7 @@ class LLMEngine:
                 new_toks.append(t)
                 self.total_output_tokens += 1
                 if seq.first_token_time is None:
-                    seq.first_token_time = time.monotonic()
+                    self._stamp_first_token(seq)
                 if self._check_stop(seq, t) is not None:
                     break
             self.spec_step_tokens += len(new_toks)
@@ -1187,7 +1216,7 @@ class LLMEngine:
             if seq.status.is_finished:
                 continue  # aborted while the dispatch was in flight
             token = int(sampled[slot])
-            seq.first_token_time = time.monotonic()
+            self._stamp_first_token(seq)
             seq.output_token_ids.append(token)
             if seq.grammar_slot >= 0 and seq.fsm is not None:
                 seq.fsm_state = int(seq.fsm.trans[0, token])
@@ -1248,6 +1277,7 @@ class LLMEngine:
                     return outputs
                 pending = None
         chain = pending is not None
+        self.clock.enter("build")
         self._context_lens[:] = 0
         self._slot_mapping[:] = -1
         for seq in decodes:
@@ -1295,7 +1325,10 @@ class LLMEngine:
                     self.runner.set_count_row(seq.slot, seq.output_token_ids)
             self._count_reset_slots.clear()
         use_controls = any(s.token_ctrl is not None for s in decodes)
-        t_dispatch = time.monotonic()
+        K = max(self.config.scheduler.multi_step, 1)
+        self.clock.describe("decode", rows=len(decodes),
+                            tokens=K * len(decodes))
+        t_call = self.clock.enter("snapshot")
         result = self.runner.decode_multi(
             self._tokens, self._positions, self._block_tables,
             self._context_lens, self._slot_mapping,
@@ -1312,9 +1345,9 @@ class LLMEngine:
             fetch=not can_chain,
             want_logprobs=use_logprobs,
         )
+        dispatch_s = self.clock.enter("postprocess") - t_call
+        self.decode_dispatches += 1
         if self.perf is not None:
-            dispatch_s = time.monotonic() - t_dispatch
-            K = max(self.config.scheduler.multi_step, 1)
             entries = [(seq, "decode", K, K) for seq in decodes]
             self.perf.record_decode(
                 len(decodes), K, int(self._context_lens.sum()),
@@ -1325,7 +1358,6 @@ class LLMEngine:
             sampled, next_tok = result
             # defer: speculative num_computed advance (the scheduler's
             # block growth needs it NOW); tokens append at resolution
-            K = max(self.config.scheduler.multi_step, 1)
             for seq in decodes:
                 seq.num_computed_tokens += K
             self._pending_decode = {
@@ -1363,7 +1395,7 @@ class LLMEngine:
         dispatch."""
         sampled = pending["sampled"]
         if not fetched:
-            sampled = np.asarray(jax.device_get(sampled))
+            sampled = np.asarray(self._fetch(sampled)[0])
         lp = pending.get("lp")  # (tok_lp (K, B), ids (K, B, N), lps ...)
         token_lists = []
         lp_lists = []
@@ -1399,6 +1431,10 @@ class LLMEngine:
             lp_lists.append(new_lps)
         return self._postprocess(live, token_lists, lp_lists)
 
+    def _stamp_first_token(self, seq: Sequence) -> None:
+        seq.first_token_time = time.monotonic()
+        seq.first_token_step = self.clock.step_num
+
     def _postprocess(
         self, seqs: list[Sequence], token_lists: list[list[int]],
         lp_lists: Optional[list] = None,
@@ -1413,6 +1449,7 @@ class LLMEngine:
                 self._slot_seq.pop(seq.slot, None)
                 self._release_grammar(seq)
                 seq.finish_time = time.monotonic()
+                seq.finish_step = self.clock.step_num
                 if self.perf is not None and seq.admit_time is not None:
                     self.perf.note_request(
                         seq.tenant, seq.admit_time - seq.arrival_time)
@@ -1437,6 +1474,10 @@ class LLMEngine:
                                       if status is not None else None),
                     finish_time=(seq.finish_time if status is not None
                                  else None),
+                    steps=({"admitted": seq.admit_step,
+                            "first_token": seq.first_token_step,
+                            "last_token": seq.finish_step}
+                           if status is not None else None),
                     new_logprobs=(lp_lists[j] if lp_lists is not None
                                   else None),
                 )
@@ -1605,6 +1646,8 @@ class LLMEngine:
             # vllm:ragged_* series)
             "ragged_dispatches_total": self.ragged_dispatches,
             "ragged_live_tokens_total": self.ragged_live_tokens,
+            "decode_dispatches_total": self.decode_dispatches,
+            "step_phases": self.clock.snapshot(),
             "ragged_stream_utilization": (
                 self.ragged_live_tokens
                 / max(1, self.ragged_dispatches

@@ -162,6 +162,58 @@ class EngineStatsCollector:
             "Live (unpadded) tokens packed into ragged dispatches",
             s.get("ragged_live_tokens_total", 0),
         )
+        yield counter(
+            "vllm:decode_dispatches",
+            "decode_multi dispatches issued (decode-only steps)",
+            s.get("decode_dispatches_total", 0),
+        )
+        # the engine thread's step clock (engine/tracing.py): where its
+        # wall time goes, by the kind of step and the phase of the loop.
+        # Waiting for the device, idling on the intake queue and on-CPU
+        # time are families of their own, so that a sum over one
+        # family's labels is one quantity; every kind is printed from the
+        # first scrape, at 0
+        phases = s.get("step_phases")
+        if phases:
+            host = CounterMetricFamily(
+                "vllm:engine_host_seconds",
+                "Engine-thread wall seconds by step kind and loop phase "
+                "(every phase but wait and idle)",
+                labels=["model_name", "kind", "phase"],
+            )
+            cpu = CounterMetricFamily(
+                "vllm:engine_host_cpu_seconds",
+                "Engine-thread on-CPU seconds (time.thread_time) over the "
+                "phases of vllm:engine_host_seconds; wall minus this is "
+                "time the thread wanted to run and did not (GIL waits, "
+                "blocking calls)",
+                labels=["model_name", "kind"],
+            )
+            wait = CounterMetricFamily(
+                "vllm:engine_device_wait_seconds",
+                "Engine-thread wall seconds blocked on device results "
+                "(jax.device_get) by step kind",
+                labels=["model_name", "kind"],
+            )
+            for kind, by_phase in phases["seconds"].items():
+                on_cpu = 0.0
+                for phase, sec in by_phase.items():
+                    if phase == "wait":
+                        wait.add_metric([self.model_name, kind], sec["wall"])
+                    else:
+                        host.add_metric([self.model_name, kind, phase],
+                                        sec["wall"])
+                        on_cpu += sec["cpu"]
+                cpu.add_metric([self.model_name, kind], on_cpu)
+            yield host
+            yield cpu
+            yield wait
+            yield counter(
+                "vllm:engine_idle_seconds",
+                "Engine-thread wall seconds blocked on the intake queue "
+                "with nothing unfinished",
+                phases["idle_seconds"],
+            )
         yield gauge(
             "vllm:ragged_stream_utilization",
             "Cumulative live-token fill of the budget-wide ragged stream "

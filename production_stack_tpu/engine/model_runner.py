@@ -45,6 +45,7 @@ from production_stack_tpu.engine.config import EngineConfig, ModelConfig
 from production_stack_tpu.engine import kv_cache as kvmod
 from production_stack_tpu.engine.quant import maybe_quantize
 from production_stack_tpu.engine.sampling import sample_tokens
+from production_stack_tpu.engine.tracing import StepClock
 from production_stack_tpu.engine.weights import init_or_load
 from production_stack_tpu.models.registry import get_model
 from production_stack_tpu.ops.paged_attention import (
@@ -56,6 +57,15 @@ from production_stack_tpu.parallel.mesh import AXIS_TENSOR
 from production_stack_tpu.parallel.shardings import rules_for_model
 
 _log = logging.getLogger(__name__)
+
+
+def _named_partial(fn, *bound):
+    """functools.partial that keeps the function's name, so XLA names the
+    jitted program after it (``jit_ragged_step``; a bare partial compiles
+    to ``jit__unknown``) and a profiler trace can tell the programs apart."""
+    part = functools.partial(fn, *bound)
+    part.__name__ = fn.__name__.lstrip("_")
+    return part
 
 
 def _pallas_ok(cfg: ModelConfig, mesh: Mesh, block_size: int) -> bool:
@@ -100,6 +110,9 @@ class ModelRunner:
         self.config = config
         self.cfg = config.model
         self.mesh = mesh
+        # the engine's step clock (engine/tracing.py); the engine replaces
+        # this one with its own, a runner driven alone keeps it
+        self.clock = StepClock()
         if (self.cfg.sliding_window
                 and self.cfg.max_model_len > self.cfg.sliding_window):
             # local/global attention layers coincide only within the window;
@@ -171,19 +184,19 @@ class ModelRunner:
             self._mh_gate_all = {}
 
         self._prefill = jax.jit(
-            functools.partial(_prefill_step, self.cfg, self._attend_prefill,
-                              self._eos_id),
+            _named_partial(_prefill_step, self.cfg, self._attend_prefill,
+                           self._eos_id),
             donate_argnums=(1,),
             static_argnames=("greedy_only", "use_controls", "use_grammar"),
             **self._mh_gate,
         )
         self._decode = jax.jit(
-            functools.partial(_decode_step, self.cfg, self._attend_decode),
+            _named_partial(_decode_step, self.cfg, self._attend_decode),
             donate_argnums=(1,),
             **self._mh_gate,
         )
         self._decode_multi = jax.jit(
-            functools.partial(
+            _named_partial(
                 _decode_multi_step, self.cfg, self._attend_decode,
                 max(config.scheduler.multi_step, 1), self._eos_id,
             ),
@@ -201,9 +214,9 @@ class ModelRunner:
             # _verify program, no lazy verify compile after warmup)
             self.spec_width = max(config.scheduler.spec_ngram_k, 0)
             self._ragged = jax.jit(
-                functools.partial(_ragged_step, self.cfg,
-                                  self._attend_ragged, self._eos_id,
-                                  self.spec_width),
+                _named_partial(_ragged_step, self.cfg,
+                               self._attend_ragged, self._eos_id,
+                               self.spec_width),
                 donate_argnums=(1,),
                 static_argnames=("greedy_only", "use_penalties",
                                  "use_controls", "use_grammar"),
@@ -223,7 +236,7 @@ class ModelRunner:
                          if self.rules.rules.get(ln.KV_HEADS) is not None
                          else None)
             self._prefill_ring = jax.jit(
-                functools.partial(
+                _named_partial(
                     _prefill_ring_step, self.cfg, mesh, head_axis, self.tp
                 ),
                 donate_argnums=(1,),
@@ -536,13 +549,11 @@ class ModelRunner:
         use_lora = adapter_ids is not None and self.lora_bank is not None
         use_grammar = g_ids is not None and self.grammar_bank is not None
         with jax.set_mesh(self.mesh):
-            self.kv, result = self._prefill(
-                self.params, self.kv,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(block_tables), jnp.asarray(context_lens),
-                jnp.asarray(slot_mapping), jnp.asarray(last_idx),
-                jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(top_ks),
-                jnp.asarray(seeds),
+            self.clock.enter("commit")
+            args = [jnp.asarray(x) for x in (
+                tokens, positions, block_tables, context_lens, slot_mapping,
+                last_idx, temps, top_ps, top_ks, seeds)]
+            kwargs = dict(
                 lora_bank=self.lora_bank if use_lora else None,
                 adapter_ids=(jnp.asarray(adapter_ids, jnp.int32)
                              if use_lora else None),
@@ -553,12 +564,17 @@ class ModelRunner:
                      jnp.asarray(g_ids, jnp.int32))
                     if use_grammar else None
                 ),
+            )
+            self.clock.launch()
+            self.kv, result = self._prefill(
+                self.params, self.kv, *args, **kwargs,
                 greedy_only=greedy_only,
                 use_controls=ctrl is not None,
                 use_grammar=use_grammar,
             )
         if not fetch:
             return result
+        self.clock.enter("wait")
         return tuple(np.asarray(x) for x in jax.device_get(result))
 
     def prefill_ring(self, tokens: np.ndarray, positions: np.ndarray,
@@ -576,20 +592,24 @@ class ModelRunner:
         S x S score matrix on one device — K/V shards rotate the ring."""
         use_lora = adapter_ids is not None and self.lora_bank is not None
         with jax.set_mesh(self.mesh):
-            self.kv, result = self._prefill_ring(
-                self.params, self.kv,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(slot_mapping), jnp.asarray(last_idx),
-                jnp.asarray(temps), jnp.asarray(top_ps),
-                jnp.asarray(top_ks), jnp.asarray(seeds),
+            self.clock.enter("commit")
+            args = [jnp.asarray(x) for x in (
+                tokens, positions, slot_mapping, last_idx, temps, top_ps,
+                top_ks, seeds)]
+            kwargs = dict(
                 lora_bank=self.lora_bank if use_lora else None,
                 adapter_ids=(jnp.asarray(adapter_ids, jnp.int32)
                              if use_lora else None),
                 ctrl=(tuple(jnp.asarray(c) for c in ctrl)
                       if ctrl is not None else None),
+            )
+            self.clock.launch()
+            self.kv, result = self._prefill_ring(
+                self.params, self.kv, *args, **kwargs,
                 greedy_only=greedy_only,
                 use_controls=ctrl is not None,
             )
+        self.clock.enter("wait")
         return tuple(np.asarray(x) for x in jax.device_get(result))
 
     def decode(self, tokens: np.ndarray, positions: np.ndarray,
@@ -674,6 +694,7 @@ class ModelRunner:
                     else tuple(np.array(c) for c in ctrl))
             g_ids = None if g_ids is None else np.array(g_ids)
             g_states = None if g_states is None else np.array(g_states)
+        self.clock.enter("commit")
         if use_penalties:
             self._ensure_counts()
             counts = self.token_counts
@@ -690,16 +711,10 @@ class ModelRunner:
         tok_in = (tokens_dev if tokens_dev is not None
                   else jnp.asarray(tokens[:, None]))
         with jax.set_mesh(self.mesh):
-            (self.kv, new_counts), (sampled, next_tok, *lp) = self._decode_multi(
-                self.params, self.kv,
-                tok_in, jnp.asarray(positions[:, None]),
-                jnp.asarray(block_tables), jnp.asarray(context_lens),
-                jnp.asarray(slot_mapping),
-                jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(top_ks),
-                jnp.asarray(seeds), jnp.asarray(steps),
-                counts, pres, freq,
-                self.lora_bank if use_lora else None,
-                (jnp.asarray(adapter_ids, jnp.int32) if use_lora else None),
+            args = [tok_in, *(jnp.asarray(x) for x in (
+                positions[:, None], block_tables, context_lens, slot_mapping,
+                temps, top_ps, top_ks, seeds, steps))]
+            kwargs = dict(
                 ctrl=(tuple(jnp.asarray(c) for c in ctrl)
                       if ctrl is not None else None),
                 grammar=(
@@ -708,6 +723,13 @@ class ModelRunner:
                      jnp.asarray(g_states, jnp.int32))
                     if use_grammar else None
                 ),
+            )
+            lora_ids = (jnp.asarray(adapter_ids, jnp.int32)
+                        if use_lora else None)
+            self.clock.launch()
+            (self.kv, new_counts), (sampled, next_tok, *lp) = self._decode_multi(
+                self.params, self.kv, *args, counts, pres, freq,
+                self.lora_bank if use_lora else None, lora_ids, **kwargs,
                 block_size=self.config.cache.block_size,
                 greedy_only=greedy_only,
                 use_penalties=use_penalties,
@@ -719,6 +741,7 @@ class ModelRunner:
             self.token_counts = new_counts
         if not fetch:
             return sampled, next_tok  # chain path never carries logprobs
+        self.clock.enter("wait")
         if want_logprobs:
             # (sampled (K, B), tok_lp (K, B), ids (K, B, N), lps (K, B, N))
             return tuple(np.asarray(x) for x in jax.device_get((sampled, *lp)))
@@ -798,6 +821,7 @@ class ModelRunner:
             g_ids = None if g_ids is None else np.array(g_ids)
             g_states = None if g_states is None else np.array(g_states)
             verify_idx = None if verify_idx is None else np.array(verify_idx)
+        self.clock.enter("commit")
         S = context_lens.shape[0]
         if use_penalties:
             self._ensure_counts()
@@ -811,15 +835,11 @@ class ModelRunner:
         use_lora = adapter_ids is not None and self.lora_bank is not None
         use_grammar = g_ids is not None and self.grammar_bank is not None
         with jax.set_mesh(self.mesh):
-            (self.kv, new_counts), result = self._ragged(
-                self.params, self.kv,
-                self._commit(tokens), self._commit(positions),
-                self._commit(block_tables), self._commit(context_lens),
-                self._commit(cu_q_lens), self._commit(slot_mapping),
-                self._commit(last_idx), self._commit(sample_mask),
-                self._commit(temps), self._commit(top_ps),
-                self._commit(top_ks), self._commit(seeds),
-                self._commit(steps), counts, pres, freq,
+            args = [self._commit(x) for x in (
+                tokens, positions, block_tables, context_lens, cu_q_lens,
+                slot_mapping, last_idx, sample_mask, temps, top_ps, top_ks,
+                seeds, steps)]
+            kwargs = dict(
                 verify_idx=(self._commit(np.asarray(verify_idx, np.int32))
                             if self.spec_width > 0 else None),
                 lora_bank=self.lora_bank if use_lora else None,
@@ -833,6 +853,10 @@ class ModelRunner:
                      jnp.asarray(g_states, jnp.int32))
                     if use_grammar else None
                 ),
+            )
+            self.clock.launch()
+            (self.kv, new_counts), result = self._ragged(
+                self.params, self.kv, *args, counts, pres, freq, **kwargs,
                 greedy_only=greedy_only,
                 use_penalties=use_penalties,
                 use_controls=ctrl is not None,
@@ -842,6 +866,7 @@ class ModelRunner:
             self.token_counts = new_counts
         if not fetch:
             return result
+        self.clock.enter("wait")
         return tuple(np.asarray(x) for x in jax.device_get(result))
 
     # -- sleep mode hooks ----------------------------------------------------

@@ -30,6 +30,7 @@ from jax.sharding import Mesh
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.model_runner import ModelRunner, _make_lora
 from production_stack_tpu.engine.quant import maybe_quantize
+from production_stack_tpu.engine.tracing import StepClock
 from production_stack_tpu.models.registry import get_model
 from production_stack_tpu.parallel.mesh import AXIS_STAGE, MESH_AXES
 from production_stack_tpu.parallel.shardings import (
@@ -62,6 +63,9 @@ class StagedModelRunner:
         num_blocks: Optional[int] = None,
     ):
         self.config = config
+        # the stages relay through the host inside one call, so the whole
+        # call is the step clock's `launch` phase (engine/tracing.py)
+        self.clock = StepClock()
         self.cfg = config.model
         self.mesh = mesh
         S = mesh.shape[AXIS_STAGE]
@@ -175,6 +179,7 @@ class StagedModelRunner:
                 slot_mapping, last_idx, temps, top_ps, top_ks, seeds,
                 greedy_only: bool = True, adapter_ids=None, ctrl=None,
                 g_ids=None, fetch: bool = True):
+        self.clock.launch()
         x = jnp.asarray(tokens)  # stage 0 consumes token ids
         common = (
             jnp.asarray(positions), jnp.asarray(block_tables),
@@ -217,6 +222,7 @@ class StagedModelRunner:
         """K single decode steps, each relayed through the stages. The host
         advances positions/slots between steps (the sampled token must come
         back to stage 0, so cross-step fusion can't live in one program)."""
+        self.clock.launch()
         K = max(self.config.scheduler.multi_step, 1)
         B = tokens.shape[0]
         bs = self.config.cache.block_size

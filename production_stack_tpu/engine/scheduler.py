@@ -83,6 +83,9 @@ class Scheduler:
         # RUNNING, so a parked sequence holds its slot and blocks but
         # consumes no budget until the engine flips it back
         self.admission_hook = None
+        # the engine's step number (StepClock.step_num), set by the engine
+        # before each schedule(): stamped on a sequence beside admit_time
+        self.step_num = 0
         # set by the engine when the mesh has a seq axis > 1: long fresh
         # prompts prefill whole via ring attention instead of chunking
         self.ring_enabled = False
@@ -321,6 +324,7 @@ class Scheduler:
             # queue_time measures the FIRST wait (the user-visible one)
             if seq.admit_time is None:
                 seq.admit_time = time.monotonic()
+                seq.admit_step = self.step_num
             self.seqs[seq.request_id] = seq
             self._note_admitted(seq)
             if self.admission_hook is not None:
@@ -343,6 +347,7 @@ class Scheduler:
         seq.status = SequenceStatus.RUNNING
         if seq.admit_time is None:
             seq.admit_time = time.monotonic()
+            seq.admit_step = self.step_num
         self.seqs[seq.request_id] = seq
 
     # -- the per-step decision ----------------------------------------------

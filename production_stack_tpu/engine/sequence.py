@@ -68,6 +68,13 @@ class Sequence:
     admit_time: Optional[float] = None  # waiting → scheduled (queue exit)
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
+    # the engine step (StepClock.step_num, the `engine_step` annotation of
+    # a profiler trace) in which each of the three stamps above was taken;
+    # a first token is stamped by the step that fetched it, one after the
+    # step that dispatched it when the fetch is deferred
+    admit_step: Optional[int] = None
+    first_token_step: Optional[int] = None
+    finish_step: Optional[int] = None
     # block ids held at release time (they stay content-addressed in the
     # allocator until evicted — the handle for P→D KV export)
     released_block_ids: list[int] = dataclasses.field(default_factory=list)
@@ -134,6 +141,9 @@ class RequestOutput:
     admit_time: Optional[float] = None
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
+    # {"admitted", "first_token", "last_token"}: the engine steps of the
+    # stamps above (Sequence.*_step), set on finish
+    steps: Optional[dict] = None
     # aligned with new_token_ids when the request asked for logprobs: each
     # entry is (token_logprob, [(token_id, logprob), ...] top-N) — the
     # server slices top-N down to the request's asked-for count

@@ -1822,7 +1822,13 @@ class EngineServer:
         continues, and return it as a tar.gz. This is the TPU equivalent of
         vLLM's torch-profiler start/stop endpoints (SURVEY.md §5.1): the
         trace shows per-kernel device time, HBM traffic, and host gaps —
-        the evidence behind docs/roofline.md."""
+        the evidence behind docs/roofline.md.
+
+        The trace keeps the runtime's own host events and the engine's
+        ``engine_step`` / ``step.<phase>`` annotations (engine/tracing.py).
+        The per-call Python hooks are off unless the body asks for them
+        with ``"python_tracer": true``: they slow a host-bound step loop
+        enough to change what the trace shows."""
         import io
         import shutil
         import tarfile
@@ -1835,6 +1841,8 @@ class EngineServer:
         except Exception:
             body = {}
         duration_ms = min(int(body.get("duration_ms") or 2000), 60_000)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 1 if body.get("python_tracer") else 0
         if getattr(self, "_profiling", False):
             return web.json_response(
                 {"error": {"message": "a profile capture is already running"}},
@@ -1844,7 +1852,7 @@ class EngineServer:
         tmp = tempfile.mkdtemp(prefix="jaxprof-")
         started = False
         try:
-            jax.profiler.start_trace(tmp)
+            jax.profiler.start_trace(tmp, profiler_options=options)
             started = True
             await asyncio.sleep(duration_ms / 1000.0)
             # stop + tar off the event loop: a trace under load is large
@@ -1904,12 +1912,18 @@ class EngineServer:
         if (getattr(self.engine, "host_kv", None) is not None
                 or getattr(self.engine, "remote_kv", None) is not None):
             tier_block = self.engine.tier_stats()
+        # the engine thread's step clock: seconds by step kind and loop
+        # phase (wall and on-CPU), steps by kind, idle seconds — the
+        # numbers behind vllm:engine_host_seconds_total and its siblings
+        step_phases = self.engine.clock.snapshot()
         if perf is None:
             return web.json_response({"enabled": False,
                                       "kv_transfer": kv_block,
                                       "kv_tier": tier_block,
+                                      "step_phases": step_phases,
                                       "tenants": self.engine.tenant_stats()})
         snap = perf.snapshot()
+        snap["step_phases"] = step_phases
         eng = self.engine
         drafted = getattr(eng, "spec_drafted", 0)
         steps = getattr(eng, "spec_steps", 0)
@@ -2312,12 +2326,17 @@ class EngineServer:
         rec = self._inflight.get(root_rid)
         if rec is None:
             return
-        tl = rec["timeline"]
+        # beside each stamp of the timeline, under the same key in `steps`:
+        # the engine step (`engine_step` annotation of a profiler trace)
+        # that took it
+        at_step = out.steps or {}
         for key, val, pick in (("admitted", out.admit_time, min),
                                ("first_token", out.first_token_time, min),
                                ("last_token", out.finish_time, max)):
-            if val is not None:
-                tl[key] = val if key not in tl else pick(tl[key], val)
+            for into, v in ((rec["timeline"], val),
+                            (rec.setdefault("steps", {}), at_step.get(key))):
+                if v is not None:
+                    into[key] = v if key not in into else pick(into[key], v)
         rec["num_prompt_tokens"] += out.num_prompt_tokens
         rec["num_output_tokens"] += out.num_output_tokens
         if self.usage_ledger is not None:
@@ -3022,7 +3041,7 @@ class EngineServer:
         # chars that a stop prefix is never streamed before it is confirmed
         # not to be one.
         holdback = max((len(s) for s in sampling.stop), default=1) - 1
-        shared = {"first_token_t": None}
+        shared = {"first_token_t": None, "first_chunk_written": False}
         # per-choice generated-token counts for continuous_usage_stats
         # (vLLM stream_options extension): every content chunk carries
         # cumulative usage so a mid-stream death leaves the router's
@@ -3107,6 +3126,14 @@ class EngineServer:
                             "total_tokens": n_prompt + n_gen,
                         }
                     await send(chunk)
+                    if not shared["first_chunk_written"]:
+                        # once a request: when the event loop had written
+                        # the first generated chunk to the socket
+                        shared["first_chunk_written"] = True
+                        inflight = self._inflight.get(rid)
+                        if inflight is not None:
+                            self.flight_recorder.stamp(
+                                inflight, "first_chunk_written")
                 if finish_reason is not None:
                     break
             return n_kept

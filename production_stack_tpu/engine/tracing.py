@@ -1,4 +1,8 @@
-"""Engine-side distributed tracing: the same graceful-degradation layering
+"""Engine-side tracing. Two things live here: the OpenTelemetry request span
+(below), and the step clock (`StepClock`, at the end), which says where the
+engine thread's time goes, as counters and as profiler annotations.
+
+Distributed tracing: the same graceful-degradation layering
 as router/experimental/tracing.py, so engine spans JOIN the router's trace
 instead of dying at the proxy boundary. The router injects W3C
 ``traceparent`` into the backend request (request_service._proxy_and_stream);
@@ -14,7 +18,10 @@ image (init degrades gracefully otherwise).
 from __future__ import annotations
 
 import logging
+import time
 from typing import Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 logger = logging.getLogger("engine.tracing")
 
@@ -135,3 +142,111 @@ class request_span:
         if self._cm is not None:
             return self._cm.__exit__(*exc)
         return False
+
+
+# -- the step clock -----------------------------------------------------------
+# Where the engine thread's time goes, always on. One switch per phase
+# (`enter`) reads the wall clock and the thread's CPU clock once each, so
+# the phases of the worker loop sum to its wall time by construction; the
+# same switch opens a `jax.profiler.TraceAnnotation("step.<phase>")`, which
+# costs nothing without a profiler session and puts the phase on the device
+# trace's clock with one. Counters are plain floats read by
+# `LLMEngine.stats()` at scrape time; nothing Prometheus is on the hot path.
+
+# `wait` (blocked on the device) and `idle` (blocked on the intake queue)
+# are exported as families of their own, the others as
+# vllm:engine_host_seconds_total{kind,phase}
+HOST_PHASES = ("intake", "schedule", "build", "snapshot", "commit", "launch",
+               "postprocess", "deliver", "prefetch_wait")
+STEP_KINDS = ("decode", "ragged", "prefill", "other")
+
+
+class StepClock:
+    """Owned by the engine thread (no lock: `stats()` on another thread
+    reads floats). A step's kind is known only after scheduling, so the
+    open step's phases collect in a scratch table and are charged to the
+    kind when the step ends."""
+
+    def __init__(self):
+        self.step_num = 0        # engine_step annotations opened so far
+        self.in_step = False
+        self.kind = "other"      # of the open step; `describe` sets it
+        # kind -> phase -> [wall seconds, on-CPU seconds]
+        self.seconds = {k: {p: [0.0, 0.0] for p in (*HOST_PHASES, "wait")}
+                        for k in STEP_KINDS}
+        self.steps = dict.fromkeys(STEP_KINDS, 0)
+        self.idle_seconds = 0.0
+        self._scratch: dict = {}  # phase -> [wall, cpu] since the last flush
+        self._phase: Optional[str] = None
+        self._t = self._cpu = self._t_begin = 0.0
+        self._ann = self._step_ann = None
+        self._launch: dict = {}
+
+    def enter(self, phase: str, **attrs) -> float:
+        """End the open phase and start ``phase``; returns the stamp, so a
+        caller that needs a duration subtracts two of them."""
+        t = self._close()
+        self._phase = phase
+        self._ann = TraceAnnotation("step." + phase, **attrs)
+        self._ann.__enter__()
+        return t
+
+    def _close(self) -> float:
+        t, cpu = time.monotonic(), time.thread_time()
+        if self._phase is not None:
+            acc = self._scratch.setdefault(self._phase, [0.0, 0.0])
+            acc[0] += t - self._t
+            acc[1] += cpu - self._cpu
+            self._ann.__exit__(None, None, None)
+            self._phase = None
+        self._t, self._cpu = t, cpu
+        return t
+
+    def begin_step(self) -> None:
+        self._t_begin = self._close()
+        self.step_num += 1
+        self.in_step = True
+        self._step_ann = StepTraceAnnotation("engine_step",
+                                             step_num=self.step_num)
+        self._step_ann.__enter__()
+
+    def describe(self, kind: str, rows: int, tokens: int) -> None:
+        """What the open step dispatches: its kind for the counters, and
+        what `step.launch` carries into the trace."""
+        self.kind = kind
+        self._launch = {"kind": kind, "rows": rows, "tokens": tokens}
+
+    def launch(self) -> float:
+        return self.enter("launch", **self._launch)
+
+    def end_step(self) -> float:
+        """Charge what collected since the last flush to the step's kind
+        (outside a step: to "other") and return the step's seconds,
+        begin to end."""
+        t = self._close()
+        by_phase = self.seconds[self.kind]
+        for phase, (wall, cpu) in self._scratch.items():
+            if phase == "idle":
+                self.idle_seconds += wall
+            else:
+                by_phase[phase][0] += wall
+                by_phase[phase][1] += cpu
+        self._scratch.clear()
+        if not self.in_step:
+            return 0.0
+        self.steps[self.kind] += 1
+        self.in_step = False
+        self.kind, self._launch = "other", {}
+        self._step_ann.__exit__(None, None, None)
+        return t - self._t_begin
+
+    def snapshot(self) -> dict:
+        """The `step_phases` block of /debug/perf: seconds by kind and
+        phase (wall and on-CPU), steps by kind, idle seconds."""
+        return {
+            "steps": dict(self.steps),
+            "idle_seconds": self.idle_seconds,
+            "seconds": {k: {p: {"wall": w, "cpu": c}
+                            for p, (w, c) in by_phase.items()}
+                        for k, by_phase in self.seconds.items()},
+        }

@@ -262,6 +262,7 @@ def paged_decode_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables, context_lens, layer_arr, q4, kv_cache)
     return out.reshape(B, H, D)
 
@@ -448,6 +449,7 @@ def paged_prefill_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((P, S * G, KH, D), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_prefill_attention",
     )(
         block_tables,
         layer_arr,
@@ -545,4 +547,5 @@ def kv_cache_write_pallas(
         interpret=interpret,
         input_output_aliases={3: 0},  # kv_hbm input → output buffer
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
+        name="kv_cache_write",
     )(slot_mapping, layer_arr, newkv, kv_cache)

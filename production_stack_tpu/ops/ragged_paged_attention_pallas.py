@@ -283,6 +283,7 @@ def ragged_paged_attention_pallas(
         out_shape=jax.ShapeDtypeStruct((nt, R, KH, D), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="ragged_paged_attention",
     )(
         jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(cu_q_lens, jnp.int32),

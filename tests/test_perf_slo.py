@@ -303,7 +303,8 @@ def test_unexpected_recompile_after_steady(server):
 def test_profile_roundtrip_and_memory_profile(server, monkeypatch):
     import jax
 
-    monkeypatch.setattr(jax.profiler, "start_trace", lambda path: None)
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda path, **options: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     monkeypatch.setattr(jax.profiler, "device_memory_profile",
                         lambda: b"pprof-bytes")
@@ -338,7 +339,7 @@ def test_profile_409_while_capture_running(server):
 def test_profile_start_failure_is_500_and_resets(server, monkeypatch):
     import jax
 
-    def boom(path):
+    def boom(path, **options):
         raise RuntimeError("no backend profiler")
 
     monkeypatch.setattr(jax.profiler, "start_trace", boom)
@@ -355,7 +356,8 @@ def test_profile_start_failure_is_500_and_resets(server, monkeypatch):
 def test_profile_stop_failure_is_500_and_resets(server, monkeypatch):
     import jax
 
-    monkeypatch.setattr(jax.profiler, "start_trace", lambda path: None)
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda path, **options: None)
 
     def boom():
         raise RuntimeError("serialization failed")

@@ -1,0 +1,312 @@
+"""The engine thread's step clock (engine/tracing.py) over a real tiny
+engine on the CPU: phases cover the worker loop, every step has one kind,
+the counters reach /metrics and /debug/perf, the timers it replaced still
+feed PerfAccountant and the step histogram, programs carry names, request
+records name their steps, and none of it changes what is generated."""
+
+import asyncio
+import functools
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from production_stack_tpu.engine import model_runner
+from production_stack_tpu.engine.async_engine import AsyncEngine
+from production_stack_tpu.engine.config import (
+    CacheConfig,
+    EngineConfig,
+    ModelConfig,
+    SchedulerConfig,
+)
+from production_stack_tpu.engine.engine import LLMEngine
+from production_stack_tpu.engine.sampling import SamplingParams
+from production_stack_tpu.engine.server import EngineServer
+from production_stack_tpu.engine.tracing import (
+    HOST_PHASES,
+    STEP_KINDS,
+    StepClock,
+)
+from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
+
+PROMPTS = ["hello world", "the quick brown fox jumps over the lazy dog"]
+# greedy tokens of the parent commit (3c635ce) for PROMPTS on tiny-llama,
+# seed 0, 12 tokens, ignore_eos: the same under the ragged and the
+# bucketed attention path (computed from an unpacked `git archive` of the
+# parent, on the CPU)
+PARENT_TOKENS = [
+    [263, 351, 351, 351, 358, 351, 351, 351, 351, 351, 263, 331],
+    [218, 400, 218, 400, 218, 400, 430, 36, 319, 218, 400, 218],
+]
+GREEDY = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
+FAMILIES = ("vllm:engine_host_seconds_total",
+            "vllm:engine_host_cpu_seconds_total",
+            "vllm:engine_device_wait_seconds_total",
+            "vllm:engine_idle_seconds_total",
+            "vllm:decode_dispatches_total")
+
+
+def make_config(**kw) -> EngineConfig:
+    return EngineConfig(
+        model=ModelConfig.from_pretrained("tiny-llama"),
+        cache=CacheConfig(block_size=4, num_blocks=512),
+        scheduler=SchedulerConfig(
+            max_num_seqs=4, max_num_batched_tokens=64,
+            prefill_buckets=(32, 64),
+        ),
+        mesh=MeshConfig(data=1, tensor=1), **kw)
+
+
+@pytest.fixture(scope="module")
+def server():
+    return EngineServer(make_config())
+
+
+async def _with_client(server, fn):
+    from aiohttp.test_utils import TestClient, TestServer
+
+    async with TestClient(TestServer(server.build_app())) as client:
+        return await fn(client)
+
+
+def _clock_total(clock: StepClock) -> float:
+    return clock.idle_seconds + sum(
+        wall for by_phase in clock.seconds.values()
+        for wall, _ in by_phase.values())
+
+
+def _samples(text: str, name: str) -> dict:
+    """{label text: value} of one sample name in a /metrics exposition."""
+    out = {}
+    for line in text.splitlines():
+        head, _, value = line.rpartition(" ")
+        if head.split("{", 1)[0] == name:
+            out[head] = float(value)
+    return out
+
+
+# -- the clock over a worker loop ---------------------------------------------
+
+def test_phases_sum_to_the_worker_wall_time_and_each_step_has_one_kind():
+    eng = LLMEngine(make_config())
+    clock = eng.clock
+    seen = []
+
+    async def fn():
+        ae = AsyncEngine(eng)
+        ae.step_observer = seen.append
+        t0 = time.monotonic()
+        await ae.start()
+        outs = []
+        for p in PROMPTS:  # one after the other: idle gaps in between
+            toks = []
+            async for out in ae.generate(eng.tokenizer.encode(p), GREEDY):
+                toks.extend(out.new_token_ids)
+            outs.append(toks)
+            await asyncio.sleep(0.12)
+        ae.stop()  # joins the worker: its last iteration has flushed
+        return outs, time.monotonic() - t0, ae.step_count
+
+    outs, wall, step_count = asyncio.run(fn())
+    assert outs == PARENT_TOKENS
+    # the worker's whole life is in some phase: host + wait + idle = wall
+    assert _clock_total(clock) == pytest.approx(wall, rel=0.02)
+    assert clock.idle_seconds > 0.2
+    # every step was charged to exactly one kind, and only steps were
+    assert sum(clock.steps.values()) == clock.step_num == step_count
+    assert clock.steps["decode"] > 0
+    assert clock.steps["ragged"] + clock.steps["prefill"] > 0
+    assert not clock.in_step
+    for kind in STEP_KINDS:
+        if not clock.steps[kind]:
+            # a kind that never ran holds no step phase (idle iterations
+            # flush their intake under "other")
+            assert all(w == 0.0 for p, (w, _) in clock.seconds[kind].items()
+                       if p != "intake"), kind
+    decode = clock.seconds["decode"]
+    for phase in ("schedule", "build", "snapshot", "commit", "launch",
+                  "postprocess", "deliver", "wait"):
+        assert decode[phase][0] > 0.0, phase
+    # on-CPU time is part of wall time (summed: a coarse thread clock may
+    # charge a tick to a phase shorter than the tick)
+    pairs = [v for by_phase in clock.seconds.values()
+             for v in by_phase.values()]
+    assert 0 < sum(c for _, c in pairs) <= sum(w for w, _ in pairs) + 0.05
+    # the step histogram's observer got one positive duration per step,
+    # and together they are the steps' phases (idle and intake excluded)
+    assert len(seen) == step_count and min(seen) > 0
+    in_steps = sum(w for by_phase in clock.seconds.values()
+                   for p, (w, _) in by_phase.items() if p != "intake")
+    assert sum(seen) == pytest.approx(in_steps, rel=0.02)
+
+
+def test_a_step_driven_directly_opens_and_closes_its_own_step():
+    eng = LLMEngine(make_config(attention_impl="bucketed"))
+    outs = eng.generate(PROMPTS, GREEDY)
+    assert list(outs.values()) == PARENT_TOKENS
+    clock = eng.clock
+    assert not clock.in_step and clock.step_num == sum(clock.steps.values())
+    assert clock.steps["prefill"] > 0 and clock.steps["decode"] > 0
+    assert clock.steps["ragged"] == 0
+    assert eng.decode_dispatches == clock.steps["decode"]
+    snap = clock.snapshot()
+    assert set(snap["seconds"]) == set(STEP_KINDS)
+    assert set(snap["seconds"]["decode"]) == {*HOST_PHASES, "wait"}
+
+
+@pytest.mark.parametrize("impl,record,kind", [
+    ("ragged", "record_ragged", "ragged"),
+    ("bucketed", "record_prefill", "prefill")])
+def test_perf_accountant_receives_the_clocks_seconds(monkeypatch, impl,
+                                                     record, kind):
+    eng = LLMEngine(make_config(attention_impl=impl))
+    calls = {"record_decode": [], "record_ragged": [], "record_prefill": []}
+    for name, got in calls.items():
+        real = getattr(eng.perf, name)
+
+        def spy(*a, _real=real, _got=got, **kw):
+            _got.append(kw["seconds"])
+            return _real(*a, **kw)
+        monkeypatch.setattr(eng.perf, name, spy)
+    assert list(eng.generate(PROMPTS, GREEDY).values()) == PARENT_TOKENS
+    # one record per dispatch, as before the clock: same call counts
+    steps = eng.clock.steps
+    assert len(calls["record_decode"]) == eng.decode_dispatches \
+        == steps["decode"] > 0
+    assert len(calls[record]) == steps[kind] > 0
+    assert len(calls["record_ragged"]) == eng.ragged_dispatches
+    assert sum(map(len, calls.values())) == steps["decode"] + steps[kind]
+    assert min(calls["record_decode"] + calls[record]) > 0
+    # what they received is the clock's snapshot + commit + launch (+ wait
+    # where the call fetched): never more than the clock saw
+    by = eng.clock.seconds
+    for k, got in (("decode", calls["record_decode"]), (kind, calls[record])):
+        seen = sum(by[k][p][0]
+                   for p in ("snapshot", "commit", "launch", "wait"))
+        assert 0 < sum(got) <= seen + 1e-6
+
+
+# -- what reaches the server's surfaces ---------------------------------------
+
+def test_families_in_metrics_and_debug_perf_only_grow(server):
+    async def fn(client):
+        first = await (await client.get("/metrics")).text()
+        for fam in FAMILIES[:3]:
+            kinds = {k for k in STEP_KINDS
+                     if any(f'kind="{k}"' in s for s in _samples(first, fam))}
+            assert kinds == set(STEP_KINDS), (fam, kinds)
+        phases = {p for p in HOST_PHASES if any(
+            f'phase="{p}"' in s for s in _samples(first, FAMILIES[0]))}
+        assert phases == set(HOST_PHASES)
+        for fam in FAMILIES[3:]:
+            assert len(_samples(first, fam)) == 1, fam
+        perf0 = await (await client.get("/debug/perf")).json()
+        r = await client.post("/v1/completions", json={
+            "model": "tiny-llama", "prompt": PROMPTS[1], "max_tokens": 12,
+            "temperature": 0, "ignore_eos": True})
+        assert r.status == 200
+        second = await (await client.get("/metrics")).text()
+        perf1 = await (await client.get("/debug/perf")).json()
+        for fam in FAMILIES:
+            a, b = _samples(first, fam), _samples(second, fam)
+            assert set(a) == set(b)
+            assert all(b[k] >= a[k] for k in a), fam
+            assert sum(b.values()) > sum(a.values()), fam
+        assert _samples(second, "vllm:unexpected_recompiles_total") == {
+            'vllm:unexpected_recompiles_total{model_name="tiny-llama"}': 0.0}
+        sp0, sp1 = perf0["step_phases"], perf1["step_phases"]
+        assert sp1["idle_seconds"] >= sp0["idle_seconds"]
+        assert sum(sp1["steps"].values()) > sum(sp0["steps"].values())
+        for kind in STEP_KINDS:
+            for phase in (*HOST_PHASES, "wait"):
+                s0 = sp0["seconds"][kind][phase]
+                s1 = sp1["seconds"][kind][phase]
+                assert s1["wall"] >= s0["wall"] and s1["cpu"] >= s0["cpu"]
+        # /metrics and /debug/perf say the same thing
+        host = sum(s["wall"] for by in sp1["seconds"].values()
+                   for p, s in by.items() if p != "wait")
+        assert host >= sum(_samples(second, FAMILIES[0]).values()) > 0
+
+    asyncio.run(_with_client(server, fn))
+
+
+def test_flight_record_names_its_steps_and_the_first_chunk(server):
+    async def fn(client):
+        for rid, stream in (("trace-stream", True), ("trace-plain", False)):
+            r = await client.post("/v1/completions", json={
+                "model": "tiny-llama", "prompt": PROMPTS[0], "max_tokens": 6,
+                "temperature": 0, "ignore_eos": True, "stream": stream},
+                headers={"x-request-id": rid})
+            assert r.status == 200
+            await r.text()
+        recs = {x["client_request_id"]: x for x in (await (
+            await client.get("/debug/requests")).json())["requests"]}
+        for rid in ("trace-stream", "trace-plain"):
+            steps = recs[rid]["steps"]
+            assert 0 < steps["admitted"] <= steps["first_token"] \
+                <= steps["last_token"] <= server.engine.clock.step_num
+        tl = recs["trace-stream"]["timeline"]
+        assert tl["first_token"] <= tl["first_chunk_written"] <= tl["finished"]
+        assert "first_chunk_written" not in recs["trace-plain"]["timeline"]
+
+    asyncio.run(_with_client(server, fn))
+
+
+@pytest.mark.parametrize("body,level", [({}, 0),
+                                        ({"python_tracer": False}, 0),
+                                        ({"python_tracer": True}, 1)])
+def test_debug_profile_python_tracer_level(server, monkeypatch, body, level):
+    seen = {}
+
+    def start_trace(log_dir, *a, profiler_options=None, **kw):
+        seen["level"] = profiler_options.python_tracer_level
+
+    monkeypatch.setattr(jax.profiler, "start_trace", start_trace)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+
+    async def fn(client):
+        r = await client.post("/debug/profile",
+                              json={"duration_ms": 1, **body})
+        assert r.status == 200
+        assert r.content_type == "application/gzip"
+
+    asyncio.run(_with_client(server, fn))
+    assert seen == {"level": level}
+
+
+# -- names --------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def ring_runner():
+    cfg = make_config()
+    cfg = EngineConfig(model=cfg.model, cache=cfg.cache,
+                       scheduler=cfg.scheduler,
+                       mesh=MeshConfig(data=1, tensor=1, seq=2),
+                       attention_impl="ragged")
+    return model_runner.ModelRunner(cfg, build_mesh(cfg.mesh),
+                                    num_blocks=64)
+
+
+@pytest.mark.parametrize("attr,name", [
+    ("_ragged", "ragged_step"),
+    ("_decode_multi", "decode_multi_step"),
+    ("_prefill", "prefill_step"),
+    ("_decode", "decode_step"),
+    ("_prefill_ring", "prefill_ring_step"),
+])
+def test_jitted_programs_carry_their_names(ring_runner, attr, name):
+    assert getattr(ring_runner, attr).__name__ == name
+
+
+def test_named_partial_names_the_compiled_module():
+    def _toy_step(scale, x):
+        return x * scale
+
+    part = model_runner._named_partial(_toy_step, 2.0)
+    assert isinstance(part, functools.partial)
+    x = jnp.ones((4,), jnp.float32)
+    text = jax.jit(part).lower(x).compile().as_text()
+    assert "HloModule jit_toy_step" in text
+    bare = jax.jit(functools.partial(_toy_step, 2.0)).lower(x).compile()
+    assert "HloModule jit__unknown" in bare.as_text()
