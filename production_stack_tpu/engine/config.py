@@ -474,8 +474,8 @@ class SchedulerConfig:
     # (SURVEY.md §5.7).
     ring_prefill_threshold: int = 0
     # chain decode dispatches through device-resident tokens with the
-    # sample fetch deferred one dispatch. Off, and not measured on the
-    # chip (ROADMAP D2 decides: default or delete).
+    # sample fetch deferred one dispatch. Off: one chip run read TPOT
+    # -9 % and TTFT +11 % (PERF.md section 7; ROADMAP D2 decides).
     chain_decode: bool = False
     # n-gram (prompt-lookup) speculative decoding: propose up to this many
     # draft tokens per step from the sequence's own token history and

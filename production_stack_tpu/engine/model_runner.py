@@ -32,8 +32,10 @@ either impl can be forced (the ragged XLA path is the CPU parity oracle).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import logging
+import math
 from typing import Optional
 
 import jax
@@ -66,6 +68,68 @@ def _named_partial(fn, *bound):
     part = functools.partial(fn, *bound)
     part.__name__ = fn.__name__.lstrip("_")
     return part
+
+
+@dataclasses.dataclass(frozen=True)
+class StepLayout:
+    """Where each always-present host input of a step lies in the ONE
+    packed int32 buffer the runner transfers per dispatch.
+
+    ``fields`` is ((name, shape, dtype name), ...) in buffer order, every
+    dtype 4 bytes wide: floats and the uint32 seeds travel as their bit
+    patterns (``view`` on the host, ``bitcast_convert_type`` in the
+    program), so a round trip is bit-exact. The layout follows from the
+    inputs' shapes alone — every static variant of a program reads the
+    same buffer — and is a static (hashable) argument of the jitted step,
+    which unpacks with static slices. ``ModelRunner`` owns it; the
+    engine and the other runners never see the buffer."""
+
+    fields: tuple
+
+    @classmethod
+    def of(cls, spec, arrays) -> "StepLayout":
+        """Layout of ``arrays`` under ``spec`` ((name, dtype name), ...)."""
+        return cls(tuple((name, tuple(np.shape(a)), dt)
+                         for (name, dt), a in zip(spec, arrays, strict=True)))
+
+    def pack(self, arrays) -> np.ndarray:
+        """A fresh contiguous buffer holding ``arrays``: the copy is also
+        the snapshot of host arrays the caller rewrites in place."""
+        return np.concatenate([
+            np.asarray(a, dt).reshape(-1).view(np.int32)
+            for a, (_, _, dt) in zip(arrays, self.fields, strict=True)])
+
+    def unpack(self, buf) -> dict:
+        """name -> array, traced inside the jitted program: static slices,
+        reshapes and bitcasts (microseconds on the device)."""
+        out, off = {}, 0
+        for name, shape, dt in self.fields:
+            n = math.prod(shape)
+            x = buf[off:off + n].reshape(shape)
+            if dt != "int32":
+                x = jax.lax.bitcast_convert_type(x, jnp.dtype(dt))
+            out[name] = x
+            off += n
+        return out
+
+
+_DECODE_INPUTS = (
+    ("tokens", "int32"), ("positions", "int32"), ("block_tables", "int32"),
+    ("context_lens", "int32"), ("slot_mapping", "int32"),
+    ("temps", "float32"), ("top_ps", "float32"), ("top_ks", "int32"),
+    ("seeds", "uint32"), ("steps", "int32"),
+    # 1 = this dispatch's input tokens are the device-resident next_tok of
+    # the previous one (chained decode), 0 = the ``tokens`` field above
+    ("tokens_on_device", "int32"),
+)
+_RAGGED_INPUTS = (
+    ("tokens", "int32"), ("positions", "int32"), ("block_tables", "int32"),
+    ("context_lens", "int32"), ("cu_q_lens", "int32"),
+    ("slot_mapping", "int32"), ("last_idx", "int32"),
+    ("sample_mask", "float32"), ("temps", "float32"), ("top_ps", "float32"),
+    ("top_ks", "int32"), ("seeds", "uint32"), ("steps", "int32"),
+    ("verify_idx", "int32"),  # only with speculation compiled in
+)
 
 
 def _pallas_ok(cfg: ModelConfig, mesh: Mesh, block_size: int) -> bool:
@@ -174,12 +238,11 @@ class ModelRunner:
 
         self._replicate_results = jax.process_count() > 1
         self._multi_device = mesh.devices.size > 1
+        self._repl = replicated(mesh)
         if self._multi_device:
-            self._repl = replicated(mesh)
             self._mh_gate = {"out_shardings": (None, self._repl)}
             self._mh_gate_all = {"out_shardings": self._repl}
         else:
-            self._repl = None
             self._mh_gate = {}
             self._mh_gate_all = {}
 
@@ -201,9 +264,9 @@ class ModelRunner:
                 max(config.scheduler.multi_step, 1), self._eos_id,
             ),
             donate_argnums=(1,),
-            static_argnames=("block_size", "greedy_only", "use_penalties",
-                             "use_controls", "want_logprobs",
-                             "use_grammar"),
+            static_argnames=("layout", "block_size", "greedy_only",
+                             "use_penalties", "use_controls",
+                             "want_logprobs", "use_grammar"),
             **self._mh_gate,
         )
         if self.attention_impl == "ragged":
@@ -218,7 +281,7 @@ class ModelRunner:
                                self._attend_ragged, self._eos_id,
                                self.spec_width),
                 donate_argnums=(1,),
-                static_argnames=("greedy_only", "use_penalties",
+                static_argnames=("layout", "greedy_only", "use_penalties",
                                  "use_controls", "use_grammar"),
                 **self._mh_gate,
             )
@@ -246,6 +309,13 @@ class ModelRunner:
         # per-slot output-token counts for presence/frequency penalties
         # ((B, V) int32; allocated on first penalised batch)
         self.token_counts = None
+        # decode_multi's program takes device tokens on every dispatch, so
+        # chained and unchained ones share ONE executable; unchained, it
+        # reads the packed tokens and ignores this constant (placed like
+        # the program's own next_tok output)
+        self._no_tokens_dev = jax.device_put(
+            np.zeros((config.scheduler.max_num_seqs, 1), np.int32),
+            self._repl)
         # multi-LoRA bank: target -> (A (L, N, in, R), B (L, N, R, *out));
         # slot 0 stays zeros (base model)
         self.lora_bank: Optional[dict] = None
@@ -379,17 +449,49 @@ class ModelRunner:
             check_vma=False,
         )
 
-    def _commit(self, x):
-        """Host step input → device, committed fully replicated on a
-        multi-device mesh (single chip: plain asarray). An uncommitted
-        host array leaves the placement decision to GSPMD per program;
-        committing up front pins the sharded steady-state signature —
-        stream replicated, KV/weights partitioned — so TP=4/8 dispatches
-        retrace exactly as often as single-chip ones (never, after
-        warmup)."""
-        if self._repl is None:
-            return jnp.asarray(x)
-        return jax.device_put(jnp.asarray(x), self._repl)
+    def _commit(self, buf: np.ndarray):
+        """A step's packed host inputs → device, in ONE transfer. On a
+        multi-device mesh the buffer is committed fully replicated: an
+        uncommitted host array leaves the placement decision to GSPMD per
+        program; committing up front pins the sharded steady-state
+        signature — stream replicated, KV/weights partitioned — so TP=4/8
+        dispatches retrace exactly as often as single-chip ones (never,
+        after warmup). Single chip: a plain put."""
+        if self._multi_device:
+            return jax.device_put(buf, self._repl)
+        return jax.device_put(buf)
+
+    def _optional_inputs(self, presence, frequency, adapter_ids, ctrl,
+                         g_ids, g_states) -> dict:
+        """The rare per-request inputs (penalties, LoRA ids, token
+        controls, grammar state) as arguments of the variants that use
+        them. They stay outside the packed buffer — folding them in would
+        make its layout depend on the variant — and are copied first: the
+        engine rewrites its host arrays in place while a deferred step
+        may still be pending, and a jax.Array can alias numpy memory."""
+        def dev(x, dtype=None):
+            return jnp.asarray(np.array(x, dtype))
+
+        use_lora = adapter_ids is not None and self.lora_bank is not None
+        use_grammar = g_ids is not None and self.grammar_bank is not None
+        if presence is not None:
+            self._ensure_counts()
+        return dict(
+            token_counts=None if presence is None else self.token_counts,
+            presence=None if presence is None else dev(presence),
+            frequency=None if presence is None else dev(frequency),
+            lora_bank=self.lora_bank if use_lora else None,
+            adapter_ids=dev(adapter_ids, np.int32) if use_lora else None,
+            ctrl=None if ctrl is None else tuple(dev(c) for c in ctrl),
+            grammar=(
+                (self.grammar_bank, self.grammar_accept,
+                 dev(g_ids, np.int32), dev(g_states, np.int32))
+                if use_grammar else None
+            ),
+            use_penalties=presence is not None,
+            use_controls=ctrl is not None,
+            use_grammar=use_grammar,
+        )
 
     def _xla_attend(self, q, caches, layer_idx, block_tables, context_lens,
                     q_positions):
@@ -673,71 +775,33 @@ class ModelRunner:
         samples (no host round trip between chained dispatches).
         ``greedy_only`` selects the argmax-only compiled variant;
         presence/frequency arrays activate the penalised variant (counts
-        tracked on device)."""
-        use_penalties = presence is not None
-        if not fetch:
-            # the engine rewrites these host buffers in place each step;
-            # with the fetch deferred the computation may still be pending
-            # when that happens, and jax.Array can ALIAS numpy memory (CPU
-            # zero-copy) — snapshot every mutable input
-            (tokens, positions, block_tables, context_lens, slot_mapping,
-             temps, top_ps, top_ks, seeds, steps) = (
-                np.array(x) for x in (
-                    tokens, positions, block_tables, context_lens,
-                    slot_mapping, temps, top_ps, top_ks, seeds, steps)
-            )
-            presence = None if presence is None else np.array(presence)
-            frequency = None if frequency is None else np.array(frequency)
-            adapter_ids = (None if adapter_ids is None
-                           else np.array(adapter_ids))
-            ctrl = (None if ctrl is None
-                    else tuple(np.array(c) for c in ctrl))
-            g_ids = None if g_ids is None else np.array(g_ids)
-            g_states = None if g_states is None else np.array(g_states)
+        tracked on device).
+
+        The ten always-present inputs reach the device as ONE packed
+        buffer in one transfer (``StepLayout``, ``_commit``); packing
+        copies them, so the engine may rewrite its host arrays as soon as
+        this returns, fetched or not."""
+        arrays = (tokens, positions, block_tables, context_lens,
+                  slot_mapping, temps, top_ps, top_ks, seeds, steps,
+                  np.full(1, tokens_dev is not None, np.int32))
+        layout = StepLayout.of(_DECODE_INPUTS, arrays)
+        buf = layout.pack(arrays)
+        if tokens_dev is None:
+            tokens_dev = self._no_tokens_dev
         self.clock.enter("commit")
-        if use_penalties:
-            self._ensure_counts()
-            counts = self.token_counts
-            pres = jnp.asarray(presence)
-            freq = jnp.asarray(frequency)
-        else:
-            counts = jnp.zeros((tokens.shape[0], 1), jnp.int32)  # placeholder
-            pres = jnp.zeros(tokens.shape[0], jnp.float32)
-            freq = pres
-        use_lora = adapter_ids is not None and self.lora_bank is not None
-        use_grammar = g_ids is not None and self.grammar_bank is not None
-        # tokens_dev is the (B, 1) next-token output of the previous
-        # dispatch's program — already shaped, no eager ops on the hot path
-        tok_in = (tokens_dev if tokens_dev is not None
-                  else jnp.asarray(tokens[:, None]))
         with jax.set_mesh(self.mesh):
-            args = [tok_in, *(jnp.asarray(x) for x in (
-                positions[:, None], block_tables, context_lens, slot_mapping,
-                temps, top_ps, top_ks, seeds, steps))]
-            kwargs = dict(
-                ctrl=(tuple(jnp.asarray(c) for c in ctrl)
-                      if ctrl is not None else None),
-                grammar=(
-                    (self.grammar_bank, self.grammar_accept,
-                     jnp.asarray(g_ids, jnp.int32),
-                     jnp.asarray(g_states, jnp.int32))
-                    if use_grammar else None
-                ),
-            )
-            lora_ids = (jnp.asarray(adapter_ids, jnp.int32)
-                        if use_lora else None)
+            opt = self._optional_inputs(presence, frequency, adapter_ids,
+                                        ctrl, g_ids, g_states)
+            packed = self._commit(buf)
             self.clock.launch()
             (self.kv, new_counts), (sampled, next_tok, *lp) = self._decode_multi(
-                self.params, self.kv, *args, counts, pres, freq,
-                self.lora_bank if use_lora else None, lora_ids, **kwargs,
+                self.params, self.kv, packed, tokens_dev, **opt,
+                layout=layout,
                 block_size=self.config.cache.block_size,
                 greedy_only=greedy_only,
-                use_penalties=use_penalties,
-                use_controls=ctrl is not None,
                 want_logprobs=want_logprobs,
-                use_grammar=use_grammar,
             )
-        if use_penalties:
+        if opt["use_penalties"]:
             self.token_counts = new_counts
         if not fetch:
             return sampled, next_tok  # chain path never carries logprobs
@@ -784,85 +848,44 @@ class ModelRunner:
         (CompileTracker treats any post-warmup fresh signature here as a
         bug signal).
 
+        The always-present inputs (verify_idx included when compiled in)
+        reach the device as ONE packed buffer in one transfer
+        (``StepLayout``, ``_commit``); packing copies them, so the engine
+        may rewrite its host arrays as soon as this returns.
+
         Sharded-signature contract (multi-chip mesh): this one program IS
         the multi-chip serving path. Weights and the paged KV pool are
         partitioned over the ``tensor`` axis (KV pages by KV head —
-        kv_cache.py); the packed token stream, span offsets, verify
-        columns and every other host-built input here are committed
-        fully REPLICATED (``_commit``), and the result leaves come back
-        replicated (``out_shardings`` gate in ``__init__``) so the fetch
-        is a local host copy — no per-step cross-chip sync on the host
-        path, and the fused KV-write + verify columns run inside the
-        same ``shard_map`` as single-chip. Warmup exercises exactly this
-        signature, so steady state must tick zero
+        kv_cache.py); the packed buffer that holds the token stream, span
+        offsets, verify columns and every other always-present host-built
+        input is committed fully REPLICATED (``_commit``), and the result
+        leaves come back replicated (``out_shardings`` gate in
+        ``__init__``) so the fetch is a local host copy — no per-step
+        cross-chip sync on the host path, and the fused KV-write + verify
+        columns run inside the same ``shard_map`` as single-chip. Warmup
+        exercises exactly this signature, so steady state must tick zero
         ``vllm:unexpected_recompiles_total`` at TP=4/8 just as at TP=1
         (regression-tested in tests/test_multichip_ragged.py)."""
-        use_penalties = presence is not None
-        if self.spec_width > 0 and verify_idx is None:
-            verify_idx = np.zeros(
-                (context_lens.shape[0], self.spec_width), np.int32)
-        if not fetch:
-            # the engine rewrites these host buffers in place each step;
-            # snapshot every mutable input (see decode_multi)
-            (tokens, positions, block_tables, context_lens, cu_q_lens,
-             slot_mapping, last_idx, sample_mask, temps, top_ps, top_ks,
-             seeds, steps) = (
-                np.array(x) for x in (
-                    tokens, positions, block_tables, context_lens,
-                    cu_q_lens, slot_mapping, last_idx, sample_mask,
-                    temps, top_ps, top_ks, seeds, steps)
-            )
-            presence = None if presence is None else np.array(presence)
-            frequency = None if frequency is None else np.array(frequency)
-            adapter_ids = (None if adapter_ids is None
-                           else np.array(adapter_ids))
-            ctrl = (None if ctrl is None
-                    else tuple(np.array(c) for c in ctrl))
-            g_ids = None if g_ids is None else np.array(g_ids)
-            g_states = None if g_states is None else np.array(g_states)
-            verify_idx = None if verify_idx is None else np.array(verify_idx)
+        arrays = [tokens, positions, block_tables, context_lens, cu_q_lens,
+                  slot_mapping, last_idx, sample_mask, temps, top_ps, top_ks,
+                  seeds, steps]
+        if self.spec_width > 0:
+            arrays.append(
+                np.zeros((context_lens.shape[0], self.spec_width), np.int32)
+                if verify_idx is None else verify_idx)
+        layout = StepLayout.of(_RAGGED_INPUTS[:len(arrays)], arrays)
+        buf = layout.pack(arrays)
         self.clock.enter("commit")
-        S = context_lens.shape[0]
-        if use_penalties:
-            self._ensure_counts()
-            counts = self.token_counts
-            pres = jnp.asarray(presence)
-            freq = jnp.asarray(frequency)
-        else:
-            counts = jnp.zeros((S, 1), jnp.int32)  # placeholder
-            pres = jnp.zeros(S, jnp.float32)
-            freq = pres
-        use_lora = adapter_ids is not None and self.lora_bank is not None
-        use_grammar = g_ids is not None and self.grammar_bank is not None
         with jax.set_mesh(self.mesh):
-            args = [self._commit(x) for x in (
-                tokens, positions, block_tables, context_lens, cu_q_lens,
-                slot_mapping, last_idx, sample_mask, temps, top_ps, top_ks,
-                seeds, steps)]
-            kwargs = dict(
-                verify_idx=(self._commit(np.asarray(verify_idx, np.int32))
-                            if self.spec_width > 0 else None),
-                lora_bank=self.lora_bank if use_lora else None,
-                adapter_ids=(jnp.asarray(adapter_ids, jnp.int32)
-                             if use_lora else None),
-                ctrl=(tuple(jnp.asarray(c) for c in ctrl)
-                      if ctrl is not None else None),
-                grammar=(
-                    (self.grammar_bank, self.grammar_accept,
-                     jnp.asarray(g_ids, jnp.int32),
-                     jnp.asarray(g_states, jnp.int32))
-                    if use_grammar else None
-                ),
-            )
+            opt = self._optional_inputs(presence, frequency, adapter_ids,
+                                        ctrl, g_ids, g_states)
+            packed = self._commit(buf)
             self.clock.launch()
             (self.kv, new_counts), result = self._ragged(
-                self.params, self.kv, *args, counts, pres, freq, **kwargs,
-                greedy_only=greedy_only,
-                use_penalties=use_penalties,
-                use_controls=ctrl is not None,
-                use_grammar=use_grammar,
+                self.params, self.kv, packed, **opt,
+                layout=layout, greedy_only=greedy_only,
             )
-        if use_penalties:
+        if opt["use_penalties"]:
             self.token_counts = new_counts
         if not fetch:
             return result
@@ -1363,12 +1386,10 @@ def _decode_step(cfg: ModelConfig, attend_impl, params, kv, tokens, positions,
 
 
 def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
-                       params, kv,
-                       tokens, positions, block_tables, context_lens,
-                       slot_mapping, temps, top_ps, top_ks, seeds, steps,
-                       token_counts, presence, frequency,
+                       params, kv, packed, tokens_dev,
+                       token_counts=None, presence=None, frequency=None,
                        lora_bank=None, adapter_ids=None, ctrl=None,
-                       grammar=None, *,
+                       grammar=None, *, layout: StepLayout,
                        block_size: int, greedy_only: bool = False,
                        use_penalties: bool = False,
                        use_controls: bool = False,
@@ -1380,11 +1401,22 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
     positions/context lens/slot mappings advance on device too (the host
     pre-allocated ``num_steps`` tokens of block capacity per sequence).
     Amortises host→device dispatch latency — the dominant decode cost on
-    single-chip serving. Returns (new_kv, sampled (num_steps, B))."""
+    single-chip serving. ``packed`` holds the always-present host inputs
+    (``layout``, ``_DECODE_INPUTS``); ``tokens_dev`` (B, 1) is the previous
+    dispatch's ``next_tok``, read when the packed ``tokens_on_device`` flag
+    is set. ``token_counts``/``presence``/``frequency`` exist only under
+    ``use_penalties``. Returns ((new_kv, counts), (sampled (num_steps, B),
+    next_tok (B, 1)[, logprobs]))."""
     from production_stack_tpu.engine.sampling import sample_tokens
     from production_stack_tpu.models.registry import get_model
 
     model = get_model(cfg)
+    f = layout.unpack(packed)
+    block_tables, context_lens = f["block_tables"], f["context_lens"]
+    temps, top_ps, top_ks, seeds = (
+        f["temps"], f["top_ps"], f["top_ks"], f["seeds"])
+    tokens = jnp.where(f["tokens_on_device"] != 0, tokens_dev[:, 0],
+                       f["tokens"])
     B = tokens.shape[0]
     active = context_lens > 0
     if use_grammar:
@@ -1464,8 +1496,8 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
             (sampled, *lp),
         )
 
-    init = (kv, tokens[:, 0], positions[:, 0], context_lens, slot_mapping,
-            steps, token_counts, g_states0)
+    init = (kv, tokens, f["positions"], context_lens, f["slot_mapping"],
+            f["steps"], token_counts, g_states0)
     (kv, _, _, _, _, _, counts, _), (sampled, *lp) = jax.lax.scan(
         body, init, None, length=num_steps
     )
@@ -1478,20 +1510,18 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
 
 
 def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
-                 tokens, positions, block_tables, context_lens, cu_q_lens,
-                 slot_mapping, last_idx, sample_mask,
-                 temps, top_ps, top_ks, seeds, steps,
-                 token_counts, presence, frequency,
-                 verify_idx=None,
+                 packed,
+                 token_counts=None, presence=None, frequency=None,
                  lora_bank=None, adapter_ids=None, ctrl=None, grammar=None,
-                 *, greedy_only: bool = False,
+                 *, layout: StepLayout, greedy_only: bool = False,
                  use_penalties: bool = False,
                  use_controls: bool = False,
                  use_grammar: bool = False):
     """The unified mixed prefill+decode step: ONE forward over the packed
     token stream, then one sample per slot at its span's last token.
 
-    tokens/positions: (1, T); cu_q_lens (S+1,) span offsets in slot order
+    ``packed`` holds the always-present host inputs (``layout``,
+    ``_RAGGED_INPUTS``), unpacked here: tokens/positions: (1, T); cu_q_lens (S+1,) span offsets in slot order
     (decode rows span 1 token — or 1 + drafts when speculating, prefilling
     slots their chunk, inactive 0); last_idx (S,) stream index of each
     slot's final token; sample_mask (S,) gates the on-device penalty-count
@@ -1521,11 +1551,13 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
     from production_stack_tpu.models.registry import get_model
 
     model = get_model(cfg)
+    f = layout.unpack(packed)
+    tokens, positions, last_idx = f["tokens"], f["positions"], f["last_idx"]
 
     def attend(q, k, v, caches, layer_idx):
         return attend_impl(
-            q, k, v, caches, layer_idx, block_tables, context_lens,
-            positions, slot_mapping, cu_q_lens,
+            q, k, v, caches, layer_idx, f["block_tables"],
+            f["context_lens"], positions, f["slot_mapping"], f["cu_q_lens"],
         )
 
     lora = None
@@ -1558,11 +1590,12 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
     if greedy_only:
         sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     else:
-        sampled = sample_tokens(logits, temps, top_ps, top_ks, seeds, steps)
+        sampled = sample_tokens(logits, f["temps"], f["top_ps"], f["top_ks"],
+                                f["seeds"], f["steps"])
     if use_penalties:
         S = sampled.shape[0]
         token_counts = token_counts.at[jnp.arange(S), sampled].add(
-            sample_mask.astype(token_counts.dtype)
+            f["sample_mask"].astype(token_counts.dtype)
         )
     lp = compute_logprobs(raw_logits, sampled)
     if spec_width > 0:
@@ -1571,6 +1604,6 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
             col = model.logits_from_hidden(cfg, params, h[:, None])[:, 0]
             return jnp.argmax(col, axis=-1).astype(jnp.int32)
 
-        verify = jax.lax.map(one_col, verify_idx.T).T  # (S, spec_width)
+        verify = jax.lax.map(one_col, f["verify_idx"].T).T  # (S, spec_width)
         return (new_kv, token_counts), (sampled, verify, *lp)
     return (new_kv, token_counts), (sampled, *lp)
