@@ -22,9 +22,11 @@ from production_stack_tpu.parallel.mesh import MeshConfig
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str = "tiny-llama"
-    # "llama" | "mixtral" | "gemma" | "gemma2" | "phi3" — Mistral and Qwen
-    # run as "llama" (their deltas are knobs: sliding_window, qkv_bias,
-    # qk_norm); "phi3" differs only in its fused HF weight layout
+    # "llama" | "mixtral" | "olmoe" | "gemma" | "gemma2" | "phi3" — Mistral
+    # and Qwen run as "llama" (their deltas are knobs: sliding_window,
+    # qkv_bias, qk_norm); "phi3" differs only in its fused HF weight
+    # layout, "mixtral" and "olmoe" in their HF tensor names (the MoE
+    # block itself is chosen by num_experts > 0, see is_moe)
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -39,13 +41,21 @@ class ModelConfig:
     max_model_len: int = 4096
     tie_word_embeddings: bool = False
     dtype: str = "bfloat16"
-    # MoE (mixtral)
+    # MoE (mixtral, olmoe): experts of width intermediate_size, no shared
+    # expert. Routing weights are softmax over ALL experts, then top-k;
+    # norm_topk_prob renormalises the k chosen to sum to 1 (Mixtral: the
+    # same as its top-k-then-softmax) — OLMoE uses them as they are
     num_experts: int = 0
     num_experts_per_tok: int = 2
+    norm_topk_prob: bool = True
     # Qwen2-family: biases on the QKV projections
     qkv_bias: bool = False
-    # Qwen3-family: per-head RMSNorm on q and k (over head_dim, pre-rope)
+    # RMSNorm on q and k, pre-rope. "head": one weight of head_dim shared
+    # by every head, statistics per head (Qwen3); "full": over the whole
+    # projected vector before the head split, a weight per (head, dim)
+    # (OLMoE)
     qk_norm: bool = False
+    qk_norm_kind: str = "head"
     # Gemma family knobs (all default to the Llama behaviour)
     act: str = "silu"  # MLP gate activation: "silu" | "gelu_tanh" (GeGLU)
     norm_offset: float = 0.0  # RMSNorm scales by (offset + weight); Gemma: 1
@@ -94,6 +104,10 @@ class ModelConfig:
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
 
+    @property
+    def is_moe(self) -> bool:
+        return self.num_experts > 0
+
     @staticmethod
     def from_hf_config(cfg: dict[str, Any], name: str = "") -> "ModelConfig":
         """Build from a HuggingFace config.json dict (LlamaForCausalLM /
@@ -104,6 +118,15 @@ class ModelConfig:
             return ModelConfig._whisper_from_hf(cfg, name)
         if any("Mixtral" in a for a in archs) or "num_local_experts" in cfg:
             arch = "mixtral"
+        elif (any(a == "OlmoeForCausalLM" for a in archs)
+              or cfg.get("model_type") == "olmoe"):
+            if cfg.get("clip_qkv") is not None:
+                # null in every published OLMoE file; a clamp on q/k/v
+                # that is silently skipped would serve another model
+                raise ValueError(
+                    f"OLMoE with clip_qkv={cfg['clip_qkv']!r} is not "
+                    "supported (only clip_qkv: null)")
+            arch = "olmoe"
         elif any("Phi3" in a for a in archs):
             # only the standard Phi-3 maps onto the fused-Llama layout;
             # Phi-3-small (query_key_value naming, gegelu, blocksparse)
@@ -146,7 +169,16 @@ class ModelConfig:
                 f"unsupported Qwen3 variant {archs}; supported: "
                 "Qwen3ForCausalLM (dense)"
             )
-        qk_norm = any("Qwen3" in a for a in archs)
+        if arch not in ("mixtral", "olmoe") and any(
+                cfg.get(k) for k in ("num_experts", "n_routed_experts",
+                                     "moe_num_experts")):
+            # any other MoE family (Qwen2-MoE, DeepSeek, ...) has shared
+            # experts, other routing or other tensor names: read as dense
+            # it would serve a different model
+            raise ValueError(
+                f"unsupported MoE architecture {archs or cfg.get('model_type')}"
+                "; supported: MixtralForCausalLM, OlmoeForCausalLM")
+        qk_norm = any("Qwen3" in a for a in archs) or arch == "olmoe"
         hidden = cfg["hidden_size"]
         heads = cfg["num_attention_heads"]
         gemma = arch in ("gemma", "gemma2")
@@ -167,6 +199,7 @@ class ModelConfig:
         return ModelConfig(
             qkv_bias=qkv_bias,
             qk_norm=qk_norm,
+            qk_norm_kind="full" if arch == "olmoe" else "head",
             name=name or cfg.get("_name_or_path", "hf-model"),
             architecture=arch,
             vocab_size=cfg["vocab_size"],
@@ -181,8 +214,11 @@ class ModelConfig:
             rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
             max_model_len=max_len,
             tie_word_embeddings=cfg.get("tie_word_embeddings", gemma),
-            num_experts=cfg.get("num_local_experts", 0),
+            num_experts=(cfg["num_experts"] if arch == "olmoe"
+                         else cfg.get("num_local_experts", 0)),
             num_experts_per_tok=cfg.get("num_experts_per_tok", 2),
+            norm_topk_prob=(bool(cfg.get("norm_topk_prob", False))
+                            if arch == "olmoe" else True),
             act="gelu_tanh" if "gelu" in hf_act else "silu",
             norm_offset=1.0 if gemma else 0.0,
             embed_scale=gemma,
@@ -271,6 +307,15 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         name="tiny-mixtral", architecture="mixtral", vocab_size=512, hidden_size=128,
         intermediate_size=256, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=32,
         max_model_len=512, num_experts=4, num_experts_per_tok=2, dtype="float32",
+    ),
+    "tiny-olmoe": ModelConfig(
+        # OLMoE's block at test size: MHA, QK-norm over the whole
+        # projection, 8 experts of 64, top-2, weights not renormalised
+        name="tiny-olmoe", architecture="olmoe", vocab_size=512,
+        hidden_size=128, intermediate_size=64, num_layers=2, num_heads=4,
+        num_kv_heads=4, head_dim=32, max_model_len=512, num_experts=8,
+        num_experts_per_tok=2, norm_topk_prob=False, qk_norm=True,
+        qk_norm_kind="full", dtype="float32",
     ),
     # real shapes (weights random-initialised unless weights_path given)
     "llama-3-8b": ModelConfig(
@@ -384,6 +429,15 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         intermediate_size=14336, num_layers=32, num_heads=32, num_kv_heads=8,
         head_dim=128, rope_theta=1000000.0, max_model_len=32768, num_experts=8,
         num_experts_per_tok=2,
+    ),
+    "olmoe-1b-7b": ModelConfig(
+        # allenai/OLMoE-1B-7B-0125-Instruct geometry: 64 experts of 1024,
+        # 8 per token, MHA, QK-norm over the whole projection
+        name="olmoe-1b-7b", architecture="olmoe", vocab_size=50304,
+        hidden_size=2048, intermediate_size=1024, num_layers=16,
+        num_heads=16, num_kv_heads=16, head_dim=128, rope_theta=10000.0,
+        max_model_len=4096, num_experts=64, num_experts_per_tok=8,
+        norm_topk_prob=False, qk_norm=True, qk_norm_kind="full",
     ),
     "tiny-whisper": ModelConfig(
         # CPU-testable Whisper: 1 s audio window (n_audio_ctx 50 -> 100

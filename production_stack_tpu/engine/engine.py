@@ -1149,7 +1149,8 @@ class LLMEngine:
         pending = self._pending_ragged
         self._pending_ragged = None
         fetched, fetch_s = self._fetch(pending["result"])
-        fetched = tuple(np.asarray(x) for x in fetched)
+        fetched = self.runner.take_moe_hist(
+            tuple(np.asarray(x) for x in fetched))
         if self.perf is not None:
             # the blocking result fetch is dispatch wall time too — billed
             # by the same live-token shares so conservation spans the
@@ -1355,7 +1356,7 @@ class LLMEngine:
             )
             self._attribute_seq_seconds(dispatch_s, entries)
         if can_chain:
-            sampled, next_tok = result
+            sampled, next_tok, moe_hist = result
             # defer: speculative num_computed advance (the scheduler's
             # block growth needs it NOW); tokens append at resolution
             for seq in decodes:
@@ -1366,6 +1367,7 @@ class LLMEngine:
                 "rids": [s.request_id for s in decodes],
                 "sampled": sampled,
                 "next_tok": next_tok,
+                "moe_hist": moe_hist,  # None unless the model is MoE
             }
             if chain:
                 # the previous dispatch's results are fetchable now that
@@ -1395,7 +1397,11 @@ class LLMEngine:
         dispatch."""
         sampled = pending["sampled"]
         if not fetched:
-            sampled = np.asarray(self._fetch(sampled)[0])
+            sampled, moe_hist = self._fetch(
+                (sampled, pending.get("moe_hist")))[0]
+            sampled = np.asarray(sampled)
+            if moe_hist is not None:
+                self.runner.moe.record("decode", moe_hist)
         lp = pending.get("lp")  # (tok_lp (K, B), ids (K, B, N), lps ...)
         token_lists = []
         lp_lists = []
@@ -1654,6 +1660,9 @@ class LLMEngine:
                       * self.config.scheduler.max_num_batched_tokens)
             ),
         }
+        moe = getattr(self.runner, "moe", None)
+        if moe is not None:
+            out.update(moe.snapshot())
         if self.host_kv is not None:
             out["cpu_cache_usage_perc"] = self.host_kv.usage
             out["cpu_prefix_cache_hits_total"] = self.host_kv.hits

@@ -214,6 +214,43 @@ class EngineStatsCollector:
                 "with nothing unfinished",
                 phases["idle_seconds"],
             )
+        # MoE routing (engine/tracing.py MoeCounters): exported by MoE
+        # models only, summed over layers and dispatches
+        if "moe_routed_tokens_total" in s:
+            yield counter(
+                "vllm:moe_routed_tokens",
+                "(token, choice) pairs sent to experts, live rows only",
+                s["moe_routed_tokens_total"],
+            )
+            yield counter(
+                "vllm:moe_padding_rows",
+                "Stream rows masked out of MoE routing (padding of the "
+                "ragged stream, idle decode slots)",
+                s["moe_padding_rows_total"],
+            )
+            yield counter(
+                "vllm:moe_expert_load_max",
+                "Pairs received by the busiest expert of each MoE layer",
+                s["moe_expert_load_max_total"],
+            )
+            yield counter(
+                "vllm:moe_expert_load_mean",
+                "Pairs received per expert of each MoE layer "
+                "(pairs / experts)",
+                s["moe_expert_load_mean_total"],
+            )
+            yield counter(
+                "vllm:moe_decode_experts_touched",
+                "Experts that received a pair, per MoE layer of decode steps",
+                s["moe_decode_experts_touched_total"],
+            )
+            yield counter(
+                "vllm:moe_decode_layer_steps",
+                "MoE layers run by decode steps (layers x fused iterations "
+                "x dispatches): the denominator of "
+                "vllm:moe_decode_experts_touched",
+                s["moe_decode_layer_steps_total"],
+            )
         yield gauge(
             "vllm:ragged_stream_utilization",
             "Cumulative live-token fill of the budget-wide ragged stream "

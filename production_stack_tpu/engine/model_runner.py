@@ -47,7 +47,7 @@ from production_stack_tpu.engine.config import EngineConfig, ModelConfig
 from production_stack_tpu.engine import kv_cache as kvmod
 from production_stack_tpu.engine.quant import maybe_quantize
 from production_stack_tpu.engine.sampling import sample_tokens
-from production_stack_tpu.engine.tracing import StepClock
+from production_stack_tpu.engine.tracing import MoeCounters, StepClock
 from production_stack_tpu.engine.weights import init_or_load
 from production_stack_tpu.models.registry import get_model
 from production_stack_tpu.ops.paged_attention import (
@@ -190,6 +190,12 @@ class ModelRunner:
             )
         self.rules = rules_for_model(self.cfg, mesh)
         self.model = get_model(self.cfg)
+        # routing counters of an MoE model (engine/tracing.py): the step
+        # programs then return a per-layer routing histogram as their last
+        # result leaf, which is fetched with the step's own results
+        self.moe = (MoeCounters(self.cfg.num_experts,
+                                self.cfg.num_experts_per_tok)
+                    if self.cfg.is_moe else None)
         with jax.set_mesh(mesh):
             self.params = maybe_quantize(
                 self.cfg,
@@ -354,6 +360,13 @@ class ModelRunner:
             T = min(sched.max_num_batched_tokens, self.cfg.max_model_len)
             hidden = T * self.cfg.hidden_size * 4
             logits = sched.max_num_seqs * self.cfg.vocab_size * 4
+            if self.cfg.is_moe:
+                # the MoE block's sorted (token, choice) rows: gathered
+                # input, gate, up, their product and the output in the
+                # model dtype, the weighted output in float32
+                E, F = self.cfg.hidden_size, self.cfg.intermediate_size
+                hidden += (T * self.cfg.num_experts_per_tok
+                           * (2 * (2 * E + 3 * F) + 4 * E)) // 8
             if self.use_pallas:
                 return int(8 * hidden + 4 * logits)
             ctx = self.cfg.max_model_len
@@ -803,13 +816,20 @@ class ModelRunner:
             )
         if opt["use_penalties"]:
             self.token_counts = new_counts
+        # an MoE model's routing histogram is the last leaf
+        moe_hist = lp.pop() if self.moe is not None else None
         if not fetch:
-            return sampled, next_tok  # chain path never carries logprobs
+            # chain path never carries logprobs; the caller fetches the
+            # histogram (None: not an MoE model) with the sampled tokens
+            return sampled, next_tok, moe_hist
         self.clock.enter("wait")
+        # (sampled (K, B)[, tok_lp (K, B), ids (K, B, N), lps (K, B, N)])
+        sampled, moe_hist, *lp = jax.device_get((sampled, moe_hist, *lp))
+        if moe_hist is not None:
+            self.moe.record("decode", moe_hist)
         if want_logprobs:
-            # (sampled (K, B), tok_lp (K, B), ids (K, B, N), lps (K, B, N))
-            return tuple(np.asarray(x) for x in jax.device_get((sampled, *lp)))
-        return np.asarray(jax.device_get(sampled))
+            return tuple(np.asarray(x) for x in (sampled, *lp))
+        return np.asarray(sampled)
 
     def ragged_step(self, tokens, positions, block_tables, context_lens,
                     cu_q_lens, slot_mapping, last_idx, sample_mask,
@@ -836,7 +856,9 @@ class ModelRunner:
         (S, spec_width) carries the stream indices of each slot's draft
         positions (clamped/zero for rows with fewer or no drafts) and the
         result tuple gains the greedy argmax at those positions,
-        (S, spec_width), right after ``sampled``. verify_idx rides EVERY
+        (S, spec_width), right after ``sampled``; an MoE model appends its
+        routing histogram (L, X + 1) as the last leaf, which
+        ``take_moe_hist`` takes off a fetched tuple. verify_idx rides EVERY
         dispatch so verify-bearing steps share the one steady-state
         signature with plain ones.
 
@@ -890,7 +912,17 @@ class ModelRunner:
         if not fetch:
             return result
         self.clock.enter("wait")
-        return tuple(np.asarray(x) for x in jax.device_get(result))
+        return self.take_moe_hist(
+            tuple(np.asarray(x) for x in jax.device_get(result)))
+
+    def take_moe_hist(self, fetched: tuple) -> tuple:
+        """A ragged step's results on the host without the routing
+        histogram an MoE model appends (counted here); any other model's
+        results as they are."""
+        if self.moe is None:
+            return fetched
+        self.moe.record("ragged", fetched[-1])
+        return fetched[:-1]
 
     # -- sleep mode hooks ----------------------------------------------------
     def drop_kv(self) -> None:
@@ -1431,9 +1463,12 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
                 slots,
             )
 
-        hidden, kv = model.forward_tokens(
+        # idle slots stay out of an MoE model's routing, whose per-layer
+        # histogram joins the results
+        hidden, kv, *moe_hist = model.forward_tokens(
             cfg, params, tok[:, None], pos[:, None], attend, kv,
             lora=_make_lora(lora_bank, adapter_ids, 1),
+            live=active[:, None], moe_hist=cfg.is_moe,
         )
         logits = model.logits_from_hidden(cfg, params, hidden)[:, 0]
         raw_logits = logits  # logprobs report the raw model distribution
@@ -1466,8 +1501,9 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
         if want_logprobs:
             from production_stack_tpu.engine.sampling import compute_logprobs
 
-            return kv, g_state, (sampled, *compute_logprobs(raw_logits, sampled))
-        return kv, g_state, (sampled,)
+            return kv, g_state, (
+                sampled, *compute_logprobs(raw_logits, sampled), *moe_hist)
+        return kv, g_state, (sampled, *moe_hist)
 
     def body(carry, _):
         kv, tok, pos, ctx, slots, step_ctr, counts, g_state = carry
@@ -1505,7 +1541,8 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
     # would cost extra dispatches on the chained-decode hot path
     next_tok = sampled[-1][:, None]  # (B, 1) input for a chained dispatch
     # sampled: (num_steps, B); lp (when requested): tok_lp (K, B),
-    # top_ids (K, B, N), top_lps (K, B, N)
+    # top_ids (K, B, N), top_lps (K, B, N); an MoE model's routing
+    # histogram (K, L, X + 1) last
     return (kv, counts), (sampled, next_tok, *lp)
 
 
@@ -1543,7 +1580,8 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
     (S, spec_width, V) logits cube is never materialised.
 
     Returns ((new_kv, new_counts),
-    (sampled (S,)[, verify (S, spec_width)], tok_lp, ids, lps))."""
+    (sampled (S,)[, verify (S, spec_width)], tok_lp, ids, lps[, an MoE
+    model's routing histogram (L, X + 1)]))."""
     from production_stack_tpu.engine.sampling import (
         compute_logprobs,
         sample_tokens,
@@ -1566,8 +1604,11 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
         N = next(iter(lora_bank.values()))[0].shape[1]
         onehot = jax.nn.one_hot(adapter_ids, N, dtype=jnp.float32)[None]
         lora = {"onehot": onehot, "bank": lora_bank}
-    hidden, new_kv = model.forward_tokens(
+    # an MoE model keeps the stream's padding (position -1) out of its
+    # routing and returns a per-layer histogram, which joins the results
+    hidden, new_kv, *moe_hist = model.forward_tokens(
         cfg, params, tokens, positions, attend, kv, lora=lora,
+        moe_hist=cfg.is_moe,
     )
     last_hidden = jnp.take(hidden[0], last_idx, axis=0)  # (S, E)
     logits = model.logits_from_hidden(cfg, params, last_hidden[:, None])[:, 0]
@@ -1605,5 +1646,5 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
             return jnp.argmax(col, axis=-1).astype(jnp.int32)
 
         verify = jax.lax.map(one_col, f["verify_idx"].T).T  # (S, spec_width)
-        return (new_kv, token_counts), (sampled, verify, *lp)
-    return (new_kv, token_counts), (sampled, *lp)
+        return (new_kv, token_counts), (sampled, verify, *lp, *moe_hist)
+    return (new_kv, token_counts), (sampled, *lp, *moe_hist)

@@ -69,6 +69,8 @@ def estimate_param_count(model_cfg) -> int:
     qkv = (h * model_cfg.num_heads * model_cfg.head_dim
            + 2 * h * model_cfg.num_kv_heads * model_cfg.head_dim
            + model_cfg.num_heads * model_cfg.head_dim * h)
+    # every expert: this is what is HELD; what a token multiplies with is
+    # PerfAccountant.active_param_count
     mlp = 3 * h * inter * max(getattr(model_cfg, "num_experts", 0) or 1, 1)
     return int(2 * model_cfg.vocab_size * h
                + model_cfg.num_layers * (qkv + mlp))
@@ -173,6 +175,17 @@ class PerfAccountant:
         self.param_bytes = max(int(param_bytes), 1)
         self.hbm_poll_interval = hbm_poll_interval
         cfg = model_cfg
+        # MoE: a token multiplies with its k experts of X only, and a
+        # dispatch reads only the experts some token was routed to. FLOPs
+        # are reckoned on the ACTIVE parameters, bytes on the experts
+        # touched (expected under uniform routing: 1 - (1 - k/X)^tokens)
+        X = getattr(cfg, "num_experts", 0) or 0
+        self._expert_share = X and (
+            cfg.num_layers * X * 3 * cfg.hidden_size * cfg.intermediate_size
+            / self.param_count)
+        self._route_share = X and cfg.num_experts_per_tok / X
+        self.active_param_count = self.param_count * (
+            1.0 - self._expert_share * (1.0 - self._route_share))
         self._attn_per_tok_ctx = (4 * cfg.num_layers * cfg.num_heads
                                   * cfg.head_dim)
         self._kv_bytes_per_tok = (2 * cfg.num_layers * cfg.num_kv_heads
@@ -367,6 +380,14 @@ class PerfAccountant:
             self._drift_out.clear()
 
     # -- dispatch accounting -------------------------------------------------
+    def _weight_bytes(self, tokens: int) -> float:
+        """Weight bytes a dispatch over ``tokens`` live tokens reads."""
+        if not self._expert_share:
+            return self.param_bytes
+        touched = 1.0 - (1.0 - self._route_share) ** max(tokens, 0)
+        return self.param_bytes * (
+            1.0 - self._expert_share * (1.0 - touched))
+
     def record_prefill(self, live_tokens: int, ctx_tokens: int,
                        rows: int, ts: Optional[float] = None, *,
                        seconds: float = 0.0,
@@ -378,9 +399,9 @@ class PerfAccountant:
         ``{"prefill": n, "decode": n, "live": n}`` token shares the
         engine packed — both feed the tenant attribution plane only."""
         ctx_mean = ctx_tokens / max(rows, 1)
-        flops = (2.0 * self.param_count * live_tokens
+        flops = (2.0 * self.active_param_count * live_tokens
                  + self._attn_per_tok_ctx * live_tokens * ctx_mean)
-        hbm = (self.param_bytes
+        hbm = (self._weight_bytes(live_tokens)
                + (live_tokens + ctx_tokens) * self._kv_bytes_per_tok)
         ar = live_tokens * self._ar_bytes_per_tok
         ag = rows * self._ag_bytes_per_row
@@ -400,9 +421,9 @@ class PerfAccountant:
         re-reads the weights every step — the weight-bandwidth-bound
         regime of docs/roofline.md."""
         tokens = live_seqs * steps
-        flops = (2.0 * self.param_count * tokens
+        flops = (2.0 * self.active_param_count * tokens
                  + self._attn_per_tok_ctx * ctx_tokens * steps)
-        hbm = steps * (self.param_bytes
+        hbm = steps * (self._weight_bytes(live_seqs)
                        + (ctx_tokens + live_seqs) * self._kv_bytes_per_tok)
         ar = tokens * self._ar_bytes_per_tok
         ag = tokens * self._ag_bytes_per_row
@@ -458,13 +479,14 @@ class PerfAccountant:
         predicted: List[Tuple[str, float]] = []
         if prefill_tokens > 0 or spec_tokens > 0:
             ctx_mean = prefill_ctx / max(prefill_rows, 1)
-            flops = (2.0 * self.param_count * prefill_tokens
+            flops = (2.0 * self.active_param_count * prefill_tokens
                      + self._attn_per_tok_ctx * prefill_tokens * ctx_mean)
-            hbm = (self.param_bytes
+            hbm = (self._weight_bytes(prefill_tokens + spec_tokens
+                                      + decode_seqs)
                    + (prefill_tokens + prefill_ctx) * self._kv_bytes_per_tok)
             if spec_tokens > 0:
                 spec_ctx_mean = spec_ctx / max(spec_rows, 1)
-                flops += (2.0 * self.param_count * spec_tokens
+                flops += (2.0 * self.active_param_count * spec_tokens
                           + self._attn_per_tok_ctx * spec_tokens
                           * spec_ctx_mean)
                 hbm += ((spec_tokens + spec_ctx) * self._kv_bytes_per_tok)
@@ -475,11 +497,12 @@ class PerfAccountant:
             predicted.append(
                 ("prefill", self._predicted_seconds(flops, hbm, ar + ag)))
         if decode_seqs > 0:
-            flops = (2.0 * self.param_count * decode_seqs
+            flops = (2.0 * self.active_param_count * decode_seqs
                      + self._attn_per_tok_ctx * decode_ctx)
             hbm = (decode_ctx + decode_seqs) * self._kv_bytes_per_tok
             if prefill_tokens <= 0 and spec_tokens <= 0:
-                hbm += self.param_bytes  # decode-only pays the weights
+                # decode-only pays the weights
+                hbm += self._weight_bytes(decode_seqs)
             ar = decode_seqs * self._ar_bytes_per_tok
             ag = decode_seqs * self._ag_bytes_per_row
             self._record(ts, "decode", flops, hbm, decode_seqs,

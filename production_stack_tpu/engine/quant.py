@@ -58,8 +58,8 @@ _EPS = 1e-8
 # NOT a prefill/decode switch: a 512-sequence decode batch has the same
 # intensity as a 512-token prefill and takes the same branch (the
 # measured prefill regression is evidence for W8A16 in exactly that
-# regime). MoE expert matmuls pass their REAL token count via
-# ``tokens_hint`` — capacity padding is not intensity.
+# regime). MoE expert matmuls (``ragged_quant_dot``) always run W8A8:
+# an expert's intensity is the rows routed to it, which no shape says.
 # Override: PSTPU_QUANT_A16_THRESHOLD (values <= 0 disable W8A16).
 def _a16_threshold() -> int:
     import os
@@ -109,8 +109,8 @@ def dequantize_array(w: dict) -> jnp.ndarray:
     return w["q"].astype(jnp.float32) * w["s"]
 
 
-def quant_einsum(eq: str, x: jnp.ndarray, w: Any, out_dtype=None,
-                 tokens_hint: int | None = None) -> jnp.ndarray:
+def quant_einsum(eq: str, x: jnp.ndarray, w: Any,
+                 out_dtype=None) -> jnp.ndarray:
     """``jnp.einsum(eq, x, w)`` accepting a quantized ``w``.
 
     With a plain array this is exactly ``jnp.einsum``. With a quantized
@@ -120,9 +120,6 @@ def quant_einsum(eq: str, x: jnp.ndarray, w: Any, out_dtype=None,
     int8×int8→int32 on the MXU, and the result is rescaled by
     (activation scale × weight scale); at/above it the weights
     fused-dequantize into a model-dtype contraction (W8A16).
-    ``tokens_hint`` overrides the token count inferred from ``x``'s
-    shape — MoE expert matmuls pass the real token count (their
-    capacity-slot shape over-counts by ~2x).
 
     Supported equations: activation first, any leading ``...`` batch dims,
     every non-contracted explicit activation letter appearing as a prefix of
@@ -142,13 +139,10 @@ def quant_einsum(eq: str, x: jnp.ndarray, w: Any, out_dtype=None,
 
     # intensity-adaptive: compute-bound (many-token) contractions skip
     # the activation quantize and run W8A16 — see _a16_threshold
-    if tokens_hint is not None:
-        tokens = tokens_hint
-    else:
-        contracted_sizes = 1
-        for i in cax:
-            contracted_sizes *= x.shape[i]
-        tokens = x.size // max(contracted_sizes, 1)
+    contracted_sizes = 1
+    for i in cax:
+        contracted_sizes *= x.shape[i]
+    tokens = x.size // max(contracted_sizes, 1)
     thresh = _a16_threshold()
     if thresh and tokens >= thresh:
         # multiply q*s in f32, round ONCE into the model dtype — the
@@ -186,6 +180,28 @@ def quant_einsum(eq: str, x: jnp.ndarray, w: Any, out_dtype=None,
     w_s = jnp.transpose(w["s"], order).reshape(sizes)
     out = acc.astype(jnp.float32) * sx_b * w_s
     return out.astype(out_dtype if out_dtype is not None else x.dtype)
+
+
+def ragged_quant_dot(x: jnp.ndarray, w: Any, group_sizes: jnp.ndarray,
+                     group_of_row: jnp.ndarray) -> jnp.ndarray:
+    """Grouped matmul ``jax.lax.ragged_dot(x, w, group_sizes)`` accepting
+    a quantized ``w``: x (M, K) rows sorted by group, w (G, K, N), row i
+    multiplied by the matrix of its group; rows past sum(group_sizes) are
+    left undefined. With a quantized weight it runs W8A8 (rows quantized
+    per row, int8 x int8 -> int32), rescaled by each row's own scale and
+    its group's weight scale (G, 1, N), looked up through
+    ``group_of_row`` (M,), which may point one past the last group for
+    the undefined rows."""
+    if not is_quantized(w):
+        return jax.lax.ragged_dot(x, w, group_sizes)
+    xf = x.astype(jnp.float32)
+    sx = jnp.maximum(jnp.max(jnp.abs(xf), axis=-1, keepdims=True) / 127.0,
+                     _EPS)
+    xq = jnp.clip(jnp.round(xf / sx), -127, 127).astype(jnp.int8)
+    acc = jax.lax.ragged_dot(xq, w["q"], group_sizes,
+                             preferred_element_type=jnp.int32)
+    sw = w["s"][:, 0, :][jnp.clip(group_of_row, 0, w["s"].shape[0] - 1)]
+    return (acc.astype(jnp.float32) * sx * sw).astype(x.dtype)
 
 
 def embed_lookup(embed: Any, tokens: jnp.ndarray, dtype) -> jnp.ndarray:
@@ -250,9 +266,8 @@ def quantize_params(cfg, params: dict) -> dict:
     quantizes as an elementwise+reduce op, so shardings propagate and a 70B
     never gathers to one host.
     """
-    moe = cfg.architecture == "mixtral" and cfg.num_experts > 0
     contract = dict(_LAYER_CONTRACT)
-    if moe:
+    if cfg.is_moe:
         contract.update(_MOE_CONTRACT)
     layers = dict(params["layers"])
     for name, axes in contract.items():

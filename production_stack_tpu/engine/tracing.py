@@ -21,6 +21,7 @@ import logging
 import time
 from typing import Optional
 
+import numpy as np
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 logger = logging.getLogger("engine.tracing")
@@ -249,4 +250,48 @@ class StepClock:
             "seconds": {k: {p: {"wall": w, "cpu": c}
                             for p, (w, c) in by_phase.items()}
                         for k, by_phase in self.seconds.items()},
+        }
+
+
+# -- MoE routing counters -----------------------------------------------------
+
+class MoeCounters:
+    """What the MoE block routed, always on, as plain numbers read at
+    scrape time. The step programs of an MoE model return one small
+    histogram per layer (``models/llama.py`` ``_moe_mlp``: pairs received
+    by each expert, then the pairs of the null group that padding rows
+    are sent to); it comes to the host in the fetch of the step's own
+    results, and ``record`` folds it in: no transfer or device program
+    of its own. Everything is summed over layers and dispatches."""
+
+    def __init__(self, num_experts: int, top_k: int):
+        self.num_experts, self.top_k = num_experts, top_k
+        self.routed_tokens = 0        # (token, choice) pairs sent to experts
+        self.padding_rows = 0         # stream rows kept out of the routing
+        self.expert_load_max = 0      # pairs of the busiest expert
+        self.decode_experts_touched = 0  # experts with a pair, decode steps
+        self.decode_layer_steps = 0   # layers x fused iterations, decode steps
+
+    def record(self, kind: str, hist) -> None:
+        """``hist``: (..., X + 1) integers on the host, one row per layer
+        (and per fused decode iteration) of one dispatch of ``kind``."""
+        h = np.asarray(hist, np.int64).reshape(-1, self.num_experts + 1)
+        loads = h[:, :-1]
+        self.routed_tokens += int(loads.sum())
+        self.padding_rows += int(h[:, -1].sum()) // self.top_k
+        self.expert_load_max += int(loads.max(axis=1).sum())
+        if kind == "decode":
+            self.decode_experts_touched += int((loads > 0).sum())
+            self.decode_layer_steps += len(loads)
+
+    def snapshot(self) -> dict:
+        return {
+            "moe_routed_tokens_total": self.routed_tokens,
+            "moe_padding_rows_total": self.padding_rows,
+            "moe_expert_load_max_total": self.expert_load_max,
+            # pairs per expert: what an even routing would give each
+            "moe_expert_load_mean_total": (self.routed_tokens
+                                           / self.num_experts),
+            "moe_decode_experts_touched_total": self.decode_experts_touched,
+            "moe_decode_layer_steps_total": self.decode_layer_steps,
         }

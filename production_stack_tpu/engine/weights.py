@@ -132,9 +132,12 @@ def _hf_key_map(cfg: ModelConfig, i: int) -> dict:
             ("w_gate", "fused_gate"), ("w_up", "fused_up"),
         ]
         m[f"model.layers.{i}.mlp.down_proj.weight"] = ("w_down", "t")
-    if cfg.qk_norm:  # Qwen3
-        m[f"model.layers.{i}.self_attn.q_norm.weight"] = ("q_norm", "copy")
-        m[f"model.layers.{i}.self_attn.k_norm.weight"] = ("k_norm", "copy")
+    if cfg.qk_norm:  # Qwen3: (D,); OLMoE: (H*D,) and (KH*D,), kept by head
+        full = cfg.qk_norm_kind == "full"
+        m[f"model.layers.{i}.self_attn.q_norm.weight"] = (
+            "q_norm", "bias_q" if full else "copy")
+        m[f"model.layers.{i}.self_attn.k_norm.weight"] = (
+            "k_norm", "bias_kv" if full else "copy")
     if cfg.post_norms:
         # Gemma-2 block: HF "post_attention_layernorm" is the norm on the
         # ATTENTION OUTPUT (our post_attn_norm); the pre-MLP norm is
@@ -150,12 +153,18 @@ def _hf_key_map(cfg: ModelConfig, i: int) -> dict:
         m[f"model.layers.{i}.self_attn.q_proj.bias"] = ("bq", "bias_q")
         m[f"model.layers.{i}.self_attn.k_proj.bias"] = ("bk", "bias_kv")
         m[f"model.layers.{i}.self_attn.v_proj.bias"] = ("bv", "bias_kv")
-    if cfg.architecture == "mixtral" and cfg.num_experts > 0:
-        m[f"model.layers.{i}.block_sparse_moe.gate.weight"] = ("router", "t")
+    if cfg.is_moe:
+        # Mixtral: block_sparse_moe.{gate, experts.N.{w1,w3,w2}};
+        # OLMoE: mlp.{gate, experts.N.{gate,up,down}_proj}
+        moe, names = {
+            "mixtral": ("block_sparse_moe", ("w1", "w3", "w2")),
+            "olmoe": ("mlp", ("gate_proj", "up_proj", "down_proj")),
+        }[cfg.architecture]
+        m[f"model.layers.{i}.{moe}.gate.weight"] = ("router", "t")
         for x in range(cfg.num_experts):
-            m[f"model.layers.{i}.block_sparse_moe.experts.{x}.w1.weight"] = (f"w_gate.{x}", "t")
-            m[f"model.layers.{i}.block_sparse_moe.experts.{x}.w3.weight"] = (f"w_up.{x}", "t")
-            m[f"model.layers.{i}.block_sparse_moe.experts.{x}.w2.weight"] = (f"w_down.{x}", "t")
+            for ours, theirs in zip(("w_gate", "w_up", "w_down"), names):
+                m[f"model.layers.{i}.{moe}.experts.{x}.{theirs}.weight"] = (
+                    f"{ours}.{x}", "t")
     elif cfg.architecture != "phi3":  # phi3's MLP keys are set above
         m[f"model.layers.{i}.mlp.gate_proj.weight"] = ("w_gate", "t")
         m[f"model.layers.{i}.mlp.up_proj.weight"] = ("w_up", "t")

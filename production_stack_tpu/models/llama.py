@@ -33,6 +33,7 @@ from production_stack_tpu.engine.quant import (
     head_from_embed,
     is_quantized,
     quant_einsum,
+    ragged_quant_dot,
 )
 from production_stack_tpu.ops.attention import dense_causal_attention
 from production_stack_tpu.ops.norms import rms_norm
@@ -66,7 +67,16 @@ def param_specs(cfg: ModelConfig) -> dict:
                 "bv": (L.LAYERS, L.KV_HEADS, L.HEAD_DIM),
             }
         )
-    if cfg.qk_norm:  # Qwen3 family: per-head q/k RMSNorm over head_dim
+    if cfg.qk_norm and cfg.qk_norm_kind == "full":
+        # OLMoE: RMSNorm over the whole projected q / k vector; the weight
+        # (H*D,) is kept as (H, D) so that it shards with the heads
+        layer.update(
+            {
+                "q_norm": (L.LAYERS, L.HEADS, L.HEAD_DIM),
+                "k_norm": (L.LAYERS, L.KV_HEADS, L.HEAD_DIM),
+            }
+        )
+    elif cfg.qk_norm:  # Qwen3 family: per-head q/k RMSNorm over head_dim
         layer.update(
             {
                 "q_norm": (L.LAYERS, L.HEAD_DIM),
@@ -80,7 +90,7 @@ def param_specs(cfg: ModelConfig) -> dict:
                 "post_mlp_norm": (L.LAYERS, L.EMBED),
             }
         )
-    if cfg.architecture == "mixtral" and cfg.num_experts > 0:
+    if cfg.is_moe:
         layer.update(
             {
                 "router": (L.LAYERS, L.EMBED, L.EXPERTS),
@@ -145,10 +155,13 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             }
         )
     if cfg.qk_norm:
+        full = cfg.qk_norm_kind == "full"
         layers.update(
             {
-                "q_norm": jnp.full((Ln, D), norm_one, dt),
-                "k_norm": jnp.full((Ln, D), norm_one, dt),
+                "q_norm": jnp.full((Ln, H, D) if full else (Ln, D),
+                                   norm_one, dt),
+                "k_norm": jnp.full((Ln, KH, D) if full else (Ln, D),
+                                   norm_one, dt),
             }
         )
     if cfg.post_norms:
@@ -159,7 +172,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
                 "post_mlp_norm": jnp.full((Ln, E), 1.0 - cfg.norm_offset, dt),
             }
         )
-    if cfg.architecture == "mixtral" and cfg.num_experts > 0:
+    if cfg.is_moe:
         X = cfg.num_experts
         layers.update(
             {
@@ -193,8 +206,6 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
 
 def _mlp(cfg: ModelConfig, lp: dict, x: jnp.ndarray, lb=None,
          onehot=None) -> jnp.ndarray:
-    if cfg.architecture == "mixtral" and cfg.num_experts > 0:
-        return _moe_mlp(cfg, lp, x)  # LoRA on MoE experts: not supported yet
     gate = quant_einsum("...te,ef->...tf", x, lp["w_gate"])
     up = quant_einsum("...te,ef->...tf", x, lp["w_up"])
     if lb is not None:
@@ -202,69 +213,107 @@ def _mlp(cfg: ModelConfig, lp: dict, x: jnp.ndarray, lb=None,
             gate = gate + _lora_delta(x, onehot, *lb["w_gate"])
         if "w_up" in lb:
             up = up + _lora_delta(x, onehot, *lb["w_up"])
-    # Gemma is GeGLU (tanh-approx gelu on the gate); Llama/Qwen are SwiGLU
-    act = (jax.nn.silu if cfg.act == "silu"
-           else functools.partial(jax.nn.gelu, approximate=True))
-    hidden2 = act(gate) * up
+    hidden2 = _act(cfg)(gate) * up
     out = quant_einsum("...tf,fe->...te", hidden2, lp["w_down"])
     if lb is not None and "w_down" in lb:
         out = out + _lora_delta(hidden2, onehot, *lb["w_down"])
     return out
 
 
-def _moe_mlp(cfg: ModelConfig, lp: dict, x: jnp.ndarray) -> jnp.ndarray:
-    """Mixtral sparse MoE block — capacity-based top-k dispatch.
+def _act(cfg: ModelConfig):
+    # Gemma is GeGLU (tanh-approx gelu on the gate); Llama/Qwen are SwiGLU
+    return (jax.nn.silu if cfg.act == "silu"
+            else functools.partial(jax.nn.gelu, approximate=True))
 
-    Tokens are routed to their top-k experts through dispatch/combine
-    one-hots (Mesh-TensorFlow/GSPMD style): expert FFNs see a dense
-    (experts, capacity, E) batch, so with the ``experts`` axis sharded over
-    the expert mesh axis XLA partitions per-expert compute and inserts the
-    all_to_all-equivalent collectives itself — no hand-written dispatch.
-    Static shapes throughout; tokens beyond an expert's capacity are dropped
-    (capacity_factor 2.0 makes that vanishingly rare at Mixtral's k/X).
-    """
-    orig_shape = x.shape
-    E = orig_shape[-1]
+
+# the MoE block's expert matrices, (L, X, in, out) in the stacked layers
+_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def _moe_mlp(cfg: ModelConfig, router: jnp.ndarray, experts: dict,
+             layer_idx, x: jnp.ndarray, live: Optional[jnp.ndarray] = None
+             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Sparse MoE block (Mixtral, OLMoE), dropless: route, sort the
+    (token, choice) pairs by expert, one grouped matmul per projection
+    over the sorted rows, unsort and combine.
+
+    Every pair is computed whatever the routing looks like: there is no
+    capacity, so the block equals "every expert on every token, weighted
+    by the routing weight" exactly. ``jax.lax.ragged_dot`` is the grouped
+    matmul; on TPU it compiles to XLA's own ``ragged-dot`` Mosaic kernel
+    (the instruction a device trace shows), elsewhere to a masked loop.
+
+    ``router`` (E, X) is this layer's; ``experts`` holds the expert
+    matrices of ALL the stack's layers, (L, X, in, out) each, and
+    ``layer_idx`` says which layer's to use. The grouped matmul runs over
+    the L * X groups of the whole stack with every other layer's group
+    empty: it reads the experts where they lie. Handing it one layer's
+    slice instead makes XLA copy that slice out first, 3 x 268 MB a layer
+    at OLMoE's widths, as long again as the matmuls themselves (measured,
+    PERF.md section 6, PR 26).
+
+    Routing as published: router logits in float32, softmax over ALL
+    experts, top-k of the probabilities; ``cfg.norm_topk_prob``
+    renormalises the k chosen (Mixtral), OLMoE uses them as they are.
+
+    ``live`` (bool, x's leading shape) marks the rows that are tokens.
+    The others (the tail of a padded ragged stream, idle decode slots)
+    go to a null group behind the last expert: they are sorted past the
+    rows the grouped matmul covers, add nothing to any expert's load and
+    come back as zeros.
+
+    Returns (out like x, histogram (X + 1,) int32: pairs received by each
+    expert, then the pairs of the null group)."""
+    E = x.shape[-1]
     xt = x.reshape(-1, E)  # (T, E) flattened tokens
     T = xt.shape[0]
-    X = cfg.num_experts
-    k = cfg.num_experts_per_tok
+    X, k = cfg.num_experts, cfg.num_experts_per_tok
 
-    logits = jnp.einsum("te,ex->tx", xt, lp["router"]).astype(jnp.float32)
-    top_vals, top_idx = lax.top_k(logits, k)  # (T, k)
-    weights = jax.nn.softmax(top_vals, axis=-1)  # normalised over chosen k
+    logits = jnp.einsum("te,ex->tx", xt, router,
+                        preferred_element_type=jnp.float32)
+    weights, top_idx = lax.top_k(jax.nn.softmax(logits, axis=-1), k)  # (T, k)
+    if cfg.norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
 
-    capacity = max(int(2.0 * T * k / X), k)
-    # position of each (token, choice) within its expert's capacity buffer
-    choice_onehot = jax.nn.one_hot(top_idx, X, dtype=jnp.int32)  # (T, k, X)
-    flat = choice_onehot.reshape(T * k, X)
-    pos_in_expert = jnp.cumsum(flat, axis=0) - flat  # (T*k, X)
-    pos = jnp.sum(pos_in_expert * flat, axis=-1).reshape(T, k)  # (T, k)
-    keep = pos < capacity
+    expert = top_idx.reshape(T * k).astype(jnp.int32)
+    if live is not None:
+        expert = jnp.where(jnp.repeat(live.reshape(T), k), expert, X)
+    hist = jnp.sum(expert[:, None] == jnp.arange(X + 1, dtype=jnp.int32),
+                   axis=0, dtype=jnp.int32)
+    order = jnp.argsort(expert, stable=True)  # pair indices, by expert
+    rows = xt[order // k]  # (T*k, E) each pair's token, grouped by expert
+    sorted_expert = expert[order]
+    # groups of the whole stack: this layer's X sizes at layer_idx * X
+    first = jnp.asarray(layer_idx, jnp.int32) * X
+    num_layers = jax.tree.leaves(experts["w_gate"])[0].shape[0]
+    sizes = lax.dynamic_update_slice(
+        jnp.zeros(num_layers * X, jnp.int32), hist[:X], (first,))
+    group = first + sorted_expert
 
-    # dispatch (T, X, C) one-hot and combine (T, X, C) weighted
-    pos_oh = jax.nn.one_hot(jnp.where(keep, pos, capacity), capacity,
-                            dtype=xt.dtype)  # (T, k, C)
-    disp = jnp.einsum("tkx,tkc->txc", choice_onehot.astype(xt.dtype), pos_oh)
-    comb = jnp.einsum(
-        "tkx,tkc->txc", choice_onehot.astype(jnp.float32) * weights[..., None],
-        pos_oh.astype(jnp.float32),
-    ).astype(xt.dtype)
+    def grouped(y, name):
+        w = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), experts[name])
+        return ragged_quant_dot(y, w, sizes, group)
 
-    expert_in = jnp.einsum("txc,te->xce", disp, xt)  # (X, C, E)
-    # expert matmuls see (X, C, E) capacity slots, ~2x the real token
-    # count — pass the true T so the intensity-adaptive int8 kernel
-    # (quant.py _a16_threshold) doesn't misread padding as intensity
-    gate = quant_einsum("xce,xef->xcf", expert_in, lp["w_gate"],
-                        tokens_hint=T)
-    up = quant_einsum("xce,xef->xcf", expert_in, lp["w_up"],
-                      tokens_hint=T)
-    expert_out = quant_einsum(
-        "xcf,xfe->xce", jax.nn.silu(gate) * up, lp["w_down"],
-        tokens_hint=T,
-    )
-    out = jnp.einsum("txc,xce->te", comb, expert_out)
-    return out.reshape(orig_shape)
+    out = grouped(_act(cfg)(grouped(rows, "w_gate")) * grouped(rows, "w_up"),
+                  "w_down")
+    # rows past the last group are not written by the grouped matmul
+    out = jnp.where((sorted_expert < X)[:, None],
+                    out.astype(jnp.float32)
+                    * weights.reshape(T * k)[order][:, None], 0.0)
+    inverse = jnp.zeros(T * k, jnp.int32).at[order].set(
+        jnp.arange(T * k, dtype=jnp.int32))
+    out = jnp.sum(out[inverse].reshape(T, k, E), axis=1)
+    return out.astype(x.dtype).reshape(x.shape), hist
+
+
+def _rms_norm_heads(x: jnp.ndarray, weight: jnp.ndarray,
+                    eps: float) -> jnp.ndarray:
+    """RMSNorm of (..., H, D) over heads and head_dim together, i.e. over
+    the projected vector before its head split; ``weight`` is (H, D)."""
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(xf * xf, axis=(-2, -1), keepdims=True)
+    return (xf * lax.rsqrt(var + eps)
+            * weight.astype(jnp.float32)).astype(x.dtype)
 
 
 def _lora_delta(x: jnp.ndarray, onehot: jnp.ndarray, A: jnp.ndarray,
@@ -292,10 +341,13 @@ def forward_tokens(
     attend: AttendFn,
     kv_caches: Any = None,
     lora: Any = None,
+    live: Optional[jnp.ndarray] = None,
+    moe_hist: bool = False,
 ) -> Tuple[jnp.ndarray, Any]:
     """Embed tokens then run the decoder stack (see forward_hidden)."""
     x = embed_tokens(cfg, params, tokens)
-    return forward_hidden(cfg, params, x, positions, attend, kv_caches, lora)
+    return forward_hidden(cfg, params, x, positions, attend, kv_caches, lora,
+                          live, moe_hist)
 
 
 def embed_tokens(cfg: ModelConfig, params: dict, tokens: jnp.ndarray) -> jnp.ndarray:
@@ -315,6 +367,8 @@ def forward_hidden(
     attend: AttendFn,
     kv_caches: Any = None,
     lora: Any = None,
+    live: Optional[jnp.ndarray] = None,
+    moe_hist: bool = False,
 ) -> Tuple[jnp.ndarray, Any]:
     """Run the decoder stack from pre-embedded activations.
 
@@ -330,8 +384,20 @@ def forward_hidden(
     copied (scan ys would allocate a fresh stacked output every step —
     measured as 2× cache HLO-temp on v5e). ``attend`` receives the cache
     plus the LOCAL layer index and returns the updated cache.
-    Returns (hidden (..., T, E), new_kv_caches).
+    ``live`` (bool, like positions) marks the rows that are tokens; by
+    default those with a position >= 0 (the ragged stream pads its tail
+    with -1). Only the MoE block reads it: other rows are kept out of the
+    routing. Returns (hidden (..., T, E), new_kv_caches), and with
+    ``moe_hist`` a third value: the MoE block's per-layer routing
+    histogram (L, X + 1), see _moe_mlp.
     """
+    layers, experts = params["layers"], None
+    if cfg.is_moe:
+        if live is None:
+            live = positions >= 0
+        # the expert matrices stay whole, outside the scan (see _moe_mlp)
+        experts = {k: layers[k] for k in _EXPERT_WEIGHTS}
+        layers = {k: v for k, v in layers.items() if k not in experts}
     onehot = None if lora is None else lora["onehot"].astype(cfg.jax_dtype)
 
     def layer_fn(carry, scanned):
@@ -353,7 +419,11 @@ def forward_hidden(
             q = q + lp["bq"]
             k = k + lp["bk"]
             v = v + lp["bv"]
-        if cfg.qk_norm:  # Qwen3: per-head RMSNorm over head_dim, pre-rope
+        if cfg.qk_norm and cfg.qk_norm_kind == "full":
+            # OLMoE: RMSNorm over the whole projection (H*D), pre-rope
+            q = _rms_norm_heads(q, lp["q_norm"], cfg.rms_norm_eps)
+            k = _rms_norm_heads(k, lp["k_norm"], cfg.rms_norm_eps)
+        elif cfg.qk_norm:  # Qwen3: per-head RMSNorm over head_dim, pre-rope
             q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps, cfg.norm_offset)
             k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps, cfg.norm_offset)
         if cfg.query_scale:
@@ -375,17 +445,25 @@ def forward_hidden(
         h = h + o
         normed2 = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps,
                            cfg.norm_offset)
-        mlp_out = _mlp(cfg, lp, normed2, lb=lb, onehot=onehot)
+        hist = None
+        if cfg.is_moe:  # LoRA on MoE experts: not supported yet
+            with jax.named_scope("moe"):
+                mlp_out, hist = _moe_mlp(cfg, lp["router"], experts,
+                                         layer_idx, normed2, live)
+        else:
+            mlp_out = _mlp(cfg, lp, normed2, lb=lb, onehot=onehot)
         if cfg.post_norms:
             mlp_out = rms_norm(mlp_out, lp["post_mlp_norm"],
                                cfg.rms_norm_eps, cfg.norm_offset)
         h = h + mlp_out
-        return (h, layer_idx + 1, caches), None
+        return (h, layer_idx + 1, caches), hist
 
     bank = None if lora is None else lora["bank"]
-    (x, _, new_caches), _ = lax.scan(
-        layer_fn, (x, jnp.int32(0), kv_caches), (params["layers"], bank)
+    (x, _, new_caches), hists = lax.scan(
+        layer_fn, (x, jnp.int32(0), kv_caches), (layers, bank)
     )
+    if moe_hist:
+        return x, new_caches, hists
     return x, new_caches
 
 

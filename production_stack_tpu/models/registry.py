@@ -3,8 +3,8 @@
 Every module exposes ``param_specs(cfg)``, ``init_params(cfg, key)``,
 ``forward_tokens(cfg, params, tokens, positions, attend, kv_caches)``,
 ``logits_from_hidden(cfg, params, hidden)`` and ``forward_dense(...)``.
-Mixtral reuses the Llama stack (its attention/MLP wiring is selected by
-``cfg.architecture`` inside the shared layer code).
+Mixtral and OLMoE reuse the Llama stack (the MoE block replaces the MLP
+wherever ``cfg.num_experts`` > 0).
 """
 
 from __future__ import annotations
@@ -16,7 +16,10 @@ from production_stack_tpu.models import llama, whisper
 
 _REGISTRY: dict[str, ModuleType] = {
     "llama": llama,
-    "mixtral": llama,  # shared stack; MoE block chosen via cfg.architecture
+    # shared stack; the MoE block is chosen by cfg.is_moe, the
+    # architecture names the HF tensor layout (engine/weights.py)
+    "mixtral": llama,
+    "olmoe": llama,
     # Gemma runs the shared stack too: GeGLU / (1+w) norms / embed scale /
     # softcaps / post-norms are ModelConfig knobs inside the layer code
     "gemma": llama,

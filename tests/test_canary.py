@@ -700,13 +700,20 @@ def test_drift_drill_detects_one_noised_engine(tmp_path):
                 assert not bundle_id.startswith("error"), bundle_id
                 assert fe.diagnostics.bundle_path(bundle_id) is not None
 
-            # idempotent while open: further drifting rounds re-touch
+            # idempotent while open: further drifting rounds re-touch. The
+            # router round-robins a round's probes over the fleet, so a
+            # round can miss the one noised engine (and, being clean, close
+            # the incident: one run in three, by the order of the engines'
+            # ports); this round drifts whichever engines it lands on
+            for fe in engines:
+                fe.fault_state.set(FaultSpec.parse("logit_noise_scale=0.5"))
             await prober.run_round()
             assert im.snapshot()["open"] == 1
             assert open_rows()[0]["id"] == inc_id
 
             # heal → a fully clean round closes the incident
-            engines[1].fault_state.set(None)
+            for fe in engines:
+                fe.fault_state.set(None)
             await prober.run_round()
             assert all(st.outcome == "ok" for st in prober.state.values())
             assert im.snapshot()["open"] == 0

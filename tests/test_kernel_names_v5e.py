@@ -91,3 +91,70 @@ def test_kernel_is_a_named_custom_call(one_chip, name):
     assert named, f"no custom-call headed %{name}.N among {heads}"
     # and no kernel of the program is left with a name XLA made up
     assert not [h for h in heads if "unknown" in h or "closed_call" in h]
+
+
+# -- OLMoE-1B-7B on one chip (chipbench olmoe-1b-7b-l8): MHA, KH=16, G=1 ----
+# the cell's own shapes: a 2048-token stream, 64 slots, 4096 positions in
+# blocks of 16, 8 layers; both attention kernels and the KV write have to
+# fit the DEFAULT 16 MiB of scoped VMEM (the configuration sets no libtpu
+# flag), and the MoE block's grouped matmul has to reach the trace under a
+# name chipbench/layer_metrics/moe_*.json can match
+
+MHA_CACHE = ((8, 2048, BS, 2 * 16, D), jnp.bfloat16)
+MHA_CASES = {
+    "ragged_paged_attention": (
+        lambda q, c, bt, cu, cl: ragged_paged_attention_pallas(
+            q, c, bt, cu, cl, layer_idx=1),
+        (((2048, 16, D), jnp.bfloat16), MHA_CACHE, ((64, 256), I32),
+         ((65,), I32), ((64,), I32))),
+    "paged_decode_attention": (
+        lambda q, c, bt, cl: paged_decode_attention_pallas(
+            q, c, bt, cl, layer_idx=1),
+        (((64, 16, D), jnp.bfloat16), MHA_CACHE, ((64, 256), I32),
+         ((64,), I32))),
+    "kv_cache_write": (
+        lambda c, new, sm: kv_cache_write_pallas(c, new, sm, layer_idx=1),
+        (MHA_CACHE, ((2048, 2 * 16, D), jnp.bfloat16), ((2048,), I32))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MHA_CASES))
+def test_kernel_compiles_at_olmoe_geometry_under_default_vmem(one_chip, name):
+    fn, shapes = MHA_CASES[name]
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = .*? custom-call\(",
+                     text, flags=re.M)
+
+
+@pytest.mark.parametrize("rows", [64 * 8, 2048 * 8])  # decode step, stream
+def test_moe_block_compiles_to_named_grouped_matmuls(one_chip, rows):
+    """``jax.lax.ragged_dot`` becomes XLA's own Mosaic kernel on the TPU,
+    three a layer, each an instruction headed ``%ragged-dot-none[.N]``
+    over the sorted (token, choice) rows: no dense expansion over the 64
+    experts, no one-hot dispatch, and no copy of a layer's experts out of
+    the 8-layer stack (3 x 268 MB a layer if the kernel were handed a
+    slice)."""
+    from production_stack_tpu.engine.config import ModelConfig
+    from production_stack_tpu.models import llama
+
+    cfg = ModelConfig.from_pretrained("olmoe-1b-7b")
+    E, F, X = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
+    T = rows // cfg.num_experts_per_tok
+    bf = jnp.bfloat16
+    def sds(shape, dt=bf):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    experts = {"w_gate": sds((8, X, E, F)), "w_up": sds((8, X, E, F)),
+               "w_down": sds((8, X, F, E))}
+    compiled = jax.jit(
+        lambda router, experts, layer, x, live: llama._moe_mlp(
+            cfg, router, experts, layer, x, live)
+    ).lower(sds((E, X)), experts, sds((), I32), sds((T, E)),
+            sds((T,), jnp.bool_)).compile()
+    heads = re.findall(
+        rf"^\s*(?:ROOT )?%(ragged-dot-none[.\d]*) = bf16\[{rows},",
+        compiled.as_text(), flags=re.M)
+    assert len(heads) == 3, heads
+    # the block's transients are its sorted rows (64 MB each at stream
+    # size), never a layer's experts (805 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 400 * 2 ** 20

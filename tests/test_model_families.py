@@ -1,9 +1,12 @@
-"""Mistral / Phi-3 / Qwen3 families — exactness against HF transformers.
+"""Mistral / Phi-3 / Qwen3 / OLMoE families — exactness against HF
+transformers.
 
 The reference serves these via vLLM's model zoo; here the shared Llama
 stack grows the deltas as ModelConfig knobs (Mistral: all-layer sliding
 window under the exactness gate; Phi-3: fused HF qkv/gate_up checkpoint
-layout split at load; Qwen3: per-head QK RMSNorm pre-rope). Tiny random HF
+layout split at load; Qwen3: per-head QK RMSNorm pre-rope; OLMoE: QK
+RMSNorm over the whole projection, 8 experts, top-2, weights not
+renormalised, ``mlp.experts.N.*`` tensor names). Tiny random HF
 checkpoints are saved to disk, loaded through our safetensors path, and
 logits must match HF to float32 tolerance — then the serving engine (paged
 path) must reproduce HF greedy generation.
@@ -54,6 +57,18 @@ def _mk_checkpoint(tmpdir, family: str):
             eos_token_id=2, **COMMON
         )
         hf = transformers.Phi3ForCausalLM(cfg)
+    elif family == "olmoe":
+        cfg = transformers.OlmoeConfig(
+            **{**COMMON, "intermediate_size": 64, "num_key_value_heads": 4,
+               "rms_norm_eps": 1e-5},
+            num_experts=8, num_experts_per_tok=2, norm_topk_prob=False,
+            tie_word_embeddings=False, pad_token_id=0,
+        )
+        hf = transformers.OlmoeForCausalLM(cfg)
+        with torch.no_grad():  # HF initialises norms to 1: make them count
+            for name, p in hf.named_parameters():
+                if name.endswith(("q_norm.weight", "k_norm.weight")):
+                    p.uniform_(0.5, 1.5)
     else:  # qwen3
         cfg = transformers.Qwen3Config(
             head_dim=32, tie_word_embeddings=True, **COMMON
@@ -64,7 +79,8 @@ def _mk_checkpoint(tmpdir, family: str):
     return hf
 
 
-@pytest.fixture(scope="module", params=["mistral", "phi3", "qwen3"])
+@pytest.fixture(scope="module",
+                params=["mistral", "phi3", "qwen3", "olmoe"])
 def family_ckpt(request, tmp_path_factory):
     tmp = tmp_path_factory.mktemp(request.param)
     hf = _mk_checkpoint(tmp, request.param)
@@ -79,6 +95,10 @@ def test_logits_match_hf(family_ckpt):
         assert cfg.sliding_window == 512  # gate: serve within the window
     elif family == "phi3":
         assert cfg.architecture == "phi3"
+    elif family == "olmoe":
+        assert cfg.architecture == "olmoe" and cfg.qk_norm_kind == "full"
+        assert (cfg.num_experts, cfg.num_experts_per_tok) == (8, 2)
+        assert not cfg.norm_topk_prob
     else:
         assert cfg.qk_norm and cfg.tie_word_embeddings
     toks = torch.randint(0, cfg.vocab_size, (2, 16),
@@ -90,6 +110,27 @@ def test_logits_match_hf(family_ckpt):
         params = init_or_load(cfg, mesh)
     got = np.asarray(llama.forward_dense(cfg, params, jnp.asarray(toks.numpy())))
     np.testing.assert_allclose(got, ref, atol=3e-5, rtol=1e-4)
+    if family == "olmoe":
+        # every HF tensor was placed (mlp.gate, mlp.experts.N.*_proj, the
+        # whole-projection norms by head), and the benchmark's plain
+        # reference is the published model too
+        from production_stack_tpu.engine.weights import _hf_key_map
+
+        assert cfg.num_layers * len(_hf_key_map(cfg, 0)) + 3 == len(
+            hf.state_dict())
+        assert params["layers"]["w_down"].shape == (2, 8, 64, 128)
+        assert params["layers"]["q_norm"].shape == (2, 4, 32)
+        import json
+        import os
+
+        from chipbench.reference import olmoe as reference
+
+        with open(os.path.join(path, "config.json")) as f:
+            hf_cfg = json.load(f)
+        want = torch.log_softmax(torch.from_numpy(ref[0]), -1).numpy()
+        plain = np.asarray(reference.logprobs(
+            hf_cfg, params, toks[0].tolist(), 0))
+        np.testing.assert_allclose(plain, want, atol=5e-5, rtol=1e-4)
 
 
 def test_engine_matches_hf_greedy(family_ckpt):

@@ -213,16 +213,26 @@ def test_a16_threshold_env_robustness(monkeypatch):
     assert quant._a16_threshold() == 512
 
 
-def test_quant_einsum_tokens_hint_overrides_shape(monkeypatch):
-    """MoE capacity slots over-count tokens ~2x; tokens_hint keeps the
-    bandwidth-bound W8A8 path selected for real decode batches."""
-    eq = "xce,xef->xcf"
+def test_ragged_quant_dot_matches_a_per_group_quant_einsum():
+    """The MoE experts' grouped matmul with int8 weights is W8A8 with each
+    row's own activation scale and its group's weight scale: the same
+    numbers as quant_einsum on each group's rows alone (forced W8A8)."""
     w = jax.random.normal(jax.random.PRNGKey(2), (4, 32, 20),
                           jnp.float32) * 0.1
     qw = quant.quantize_array(w, (1,))
-    x = jax.random.normal(jax.random.PRNGKey(1), (4, 256, 32), jnp.float32)
-    # shape says 1024 slots (would pick W8A16); hint says 300 real tokens
-    hinted = quant.quant_einsum(eq, x, qw, tokens_hint=300)
-    monkeypatch.setenv("PSTPU_QUANT_A16_THRESHOLD", "1000000")
-    a8 = quant.quant_einsum(eq, x, qw)  # forced W8A8
-    assert np.allclose(np.asarray(hinted), np.asarray(a8), atol=1e-6)
+    x = jax.random.normal(jax.random.PRNGKey(1), (24, 32), jnp.float32)
+    sizes = jnp.asarray([5, 0, 11, 4], jnp.int32)  # 4 rows past the groups
+    group = jnp.repeat(jnp.arange(5), jnp.asarray([5, 0, 11, 4, 4]),
+                       total_repeat_length=24)
+    got = quant.ragged_quant_dot(x, qw, sizes, group)
+    plain = quant.ragged_quant_dot(x, w, sizes, group)
+    lo = 0
+    for g, n in enumerate([5, 0, 11, 4]):
+        one = {"q": qw["q"][g], "s": qw["s"][g]}
+        want = quant.quant_einsum("te,ef->tf", x[lo:lo + n], one)
+        assert np.allclose(np.asarray(got[lo:lo + n]), np.asarray(want),
+                           atol=1e-6)
+        ref = x[lo:lo + n] @ w[g]
+        assert np.allclose(np.asarray(plain[lo:lo + n]), np.asarray(ref),
+                           atol=1e-5)
+        lo += n
