@@ -51,6 +51,9 @@ class AsyncEngine:
         if self.thread is not None and self.thread.is_alive():
             return
         self.running = True
+        # what a step has resolved before it blocks on a decode program
+        # leaves through here and not in step()'s return value
+        self.engine.output_sink = self._hand_over
         self.thread = threading.Thread(target=self._worker, daemon=True)
         self.thread.start()
 
@@ -59,12 +62,18 @@ class AsyncEngine:
         if self.thread is not None:
             self.thread.join(timeout=2.0)
             self.thread = None
+        self.engine.output_sink = None  # step() driven by hand again
 
     # -- worker thread -------------------------------------------------------
     def _worker(self) -> None:
         # every moment of this loop belongs to a phase of the engine's
         # step clock (engine/tracing.py): idle and intake here, the rest
-        # inside engine.step(), deliver after it
+        # inside engine.step(). `deliver` is entered twice at most: inside
+        # the step, where the engine hands what it has resolved to
+        # `_hand_over` before it blocks on a decode program, and after it
+        # for what step() returns. An output takes one way or the other,
+        # never both, so a step that raises after a hand-over leaves
+        # nothing to deliver again
         clock = self.engine.clock
         while self.running:
             self._drain_intake(block=not self.engine.has_unfinished())
@@ -90,9 +99,9 @@ class AsyncEngine:
                 clock.end_step()
                 continue
             self.step_count += 1
-            if outputs and self.loop is not None:
+            if outputs:
                 clock.enter("deliver")
-                self.loop.call_soon_threadsafe(self._deliver, outputs)
+                self._hand_over(outputs)
             step_seconds = clock.end_step()
             if self.step_observer is not None:
                 try:
@@ -163,6 +172,14 @@ class AsyncEngine:
                 item = self.intake.get_nowait()
             except queue.Empty:
                 return
+
+    def _hand_over(self, outputs: list[RequestOutput]) -> None:
+        """Engine thread -> event loop, in the order handed over (one
+        thread, and call_soon_threadsafe is FIFO): a request's token from
+        a ragged step reaches its stream before its token from the decode
+        step after it."""
+        if self.loop is not None:
+            self.loop.call_soon_threadsafe(self._deliver, outputs)
 
     def _deliver(self, outputs: list[RequestOutput]) -> None:
         for out in outputs:

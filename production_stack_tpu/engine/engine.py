@@ -252,6 +252,11 @@ class LLMEngine:
         self.ragged_dispatches = 0
         self.ragged_live_tokens = 0
         self.decode_dispatches = 0  # decode_multi dispatches
+        # where a decode-only step's already resolved outputs go before
+        # the thread blocks on the decode program (`_hand_over`): the
+        # async worker sets it; None means step() returns everything
+        self.output_sink = None
+        self.early_handovers = 0  # hand-overs made before a wait
         # goodput accounting + compile tracking (perf_accounting.py); the
         # staged PP runner exposes no single param tree or jit programs to
         # wrap, so it only gets dispatch accounting
@@ -464,7 +469,9 @@ class LLMEngine:
                 # verification is fused in the same ragged dispatch
                 outputs.extend(self._run_ragged(out, proposed=True))
             else:
-                outputs.extend(self._run_decode(decodes))
+                # what is resolved leaves through the sink, if there is
+                # one, before the thread waits for the decode program
+                self._run_decode(decodes, outputs)
         else:
             outputs.extend(self._resolve_pending_decode())
         return outputs
@@ -523,6 +530,20 @@ class LLMEngine:
             else:
                 self._spec.update(seq, k, 0)
         return any_drafts
+
+    def _hand_over(self, outputs: list[RequestOutput]) -> None:
+        """Give what the step has resolved so far to the output sink
+        before the thread blocks on the device, and take it out of
+        ``outputs`` so that step() does not return it a second time.
+        Without a sink (step() driven by hand) or with nothing resolved
+        it does nothing."""
+        sink = self.output_sink
+        if sink is None or not outputs:
+            return
+        self.clock.enter("deliver")
+        sink(outputs[:])
+        outputs.clear()
+        self.early_handovers += 1
 
     def _fetch(self, result_dev) -> tuple:
         """Block on a dispatch's results: the step clock's `wait` phase.
@@ -1249,9 +1270,15 @@ class LLMEngine:
             )
         return self._postprocess(live, token_lists, lp_lists)
 
-    def _run_decode(self, decodes: list[Sequence]) -> list[RequestOutput]:
+    def _run_decode(self, decodes: list[Sequence],
+                    outputs: list[RequestOutput]) -> None:
+        """One decode dispatch over ``decodes``. ``outputs`` holds what
+        the step resolved before it (the pending ragged or prefill
+        dispatch's tokens): it goes to the output sink once the decode
+        program is launched and before the thread waits for it
+        (`_hand_over`), and this dispatch's outputs are appended to what
+        is left of it."""
         bs = self.config.cache.block_size
-        outputs: list[RequestOutput] = []
         use_logprobs = (
             getattr(self.runner, "supports_logprobs", False)
             and any(s.sampling.logprobs is not None for s in decodes)
@@ -1275,7 +1302,7 @@ class LLMEngine:
                 decodes = [s for s in decodes
                            if s.status is SequenceStatus.RUNNING]
                 if not decodes:
-                    return outputs
+                    return
                 pending = None
         chain = pending is not None
         self.clock.enter("build")
@@ -1329,6 +1356,12 @@ class LLMEngine:
         K = max(self.config.scheduler.multi_step, 1)
         self.clock.describe("decode", rows=len(decodes),
                             tokens=K * len(decodes))
+        # a runner that cannot chain (the staged pipeline) relays every
+        # decode step through the host and returns with the tokens on it:
+        # there the hand-over comes before the call, not after the launch
+        launches = getattr(self.runner, "supports_chaining", False)
+        if not launches:
+            self._hand_over(outputs)
         t_call = self.clock.enter("snapshot")
         result = self.runner.decode_multi(
             self._tokens, self._positions, self._block_tables,
@@ -1343,11 +1376,21 @@ class LLMEngine:
             tokens_dev=(pending["next_tok"] if chain else None),
             g_ids=self._g_ids if use_grammar else None,
             g_states=self._g_states if use_grammar else None,
-            fetch=not can_chain,
             want_logprobs=use_logprobs,
         )
         dispatch_s = self.clock.enter("postprocess") - t_call
         self.decode_dispatches += 1
+        pend = {"decodes": list(decodes), "slots": [s.slot for s in decodes]}
+        if launches:
+            pend["sampled"], next_tok, pend["moe_hist"], *lp = result
+            pend["lp"] = lp  # empty unless the variant returns logprobs
+            if not can_chain:
+                # the program is in flight: the event loop works on what
+                # is handed over while this thread waits in the fetch
+                self._hand_over(outputs)
+                dispatch_s += self._fetch_decode(pend)
+        else:
+            pend["sampled"] = result  # (K, B) on the host already
         if self.perf is not None:
             entries = [(seq, "decode", K, K) for seq in decodes]
             self.perf.record_decode(
@@ -1355,61 +1398,62 @@ class LLMEngine:
                 seconds=dispatch_s, tenants=self._tenant_map(entries),
             )
             self._attribute_seq_seconds(dispatch_s, entries)
-        if can_chain:
-            sampled, next_tok, moe_hist = result
-            # defer: speculative num_computed advance (the scheduler's
-            # block growth needs it NOW); tokens append at resolution
-            for seq in decodes:
-                seq.num_computed_tokens += K
-            self._pending_decode = {
-                "decodes": list(decodes),
-                "slots": [s.slot for s in decodes],
-                "rids": [s.request_id for s in decodes],
-                "sampled": sampled,
-                "next_tok": next_tok,
-                "moe_hist": moe_hist,  # None unless the model is MoE
-            }
-            if chain:
-                # the previous dispatch's results are fetchable now that
-                # this one is in flight
-                outputs.extend(self._finish_decode(pending))
-            return outputs
-        pend = {"decodes": decodes, "slots": [s.slot for s in decodes]}
-        if use_logprobs:
-            pend["sampled"], pend["lp"] = result[0], result[1:]
-        else:
-            pend["sampled"] = result
-        outputs.extend(self._finish_decode(pend, fetched=True, advance=True))
-        return outputs
+        if not can_chain:
+            outputs.extend(self._finish_decode(pend, advance=True))
+            return
+        # defer: speculative num_computed advance (the scheduler's block
+        # growth needs it NOW); tokens append at resolution
+        for seq in decodes:
+            seq.num_computed_tokens += K
+        pend["rids"] = [s.request_id for s in decodes]
+        pend["next_tok"] = next_tok
+        self._pending_decode = pend
+        if chain:
+            # the previous dispatch's results are fetchable now that this
+            # one is in flight
+            self._hand_over(outputs)
+            self._fetch_decode(pending)
+            outputs.extend(self._finish_decode(pending))
 
     def _resolve_pending_decode(self) -> list[RequestOutput]:
         if self._pending_decode is None:
             return []
         pending = self._pending_decode
         self._pending_decode = None
+        self._fetch_decode(pending)
         return self._finish_decode(pending)
 
-    def _finish_decode(self, pending, fetched: bool = False,
+    def _fetch_decode(self, pending) -> float:
+        """Block on a launched decode dispatch and put its results into
+        ``pending`` in place of the device arrays: sampled tokens (K, B),
+        the log-probability arrays where the variant returns them, and an
+        MoE model's routing histogram, which goes to its counters.
+        Returns the seconds blocked."""
+        (sampled, moe_hist, *lp), wait_s = self._fetch(
+            (pending["sampled"], pending.get("moe_hist"),
+             *pending.get("lp", ())))
+        pending["sampled"] = np.asarray(sampled)
+        pending["lp"] = [np.asarray(x) for x in lp]
+        if moe_hist is not None:
+            self.runner.moe.record("decode", moe_hist)
+        return wait_s
+
+    def _finish_decode(self, pending,
                        advance: bool = False) -> list[RequestOutput]:
-        """Fetch (unless already host-side) + append + stop-check one decode
-        dispatch's sampled tokens. ``advance`` replays the legacy behaviour
-        for non-chaining runners where num_computed wasn't advanced at
-        dispatch."""
+        """Append + stop-check one decode dispatch's sampled tokens, on
+        the host by now (`_fetch_decode`, or a runner that returned them
+        there). ``advance`` moves num_computed here, for a dispatch that
+        was not deferred (the chained path advances it at dispatch)."""
         sampled = pending["sampled"]
-        if not fetched:
-            sampled, moe_hist = self._fetch(
-                (sampled, pending.get("moe_hist")))[0]
-            sampled = np.asarray(sampled)
-            if moe_hist is not None:
-                self.runner.moe.record("decode", moe_hist)
-        lp = pending.get("lp")  # (tok_lp (K, B), ids (K, B, N), lps ...)
+        # [tok_lp (K, B), ids (K, B, N), lps (K, B, N)], or nothing
+        lp = pending.get("lp")
         token_lists = []
         lp_lists = []
         live = []
         for seq, slot in zip(pending["decodes"], pending["slots"]):
             if seq.status.is_finished:
                 continue  # aborted while in flight; surplus tokens dropped
-            want_lp = lp is not None and seq.sampling.logprobs is not None
+            want_lp = bool(lp) and seq.sampling.logprobs is not None
             new_toks = []
             new_lps = [] if want_lp else None
             for k in range(sampled.shape[0]):
@@ -1653,6 +1697,7 @@ class LLMEngine:
             "ragged_dispatches_total": self.ragged_dispatches,
             "ragged_live_tokens_total": self.ragged_live_tokens,
             "decode_dispatches_total": self.decode_dispatches,
+            "early_handovers_total": self.early_handovers,
             "step_phases": self.clock.snapshot(),
             "ragged_stream_utilization": (
                 self.ragged_live_tokens

@@ -167,6 +167,12 @@ class EngineStatsCollector:
             "decode_multi dispatches issued (decode-only steps)",
             s.get("decode_dispatches_total", 0),
         )
+        yield counter(
+            "vllm:engine_early_handovers",
+            "Times the engine thread handed a step's resolved outputs to "
+            "the event loop before it blocked on the next decode program",
+            s.get("early_handovers_total", 0),
+        )
         # the engine thread's step clock (engine/tracing.py): where its
         # wall time goes, by the kind of step and the phase of the loop.
         # Waiting for the device, idling on the intake queue and on-CPU

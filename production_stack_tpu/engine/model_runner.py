@@ -768,8 +768,10 @@ class ModelRunner:
                 jnp.asarray(row),
             )
 
-    supports_chaining = True  # device-resident token chaining across
-    # dispatches (the staged PP runner relays through the host instead)
+    supports_chaining = True  # decode_multi launches and returns device
+    # arrays: the engine fetches them itself and may chain the next
+    # dispatch on them (the staged PP runner relays through the host and
+    # returns only when the tokens are on it)
     supports_logprobs = True  # prefill/decode programs emit logprobs
     # (the staged PP runner's per-stage programs don't — server 400s)
 
@@ -779,21 +781,25 @@ class ModelRunner:
                      presence=None, frequency=None,
                      adapter_ids=None, ctrl=None, tokens_dev=None,
                      g_ids=None, g_states=None,
-                     fetch: bool = True, want_logprobs: bool = False):
-        """multi_step fused decode+sample iterations; returns sampled tokens
-        (num_steps, B) on host — or the un-fetched device array with
-        ``fetch=False`` so the next dispatch overlaps this one's compute
-        and result round trip. ``tokens_dev`` feeds the batch's input
-        tokens straight from the previous dispatch's device-resident
-        samples (no host round trip between chained dispatches).
-        ``greedy_only`` selects the argmax-only compiled variant;
-        presence/frequency arrays activate the penalised variant (counts
-        tracked on device).
+                     want_logprobs: bool = False):
+        """Launch multi_step fused decode+sample iterations and return
+        without waiting for them: ``(sampled (num_steps, B), next_tok,
+        moe_hist[, tok_lp (K, B), ids (K, B, N), lps (K, B, N)])``, all
+        still on the device, so whatever the caller does before it fetches
+        (hand over what it has resolved, launch the next dispatch)
+        overlaps this one's compute. ``moe_hist`` is the routing histogram
+        of an MoE model, else None; the caller fetches it with the sampled
+        tokens. ``tokens_dev`` feeds the batch's input tokens straight
+        from the previous dispatch's device-resident ``next_tok`` (no host
+        round trip between chained dispatches). ``greedy_only`` selects
+        the argmax-only compiled variant; presence/frequency arrays
+        activate the penalised variant (counts tracked on device);
+        ``want_logprobs`` the variant that also returns log-probabilities.
 
         The ten always-present inputs reach the device as ONE packed
         buffer in one transfer (``StepLayout``, ``_commit``); packing
         copies them, so the engine may rewrite its host arrays as soon as
-        this returns, fetched or not."""
+        this returns."""
         arrays = (tokens, positions, block_tables, context_lens,
                   slot_mapping, temps, top_ps, top_ks, seeds, steps,
                   np.full(1, tokens_dev is not None, np.int32))
@@ -818,18 +824,7 @@ class ModelRunner:
             self.token_counts = new_counts
         # an MoE model's routing histogram is the last leaf
         moe_hist = lp.pop() if self.moe is not None else None
-        if not fetch:
-            # chain path never carries logprobs; the caller fetches the
-            # histogram (None: not an MoE model) with the sampled tokens
-            return sampled, next_tok, moe_hist
-        self.clock.enter("wait")
-        # (sampled (K, B)[, tok_lp (K, B), ids (K, B, N), lps (K, B, N)])
-        sampled, moe_hist, *lp = jax.device_get((sampled, moe_hist, *lp))
-        if moe_hist is not None:
-            self.moe.record("decode", moe_hist)
-        if want_logprobs:
-            return tuple(np.asarray(x) for x in (sampled, *lp))
-        return np.asarray(sampled)
+        return (sampled, next_tok, moe_hist, *lp)
 
     def ragged_step(self, tokens, positions, block_tables, context_lens,
                     cu_q_lens, slot_mapping, last_idx, sample_mask,

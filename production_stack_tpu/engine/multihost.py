@@ -356,8 +356,8 @@ class FollowerReplayer:
             kwargs = dict(kwargs)
             kwargs["tokens_dev"] = self._next_tok
         result = getattr(self.runner, method)(*args, **kwargs)
-        if method == "decode_multi" and not kwargs.get("fetch", True):
-            # fetch=False returns (sampled, next_tok) device arrays
+        if method == "decode_multi":
+            # (sampled, next_tok, ...) device arrays, un-fetched
             self._next_tok = result[1]
 
 

@@ -1916,14 +1916,18 @@ class EngineServer:
         # phase (wall and on-CPU), steps by kind, idle seconds — the
         # numbers behind vllm:engine_host_seconds_total and its siblings
         step_phases = self.engine.clock.snapshot()
+        # hand-overs made before a wait (vllm:engine_early_handovers_total)
+        handovers = self.engine.early_handovers
         if perf is None:
             return web.json_response({"enabled": False,
                                       "kv_transfer": kv_block,
                                       "kv_tier": tier_block,
                                       "step_phases": step_phases,
+                                      "early_handovers": handovers,
                                       "tenants": self.engine.tenant_stats()})
         snap = perf.snapshot()
         snap["step_phases"] = step_phases
+        snap["early_handovers"] = handovers
         eng = self.engine
         drafted = getattr(eng, "spec_drafted", 0)
         steps = getattr(eng, "spec_steps", 0)

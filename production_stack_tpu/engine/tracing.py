@@ -156,7 +156,11 @@ class request_span:
 
 # `wait` (blocked on the device) and `idle` (blocked on the intake queue)
 # are exported as families of their own, the others as
-# vllm:engine_host_seconds_total{kind,phase}
+# vllm:engine_host_seconds_total{kind,phase}. A step's phases come in the
+# order listed, except that `deliver` may come twice: mid-step, between
+# `launch` and `wait`, when a decode-only step hands over what it resolved
+# before it blocks on the decode program (LLMEngine._hand_over), and after
+# `postprocess` for what step() returns
 HOST_PHASES = ("intake", "schedule", "build", "snapshot", "commit", "launch",
                "postprocess", "deliver", "prefetch_wait")
 STEP_KINDS = ("decode", "ragged", "prefill", "other")

@@ -117,3 +117,114 @@ def test_admit_batch_failure_aborts_siblings(setup):
         return True
 
     assert asyncio.run(fn())
+
+
+# -- early hand-over: a step's resolved tokens reach the streams before the
+# engine thread blocks on the next decode program, and once only ----------------
+
+def _collect(ae, prompt, sp, rid):
+    async def go():
+        items = []
+        try:
+            async for out in ae.generate(prompt, sp, request_id=rid):
+                items.append(out)
+        except Exception as e:  # what the stream raised, in its place
+            items.append(e)
+        return items
+    return go()
+
+
+@pytest.mark.parametrize("chain", [False, True], ids=["unchained", "chained"])
+def test_streams_get_what_the_engine_produced_once_and_in_order(setup, chain):
+    """Prompts arrive while others decode (one wants a single token, one
+    log-probabilities): every stream receives exactly the outputs the
+    engine thread produced for it, in that order, whichever way each took
+    (handed over before a decode wait, or returned by step())."""
+    import dataclasses
+
+    cfg, mesh, params = setup
+    cfg = dataclasses.replace(cfg, scheduler=dataclasses.replace(
+        cfg.scheduler, chain_decode=chain))
+    eng = LLMEngine(cfg, mesh=mesh, params=params,
+                    num_blocks=cfg.cache.num_blocks)
+    produced = {}
+    real = eng._postprocess
+
+    def postprocess(*a, **kw):
+        outs = real(*a, **kw)
+        for o in outs:
+            produced.setdefault(o.request_id, []).append(o)
+        return outs
+
+    eng._postprocess = postprocess
+    greedy = dict(temperature=0.0, ignore_eos=True)
+    requests = [
+        ("s0", [1, 2, 3, 4, 5], SamplingParams(max_tokens=12, **greedy)),
+        ("s1", [9, 8, 7], SamplingParams(max_tokens=1, **greedy)),
+        ("s2", [3, 1, 4, 1, 5, 9, 2], SamplingParams(
+            max_tokens=8, temperature=0.8, top_k=30, seed=7, logprobs=2,
+            ignore_eos=True)),
+        ("s3", [6, 6, 6, 6], SamplingParams(max_tokens=6, **greedy)),
+    ]
+
+    async def fn():
+        ae = AsyncEngine(eng)
+        await ae.start()
+        assert eng.output_sink is not None
+        try:
+            tasks = []
+            for rid, prompt, sp in requests:
+                tasks.append(asyncio.ensure_future(
+                    _collect(ae, prompt, sp, rid)))
+                await asyncio.sleep(0.05)  # the others are decoding by now
+            return await asyncio.wait_for(asyncio.gather(*tasks), 120)
+        finally:
+            ae.stop()
+
+    got = asyncio.run(fn())
+    assert eng.output_sink is None  # stop() gives step() back to its caller
+    assert eng.early_handovers > 0
+    for (rid, _, sp), items in zip(requests, got):
+        assert items == produced[rid]  # the same objects, each once
+        assert sum(len(o.new_token_ids) for o in items) == sp.max_tokens
+        assert [o.finished for o in items].count(True) == 1
+        assert items[-1].finished
+        if sp.logprobs is not None:
+            assert all(o.new_logprobs for o in items)
+
+
+def test_a_step_that_raises_after_the_hand_over_delivers_nothing_twice(setup):
+    """The decode step fails once its program is launched and the first
+    token handed over: the stream has that token once, then the error, and
+    nothing after it."""
+    cfg, mesh, params = setup
+    eng = LLMEngine(cfg, mesh=mesh, params=params,
+                    num_blocks=cfg.cache.num_blocks)
+    handed = []
+
+    def fetch_decode(pending):
+        handed.append(eng.early_handovers)
+        raise RuntimeError("device lost")
+
+    eng._fetch_decode = fetch_decode
+    sp = SamplingParams(temperature=0.0, max_tokens=8, ignore_eos=True)
+
+    async def fn():
+        ae = AsyncEngine(eng)
+        await ae.start()
+        try:
+            items = await asyncio.wait_for(
+                _collect(ae, [1, 2, 3, 4, 5], sp, "boom"), 60)
+            await asyncio.sleep(0.2)  # anything late would land now
+            busy = await ae.run_on_engine(lambda e: e.has_unfinished())
+            return items, busy, dict(ae.streams)
+        finally:
+            ae.stop()
+
+    items, busy, streams = asyncio.run(fn())
+    assert handed == [1]  # the hand-over came before the failing wait
+    assert len(items) == 2
+    first, err = items
+    assert len(first.new_token_ids) == 1 and not first.finished
+    assert isinstance(err, ValueError) and "device lost" in str(err)
+    assert not busy and streams == {}

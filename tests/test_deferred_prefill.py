@@ -4,6 +4,9 @@ dispatch's sampled tokens land one step after scheduler-visible state
 advances, so aborts, preemption, and max_tokens=1 finishes can all occur
 while the dispatch is in flight."""
 
+import numpy as np
+import pytest
+
 from production_stack_tpu.engine.config import (
     CacheConfig,
     EngineConfig,
@@ -159,3 +162,189 @@ def test_chained_decode_token_identical():
     assert got == ref
     for i in range(len(prompts)):
         assert len(ref[f"r{i}"]) == sp.max_tokens - 4 * i
+
+
+# -- a step's resolved tokens leave before the wait for the decode program ----
+# (LLMEngine.output_sink / _hand_over). A mixed run: prompts arrive while
+# others decode, one asks for a single token, one for log-probabilities
+# with seeded sampling. What step() returned for it at the parent commit
+# (e7d0282, computed from an unpacked `git archive` of it, on the CPU) is
+# pinned: [step, request, tokens, finished, has logprobs], in order.
+
+def _sp(max_tokens, **kw):
+    kw.setdefault("temperature", 0.0)
+    return SamplingParams(max_tokens=max_tokens, ignore_eos=True, **kw)
+
+
+ARRIVALS = {  # before step n
+    0: [("r0", [1, 2, 3, 4, 5], _sp(6)), ("r1", [9, 8, 7], _sp(1))],
+    2: [("r2", [3, 1, 4, 1, 5, 9, 2],
+         _sp(4, temperature=0.8, top_k=30, seed=7, logprobs=2))],
+    4: [("r3", [6, 6, 6, 6], _sp(3))],
+}
+SCHEDULES = {
+    "ragged": dict(attention_impl="ragged"),
+    "bucketed": dict(attention_impl="bucketed"),
+    "chained": dict(attention_impl="ragged", multi_step=2, chain_decode=True),
+}
+PARENT_EVENTS = {
+    "ragged": [
+        [1, "r0", [400], False, False], [1, "r1", [27], True, False],
+        [1, "r0", [400], False, False], [3, "r2", [408], False, True],
+        [3, "r0", [400], False, False], [3, "r0", [83], False, False],
+        [3, "r2", [83], False, True], [5, "r3", [233], False, False],
+        [5, "r0", [385], False, False], [5, "r2", [298], False, True],
+        [5, "r0", [27], True, False], [5, "r2", [419], True, True],
+        [5, "r3", [415], False, False], [6, "r3", [464], True, False]],
+    "bucketed": [
+        [1, "r0", [400], False, False], [1, "r1", [27], True, False],
+        [1, "r0", [400], False, False], [3, "r2", [408], False, True],
+        [3, "r0", [400], False, False], [3, "r2", [83], False, True],
+        [5, "r3", [233], False, False], [5, "r0", [83], False, False],
+        [5, "r2", [298], False, True], [5, "r3", [415], False, False],
+        [6, "r0", [385], False, False], [6, "r2", [419], True, True],
+        [6, "r3", [464], True, False], [7, "r0", [27], True, False]],
+    "chained": [
+        [1, "r0", [400], False, False], [1, "r1", [27], True, False],
+        [2, "r0", [400, 400], False, False], [3, "r2", [408], False, True],
+        [3, "r0", [83], False, False], [3, "r0", [385, 27], True, False],
+        [3, "r2", [83, 298], False, True], [5, "r3", [233], False, False],
+        [5, "r2", [419], True, True], [6, "r3", [415, 464], True, False]],
+}
+# the events that are resolved before a decode program the thread then
+# waits for: with a sink they take that way, the others are returned
+HANDED_OVER = {
+    "ragged": {1: 2, 3: 2, 5: 3},     # step -> leading events of that step
+    "bucketed": {1: 2, 3: 1, 5: 1},
+    # chained: step 1 launches and does not wait; step 3's decode program
+    # carries logprobs (not chainable), so the thread waits for it
+    "chained": {3: 2},
+}
+
+
+def make_scheduled_engine(attention_impl, **sched):
+    cfg = EngineConfig(
+        model=ModelConfig.from_pretrained("tiny-llama"),
+        cache=CacheConfig(block_size=4, num_blocks=128),
+        scheduler=SchedulerConfig(max_num_seqs=4, max_num_batched_tokens=64,
+                                  prefill_buckets=(16, 32), **sched),
+        mesh=MeshConfig(data=1, tensor=1), attention_impl=attention_impl)
+    return LLMEngine(cfg, mesh=build_mesh(cfg.mesh), num_blocks=128)
+
+
+def run_arrivals(engine):
+    """Drive step() by hand; events in the order they left the engine,
+    each with the way it took."""
+    events, step = [], [0]
+
+    def log(outs, way):
+        events.extend(
+            ([step[0], o.request_id, list(o.new_token_ids), o.finished,
+              o.new_logprobs is not None], way) for o in outs)
+
+    if engine.output_sink is not None:  # the caller asked for one
+        engine.output_sink = lambda outs: log(outs, "sink")
+    for i in range(64):
+        for rid, prompt, sp in ARRIVALS.get(i, ()):
+            engine.add_request(rid, prompt_token_ids=prompt, sampling=sp)
+        if not engine.has_unfinished() and i > max(ARRIVALS):
+            break
+        step[0] = i
+        log(engine.step(), "returned")
+    assert not engine.has_unfinished()
+    return events
+
+
+@pytest.mark.parametrize("case", list(SCHEDULES))
+def test_step_without_a_sink_returns_what_the_parent_returned(case):
+    engine = make_scheduled_engine(**SCHEDULES[case])
+    assert engine.output_sink is None
+    events = run_arrivals(engine)
+    assert [e for e, _ in events] == PARENT_EVENTS[case]
+    assert {way for _, way in events} == {"returned"}
+    assert engine.early_handovers == 0
+    assert engine.stats()["early_handovers_total"] == 0
+
+
+@pytest.mark.parametrize("case", list(SCHEDULES))
+def test_a_sink_changes_no_token_and_no_order(case):
+    """Same tokens, same log-probabilities flag, same finishes, in the
+    same order for every request and over all of them; only the way
+    differs, and nothing takes both."""
+    engine = make_scheduled_engine(**SCHEDULES[case])
+    engine.output_sink = print  # run_arrivals puts its own in its place
+    events = run_arrivals(engine)
+    assert [e for e, _ in events] == PARENT_EVENTS[case]
+    want_ways = []
+    for step in sorted({e[0] for e in PARENT_EVENTS[case]}):
+        n = sum(e[0] == step for e in PARENT_EVENTS[case])
+        early = HANDED_OVER[case].get(step, 0)
+        want_ways += ["sink"] * early + ["returned"] * (n - early)
+    assert [way for _, way in events] == want_ways
+    assert engine.early_handovers == len(HANDED_OVER[case])
+    # every request got exactly max_tokens tokens and one finish
+    for rid, _, sp in (a for batch in ARRIVALS.values() for a in batch):
+        mine = [e for e, _ in events if e[1] == rid]
+        assert sum(len(e[2]) for e in mine) == sp.max_tokens
+        assert [e[3] for e in mine].count(True) == 1 and mine[-1][3]
+
+
+class _BlockingRunner:
+    """What engine/pp_runner.py is to the engine: a runner whose
+    decode_multi returns only when the tokens are on the host."""
+
+    supports_chaining = False
+    supports_logprobs = False
+
+    def __init__(self, inner, log):
+        self._inner, self._log = inner, log
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def decode_multi(self, *a, want_logprobs=False, **kw):
+        self._log.append("decode_multi")
+        sampled, *_ = self._inner.decode_multi(*a, **kw)
+        return np.asarray(sampled)  # blocks
+
+
+@pytest.mark.parametrize("runner", ["launching", "blocking"])
+def test_first_token_reaches_the_sink_before_the_decode_wait(runner):
+    """The prompt completes in ragged step N; in step N+1 its first token
+    is handed over under the clock's `deliver` phase after the decode
+    program is launched and before the thread waits for it. A runner that
+    cannot launch without blocking gets the hand-over before its call."""
+    engine = make_scheduled_engine("ragged")
+    log = []
+    if runner == "blocking":
+        engine.runner = _BlockingRunner(engine.runner, log)
+    real_enter = engine.clock.enter
+
+    def enter(phase, **attrs):
+        log.append(phase)
+        return real_enter(phase, **attrs)
+
+    engine.clock.enter = enter
+    engine.output_sink = lambda outs: log.append(
+        ("sink", [(o.request_id, list(o.new_token_ids)) for o in outs]))
+    engine.add_request("r0", prompt_token_ids=[1, 2, 3, 4, 5],
+                       sampling=_sp(6))
+    assert engine.step() == [] and engine._pending_ragged is not None
+    del log[:]
+    returned = engine.step()  # resolves the ragged step, then decodes
+    sink_at = [i for i, x in enumerate(log) if isinstance(x, tuple)]
+    assert len(sink_at) == 1 and log[sink_at[0]] == ("sink", [("r0", [400])])
+    assert log[sink_at[0] - 1] == "deliver"
+    before, after = log[:sink_at[0]], log[sink_at[0] + 1:]
+    if runner == "launching":
+        # ... wait (the ragged step), build, snapshot, commit, launch,
+        # deliver, SINK, wait (the decode step), postprocess
+        assert "launch" in before and before.index("wait") < before.index(
+            "launch")
+        assert after[0] == "wait" and "launch" not in after
+    else:
+        assert "decode_multi" not in before and "decode_multi" in after
+    # the decode step's own token is returned, and only that
+    assert [(o.request_id, o.new_token_ids) for o in returned] == [
+        ("r0", [400])]
+    assert engine.early_handovers == 1

@@ -196,22 +196,25 @@ def test_layout_follows_from_shapes_alone():
 
 # -- (c) the pack is the snapshot ----------------------------------------------
 
-@pytest.mark.parametrize("method,sampling,want", [
-    ("ragged_step", GREEDY, GREEDY_TOKENS),
-    ("decode_multi", SEEDED, SEEDED_TOKENS)])
+@pytest.mark.parametrize("method,sampling,want,chain", [
+    ("ragged_step", GREEDY, GREEDY_TOKENS, True),
+    ("decode_multi", SEEDED, SEEDED_TOKENS, True),
+    ("decode_multi", SEEDED, SEEDED_TOKENS, False)],
+    ids=["ragged_step", "decode_multi-chained", "decode_multi-unchained"])
 def test_host_arrays_may_be_rewritten_once_the_call_returns(method, sampling,
-                                                            want):
+                                                            want, chain):
     """With the fetch deferred the step may still be pending when the
     engine rewrites its host arrays in place. Scribble over every one of
     them the moment the runner returns, let the step finish, then put them
-    back: the results must not have read the scribble."""
-    eng = make_engine(chain_decode=True)
+    back: the results must not have read the scribble. ``decode_multi``
+    never fetches, chained or not: the engine does, after the hand-over."""
+    eng = make_engine(chain_decode=chain)
     runner = eng.runner
     real = getattr(runner, method)
     calls = []
 
     def scribbling(*arrays, **kw):
-        assert kw["fetch"] is False
+        assert kw.get("fetch", False) is False
         result = real(*arrays, **kw)
         mutable = [a for a in (*arrays, kw.get("verify_idx"))
                    if isinstance(a, np.ndarray)]

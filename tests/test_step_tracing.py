@@ -5,6 +5,7 @@ feed PerfAccountant and the step histogram, programs carry names, request
 records name their steps, and none of it changes what is generated."""
 
 import asyncio
+import dataclasses
 import functools
 import time
 
@@ -88,10 +89,20 @@ def _samples(text: str, name: str) -> dict:
 
 # -- the clock over a worker loop ---------------------------------------------
 
-def test_phases_sum_to_the_worker_wall_time_and_each_step_has_one_kind():
-    eng = LLMEngine(make_config())
+@pytest.mark.parametrize("chain", [False, True], ids=["unchained", "chained"])
+def test_phases_sum_to_the_worker_wall_time_and_each_step_has_one_kind(chain):
+    cfg = make_config()
+    cfg = dataclasses.replace(cfg, scheduler=dataclasses.replace(
+        cfg.scheduler, chain_decode=chain))
+    eng = LLMEngine(cfg)
     clock = eng.clock
     seen = []
+    # `deliver` is entered mid-step too, when the engine hands what it has
+    # resolved to the worker's sink before it waits for a decode program
+    order = []
+    real_enter = clock.enter
+    clock.enter = lambda phase, **kw: (order.append(phase),
+                                       real_enter(phase, **kw))[1]
 
     async def fn():
         ae = AsyncEngine(eng)
@@ -110,6 +121,15 @@ def test_phases_sum_to_the_worker_wall_time_and_each_step_has_one_kind():
 
     outs, wall, step_count = asyncio.run(fn())
     assert outs == PARENT_TOKENS
+    # one hand-over a prompt: its ragged step is followed by a decode step
+    # the thread waits in (chained: the first of a run only launches, so
+    # the first token is returned at once and no hand-over is made)
+    assert eng.early_handovers == (0 if chain else len(PROMPTS))
+    mid = [i for i, p in enumerate(order[:-1])
+           if p == "deliver" and order[i + 1] == "wait"]
+    assert len(mid) == eng.early_handovers
+    assert all(order[i - 1] == "postprocess" and "launch" in order[i - 3:i]
+               for i in mid)
     # the worker's whole life is in some phase: host + wait + idle = wall
     assert _clock_total(clock) == pytest.approx(wall, rel=0.02)
     assert clock.idle_seconds > 0.2
@@ -229,6 +249,70 @@ def test_families_in_metrics_and_debug_perf_only_grow(server):
         assert host >= sum(_samples(second, FAMILIES[0]).values()) > 0
 
     asyncio.run(_with_client(server, fn))
+
+
+def test_early_handovers_counter_counts_each_hand_over(server):
+    """vllm:engine_early_handovers_total on /metrics and `early_handovers`
+    on /debug/perf are one plain count: the calls the engine made to its
+    output sink, each before a wait for a decode program."""
+    name = "vllm:engine_early_handovers_total"
+
+    async def read(client):
+        text = await (await client.get("/metrics")).text()
+        (value,) = _samples(text, name).values()
+        perf = await (await client.get("/debug/perf")).json()
+        assert perf["early_handovers"] == value
+        return value
+
+    async def fn(client):
+        eng = server.engine
+        sink, calls = eng.output_sink, []
+        assert sink is not None  # the async worker set it at start
+        eng.output_sink = lambda outs: (calls.append(
+            (eng.clock._phase, len(outs))), sink(outs))[1]
+        try:
+            before = await read(client)
+            for i, stream in enumerate((True, False, True)):
+                r = await client.post("/v1/completions", json={
+                    "model": "tiny-llama", "prompt": PROMPTS[i % 2],
+                    "max_tokens": 5, "temperature": 0, "ignore_eos": True,
+                    "stream": stream})
+                assert r.status == 200
+                await r.text()
+            after = await read(client)
+        finally:
+            eng.output_sink = sink
+        # one request at a time: its ragged step, then decode steps
+        assert after - before == len(calls) == 3
+        assert all(phase == "deliver" and n > 0 for phase, n in calls)
+
+    asyncio.run(_with_client(server, fn))
+
+
+def test_deliver_entered_twice_in_a_step_is_one_phase_and_loses_no_time():
+    clock = StepClock()
+    t0 = time.monotonic()
+    clock.begin_step()
+    clock.describe("decode", rows=1, tokens=1)
+    for phase in ("schedule", "build", "snapshot", "commit"):
+        clock.enter(phase)
+    clock.launch()
+    clock.enter("postprocess")
+    clock.enter("deliver")      # the hand-over, mid-step
+    time.sleep(0.01)
+    clock.enter("wait")
+    time.sleep(0.01)
+    clock.enter("postprocess")
+    clock.enter("deliver")      # what step() returned
+    time.sleep(0.01)
+    seconds = clock.end_step()
+    wall = time.monotonic() - t0
+    by = clock.seconds["decode"]
+    assert by["deliver"][0] >= 0.02 and by["wait"][0] >= 0.01
+    # (begin_step to the first phase is in no phase: microseconds)
+    assert seconds == pytest.approx(sum(w for w, _ in by.values()), abs=1e-3)
+    assert seconds <= wall and wall - seconds < 0.005
+    assert clock.steps == {"decode": 1, "ragged": 0, "prefill": 0, "other": 0}
 
 
 def test_flight_record_names_its_steps_and_the_first_chunk(server):
