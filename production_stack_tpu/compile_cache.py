@@ -3,8 +3,8 @@
 Engine warm-up compiles every serving program at full depth before
 ``/ready`` turns 200; a persistent cache turns a restart's warm-up from
 compile-bound into load-bound. Every entry point that opens a device
-(``engine/server.py`` ``main()``, ``bench.py``, the kernel check in
-``chip_smoke.py``) calls ``configure_compile_cache()`` before its first
+(``engine/server.py`` ``main()``, the kernel check in ``chip_smoke.py``,
+the reference child of ``chipbench/``) calls ``configure_compile_cache()`` before its first
 backend use, so all of one command's processes share one directory.
 
 The directory is part of the cache key, so it never moves between runs:

@@ -325,8 +325,8 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
     ),
     "llama-3b-class": ModelConfig(
         # Llama-3.2-3B geometry: the largest bf16 Llama that fits a single
-        # v5e chip (16 GiB HBM) with a useful KV pool — the single-chip
-        # benchmark model (bench.py).
+        # v5e chip (16 GiB HBM) with a useful KV pool (chip_smoke.py's
+        # model).
         name="llama-3b-class", vocab_size=128256, hidden_size=3072,
         intermediate_size=8192, num_layers=28, num_heads=24, num_kv_heads=8,
         head_dim=128, rope_theta=500000.0, max_model_len=8192,
@@ -606,12 +606,6 @@ class PerfConfig:
     peak_ici_gbps: float = 0.0
     # how often device.memory_stats() is sampled for the HBM gauges
     hbm_poll_interval: float = 5.0
-    # cost-model drift band: sustained excursion of the windowed
-    # measured/predicted dispatch-seconds ratio beyond this factor of
-    # its post-warmup baseline (either direction) fires the
-    # ``costmodel_drift`` anomaly. <=1 disables detection (the
-    # vllm:costmodel_* gauges export regardless)
-    costmodel_drift_band: float = 0.0
 
 
 @dataclasses.dataclass
@@ -666,13 +660,6 @@ class EngineConfig:
     # empty path = ledger off (metering gauges still work)
     tenant_ledger_path: str = ""
     tenant_ledger_max_bytes: int = 16 << 20
-    # durable perf ledger (production_stack_tpu/perf_ledger.py): rotating
-    # JSONL of fingerprint-stamped PerfAccountant snapshots journaled
-    # every perf_ledger_interval seconds and once on drain; empty path =
-    # ledger off (the in-memory window and gauges still work)
-    perf_ledger_path: str = ""
-    perf_ledger_max_bytes: int = 16 << 20
-    perf_ledger_interval: float = 60.0
 
     @staticmethod
     def for_model(name: str, **kw) -> "EngineConfig":

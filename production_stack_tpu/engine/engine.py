@@ -437,7 +437,7 @@ class LLMEngine:
                 # nothing else runnable and fetches in flight: a bounded
                 # wait trades a busy-spin for latency no request observes.
                 # Time spent here is the NON-overlapped share of prefetch
-                # (the bench's prefetch-overlap fraction reads it).
+                # (``stats()`` reports it as the prefetch-overlap fraction).
                 t0 = self.clock.enter("prefetch_wait")
                 self._prefetcher.wait_any(0.002)
                 self.prefetch_stall_seconds += (

@@ -343,52 +343,6 @@ class EngineStatsCollector:
                 "— a shape leaked past warmup (bug signal)",
                 perf["unexpected_recompiles"],
             )
-            # cost-model drift plane (perf_accounting.py): roofline-
-            # predicted dispatch seconds beside the measured wall
-            # seconds, plus the windowed measured/predicted ratio and
-            # the episode counter the CostModelDrift alert fires on
-            cm = perf.get("costmodel")
-            if cm:
-                pred = CounterMetricFamily(
-                    "vllm:costmodel_predicted_seconds",
-                    "Roofline-predicted dispatch seconds by phase (max "
-                    "of FLOP/HBM/ICI transit time for each dispatch's "
-                    "live token/byte counts)",
-                    labels=["model_name", "phase"],
-                )
-                meas = CounterMetricFamily(
-                    "vllm:costmodel_measured_seconds",
-                    "Measured dispatch wall seconds attributed to the "
-                    "cost-model drift window, by phase",
-                    labels=["model_name", "phase"],
-                )
-                ratio = GaugeMetricFamily(
-                    "vllm:costmodel_drift_ratio",
-                    "Windowed measured/predicted dispatch-seconds ratio "
-                    "by phase — the roofline cost model's honesty gauge "
-                    "(judged relative to its post-warmup baseline)",
-                    labels=["model_name", "phase"],
-                )
-                for phase in ("prefill", "decode"):
-                    pred.add_metric(
-                        [self.model_name, phase],
-                        (cm.get("predicted_seconds") or {}).get(phase, 0.0))
-                    meas.add_metric(
-                        [self.model_name, phase],
-                        (cm.get("measured_seconds") or {}).get(phase, 0.0))
-                    ratio.add_metric(
-                        [self.model_name, phase],
-                        (cm.get("drift_ratio") or {}).get(phase, 0.0))
-                yield pred
-                yield meas
-                yield ratio
-                yield counter(
-                    "vllm:costmodel_drift_episodes",
-                    "Sustained cost-model drift episodes (windowed ratio "
-                    "left the configured band relative to its baseline; "
-                    "one count per excursion)",
-                    cm.get("episodes", 0),
-                )
         # tenant attribution plane (production_stack_tpu/tenancy.py):
         # per-tenant consumption, label set bounded by the top-K +
         # tenant="other" policy. The engine folds before exporting;

@@ -15,8 +15,9 @@ Static-shape discipline (XLA traces once per shape):
   slot at each span's last token; rows whose sample is not consumed
   (mid-prompt chunks, inactive slots) produce masked garbage the host
   discards.
-- **bucketed** (fallback / rollback): decode is one compiled program over a
-  fixed (max_num_seqs, 1) batch; prefill compiles once per token-length
+- **bucketed** (fallback / rollback): decode is one compiled program
+  (``decode_multi``: ``multi_step`` fused decode + sample iterations) over
+  a fixed (max_num_seqs, 1) batch; prefill compiles once per token-length
   bucket (powers of two) with chunks padded up. Block tables are always
   (B, max_blocks_per_seq).
 - KV cache buffers are donated through every step, so XLA updates them in
@@ -257,11 +258,6 @@ class ModelRunner:
                            self._eos_id),
             donate_argnums=(1,),
             static_argnames=("greedy_only", "use_controls", "use_grammar"),
-            **self._mh_gate,
-        )
-        self._decode = jax.jit(
-            _named_partial(_decode_step, self.cfg, self._attend_decode),
-            donate_argnums=(1,),
             **self._mh_gate,
         )
         self._decode_multi = jax.jit(
@@ -726,19 +722,6 @@ class ModelRunner:
             )
         self.clock.enter("wait")
         return tuple(np.asarray(x) for x in jax.device_get(result))
-
-    def decode(self, tokens: np.ndarray, positions: np.ndarray,
-               block_tables: np.ndarray, context_lens: np.ndarray,
-               slot_mapping: np.ndarray):
-        """One decode step over all slots. Returns logits (B, V)."""
-        with jax.set_mesh(self.mesh):
-            self.kv, logits = self._decode(
-                self.params, self.kv,
-                jnp.asarray(tokens[:, None]), jnp.asarray(positions[:, None]),
-                jnp.asarray(block_tables), jnp.asarray(context_lens),
-                jnp.asarray(slot_mapping),
-            )
-        return logits
 
     def _ensure_counts(self):
         if self.token_counts is None:
@@ -1391,25 +1374,6 @@ def _prefill_ring_step(cfg: ModelConfig, mesh, head_axis, tp, params, kv,
 
     lp = compute_logprobs(raw_logits, sampled)
     return new_kv, (sampled, *lp)
-
-
-def _decode_step(cfg: ModelConfig, attend_impl, params, kv, tokens, positions,
-                 block_tables, context_lens, slot_mapping):
-    from production_stack_tpu.models.registry import get_model
-
-    model = get_model(cfg)
-
-    def attend(q, k, v, caches, layer_idx):
-        return attend_impl(
-            q, k, v, caches, layer_idx, block_tables, context_lens, positions,
-            slot_mapping,
-        )
-
-    hidden, new_kv = model.forward_tokens(
-        cfg, params, tokens, positions, attend, kv
-    )
-    logits = model.logits_from_hidden(cfg, params, hidden)[:, 0]  # (B, V)
-    return new_kv, logits
 
 
 def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,

@@ -40,11 +40,10 @@ show up as lost MFU; that is the goodput story.
 from __future__ import annotations
 
 import logging
-import os
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 from production_stack_tpu.tenancy import OTHER, fold_records, split_shares
 
@@ -138,8 +137,8 @@ def wrap_runner_programs(runner, observer: Callable) -> None:
     """Install ``CompileTracker`` proxies over a runner's jitted programs
     (the per-bucket prefill variants and every decode variant; speculative
     verify has no program of its own — it is fused into ``_ragged``)."""
-    for attr in ("_prefill", "_prefill_ring", "_decode", "_decode_multi",
-                 "_sample", "_ragged"):
+    for attr in ("_prefill", "_prefill_ring", "_decode_multi", "_sample",
+                 "_ragged"):
         fn = getattr(runner, attr, None)
         if fn is None or isinstance(fn, CompileTracker):
             continue
@@ -226,42 +225,6 @@ class PerfAccountant:
         # its capture thread and returns).
         self.anomaly_hook: Optional[Callable[[str, dict], None]] = None
         self.hbm_threshold = 0.0  # fraction of HBM; 0 = disabled
-        # -- cost-model drift plane (docs/observability.md "Perf ledger &
-        # cost-model drift") ----------------------------------------------
-        # Every dispatch the window already costs gets a PREDICTED wall
-        # time from the same roofline arithmetic — max of FLOP-time,
-        # HBM-time and ICI-time for its live token/byte counts — kept
-        # beside the MEASURED wall seconds the engine passes in. The
-        # windowed measured/predicted ratio is the cost model's honesty
-        # gauge: the absolute value is platform-shaped (a CPU backend
-        # runs ~1e4x over the TPU rooflines), so detection is
-        # BASELINE-RELATIVE — after warmup the first full window freezes
-        # a per-phase baseline ratio, and sustained excursion of
-        # ratio/baseline outside [1/band, band] fires the
-        # ``costmodel_drift`` anomaly exactly once per episode. band<=1
-        # (the default 0) disables detection; the gauges export either
-        # way. This is the enforced check that quantized byte accounting
-        # stays honest (ROADMAP item 1): mis-counted HBM bytes move the
-        # predicted denominator and the ratio walks out of band.
-        self.costmodel_drift_band = 0.0
-        # windowed dispatches a phase needs before its ratio is judged
-        # (or its baseline frozen) — one outlier dispatch is not drift
-        self.costmodel_min_events = 8
-        # test-only fault knob: scales MEASURED seconds in this plane
-        # (and nowhere else — tenant chip-second conservation and every
-        # goodput gauge are untouched), so drills can force drift
-        # without slowing a real dispatch
-        self.measured_time_scale = float(
-            os.environ.get("PSTPU_PERF_MEASURED_SCALE", "") or 1.0)
-        # (ts, phase, predicted_s, measured_s) — same window as _events
-        self._drift_events: deque = deque()
-        self._costmodel = {
-            "predicted_seconds": {"prefill": 0.0, "decode": 0.0},
-            "measured_seconds": {"prefill": 0.0, "decode": 0.0},
-            "episodes": 0,
-        }
-        self._drift_baseline: Dict[str, float] = {}
-        self._drift_out: Dict[str, bool] = {}
         # -- tenant attribution plane (production_stack_tpu/tenancy.py) --
         # Per-tenant cumulative counters, fed by the same record_* calls
         # that bill the fleet-wide window: every dispatch's wall seconds
@@ -341,8 +304,6 @@ class PerfAccountant:
                    peak_ici_gbps=peaks[2],
                    tenant_metering=getattr(config, "tenant_metering", True),
                    tenant_top_k=getattr(config, "tenant_top_k", 8))
-        acct.costmodel_drift_band = getattr(perf, "costmodel_drift_band",
-                                            0.0)
         return acct
 
     # -- compile events ------------------------------------------------------
@@ -365,19 +326,9 @@ class PerfAccountant:
 
     def mark_steady(self) -> None:
         """Warmup pre-compiled every serving variant: from here on a fresh
-        compile means a shape leaked past warmup — a bug signal.
-
-        The cost-model drift window resets here: pre-steady dispatch
-        wall times are compile-polluted (a first call is dominated by
-        XLA, not by the roofline), so the measured/predicted baseline
-        is only meaningful from steady state on. The cumulative
-        predicted/measured counters keep their pre-steady totals — they
-        are counters, not the judged window."""
+        compile means a shape leaked past warmup — a bug signal."""
         with self._lock:
             self._steady = True
-            self._drift_events.clear()
-            self._drift_baseline.clear()
-            self._drift_out.clear()
 
     # -- dispatch accounting -------------------------------------------------
     def _weight_bytes(self, tokens: int) -> float:
@@ -407,9 +358,6 @@ class PerfAccountant:
         ag = rows * self._ag_bytes_per_row
         self._record(ts, "prefill", flops, hbm, live_tokens,
                      ar_bytes=ar, ag_bytes=ag)
-        self._note_costmodel(
-            ts, [("prefill", self._predicted_seconds(flops, hbm, ar + ag))],
-            seconds)
         self.attribute_tenants(seconds, tenants)
 
     def record_decode(self, live_seqs: int, steps: int, ctx_tokens: int,
@@ -429,9 +377,6 @@ class PerfAccountant:
         ag = tokens * self._ag_bytes_per_row
         self._record(ts, "decode", flops, hbm, tokens,
                      ar_bytes=ar, ag_bytes=ag)
-        self._note_costmodel(
-            ts, [("decode", self._predicted_seconds(flops, hbm, ar + ag))],
-            seconds)
         self.attribute_tenants(seconds, tenants)
 
     def record_ragged(self, prefill_tokens: int, prefill_ctx: int,
@@ -476,7 +421,6 @@ class PerfAccountant:
         if prefill_tokens <= 0 and decode_seqs <= 0 and spec_tokens <= 0:
             return
         self.attribute_tenants(seconds, tenants)
-        predicted: List[Tuple[str, float]] = []
         if prefill_tokens > 0 or spec_tokens > 0:
             ctx_mean = prefill_ctx / max(prefill_rows, 1)
             flops = (2.0 * self.active_param_count * prefill_tokens
@@ -494,8 +438,6 @@ class PerfAccountant:
             ag = (prefill_rows + spec_tokens) * self._ag_bytes_per_row
             self._record(ts, "prefill", flops, hbm, prefill_tokens,
                          ar_bytes=ar, ag_bytes=ag)
-            predicted.append(
-                ("prefill", self._predicted_seconds(flops, hbm, ar + ag)))
         if decode_seqs > 0:
             flops = (2.0 * self.active_param_count * decode_seqs
                      + self._attn_per_tok_ctx * decode_ctx)
@@ -507,11 +449,6 @@ class PerfAccountant:
             ag = decode_seqs * self._ag_bytes_per_row
             self._record(ts, "decode", flops, hbm, decode_seqs,
                          ar_bytes=ar, ag_bytes=ag)
-            predicted.append(
-                ("decode", self._predicted_seconds(flops, hbm, ar + ag)))
-        # one fused wall time covers both phase events: split it by each
-        # event's predicted share (conserves the measured total)
-        self._note_costmodel(ts, predicted, seconds)
 
     def record_spec_accepted(self, tokens: int,
                              ts: Optional[float] = None,
@@ -529,119 +466,6 @@ class PerfAccountant:
             self._trim(now)
         if tenant is not None:
             self.attribute_tenants(0.0, {tenant: {"decode": tokens}})
-
-    # -- cost-model drift detection ------------------------------------------
-    def _predicted_seconds(self, flops: float, hbm: float,
-                           ici: float) -> float:
-        """Roofline-predicted wall time for one dispatch event: the
-        binding ceiling's transit time for its live FLOP/byte counts —
-        exactly the arithmetic docs/roofline.md does by hand."""
-        return max(_ratio(flops, self.peak_flops) or 0.0,
-                   _ratio(hbm, self.peak_hbm) or 0.0,
-                   _ratio(ici, self.peak_ici) or 0.0)
-
-    def _note_costmodel(self, ts: Optional[float],
-                        predicted: List[Tuple[str, float]],
-                        seconds: float) -> None:
-        """Feed one dispatch's predicted-vs-measured seconds into the
-        drift window and judge the band. Measured wall time is split
-        across the dispatch's phase events by predicted share; events
-        with no wall time (warmup probes, synthetic records) still
-        accumulate the predicted counter but never enter the ratio
-        window. Fires ``anomaly_hook("costmodel_drift", ...)`` OUTSIDE
-        the lock, one call per phase episode edge."""
-        if not predicted:
-            return
-        now = ts if ts is not None else time.monotonic()
-        measured = max(float(seconds), 0.0) * self.measured_time_scale
-        total_pred = sum(p for _, p in predicted)
-        alerts: List[dict] = []
-        with self._lock:
-            for phase, pred in predicted:
-                if pred <= 0:
-                    continue
-                self._costmodel["predicted_seconds"][phase] += pred
-                if measured > 0 and total_pred > 0:
-                    share = measured * (pred / total_pred)
-                    self._costmodel["measured_seconds"][phase] += share
-                    self._drift_events.append((now, phase, pred, share))
-            self._trim_drift(now)
-            if measured > 0:
-                alerts = self._evaluate_drift_locked(now)
-        if self.anomaly_hook is not None:
-            for detail in alerts:
-                self.anomaly_hook("costmodel_drift", detail)
-
-    def _trim_drift(self, now: float) -> None:
-        while (self._drift_events
-               and self._drift_events[0][0] < now - self.window):
-            self._drift_events.popleft()
-
-    def _drift_ratios_locked(self) -> Tuple[Dict[str, float],
-                                            Dict[str, int]]:
-        pred = {"prefill": 0.0, "decode": 0.0}
-        meas = {"prefill": 0.0, "decode": 0.0}
-        counts = {"prefill": 0, "decode": 0}
-        for _, phase, p, m in self._drift_events:
-            pred[phase] += p
-            meas[phase] += m
-            counts[phase] += 1
-        ratios = {phase: (meas[phase] / pred[phase]) if pred[phase] > 0
-                  else 0.0 for phase in pred}
-        return ratios, counts
-
-    def _evaluate_drift_locked(self, now: float) -> List[dict]:
-        """Judge each phase's windowed ratio against its frozen baseline.
-        Called under ``self._lock``; returns the anomaly details to fire
-        after release. Detection needs: band > 1, warmup done
-        (``mark_steady``), and ``costmodel_min_events`` windowed
-        dispatches in the phase. The first qualifying window FREEZES the
-        phase's baseline (platform-relative zero point); an episode is
-        entered when ratio/baseline leaves [1/band, band] and exits when
-        it returns — exactly one anomaly per entry edge."""
-        band = self.costmodel_drift_band
-        if band <= 1.0 or not self._steady:
-            return []
-        ratios, counts = self._drift_ratios_locked()
-        alerts: List[dict] = []
-        for phase, ratio in ratios.items():
-            if counts[phase] < self.costmodel_min_events or ratio <= 0:
-                continue
-            baseline = self._drift_baseline.get(phase)
-            if baseline is None or baseline <= 0:
-                self._drift_baseline[phase] = ratio
-                continue
-            relative = ratio / baseline
-            out = relative > band or relative < 1.0 / band
-            if out and not self._drift_out.get(phase, False):
-                self._costmodel["episodes"] += 1
-                alerts.append({
-                    "phase": phase,
-                    "ratio": round(ratio, 6),
-                    "baseline": round(baseline, 6),
-                    "relative": round(relative, 4),
-                    "band": band,
-                    "window_events": counts[phase],
-                    "ts": time.time(),
-                })
-            self._drift_out[phase] = out
-        return alerts
-
-    def _costmodel_fields_locked(self) -> dict:
-        ratios, counts = self._drift_ratios_locked()
-        return {
-            "band": self.costmodel_drift_band,
-            "min_events": self.costmodel_min_events,
-            "predicted_seconds": dict(self._costmodel["predicted_seconds"]),
-            "measured_seconds": dict(self._costmodel["measured_seconds"]),
-            "drift_ratio": ratios,
-            "window_events": counts,
-            "baseline": {p: round(v, 6) for p, v
-                         in self._drift_baseline.items()},
-            "out_of_band": sorted(p for p, o in self._drift_out.items()
-                                  if o),
-            "episodes": self._costmodel["episodes"],
-        }
 
     # -- tenant attribution --------------------------------------------------
     def attribute_tenants(self, seconds: float,
@@ -783,7 +607,6 @@ class PerfAccountant:
     def _trim(self, now: float) -> None:
         while self._events and self._events[0][0] < now - self.window:
             self._events.popleft()
-        self._trim_drift(now)
 
     # -- HBM occupancy -------------------------------------------------------
     def poll_hbm(self, now: Optional[float] = None) -> None:
@@ -859,7 +682,6 @@ class PerfAccountant:
                 "compile_seconds_total": self._compile_seconds,
                 "unexpected_recompiles": self._unexpected,
                 "dispatches_total": self._totals["dispatches"],
-                "costmodel": self._costmodel_fields_locked(),
             }
 
     def snapshot(self) -> dict:
@@ -902,7 +724,6 @@ class PerfAccountant:
                                       "decode": rates["decode_tps"]},
                 "hbm_bytes": dict(self._hbm),
                 "totals": dict(self._totals),
-                "costmodel": self._costmodel_fields_locked(),
                 "compile": {
                     "steady": self._steady,
                     "total_events": sum(self._compile_counts.values()),

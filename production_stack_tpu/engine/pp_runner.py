@@ -153,19 +153,19 @@ class StagedModelRunner:
     # -- compiled stage steps ----------------------------------------------
     def _compile_steps(self) -> None:
         cfg = self.stage_cfg
-        self._prefill_steps = []
-        self._decode_steps = []
+        self._stage_prefills = []
+        self._stage_decodes = []
         for s, runner in enumerate(self.stages):
             first = s == 0
             last = s == self.n_stages - 1
-            self._prefill_steps.append(jax.jit(
+            self._stage_prefills.append(jax.jit(
                 functools.partial(
                     _stage_prefill, cfg, runner._attend_prefill, first, last
                 ),
                 donate_argnums=(1,),
                 static_argnames=("greedy_only", "use_controls"),
             ))
-            self._decode_steps.append(jax.jit(
+            self._stage_decodes.append(jax.jit(
                 functools.partial(
                     _stage_decode, cfg, runner._attend_decode, first, last
                 ),
@@ -195,7 +195,7 @@ class StagedModelRunner:
                 x = jax.device_put(
                     x, _replicated(self.submeshes[s]))
             with jax.set_mesh(self.submeshes[s]):
-                runner.kv, x = self._prefill_steps[s](
+                runner.kv, x = self._stage_prefills[s](
                     runner.params, runner.kv, x, *common, *sample_args,
                     lora_bank=runner.lora_bank if use_lora else None,
                     adapter_ids=(jnp.asarray(adapter_ids, jnp.int32)
@@ -263,7 +263,7 @@ class StagedModelRunner:
                     x, _replicated(self.submeshes[s]))
                 with jax.set_mesh(self.submeshes[s]):
                     if is_last:
-                        (runner.kv, new_counts), x = self._decode_steps[s](
+                        (runner.kv, new_counts), x = self._stage_decodes[s](
                             runner.params, runner.kv, x,
                             jnp.asarray(pos[:, None]), bt, jnp.asarray(ctx),
                             jnp.asarray(slots),
@@ -280,7 +280,7 @@ class StagedModelRunner:
                         if use_penalties:
                             last.token_counts = new_counts
                     else:
-                        runner.kv, x = self._decode_steps[s](
+                        runner.kv, x = self._stage_decodes[s](
                             runner.params, runner.kv, x,
                             jnp.asarray(pos[:, None]), bt, jnp.asarray(ctx),
                             jnp.asarray(slots),

@@ -286,23 +286,7 @@ class EngineServer:
                 config.tenant_ledger_path,
                 max_bytes=config.tenant_ledger_max_bytes,
             )
-        # durable perf ledger (production_stack_tpu/perf_ledger.py):
-        # fingerprint-stamped accountant snapshots journaled every
-        # perf_ledger_interval seconds and once on drain, so perf history
-        # survives restarts. Off unless a path was configured AND the
-        # accountant exists — journaling is read-only over stats() and
-        # never touches the serving path.
-        self.perf_ledger = None
-        self._perf_ledger_task: Optional[asyncio.Task] = None
         self._perf_fp: Optional[dict] = None
-        if (config.perf_ledger_path
-                and getattr(self.engine, "perf", None) is not None):
-            from production_stack_tpu.perf_ledger import PerfLedger
-
-            self.perf_ledger = PerfLedger(
-                config.perf_ledger_path,
-                max_bytes=config.perf_ledger_max_bytes,
-            )
         self.start_time = time.time()
         # -- fleet lifecycle: drain state machine + stuck-step watchdog.
         # SERVING → DRAINING (SIGTERM / POST /drain): readiness (GET
@@ -465,9 +449,6 @@ class EngineServer:
         if self.brownout is not None and self.brownout.config.enabled:
             self._brownout_task = asyncio.ensure_future(
                 self._brownout_worker())
-        if self.perf_ledger is not None:
-            self._perf_ledger_task = asyncio.ensure_future(
-                self._perf_ledger_worker())
 
     async def _run_warmup(self) -> None:
         assert self._warmup_t0 is not None
@@ -493,8 +474,6 @@ class EngineServer:
             self._warmup_task.cancel()
         if self._brownout_task is not None:
             self._brownout_task.cancel()
-        if self._perf_ledger_task is not None:
-            self._perf_ledger_task.cancel()
         if self._drain_task is not None:
             self._drain_task.cancel()
         self.watchdog.stop()
@@ -619,30 +598,12 @@ class EngineServer:
     async def debug_overload(self, request: web.Request) -> web.Response:
         return web.json_response(self._overload_snapshot())
 
-    # -- durable perf ledger (production_stack_tpu/perf_ledger.py) -----------
-    async def _perf_ledger_worker(self) -> None:
-        """Periodic journal of the accountant's windowed marks into the
-        durable ledger. Read-only over ``engine.stats()`` (the same call
-        the metrics collector makes from the scrape thread) — the
-        serving path never waits on ledger IO, and ledger IO errors are
-        counted, never raised."""
-        interval = max(float(self.config.perf_ledger_interval), 0.5)
-        while True:
-            await asyncio.sleep(interval)
-            try:
-                self._journal_perf("interval")
-            except asyncio.CancelledError:
-                raise
-            except Exception:
-                _log.exception("perf ledger journal failed")
-
     def _perf_fingerprint(self) -> dict:
-        """The config cohort stamp every ledger record carries (computed
-        once): ledger comparisons are only meaningful within a cohort."""
+        """What this engine is serving with, for ``/debug/perf`` (computed
+        once): the fields that change the performance envelope, so that a
+        reader compares runs only within one configuration."""
         if self._perf_fp is not None:
             return self._perf_fp
-        from production_stack_tpu import perf_ledger as pl
-
         cfg = self.config
         perf = getattr(self.engine, "perf", None)
         jax_version = platform = chip = ""
@@ -658,36 +619,26 @@ class EngineServer:
         except Exception:
             # fingerprint degrades (empty jax/chip fields), never fails
             _log.debug("perf fingerprint: no jax device identifiers")
-        self._perf_fp = pl.fingerprint(
-            model=cfg.model.name,
-            role=getattr(cfg, "role", "unified"),
-            tensor_parallel=getattr(perf, "tp", 1),
-            attention_impl=getattr(self.engine, "attention_impl",
-                                   cfg.attention_impl),
-            dtype=cfg.model.dtype,
-            quantization=cfg.model.quant or "",
-            speculative=bool(getattr(cfg.scheduler, "spec_ngram_k", 0)),
-            n_chips=getattr(perf, "n_chips", 1),
-            jax_version=jax_version,
-            platform=platform,
-            chip=chip,
+        self._perf_fp = {
+            "model": cfg.model.name,
+            "role": cfg.role,
+            "tensor_parallel": int(getattr(perf, "tp", 1) or 1),
+            "attention_impl": str(getattr(self.engine, "attention_impl",
+                                          cfg.attention_impl) or ""),
+            "dtype": cfg.model.dtype,
+            "quantization": cfg.model.quant or "",
+            "speculative": bool(cfg.scheduler.spec_ngram_k),
+            "n_chips": int(getattr(perf, "n_chips", 1) or 1),
+            "jax_version": jax_version,
+            "platform": platform,
+            "chip": chip,
+            "n_devices": n_devices,
             # the attention path actually taken: False on an accelerator
             # is the XLA gather slow path (model_runner._pallas_ok)
-            extra={"use_pallas": bool(getattr(
-                       getattr(self.engine, "runner", None), "use_pallas",
-                       False)),
-                   "n_devices": n_devices},
-        )
+            "use_pallas": bool(getattr(
+                getattr(self.engine, "runner", None), "use_pallas", False)),
+        }
         return self._perf_fp
-
-    def _journal_perf(self, reason: str) -> bool:
-        if self.perf_ledger is None:
-            return False
-        from production_stack_tpu import perf_ledger as pl
-
-        marks = pl.marks_from_engine_stats(self.engine.stats())
-        return self.perf_ledger.append_engine_snapshot(
-            time.time(), self._perf_fingerprint(), marks, reason=reason)
 
     def begin_drain(self, reason: str) -> bool:
         """Flip SERVING → DRAINING (idempotent; returns False when already
@@ -697,12 +648,6 @@ class EngineServer:
         self.draining = True
         self.drain_reason = reason
         self._drain_t0 = time.monotonic()
-        try:
-            # final journal entry while the window still holds the run's
-            # steady state — restarts must not cost the last interval
-            self._journal_perf("drain")
-        except Exception:
-            _log.exception("perf ledger drain journal failed")
         _log.warning(
             "drain started (%s): %d in-flight request(s), deadline %.1fs",
             reason, len(self._inflight), self.drain_deadline,
@@ -1945,10 +1890,6 @@ class EngineServer:
         snap["kv_transfer"] = kv_block
         snap["kv_tier"] = tier_block
         snap["tenants"] = self.engine.tenant_stats()
-        snap["perf_ledger"] = (
-            {"enabled": True, **self.perf_ledger.stats(),
-             "interval": self.config.perf_ledger_interval}
-            if self.perf_ledger is not None else {"enabled": False})
         snap["fingerprint"] = self._perf_fingerprint()
         snap["startup_seconds"] = {
             **self.startup_seconds, "warmup": round(self.warmup_seconds, 2)}
@@ -3390,25 +3331,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--perf-peak-ici-gbps", type=float, default=0.0,
                    help="per-chip ICI GB/s for the collective roofline "
                         "(multi-chip meshes); 0 = by device_kind")
-    p.add_argument("--perf-ledger-path", default="",
-                   help="rotating JSONL perf-ledger path (fingerprint-"
-                        "stamped accountant snapshots journaled every "
-                        "--perf-ledger-interval seconds and on drain — "
-                        "production_stack_tpu/perf_ledger.py); empty = "
-                        "ledger off")
-    p.add_argument("--perf-ledger-max-bytes", type=int, default=16 << 20,
-                   help="perf-ledger rotation threshold in bytes")
-    p.add_argument("--perf-ledger-interval", type=float, default=60.0,
-                   help="seconds between periodic perf-ledger journal "
-                        "entries")
-    p.add_argument("--costmodel-drift-band", type=float, default=0.0,
-                   help="cost-model drift band: sustained excursion of "
-                        "the windowed measured/predicted dispatch-seconds "
-                        "ratio beyond this factor of its post-warmup "
-                        "baseline fires the costmodel_drift anomaly "
-                        "(diagnostics bundle + CostModelDrift alert). "
-                        "<=1 (default 0) = detection off; the "
-                        "vllm:costmodel_* gauges export regardless")
     p.add_argument("--host-offload-blocks", type=int, default=0,
                    help="host-DRAM KV tier capacity in blocks (0 = off; "
                         "prefer --kv-host-cache-bytes)")
@@ -3553,13 +3475,6 @@ def config_from_args(args) -> EngineConfig:
         cfg.perf.peak_hbm_gbps = args.perf_peak_hbm_gbps
     if getattr(args, "perf_peak_ici_gbps", 0.0):
         cfg.perf.peak_ici_gbps = args.perf_peak_ici_gbps
-    cfg.perf.costmodel_drift_band = (
-        getattr(args, "costmodel_drift_band", 0.0) or 0.0)
-    cfg.perf_ledger_path = getattr(args, "perf_ledger_path", "") or ""
-    cfg.perf_ledger_max_bytes = (
-        getattr(args, "perf_ledger_max_bytes", 16 << 20) or (16 << 20))
-    cfg.perf_ledger_interval = (
-        getattr(args, "perf_ledger_interval", 60.0) or 60.0)
     cfg.tenant_metering = getattr(args, "tenant_metering", True)
     cfg.tenant_top_k = getattr(args, "tenant_top_k", 8) or 8
     cfg.tenant_ledger_path = getattr(args, "tenant_ledger_path", "") or ""

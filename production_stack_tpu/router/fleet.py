@@ -106,10 +106,6 @@ def _engine_row(ep, probe: dict, estats, rstats, reasons: dict,
         "ttft": rstats.ttft if rstats else None,
         "tokens_per_second": tps or None,
         "unexpected_recompiles": compile_info.get("unexpected_recompiles"),
-        # cost-model drift block (band/ratios/episodes) from /debug/perf
-        # — None for engines without perf accounting; stacktop's DRIFT
-        # column reads the per-phase ratios from here
-        "costmodel": perf.get("costmodel"),
         # correctness-canary verdict for this engine's model(s): last
         # outcome + max logit error from the router's prober — None
         # when the canary plane is off or hasn't probed yet

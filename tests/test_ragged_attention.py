@@ -17,6 +17,7 @@ Four layers of coverage for the unified path:
 """
 
 import dataclasses
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -382,30 +383,39 @@ def make_engine(setup, **overrides):
                      num_blocks=cfg.cache.num_blocks)
 
 
-def _drain(eng, reqs, stagger_at=()):
+def _drain(eng, reqs, stagger_at=(), abort_at=None, top=False):
     """Submit requests (optionally staggered mid-flight), collect tokens
-    and token-logprobs per request id."""
-    toks = {rid: [] for rid, _, _ in reqs}
-    lps = {rid: [] for rid, _, _ in reqs}
+    and token-logprobs per request id (with ``top`` the whole entries:
+    the chosen token's and the top list). A request is ``(id, prompt,
+    sampling)`` or ``(id, prompt, sampling, adapter_slot)``;
+    ``abort_at=(step, id)`` aborts that request between two steps."""
+    toks = {rid: [] for rid, *_ in reqs}
+    lps = {rid: [] for rid, *_ in reqs}
+
+    def submit(rid, prompt, sampling, slot=0):
+        eng.add_request(rid, prompt_token_ids=prompt, sampling=sampling,
+                        adapter_slot=slot)
+
     queue = list(reqs)
     if not stagger_at:  # submit everything up front
-        for r, pr, s in queue:
-            eng.add_request(r, prompt_token_ids=pr, sampling=s)
+        for req in queue:
+            submit(*req)
         queue = []
     else:  # first request now, the rest at the named step numbers
-        r, pr, s = queue.pop(0)
-        eng.add_request(r, prompt_token_ids=pr, sampling=s)
+        submit(*queue.pop(0))
     n = 0
     while True:
         outs = eng.step()
         n += 1
         if queue and n in stagger_at:
-            r, pr, s = queue.pop(0)
-            eng.add_request(r, prompt_token_ids=pr, sampling=s)
+            submit(*queue.pop(0))
         for o in outs:
             toks[o.request_id].extend(o.new_token_ids)
             if o.new_logprobs:
-                lps[o.request_id].extend(e[0] for e in o.new_logprobs)
+                lps[o.request_id].extend(
+                    e if top else e[0] for e in o.new_logprobs)
+        if abort_at and n == abort_at[0]:
+            assert eng.abort_request(abort_at[1])
         if not eng.has_unfinished() and not queue:
             break
     return toks, lps
@@ -503,3 +513,207 @@ def test_ragged_no_recompiles_after_warmup(setup):
     assert eng.ragged_dispatches > 0
     stats = eng.stats()
     assert 0.0 < stats["ragged_stream_utilization"] <= 1.0
+
+
+# ---- the serving path, feature by feature ----------------------------------
+# Every cell of the benchmark serves through the ragged step + decode_multi;
+# on the CPU ``attention_impl="auto"`` resolves to bucketed, so the
+# feature tests elsewhere run prefill programs no cell runs. Each case
+# below sends the same requests through both families on the CPU and asks
+# for equal tokens (log-probabilities within 1e-3). A configuration's two
+# engines are built once and shared by its cases; both see the same
+# history, so what an earlier case left in the prefix cache is the same on
+# both sides.
+
+def _sp(max_tokens=8, temperature=0.0, ignore_eos=True, **kw):
+    return SamplingParams(max_tokens=max_tokens, temperature=temperature,
+                          ignore_eos=ignore_eos, **kw)
+
+
+SHORT = [1, 5, 9, 13, 2, 6]
+LONG = list(range(1, 70))  # more than the 32-token step budget
+FAMILIES = ("tiny-gemma", "tiny-gemma2", "tiny-qwen3", "tiny-phi3",
+            "tiny-mistral", "tiny-mixtral")
+
+
+@pytest.fixture(scope="module")
+def pair(setup):
+    """``pair(name)`` -> the (bucketed, ragged) engines of a configuration."""
+    from production_stack_tpu.engine.weights import init_or_load
+    from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    built = {}
+
+    def build(name):
+        base, over = setup, {}
+        if name == "small-pool":  # the case's 3 sequences need 16 blocks
+            over = {"cache": CacheConfig(block_size=4, num_blocks=12)}
+        elif name != "llama":
+            # its own weights: one device, so that every head count divides
+            model = (ModelConfig.from_pretrained("tiny-llama", quant="int8")
+                     if name == "int8" else ModelConfig.from_pretrained(name))
+            cfg = dataclasses.replace(setup[0], model=model,
+                                      mesh=MeshConfig(data=1, tensor=1))
+            mesh = build_mesh(cfg.mesh, devices=jax.devices()[:1])
+            base = (cfg, mesh, init_or_load(cfg.model, mesh, seed=0))
+        return tuple(make_engine(base, attention_impl=impl, **over)
+                     for impl in ("bucketed", "ragged"))
+
+    def get(name):
+        if name not in built:
+            built[name] = build(name)
+            assert [e.runner.attention_impl for e in built[name]] == [
+                "bucketed", "ragged"]
+        return built[name]
+
+    return get
+
+
+def _serve(reqs, **kw):
+    return lambda eng: _drain(eng, list(reqs), **kw)
+
+
+def _stop_at_fourth_token(eng):
+    free, _ = _drain(eng, [("free", SHORT, _sp(12))])
+    stop = free["free"][3]
+    toks, _ = _drain(eng, [("stop", SHORT, SamplingParams(
+        temperature=0.0, max_tokens=12, stop_token_ids=[stop]))])
+    assert toks["stop"] and toks["stop"][-1] == stop
+    assert len(toks["stop"]) <= 4
+    return free, toks
+
+
+def _lora_beside_plain(eng):
+    import shutil
+
+    from production_stack_tpu.engine.lora import LoraManager
+    from tests.test_lora import make_adapter_dir
+
+    lora = LoraManager(eng)
+    path = make_adapter_dir(eng.config.model, seed=1)
+    try:
+        lora.load("parity-adapter", path)
+        slot = lora.slot_of("parity-adapter")
+        toks, _ = _drain(eng, [("plain", SHORT, _sp(8)),
+                               ("lora", SHORT, _sp(8), slot)])
+    finally:
+        lora.unload("parity-adapter")
+        shutil.rmtree(path)
+    assert toks["plain"] != toks["lora"]  # the adapter was applied
+    return toks
+
+
+def _prefix_hit(eng):
+    prompt = [int(t) for t in
+              np.random.default_rng(3).integers(1, 500, 40)]
+    first, _ = _drain(eng, [("first", prompt, _sp(8))])
+    hits = eng.stats()["gpu_prefix_cache_hits_total"]
+    second, _ = _drain(eng, [("second", prompt, _sp(8))])
+    assert eng.stats()["gpu_prefix_cache_hits_total"] > hits
+    assert first["first"] == second["second"]
+    return second
+
+
+def _preempt_and_recompute(eng):
+    sched = eng.scheduler
+    with mock.patch.object(sched, "_preempt", wraps=sched._preempt) as spy:
+        toks, _ = _drain(eng, [("a", SHORT, _sp(12)),
+                               ("b", [3, 3, 3, 100, 200], _sp(12)),
+                               ("c", list(range(42, 51)), _sp(12))])
+    assert spy.called, "the pool was meant to be too small for the batch"
+    assert all(len(t) == 12 for t in toks.values())
+    return toks
+
+
+def _abort_between_steps(eng):
+    free = eng.scheduler.num_free_blocks
+    toks, _ = _drain(eng, [("keep", SHORT, _sp(10)),
+                           ("gone", LONG, _sp(10)),
+                           ("keep2", [2, 4], _sp(10))],
+                     abort_at=(2, "gone"))
+    assert len(toks.pop("gone")) < 10
+    # what the aborted sequence held is back in the pool (cached prefix
+    # blocks count as free)
+    assert eng.scheduler.num_free_blocks == free
+    return toks
+
+
+def _guided_choice(eng):
+    return eng.choice_logprobs([5, 6, 7, 8], [[10, 11], [12], [13, 14, 15]])
+
+
+JSON_SCHEMA = {"type": "object",
+               "properties": {"sentiment": {"enum": ["pos", "neg"]},
+                              "score": {"type": "integer"}}}
+
+# name -> (configuration, what to run on each of its two engines)
+PARITY_CASES = {
+    "logprobs_top5_chunked_prompt": ("llama", _serve(
+        [("lp", LONG, _sp(8, logprobs=5)), ("side", SHORT, _sp(8))],
+        top=True)),
+    "seeded_sampling": ("llama", _serve(
+        [("s", SHORT, _sp(10, temperature=0.8, top_p=0.9, top_k=20,
+                          seed=1234)),
+         ("s2", LONG, _sp(10, temperature=1.0, top_k=5, seed=7))])),
+    "guided_regex": ("llama", _serve(
+        [("g", [5, 6, 7], SamplingParams(
+            temperature=0.0, max_tokens=16,
+            guided_regex=r"(yes|no)( indeed)?")),
+         ("free", SHORT, _sp(8))])),
+    "guided_json": ("llama", _serve(
+        [("j", [9, 8, 7, 6], SamplingParams(
+            temperature=0.9, seed=3, max_tokens=48,
+            guided_json=JSON_SCHEMA))])),
+    "guided_choice": ("llama", _guided_choice),
+    "logit_bias": ("llama", _serve(
+        [("bias", SHORT, _sp(8, logit_bias={7: 0.3, 93: -5.0})),
+         ("plain", SHORT, _sp(8))])),
+    "allowed_token_ids": ("llama", _serve(
+        [("allow", SHORT, _sp(8, temperature=1.0, seed=5,
+                              allowed_token_ids=[3, 5, 9, 200]))])),
+    "stop_token_ids": ("llama", _stop_at_fourth_token),
+    "max_tokens_1": ("llama", _serve(
+        [("one", SHORT, _sp(1)), ("one_long", LONG, _sp(1, logprobs=2))])),
+    "penalties": ("llama", _serve(
+        [("pen", [5, 6, 7, 8], _sp(10, presence_penalty=0.8,
+                                   frequency_penalty=0.3))])),
+    "lora_beside_plain": ("llama", _lora_beside_plain),
+    "int8_weights": ("int8", _serve(
+        [("q", SHORT, _sp(8)), ("q_long", LONG, _sp(8, logprobs=1))])),
+    "prefix_cache_hit": ("llama", _prefix_hit),
+    "preempt_and_recompute": ("small-pool", _preempt_and_recompute),
+    "abort_between_steps": ("llama", _abort_between_steps),
+    **{name: (name, _serve([("g", SHORT, _sp(8)), ("g_long", LONG, _sp(8))]))
+       for name in FAMILIES},
+}
+
+
+def _assert_same(got, want):
+    """Tokens (ints) equal, log-probabilities (floats) within 1e-3, over
+    whatever nesting of dicts, lists and tuples a case returns."""
+    if isinstance(want, dict):
+        assert got.keys() == want.keys()
+        for k in want:
+            _assert_same(got[k], want[k])
+    elif isinstance(want, (list, tuple)):
+        assert len(got) == len(want)
+        for g, w in zip(got, want):
+            _assert_same(g, w)
+    elif isinstance(want, float):
+        assert got == pytest.approx(want, abs=1e-3)
+    else:
+        assert got == want
+
+
+@pytest.mark.parametrize("case", PARITY_CASES)
+def test_serving_path_matches_bucketed(pair, case):
+    config, run = PARITY_CASES[case]
+    bucketed, ragged = pair(config)
+    before = ragged.ragged_dispatches
+    want = run(bucketed)
+    got = run(ragged)
+    assert not bucketed.has_unfinished() and not ragged.has_unfinished()
+    assert bucketed.ragged_dispatches == 0
+    # guided choice scores in one dense program under either family
+    assert ragged.ragged_dispatches > before or case == "guided_choice"
+    _assert_same(got, want)

@@ -742,55 +742,6 @@ def test_canary_surface_is_inside_the_gates():
         assert "vllm:canary_probes_total" in text
 
 
-def test_perf_sentinel_surface_is_inside_the_gates():
-    """The perf regression sentinel (PR: durable perf ledger + roofline
-    cost-model drift detection + perfdiff/CI gates) is covered by the
-    gates, not grandfathered: config-drift sees the ledger/drift flags
-    as declared engine CLI flags (so the helm perfLedger template block
-    stays honest), metric-hygiene tracks the four vllm:costmodel_*
-    families as defined in code AND documented, the chart's perfLedger
-    block is consumed by the engine template with values-ci exercising
-    the ledger on CPU, and both alert-rule copies carry the
-    CostModelDrift warning on the episodes counter."""
-    from tools.stackcheck.passes import config_drift, metric_hygiene
-
-    ctx = core.Context(REPO)
-    engine_flags = config_drift._parser_flags(
-        ctx, REPO / "production_stack_tpu" / "engine" / "server.py")
-    assert {"--perf-ledger-path", "--perf-ledger-max-bytes",
-            "--perf-ledger-interval",
-            "--costmodel-drift-band"} <= engine_flags
-
-    # exposition adds _total to the counters; the gate pins base names
-    costmodel = {"vllm:costmodel_predicted_seconds",
-                 "vllm:costmodel_measured_seconds",
-                 "vllm:costmodel_drift_ratio",
-                 "vllm:costmodel_drift_episodes"}
-    defined = metric_hygiene.code_metrics(ctx)
-    assert costmodel <= defined
-    documented = metric_hygiene.doc_refs(ctx)
-    assert costmodel <= documented
-
-    values = (REPO / "helm" / "values.yaml").read_text()
-    assert "perfLedger:" in values and "costModelDriftBand:" in values
-    values_ci = (REPO / "helm" / "values-ci.yaml").read_text()
-    assert "perfLedger:" in values_ci and "costModelDriftBand:" in values_ci
-    engine_tmpl = (REPO / "helm" / "templates"
-                   / "deployment-engine.yaml").read_text()
-    assert ("--perf-ledger-path" in engine_tmpl
-            and "--perf-ledger-max-bytes" in engine_tmpl
-            and "--perf-ledger-interval" in engine_tmpl
-            and "--costmodel-drift-band" in engine_tmpl)
-
-    # the drift warning rides the episodes counter in both rule copies
-    # (repo-root reference + chart-shipped)
-    for rules in (REPO / "observability" / "alert-rules.yaml",
-                  REPO / "helm" / "rules" / "alert-rules.yaml"):
-        text = rules.read_text()
-        assert "CostModelDrift" in text
-        assert "vllm:costmodel_drift_episodes_total" in text
-
-
 def test_repo_has_no_active_findings():
     report = core.run_passes(
         REPO, baseline_path=REPO / core.BASELINE_DEFAULT)

@@ -376,7 +376,6 @@ def ring_runner():
     ("_ragged", "ragged_step"),
     ("_decode_multi", "decode_multi_step"),
     ("_prefill", "prefill_step"),
-    ("_decode", "decode_step"),
     ("_prefill_ring", "prefill_ring_step"),
 ])
 def test_jitted_programs_carry_their_names(ring_runner, attr, name):

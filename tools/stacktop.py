@@ -26,7 +26,7 @@ import urllib.request
 # single-chip one compare directly in the same table.
 COLUMNS = (
     ("ENGINE", 28), ("MODEL", 14), ("ROLE", 7), ("STATUS", 10), ("CHIPS", 5),
-    ("MFU", 6), ("ICI", 6), ("DRIFT", 8), ("HBM", 12), ("KVFREE", 7),
+    ("MFU", 6), ("ICI", 6), ("HBM", 12), ("KVFREE", 7),
     ("HOSTHIT", 7), ("WAIT", 5), ("RUN", 5), ("QPS", 6), ("TTFT", 7),
     ("TENANT", 14), ("CANARY", 12), ("INCIDENTS", 14),
 )
@@ -90,20 +90,6 @@ def _fmt_top_tenant(row: dict) -> str:
     return f"{name} {rec.get('chip_seconds', 0.0) / total * 100:.0f}%"
 
 
-def _fmt_drift(row: dict) -> str:
-    """Worst-phase cost-model drift ratio (measured/predicted dispatch
-    seconds) from the engine's costmodel block; '!' marks a phase
-    currently outside its configured band. '-' for engines without perf
-    accounting or before the first measured dispatch."""
-    cm = row.get("costmodel") or {}
-    ratios = {p: r for p, r in (cm.get("drift_ratio") or {}).items() if r}
-    if not ratios:
-        return "-"
-    worst = max(ratios.values())
-    flag = "!" if cm.get("out_of_band") else ""
-    return f"{worst:.3g}{flag}"
-
-
 def _fmt_canary(row: dict) -> str:
     """Last canary verdict for this engine's model (worst across its
     models): outcome plus the observed L-infinity logit error; '-' for
@@ -132,7 +118,6 @@ def engine_row_cells(row: dict) -> list:
         _fmt_num(row.get("chips"), "d"),
         _fmt_pct(row.get("mfu")),
         _fmt_pct(row.get("ici")),
-        _fmt_drift(row),
         _fmt_hbm(row.get("hbm_used_bytes"), row.get("hbm_total_bytes")),
         _fmt_pct(row.get("kv_free")),
         _fmt_host_hit(row),
@@ -295,78 +280,6 @@ def render_canary(snapshot: dict) -> str:
     return "\n".join(lines)
 
 
-# --history mode: per-cohort perf trend straight from a ledger file —
-# no router needed (the ledger is the durable artifact)
-HISTORY_COLUMNS = (
-    ("WHEN", 19), ("KIND", 8), ("NOTE", 16), ("TOK/S/CHIP", 10),
-    ("MFU", 7), ("DRIFT", 7),
-)
-
-
-def render_history(records: list, skipped: int = 0) -> str:
-    """Pure ledger-records → per-cohort tok/s/chip + MFU trend table
-    (production_stack_tpu/perf_ledger.py schema). Engine snapshots show
-    the windowed phase throughput per chip; bench records show the
-    artifact's headline mark; infra failures show their failure class —
-    a pool outage reads as a dated hole, not a missing cohort."""
-    from production_stack_tpu import perf_ledger as pl
-
-    lines = []
-    cohorts = pl.group_by_cohort(records)
-    for fpid in sorted(cohorts):
-        recs = cohorts[fpid]
-        fp = next((r.get("fingerprint") for r in recs
-                   if r.get("fingerprint")), {}) or {}
-        label = " ".join(str(fp.get(k)) for k in
-                         ("model", "platform", "attention_impl")
-                         if fp.get(k))
-        lines.append(f"cohort {fpid}  {label}  ({len(recs)} record(s))")
-        header = "  ".join(name.ljust(width)
-                           for name, width in HISTORY_COLUMNS)
-        lines.append("  " + header)
-        lines.append("  " + "-" * len(header))
-        for rec in recs:
-            marks = rec.get("marks") or {}
-            when = time.strftime("%Y-%m-%d %H:%M:%S", time.localtime(
-                float(rec.get("ts") or 0)))
-            if rec.get("kind") == pl.BENCH_KIND:
-                note = (rec.get("status", "ok")
-                        if rec.get("status") != "infra_failure"
-                        else rec.get("failure_class", "infra_failure"))
-                tok = marks.get("value_tok_s_chip")
-                mfu = drift = None
-            else:
-                note = rec.get("reason", "-")
-                chips = max(int(marks.get("chips") or 1), 1)
-                tps = (marks.get("prefill_tps") or 0.0) + (
-                    marks.get("decode_tps") or 0.0)
-                tok = tps / chips if tps else None
-                mfu = marks.get("mfu")
-                ratios = {p: r for p, r in (
-                    marks.get("costmodel_drift_ratio") or {}).items() if r}
-                drift = max(ratios.values()) if ratios else None
-            cells = [
-                when, rec.get("kind", "-"), note,
-                ("-" if tok is None else f"{tok:.1f}"),
-                _fmt_pct(mfu),
-                ("-" if drift is None else f"{drift:.3g}"),
-            ]
-            lines.append("  " + "  ".join(
-                _clip(cell, width).ljust(width)
-                for cell, (_, width) in zip(cells, HISTORY_COLUMNS)))
-        good = pl.last_known_good(recs, fpid)
-        if good is not None:
-            age = time.time() - float(good.get("ts") or 0)
-            lines.append(f"  last known good: {good.get('kind')} "
-                         f"{age / 3600:.1f}h ago")
-        lines.append("")
-    if not cohorts:
-        lines.append("(no ledger records)")
-    if skipped:
-        lines.append(f"({skipped} corrupt line(s) skipped)")
-    return "\n".join(lines).rstrip()
-
-
 def fetch_fleet(router: str, timeout: float = 10.0) -> dict:
     url = router.rstrip("/") + "/debug/fleet"
     with urllib.request.urlopen(url, timeout=timeout) as resp:
@@ -390,22 +303,7 @@ def main(argv=None) -> int:
                    help="correctness-canary table (per-model golden "
                         "version, probe age, drift verdicts) instead "
                         "of the engine table")
-    p.add_argument("--history", default="", metavar="LEDGER",
-                   help="render the per-cohort tok/s/chip + MFU trend "
-                        "from a perf-ledger JSONL file instead of "
-                        "querying the router")
     args = p.parse_args(argv)
-
-    if args.history:
-        import pathlib
-        repo = str(pathlib.Path(__file__).resolve().parent.parent)
-        if repo not in sys.path:
-            sys.path.insert(0, repo)
-        from production_stack_tpu import perf_ledger as pl
-
-        records, skipped = pl.read_records(args.history)
-        print(render_history(records, skipped))
-        return 0
 
     while True:
         try:
