@@ -13,7 +13,8 @@ Phases, each of which must pass:
              three Pallas kernels of the serving path (ragged attention,
              batched paged decode, DMA-ring KV write) COMPILED on the chip
              against the XLA references in ops/paged_attention.py, at the
-             model's per-shard serving geometry.
+             model's per-shard serving geometry; then, a child each, at
+             the geometries of the benchmark's cells (CELL_GEOMETRIES).
 2. engine    ``python -m production_stack_tpu.engine.server`` with server
              defaults (warm-up on); waits for ``/ready``.
 3. router    ``python -m production_stack_tpu.router.app`` in front.
@@ -34,6 +35,7 @@ from __future__ import annotations
 
 import argparse
 import concurrent.futures
+import functools
 import json
 import os
 import re
@@ -53,6 +55,15 @@ READY_TIMEOUT_S = 900.0
 # One bf16 rounding is 2^-9 relative; the kernels also round the softmax
 # weights to bf16 inside the MXU. A wrong mask or block shows as O(1).
 KERNEL_TOL = 1e-2
+# (KH, G, environment) of the benchmark's configurations on one chip, head
+# size 128: qwen3-8b-l16 with the libtpu flag its manifest sets
+# (chipbench/configs/qwen3-8b-l16/manifest.json engine_env: the ragged
+# kernel asks for more scoped VMEM than the default at KH=8, G=4), and
+# olmoe-1b-7b-l8 under the defaults
+CELL_GEOMETRIES = (
+    (8, 4, {"LIBTPU_INIT_ARGS": "--xla_tpu_scoped_vmem_limit_kib=32768"}),
+    (16, 1, {}),
+)
 
 
 class SmokeFailure(Exception):
@@ -130,16 +141,7 @@ def _kernel_cases(KH: int, G: int, D: int, bs: int, dtype):
             t[s, :nb] = rng.integers(1, N, nb)
         return t
 
-    def ragged():
-        # FUZZ_CASES of tests/test_ragged_attention.py scaled to serving
-        # tiles: a mid-prompt chunk across three q-tiles, decode rows,
-        # empty slots, a fresh whole-prompt chunk, a verify-shaped span,
-        # spans straddling tile edges, and a padded tail tile
-        q_lens = [300, 0, 1, 1, 130, 5, 1, 0, 64, 1, 0, 0, 1, 7, 0, 0]
-        ctxs = [700, 0, 513, 17, 130, 260, 1, 0, 1088, 128, 0, 0, 300, 7,
-                0, 0]
-        T = 640  # 5 q-tiles of 128; live tokens end inside the 4th
-        M = 72
+    def ragged(q_lens, ctxs, T, M):
         tables = tables_for(ctxs, M)
         cu = np.zeros(len(q_lens) + 1, np.int32)
         cu[1:] = np.cumsum(q_lens)
@@ -226,13 +228,31 @@ def _kernel_cases(KH: int, G: int, D: int, bs: int, dtype):
                          np.asarray(want.astype(jnp.float32)))
         return err, f"T={T} skipped={int((slots < 0).sum())}"
 
-    return [("ragged_paged_attention", ragged),
+    # FUZZ_CASES of tests/test_ragged_attention.py scaled to serving
+    # tiles: a mid-prompt chunk across three q-tiles, decode rows, empty
+    # slots, a fresh whole-prompt chunk, a verify-shaped span, spans
+    # straddling tile edges, and a padded tail tile (5 q-tiles of 128;
+    # live tokens end inside the 4th)
+    mixed = functools.partial(
+        ragged, [300, 0, 1, 1, 130, 5, 1, 0, 64, 1, 0, 0, 1, 7, 0, 0],
+        [700, 0, 513, 17, 130, 260, 1, 0, 1088, 128, 0, 0, 300, 7, 0, 0],
+        640, 72)
+    # a decode-heavy cell's ragged step: 64 one-token spans at contexts
+    # 300-1000 (the kernel's narrow row block) and one 160-token prompt
+    # (its full tile), in the server's 2048-token stream
+    decode_mix = functools.partial(
+        ragged, [1] * 64 + [160],
+        [int(c) for c in np.linspace(300, 1000, 64)] + [160], 2048, 64)
+    return [("ragged_paged_attention", mixed),
+            ("ragged_paged_attention.decode_mix", decode_mix),
             ("paged_decode_attention", decode),
             ("kv_cache_write", kv_write)]
 
 
-def child_check(model: str, tp: int) -> int:
-    """Report the device, then check the kernels compiled on it."""
+def child_check(model: str, tp: int, geometry: str | None = None) -> int:
+    """Report the device, then check the kernels compiled on it, at the
+    model's per-shard geometry or at ``geometry`` ("KH,G", head size
+    128)."""
     t0 = time.monotonic()
     from production_stack_tpu.compile_cache import configure_compile_cache
 
@@ -250,11 +270,15 @@ def child_check(model: str, tp: int) -> int:
 
     from production_stack_tpu.engine.config import CacheConfig, ModelConfig
 
-    cfg = ModelConfig.from_pretrained(model)
-    KH, G = cfg.num_kv_heads // tp, cfg.q_per_kv
+    if geometry:
+        KH, G = (int(x) for x in geometry.split(","))
+        D = 128
+    else:
+        cfg = ModelConfig.from_pretrained(model)
+        KH, G, D = cfg.num_kv_heads // tp, cfg.q_per_kv, cfg.head_dim
     bs = CacheConfig().block_size
     results, ok = [], True
-    for name, thunk in _kernel_cases(KH, G, cfg.head_dim, bs, jnp.bfloat16):
+    for name, thunk in _kernel_cases(KH, G, D, bs, jnp.bfloat16):
         t = time.monotonic()
         try:
             err, detail = thunk()
@@ -272,7 +296,7 @@ def child_check(model: str, tp: int) -> int:
             results.append({"kernel": name, "ok": False, "error": tb})
             print(f"--- {name} FAILED ---\n{tb}", file=sys.stderr, flush=True)
         ok = ok and passed
-    print(json.dumps({"geometry": {"KH": KH, "G": G, "D": cfg.head_dim,
+    print(json.dumps({"geometry": {"KH": KH, "G": G, "D": D,
                                    "bs": bs, "dtype": "bfloat16"},
                       "kernels": results}), flush=True)
     return 0 if ok else 1
@@ -314,15 +338,16 @@ def _tail(path: str, n: int = 60) -> str:
 class Child:
     """A subprocess in its own process group, logging to a file."""
 
-    def __init__(self, name: str, argv: list, log_dir: str):
+    def __init__(self, name: str, argv: list, log_dir: str, env=None):
         self.name = name
         self.log = os.path.join(log_dir, f"{name}.log")
         self._fh = open(self.log, "w")
         # environment passed through unchanged (JAX_COMPILATION_CACHE_DIR,
-        # JAX_PLATFORMS and TPU_* reach the children as the caller set them)
+        # JAX_PLATFORMS and TPU_* reach the children as the caller set
+        # them), with ``env`` laid over it
         self.proc = subprocess.Popen(
             argv, cwd=HERE, stdout=self._fh, stderr=subprocess.STDOUT,
-            start_new_session=True)
+            start_new_session=True, env={**os.environ, **(env or {})})
 
     def alive(self) -> bool:
         return self.proc.poll() is None
@@ -359,13 +384,17 @@ class Child:
         self._fh.close()
 
 
-def _run_child_mode(mode: str, args, log_dir: str, timeout: float) -> list:
+def _run_child_mode(mode: str, args, log_dir: str, timeout: float,
+                    geometry: str | None = None, env=None) -> list:
     """Run this script in a child mode to completion; return the JSON
     objects it printed. Raises SmokeFailure on non-zero exit."""
     argv = [sys.executable, os.path.abspath(__file__), "--child", mode,
             "--model", args.model,
             "--tensor-parallel-size", str(args.tensor_parallel_size)]
-    child = Child(mode, argv, log_dir)
+    if geometry:
+        argv += ["--geometry", geometry]
+    child = Child(f"{mode}-{geometry}" if geometry else mode, argv, log_dir,
+                  env)
     try:
         try:
             rc = child.proc.wait(timeout=timeout)
@@ -551,13 +580,18 @@ def run(args, log_dir: str) -> dict:
         t = time.monotonic()
         out = _run_child_mode("check", args, log_dir, timeout=600.0)
         device = next(o["device"] for o in out if "device" in o)
-        kernels = next(o for o in out if "kernels" in o)
-        phases["kernels_s"] = round(time.monotonic() - t, 1)
         print(f"device: {json.dumps(device)}")
-        print(f"kernel geometry: {json.dumps(kernels['geometry'])}")
-        for k in kernels["kernels"]:
-            print(f"  {k['kernel']}: scaled_err {k['scaled_err']:.3g} "
-                  f"(tol {k['tol']}) {k['detail']} [{k['seconds']}s]")
+        checks = [next(o for o in out if "kernels" in o)]
+        for KH, G, env in CELL_GEOMETRIES:
+            out = _run_child_mode("check", args, log_dir, timeout=600.0,
+                                  geometry=f"{KH},{G}", env=env)
+            checks.append(next(o for o in out if "kernels" in o))
+        phases["kernels_s"] = round(time.monotonic() - t, 1)
+        for kernels in checks:
+            print(f"kernel geometry: {json.dumps(kernels['geometry'])}")
+            for k in kernels["kernels"]:
+                print(f"  {k['kernel']}: scaled_err {k['scaled_err']:.3g} "
+                      f"(tol {k['tol']}) {k['detail']} [{k['seconds']}s]")
         if device["count"] < tp:
             raise SmokeFailure(
                 f"--tensor-parallel-size {tp} needs {tp} devices, the "
@@ -633,9 +667,11 @@ def main() -> int:
                         "temporary directory, removed on exit)")
     p.add_argument("--child", choices=["check", "probe"], default=None,
                    help=argparse.SUPPRESS)
+    p.add_argument("--geometry", default=None, help=argparse.SUPPRESS)
     args = p.parse_args()
     if args.child == "check":
-        return child_check(args.model, args.tensor_parallel_size)
+        return child_check(args.model, args.tensor_parallel_size,
+                           args.geometry)
     if args.child == "probe":
         return child_probe()
     # a terminated parent must still stop its children (they have their

@@ -31,6 +31,9 @@ from production_stack_tpu.engine.sequence import (
 )
 from production_stack_tpu.engine.tokenizer import get_tokenizer
 from production_stack_tpu.engine.tracing import StepClock
+from production_stack_tpu.ops.ragged_paged_attention_pallas import (
+    count_walks,
+)
 from production_stack_tpu.parallel.mesh import build_mesh
 from production_stack_tpu.tenancy import split_shares
 
@@ -251,6 +254,11 @@ class LLMEngine:
         # padding-waste signal the bucketed path hid in bucket geometry
         self.ragged_dispatches = 0
         self.ragged_live_tokens = 0
+        # (tile, span) context walks the ragged attention kernel makes for
+        # the dispatched span offsets, and those it runs on its narrow row
+        # block (ops/ragged_paged_attention_pallas.count_walks)
+        self.ragged_attn_walks = 0
+        self.ragged_attn_narrow_walks = 0
         self.decode_dispatches = 0  # decode_multi dispatches
         # where a decode-only step's already resolved outputs go before
         # the thread blocks on the decode program (`_hand_over`): the
@@ -1120,6 +1128,9 @@ class LLMEngine:
             self._attribute_seq_seconds(dispatch_s, t_entries)
         self.ragged_dispatches += 1
         self.ragged_live_tokens += cu
+        walks, narrow = count_walks(self._r_cu, T, self.config.model.q_per_kv)
+        self.ragged_attn_walks += walks
+        self.ragged_attn_narrow_walks += narrow
 
         # scheduler-visible state advances NOW; results land next step
         # (same deferral contract as _run_prefill / chained decode). A spec
@@ -1696,6 +1707,8 @@ class LLMEngine:
             # vllm:ragged_* series)
             "ragged_dispatches_total": self.ragged_dispatches,
             "ragged_live_tokens_total": self.ragged_live_tokens,
+            "ragged_attn_walks_total": self.ragged_attn_walks,
+            "ragged_attn_narrow_walks_total": self.ragged_attn_narrow_walks,
             "decode_dispatches_total": self.decode_dispatches,
             "early_handovers_total": self.early_handovers,
             "step_phases": self.clock.snapshot(),

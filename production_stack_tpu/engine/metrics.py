@@ -163,6 +163,18 @@ class EngineStatsCollector:
             s.get("ragged_live_tokens_total", 0),
         )
         yield counter(
+            "vllm:ragged_attn_walks",
+            "(tile, span) context walks of the ragged attention kernel "
+            "over the dispatched span offsets",
+            s.get("ragged_attn_walks_total", 0),
+        )
+        yield counter(
+            "vllm:ragged_attn_narrow_walks",
+            "Walks whose rows fit the kernel's narrow row block (decode "
+            "rows, verify spans, short chunk heads and tails)",
+            s.get("ragged_attn_narrow_walks_total", 0),
+        )
+        yield counter(
             "vllm:decode_dispatches",
             "decode_multi dispatches issued (decode-only steps)",
             s.get("decode_dispatches_total", 0),

@@ -1863,16 +1863,23 @@ class EngineServer:
         step_phases = self.engine.clock.snapshot()
         # hand-overs made before a wait (vllm:engine_early_handovers_total)
         handovers = self.engine.early_handovers
+        # the ragged attention kernel's walks, and those on its narrow
+        # row block (vllm:ragged_attn_walks_total, ..._narrow_walks_total)
+        walks = {"ragged_attn_walks": self.engine.ragged_attn_walks,
+                 "ragged_attn_narrow_walks":
+                     self.engine.ragged_attn_narrow_walks}
         if perf is None:
             return web.json_response({"enabled": False,
                                       "kv_transfer": kv_block,
                                       "kv_tier": tier_block,
                                       "step_phases": step_phases,
                                       "early_handovers": handovers,
+                                      **walks,
                                       "tenants": self.engine.tenant_stats()})
         snap = perf.snapshot()
         snap["step_phases"] = step_phases
         snap["early_handovers"] = handovers
+        snap.update(walks)
         eng = self.engine
         drafted = getattr(eng, "spec_drafted", 0)
         steps = getattr(eng, "spec_steps", 0)

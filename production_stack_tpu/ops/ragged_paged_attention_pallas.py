@@ -23,6 +23,15 @@ packed token stream directly ("Ragged Paged Attention", PAPERS.md):
   on the tile's causal reach (the roofline's over-read fix), causal
   masking within the ragged span, NaN-safe V zeroing past the reach.
 
+A walk computes on the rows its span owns, not on the whole tile: where
+those rows fit ``ROW_BLOCK`` (a decode row, a verify span, the short head
+or tail of a chunk that straddles a tile) the QK, softmax update and PV
+run on the aligned ``ROW_BLOCK``-row block that covers them, and on all
+``q_tile * G`` rows otherwise (``narrow_walk`` decides, from the span
+offsets alone). The flash state lives in VMEM scratch, head-major, so a
+walk reads and writes just its block; rows of the block that belong to a
+neighbouring span stay masked as everywhere else.
+
 There are no padding lanes between spans and no shape buckets: the only
 compile-relevant shape is the budget-padded ``T`` (tokens the scheduler
 may batch) and the fixed ``S`` slot count, so the steady-state engine
@@ -42,10 +51,55 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+Q_TILE = 128  # stream tokens a grid step owns
+# Rows a narrow walk computes on: a one-token span at any G <= 16 and a
+# 1 + 4 verify span at G = 4 (20 rows from a multiple of 4) fit it at any
+# offset. The MXU streams these rows past each (128 x 128) key tile, so
+# 32 costs little more than 8 (chosen on the chip, PERF.md section 6).
+ROW_BLOCK = 32
+# a narrow block starts at a multiple of the bf16 sublane tile, so the
+# query rows load without a relayout
+ROW_ALIGN = 16
+
+
+def narrow_walk(lo, hi, group: int, rows: int, xp=jnp):
+    """(narrow, r0) for a walk that owns stream tokens ``[lo, hi)`` of its
+    tile (tile-relative): whether its rows ``[lo * group, hi * group)`` fit
+    the ``ROW_BLOCK``-row block starting at row ``r0``, a multiple of
+    ``ROW_ALIGN`` inside the tile's ``rows``. The kernel calls it on SMEM
+    scalars, ``count_walks`` on numpy arrays (``xp=np``). ``narrow`` is the
+    Python ``False`` where the tile has no narrow block at all."""
+    if rows <= ROW_BLOCK or rows % ROW_ALIGN:
+        return False, 0
+    r0 = xp.minimum(lo * group // ROW_ALIGN * ROW_ALIGN, rows - ROW_BLOCK)
+    return hi * group - r0 <= ROW_BLOCK, r0
+
+
+def count_walks(cu_q_lens, stream_tokens: int, group: int,
+                q_tile: int = Q_TILE) -> tuple[int, int]:
+    """(walks, narrow walks) of one dispatch, on the host: the non-empty
+    (tile, span) pairs the kernel walks for these span offsets, and how
+    many of them meet ``narrow_walk``."""
+    cu = np.asarray(cu_q_lens, np.int64)
+    tq = min(q_tile, stream_tokens)
+    start, end = cu[:-1], cu[1:]
+    live = end > start
+    start, end = start[live], end[live]
+    first = start // tq
+    per_span = (end - 1) // tq - first + 1  # tiles a span overlaps
+    span = np.repeat(np.arange(len(start)), per_span)
+    # a walk's tile: its span's first one plus its place in the span's run
+    tile = first[span] + np.arange(len(span)) - np.repeat(
+        np.cumsum(per_span) - per_span, per_span)
+    lo = np.maximum(start[span], tile * tq) - tile * tq
+    hi = np.minimum(end[span], (tile + 1) * tq) - tile * tq
+    narrow, _ = narrow_walk(lo, hi, group, tq * group, xp=np)
+    return len(span), int(np.sum(narrow))
 
 
 def _ragged_kernel(
@@ -57,13 +111,16 @@ def _ragged_kernel(
     tcnt_ref,  # (nt,) SMEM — sequences overlapping each tile
     layer_ref,  # (1,) SMEM
     # inputs
-    q_ref,  # (1, R, KH, D) VMEM — R = q_tile*G rows of this tile
+    q_ref,  # (1, KH, R, D) VMEM — R = q_tile*G rows of this tile
     kv_hbm,  # (L, N, bs, 2KH, D) ANY
     # outputs
-    o_ref,  # (1, R, KH, D) VMEM
+    o_ref,  # (1, KH, R, D) VMEM
     # scratch
     buf,  # (2, W, bs, 2KH, D) VMEM
     sems,  # (2, W) DMA sems
+    m_ref,  # (KH, R, 1) f32 VMEM — flash running max
+    l_ref,  # (KH, R, 1) f32 VMEM — flash running sum
+    acc_ref,  # (KH, R, D) f32 VMEM — flash accumulator
     *,
     block_size: int,
     windows: int,
@@ -77,35 +134,31 @@ def _ragged_kernel(
     W = windows
     bs = block_size
     win_tokens = W * bs
-    _, R, KH, D = q_ref.shape
+    _, KH, R, D = q_ref.shape
     TQ = q_tile
     first = tfirst_ref[t]
     cnt = tcnt_ref[t]
+    tile0 = t * TQ
 
-    q = q_ref[0].astype(jnp.float32)  # (R, KH, D)
-    # row r is stream token g = t*TQ + r//G (rows ordered (token, g))
-    g_idx = t * TQ + jax.lax.broadcasted_iota(
-        jnp.int32, (1, R, 1), 1
-    ) // group  # (1, R, 1)
+    # ONE flash-softmax state per tile, persisting ACROSS its sequences:
+    # each row belongs to exactly one span, and rows outside the current
+    # span get explicit zero probability (see the masked-p note below), so
+    # foreign sequences never move a row's (m, l, acc)
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    def seq_body(si, carry):
+    def seq_body(si, _):
         """Walk one sequence's paged context for the rows it owns in this
-        tile. The flash carry persists ACROSS sequences: each row belongs
-        to exactly one span, and rows outside the current span get
-        explicit zero probability (see the masked-p note below), so
-        foreign sequences never move a row's (m, l, acc)."""
+        tile."""
         s = first + si
         q_start = cu_ref[s]
         q_end = cu_ref[s + 1]
         ctx = cl_ref[s]
         q_len = q_end - q_start
-        row_in = (g_idx >= q_start) & (g_idx < q_end)  # (1, R, 1)
-        # absolute position of each owned query token; garbage elsewhere
-        # (masked by row_in)
-        qpos = ctx - q_len + (g_idx - q_start)
         # causal reach of this sequence's LAST token in this tile — the
         # per-block DMA predicate, so the tail over-read stays one block
-        last_g = jnp.minimum(q_end, (t + 1) * TQ) - 1
+        last_g = jnp.minimum(q_end, tile0 + TQ) - 1
         reach = jnp.minimum(ctx, ctx - q_len + (last_g - q_start) + 1)
         # empty spans (inactive slots, seqs not in this step) skip the
         # whole context walk
@@ -127,81 +180,108 @@ def _ragged_kernel(
                 def _():
                     dma(slot, w, j).start()
 
-        @pl.when(nwin > 0)
-        def _():
+        def walk(rows, r0):
+            """Stream the context past tile rows [r0, r0 + rows): the
+            whole tile, or the block that covers a short span."""
+            rs = pl.ds(r0, rows)
+            q = q_ref[0, :, rs, :].astype(jnp.float32)  # (KH, rows, D)
+            # row r is stream token g = t*TQ + r//G (rows ordered
+            # (token, g))
+            g_idx = tile0 + (r0 + jax.lax.broadcasted_iota(
+                jnp.int32, (1, rows, 1), 1
+            )) // group  # (1, rows, 1)
+            row_in = (g_idx >= q_start) & (g_idx < q_end)
+            # absolute position of each owned query token; garbage
+            # elsewhere (masked by row_in)
+            qpos = ctx - q_len + (g_idx - q_start)
+
             issue(0, 0)
 
-        def win_body(w, carry2):
-            m, l, acc = carry2
-            slot = jax.lax.rem(w, 2)
+            def win_body(w, _):
+                m, l = m_ref[:, rs, :], l_ref[:, rs, :]
+                slot = jax.lax.rem(w, 2)
 
-            @pl.when(w + 1 < nwin)
-            def _():
-                issue(jax.lax.rem(w + 1, 2), w + 1)
-
-            for j in range(W):
-                @pl.when(block_active(w, j))
+                @pl.when(w + 1 < nwin)
                 def _():
-                    dma(slot, w, j).wait()
+                    issue(jax.lax.rem(w + 1, 2), w + 1)
 
-            kv = buf[slot].reshape(win_tokens, 2 * KH, D)
-            s_heads = []
-            for h in range(KH):
-                k_h = kv[:, h, :].astype(jnp.float32)  # (T, D)
-                s_heads.append(
-                    jax.lax.dot_general(
-                        q[:, h, :], k_h, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
-                )  # (R, T)
-            sc = jnp.stack(s_heads) * scale  # (KH, R, T)
-            if soft_cap:  # Gemma-2 score capping, before masking
-                sc = soft_cap * jnp.tanh(sc / soft_cap)
-            kvpos = w * win_tokens + jax.lax.broadcasted_iota(
-                jnp.int32, (1, 1, win_tokens), 2
-            )
-            valid = row_in & (kvpos <= qpos) & (kvpos < ctx)  # (1, R, T)
-            sc = jnp.where(valid, sc, NEG_INF)
+                for j in range(W):
+                    @pl.when(block_active(w, j))
+                    def _():
+                        dma(slot, w, j).wait()
 
-            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            # masked-p: a row NOT owned by this sequence has every score
-            # at NEG_INF. If that row is still untouched (m == NEG_INF),
-            # exp(sc - m_new) = exp(0) = 1 would inflate its l by T per
-            # window — so invalid lanes are zeroed EXPLICITLY rather than
-            # through the exp underflow the bucketed kernels rely on.
-            p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
-            l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            # blocks past `reach` were never DMA'd: zero their V rows —
-            # 0 x NaN = NaN would poison the accumulator through
-            # masked-out weights
-            vvalid = (w * win_tokens + jax.lax.broadcasted_iota(
-                jnp.int32, (win_tokens, 1), 0) < reach)
-            acc_heads = []
-            for h in range(KH):
-                v_h = jnp.where(
-                    vvalid, kv[:, KH + h, :].astype(jnp.float32), 0.0
+                kv = buf[slot].reshape(win_tokens, 2 * KH, D)
+                s_heads = []
+                for h in range(KH):
+                    k_h = kv[:, h, :].astype(jnp.float32)  # (T, D)
+                    s_heads.append(
+                        jax.lax.dot_general(
+                            q[h], k_h, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        )
+                    )  # (rows, T)
+                sc = jnp.stack(s_heads) * scale  # (KH, rows, T)
+                if soft_cap:  # Gemma-2 score capping, before masking
+                    sc = soft_cap * jnp.tanh(sc / soft_cap)
+                kvpos = w * win_tokens + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, 1, win_tokens), 2
                 )
-                acc_heads.append(
-                    jax.lax.dot_general(
-                        p[h], v_h, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
+                valid = row_in & (kvpos <= qpos) & (kvpos < ctx)
+                sc = jnp.where(valid, sc, NEG_INF)
+
+                m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                # masked-p: a row NOT owned by this sequence has every
+                # score at NEG_INF. If that row is still untouched (m ==
+                # NEG_INF), exp(sc - m_new) = exp(0) = 1 would inflate its
+                # l by T per window — so invalid lanes are zeroed
+                # EXPLICITLY rather than through the exp underflow the
+                # bucketed kernels rely on.
+                p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+                l_ref[:, rs, :] = l * alpha + jnp.sum(
+                    p, axis=-1, keepdims=True)
+                m_ref[:, rs, :] = m_new
+                # blocks past `reach` were never DMA'd: zero their V rows
+                # — 0 x NaN = NaN would poison the accumulator through
+                # masked-out weights
+                vvalid = (w * win_tokens + jax.lax.broadcasted_iota(
+                    jnp.int32, (win_tokens, 1), 0) < reach)
+                acc_heads = []
+                for h in range(KH):
+                    v_h = jnp.where(
+                        vvalid, kv[:, KH + h, :].astype(jnp.float32), 0.0
                     )
-                )  # (R, D)
-            acc_new = acc * alpha + jnp.stack(acc_heads)
-            return m_new, l_new, acc_new
+                    acc_heads.append(
+                        jax.lax.dot_general(
+                            p[h], v_h, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        )
+                    )  # (rows, D)
+                acc_ref[:, rs, :] = (
+                    acc_ref[:, rs, :] * alpha + jnp.stack(acc_heads))
+                return 0
 
-        return jax.lax.fori_loop(0, nwin, win_body, carry)
+            jax.lax.fori_loop(0, nwin, win_body, 0)
 
-    init = (
-        jnp.full((KH, R, 1), NEG_INF, jnp.float32),
-        jnp.zeros((KH, R, 1), jnp.float32),
-        jnp.zeros((KH, R, D), jnp.float32),
-    )
-    m, l, acc = jax.lax.fori_loop(0, cnt, seq_body, init)
+        # the rows this span owns in the tile decide the block it pays for
+        narrow, r0 = narrow_walk(
+            jnp.maximum(q_start, tile0) - tile0,
+            jnp.minimum(q_end, tile0 + TQ) - tile0, group, R,
+        )
+        live = nwin > 0
+        if narrow is False:  # a tile of at most ROW_BLOCK rows
+            pl.when(live)(lambda: walk(R, 0))
+        else:
+            pl.when(live & narrow)(
+                lambda: walk(ROW_BLOCK, pl.multiple_of(r0, ROW_ALIGN)))
+            pl.when(live & jnp.logical_not(narrow))(lambda: walk(R, 0))
+        return 0
+
+    jax.lax.fori_loop(0, cnt, seq_body, 0)
     # rows owned by no sequence (tail padding) kept l = 0 → output 0
-    out = acc / jnp.maximum(l, 1e-30)  # (KH, R, D)
-    o_ref[0] = out.transpose(1, 0, 2).astype(o_ref.dtype)
+    o_ref[0] = (
+        acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    ).astype(o_ref.dtype)
 
 
 def tile_metadata(
@@ -237,7 +317,7 @@ def ragged_paged_attention_pallas(
     cu_q_lens: jnp.ndarray,  # (S+1,) int32 cumulative span offsets
     context_lens: jnp.ndarray,  # (S,) int32 total context per slot
     layer_idx: jnp.ndarray | int = 0,
-    q_tile: int = 128,
+    q_tile: int = Q_TILE,
     windows: int = 8,
     interpret: bool = False,
     soft_cap: float = 0.0,
@@ -254,24 +334,28 @@ def ragged_paged_attention_pallas(
     R = TQ * G
 
     tfirst, tcnt = tile_metadata(cu_q_lens, nt, TQ)
-    # rows ordered (token, g): (Tp, H, D) -> (nt, TQ*G, KH, D)
+    # head-major, rows ordered (token, g): (Tp, H, D) -> (nt, KH, TQ*G, D)
     q_rows = (
-        q.reshape(Tp, KH, G, D).transpose(0, 2, 1, 3).reshape(nt, R, KH, D)
+        q.reshape(nt, TQ, KH, G, D).transpose(0, 2, 1, 3, 4)
+        .reshape(nt, KH, R, D)
     )
     layer_arr = jnp.asarray(layer_idx, jnp.int32).reshape(1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(nt,),
         in_specs=[
-            pl.BlockSpec((1, R, KH, D), lambda t, *_: (t, 0, 0, 0),
+            pl.BlockSpec((1, KH, R, D), lambda t, *_: (t, 0, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, R, KH, D), lambda t, *_: (t, 0, 0, 0),
+        out_specs=pl.BlockSpec((1, KH, R, D), lambda t, *_: (t, 0, 0, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
             pltpu.VMEM((2, windows, bs, KH2, D), kv_cache.dtype),
             pltpu.SemaphoreType.DMA((2, windows)),
+            pltpu.VMEM((KH, R, 1), jnp.float32),
+            pltpu.VMEM((KH, R, 1), jnp.float32),
+            pltpu.VMEM((KH, R, D), jnp.float32),
         ],
     )
     kernel = functools.partial(
@@ -280,7 +364,7 @@ def ragged_paged_attention_pallas(
     )
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((nt, R, KH, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((nt, KH, R, D), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
         name="ragged_paged_attention",
@@ -296,5 +380,6 @@ def ragged_paged_attention_pallas(
     )
     # rows (token, g) back to (T, H, D) with h = kh*G + g
     return (
-        out.reshape(Tp, G, KH, D).transpose(0, 2, 1, 3).reshape(Tp, H, D)[:T]
+        out.reshape(nt, KH, TQ, G, D).transpose(0, 2, 1, 3, 4)
+        .reshape(Tp, H, D)[:T]
     )

@@ -41,6 +41,9 @@ from production_stack_tpu.ops.paged_attention import (
     write_kv,
 )
 from production_stack_tpu.ops.ragged_paged_attention_pallas import (
+    ROW_BLOCK,
+    count_walks,
+    narrow_walk,
     ragged_paged_attention_pallas,
     tile_metadata,
 )
@@ -78,10 +81,11 @@ def test_tile_metadata_one_span_many_tiles():
 
 # ---- kernel parity fuzz ---------------------------------------------------
 
-def _build_ragged_case(rng, q_lens, ctx_lens, M, num_blocks=64):
+def _build_ragged_case(rng, q_lens, ctx_lens, M, num_blocks=64, G=H // KH):
     """Scatter per-slot contexts into a fused cache; return everything the
     two ragged implementations and the padded reference need."""
     S = len(q_lens)
+    H = KH * G
     cache = jnp.zeros((L, num_blocks, BS, 2 * KH, D), jnp.float32)
     tables = np.zeros((S, M), np.int32)
     next_block = 1  # keep block 0 as the shared pad target
@@ -138,12 +142,130 @@ FUZZ_CASES = [
 ]
 
 
-@pytest.mark.parametrize("case", range(len(FUZZ_CASES)))
+def _row_block_cases():
+    """Spans around the kernel's row block (``ROW_BLOCK`` rows, chosen per
+    (tile, span) by ``narrow_walk``) at G = 1, 3, 4: (id, G, q_tile,
+    q_lens, ctx_lens, narrow walks expected, soft cap). Tiles are wider
+    than the block, so both paths run; contexts reach over several
+    8-token windows."""
+    cases = []
+    for G, tq in ((1, 128), (3, 32), (4, 32)):
+        fit = ROW_BLOCK // G  # tokens of the largest narrow span
+        cases += [
+            # a span of exactly the block (narrow) beside one a token
+            # longer (full), both from an aligned row
+            (f"g{G}-block-exact", G, tq, [fit, tq - fit, fit + 1],
+             [fit + 9, tq - fit, fit + 20], 1, 0.0),
+            # one-token spans as first and as last row of a tile, a chunk
+            # between them (full), the last row's neighbour in tile 1
+            (f"g{G}-first-last-row", G, tq, [1, tq - 2, 1, 1],
+             [17, tq + 5, 30, 9], 3, 0.0),
+            # a chunk whose first tile is full and whose last 1-3 tokens
+            # fall in the next tile (narrow), decode rows on both sides:
+            # their flash state must come through both walks unharmed
+            (f"g{G}-chunk-tail", G, tq, [1, tq + 1, 1, 1],
+             [12, tq + 14, 21, 5], 4, 0.0),
+            (f"g{G}-chunk-tail3", G, tq, [2, tq + 1, 1],
+             [2, tq + 1, 26], 3, 0.0),
+            # a 1 + 4 verify span, empty spans between live ones; the
+            # chunk behind them leaves 10 tokens to the next tile
+            (f"g{G}-verify-empties", G, tq, [1, 0, 5, 0, 0, 1, 3, tq],
+             [19, 0, 23, 0, 0, 8, 11, tq + 2],
+             4 + (10 * G <= ROW_BLOCK), 0.0),
+            # Gemma-2's score cap through both paths
+            (f"g{G}-softcap", G, tq, [1, tq, 5, 1],
+             [27, tq + 3, 16, 14], 4, 5.0),
+        ]
+    # 128 one-token spans filling a serving-size tile
+    cases.append(("g4-128-decode-rows", 4, 128, [1] * 128,
+                  [1 + (7 * i) % 23 for i in range(128)], 128, 0.0))
+    cases.append(("g1-128-decode-rows", 1, 128, [1] * 128,
+                  [1 + (5 * i) % 19 for i in range(128)], 128, 0.0))
+    return cases
+
+
+ROW_BLOCK_CASES = _row_block_cases()
+
+
+@pytest.mark.parametrize(
+    "case", list(range(len(FUZZ_CASES)))
+    + [pytest.param(c, id=c[0]) for c in ROW_BLOCK_CASES])
 def test_ragged_pallas_matches_reference(case):
-    q_lens, ctx_lens, M = FUZZ_CASES[case]
-    rng = np.random.default_rng(case)
+    if isinstance(case, int):
+        q_lens, ctx_lens, M = FUZZ_CASES[case]
+        G, tq, narrow, cap, seed = H // KH, 8, 0, 0.0, case
+    else:
+        _, G, tq, q_lens, ctx_lens, narrow, cap = case
+        M = max(-(-c // BS) for c in ctx_lens)
+        seed = len(q_lens) + G
+    rng = np.random.default_rng(seed)
     cache, tables, cu, q, seq_ids, q_pos, _ = _build_ragged_case(
-        rng, q_lens, ctx_lens, M
+        rng, q_lens, ctx_lens, M,
+        num_blocks=2 + sum(-(-c // BS) for c in ctx_lens), G=G,
+    )
+    # the case runs the path it was written for
+    assert count_walks(cu, int(cu[-1]), G, q_tile=tq)[1] == narrow
+    want = ragged_paged_attention(
+        jnp.asarray(q), cache[1], jnp.asarray(tables),
+        jnp.asarray(ctx_lens, jnp.int32), jnp.asarray(seq_ids),
+        jnp.asarray(q_pos), soft_cap=cap,
+    )
+    got = ragged_paged_attention_pallas(
+        jnp.asarray(q), cache, jnp.asarray(tables),
+        jnp.asarray(cu), jnp.asarray(ctx_lens, jnp.int32),
+        layer_idx=1, q_tile=tq, windows=2, interpret=True, soft_cap=cap,
+    )
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
+    )
+
+
+@pytest.mark.parametrize("G,tq", [(1, 128), (3, 32), (4, 32), (4, 128)])
+def test_count_walks_equals_brute_force(G, tq):
+    """The engine's host-side count (``vllm:ragged_attn_walks_total`` and
+    ``..._narrow_walks_total``) is the kernel's own iteration: every
+    non-empty (tile, span) overlap, judged by the kernel's predicate."""
+    rng = np.random.default_rng(G * 1000 + tq)
+    for draw in range(20):
+        S = int(rng.integers(1, 40))
+        q_lens = rng.choice([0, 1, 1, 1, 5, 8, 9, 33, 100, 300], S)
+        cu = np.concatenate([[0], np.cumsum(q_lens)]).astype(np.int32)
+        T = max(int(cu[-1]), 1) + int(rng.integers(0, 50))
+        TQ = min(tq, T)
+        walks = narrow = 0
+        first, cnt = (np.asarray(a) for a in tile_metadata(
+            jnp.asarray(cu), -(-T // TQ), TQ)) if not draw else (None, None)
+        for t in range(-(-T // TQ)):
+            for s in range(S):  # every (tile, span) pair, no metadata
+                lo = max(cu[s], t * TQ) - t * TQ
+                hi = min(cu[s + 1], (t + 1) * TQ) - t * TQ
+                if hi > lo:  # the span owns rows of this tile
+                    walks += 1
+                    # ... and the kernel's tile metadata visits the pair
+                    assert draw or first[t] <= s < first[t] + cnt[t]
+                    narrow += bool(narrow_walk(lo, hi, G, TQ * G, xp=np)[0])
+        assert count_walks(cu, T, G, q_tile=tq) == (walks, narrow)
+    # an idle dispatch holds no walk
+    assert count_walks(np.zeros(5, np.int32), 64, G, q_tile=tq) == (0, 0)
+
+
+@pytest.mark.parametrize("G,tq", [(1, 128), (3, 32), (4, 32)])
+@pytest.mark.parametrize("seed", range(2))
+def test_ragged_pallas_row_block_fuzz(seed, G, tq):
+    """The randomized fuzz on tiles wider than the row block: decode rows,
+    verify spans, chunks of any length and empty slots in one stream."""
+    rng = np.random.default_rng(300 + 10 * G + seed)
+    q_lens, ctx_lens = [], []
+    for _ in range(int(rng.integers(6, 14))):
+        kind = rng.integers(0, 5)
+        n = (0, 1, int(rng.integers(2, 7)), int(rng.integers(7, 40)),
+             int(rng.integers(tq - 3, tq + 4)))[kind]
+        q_lens.append(n)
+        ctx_lens.append(n + int(rng.integers(0, 20)) if n else 0)
+    M = max(1, max(-(-c // BS) for c in ctx_lens))
+    cache, tables, cu, q, seq_ids, q_pos, _ = _build_ragged_case(
+        rng, q_lens, ctx_lens, M,
+        num_blocks=2 + sum(-(-c // BS) for c in ctx_lens), G=G,
     )
     want = ragged_paged_attention(
         jnp.asarray(q), cache[1], jnp.asarray(tables),
@@ -153,7 +275,7 @@ def test_ragged_pallas_matches_reference(case):
     got = ragged_paged_attention_pallas(
         jnp.asarray(q), cache, jnp.asarray(tables),
         jnp.asarray(cu), jnp.asarray(ctx_lens, jnp.int32),
-        layer_idx=1, q_tile=8, windows=2, interpret=True,
+        layer_idx=1, q_tile=tq, windows=2, interpret=True,
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4

@@ -393,3 +393,45 @@ def test_named_partial_names_the_compiled_module():
     assert "HloModule jit_toy_step" in text
     bare = jax.jit(functools.partial(_toy_step, 2.0)).lower(x).compile()
     assert "HloModule jit__unknown" in bare.as_text()
+
+
+def test_ragged_attn_walk_counters_follow_the_dispatched_spans():
+    """vllm:ragged_attn_walks_total / ..._narrow_walks_total on /metrics
+    and the same two on /debug/perf: one walk per live span and 64-token
+    tile of the stream, narrow when its rows fit the kernel's row block."""
+    from production_stack_tpu.ops.ragged_paged_attention_pallas import (
+        ROW_BLOCK,
+    )
+    names = ("vllm:ragged_attn_walks_total",
+             "vllm:ragged_attn_narrow_walks_total")
+
+    async def read(client):
+        text = await (await client.get("/metrics")).text()
+        values = [sum(_samples(text, n).values()) for n in names]
+        perf = await (await client.get("/debug/perf")).json()
+        assert [perf["ragged_attn_walks"],
+                perf["ragged_attn_narrow_walks"]] == values
+        return values
+
+    server = EngineServer(make_config(attention_impl="ragged"))
+
+    async def fn(client):
+        eng = server.engine
+        G = eng.config.model.q_per_kv
+        before = await read(client)
+        dispatches = eng.ragged_dispatches
+        for prompt in ("hi", "x" * 40):  # 2 and 40 byte tokens (+ BOS)
+            r = await client.post("/v1/completions", json={
+                "model": "tiny-llama", "prompt": prompt, "max_tokens": 3,
+                "temperature": 0, "ignore_eos": True})
+            assert r.status == 200
+        after = await read(client)
+        # one request at a time: one ragged step of one span each
+        assert eng.ragged_dispatches - dispatches == 2
+        assert after[0] - before[0] == 2
+        spans = [len(eng.tokenizer.encode(p)) for p in
+                 ("hi", "x" * 40)]
+        assert after[1] - before[1] == sum(
+            n * G <= ROW_BLOCK for n in spans) == 1
+
+    asyncio.run(_with_client(server, fn))
