@@ -22,11 +22,12 @@ from production_stack_tpu.parallel.mesh import MeshConfig
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str = "tiny-llama"
-    # "llama" | "mixtral" | "olmoe" | "gemma" | "gemma2" | "phi3" — Mistral
-    # and Qwen run as "llama" (their deltas are knobs: sliding_window,
-    # qkv_bias, qk_norm); "phi3" differs only in its fused HF weight
-    # layout, "mixtral" and "olmoe" in their HF tensor names (the MoE
-    # block itself is chosen by num_experts > 0, see is_moe)
+    # "llama" | "mixtral" | "olmoe" | "gemma" | "gemma2" | "phi3" | "ouro"
+    # — Mistral and Qwen run as "llama" (their deltas are knobs:
+    # sliding_window, qkv_bias, qk_norm); "phi3" differs only in its fused
+    # HF weight layout, "mixtral" and "olmoe" in their HF tensor names
+    # (the MoE block itself is chosen by num_experts > 0, see is_moe),
+    # "ouro" in its tensor names and in loop_passes > 1
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -69,6 +70,18 @@ class ModelConfig:
     # required to be <= sliding_window (enforced at engine init), where
     # local and global attention coincide.
     sliding_window: int = 0
+    # weight-tied stacks ("ouro"): every token passes through the SAME
+    # num_layers layers loop_passes times; the final norm closes each pass
+    # and every (pass, layer) pair attends over keys and values of its
+    # own, so the paged cache holds cache_layers = num_layers x
+    # loop_passes layers where the weights hold num_layers. 1 for every
+    # other family
+    loop_passes: int = 1
+    # carry the residual stream between sublayers in float32 (the matmuls
+    # keep the model dtype). A looped stack adds loop_passes x 2 x
+    # num_layers sublayer outputs to one stream, and each pass feeds the
+    # next: bf16 rounding of the stream is what the passes amplify
+    residual_f32: bool = False
     # Whisper family (architecture == "whisper": encoder-decoder audio
     # transcription, models/whisper.py). num_heads doubles as both
     # encoder and decoder head count (equal in every Whisper size);
@@ -108,6 +121,11 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
+    @property
+    def cache_layers(self) -> int:
+        """Layers of KV cache: one per (pass, layer) pair."""
+        return self.num_layers * self.loop_passes
+
     @staticmethod
     def from_hf_config(cfg: dict[str, Any], name: str = "") -> "ModelConfig":
         """Build from a HuggingFace config.json dict (LlamaForCausalLM /
@@ -127,6 +145,29 @@ class ModelConfig:
                     f"OLMoE with clip_qkv={cfg['clip_qkv']!r} is not "
                     "supported (only clip_qkv: null)")
             arch = "olmoe"
+        elif (any(a == "OuroForCausalLM" for a in archs)
+              or cfg.get("model_type") == "ouro"):
+            # a weight-tied stack run total_ut_steps times. What is served
+            # is every token through every pass, each layer attending over
+            # the whole context: anything else is refused by name rather
+            # than served as another model
+            if float(cfg.get("early_exit_threshold", 1.0)) < 1.0:
+                raise ValueError(
+                    f"Ouro with early_exit_threshold="
+                    f"{cfg['early_exit_threshold']!r} is not supported: the "
+                    "exit gate is not evaluated, every token runs all "
+                    "total_ut_steps passes (only early_exit_threshold >= 1)")
+            other = sorted({t for t in cfg.get("layer_types") or ()
+                            if t != "full_attention"})
+            if other:
+                raise ValueError(
+                    f"Ouro with layer_types {other} is not supported (only "
+                    "full_attention layers)")
+            if cfg.get("use_sliding_window"):
+                raise ValueError(
+                    "Ouro with use_sliding_window: true is not supported "
+                    "(every layer attends over the whole context)")
+            arch = "ouro"
         elif any("Phi3" in a for a in archs):
             # only the standard Phi-3 maps onto the fused-Llama layout;
             # Phi-3-small (query_key_value naming, gegelu, blocksparse)
@@ -226,9 +267,12 @@ class ModelConfig:
                 cfg.get("attn_logit_softcapping") or 0.0),
             final_logit_softcap=float(
                 cfg.get("final_logit_softcapping") or 0.0),
-            post_norms=arch == "gemma2",
+            post_norms=arch in ("gemma2", "ouro"),
             query_scale=(qpas ** -0.5) if qpas else 0.0,
             sliding_window=window,
+            loop_passes=(int(cfg.get("total_ut_steps", 1))
+                         if arch == "ouro" else 1),
+            residual_f32=arch == "ouro",
         )
 
     @staticmethod
@@ -438,6 +482,24 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         num_heads=16, num_kv_heads=16, head_dim=128, rope_theta=10000.0,
         max_model_len=4096, num_experts=64, num_experts_per_tok=8,
         norm_topk_prob=False, qk_norm=True, qk_norm_kind="full",
+    ),
+    "tiny-ouro": ModelConfig(
+        # Ouro's block at test size: MHA, norms before and after each
+        # sublayer, 3 weight-tied layers run 4 times (12 cache layers)
+        name="tiny-ouro", architecture="ouro", vocab_size=512,
+        hidden_size=128, intermediate_size=256, num_layers=3, num_heads=4,
+        num_kv_heads=4, head_dim=32, rope_theta=1000000.0,
+        rms_norm_eps=1e-6, max_model_len=512, post_norms=True,
+        loop_passes=4, residual_f32=True, dtype="float32",
+    ),
+    "ouro-2.6b": ModelConfig(
+        # ByteDance/Ouro-2.6B geometry: 48 weight-tied layers run 4 times
+        # (192 cache layers, 1.57 MB of KV a token), MHA, untied head
+        name="ouro-2.6b", architecture="ouro", vocab_size=49152,
+        hidden_size=2048, intermediate_size=5632, num_layers=48,
+        num_heads=16, num_kv_heads=16, head_dim=128, rope_theta=1000000.0,
+        rms_norm_eps=1e-6, max_model_len=65536, post_norms=True,
+        loop_passes=4, residual_f32=True,
     ),
     "tiny-whisper": ModelConfig(
         # CPU-testable Whisper: 1 s audio window (n_audio_ctx 50 -> 100

@@ -1181,7 +1181,7 @@ class LLMEngine:
         pending = self._pending_ragged
         self._pending_ragged = None
         fetched, fetch_s = self._fetch(pending["result"])
-        fetched = self.runner.take_moe_hist(
+        fetched = self.runner.take_counters(
             tuple(np.asarray(x) for x in fetched))
         if self.perf is not None:
             # the blocking result fetch is dispatch wall time too — billed
@@ -1393,7 +1393,7 @@ class LLMEngine:
         self.decode_dispatches += 1
         pend = {"decodes": list(decodes), "slots": [s.slot for s in decodes]}
         if launches:
-            pend["sampled"], next_tok, pend["moe_hist"], *lp = result
+            pend["sampled"], next_tok, pend["counters"], *lp = result
             pend["lp"] = lp  # empty unless the variant returns logprobs
             if not can_chain:
                 # the program is in flight: the event loop works on what
@@ -1437,16 +1437,16 @@ class LLMEngine:
     def _fetch_decode(self, pending) -> float:
         """Block on a launched decode dispatch and put its results into
         ``pending`` in place of the device arrays: sampled tokens (K, B),
-        the log-probability arrays where the variant returns them, and an
-        MoE model's routing histogram, which goes to its counters.
-        Returns the seconds blocked."""
-        (sampled, moe_hist, *lp), wait_s = self._fetch(
-            (pending["sampled"], pending.get("moe_hist"),
+        the log-probability arrays where the variant returns them, and
+        what an MoE model or a looped stack counted, which goes to the
+        runner's counters. Returns the seconds blocked."""
+        (sampled, counters, *lp), wait_s = self._fetch(
+            (pending["sampled"], pending.get("counters"),
              *pending.get("lp", ())))
         pending["sampled"] = np.asarray(sampled)
         pending["lp"] = [np.asarray(x) for x in lp]
-        if moe_hist is not None:
-            self.runner.moe.record("decode", moe_hist)
+        if counters is not None:
+            self.runner.record_counters("decode", counters)
         return wait_s
 
     def _finish_decode(self, pending,
@@ -1718,9 +1718,10 @@ class LLMEngine:
                       * self.config.scheduler.max_num_batched_tokens)
             ),
         }
-        moe = getattr(self.runner, "moe", None)
-        if moe is not None:
-            out.update(moe.snapshot())
+        for name in ("moe", "loop"):
+            counters = getattr(self.runner, name, None)
+            if counters is not None:
+                out.update(counters.snapshot())
         if self.host_kv is not None:
             out["cpu_cache_usage_perc"] = self.host_kv.usage
             out["cpu_prefix_cache_hits_total"] = self.host_kv.hits

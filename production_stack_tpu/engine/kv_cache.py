@@ -3,10 +3,12 @@
 TPU-first design:
 
 - The device cache is one pytree ``{"k", "v"}`` of shape
-  ``(L, num_blocks, block_size, KH, D)`` living in HBM, KV-heads sharded over
-  the ``tensor`` mesh axis. Block tables and slot mappings are tiny int32
-  host arrays recomputed each step — all device shapes stay static, so the
-  serving step never retraces.
+  ``(L, num_blocks, block_size, KH, D)`` living in HBM (L is
+  ``ModelConfig.cache_layers``: a looped stack keeps a cache layer for
+  every (pass, layer) pair, so L is its weight layers times its passes),
+  KV-heads sharded over the ``tensor`` mesh axis. Block tables and slot
+  mappings are tiny int32 host arrays recomputed each step — all device
+  shapes stay static, so the serving step never retraces.
 - The allocator runs on host Python (control plane, off the hot device path)
   and implements vLLM-style *prefix caching*: full blocks are content-hashed
   by their token chain; a new request reuses any cached prefix blocks
@@ -66,7 +68,7 @@ def init_kv_cache(
     axes = (None, None, None, ln.KV_HEADS, ln.HEAD_DIM)
     sharding = logical_to_sharding(axes, mesh, rules)
     shape = (
-        model.num_layers, n, cache.block_size, 2 * model.num_kv_heads,
+        model.cache_layers, n, cache.block_size, 2 * model.num_kv_heads,
         model.head_dim,
     )
     dt = model.jax_dtype
@@ -85,7 +87,7 @@ def init_kv_cache(
 def kv_cache_bytes_per_block(model: ModelConfig, cache: CacheConfig) -> int:
     itemsize = jnp.dtype(model.jax_dtype).itemsize
     return (
-        2 * model.num_layers * cache.block_size * model.num_kv_heads
+        2 * model.cache_layers * cache.block_size * model.num_kv_heads
         * model.head_dim * itemsize
     )
 

@@ -65,7 +65,8 @@ class FrameDigestError(ValueError):
 
 
 def default_group(num_layers: int) -> int:
-    """Half the stack (two frames): every extra frame costs one more
+    """``num_layers`` here and below: the layers of the KV pool
+    (``ModelConfig.cache_layers``). Half the stack (two frames): every extra frame costs one more
     gather and one more scatter dispatch, while a single frame forfeits
     the consumer-side scatter/read overlap. Two frames is the smallest
     count that keeps the pipeline; not measured on the chip. Deployments

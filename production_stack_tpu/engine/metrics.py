@@ -269,6 +269,22 @@ class EngineStatsCollector:
                 "vllm:moe_decode_experts_touched",
                 s["moe_decode_layer_steps_total"],
             )
+        # looped stacks (engine/tracing.py LoopCounters): exported by
+        # models whose layers run more than once a forward
+        if "loop_layer_passes_total" in s:
+            yield counter(
+                "vllm:loop_layer_passes",
+                "Layer executions of a looped stack (layers x passes run), "
+                "summed over forwards and dispatches",
+                s["loop_layer_passes_total"],
+            )
+            yield counter(
+                "vllm:loop_layer_steps",
+                "Layers x forwards (fused decode iterations and ragged "
+                "steps) of a looped stack: the denominator of "
+                "vllm:loop_layer_passes",
+                s["loop_layer_steps_total"],
+            )
         yield gauge(
             "vllm:ragged_stream_utilization",
             "Cumulative live-token fill of the budget-wide ragged stream "

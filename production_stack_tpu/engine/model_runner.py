@@ -48,7 +48,11 @@ from production_stack_tpu.engine.config import EngineConfig, ModelConfig
 from production_stack_tpu.engine import kv_cache as kvmod
 from production_stack_tpu.engine.quant import maybe_quantize
 from production_stack_tpu.engine.sampling import sample_tokens
-from production_stack_tpu.engine.tracing import MoeCounters, StepClock
+from production_stack_tpu.engine.tracing import (
+    LoopCounters,
+    MoeCounters,
+    StepClock,
+)
 from production_stack_tpu.engine.weights import init_or_load
 from production_stack_tpu.models.registry import get_model
 from production_stack_tpu.ops.paged_attention import (
@@ -197,6 +201,13 @@ class ModelRunner:
         self.moe = (MoeCounters(self.cfg.num_experts,
                                 self.cfg.num_experts_per_tok)
                     if self.cfg.is_moe else None)
+        # a looped stack's step programs return the passes they made, one
+        # int32 a forward, after the histogram: same fetch, same route
+        self.loop = (LoopCounters(self.cfg.num_layers)
+                     if self.cfg.loop_passes > 1 else None)
+        # what `step.launch` says of such a stack in a trace
+        self._launch_attrs = ({"passes": self.cfg.loop_passes}
+                              if self.loop is not None else {})
         with jax.set_mesh(mesh):
             self.params = maybe_quantize(
                 self.cfg,
@@ -676,7 +687,7 @@ class ModelRunner:
                     if use_grammar else None
                 ),
             )
-            self.clock.launch()
+            self.clock.launch(**self._launch_attrs)
             self.kv, result = self._prefill(
                 self.params, self.kv, *args, **kwargs,
                 greedy_only=greedy_only,
@@ -714,7 +725,7 @@ class ModelRunner:
                 ctrl=(tuple(jnp.asarray(c) for c in ctrl)
                       if ctrl is not None else None),
             )
-            self.clock.launch()
+            self.clock.launch(**self._launch_attrs)
             self.kv, result = self._prefill_ring(
                 self.params, self.kv, *args, **kwargs,
                 greedy_only=greedy_only,
@@ -767,14 +778,16 @@ class ModelRunner:
                      want_logprobs: bool = False):
         """Launch multi_step fused decode+sample iterations and return
         without waiting for them: ``(sampled (num_steps, B), next_tok,
-        moe_hist[, tok_lp (K, B), ids (K, B, N), lps (K, B, N)])``, all
+        counters[, tok_lp (K, B), ids (K, B, N), lps (K, B, N)])``, all
         still on the device, so whatever the caller does before it fetches
         (hand over what it has resolved, launch the next dispatch)
-        overlaps this one's compute. ``moe_hist`` is the routing histogram
-        of an MoE model, else None; the caller fetches it with the sampled
-        tokens. ``tokens_dev`` feeds the batch's input tokens straight
-        from the previous dispatch's device-resident ``next_tok`` (no host
-        round trip between chained dispatches). ``greedy_only`` selects
+        overlaps this one's compute. ``counters`` is (the routing
+        histogram of an MoE model, the passes a looped stack made), None
+        where the model has no such thing; the caller fetches them with
+        the sampled tokens and hands them to ``record_counters``.
+        ``tokens_dev`` feeds the batch's input tokens straight from the
+        previous dispatch's device-resident ``next_tok`` (no host round
+        trip between chained dispatches). ``greedy_only`` selects
         the argmax-only compiled variant; presence/frequency arrays
         activate the penalised variant (counts tracked on device);
         ``want_logprobs`` the variant that also returns log-probabilities.
@@ -795,7 +808,7 @@ class ModelRunner:
             opt = self._optional_inputs(presence, frequency, adapter_ids,
                                         ctrl, g_ids, g_states)
             packed = self._commit(buf)
-            self.clock.launch()
+            self.clock.launch(**self._launch_attrs)
             (self.kv, new_counts), (sampled, next_tok, *lp) = self._decode_multi(
                 self.params, self.kv, packed, tokens_dev, **opt,
                 layout=layout,
@@ -805,9 +818,7 @@ class ModelRunner:
             )
         if opt["use_penalties"]:
             self.token_counts = new_counts
-        # an MoE model's routing histogram is the last leaf
-        moe_hist = lp.pop() if self.moe is not None else None
-        return (sampled, next_tok, moe_hist, *lp)
+        return (sampled, next_tok, self._split_counters(lp), *lp)
 
     def ragged_step(self, tokens, positions, block_tables, context_lens,
                     cu_q_lens, slot_mapping, last_idx, sample_mask,
@@ -835,10 +846,10 @@ class ModelRunner:
         positions (clamped/zero for rows with fewer or no drafts) and the
         result tuple gains the greedy argmax at those positions,
         (S, spec_width), right after ``sampled``; an MoE model appends its
-        routing histogram (L, X + 1) as the last leaf, which
-        ``take_moe_hist`` takes off a fetched tuple. verify_idx rides EVERY
-        dispatch so verify-bearing steps share the one steady-state
-        signature with plain ones.
+        routing histogram (L, X + 1) and a looped stack its pass count as
+        the last leaves, which ``take_counters`` takes off a fetched
+        tuple. verify_idx rides EVERY dispatch so verify-bearing steps
+        share the one steady-state signature with plain ones.
 
         Returns (sampled (S,)[, verify (S, W)], tok_lp (S,),
         top_ids (S, N), top_lps (S, N)) on host — or the un-fetched
@@ -880,7 +891,7 @@ class ModelRunner:
             opt = self._optional_inputs(presence, frequency, adapter_ids,
                                         ctrl, g_ids, g_states)
             packed = self._commit(buf)
-            self.clock.launch()
+            self.clock.launch(**self._launch_attrs)
             (self.kv, new_counts), result = self._ragged(
                 self.params, self.kv, packed, **opt,
                 layout=layout, greedy_only=greedy_only,
@@ -890,17 +901,35 @@ class ModelRunner:
         if not fetch:
             return result
         self.clock.enter("wait")
-        return self.take_moe_hist(
+        return self.take_counters(
             tuple(np.asarray(x) for x in jax.device_get(result)))
 
-    def take_moe_hist(self, fetched: tuple) -> tuple:
-        """A ragged step's results on the host without the routing
-        histogram an MoE model appends (counted here); any other model's
+    def _split_counters(self, leaves: list) -> tuple:
+        """Take what the model's step programs append to their results off
+        the end of ``leaves``: (an MoE model's routing histogram, a looped
+        stack's pass count), None where the model has no such thing."""
+        passes = leaves.pop() if self.loop is not None else None
+        moe_hist = leaves.pop() if self.moe is not None else None
+        return moe_hist, passes
+
+    def record_counters(self, kind: str, counters: tuple) -> None:
+        """Fold one dispatch's fetched ``_split_counters`` pair into the
+        always-on counters (engine/tracing.py)."""
+        moe_hist, passes = counters
+        if moe_hist is not None:
+            self.moe.record(kind, moe_hist)
+        if passes is not None:
+            self.loop.record(passes)
+
+    def take_counters(self, fetched: tuple) -> tuple:
+        """A ragged step's results on the host without the counters an MoE
+        model or a looped stack appends (recorded here); any other model's
         results as they are."""
-        if self.moe is None:
+        if self.moe is None and self.loop is None:
             return fetched
-        self.moe.record("ragged", fetched[-1])
-        return fetched[:-1]
+        leaves = list(fetched)
+        self.record_counters("ragged", self._split_counters(leaves))
+        return tuple(leaves)
 
     # -- sleep mode hooks ----------------------------------------------------
     def drop_kv(self) -> None:
@@ -1423,11 +1452,12 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
             )
 
         # idle slots stay out of an MoE model's routing, whose per-layer
-        # histogram joins the results
+        # histogram joins the results, as a looped stack's pass count does
         hidden, kv, *moe_hist = model.forward_tokens(
             cfg, params, tok[:, None], pos[:, None], attend, kv,
             lora=_make_lora(lora_bank, adapter_ids, 1),
             live=active[:, None], moe_hist=cfg.is_moe,
+            loop_count=cfg.loop_passes > 1,
         )
         logits = model.logits_from_hidden(cfg, params, hidden)[:, 0]
         raw_logits = logits  # logprobs report the raw model distribution
@@ -1500,8 +1530,8 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
     # would cost extra dispatches on the chained-decode hot path
     next_tok = sampled[-1][:, None]  # (B, 1) input for a chained dispatch
     # sampled: (num_steps, B); lp (when requested): tok_lp (K, B),
-    # top_ids (K, B, N), top_lps (K, B, N); an MoE model's routing
-    # histogram (K, L, X + 1) last
+    # top_ids (K, B, N), top_lps (K, B, N); then an MoE model's routing
+    # histogram (K, L, X + 1), then a looped stack's pass counts (K,)
     return (kv, counts), (sampled, next_tok, *lp)
 
 
@@ -1540,7 +1570,8 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
 
     Returns ((new_kv, new_counts),
     (sampled (S,)[, verify (S, spec_width)], tok_lp, ids, lps[, an MoE
-    model's routing histogram (L, X + 1)]))."""
+    model's routing histogram (L, X + 1)][, a looped stack's pass
+    count]))."""
     from production_stack_tpu.engine.sampling import (
         compute_logprobs,
         sample_tokens,
@@ -1564,10 +1595,11 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
         onehot = jax.nn.one_hot(adapter_ids, N, dtype=jnp.float32)[None]
         lora = {"onehot": onehot, "bank": lora_bank}
     # an MoE model keeps the stream's padding (position -1) out of its
-    # routing and returns a per-layer histogram, which joins the results
+    # routing and returns a per-layer histogram, which joins the results,
+    # as a looped stack's pass count does
     hidden, new_kv, *moe_hist = model.forward_tokens(
         cfg, params, tokens, positions, attend, kv, lora=lora,
-        moe_hist=cfg.is_moe,
+        moe_hist=cfg.is_moe, loop_count=cfg.loop_passes > 1,
     )
     last_hidden = jnp.take(hidden[0], last_idx, axis=0)  # (S, E)
     logits = model.logits_from_hidden(cfg, params, last_hidden[:, None])[:, 0]

@@ -60,9 +60,8 @@ _log = logging.getLogger(__name__)
 _EVENT_TAIL = 64  # compile events kept verbatim for /debug/perf
 
 
-def estimate_param_count(model_cfg) -> int:
-    """Llama-geometry parameter count from config — the fallback when the
-    runner's param tree isn't addressable (staged pipeline runner)."""
+def _stack_param_count(model_cfg) -> int:
+    """Matrices of the decoder layers, Llama geometry, from config."""
     h = model_cfg.hidden_size
     inter = model_cfg.intermediate_size
     qkv = (h * model_cfg.num_heads * model_cfg.head_dim
@@ -71,8 +70,14 @@ def estimate_param_count(model_cfg) -> int:
     # every expert: this is what is HELD; what a token multiplies with is
     # PerfAccountant.active_param_count
     mlp = 3 * h * inter * max(getattr(model_cfg, "num_experts", 0) or 1, 1)
-    return int(2 * model_cfg.vocab_size * h
-               + model_cfg.num_layers * (qkv + mlp))
+    return int(model_cfg.num_layers * (qkv + mlp))
+
+
+def estimate_param_count(model_cfg) -> int:
+    """Llama-geometry parameter count from config — the fallback when the
+    runner's param tree isn't addressable (staged pipeline runner)."""
+    return int(2 * model_cfg.vocab_size * model_cfg.hidden_size
+               + _stack_param_count(model_cfg))
 
 
 def _ratio(rate: float, peak: float) -> Optional[float]:
@@ -185,9 +190,20 @@ class PerfAccountant:
         self._route_share = X and cfg.num_experts_per_tok / X
         self.active_param_count = self.param_count * (
             1.0 - self._expert_share * (1.0 - self._route_share))
-        self._attn_per_tok_ctx = (4 * cfg.num_layers * cfg.num_heads
+        # a looped stack (loop_passes = U > 1) multiplies every token with
+        # its layers' matrices once a pass and reads them once a pass,
+        # U - 1 times more than they are held; and it attends over, and
+        # keeps, keys and values for every (pass, layer) pair
+        self.loop_passes = int(getattr(cfg, "loop_passes", 1))
+        self.cache_layers = cfg.num_layers * self.loop_passes
+        repeats = ((self.loop_passes - 1) * _stack_param_count(cfg)
+                   if self.loop_passes > 1 else 0)
+        self.active_param_count += repeats
+        self._loop_extra_bytes = (repeats * self.param_bytes
+                                  / self.param_count)
+        self._attn_per_tok_ctx = (4 * self.cache_layers * cfg.num_heads
                                   * cfg.head_dim)
-        self._kv_bytes_per_tok = (2 * cfg.num_layers * cfg.num_kv_heads
+        self._kv_bytes_per_tok = (2 * self.cache_layers * cfg.num_kv_heads
                                   * cfg.head_dim * _dtype_bytes(cfg.dtype))
         # ICI cost model (docs/roofline.md "Multi-chip"), zero at tp=1:
         # each layer's two row-parallel matmuls (attention out-proj, MLP
@@ -198,7 +214,7 @@ class PerfAccountant:
         # columns) pays an all-gather of (tp-1)/tp x vocab f32 per chip.
         ar_fac = 2.0 * (self.tp - 1) / self.tp
         ag_fac = (self.tp - 1) / self.tp
-        self._ar_bytes_per_tok = (2 * cfg.num_layers * cfg.hidden_size
+        self._ar_bytes_per_tok = (2 * self.cache_layers * cfg.hidden_size
                                   * _dtype_bytes(cfg.dtype) * ar_fac)
         self._ag_bytes_per_row = cfg.vocab_size * 4 * ag_fac
         self._lock = threading.Lock()
@@ -334,9 +350,9 @@ class PerfAccountant:
     def _weight_bytes(self, tokens: int) -> float:
         """Weight bytes a dispatch over ``tokens`` live tokens reads."""
         if not self._expert_share:
-            return self.param_bytes
+            return self.param_bytes + self._loop_extra_bytes
         touched = 1.0 - (1.0 - self._route_share) ** max(tokens, 0)
-        return self.param_bytes * (
+        return self._loop_extra_bytes + self.param_bytes * (
             1.0 - self._expert_share * (1.0 - touched))
 
     def record_prefill(self, live_tokens: int, ctx_tokens: int,
@@ -714,7 +730,10 @@ class PerfAccountant:
                           "hbm_bytes_per_s": self.peak_hbm,
                           "ici_bytes_per_s": self.peak_ici},
                 "model": {"param_count": self.param_count,
-                          "param_bytes": self.param_bytes},
+                          "param_bytes": self.param_bytes,
+                          "loop_passes": self.loop_passes,
+                          "cache_layers": self.cache_layers,
+                          "kv_bytes_per_token": self._kv_bytes_per_tok},
                 "model_flops_utilization": rates["mfu"],
                 "hbm_bandwidth_utilization": rates["hbm_bw_util"],
                 "ici_bandwidth_utilization": rates["ici_bw_util"],

@@ -70,6 +70,14 @@ class StagedModelRunner:
         self.mesh = mesh
         S = mesh.shape[AXIS_STAGE]
         assert S > 1, "StagedModelRunner requires a stage axis > 1"
+        if self.cfg.loop_passes > 1:
+            # the stages slice the layers once; a looped stack would have
+            # to cycle the activations through them loop_passes times
+            raise ValueError(
+                f"{self.cfg.name}: loop_passes={self.cfg.loop_passes} is "
+                "not supported with pipeline stages "
+                f"(--pipeline-parallel-size {S}); serve a looped stack "
+                "without a stage axis")
         L = self.cfg.num_layers
         assert L % S == 0, f"{L} layers not divisible by {S} stages"
         self.n_stages = S

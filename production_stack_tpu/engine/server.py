@@ -1445,10 +1445,10 @@ class EngineServer:
             cfg = self.config
             group = max(1, min(
                 int(body.get("group_layers")
-                    or default_group(cfg.model.num_layers)),
-                cfg.model.num_layers,
+                    or default_group(cfg.model.cache_layers)),
+                cfg.model.cache_layers,
             ))
-            shape = (cfg.model.num_layers, len(blocks),
+            shape = (cfg.model.cache_layers, len(blocks),
                      cfg.cache.block_size, 2 * cfg.model.num_kv_heads,
                      cfg.model.head_dim)
             resp = web.StreamResponse(headers={
@@ -1468,7 +1468,7 @@ class EngineServer:
                 await resp.prepare(request)
                 async for frame in produce_frames(
                     self.async_engine.run_on_engine, blocks,
-                    cfg.model.num_layers, group,
+                    cfg.model.cache_layers, group,
                 ):
                     await resp.write(frame)
                 await resp.write_eof()
@@ -1684,7 +1684,7 @@ class EngineServer:
         from production_stack_tpu.engine.kv_transfer import push_kv
 
         cfg = self.config
-        shape = (cfg.model.num_layers, len(blocks), cfg.cache.block_size,
+        shape = (cfg.model.cache_layers, len(blocks), cfg.cache.block_size,
                  2 * cfg.model.num_kv_heads, cfg.model.head_dim)
         dtype = str(cfg.model.dtype)
         meta = {"transfer_id": transfer_id,

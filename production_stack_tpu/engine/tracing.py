@@ -221,8 +221,10 @@ class StepClock:
         self.kind = kind
         self._launch = {"kind": kind, "rows": rows, "tokens": tokens}
 
-    def launch(self) -> float:
-        return self.enter("launch", **self._launch)
+    def launch(self, **attrs) -> float:
+        """``attrs``: what the runner adds to the annotation (a looped
+        stack's ``passes``)."""
+        return self.enter("launch", **self._launch, **attrs)
 
     def end_step(self) -> float:
         """Charge what collected since the last flush to the step's kind
@@ -255,6 +257,34 @@ class StepClock:
                             for p, (w, c) in by_phase.items()}
                         for k, by_phase in self.seconds.items()},
         }
+
+
+# -- looped stacks ------------------------------------------------------------
+
+class LoopCounters:
+    """What a looped stack (``ModelConfig.loop_passes`` > 1) ran, always
+    on. The step programs of such a model return the number of passes
+    their stack made, the outer scan's own carry (``models/llama.py``
+    ``forward_hidden``), one int32 per forward; it comes to the host in
+    the fetch of the step's own results, like the MoE histogram below.
+    ``layer_passes`` over ``layer_steps`` is the passes a layer ran per
+    forward: the witness that none was left out."""
+
+    def __init__(self, num_layers: int):
+        self.num_layers = num_layers
+        self.layer_passes = 0  # layer executions: layers x passes run
+        self.layer_steps = 0   # layers x forwards (fused iterations)
+
+    def record(self, passes) -> None:
+        """``passes``: integers on the host, one per forward (fused decode
+        iteration) of one dispatch."""
+        p = np.asarray(passes, np.int64).reshape(-1)
+        self.layer_passes += self.num_layers * int(p.sum())
+        self.layer_steps += self.num_layers * len(p)
+
+    def snapshot(self) -> dict:
+        return {"loop_layer_passes_total": self.layer_passes,
+                "loop_layer_steps_total": self.layer_steps}
 
 
 # -- MoE routing counters -----------------------------------------------------

@@ -138,7 +138,15 @@ def _hf_key_map(cfg: ModelConfig, i: int) -> dict:
             "q_norm", "bias_q" if full else "copy")
         m[f"model.layers.{i}.self_attn.k_norm.weight"] = (
             "k_norm", "bias_kv" if full else "copy")
-    if cfg.post_norms:
+    if cfg.architecture == "ouro":
+        # the norms AFTER each sublayer carry a "_2" (names from the
+        # model's published implementation, from memory); the norms before
+        # them keep the Llama names set above
+        m[f"model.layers.{i}.input_layernorm_2.weight"] = (
+            "post_attn_norm", "copy")
+        m[f"model.layers.{i}.post_attention_layernorm_2.weight"] = (
+            "post_mlp_norm", "copy")
+    elif cfg.post_norms:
         # Gemma-2 block: HF "post_attention_layernorm" is the norm on the
         # ATTENTION OUTPUT (our post_attn_norm); the pre-MLP norm is
         # "pre_feedforward_layernorm" and the MLP output norm
@@ -294,6 +302,30 @@ def _load_whisper_safetensors(cfg: ModelConfig, mesh: Mesh,
     }
 
 
+def _check_ouro_names(cfg: ModelConfig, index: dict) -> None:
+    """Ouro's tensor names are written down from memory (see _hf_key_map):
+    a checkpoint that holds a tensor this loader does not know, or lacks
+    one it expects, fails here and is not served with a layer missing.
+    The exit gate is known and not loaded: it is not evaluated (at the
+    published early_exit_threshold of 1 it selects nothing)."""
+    known = {"model.embed_tokens.weight", "model.norm.weight",
+             "lm_head.weight", "model.early_exit_gate.weight",
+             "model.early_exit_gate.bias"}
+    for i in range(cfg.num_layers):
+        known.update(_hf_key_map(cfg, i))
+    unknown = sorted(k for k in index
+                     if k not in known and not k.endswith("rotary_emb.inv_freq"))
+    optional = {"model.early_exit_gate.weight", "model.early_exit_gate.bias"}
+    if cfg.tie_word_embeddings:
+        optional.add("lm_head.weight")
+    missing = sorted(known - set(index) - optional)
+    if unknown or missing:
+        raise ValueError(
+            f"Ouro checkpoint {cfg.weights_path}: tensor names this loader "
+            f"does not know {unknown[:8]}, names it expects and did not "
+            f"find {missing[:8]}")
+
+
 def load_safetensors(cfg: ModelConfig, mesh: Mesh, rules: ShardingRules) -> dict:
     from safetensors import safe_open
 
@@ -324,6 +356,8 @@ def load_safetensors(cfg: ModelConfig, mesh: Mesh, rules: ShardingRules) -> dict
             jnp.asarray(arr, dtype=dt), logical_to_sharding(axes, mesh, rules)
         )
 
+    if cfg.architecture == "ouro":
+        _check_ouro_names(cfg, index)
     params: dict = {
         "embed": put(get("model.embed_tokens.weight"), specs["embed"]),
         "final_norm": put(get("model.norm.weight"), specs["final_norm"]),

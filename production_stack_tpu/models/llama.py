@@ -117,6 +117,21 @@ def param_specs(cfg: ModelConfig) -> dict:
     return specs
 
 
+# Random stand-in weights of a looped stack (cfg.loop_passes > 1): the gain
+# of the norms AFTER each sublayer. Every pass renormalises the stream to
+# unit size and then adds 2 x num_layers sublayer outputs to it; at gain 1
+# each of those is as large as the stream, and a random stack iterated on
+# its own output is then a chaotic map (measured: four passes multiply a
+# bf16 rounding error ~2.4x a pass, past any tolerance that still tells a
+# fault from rounding; PERF.md section 6, PR 31). At 0.05 a 48-layer pass
+# still moves the stream by half its size (sqrt(96) x 0.05), so a pass
+# left out or fed the wrong cache reads three times over the benchmark's
+# limits (tests/test_ouro.py), and the iteration is stable, as a trained
+# looped model's is under its own: bf16 reads 0.03-0.04 / 0.009 against
+# the float32 reference on the chip, under half of them.
+LOOPED_POST_NORM_GAIN = 0.05
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     """Random-init parameters (tests / synthetic benchmarks; real weights come
     from safetensors via engine/weights.py)."""
@@ -166,10 +181,11 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         )
     if cfg.post_norms:
         # Gemma stores zero-centred norm weights (forward adds norm_offset)
+        gain = LOOPED_POST_NORM_GAIN if cfg.loop_passes > 1 else 1.0
         layers.update(
             {
-                "post_attn_norm": jnp.full((Ln, E), 1.0 - cfg.norm_offset, dt),
-                "post_mlp_norm": jnp.full((Ln, E), 1.0 - cfg.norm_offset, dt),
+                "post_attn_norm": jnp.full((Ln, E), gain - cfg.norm_offset, dt),
+                "post_mlp_norm": jnp.full((Ln, E), gain - cfg.norm_offset, dt),
             }
         )
     if cfg.is_moe:
@@ -343,11 +359,12 @@ def forward_tokens(
     lora: Any = None,
     live: Optional[jnp.ndarray] = None,
     moe_hist: bool = False,
+    loop_count: bool = False,
 ) -> Tuple[jnp.ndarray, Any]:
     """Embed tokens then run the decoder stack (see forward_hidden)."""
     x = embed_tokens(cfg, params, tokens)
     return forward_hidden(cfg, params, x, positions, attend, kv_caches, lora,
-                          live, moe_hist)
+                          live, moe_hist, loop_count)
 
 
 def embed_tokens(cfg: ModelConfig, params: dict, tokens: jnp.ndarray) -> jnp.ndarray:
@@ -369,6 +386,7 @@ def forward_hidden(
     lora: Any = None,
     live: Optional[jnp.ndarray] = None,
     moe_hist: bool = False,
+    loop_count: bool = False,
 ) -> Tuple[jnp.ndarray, Any]:
     """Run the decoder stack from pre-embedded activations.
 
@@ -390,6 +408,14 @@ def forward_hidden(
     routing. Returns (hidden (..., T, E), new_kv_caches), and with
     ``moe_hist`` a third value: the MoE block's per-layer routing
     histogram (L, X + 1), see _moe_mlp.
+
+    A looped stack (``cfg.loop_passes`` = U > 1) runs the scan over the
+    SAME stacked weights U times, an outer scan around the one over
+    layers: pass u's layer l writes and reads cache layer u * L + l, and
+    the final norm closes every pass (the last pass's is
+    ``logits_from_hidden``'s). ``loop_count`` appends the number of
+    passes run, int32, the outer scan's own carry; the histogram then has
+    a leading pass axis.
     """
     layers, experts = params["layers"], None
     if cfg.is_moe:
@@ -399,12 +425,18 @@ def forward_hidden(
         experts = {k: layers[k] for k in _EXPERT_WEIGHTS}
         layers = {k: v for k, v in layers.items() if k not in experts}
     onehot = None if lora is None else lora["onehot"].astype(cfg.jax_dtype)
+    if cfg.residual_f32:
+        x = x.astype(jnp.float32)
 
-    def layer_fn(carry, scanned):
+    def pre_norm(h, weight):
+        normed = rms_norm(h, weight, cfg.rms_norm_eps, cfg.norm_offset)
+        # a float32 stream feeds the matmuls in the model dtype
+        return normed.astype(cfg.jax_dtype) if cfg.residual_f32 else normed
+
+    def layer_fn(carry, scanned, first_cache_layer=None):
         h, layer_idx, caches = carry
         lp, lb = scanned  # layer params, per-layer lora bank (or None)
-        normed = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps,
-                          cfg.norm_offset)
+        normed = pre_norm(h, lp["attn_norm"])
         q = quant_einsum("...te,ehd->...thd", normed, lp["wq"])
         k = quant_einsum("...te,ehd->...thd", normed, lp["wk"])
         v = quant_einsum("...te,ehd->...thd", normed, lp["wv"])
@@ -434,7 +466,10 @@ def forward_hidden(
             )
         q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
-        attn, caches = attend(q, k, v, caches, layer_idx)
+        attn, caches = attend(
+            q, k, v, caches,
+            layer_idx if first_cache_layer is None
+            else first_cache_layer + layer_idx)
         o = quant_einsum("...thd,hde->...te", attn, lp["wo"])
         if lb is not None and "wo" in lb:
             flat = attn.reshape(*attn.shape[:-2], -1)  # (..., T, H*D)
@@ -443,8 +478,7 @@ def forward_hidden(
             o = rms_norm(o, lp["post_attn_norm"], cfg.rms_norm_eps,
                          cfg.norm_offset)
         h = h + o
-        normed2 = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps,
-                           cfg.norm_offset)
+        normed2 = pre_norm(h, lp["mlp_norm"])
         hist = None
         if cfg.is_moe:  # LoRA on MoE experts: not supported yet
             with jax.named_scope("moe"):
@@ -459,17 +493,45 @@ def forward_hidden(
         return (h, layer_idx + 1, caches), hist
 
     bank = None if lora is None else lora["bank"]
-    (x, _, new_caches), hists = lax.scan(
-        layer_fn, (x, jnp.int32(0), kv_caches), (layers, bank)
-    )
+    if cfg.loop_passes == 1:
+        (x, _, new_caches), hists = lax.scan(
+            layer_fn, (x, jnp.int32(0), kv_caches), (layers, bank)
+        )
+        passes = jnp.int32(1)
+    else:
+        def pass_fn(carry, u):
+            h, passes, caches = carry
+            # the final norm closes a pass before the next one opens. The
+            # pass index comes in as a scanned value, not as the carried
+            # count: with `where(count > 0, ...)` on the carry the TPU
+            # compiler (jax 0.9.0, v5e) built a program whose second pass
+            # already read wrongly, in float32 too, where the CPU's was
+            # right to the last bit (PERF.md section 6, PR 31)
+            h = jnp.where(u > 0,
+                          rms_norm(h, params["final_norm"], cfg.rms_norm_eps,
+                                   cfg.norm_offset), h)
+            (h, _, caches), hists = lax.scan(
+                functools.partial(layer_fn,
+                                  first_cache_layer=u * cfg.num_layers),
+                (h, jnp.int32(0), caches), (layers, bank))
+            return (h, passes + 1, caches), hists
+
+        (x, passes, new_caches), hists = lax.scan(
+            pass_fn, (x, jnp.int32(0), kv_caches),
+            jnp.arange(cfg.loop_passes, dtype=jnp.int32))
+    out = (x, new_caches)
     if moe_hist:
-        return x, new_caches, hists
-    return x, new_caches
+        out += (hists,)
+    if loop_count:
+        out += (passes,)
+    return out
 
 
 def logits_from_hidden(cfg: ModelConfig, params: dict, hidden: jnp.ndarray) -> jnp.ndarray:
     hidden = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps,
                       cfg.norm_offset)
+    if cfg.residual_f32:
+        hidden = hidden.astype(cfg.jax_dtype)
     head = (head_from_embed(params["embed"]) if cfg.tie_word_embeddings
             else params["lm_head"])
     if not is_quantized(head):

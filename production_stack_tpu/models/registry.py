@@ -27,6 +27,9 @@ _REGISTRY: dict[str, ModuleType] = {
     # Phi-3 is the Llama stack too; only its HF checkpoint layout differs
     # (fused qkv_proj / gate_up_proj, split at load in engine/weights.py)
     "phi3": llama,
+    # Ouro: the shared stack run cfg.loop_passes times over the same
+    # weights, with Gemma-2's norms after each sublayer
+    "ouro": llama,
     # encoder-decoder audio transcription: exposes its own forward
     # surface (encode/cross_kv/decode_tokens) instead of the decoder-only
     # protocol; shares param_specs/init_params so weights.py works
