@@ -260,6 +260,12 @@ class LLMEngine:
         self.ragged_attn_walks = 0
         self.ragged_attn_narrow_walks = 0
         self.decode_dispatches = 0  # decode_multi dispatches
+        # attention calls those dispatches made (fused iterations x cache
+        # layers each), and those that ran the Pallas decode kernel's slab
+        # body (ops/paged_attention_pallas.decode_slab_path, decided by
+        # the runner for its per-shard geometry)
+        self.decode_attn_calls = 0
+        self.decode_attn_slab_calls = 0
         # where a decode-only step's already resolved outputs go before
         # the thread blocks on the decode program (`_hand_over`): the
         # async worker sets it; None means step() returns everything
@@ -1391,6 +1397,10 @@ class LLMEngine:
         )
         dispatch_s = self.clock.enter("postprocess") - t_call
         self.decode_dispatches += 1
+        attn_calls = K * self.config.model.cache_layers
+        self.decode_attn_calls += attn_calls
+        if getattr(self.runner, "decode_attn_slab", False):
+            self.decode_attn_slab_calls += attn_calls
         pend = {"decodes": list(decodes), "slots": [s.slot for s in decodes]}
         if launches:
             pend["sampled"], next_tok, pend["counters"], *lp = result
@@ -1710,6 +1720,8 @@ class LLMEngine:
             "ragged_attn_walks_total": self.ragged_attn_walks,
             "ragged_attn_narrow_walks_total": self.ragged_attn_narrow_walks,
             "decode_dispatches_total": self.decode_dispatches,
+            "decode_attn_calls_total": self.decode_attn_calls,
+            "decode_attn_slab_calls_total": self.decode_attn_slab_calls,
             "early_handovers_total": self.early_handovers,
             "step_phases": self.clock.snapshot(),
             "ragged_stream_utilization": (

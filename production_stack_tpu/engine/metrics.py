@@ -180,6 +180,18 @@ class EngineStatsCollector:
             s.get("decode_dispatches_total", 0),
         )
         yield counter(
+            "vllm:decode_attn_calls",
+            "Attention calls of the decode dispatches (fused iterations x "
+            "cache layers a dispatch)",
+            s.get("decode_attn_calls_total", 0),
+        )
+        yield counter(
+            "vllm:decode_attn_slab_calls",
+            "Those that ran the Pallas decode kernel's slab body (one "
+            "query row a KV head, bf16 cache, 128-wide heads)",
+            s.get("decode_attn_slab_calls_total", 0),
+        )
+        yield counter(
             "vllm:engine_early_handovers",
             "Times the engine thread handed a step's resolved outputs to "
             "the event loop before it blocked on the next decode program",

@@ -60,6 +60,7 @@ from production_stack_tpu.ops.paged_attention import (
     paged_attention,
     write_kv,
 )
+from production_stack_tpu.ops.paged_attention_pallas import decode_slab_path
 from production_stack_tpu.parallel.mesh import AXIS_TENSOR
 from production_stack_tpu.parallel.shardings import rules_for_model
 
@@ -216,6 +217,12 @@ class ModelRunner:
                 else init_or_load(self.cfg, mesh, self.rules, config.seed),
             )
         self.use_pallas = _pallas_ok(self.cfg, mesh, config.cache.block_size)
+        # whether a decode step's attention calls run the Pallas decode
+        # kernel's slab body: the kernel's own predicate at this runner's
+        # per-shard geometry (vllm:decode_attn_slab_calls_total)
+        self.decode_attn_slab = self.use_pallas and decode_slab_path(
+            self.cfg.num_kv_heads // self.tp,
+            self.cfg.q_per_kv, self.cfg.head_dim, self.cfg.jax_dtype)
         impl = getattr(config, "attention_impl", "auto") or "auto"
         if impl not in ("auto", "ragged", "bucketed"):
             raise ValueError(

@@ -1867,7 +1867,13 @@ class EngineServer:
         # row block (vllm:ragged_attn_walks_total, ..._narrow_walks_total)
         walks = {"ragged_attn_walks": self.engine.ragged_attn_walks,
                  "ragged_attn_narrow_walks":
-                     self.engine.ragged_attn_narrow_walks}
+                     self.engine.ragged_attn_narrow_walks,
+                 # the decode dispatches' attention calls, and those on
+                 # the decode kernel's slab body (vllm:decode_attn_calls_
+                 # total, vllm:decode_attn_slab_calls_total)
+                 "decode_attn_calls": self.engine.decode_attn_calls,
+                 "decode_attn_slab_calls":
+                     self.engine.decode_attn_slab_calls}
         if perf is None:
             return web.json_response({"enabled": False,
                                       "kv_transfer": kv_block,

@@ -6,7 +6,9 @@ Three kernels over the fused cache layout ``(L, N, block_size, 2*KH, D)``
 - ``paged_decode_attention_pallas``: one grid cell per sequence; walks the
   block table in windows of W blocks, one async DMA per block moving the
   whole ``(bs, 2KH, D)`` K+V slab, double-buffered windows, flash running
-  softmax batched over heads.
+  softmax batched over heads; a landed window is scored head by head, or,
+  at one query row a KV head (``decode_slab_path``), from the slab as it
+  is stored.
 - ``paged_prefill_attention_pallas``: one grid cell per query tile of a
   single sequence's chunk; same windowed context walk with causal masking —
   this replaces the XLA dynamic-slice + gather path over the whole pool.
@@ -41,6 +43,149 @@ NEG_INF = -1e30
 # decode
 # ---------------------------------------------------------------------------
 
+def decode_slab_path(kh: int, group: int, head_dim: int, cache_dtype) -> bool:
+    """Whether the decode kernel scores a window from the slab as stored
+    (``_slab_window``) or head by head (``_head_window``); of the call's
+    per-shard geometry alone. True at one query row a KV head (MHA), a
+    bf16 cache whose K half of a token's ``(2KH, D)`` slab is whole
+    ``(16, 128)`` tiles, and 128-wide heads: a window's K rows then *are*
+    a ``(tokens * KH, D)`` matrix in (token, head) order, V the same, and
+    every head's one query row goes past each 128-row tile of it in one
+    bf16 MXU pass. KH 16 (OLMoE, Ouro) and 32 are the shapes a test and a
+    chip run have covered; grouped queries (G > 1), other head sizes, a
+    shard with fewer than 16 KV heads and float32 caches keep the
+    per-head body."""
+    return (group == 1 and head_dim == 128 and kh in (16, 32)
+            and jnp.dtype(cache_dtype) == jnp.bfloat16)
+
+
+def _flash_weights(m, l, sc):
+    """The running-softmax step both window bodies share: the new row
+    maxima, the old state's rescale, the window's weights and the new row
+    sums, from scores whose last axis is the window's keys."""
+    m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(sc - m_new)
+    l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    return m_new, alpha, p, l_new
+
+
+def _head_window(q_ref, buf, slot, w, *, W, win_tokens, scale, soft_cap):
+    """A landed window's flash update ``(s, ctx, (m, l, acc)) -> (m, l,
+    acc)`` for one of its sequences, KV head by KV head: q (KH, G, D)
+    against each head's (T, D) slice of the slab."""
+    KH = q_ref.shape[1]
+    kvpos = w * win_tokens + jax.lax.broadcasted_iota(
+        jnp.int32, (1, 1, win_tokens), 2
+    )
+
+    def update(s, ctx, carry):
+        m, l, acc = carry
+        q = q_ref[s].astype(jnp.float32)  # (KH, G, D)
+        kv = jnp.concatenate(
+            [buf[slot, s, j] for j in range(W)], axis=0
+        )  # (T, 2KH, D)
+        s_heads = []
+        for h in range(KH):
+            k_h = kv[:, h, :].astype(jnp.float32)  # (T, D)
+            s_heads.append(
+                jax.lax.dot_general(
+                    q[h], k_h, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            )  # (G, T)
+        sc = jnp.stack(s_heads) * scale  # (KH, G, T)
+        if soft_cap:  # Gemma-2 score capping, before masking
+            sc = soft_cap * jnp.tanh(sc / soft_cap)
+        sc = jnp.where(kvpos < ctx, sc, NEG_INF)
+
+        m_new, alpha, p, l_new = _flash_weights(m, l, sc)
+        # per-block DMA predication leaves tail blocks UNWRITTEN: their
+        # V rows can be NaN/Inf, and the PV contraction sums p*v over
+        # ALL T — 0 x NaN = NaN, so masked weights alone don't protect
+        # the accumulator. Zero the invalid V rows explicitly.
+        vvalid = (w * win_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, (win_tokens, 1), 0) < ctx)
+        acc_heads = []
+        for h in range(KH):
+            v_h = jnp.where(
+                vvalid, kv[:, KH + h, :].astype(jnp.float32), 0.0
+            )  # (T, D)
+            acc_heads.append(
+                jax.lax.dot_general(
+                    p[h], v_h, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            )  # (G, D)
+        acc_new = acc * alpha + jnp.stack(acc_heads)
+        return m_new, l_new, acc_new
+
+    return update
+
+
+def _slab_window(q_ref, buf, slot, w, *, W, win_tokens, scale, soft_cap):
+    """The same update where ``decode_slab_path`` holds, on the slab as
+    stored. A token's K half is one whole bf16 tile per 16 heads, so the
+    window's K is the ``(T * KH, D)`` matrix of its (token, head) rows
+    with no relayout; ``q (KH, D)`` times its transpose gives every
+    head's product with every row, and a row's wanted score is the one
+    where the query's head is the row's own (column ``c`` belongs to
+    head ``c % KH``). The flash state keeps that ``(KH, T * KH)`` form,
+    off-diagonal entries masked to exactly zero weight, and PV is its
+    mirror: the masked weights times the ``(T * KH, D)`` V rows. QK has
+    bf16 operands and float32 accumulation (a bf16 x bf16 product is exact
+    in float32); the float32 weights go past V as a bf16 high and low part
+    stacked on the sublanes (16 mantissa bits where the output is rounded
+    to 8), one load of each stationary V tile for both."""
+    KH, D = q_ref.shape[1], q_ref.shape[2]
+    bs = win_tokens // W
+    cols = win_tokens * KH
+    col = jax.lax.broadcasted_iota(jnp.int32, (KH, cols), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (KH, cols), 0)
+    # a column's position in the context, pushed past any ctx where the
+    # column is another head's: one compare masks both
+    kvpos = jnp.where(col % KH == head, w * win_tokens + col // KH,
+                      jnp.int32(2 ** 30))
+
+    def update(s, ctx, carry):
+        m, l, acc = carry  # (KH, 1), (KH, 1), (KH, D)
+        k = buf[slot, s, :, :, 0:KH, :].reshape(cols, D)
+        sc = jax.lax.dot_general(
+            q_ref[s], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # (KH, T * KH)
+        if soft_cap:
+            sc = soft_cap * jnp.tanh(sc / soft_cap)
+        sc = jnp.where(kvpos < ctx, sc, NEG_INF)
+
+        m_new, alpha, p, l_new = _flash_weights(m, l, sc)
+        # _head_window's NaN rule, in place and only where it bites: a
+        # fetched window's block with rows past ctx (its tail, or never
+        # fetched) has those V rows zeroed in the buffer
+        for j in range(W):
+            first = w * win_tokens + j * bs
+
+            @pl.when((first + bs > ctx) & (w * win_tokens < ctx))
+            def _():
+                tok = first + jax.lax.broadcasted_iota(
+                    jnp.int32, (bs, KH, D), 0)
+                v = buf[slot, s, j, :, KH:, :]
+                buf[slot, s, j, :, KH:, :] = jnp.where(
+                    tok < ctx, v, jnp.zeros_like(v))
+
+        v = buf[slot, s, :, :, KH:, :].reshape(cols, D)
+        p_hi = p.astype(v.dtype)
+        p_lo = (p - p_hi.astype(jnp.float32)).astype(v.dtype)
+        pv = jax.lax.dot_general(
+            jnp.concatenate([p_hi, p_lo], axis=0), v,
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        )  # (2KH, D)
+        acc_new = acc * alpha + (pv[:KH] + pv[KH:])
+        return m_new, l_new, acc_new
+
+    return update
+
+
 def _decode_kernel(
     # scalar prefetch
     bt_ref,  # (B, M) SMEM
@@ -60,6 +205,7 @@ def _decode_kernel(
     seqs_per_cell: int,
     scale: float,
     soft_cap: float = 0.0,
+    slab: bool = False,
 ):
     """Batched paged decode attention.
 
@@ -69,13 +215,17 @@ def _decode_kernel(
     handles SPB sequences: their window DMAs are all in flight together
     (SPB x W parallel copies) and the QK^T / PV matmuls batch over the
     sequence dim — batch dims at position 0 on both operands, the layout
-    Mosaic's batched matmul requires."""
+    Mosaic's batched matmul requires.
+
+    The DMA walk, the flash carry and the epilogue are one; what a landed
+    window computes is ``_slab_window`` where ``slab`` (the wrapper's
+    ``decode_slab_path``; q and o then come as (SPB, KH, D)) and
+    ``_head_window`` otherwise."""
     cell = pl.program_id(0)
     layer = layer_ref[0]
     SPB = seqs_per_cell
     W = windows
     bs = block_size
-    KH, G, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
     win_tokens = W * bs
     base = cell * SPB
     # per-cell window count: the longest context in the cell (shorter
@@ -114,6 +264,10 @@ def _decode_kernel(
     def _():
         issue(0, 0)
 
+    window = functools.partial(
+        _slab_window if slab else _head_window, q_ref, buf,
+        W=W, win_tokens=win_tokens, scale=scale, soft_cap=soft_cap)
+
     # per-seq tensors stay <=3D throughout (Mosaic's layout inference
     # rejects middle-dim squeezes/merges on 4D); the flash state is a flat
     # tuple of per-seq (m, l, acc) triples on the fori carry
@@ -130,70 +284,26 @@ def _decode_kernel(
                 def _():
                     dma(slot, s, w, j).wait()
 
-        kvpos = w * win_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, win_tokens), 2
-        )
+        update = window(slot, w)
         out = []
         for s in range(SPB):
-            m, l, acc = carry[3 * s : 3 * s + 3]
-            ctx = cl_ref[base + s]
-            q = q_ref[s].astype(jnp.float32)  # (KH, G, D)
-            kv = jnp.concatenate(
-                [buf[slot, s, j] for j in range(W)], axis=0
-            )  # (T, 2KH, D)
-            s_heads = []
-            for h in range(KH):
-                k_h = kv[:, h, :].astype(jnp.float32)  # (T, D)
-                s_heads.append(
-                    jax.lax.dot_general(
-                        q[h], k_h, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
-                )  # (G, T)
-            sc = jnp.stack(s_heads) * scale  # (KH, G, T)
-            if soft_cap:  # Gemma-2 score capping, before masking
-                sc = soft_cap * jnp.tanh(sc / soft_cap)
-            sc = jnp.where(kvpos < ctx, sc, NEG_INF)
-
-            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(sc - m_new)
-            l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            # per-block DMA predication leaves tail blocks UNWRITTEN: their
-            # V rows can be NaN/Inf, and the PV contraction sums p*v over
-            # ALL T — 0 x NaN = NaN, so masked weights alone don't protect
-            # the accumulator. Zero the invalid V rows explicitly.
-            vvalid = (w * win_tokens + jax.lax.broadcasted_iota(
-                jnp.int32, (win_tokens, 1), 0) < ctx)
-            acc_heads = []
-            for h in range(KH):
-                v_h = jnp.where(
-                    vvalid, kv[:, KH + h, :].astype(jnp.float32), 0.0
-                )  # (T, D)
-                acc_heads.append(
-                    jax.lax.dot_general(
-                        p[h], v_h, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
-                )  # (G, D)
-            acc_new = acc * alpha + jnp.stack(acc_heads)
+            old = carry[3 * s : 3 * s + 3]
+            new = update(s, cl_ref[base + s], old)
             # a seq inactive this window skipped its DMAs: buf holds
             # unwritten bits that can be NaN/Inf, and 0 x NaN = NaN — keep
             # the old carry instead of trusting masked math
             act = seq_active(s, w)
-            out += [
-                jnp.where(act, m_new, m),
-                jnp.where(act, l_new, l),
-                jnp.where(act, acc_new, acc),
-            ]
+            out += [jnp.where(act, n, o) for n, o in zip(new, old)]
         return tuple(out)
 
+    rows = q_ref.shape[1:-1]  # (KH, G), or (KH,) on the slab path
+    D = q_ref.shape[-1]
     init = []
     for _ in range(SPB):
         init += [
-            jnp.full((KH, G, 1), NEG_INF, jnp.float32),
-            jnp.zeros((KH, G, 1), jnp.float32),
-            jnp.zeros((KH, G, D), jnp.float32),
+            jnp.full((*rows, 1), NEG_INF, jnp.float32),
+            jnp.zeros((*rows, 1), jnp.float32),
+            jnp.zeros((*rows, D), jnp.float32),
         ]
     final = jax.lax.fori_loop(0, nwin, body, tuple(init))
     for s in range(SPB):
@@ -232,9 +342,13 @@ def paged_decode_attention_pallas(
     L, N, bs, KH2, _ = kv_cache.shape
     KH = KH2 // 2
     G = H // KH
+    slab = decode_slab_path(KH, G, D, kv_cache.dtype)
     # q heads are shard-grouped like the cache: here a single shard's view,
-    # heads ordered [h0..h_{KH-1}] matching [K_0..K_{KH-1}] halves
-    q4 = q.reshape(B, KH, G, D)
+    # heads ordered [h0..h_{KH-1}] matching [K_0..K_{KH-1}] halves; on the
+    # slab path (G = 1) the heads are the rows of one (KH, D) tile
+    qshape = (KH, D) if slab else (KH, G, D)
+    zeros = (0,) * len(qshape)
+    q4 = q.reshape(B, *qshape)
     layer_arr = jnp.asarray(layer_idx, jnp.int32).reshape(1)
     spb = _pick_seqs_per_cell(B, bs, KH2, D, windows,
                               jnp.dtype(kv_cache.dtype).itemsize)
@@ -242,11 +356,11 @@ def paged_decode_attention_pallas(
         num_scalar_prefetch=3,
         grid=(B // spb,),
         in_specs=[
-            pl.BlockSpec((spb, KH, G, D), lambda b, *_: (b, 0, 0, 0),
+            pl.BlockSpec((spb, *qshape), lambda b, *_: (b, *zeros),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((spb, KH, G, D), lambda b, *_: (b, 0, 0, 0),
+        out_specs=pl.BlockSpec((spb, *qshape), lambda b, *_: (b, *zeros),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
             pltpu.VMEM((2, spb, windows, bs, KH2, D), kv_cache.dtype),
@@ -255,11 +369,11 @@ def paged_decode_attention_pallas(
     )
     kernel = functools.partial(
         _decode_kernel, block_size=bs, windows=windows, seqs_per_cell=spb,
-        scale=D**-0.5, soft_cap=soft_cap,
+        scale=D**-0.5, soft_cap=soft_cap, slab=slab,
     )
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, *qshape), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
         name="paged_decode_attention",

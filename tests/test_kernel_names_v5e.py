@@ -107,22 +107,37 @@ MHA_CASES = {
             q, c, bt, cu, cl, layer_idx=1),
         (((2048, 16, D), jnp.bfloat16), MHA_CACHE, ((64, 256), I32),
          ((65,), I32), ((64,), I32))),
-    "paged_decode_attention": (
-        lambda q, c, bt, cl: paged_decode_attention_pallas(
-            q, c, bt, cl, layer_idx=1),
-        (((64, 16, D), jnp.bfloat16), MHA_CACHE, ((64, 256), I32),
-         ((64,), I32))),
     "kv_cache_write": (
         lambda c, new, sm: kv_cache_write_pallas(c, new, sm, layer_idx=1),
         (MHA_CACHE, ((2048, 2 * 16, D), jnp.bfloat16), ((2048,), I32))),
 }
 
 
+# the decode kernel at the shapes that take its slab body
+# (ops/paged_attention_pallas.py decode_slab_path: G = 1, bf16, D = 128,
+# 16 or 32 KV heads), 64 slots of 4096 positions each: OLMoE's cell,
+# Ouro-2.6B's (192 cache layers of 330 blocks) and an MHA stack of 32
+# heads. All under the default scoped-VMEM limit.
+def _decode_case(layers, blocks, kh):
+    return (
+        lambda q, c, bt, cl: paged_decode_attention_pallas(
+            q, c, bt, cl, layer_idx=1),
+        (((64, kh, D), jnp.bfloat16),
+         ((layers, blocks, BS, 2 * kh, D), jnp.bfloat16),
+         ((64, 256), I32), ((64,), I32)))
+
+
+MHA_CASES["paged_decode_attention"] = _decode_case(*MHA_CACHE[0][:2], 16)
+MHA_CASES["paged_decode_attention.ouro"] = _decode_case(192, 330, 16)
+MHA_CASES["paged_decode_attention.kh32"] = _decode_case(4, 1024, 32)
+
+
 @pytest.mark.parametrize("name", sorted(MHA_CASES))
 def test_kernel_compiles_at_olmoe_geometry_under_default_vmem(one_chip, name):
     fn, shapes = MHA_CASES[name]
     text = _compiled_text(fn, one_chip, *shapes)
-    assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = .*? custom-call\(",
+    kernel = name.split(".")[0]
+    assert re.search(rf"^\s*(?:ROOT )?%{kernel}[.\d]* = .*? custom-call\(",
                      text, flags=re.M)
 
 

@@ -113,35 +113,122 @@ def test_paged_chunk_prefill_matches_dense():
 # Pallas kernels (interpret mode on CPU)
 # ---------------------------------------------------------------------------
 
-def build_random_cache(rng, layers, n, kh, d):
+def build_random_cache(rng, layers, n, kh, d, bs=BS, dtype=jnp.float32):
     return jnp.asarray(
-        rng.standard_normal((layers, n, BS, 2 * kh, d)), jnp.float32
+        rng.standard_normal((layers, n, bs, 2 * kh, d)), dtype
     )
 
 
-def test_pallas_decode_matches_xla_interpret():
-    rng = np.random.default_rng(2)
+# Decode-kernel geometries: (id, kh, G, d, bs, windows, dtype, lens,
+# layer, soft_cap). The small float32 ones run the per-head body; those at
+# KH 16 (and 32), G 1, D 128 with a bf16 cache meet ``decode_slab_path``
+# (OLMoE's and Ouro's shape) and run the slab body: contexts that end
+# mid-block, mid-window, on a window's edge and at 0, a dead slot inside a
+# live cell (all four sequences of B = 4 share one grid cell), a cell that
+# is dead altogether (B = 8), soft_cap > 0 and layer_idx > 0.
+#
+# Tolerance. float32 cases: 2e-4, as before. bf16 cases: kernel and XLA
+# reference read the same bf16 q and cache and accumulate in float32; the
+# kernel's QK products are exact, its softmax weights carry 16 mantissa
+# bits (a bf16 high and low part) against the reference's 24, an error of
+# ~2^-17 relative before both round the output to bf16. So the two
+# outputs differ by at most one bf16 step where a value sits on a
+# rounding edge: 2^-8 to 2^-7 of the value (rtol 2^-7), and absolutely by
+# ~2^-17 x max|v| ~ 3e-5 where values cancel near zero (atol 1e-4).
+F32, BF16 = jnp.float32, jnp.bfloat16
+DECODE_GEOMETRIES = [
+    ("kh4-g2-f32", 4, 2, 16, 4, 2, F32, [9, 16, 3], 1, 0.0),
+    ("kh2-g1-f32", 2, 1, 16, 4, 2, F32, [5, 0, 8, 13], 0, 0.0),
+    ("kh4-g2-f32-softcap", 4, 2, 16, 4, 2, F32, [9, 16, 3], 1, 30.0),
+    ("slab-kh16-mid-block-window-zero", 16, 1, 128, 16, 2, BF16,
+     [37, 0, 64, 5], 1, 0.0),
+    ("slab-kh16-dead-cell-long", 16, 1, 128, 16, 2, BF16,
+     [0, 0, 0, 0, 97, 32, 1, 128], 0, 0.0),
+    ("slab-kh16-softcap-layer2", 16, 1, 128, 16, 2, BF16,
+     [50, 33, 0, 16], 2, 30.0),
+    ("slab-kh16-serving-window", 16, 1, 128, 16, 8, BF16,
+     [130, 7, 0, 128], 1, 0.0),
+    ("slab-kh32", 32, 1, 128, 16, 2, BF16, [19, 40], 1, 0.0),
+    ("kh16-g1-f32-not-slab", 16, 1, 128, 16, 2, F32, [37, 0], 1, 0.0),
+    ("kh8-g4-bf16-not-slab", 8, 4, 128, 16, 2, BF16, [37, 0, 20, 64], 1,
+     0.0),
+]
+
+
+def _decode_tol(dtype):
+    return (dict(rtol=2e-4, atol=2e-4) if dtype == F32
+            else dict(rtol=2 ** -7, atol=1e-4))
+
+
+def _f32(x):
+    return np.asarray(jnp.asarray(x).astype(jnp.float32))
+
+
+@pytest.mark.parametrize(
+    "geometry", [pytest.param(g, id=g[0]) for g in DECODE_GEOMETRIES])
+def test_pallas_decode_matches_xla_interpret(geometry):
     from production_stack_tpu.ops.paged_attention_pallas import (
+        decode_slab_path,
         paged_decode_attention_pallas,
     )
 
-    kh, d, H = 4, 16, 8
-    B, N, M, layers = 3, 16, 8, 2
-    layer = 1
-    lens = np.array([9, 16, 3], np.int32)
-    cache = build_random_cache(rng, layers, N, kh, d)
+    name, kh, G, d, bs, windows, dtype, lens, layer, soft_cap = geometry
+    assert decode_slab_path(kh, G, d, dtype) == name.startswith("slab")
+    rng = np.random.default_rng(2)
+    lens = np.array(lens, np.int32)
+    B, layers = len(lens), 3
+    M = -(-int(lens.max()) // bs) + 2
+    N = 24
+    cache = build_random_cache(rng, layers, N, kh, d, bs, dtype)
     tables = rng.integers(0, N, (B, M)).astype(np.int32)
-    q = rng.standard_normal((B, H, d), dtype=np.float32)
+    q = jnp.asarray(rng.standard_normal((B, kh * G, d)), dtype)
 
     got = paged_decode_attention_pallas(
-        jnp.asarray(q), cache, jnp.asarray(tables), jnp.asarray(lens),
-        layer, windows=2, interpret=True,
+        q, cache, jnp.asarray(tables), jnp.asarray(lens),
+        layer, windows=windows, interpret=True, soft_cap=soft_cap,
     )
     want = paged_attention(
-        jnp.asarray(q)[:, None], cache[layer], jnp.asarray(tables),
-        jnp.asarray(lens), jnp.asarray(lens - 1)[:, None],
+        q[:, None], cache[layer], jnp.asarray(tables),
+        jnp.asarray(lens), jnp.asarray(lens - 1)[:, None], soft_cap=soft_cap,
     )[:, 0]
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
+    assert got.dtype == q.dtype
+    live = lens > 0
+    np.testing.assert_allclose(_f32(got)[live], _f32(want)[live],
+                               **_decode_tol(dtype))
+    assert np.all(_f32(got)[~live] == 0)  # a dead slot's rows come out 0
+
+
+def test_decode_slab_path_predicate():
+    """The decode kernel's choice of body, from (KH, G, D, cache dtype)
+    alone: true for OLMoE and Ouro as served (per shard at TP 1), false
+    for grouped queries, a shard with fewer than 16 KV heads, other head
+    sizes and float32 caches."""
+    from production_stack_tpu.engine.config import ModelConfig
+    from production_stack_tpu.ops.paged_attention_pallas import (
+        decode_slab_path,
+    )
+
+    def served(name, tp=1):
+        cfg = ModelConfig.from_pretrained(name)
+        return decode_slab_path(cfg.num_kv_heads // tp, cfg.q_per_kv,
+                                cfg.head_dim, cfg.jax_dtype)
+
+    assert served("olmoe-1b-7b") and served("ouro-2.6b")
+    assert not served("qwen3-8b-class")      # KH 8, G 4
+    assert not served("olmoe-1b-7b", tp=4)   # a TP-4 shard: KH 4
+    assert not served("gemma-7b-class")      # D 256
+    assert not served("gemma2-9b-class")     # KH 8, G 2
+    assert not served("phi3-mini-class")     # KH 32, G 1, D 96
+    assert not served("tiny-llama")          # float32, small heads
+    assert not served("tiny-olmoe") and not served("tiny-ouro")  # G 1, f32
+    assert decode_slab_path(16, 1, 128, "bfloat16")
+    assert decode_slab_path(32, 1, 128, jnp.bfloat16)
+    assert not decode_slab_path(16, 1, 128, jnp.float32)
+    assert not decode_slab_path(16, 2, 128, jnp.bfloat16)
+    assert not decode_slab_path(8, 1, 128, jnp.bfloat16)
+    assert not decode_slab_path(16, 1, 256, jnp.bfloat16)
+    assert not decode_slab_path(24, 1, 128, jnp.bfloat16)
+    assert not decode_slab_path(64, 1, 128, jnp.bfloat16)  # no chip run
 
 
 def test_pallas_prefill_matches_xla_interpret():
@@ -241,43 +328,53 @@ def test_slot_mapping():
     np.testing.assert_array_equal(slots, [22, 23, 36, 37])
 
 
-def test_pallas_decode_poisoned_tail_blocks_ignored():
+@pytest.mark.parametrize("kh,G,d,bs,dtype,lens", [
+    pytest.param(4, 2, 16, 4, F32, [9, 21], id="kh4-g2-f32"),
+    pytest.param(16, 1, 128, 16, BF16, [37, 70, 0, 16], id="slab-kh16"),
+    pytest.param(16, 1, 128, 16, BF16, [1, 33], id="slab-kh16-one-token"),
+])
+def test_pallas_decode_poisoned_tail_blocks_ignored(kh, G, d, bs, dtype,
+                                                    lens):
     """Per-block DMA predication (r4: the roofline's 1.8x over-read fix)
     must not let NaN/Inf in never-read tail blocks reach the output: tail
-    blocks past each context are poisoned and outputs must still match."""
+    blocks past each context are poisoned and outputs must still match.
+    On the slab body the poison sits where its in-place V zeroing and its
+    masked (KH, tokens x KH) weights have to keep it out."""
     rng = np.random.default_rng(7)
     from production_stack_tpu.ops.paged_attention_pallas import (
         paged_decode_attention_pallas,
     )
 
-    kh, d, H = 4, 16, 8
-    B, N, M, layers = 2, 32, 8, 1
-    lens = np.array([9, 21], np.int32)  # partial blocks at BS=4
-    cache = np.array(build_random_cache(rng, layers, N, kh, d))
+    lens = np.array(lens, np.int32)  # partial blocks
+    B, M, layers = len(lens), 8, 1
+    N = B * M
+    cache = np.array(build_random_cache(rng, layers, N, kh, d, bs),
+                     np.float32)
     tables = np.arange(B * M, dtype=np.int32).reshape(B, M)
     # poison every block slot past each row's live context
     for b in range(B):
-        live_blocks = -(-int(lens[b]) // BS)
+        live_blocks = -(-int(lens[b]) // bs)
         for m in range(live_blocks, M):
             cache[0, tables[b, m]] = np.nan
         # ...and the tail of the last partial block
-        tail = int(lens[b]) % BS
+        tail = int(lens[b]) % bs
         if tail:
             cache[0, tables[b, live_blocks - 1], tail:] = np.inf
-    q = rng.standard_normal((B, H, d), dtype=np.float32)
+    q = jnp.asarray(rng.standard_normal((B, kh * G, d)), dtype)
     got = paged_decode_attention_pallas(
-        jnp.asarray(q), jnp.asarray(cache), jnp.asarray(tables),
+        q, jnp.asarray(cache, dtype), jnp.asarray(tables),
         jnp.asarray(lens), 0, windows=2, interpret=True,
     )
-    assert np.isfinite(np.asarray(got)).all()
+    assert np.isfinite(_f32(got)).all()
     want = paged_attention(
-        jnp.asarray(q)[:, None],
-        jnp.asarray(np.nan_to_num(cache, posinf=0.0))[0],
+        q[:, None],
+        jnp.asarray(np.nan_to_num(cache, posinf=0.0), dtype)[0],
         jnp.asarray(tables), jnp.asarray(lens),
         jnp.asarray(lens - 1)[:, None],
     )[:, 0]
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-4, atol=2e-4)
+    live = lens > 0
+    np.testing.assert_allclose(_f32(got)[live], _f32(want)[live],
+                               **_decode_tol(dtype))
 
 
 def test_pallas_prefill_poisoned_tail_blocks_ignored():

@@ -435,3 +435,54 @@ def test_ragged_attn_walk_counters_follow_the_dispatched_spans():
             n * G <= ROW_BLOCK for n in spans) == 1
 
     asyncio.run(_with_client(server, fn))
+
+
+def test_decode_attn_call_counters_follow_the_decode_dispatches():
+    """vllm:decode_attn_calls_total / ..._slab_calls_total on /metrics and
+    the same two on /debug/perf: fused iterations x cache layers a decode
+    dispatch, and those again where the runner says its geometry takes the
+    decode kernel's slab body (never on the CPU: no Pallas kernel runs).
+    Both series are there from start-up, so a ratio of their deltas reads
+    0.0 and not nothing where the slab count stands still."""
+    names = ("vllm:decode_attn_calls_total",
+             "vllm:decode_attn_slab_calls_total")
+
+    async def read(client):
+        text = await (await client.get("/metrics")).text()
+        samples = [_samples(text, n) for n in names]
+        assert all(samples), "both series exported, moved or not"
+        values = [sum(s.values()) for s in samples]
+        perf = await (await client.get("/debug/perf")).json()
+        assert [perf["decode_attn_calls"],
+                perf["decode_attn_slab_calls"]] == values
+        return values
+
+    server = EngineServer(make_config(attention_impl="ragged"))
+
+    async def fn(client):
+        eng = server.engine
+        assert eng.runner.decode_attn_slab is False
+        per_dispatch = (max(eng.config.scheduler.multi_step, 1)
+                        * eng.config.model.cache_layers)
+
+        async def generate():
+            dispatches = eng.decode_dispatches
+            r = await client.post("/v1/completions", json={
+                "model": "tiny-llama", "prompt": "hi", "max_tokens": 6,
+                "temperature": 0, "ignore_eos": True})
+            assert r.status == 200
+            made = eng.decode_dispatches - dispatches
+            assert made > 0
+            return made * per_dispatch
+
+        before = await read(client)
+        calls = await generate()
+        after = await read(client)
+        assert [after[0] - before[0], after[1] - before[1]] == [calls, 0]
+        # a runner whose geometry meets the kernel's predicate
+        eng.runner.decode_attn_slab = True
+        calls = await generate()
+        last = await read(client)
+        assert [last[0] - after[0], last[1] - after[1]] == [calls, calls]
+
+    asyncio.run(_with_client(server, fn))
