@@ -63,6 +63,8 @@ KERNEL_TOL = 1e-2
 CELL_GEOMETRIES = (
     (8, 4, {"LIBTPU_INIT_ARGS": "--xla_tpu_scoped_vmem_limit_kib=32768"}),
     (16, 1, {}),
+    # solar-open2-250b-ep16-l8's attention layers: KH=8, G=8, the same flag
+    (8, 8, {"LIBTPU_INIT_ARGS": "--xla_tpu_scoped_vmem_limit_kib=32768"}),
 )
 
 

@@ -23,11 +23,13 @@ from production_stack_tpu.parallel.mesh import MeshConfig
 class ModelConfig:
     name: str = "tiny-llama"
     # "llama" | "mixtral" | "olmoe" | "gemma" | "gemma2" | "phi3" | "ouro"
+    # | "solar_open2"
     # — Mistral and Qwen run as "llama" (their deltas are knobs:
     # sliding_window, qkv_bias, qk_norm); "phi3" differs only in its fused
     # HF weight layout, "mixtral" and "olmoe" in their HF tensor names
     # (the MoE block itself is chosen by num_experts > 0, see is_moe),
-    # "ouro" in its tensor names and in loop_passes > 1
+    # "ouro" in its tensor names and in loop_passes > 1, "solar_open2" in
+    # its layer pattern (attn_period > 1) and its sparse block's knobs
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -49,6 +51,35 @@ class ModelConfig:
     num_experts: int = 0
     num_experts_per_tok: int = 2
     norm_topk_prob: bool = True
+    # the chip's share of a wider expert layer: the router scores all
+    # num_experts, this engine holds experts [expert_offset, expert_offset
+    # + experts_held) and computes only the (token, choice) pairs that fall
+    # on them; the other pairs add nothing here (their chips' part of the
+    # sum). 0 = every expert is held
+    experts_held: int = 0
+    expert_offset: int = 0
+    # "softmax" over all experts then top-k (Mixtral, OLMoE), or "sigmoid"
+    # scores with a per-expert bias that is added to CHOOSE the k and not
+    # to weigh them (the GLM-4-MoE / DeepSeek-V3 router)
+    moe_scoring: str = "softmax"
+    routed_scaling: float = 1.0  # factor on the routed experts' sum
+    # width of the shared expert (a dense SwiGLU every token passes
+    # through, added to the routed sum); 0 = none
+    shared_expert_size: int = 0
+    # hybrid stacks: layer l is softmax attention where l % attn_period
+    # == 0 and a gated delta-rule (KDA) linear-attention layer elsewhere;
+    # 0 = every layer is attention. A KDA layer keeps a recurrent state
+    # (kda_heads, kda_head_dim, kda_head_dim) float32 and a short-conv
+    # tail per decode slot instead of keys and values per token
+    attn_period: int = 0
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4        # width of the causal depthwise convolution
+    kda_rank: int = 0        # low-rank width of the decay and gate pairs
+    kda_neg_eigval: bool = False  # beta in (0, 2) instead of (0, 1)
+    # of a hybrid stack's attention layers, which apply no positional
+    # encoding (the shared stack's own layers rotate and have no gate)
+    attn_gate: bool = False  # sigmoid(W x) on the attention output
     # Qwen2-family: biases on the QKV projections
     qkv_bias: bool = False
     # RMSNorm on q and k, pre-rope. "head": one weight of head_dim shared
@@ -122,9 +153,43 @@ class ModelConfig:
         return self.num_experts > 0
 
     @property
+    def num_held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        return self.attn_period > 1
+
+    @property
+    def num_attn_layers(self) -> int:
+        if not self.has_recurrent_state:
+            return self.num_layers
+        return self.num_layers // self.attn_period
+
+    @property
+    def num_kda_layers(self) -> int:
+        return self.num_layers - self.num_attn_layers
+
+    @property
     def cache_layers(self) -> int:
-        """Layers of KV cache: one per (pass, layer) pair."""
-        return self.num_layers * self.loop_passes
+        """Layers of KV cache: one per (pass, attention layer) pair."""
+        return self.num_attn_layers * self.loop_passes
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        """Keys and values one token of context holds, all cache layers."""
+        return (2 * self.cache_layers * self.num_kv_heads * self.head_dim
+                * jnp.dtype(self.jax_dtype).itemsize)
+
+    def recurrent_state_bytes(self, slots: int) -> int:
+        """What the recurrent layers keep for ``slots`` decode slots: a
+        float32 state per head and a conv tail in the model dtype."""
+        if not self.has_recurrent_state:
+            return 0
+        h, d = self.kda_heads, self.kda_head_dim
+        tail = (self.kda_conv - 1) * 3 * h * d
+        return self.num_kda_layers * slots * (
+            h * d * d * 4 + tail * jnp.dtype(self.jax_dtype).itemsize)
 
     @staticmethod
     def from_hf_config(cfg: dict[str, Any], name: str = "") -> "ModelConfig":
@@ -168,6 +233,8 @@ class ModelConfig:
                     "Ouro with use_sliding_window: true is not supported "
                     "(every layer attends over the whole context)")
             arch = "ouro"
+        elif cfg.get("model_type") == "solar_open2":
+            return ModelConfig._solar_open2_from_hf(cfg, name)
         elif any("Phi3" in a for a in archs):
             # only the standard Phi-3 maps onto the fused-Llama layout;
             # Phi-3-small (query_key_value naming, gegelu, blocksparse)
@@ -273,6 +340,95 @@ class ModelConfig:
             loop_passes=(int(cfg.get("total_ut_steps", 1))
                          if arch == "ouro" else 1),
             residual_f32=arch == "ouro",
+        )
+
+    @staticmethod
+    def _solar_open2_from_hf(cfg: dict, name: str = "") -> "ModelConfig":
+        """``model_type: solar_open2``: one softmax GQA layer (no rope, a
+        sigmoid output gate) in every ``gqa_interval + 1`` layers, gated
+        delta-rule (KDA) layers between them, and in every layer a sparse
+        block with sigmoid routing and a shared expert. Two keys of this
+        repo state the chip's share of the routed experts:
+        ``n_routed_experts_held`` and ``routed_expert_offset`` (absent:
+        all of them). What is not computed is refused by name."""
+        what = "solar_open2"
+        if cfg.get("kda_use_full_proj"):
+            raise ValueError(
+                f"{what} with kda_use_full_proj: true is not supported "
+                "(only the low-rank decay and gate projections)")
+        if int(cfg.get("first_k_dense_replace", 0)) > 0:
+            raise ValueError(
+                f"{what} with first_k_dense_replace="
+                f"{cfg['first_k_dense_replace']} is not supported (every "
+                "layer's MLP is the sparse block)")
+        if int(cfg.get("n_group", 1) or 1) > 1:
+            raise ValueError(
+                f"{what} with n_group={cfg['n_group']} is not supported "
+                "(no group-limited routing)")
+        if cfg.get("use_rope", False):
+            raise ValueError(
+                f"{what} with use_rope: true (partial_rotary_factor="
+                f"{cfg.get('partial_rotary_factor', 1)}) is not supported: "
+                "the hybrid stack's attention layers apply no positional "
+                "encoding")
+        layers = int(cfg["num_hidden_layers"])
+        period = int(cfg.get("gqa_interval", 3)) + 1
+        want = list(range(0, layers, period))
+        if list(cfg.get("gqa_layers", want)) != want or layers % period:
+            raise ValueError(
+                f"{what} with gqa_layers={cfg.get('gqa_layers')} is not "
+                f"supported: only one attention layer first in every "
+                f"gqa_interval + 1 = {period} layers of "
+                f"{layers} (whole periods)")
+        lin = cfg.get("linear_attn_config") or {}
+        heads = cfg["num_attention_heads"]
+        kda_heads = int(lin.get("num_heads", heads))
+        if lin.get("num_kv_heads") not in (None, kda_heads):
+            raise ValueError(
+                f"{what} with linear_attn_config.num_kv_heads="
+                f"{lin['num_kv_heads']} is not supported (a key and value "
+                "head per query head)")
+        experts = int(cfg["n_routed_experts"])
+        held = int(cfg.get("n_routed_experts_held", experts))
+        offset = int(cfg.get("routed_expert_offset", 0))
+        if not 0 < held <= experts or not 0 <= offset <= experts - held:
+            raise ValueError(
+                f"{what}: n_routed_experts_held={held} from "
+                f"routed_expert_offset={offset} is not a share of "
+                f"n_routed_experts={experts}")
+        kda_dim = int(lin.get("head_dim", cfg["head_dim"]))
+        return ModelConfig(
+            name=name or cfg.get("_name_or_path", "hf-model"),
+            architecture="solar_open2",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["moe_intermediate_size"],
+            num_layers=layers,
+            num_heads=heads,
+            num_kv_heads=cfg.get("num_key_value_heads", heads),
+            head_dim=cfg.get("head_dim") or cfg["hidden_size"] // heads,
+            rope_theta=cfg.get("rope_theta", 10000.0),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_model_len=cfg.get("max_position_embeddings", 4096),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            num_experts=experts,
+            num_experts_per_tok=cfg.get("num_experts_per_tok", 8),
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+            experts_held=held if held < experts else 0,
+            expert_offset=offset,
+            moe_scoring="sigmoid",
+            routed_scaling=float(cfg.get("routed_scaling_factor", 1.0)),
+            shared_expert_size=(int(cfg.get("n_shared_experts", 0))
+                                * cfg["moe_intermediate_size"]),
+            attn_period=period,
+            kda_heads=kda_heads,
+            kda_head_dim=kda_dim,
+            kda_conv=int(lin.get("short_conv_kernel_size", 4)),
+            # not a key of the published file: the low-rank width of the
+            # decay and output-gate pairs is the KDA head size
+            kda_rank=kda_dim,
+            kda_neg_eigval=bool(cfg.get("kda_allow_neg_eigval", False)),
+            attn_gate=bool(cfg.get("use_gqa_gate", False)),
         )
 
     @staticmethod

@@ -31,6 +31,7 @@ from production_stack_tpu.engine.sequence import (
 )
 from production_stack_tpu.engine.tokenizer import get_tokenizer
 from production_stack_tpu.engine.tracing import StepClock
+from production_stack_tpu.ops.kda import continues_one_row
 from production_stack_tpu.ops.ragged_paged_attention_pallas import (
     count_walks,
 )
@@ -97,7 +98,26 @@ class LLMEngine:
         self.scheduler = Scheduler(
             config.scheduler, config.cache, self.runner.num_blocks,
             max_model_len=config.model.max_model_len,
+            recurrent_state=config.model.has_recurrent_state,
         )
+        # what the recurrent layers of a hybrid stack ran
+        # (engine/tracing.py); None for every other model
+        self.recurrent = None
+        if config.model.has_recurrent_state:
+            import logging
+
+            from production_stack_tpu.engine.tracing import RecurrentCounters
+
+            slots = config.scheduler.max_num_seqs
+            self.recurrent = RecurrentCounters(
+                config.model.num_kda_layers,
+                config.model.recurrent_state_bytes(slots))
+            logging.getLogger(__name__).info(
+                "%s keeps recurrent state per decode slot (%d KDA layers x "
+                "%d slots, %.2f GB): prefix-cache lookups are served as "
+                "misses, a preempted sequence recomputes from position 0",
+                config.model.name, config.model.num_kda_layers, slots,
+                self.recurrent.state_bytes / 1e9)
         # ragged unified step (ops/ragged_paged_attention_pallas.py): the
         # scheduler mixes decode rows and prefill chunks into one
         # token-budget batch, packed here into a single (1, T) stream
@@ -1134,6 +1154,11 @@ class LLMEngine:
             self._attribute_seq_seconds(dispatch_s, t_entries)
         self.ragged_dispatches += 1
         self.ragged_live_tokens += cu
+        if self.recurrent is not None:
+            q_len = np.diff(self._r_cu)
+            self.recurrent.record_ragged(
+                q_len, continues_one_row(q_len, self._context_lens),
+                sum(sp.chunk_start == 0 for sp in prefills))
         walks, narrow = count_walks(self._r_cu, T, self.config.model.q_per_kv)
         self.ragged_attn_walks += walks
         self.ragged_attn_narrow_walks += narrow
@@ -1397,6 +1422,8 @@ class LLMEngine:
         )
         dispatch_s = self.clock.enter("postprocess") - t_call
         self.decode_dispatches += 1
+        if self.recurrent is not None:
+            self.recurrent.record_decode(K)
         attn_calls = K * self.config.model.cache_layers
         self.decode_attn_calls += attn_calls
         if getattr(self.runner, "decode_attn_slab", False):
@@ -1734,6 +1761,7 @@ class LLMEngine:
             counters = getattr(self.runner, name, None)
             if counters is not None:
                 out.update(counters.snapshot())
+        out.update(self.recurrent_stats())
         if self.host_kv is not None:
             out["cpu_cache_usage_perc"] = self.host_kv.usage
             out["cpu_prefix_cache_hits_total"] = self.host_kv.hits
@@ -1744,6 +1772,14 @@ class LLMEngine:
             out["perf"] = self.perf.stats_fields()
             out["tenants"] = self.tenant_stats()
         return out
+
+    def recurrent_stats(self) -> dict:
+        """What a hybrid stack's recurrent layers ran and hold
+        (engine/tracing.py RecurrentCounters); {} for every other model."""
+        if self.recurrent is None:
+            return {}
+        return self.recurrent.snapshot(
+            self.scheduler.allocator.lookups_bypassed)
 
     def tenant_stats(self) -> dict:
         """Per-tenant attribution snapshot (tokens by phase, chip-seconds,
@@ -1828,11 +1864,16 @@ class LLMEngine:
             PrefixCachingBlockAllocator,
         )
 
+        # the recurrent layers' per-slot state goes and comes back with
+        # the pool (one pytree): no sequence is alive across a sleep
         self.runner.drop_kv()
+        bypassed = self.scheduler.allocator.lookups_bypassed
         self.scheduler.allocator = PrefixCachingBlockAllocator(
             self.runner.num_blocks, self.config.cache.block_size,
             self.config.cache.enable_prefix_caching,
+            bypass_prefix=self.scheduler.recurrent_state,
         )
+        self.scheduler.allocator.lookups_bypassed = bypassed
         self._wire_tier_hooks()  # the rebuilt allocator must keep demoting
         if level >= 2:
             self.runner.drop_params()

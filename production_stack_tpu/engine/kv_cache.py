@@ -2,13 +2,20 @@
 
 TPU-first design:
 
-- The device cache is one pytree ``{"k", "v"}`` of shape
-  ``(L, num_blocks, block_size, KH, D)`` living in HBM (L is
-  ``ModelConfig.cache_layers``: a looped stack keeps a cache layer for
-  every (pass, layer) pair, so L is its weight layers times its passes),
-  KV-heads sharded over the ``tensor`` mesh axis. Block tables and slot
-  mappings are tiny int32 host arrays recomputed each step — all device
-  shapes stay static, so the serving step never retraces.
+- The device cache is of two kinds. Attention layers keep paged keys and
+  values: one fused array ``(L, num_blocks, block_size, 2*KH, D)`` living
+  in HBM (L is ``ModelConfig.cache_layers``: the ATTENTION layers; a
+  looped stack keeps a cache layer for every (pass, layer) pair),
+  KV-heads sharded over the ``tensor`` mesh axis. Recurrent (KDA) layers
+  keep state per decode SLOT, not per token: ``state`` (KDA layers,
+  slots, H, d, d) float32 and the short convolution's tail ``conv``
+  (KDA layers, slots, K-1, 3*H*d). A model without recurrent layers has
+  the paged array alone, as it is; a hybrid stack a dict ``{"kv",
+  "state", "conv"}`` (``init_kv_cache``). A slot's state belongs to the
+  sequence that holds the slot and is taken as zeros by a span that
+  starts at position 0, so nothing on the host resets it. Block tables
+  and slot mappings are tiny int32 host arrays recomputed each step — all
+  device shapes stay static, so the serving step never retraces.
 - The allocator runs on host Python (control plane, off the hot device path)
   and implements vLLM-style *prefix caching*: full blocks are content-hashed
   by their token chain; a new request reuses any cached prefix blocks
@@ -55,8 +62,11 @@ def init_kv_cache(
     mesh: Mesh,
     rules: Optional[ShardingRules] = None,
     num_blocks: Optional[int] = None,
-) -> jnp.ndarray:
-    """Allocate the fused HBM block pool, sharded over the mesh."""
+    slots: int = 0,
+):
+    """Allocate the fused HBM block pool, sharded over the mesh; for a
+    model with recurrent layers, the pool and the per-slot state of
+    ``slots`` decode slots as {"kv", "state", "conv"}."""
     from production_stack_tpu.parallel.shardings import rules_for_model
 
     rules = rules or rules_for_model(model, mesh)
@@ -81,15 +91,22 @@ def init_kv_cache(
         # allocation at engine startup: the wrapper exists only to apply
         # out_shardings and is called exactly once, so there is no trace
         # cache to lose
-        return jax.jit(_zeros, out_shardings=sharding)()
+        pool = jax.jit(_zeros, out_shardings=sharding)()
+        if not model.has_recurrent_state:
+            return pool
+        if slots <= 0:
+            raise ValueError("a recurrent-state model needs its slot count")
+        h, d = model.kda_heads, model.kda_head_dim
+        lk = model.num_kda_layers
+        return {
+            "kv": pool,
+            "state": jnp.zeros((lk, slots, h, d, d), jnp.float32),
+            "conv": jnp.zeros((lk, slots, model.kda_conv - 1, 3 * h * d), dt),
+        }
 
 
 def kv_cache_bytes_per_block(model: ModelConfig, cache: CacheConfig) -> int:
-    itemsize = jnp.dtype(model.jax_dtype).itemsize
-    return (
-        2 * model.cache_layers * cache.block_size * model.num_kv_heads
-        * model.head_dim * itemsize
-    )
+    return cache.block_size * model.kv_bytes_per_token
 
 
 def resolve_num_blocks(
@@ -126,10 +143,19 @@ class PrefixCachingBlockAllocator:
     recompute for every full cached block.
     """
 
-    def __init__(self, num_blocks: int, block_size: int, enable_prefix_caching: bool = True):
+    def __init__(self, num_blocks: int, block_size: int,
+                 enable_prefix_caching: bool = True,
+                 bypass_prefix: bool = False):
         self.num_blocks = num_blocks
         self.block_size = block_size
-        self.enable_prefix_caching = enable_prefix_caching
+        # ``bypass_prefix``: the model keeps recurrent state per slot, and
+        # no cached block holds the state at its boundary, so a sequence
+        # cannot resume behind a cached prefix: every lookup is answered
+        # "miss" (and counted), nothing is content-addressed
+        self.bypass_prefix = bypass_prefix
+        self.lookups_bypassed = 0
+        self.enable_prefix_caching = (enable_prefix_caching
+                                      and not bypass_prefix)
         self.blocks = [Block(i) for i in range(num_blocks)]
         self.free_ids: collections.deque[int] = collections.deque(range(num_blocks))
         self.hash_to_block: dict[int, int] = {}
@@ -210,6 +236,7 @@ class PrefixCachingBlockAllocator:
         of blocks (caller preempts/queues). At least one token is always left
         uncached so the forward pass emits a next-token logit."""
         needed_blocks = max((len(tokens) + self.block_size - 1) // self.block_size, 1)
+        self.lookups_bypassed += self.bypass_prefix
         matched, cached_tokens = self.match_prefix(tokens)
         self.prefix_queries += len(tokens) // self.block_size
         # never treat the whole prompt as cached: recompute the last token

@@ -253,6 +253,13 @@ class EngineStatsCollector:
                 s["moe_routed_tokens_total"],
             )
             yield counter(
+                "vllm:moe_held_pairs",
+                "Of vllm:moe_routed_tokens, the pairs on experts this "
+                "engine holds (all of them unless it holds a share of a "
+                "wider expert layer)",
+                s["moe_held_pairs_total"],
+            )
+            yield counter(
                 "vllm:moe_padding_rows",
                 "Stream rows masked out of MoE routing (padding of the "
                 "ragged stream, idle decode slots)",
@@ -280,6 +287,48 @@ class EngineStatsCollector:
                 "x dispatches): the denominator of "
                 "vllm:moe_decode_experts_touched",
                 s["moe_decode_layer_steps_total"],
+            )
+        # recurrent-state layers (engine/tracing.py RecurrentCounters):
+        # exported by hybrid stacks only
+        if "kda_decode_calls_total" in s:
+            yield counter(
+                "vllm:kda_decode_calls",
+                "Recurrent decode steps the decode program ran (decode "
+                "dispatches x fused iterations x KDA layers); a ragged "
+                "dispatch runs one more a KDA layer for its decode rows",
+                s["kda_decode_calls_total"],
+            )
+            yield counter(
+                "vllm:kda_chunk_tokens",
+                "Rows of the ragged dispatches that the recurrent layers' "
+                "span scan carried: every span's but the decode rows' (one "
+                "row that continues a state: the decode step takes those)",
+                s["kda_chunk_tokens_total"],
+            )
+            yield counter(
+                "vllm:kda_chunk_spans",
+                "Spans of the ragged dispatches that the span scan carried: "
+                "each loads and stores its slot's recurrent state once a "
+                "layer",
+                s["kda_chunk_spans_total"],
+            )
+            yield counter(
+                "vllm:recurrent_state_resets",
+                "Sequences started from a zero recurrent state (new, or "
+                "recomputed after preemption)",
+                s["recurrent_state_resets_total"],
+            )
+            yield gauge(
+                "vllm:recurrent_state_bytes",
+                "Bytes the recurrent layers' per-slot state and conv "
+                "tails hold on the device",
+                s["recurrent_state_bytes"],
+            )
+            yield counter(
+                "vllm:prefix_lookups_bypassed",
+                "Prefix-cache lookups answered as misses because the "
+                "model keeps recurrent state no cached block can restore",
+                s["prefix_lookups_bypassed_total"],
             )
         # looped stacks (engine/tracing.py LoopCounters): exported by
         # models whose layers run more than once a forward

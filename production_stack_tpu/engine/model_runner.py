@@ -53,6 +53,7 @@ from production_stack_tpu.engine.tracing import (
     MoeCounters,
     StepClock,
 )
+from production_stack_tpu.ops import kda
 from production_stack_tpu.engine.weights import init_or_load
 from production_stack_tpu.models.registry import get_model
 from production_stack_tpu.ops.paged_attention import (
@@ -67,11 +68,11 @@ from production_stack_tpu.parallel.shardings import rules_for_model
 _log = logging.getLogger(__name__)
 
 
-def _named_partial(fn, *bound):
+def _named_partial(fn, *bound, **bound_kw):
     """functools.partial that keeps the function's name, so XLA names the
     jitted program after it (``jit_ragged_step``; a bare partial compiles
     to ``jit__unknown``) and a profiler trace can tell the programs apart."""
-    part = functools.partial(fn, *bound)
+    part = functools.partial(fn, *bound, **bound_kw)
     part.__name__ = fn.__name__.lstrip("_")
     return part
 
@@ -194,13 +195,16 @@ class ModelRunner:
                 f"{self.cfg.sliding_window}; serve with max_model_len <= "
                 "window (exactness gate, see ModelConfig.sliding_window)"
             )
+        if self.cfg.has_recurrent_state:
+            self._refuse_for_recurrent_state(config, mesh)
         self.rules = rules_for_model(self.cfg, mesh)
         self.model = get_model(self.cfg)
         # routing counters of an MoE model (engine/tracing.py): the step
         # programs then return a per-layer routing histogram as their last
         # result leaf, which is fetched with the step's own results
-        self.moe = (MoeCounters(self.cfg.num_experts,
-                                self.cfg.num_experts_per_tok)
+        self.moe = (MoeCounters(self.cfg.num_held_experts,
+                                self.cfg.num_experts_per_tok,
+                                share=bool(self.cfg.experts_held))
                     if self.cfg.is_moe else None)
         # a looped stack's step programs return the passes they made, one
         # int32 a forward, after the histogram: same fetch, same route
@@ -232,12 +236,16 @@ class ModelRunner:
         # ragged path stays reachable by forcing "ragged" (parity tests)
         self.attention_impl = (
             impl if impl != "auto"
-            else ("ragged" if self.use_pallas else "bucketed")
+            else ("ragged" if self.use_pallas or self.cfg.has_recurrent_state
+                  else "bucketed")
         )
+        if self.cfg.has_recurrent_state and self.attention_impl != "ragged":
+            raise ValueError(
+                f"{self.cfg.name}: attention_impl={self.attention_impl} is "
+                "not supported for a recurrent-state model: only the ragged "
+                "step carries span boundaries to its recurrent layers")
         self.num_blocks = self._resolve_num_blocks(num_blocks)
-        self.kv = kvmod.init_kv_cache(
-            self.cfg, config.cache, mesh, self.rules, self.num_blocks
-        )
+        self.kv = self._init_cache()
         # block-table width padded to a multiple of the kernels' DMA window
         # (they read whole windows; tables are 0-padded past the live blocks)
         mbs = -(-self.cfg.max_model_len // config.cache.block_size)
@@ -278,10 +286,13 @@ class ModelRunner:
             static_argnames=("greedy_only", "use_controls", "use_grammar"),
             **self._mh_gate,
         )
+        recurrent = self.cfg.has_recurrent_state
         self._decode_multi = jax.jit(
             _named_partial(
                 _decode_multi_step, self.cfg, self._attend_decode,
                 max(config.scheduler.multi_step, 1), self._eos_id,
+                recur_impl=(functools.partial(self._recur, False)
+                            if recurrent else None),
             ),
             donate_argnums=(1,),
             static_argnames=("layout", "block_size", "greedy_only",
@@ -299,7 +310,9 @@ class ModelRunner:
             self._ragged = jax.jit(
                 _named_partial(_ragged_step, self.cfg,
                                self._attend_ragged, self._eos_id,
-                               self.spec_width),
+                               self.spec_width,
+                               recur_impl=(functools.partial(self._recur, True)
+                                           if recurrent else None)),
                 donate_argnums=(1,),
                 static_argnames=("layout", "greedy_only", "use_penalties",
                                  "use_controls", "use_grammar"),
@@ -346,6 +359,44 @@ class ModelRunner:
         self.grammar_bank = None
         self.grammar_accept = None
 
+    @staticmethod
+    def _refuse_for_recurrent_state(config: EngineConfig, mesh: Mesh) -> None:
+        """A model with recurrent (KDA) layers keeps state per decode slot
+        that only the ragged and decode step programs of ONE chip carry.
+        Whatever would split, move, skip or guess at that state is refused
+        here by name, not served wrongly."""
+        name = config.model.name
+        refused = [
+            what for bad, what in (
+                (mesh.devices.size > 1,
+                 f"a mesh of {mesh.devices.size} devices (tensor, sequence "
+                 "or pipeline parallelism): the recurrent kernels are not "
+                 "partitioned and ring prefill carries no state"),
+                (config.model.quant is not None,
+                 f"quant={config.model.quant}: the recurrent layers' "
+                 "projections are not quantized"),
+                (config.scheduler.spec_ngram_k > 0,
+                 "n-gram speculative decoding (spec_ngram_k > 0): a "
+                 "rejected draft cannot be taken out of the state again"),
+                (config.role != "unified",
+                 f"role={config.role}: a P->D transfer frames keys and "
+                 "values, not recurrent state"),
+                (bool(config.cache.host_offload_blocks
+                      or config.cache.kv_host_cache_bytes
+                      or config.cache.remote_kv_url),
+                 "a host or remote KV tier: a block fetched back has no "
+                 "recurrent state to resume from"),
+            ) if bad]
+        if refused:
+            raise ValueError(
+                f"{name} keeps recurrent state per decode slot; not "
+                "supported with it: " + "; ".join(refused))
+
+    def _init_cache(self):
+        return kvmod.init_kv_cache(
+            self.cfg, self.config.cache, self.mesh, self.rules,
+            self.num_blocks, slots=self.config.scheduler.max_num_seqs)
+
     def install_compile_observer(self, observer) -> None:
         """Proxy every jitted program through a compile tracker so the
         perf accountant sees one event per (program, argument-signature)
@@ -381,6 +432,13 @@ class ModelRunner:
                 E, F = self.cfg.hidden_size, self.cfg.intermediate_size
                 hidden += (T * self.cfg.num_experts_per_tok
                            * (2 * (2 * E + 3 * F) + 4 * E)) // 8
+            if self.cfg.has_recurrent_state:
+                # a KDA layer's rows: q, k, v before and after the
+                # convolution in the model dtype, the five float32 inputs
+                # of the recurrence, head-major copies of them for the
+                # kernel, its output both ways
+                hidden += (T * self.cfg.kda_heads * self.cfg.kda_head_dim
+                           * (6 * 2 + 12 * 4)) // 8
             if self.use_pallas:
                 return int(8 * hidden + 4 * logits)
             ctx = self.cfg.max_model_len
@@ -427,7 +485,10 @@ class ModelRunner:
             raise RuntimeError(
                 f"{jax.default_backend()} device reports no memory_stats(): "
                 "cannot size the KV pool — pass --num-blocks")
-        free = hbm - used - self._prefill_temp_bytes() - 2 * 1024**3
+        # the recurrent layers' per-slot state comes out of the same memory
+        free = (hbm - used - self._prefill_temp_bytes() - 2 * 1024**3
+                - self.cfg.recurrent_state_bytes(
+                    self.config.scheduler.max_num_seqs))
         n_dev = max(self.mesh.devices.size, 1)
         total_free = free * n_dev  # cache is sharded over the mesh
         return max(int(total_free * self.config.cache.hbm_utilization) // per_block, 16)
@@ -654,6 +715,36 @@ class ModelRunner:
             layer_idx, cu_q_lens,
         )
         return out[None], caches
+
+    # -- recurrent (KDA) layers: the stateful call, as _attend_* is an
+    # attention layer's. ``caches`` is the hybrid stack's whole cache
+    # pytree; "state" and "conv" ride the scan carry like the KV pool and
+    # are updated in place at the KDA layer's index
+    def _recur(self, ragged: bool, conv_w, qkv, g, beta, caches, k_idx,
+               *step_inputs, neg_eigval):
+        """The packed stream (``ragged``: qkv (1, T, 3*H*D), ``step_inputs``
+        the span offsets and context lengths; a span continues its slot's
+        state and conv tail, or starts from zeros at position 0), or one
+        row a slot (qkv (B, 1, 3*H*D), ``step_inputs`` the live-slot mask;
+        idle slots keep what they hold)."""
+        from production_stack_tpu.ops import kda_pallas
+
+        axis = 0 if ragged else 1  # of the axis the step form lacks
+        conv, xla, pallas = (
+            (kda.conv_ragged, kda.recurrence_ragged, kda_pallas.kda_ragged)
+            if ragged else (kda.conv_decode, kda.recurrence_decode,
+                            kda_pallas.kda_decode_step))
+        tail = jax.lax.dynamic_index_in_dim(caches["conv"], k_idx, 0, False)
+        x, tail = conv(jnp.squeeze(qkv, axis), conv_w, tail, *step_inputs)
+        prep = kda.prepare(*kda.split_heads(x, g.shape[-2]),
+                           jnp.squeeze(g, axis), jnp.squeeze(beta, axis),
+                           neg_eigval)
+        o, state = (pallas if self.use_pallas else xla)(
+            caches["state"], k_idx, *prep, *step_inputs)
+        conv = jax.lax.dynamic_update_index_in_dim(
+            caches["conv"], tail, k_idx, 0)
+        return (jnp.expand_dims(o, axis),
+                {**caches, "state": state, "conv": conv})
 
     # -- public step API (host numpy in, device out) -------------------------
     def prefill(self, tokens: np.ndarray, positions: np.ndarray,
@@ -944,10 +1035,7 @@ class ModelRunner:
 
     def restore_kv(self) -> None:
         if self.kv is None:
-            self.kv = kvmod.init_kv_cache(
-                self.cfg, self.config.cache, self.mesh, self.rules,
-                self.num_blocks,
-            )
+            self.kv = self._init_cache()
 
     def drop_params(self) -> None:
         self.params = None
@@ -1292,6 +1380,21 @@ def _grammar_mask(logits, bank, accept, g_ids, g_states, eos_id):
     return jnp.where(con & ~allowed, NEG_INF, logits), row_t
 
 
+def _recur_kw(recur_impl, *step_inputs) -> dict:
+    """``forward_tokens``' ``recur`` argument of a hybrid stack: the
+    runner's recurrent call with this step's own inputs bound behind the
+    model's (an idle-slot mask, or the span offsets); nothing for a model
+    without recurrent layers."""
+    if recur_impl is None:
+        return {}
+
+    def recur(conv_w, qkv, g, beta, caches, k_idx, *, neg_eigval):
+        return recur_impl(conv_w, qkv, g, beta, caches, k_idx,
+                          *step_inputs, neg_eigval=neg_eigval)
+
+    return {"recur": recur}
+
+
 def _make_lora(lora_bank, adapter_ids, T: int):
     """Build the forward-pass lora pytree (or None)."""
     if lora_bank is None or adapter_ids is None:
@@ -1417,6 +1520,7 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
                        token_counts=None, presence=None, frequency=None,
                        lora_bank=None, adapter_ids=None, ctrl=None,
                        grammar=None, *, layout: StepLayout,
+                       recur_impl=None,
                        block_size: int, greedy_only: bool = False,
                        use_penalties: bool = False,
                        use_controls: bool = False,
@@ -1459,12 +1563,14 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
             )
 
         # idle slots stay out of an MoE model's routing, whose per-layer
-        # histogram joins the results, as a looped stack's pass count does
+        # histogram joins the results, as a looped stack's pass count does;
+        # a hybrid stack's recurrent layers leave their state as it is
         hidden, kv, *moe_hist = model.forward_tokens(
             cfg, params, tok[:, None], pos[:, None], attend, kv,
             lora=_make_lora(lora_bank, adapter_ids, 1),
             live=active[:, None], moe_hist=cfg.is_moe,
             loop_count=cfg.loop_passes > 1,
+            **_recur_kw(recur_impl, active),
         )
         logits = model.logits_from_hidden(cfg, params, hidden)[:, 0]
         raw_logits = logits  # logprobs report the raw model distribution
@@ -1546,7 +1652,8 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
                  packed,
                  token_counts=None, presence=None, frequency=None,
                  lora_bank=None, adapter_ids=None, ctrl=None, grammar=None,
-                 *, layout: StepLayout, greedy_only: bool = False,
+                 *, layout: StepLayout, recur_impl=None,
+                 greedy_only: bool = False,
                  use_penalties: bool = False,
                  use_controls: bool = False,
                  use_grammar: bool = False):
@@ -1607,6 +1714,7 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
     hidden, new_kv, *moe_hist = model.forward_tokens(
         cfg, params, tokens, positions, attend, kv, lora=lora,
         moe_hist=cfg.is_moe, loop_count=cfg.loop_passes > 1,
+        **_recur_kw(recur_impl, f["cu_q_lens"], f["context_lens"]),
     )
     last_hidden = jnp.take(hidden[0], last_idx, axis=0)  # (S, E)
     logits = model.logits_from_hidden(cfg, params, last_hidden[:, None])[:, 0]

@@ -195,7 +195,11 @@ class PerfAccountant:
         # U - 1 times more than they are held; and it attends over, and
         # keeps, keys and values for every (pass, layer) pair
         self.loop_passes = int(getattr(cfg, "loop_passes", 1))
-        self.cache_layers = cfg.num_layers * self.loop_passes
+        # layers that keep keys and values per token (a hybrid stack's
+        # recurrent layers keep state per slot instead), and what a token
+        # of context holds in them: the cache layout's own numbers
+        self.cache_layers = cfg.cache_layers
+        layer_passes = cfg.num_layers * self.loop_passes
         repeats = ((self.loop_passes - 1) * _stack_param_count(cfg)
                    if self.loop_passes > 1 else 0)
         self.active_param_count += repeats
@@ -203,8 +207,7 @@ class PerfAccountant:
                                   / self.param_count)
         self._attn_per_tok_ctx = (4 * self.cache_layers * cfg.num_heads
                                   * cfg.head_dim)
-        self._kv_bytes_per_tok = (2 * self.cache_layers * cfg.num_kv_heads
-                                  * cfg.head_dim * _dtype_bytes(cfg.dtype))
+        self._kv_bytes_per_tok = cfg.kv_bytes_per_token
         # ICI cost model (docs/roofline.md "Multi-chip"), zero at tp=1:
         # each layer's two row-parallel matmuls (attention out-proj, MLP
         # down-proj) end in an all-reduce of the (tokens, hidden)
@@ -214,7 +217,7 @@ class PerfAccountant:
         # columns) pays an all-gather of (tp-1)/tp x vocab f32 per chip.
         ar_fac = 2.0 * (self.tp - 1) / self.tp
         ag_fac = (self.tp - 1) / self.tp
-        self._ar_bytes_per_tok = (2 * self.cache_layers * cfg.hidden_size
+        self._ar_bytes_per_tok = (2 * layer_passes * cfg.hidden_size
                                   * _dtype_bytes(cfg.dtype) * ar_fac)
         self._ag_bytes_per_row = cfg.vocab_size * 4 * ag_fac
         self._lock = threading.Lock()

@@ -70,6 +70,12 @@ class StagedModelRunner:
         self.mesh = mesh
         S = mesh.shape[AXIS_STAGE]
         assert S > 1, "StagedModelRunner requires a stage axis > 1"
+        if self.cfg.has_recurrent_state:
+            raise ValueError(
+                f"{self.cfg.name} keeps recurrent state per decode slot; "
+                "not supported with pipeline stages "
+                f"(--pipeline-parallel-size {S}): the staged step programs "
+                "carry no recurrent state")
         if self.cfg.loop_passes > 1:
             # the stages slice the layers once; a looped stack would have
             # to cycle the activations through them loop_passes times

@@ -66,12 +66,17 @@ class SchedulerOutput:
 
 class Scheduler:
     def __init__(self, sched: SchedulerConfig, cache: CacheConfig,
-                 num_blocks: int, max_model_len: int = 1 << 30):
+                 num_blocks: int, max_model_len: int = 1 << 30,
+                 recurrent_state: bool = False):
         self.config = sched
         self.cache_config = cache
         self.max_model_len = max_model_len
+        # a model with recurrent layers: prefix lookups are served as
+        # misses (see PrefixCachingBlockAllocator.bypass_prefix)
+        self.recurrent_state = recurrent_state
         self.allocator = PrefixCachingBlockAllocator(
-            num_blocks, cache.block_size, cache.enable_prefix_caching
+            num_blocks, cache.block_size, cache.enable_prefix_caching,
+            bypass_prefix=recurrent_state,
         )
         self.waiting: collections.deque[Sequence] = collections.deque()
         self.seqs: dict[str, Sequence] = {}  # admitted, not finished

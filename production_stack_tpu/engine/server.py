@@ -1902,6 +1902,15 @@ class EngineServer:
         }
         snap["kv_transfer"] = kv_block
         snap["kv_tier"] = tier_block
+        recurrent = self.engine.recurrent_stats()
+        if recurrent:
+            # a hybrid stack's second kind of cache: per-slot state bytes
+            # beside the pool's, what the recurrent layers ran, and that
+            # prefix lookups are answered as misses
+            snap["recurrent_state"] = {
+                **recurrent, "prefix_cache": "bypassed",
+                "kv_pool_bytes": (self.engine.runner.num_blocks
+                                  * self.engine._kv_bytes_per_block)}
         snap["tenants"] = self.engine.tenant_stats()
         snap["fingerprint"] = self._perf_fingerprint()
         snap["startup_seconds"] = {

@@ -287,6 +287,51 @@ class LoopCounters:
                 "loop_layer_steps_total": self.layer_steps}
 
 
+# -- recurrent-state layers ---------------------------------------------------
+
+class RecurrentCounters:
+    """What the recurrent (KDA) layers of a hybrid stack ran, always on:
+    plain numbers the engine thread adds up where it builds a dispatch
+    (it knows every span it packs), read at scrape time. ``state_bytes``
+    is what the state arrays hold, fixed at start-up.
+
+    A ragged stream's spans go two ways (``ops/kda.py``
+    ``continues_one_row``): its decode rows, one row that continues a
+    stored state, run through the decode step, once a KDA layer a
+    dispatch; every other span (a prompt's chunk, a first token) is what
+    the span scan carries, and ``chunk_tokens`` / ``chunk_spans`` count
+    those alone."""
+
+    def __init__(self, kda_layers: int, state_bytes: int):
+        self.kda_layers, self.state_bytes = kda_layers, state_bytes
+        self.decode_calls = 0   # decode dispatches x iterations x layers
+        self.chunk_tokens = 0   # rows the span scan carried, a layer
+        self.chunk_spans = 0    # spans it carried (state loaded, stored)
+        self.state_resets = 0   # sequences started from a zero state
+
+    def record_decode(self, iterations: int) -> None:
+        self.decode_calls += iterations * self.kda_layers
+
+    def record_ragged(self, q_len, decode_rows, resets: int) -> None:
+        """``q_len`` (slots,) rows of each slot's span in one ragged
+        dispatch, ``decode_rows`` (slots,) bool: the spans the decode
+        step takes."""
+        scanned = np.where(decode_rows, 0, q_len)
+        self.chunk_tokens += int(scanned.sum())
+        self.chunk_spans += int((scanned > 0).sum())
+        self.state_resets += resets
+
+    def snapshot(self, lookups_bypassed: int) -> dict:
+        """``lookups_bypassed``: prefix lookups answered "miss", which the
+        block allocator counts."""
+        return {"kda_decode_calls_total": self.decode_calls,
+                "kda_chunk_tokens_total": self.chunk_tokens,
+                "kda_chunk_spans_total": self.chunk_spans,
+                "recurrent_state_resets_total": self.state_resets,
+                "recurrent_state_bytes": self.state_bytes,
+                "prefix_lookups_bypassed_total": lookups_bypassed}
+
+
 # -- MoE routing counters -----------------------------------------------------
 
 class MoeCounters:
@@ -296,11 +341,19 @@ class MoeCounters:
     by each expert, then the pairs of the null group that padding rows
     are sent to); it comes to the host in the fetch of the step's own
     results, and ``record`` folds it in: no transfer or device program
-    of its own. Everything is summed over layers and dispatches."""
+    of its own. Everything is summed over layers and dispatches.
 
-    def __init__(self, num_experts: int, top_k: int):
+    ``num_experts`` is the experts HELD here. Where that is a share of
+    the experts routed over (``share``: ``ModelConfig.experts_held``), the
+    histogram has one more column before the null group's, the pairs on
+    experts that lie elsewhere: they are routed pairs and not held ones,
+    and loads, their mean and the experts touched are of the held."""
+
+    def __init__(self, num_experts: int, top_k: int, share: bool = False):
         self.num_experts, self.top_k = num_experts, top_k
+        self.share = share
         self.routed_tokens = 0        # (token, choice) pairs sent to experts
+        self.held_pairs = 0           # of them, on experts held here
         self.padding_rows = 0         # stream rows kept out of the routing
         self.expert_load_max = 0      # pairs of the busiest expert
         self.decode_experts_touched = 0  # experts with a pair, decode steps
@@ -309,9 +362,11 @@ class MoeCounters:
     def record(self, kind: str, hist) -> None:
         """``hist``: (..., X + 1) integers on the host, one row per layer
         (and per fused decode iteration) of one dispatch of ``kind``."""
-        h = np.asarray(hist, np.int64).reshape(-1, self.num_experts + 1)
-        loads = h[:, :-1]
-        self.routed_tokens += int(loads.sum())
+        h = np.asarray(hist, np.int64).reshape(
+            -1, self.num_experts + 1 + self.share)
+        loads = h[:, :self.num_experts]
+        self.held_pairs += int(loads.sum())
+        self.routed_tokens += int(h[:, :-1].sum())
         self.padding_rows += int(h[:, -1].sum()) // self.top_k
         self.expert_load_max += int(loads.max(axis=1).sum())
         if kind == "decode":
@@ -321,10 +376,11 @@ class MoeCounters:
     def snapshot(self) -> dict:
         return {
             "moe_routed_tokens_total": self.routed_tokens,
+            "moe_held_pairs_total": self.held_pairs,
             "moe_padding_rows_total": self.padding_rows,
             "moe_expert_load_max_total": self.expert_load_max,
             # pairs per expert: what an even routing would give each
-            "moe_expert_load_mean_total": (self.routed_tokens
+            "moe_expert_load_mean_total": (self.held_pairs
                                            / self.num_experts),
             "moe_decode_experts_touched_total": self.decode_experts_touched,
             "moe_decode_layer_steps_total": self.decode_layer_steps,

@@ -35,6 +35,7 @@ from production_stack_tpu.engine.quant import (
     quant_einsum,
     ragged_quant_dot,
 )
+from production_stack_tpu.ops import kda
 from production_stack_tpu.ops.attention import dense_causal_attention
 from production_stack_tpu.ops.norms import rms_norm
 from production_stack_tpu.ops.rope import apply_rope
@@ -42,6 +43,13 @@ from production_stack_tpu.parallel import shardings as lax_names
 
 # AttendFn: (q, k, v, layer_cache, layer_idx) -> (attn_out, new_layer_cache)
 AttendFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, jnp.ndarray], Tuple[jnp.ndarray, Any]]
+# RecurFn, a recurrent (KDA) layer's stateful call, as AttendFn is an
+# attention layer's: (conv taps (K, 3*H*D), the projected rows
+# (..., T, 3*H*D) = [q | k | v], log-decay g (..., T, H, D), beta
+# (..., T, H), caches, index among the KDA layers) -> (o (..., T, H, D)
+# float32, new caches). ``caches`` is the hybrid
+# stack's whole cache pytree {"kv", "state", "conv"} (engine/kv_cache.py)
+RecurFn = Callable[..., Tuple[jnp.ndarray, Any]]
 
 
 # ---------------------------------------------------------------------------
@@ -90,6 +98,43 @@ def param_specs(cfg: ModelConfig) -> dict:
                 "post_mlp_norm": (L.LAYERS, L.EMBED),
             }
         )
+    mixers = {}
+    if cfg.has_recurrent_state:
+        # the token mixers differ by layer kind and are stacked by kind:
+        # "gqa" over the periods, "kda" over the KDA layers; what every
+        # layer has (norms, the sparse block) stays in "layers"
+        # projections onto all heads at once are kept as plain matrices,
+        # (E, H*D): handed a (E, H, D) stack indexed by layer, the TPU
+        # compiler chose a per-head layout for it and copied the whole
+        # stack at the start of every decode step (5 copies, 1.5 GB, at
+        # the published widths)
+        mixers["gqa"] = {k: layer.pop(k) for k in ("wq", "wk", "wv", "wo")}
+        mixers["gqa"]["wq"] = (L.LAYERS, L.EMBED, L.HEADS)
+        mixers["kda"] = {
+            "w_qkv": (L.LAYERS, L.EMBED, None),  # [q | k | v], each H*D
+            "wo": (L.LAYERS, L.HEADS, L.HEAD_DIM, L.EMBED),
+            "conv": (L.LAYERS, None, None),  # (K, 3*H*D), tap 0 = current
+            "a_log": (L.LAYERS, L.HEADS),
+            "dt_bias": (L.LAYERS, L.HEADS, L.HEAD_DIM),
+            "f_down": (L.LAYERS, L.EMBED, None),
+            "f_up": (L.LAYERS, None, L.HEADS, L.HEAD_DIM),
+            "w_beta": (L.LAYERS, L.EMBED, L.HEADS),
+            "g_down": (L.LAYERS, L.EMBED, None),
+            "g_up": (L.LAYERS, None, L.HEADS, L.HEAD_DIM),
+            "o_norm": (L.LAYERS, L.HEAD_DIM),
+        }
+        if cfg.attn_gate:
+            mixers["gqa"]["wg"] = (L.LAYERS, L.EMBED, L.HEADS)
+    if cfg.moe_scoring == "sigmoid":
+        layer["router_bias"] = (L.LAYERS, L.EXPERTS)
+    if cfg.shared_expert_size:
+        layer.update(
+            {
+                "shared_gate": (L.LAYERS, L.EMBED, L.MLP),
+                "shared_up": (L.LAYERS, L.EMBED, L.MLP),
+                "shared_down": (L.LAYERS, L.MLP, L.EMBED),
+            }
+        )
     if cfg.is_moe:
         layer.update(
             {
@@ -110,6 +155,7 @@ def param_specs(cfg: ModelConfig) -> dict:
     specs = {
         "embed": (L.VOCAB, L.EMBED),
         "layers": layer,
+        **mixers,
         "final_norm": (L.EMBED,),
     }
     if not cfg.tie_word_embeddings:
@@ -150,6 +196,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     def normal(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32) * (fan_in**-0.5)).astype(dt)
 
+    # a hybrid stack's stand-in: see HYBRID_INIT
+    hybrid = cfg.has_recurrent_state
+    out = 2 * LN if hybrid else 1  # x the fan-in of what writes the stream
+
     # stored norm weight giving an effective scale of 1 (Gemma stores
     # zero-centred weights; forward adds cfg.norm_offset)
     norm_one = 1.0 - cfg.norm_offset
@@ -158,7 +208,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         "wq": normal(keys[0], (Ln, E, H, D), E),
         "wk": normal(keys[1], (Ln, E, KH, D), E),
         "wv": normal(keys[2], (Ln, E, KH, D), E),
-        "wo": normal(keys[3], (Ln, H, D, E), H * D),
+        "wo": normal(keys[3], (Ln, H, D, E), H * D * out),
         "mlp_norm": jnp.full((Ln, E), norm_one, dt),
     }
     if cfg.qkv_bias:
@@ -188,14 +238,37 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
                 "post_mlp_norm": jnp.full((Ln, E), gain - cfg.norm_offset, dt),
             }
         )
+    mixers = {}
+    if cfg.has_recurrent_state:
+        Pn = cfg.num_attn_layers
+        mixers["gqa"] = {k: layers.pop(k)[:Pn]
+                         for k in ("wq", "wk", "wv", "wo")}
+        mixers["gqa"]["wq"] = mixers["gqa"]["wq"].reshape(Pn, E, H * D)
+        mixers["kda"] = _init_kda(cfg, keys[13], normal, out)
+        if cfg.attn_gate:
+            mixers["gqa"]["wg"] = normal(keys[14], (Pn, E, H * D), E)
+    if cfg.moe_scoring == "sigmoid":
+        # the selection bias (balancing state of a trained router): zeros
+        layers["router_bias"] = jnp.zeros((Ln, cfg.num_experts), jnp.float32)
+    if cfg.shared_expert_size:
+        Fs = cfg.shared_expert_size
+        ks = jax.random.split(keys[15], 3)
+        layers.update(
+            {
+                "shared_gate": normal(ks[0], (Ln, E, Fs), E),
+                "shared_up": normal(ks[1], (Ln, E, Fs), E),
+                "shared_down": normal(ks[2], (Ln, Fs, E), Fs * out),
+            }
+        )
     if cfg.is_moe:
-        X = cfg.num_experts
+        # the experts held here of num_experts routed over
+        X, Xh = cfg.num_experts, cfg.num_held_experts
         layers.update(
             {
                 "router": normal(keys[4], (Ln, E, X), E),
-                "w_gate": normal(keys[5], (Ln, X, E, F), E),
-                "w_up": normal(keys[6], (Ln, X, E, F), E),
-                "w_down": normal(keys[7], (Ln, X, F, E), F),
+                "w_gate": normal(keys[5], (Ln, Xh, E, F), E),
+                "w_up": normal(keys[6], (Ln, Xh, E, F), E),
+                "w_down": normal(keys[7], (Ln, Xh, F, E), F * out),
             }
         )
     else:
@@ -207,13 +280,68 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             }
         )
     params = {
-        "embed": normal(keys[8], (V, E), E),
+        "embed": normal(keys[8], (V, E), 1 if hybrid else E),
         "layers": layers,
+        **mixers,
         "final_norm": jnp.full((E,), norm_one, dt),
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = normal(keys[9], (E, V), E)
     return params
+
+
+# Random stand-in weights of a KDA layer: the decay. A trained layer's
+# per-channel decay rates spread over orders of magnitude; the stand-in
+# draws ``dt`` log-uniformly in [DT_MIN, DT_MAX] per (head, channel), as
+# state-space models initialise theirs, stores its inverse softplus as
+# ``dt_bias`` and sets ``A_log`` 0, so that with the low-rank projection's
+# unit-variance input alpha = exp(-softplus(z + dt_bias)) lies mostly in
+# 0.9 to 0.999: a state that remembers tens to a thousand tokens, neither
+# dead after a few rows nor (alpha < 1, |eig(I - beta k k^T)| <= 1) able
+# to grow
+KDA_DT_MIN, KDA_DT_MAX = 1e-3, 1e-1
+
+
+# HYBRID_INIT. Random stand-in weights of a hybrid stack: how large what is
+# added to the residual stream is beside what is in it. With the shared
+# stack's values (embedding rows of RMS hidden^-1/2, every sublayer's
+# output of RMS ~0.5) the first sublayer's output buries the embedding and
+# each later one is as large as the stream it joins; with sixteen
+# sublayers of routing, recurrences and gates in a row, a bf16 rounding of
+# one of them then grows through the rest: the served bf16 path read 0.34 /
+# 0.066 against the float32 reference on the chip (limits 0.15 / 0.03) and
+# 0.37-0.42 / 0.07 on the CPU at width 512, where rounding ONE kind of
+# sublayer alone still read 0.035 mean. No tolerance could then tell a
+# fault from rounding (PERF.md section 6, PR 34; PR 31 met the same with a
+# looped stack). So the stand-in is given what a trained stack has: an
+# embedding of unit RMS, and matrices that write into the stream (W_o, the
+# experts' and the shared expert's down projections) at 1 / sqrt(2 x
+# layers) of the usual size, the residual scaling GPT-2 initialises with.
+# At width 512 that reads 0.04-0.05 / 0.008. Every other family keeps the
+# shared values: its programs and its cells are what they were.
+
+
+def _init_kda(cfg: ModelConfig, key: jax.Array, normal, out: int) -> dict:
+    E, H, D = cfg.hidden_size, cfg.kda_heads, cfg.kda_head_dim
+    K, R, Lk = cfg.kda_conv, cfg.kda_rank, cfg.num_kda_layers
+    ks = jax.random.split(key, 11)
+    dt = jnp.exp(jax.random.uniform(
+        ks[9], (Lk, H, D), jnp.float32, jnp.log(KDA_DT_MIN),
+        jnp.log(KDA_DT_MAX)))
+    return {
+        "w_qkv": normal(ks[0], (Lk, E, 3 * H * D), E),
+        "wo": normal(ks[3], (Lk, H, D, E), H * D * out),
+        # depthwise taps over the [q | k | v] row; tap 0 is the current row
+        "conv": normal(ks[4], (Lk, K, 3 * H * D), K),
+        "a_log": jnp.zeros((Lk, H), jnp.float32),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
+        "f_down": normal(ks[5], (Lk, E, R), E),
+        "f_up": normal(ks[6], (Lk, R, H, D), R),
+        "w_beta": normal(ks[7], (Lk, E, H), E),
+        "g_down": normal(ks[8], (Lk, E, R), E),
+        "g_up": normal(ks[10], (Lk, R, H, D), R),
+        "o_norm": jnp.ones((Lk, D), cfg.jax_dtype),
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -247,7 +375,8 @@ _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
 def _moe_mlp(cfg: ModelConfig, router: jnp.ndarray, experts: dict,
-             layer_idx, x: jnp.ndarray, live: Optional[jnp.ndarray] = None
+             layer_idx, x: jnp.ndarray, live: Optional[jnp.ndarray] = None,
+             bias: Optional[jnp.ndarray] = None
              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Sparse MoE block (Mixtral, OLMoE), dropless: route, sort the
     (token, choice) pairs by expert, one grouped matmul per projection
@@ -271,6 +400,9 @@ def _moe_mlp(cfg: ModelConfig, router: jnp.ndarray, experts: dict,
     Routing as published: router logits in float32, softmax over ALL
     experts, top-k of the probabilities; ``cfg.norm_topk_prob``
     renormalises the k chosen (Mixtral), OLMoE uses them as they are.
+    With ``cfg.moe_scoring`` "sigmoid" the scores are sigmoids, the k are
+    chosen by score + ``bias`` (X,) and weighed by the score alone, then
+    renormalised and scaled by ``cfg.routed_scaling``.
 
     ``live`` (bool, x's leading shape) marks the rows that are tokens.
     The others (the tail of a padded ragged stream, idle decode slots)
@@ -278,24 +410,45 @@ def _moe_mlp(cfg: ModelConfig, router: jnp.ndarray, experts: dict,
     rows the grouped matmul covers, add nothing to any expert's load and
     come back as zeros.
 
-    Returns (out like x, histogram (X + 1,) int32: pairs received by each
-    expert, then the pairs of the null group)."""
+    Where the engine holds a share of the experts (``cfg.experts_held``
+    of them from ``cfg.expert_offset``; ``experts`` then holds those
+    alone), the routing is still over all ``cfg.num_experts``; a pair on
+    an expert that lies elsewhere joins a null group of its own and adds
+    nothing here: its chip's part of the sum.
+
+    Returns (out like x, histogram int32: pairs received by each expert
+    held, then, of a share, the pairs on absent experts, then the pairs
+    of the null group; (X + 1,) where every expert is held)."""
     E = x.shape[-1]
     xt = x.reshape(-1, E)  # (T, E) flattened tokens
     T = xt.shape[0]
-    X, k = cfg.num_experts, cfg.num_experts_per_tok
+    k = cfg.num_experts_per_tok
+    X = cfg.num_held_experts  # groups of the grouped matmul, a layer
+    nulls = 2 if cfg.experts_held else 1
 
     logits = jnp.einsum("te,ex->tx", xt, router,
                         preferred_element_type=jnp.float32)
-    weights, top_idx = lax.top_k(jax.nn.softmax(logits, axis=-1), k)  # (T, k)
+    if cfg.moe_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, top_idx = lax.top_k(scores + bias.astype(jnp.float32), k)
+        weights = jnp.take_along_axis(scores, top_idx, axis=-1)
+    else:
+        weights, top_idx = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
     if cfg.norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if cfg.routed_scaling != 1.0:
+        weights = weights * cfg.routed_scaling
 
     expert = top_idx.reshape(T * k).astype(jnp.int32)
+    if cfg.experts_held:
+        local = expert - cfg.expert_offset
+        expert = jnp.where((local >= 0) & (local < X), local, X)
     if live is not None:
-        expert = jnp.where(jnp.repeat(live.reshape(T), k), expert, X)
-    hist = jnp.sum(expert[:, None] == jnp.arange(X + 1, dtype=jnp.int32),
-                   axis=0, dtype=jnp.int32)
+        expert = jnp.where(jnp.repeat(live.reshape(T), k), expert,
+                           X + nulls - 1)
+    hist = jnp.sum(
+        expert[:, None] == jnp.arange(X + nulls, dtype=jnp.int32),
+        axis=0, dtype=jnp.int32)
     order = jnp.argsort(expert, stable=True)  # pair indices, by expert
     rows = xt[order // k]  # (T*k, E) each pair's token, grouped by expert
     sorted_expert = expert[order]
@@ -360,11 +513,12 @@ def forward_tokens(
     live: Optional[jnp.ndarray] = None,
     moe_hist: bool = False,
     loop_count: bool = False,
+    recur: Optional[RecurFn] = None,
 ) -> Tuple[jnp.ndarray, Any]:
     """Embed tokens then run the decoder stack (see forward_hidden)."""
     x = embed_tokens(cfg, params, tokens)
     return forward_hidden(cfg, params, x, positions, attend, kv_caches, lora,
-                          live, moe_hist, loop_count)
+                          live, moe_hist, loop_count, recur)
 
 
 def embed_tokens(cfg: ModelConfig, params: dict, tokens: jnp.ndarray) -> jnp.ndarray:
@@ -387,6 +541,7 @@ def forward_hidden(
     live: Optional[jnp.ndarray] = None,
     moe_hist: bool = False,
     loop_count: bool = False,
+    recur: Optional[RecurFn] = None,
 ) -> Tuple[jnp.ndarray, Any]:
     """Run the decoder stack from pre-embedded activations.
 
@@ -416,6 +571,10 @@ def forward_hidden(
     ``logits_from_hidden``'s). ``loop_count`` appends the number of
     passes run, int32, the outer scan's own carry; the histogram then has
     a leading pass axis.
+
+    A hybrid stack (``cfg.attn_period`` > 1) scans over PERIODS of its
+    layer pattern, see ``_forward_hybrid``; ``recur`` is its recurrent
+    layers' stateful call (default: whole sequences from a zero state).
     """
     layers, experts = params["layers"], None
     if cfg.is_moe:
@@ -432,6 +591,18 @@ def forward_hidden(
         normed = rms_norm(h, weight, cfg.rms_norm_eps, cfg.norm_offset)
         # a float32 stream feeds the matmuls in the model dtype
         return normed.astype(cfg.jax_dtype) if cfg.residual_f32 else normed
+
+    if cfg.has_recurrent_state:
+        if lora is not None:
+            raise ValueError("LoRA on a hybrid (recurrent-state) stack is "
+                             "not supported")
+        x, new_caches, hists = _forward_hybrid(
+            cfg, params, layers, experts, x, attend,
+            recur or _recur_dense, kv_caches, live, pre_norm)
+        out = (x, new_caches)
+        if moe_hist:
+            out += (hists,)
+        return out
 
     def layer_fn(carry, scanned, first_cache_layer=None):
         h, layer_idx, caches = carry
@@ -482,8 +653,8 @@ def forward_hidden(
         hist = None
         if cfg.is_moe:  # LoRA on MoE experts: not supported yet
             with jax.named_scope("moe"):
-                mlp_out, hist = _moe_mlp(cfg, lp["router"], experts,
-                                         layer_idx, normed2, live)
+                mlp_out, hist = _sparse_block(cfg, lp, experts, layer_idx,
+                                              normed2, live)
         else:
             mlp_out = _mlp(cfg, lp, normed2, lb=lb, onehot=onehot)
         if cfg.post_norms:
@@ -525,6 +696,129 @@ def forward_hidden(
     if loop_count:
         out += (passes,)
     return out
+
+
+def _sparse_block(cfg: ModelConfig, lp: dict, experts: dict, layer_idx,
+                  x: jnp.ndarray, live) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The routed experts and, where the family has one, the shared
+    expert every token passes through, added once."""
+    out, hist = _moe_mlp(cfg, lp["router"], experts, layer_idx, x, live,
+                         bias=lp.get("router_bias"))
+    if cfg.shared_expert_size:
+        out = out + _mlp(cfg, {"w_gate": lp["shared_gate"],
+                               "w_up": lp["shared_up"],
+                               "w_down": lp["shared_down"]}, x)
+    return out, hist
+
+
+def _gated(attn: jnp.ndarray, x: jnp.ndarray, wg) -> jnp.ndarray:
+    """The attention output times sigmoid(W_g x), elementwise over heads
+    and head_dim, before the output projection."""
+    gate = quant_einsum("...te,ef->...tf", x, wg).reshape(attn.shape)
+    return (attn.astype(jnp.float32)
+            * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(attn.dtype)
+
+
+def _recur_dense(conv_w, qkv, g, beta, caches, k_idx, *, neg_eigval):
+    """A KDA layer over whole sequences, qkv (B, T, 3*H*D), from an empty
+    past: no cache is read or written (the dense forward, and the
+    definition the cached forms are held against)."""
+    q, k, v = kda.split_heads(kda.conv_dense(qkv, conv_w), g.shape[-2])
+    return kda.recurrence_dense(
+        *kda.prepare(q, k, v, g, beta, neg_eigval)), caches
+
+
+def _kda_mixer(cfg: ModelConfig, kp: dict, x: jnp.ndarray, recur: RecurFn,
+               caches, k_idx) -> Tuple[jnp.ndarray, Any]:
+    """One gated delta-rule layer: projections, the low-rank decay and
+    gate, the stateful part (``recur``: short convolution, SiLU, L2 norm
+    and the recurrence), per-head RMSNorm, the output gate, W_o. Decay,
+    beta and the recurrence are float32."""
+    f32 = jnp.float32
+    qkv = quant_einsum("...te,ef->...tf", x, kp["w_qkv"])
+    z = jnp.einsum("...tr,rhd->...thd",
+                   jnp.einsum("...te,er->...tr", x, kp["f_down"]),
+                   kp["f_up"])
+    g = -jnp.exp(kp["a_log"].astype(f32))[:, None] * jax.nn.softplus(
+        z.astype(f32) + kp["dt_bias"].astype(f32))
+    beta = jax.nn.sigmoid(
+        jnp.einsum("...te,eh->...th", x, kp["w_beta"]).astype(f32))
+    o, caches = recur(kp["conv"], qkv, g, beta, caches, k_idx,
+                      neg_eigval=cfg.kda_neg_eigval)
+    o = rms_norm(o, kp["o_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+    gate = jnp.einsum("...tr,rhd->...thd",
+                      jnp.einsum("...te,er->...tr", x, kp["g_down"]),
+                      kp["g_up"])
+    o = (o * jax.nn.sigmoid(gate.astype(f32))).astype(x.dtype)
+    return quant_einsum("...thd,hde->...te", o, kp["wo"]), caches
+
+
+def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
+                    experts: dict, x, attend: AttendFn,
+                    recur: RecurFn, caches, live, pre_norm):
+    """The hybrid stack: a scan over periods of ``cfg.attn_period``
+    layers, in each the softmax-attention layer first (no positional
+    encoding: order comes from the recurrence), then the KDA layers,
+    every one followed by the sparse block. ``layers`` holds what
+    every layer has, (L, ...); ``params["gqa"]`` the attention mixers,
+    (periods, ...); ``params["kda"]`` the KDA mixers, (KDA layers, ...);
+    ``experts`` the routed experts of all layers, outside the scan (see
+    _moe_mlp). ``caches`` is {"kv": the paged pool of the attention
+    layers alone, "state", "conv": the KDA layers' per-slot state} or
+    None; it rides the scan carry whole. Returns (hidden, caches, routing
+    histograms (L, ...)).
+
+    The scan runs over the period's index alone and every layer takes its
+    own parameters out of the stacks by its absolute index, one dynamic
+    slice a layer, which XLA fuses into the matmul that reads it, as it
+    does with a scan's own slicing. Scanning over stacks reshaped to
+    (periods, layers a period, ...) made the compiler materialise a whole
+    period's slice first: 3 x 200 MB of copies a period in the decode
+    step, counted by the TPU compiler at the published widths."""
+    period = cfg.attn_period
+
+    def at(tree, i):
+        return jax.tree.map(
+            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
+
+    def period_fn(carry, p):
+        h, _, caches = carry
+        hists = []
+        for j in range(period):
+            lp = at(layers, p * period + j)
+            normed = pre_norm(h, lp["attn_norm"])
+            if j == 0:
+                with jax.named_scope("gqa"):
+                    gp = at(params["gqa"], p)
+                    q = quant_einsum("...te,ef->...tf", normed, gp["wq"])
+                    q = q.reshape(*q.shape[:-1], cfg.num_heads, cfg.head_dim)
+                    k = quant_einsum("...te,ehd->...thd", normed, gp["wk"])
+                    v = quant_einsum("...te,ehd->...thd", normed, gp["wv"])
+                    kv = None if caches is None else caches["kv"]
+                    attn, kv = attend(q, k, v, kv, p)
+                    if caches is not None:
+                        caches = {**caches, "kv": kv}
+                    if cfg.attn_gate:
+                        attn = _gated(attn, normed, gp["wg"])
+                    o = quant_einsum("...thd,hde->...te", attn, gp["wo"])
+            else:
+                with jax.named_scope("kda"):
+                    k_idx = p * (period - 1) + (j - 1)
+                    o, caches = _kda_mixer(cfg, at(params["kda"], k_idx),
+                                           normed, recur, caches, k_idx)
+            h = h + o
+            with jax.named_scope("moe"):
+                mlp_out, hist = _sparse_block(
+                    cfg, lp, experts, p * period + j,
+                    pre_norm(h, lp["mlp_norm"]), live)
+            h = h + mlp_out
+            hists.append(hist)
+        return (h, p + 1, caches), jnp.stack(hists)
+
+    (x, _, caches), hists = lax.scan(
+        period_fn, (x, jnp.int32(0), caches),
+        jnp.arange(cfg.num_attn_layers, dtype=jnp.int32))
+    return x, caches, hists.reshape(-1, hists.shape[-1])
 
 
 def logits_from_hidden(cfg: ModelConfig, params: dict, hidden: jnp.ndarray) -> jnp.ndarray:

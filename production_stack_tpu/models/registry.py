@@ -30,6 +30,11 @@ _REGISTRY: dict[str, ModuleType] = {
     # Ouro: the shared stack run cfg.loop_passes times over the same
     # weights, with Gemma-2's norms after each sublayer
     "ouro": llama,
+    # Solar-Open2: the shared file's hybrid stack (cfg.attn_period > 1):
+    # periods of one gated NoPE attention layer and KDA layers, the
+    # sparse block with sigmoid routing, a shared expert and, where the
+    # engine holds a share of the experts, only the pairs that fall on it
+    "solar_open2": llama,
     # encoder-decoder audio transcription: exposes its own forward
     # surface (encode/cross_kv/decode_tokens) instead of the decoder-only
     # protocol; shares param_specs/init_params so weights.py works

@@ -56,7 +56,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-Q_TILE = 128  # stream tokens a grid step owns
+Q_TILE = 128  # stream tokens a grid step owns, at up to 4 query heads a KV head
+
+
+def q_tile_for(group: int) -> int:
+    """Stream tokens a grid step owns at ``group`` query heads a KV head.
+    A tile's rows are tokens x group, and its query block, output block
+    and float32 accumulators grow with them: 128 tokens at G = 8 ask for
+    40 MiB of scoped VMEM (the compiler's count for KH = 8, D = 128), over
+    what the cells' 32 MiB flag allows. Past G = 4 the tile shrinks so that
+    it keeps G = 4's 512 rows; every geometry up to G = 4 keeps 128."""
+    return Q_TILE if group <= 4 else max(Q_TILE * 4 // group, 16)
 # Rows a narrow walk computes on: a one-token span at any G <= 16 and a
 # 1 + 4 verify span at G = 4 (20 rows from a multiple of 4) fit it at any
 # offset. The MXU streams these rows past each (128 x 128) key tile, so
@@ -81,12 +91,12 @@ def narrow_walk(lo, hi, group: int, rows: int, xp=jnp):
 
 
 def count_walks(cu_q_lens, stream_tokens: int, group: int,
-                q_tile: int = Q_TILE) -> tuple[int, int]:
+                q_tile: int | None = None) -> tuple[int, int]:
     """(walks, narrow walks) of one dispatch, on the host: the non-empty
     (tile, span) pairs the kernel walks for these span offsets, and how
     many of them meet ``narrow_walk``."""
     cu = np.asarray(cu_q_lens, np.int64)
-    tq = min(q_tile, stream_tokens)
+    tq = min(q_tile or q_tile_for(group), stream_tokens)
     start, end = cu[:-1], cu[1:]
     live = end > start
     start, end = start[live], end[live]
@@ -317,7 +327,7 @@ def ragged_paged_attention_pallas(
     cu_q_lens: jnp.ndarray,  # (S+1,) int32 cumulative span offsets
     context_lens: jnp.ndarray,  # (S,) int32 total context per slot
     layer_idx: jnp.ndarray | int = 0,
-    q_tile: int = Q_TILE,
+    q_tile: int | None = None,  # default: q_tile_for(G)
     windows: int = 8,
     interpret: bool = False,
     soft_cap: float = 0.0,
@@ -326,7 +336,7 @@ def ragged_paged_attention_pallas(
     L, N, bs, KH2, _ = kv_cache.shape
     KH = KH2 // 2
     G = H // KH
-    TQ = min(q_tile, T)
+    TQ = min(q_tile or q_tile_for(G), T)
     Tp = -(-T // TQ) * TQ
     if Tp != T:  # tail-pad the stream to a tile multiple (rows → zeros)
         q = jnp.pad(q, ((0, Tp - T), (0, 0), (0, 0)))

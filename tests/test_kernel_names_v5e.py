@@ -173,3 +173,88 @@ def test_moe_block_compiles_to_named_grouped_matmuls(one_chip, rows):
     # the block's transients are its sorted rows (64 MB each at stream
     # size), never a layer's experts (805 MB)
     assert compiled.memory_analysis().temp_size_in_bytes < 400 * 2 ** 20
+
+
+# -- Solar-Open2's share on one chip (chipbench solar-open2-250b-ep16-l8) ----
+# the two KDA kernels at the cell's shapes (6 KDA layers, 64 slots, 64 heads
+# of 128, a 2048-token stream), under the names chipbench/layer_metrics/
+# kda_*.json match, and the attention kernels at its third geometry, KH=8,
+# G=8, under the 32 MiB scoped-VMEM limit the configuration's manifest sets
+# (the ragged kernel keeps G=4's 512 rows a tile: q_tile_for)
+
+KDA_STATE = ((6, 64, 64, D, D), jnp.float32)
+
+
+def _kda_cases():
+    from production_stack_tpu.ops.kda_pallas import (
+        kda_chunk_scan,
+        kda_decode_step,
+    )
+
+    rows = [((64, 64, D), jnp.float32)] * 5
+    stream = [((2048, 64, D), jnp.float32)] * 5
+    return {
+        "kda_decode_step": (
+            lambda st, a, kb, k, q, vb, act: kda_decode_step(
+                st, 3, a, kb, k, q, vb, act),
+            (KDA_STATE, *rows, ((64,), jnp.bool_))),
+        "kda_chunk_scan": (
+            lambda st, a, kb, k, q, vb, cu, ctx: kda_chunk_scan(
+                st, 3, a, kb, k, q, vb, cu, ctx),
+            (KDA_STATE, *stream, ((65,), I32), ((64,), I32))),
+    }
+
+
+@pytest.mark.parametrize("name", ["kda_chunk_scan", "kda_decode_step"])
+def test_kda_kernel_is_a_named_custom_call_at_the_cells_shapes(one_chip, name):
+    fn, shapes = _kda_cases()[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(fn, donate_argnums=0).lower(*args).compile()
+    assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = .*? custom-call\(",
+                     compiled.as_text(), flags=re.M)
+    # the state of all layers is updated in place: no second copy of it
+    # (1.6 GB) among the program's temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 600 * 2 ** 20
+
+
+G8_CACHE = ((2, 4096, BS, 2 * 8, D), jnp.bfloat16)
+G8_CASES = {
+    "ragged_paged_attention": (
+        lambda q, c, bt, cu, cl: ragged_paged_attention_pallas(
+            q, c, bt, cu, cl, layer_idx=1),
+        (((2048, 64, D), jnp.bfloat16), G8_CACHE, ((64, 512), I32),
+         ((65,), I32), ((64,), I32))),
+    "paged_decode_attention": (
+        lambda q, c, bt, cl: paged_decode_attention_pallas(
+            q, c, bt, cl, layer_idx=1),
+        (((64, 64, D), jnp.bfloat16), G8_CACHE, ((64, 512), I32),
+         ((64,), I32))),
+    "kv_cache_write": (
+        lambda c, new, sm: kv_cache_write_pallas(c, new, sm, layer_idx=1),
+        (G8_CACHE, ((2048, 2 * 8, D), jnp.bfloat16), ((2048,), I32))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(G8_CASES))
+def test_kernel_compiles_at_kh8_g8_under_the_manifests_vmem_limit(
+        one_chip, name):
+    fn, shapes = G8_CASES[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile(
+        compiler_options={"xla_tpu_scoped_vmem_limit_kib": 32768}).as_text()
+    assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = .*? custom-call\(",
+                     text, flags=re.M)
+
+
+def test_the_ragged_tile_is_keyed_by_the_group():
+    """128 stream tokens a tile up to G = 4 (Qwen3's and OLMoE's programs
+    are what they were), 512 rows a tile past it."""
+    from production_stack_tpu.ops.ragged_paged_attention_pallas import (
+        Q_TILE,
+        q_tile_for,
+    )
+
+    assert [q_tile_for(g) for g in (1, 3, 4)] == [Q_TILE] * 3 == [128] * 3
+    assert (q_tile_for(8), q_tile_for(16)) == (64, 32)
