@@ -179,7 +179,8 @@ def _kernel_cases(KH: int, G: int, D: int, bs: int, dtype):
         want = np.concatenate(want)[:live]
         if not np.isfinite(got).all():
             raise SmokeFailure("ragged kernel produced non-finite values")
-        tail = float(np.abs(got[live:]).max())
+        # (a stream filled to its last token has no tail)
+        tail = float(np.abs(got[live:]).max()) if live < T else 0.0
         if tail != 0.0:
             raise SmokeFailure(f"ragged tail padding not zero ({tail})")
         return (scaled_err(got[:live], want),
@@ -245,8 +246,20 @@ def _kernel_cases(KH: int, G: int, D: int, bs: int, dtype):
     decode_mix = functools.partial(
         ragged, [1] * 64 + [160],
         [int(c) for c in np.linspace(300, 1000, 64)] + [160], 2048, 64)
+
+    # a prefill-heavy cell's ragged step: a lone 2048-token chunk behind
+    # 1024 tokens of context (every tile owned whole: the kernel's
+    # interior window body up to each tile's diagonal), and the same
+    # stream shared with 10 decode rows at context 3072
+    def prefill_full():
+        runs = [ragged([2048], [3072], 2048, 192),
+                ragged([1] * 10 + [2038], [3072] * 11, 2048, 192)]
+        return (max(err for err, _ in runs),
+                "; ".join(detail for _, detail in runs))
+
     return [("ragged_paged_attention", mixed),
             ("ragged_paged_attention.decode_mix", decode_mix),
+            ("ragged_paged_attention.prefill_full", prefill_full),
             ("paged_decode_attention", decode),
             ("kv_cache_write", kv_write)]
 

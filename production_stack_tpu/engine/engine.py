@@ -34,6 +34,7 @@ from production_stack_tpu.engine.tracing import StepClock
 from production_stack_tpu.ops.kda import continues_one_row
 from production_stack_tpu.ops.ragged_paged_attention_pallas import (
     count_walks,
+    count_windows,
 )
 from production_stack_tpu.parallel.mesh import build_mesh
 from production_stack_tpu.tenancy import split_shares
@@ -279,6 +280,10 @@ class LLMEngine:
         # block (ops/ragged_paged_attention_pallas.count_walks)
         self.ragged_attn_walks = 0
         self.ragged_attn_narrow_walks = 0
+        # context windows those walks stream, and those a full-tile walk
+        # runs through the kernel's interior body (count_windows)
+        self.ragged_attn_windows = 0
+        self.ragged_attn_interior_windows = 0
         self.decode_dispatches = 0  # decode_multi dispatches
         # attention calls those dispatches made (fused iterations x cache
         # layers each), and those that ran the Pallas decode kernel's slab
@@ -1162,6 +1167,11 @@ class LLMEngine:
         walks, narrow = count_walks(self._r_cu, T, self.config.model.q_per_kv)
         self.ragged_attn_walks += walks
         self.ragged_attn_narrow_walks += narrow
+        windows, interior = count_windows(
+            self._r_cu, self._context_lens, T, self.config.model.q_per_kv,
+            self.config.cache.block_size)
+        self.ragged_attn_windows += windows
+        self.ragged_attn_interior_windows += interior
 
         # scheduler-visible state advances NOW; results land next step
         # (same deferral contract as _run_prefill / chained decode). A spec
@@ -1746,6 +1756,9 @@ class LLMEngine:
             "ragged_live_tokens_total": self.ragged_live_tokens,
             "ragged_attn_walks_total": self.ragged_attn_walks,
             "ragged_attn_narrow_walks_total": self.ragged_attn_narrow_walks,
+            "ragged_attn_windows_total": self.ragged_attn_windows,
+            "ragged_attn_interior_windows_total":
+                self.ragged_attn_interior_windows,
             "decode_dispatches_total": self.decode_dispatches,
             "decode_attn_calls_total": self.decode_attn_calls,
             "decode_attn_slab_calls_total": self.decode_attn_slab_calls,

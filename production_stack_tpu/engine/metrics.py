@@ -175,6 +175,18 @@ class EngineStatsCollector:
             s.get("ragged_attn_narrow_walks_total", 0),
         )
         yield counter(
+            "vllm:ragged_attn_windows",
+            "Context windows (8 KV blocks) the ragged attention kernel's "
+            "walks stream, up to each walk's causal reach",
+            s.get("ragged_attn_windows_total", 0),
+        )
+        yield counter(
+            "vllm:ragged_attn_interior_windows",
+            "Windows of full-tile walks that nothing can mask: the ones "
+            "the kernel runs through its interior body",
+            s.get("ragged_attn_interior_windows_total", 0),
+        )
+        yield counter(
             "vllm:decode_dispatches",
             "decode_multi dispatches issued (decode-only steps)",
             s.get("decode_dispatches_total", 0),

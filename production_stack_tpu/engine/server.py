@@ -1868,6 +1868,12 @@ class EngineServer:
         walks = {"ragged_attn_walks": self.engine.ragged_attn_walks,
                  "ragged_attn_narrow_walks":
                      self.engine.ragged_attn_narrow_walks,
+                 # the context windows those walks stream, and those on
+                 # the kernel's interior body (vllm:ragged_attn_windows_
+                 # total, vllm:ragged_attn_interior_windows_total)
+                 "ragged_attn_windows": self.engine.ragged_attn_windows,
+                 "ragged_attn_interior_windows":
+                     self.engine.ragged_attn_interior_windows,
                  # the decode dispatches' attention calls, and those on
                  # the decode kernel's slab body (vllm:decode_attn_calls_
                  # total, vllm:decode_attn_slab_calls_total)

@@ -32,6 +32,16 @@ offsets alone). The flash state lives in VMEM scratch, head-major, so a
 walk reads and writes just its block; rows of the block that belong to a
 neighbouring span stay masked as everywhere else.
 
+A full-tile walk streams the *interior* of its context (the windows that
+end at or below the position of the tile's first token, counted by
+``interior_windows`` from the scalars the kernel already holds) through a
+window body of its own: nothing in such a window can be masked, so it
+builds no mask, takes each head's K and V from the landed slab in bf16
+with one sublane-strided load a head pair, and keeps the flash state at
+register width. The one or two windows left (the causal diagonal, the
+context's tail) take the masked body, as every walk of a narrow block or
+of a tile shared between spans does.
+
 There are no padding lanes between spans and no shape buckets: the only
 compile-relevant shape is the budget-padded ``T`` (tokens the scheduler
 may batch) and the fixed ``S`` slot count, so the steady-state engine
@@ -56,6 +66,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+WINDOWS = 8  # KV blocks a context window holds: 128 tokens at block 16
+# lanes of the flash state's scratch: a vector register's width. The
+# masked body keeps a row's (m, l) in lane 0, as a ``(KH, R, 1)`` array
+# would lie there; the interior body uses all of them
+LANES = 128
 Q_TILE = 128  # stream tokens a grid step owns, at up to 4 query heads a KV head
 
 
@@ -90,15 +105,29 @@ def narrow_walk(lo, hi, group: int, rows: int, xp=jnp):
     return hi * group - r0 <= ROW_BLOCK, r0
 
 
-def count_walks(cu_q_lens, stream_tokens: int, group: int,
-                q_tile: int | None = None) -> tuple[int, int]:
-    """(walks, narrow walks) of one dispatch, on the host: the non-empty
-    (tile, span) pairs the kernel walks for these span offsets, and how
-    many of them meet ``narrow_walk``."""
+def interior_windows(lo, hi, q_tile: int, first_pos, win_tokens: int,
+                     xp=jnp):
+    """Leading context windows of ``win_tokens`` in which nothing can be
+    masked, for a walk that owns stream tokens ``[lo, hi)`` of its tile
+    (tile-relative) and whose tile's first token sits at absolute position
+    ``first_pos``: none unless the span owns every row of the tile, else
+    the windows ``w`` with ``(w + 1) * win_tokens - 1 <= first_pos``, which
+    every row of the tile reaches causally and which end below the
+    context's end. The kernel calls it on SMEM scalars, ``count_windows``
+    on numpy arrays (``xp=np``)."""
+    return xp.where((lo == 0) & (hi == q_tile),
+                    (first_pos + 1) // win_tokens, 0)
+
+
+def _walk_offsets(cu_q_lens, stream_tokens: int, group: int,
+                  q_tile: int | None):
+    """(tq, span, tile, lo, hi, start) of a dispatch's walks, on the host:
+    the non-empty (tile, span) pairs, ``span`` indexing the live spans
+    whose stream offsets ``start`` holds, ``lo`` / ``hi`` tile-relative."""
     cu = np.asarray(cu_q_lens, np.int64)
     tq = min(q_tile or q_tile_for(group), stream_tokens)
     start, end = cu[:-1], cu[1:]
-    live = end > start
+    live = np.flatnonzero(end > start)
     start, end = start[live], end[live]
     first = start // tq
     per_span = (end - 1) // tq - first + 1  # tiles a span overlaps
@@ -108,8 +137,73 @@ def count_walks(cu_q_lens, stream_tokens: int, group: int,
         np.cumsum(per_span) - per_span, per_span)
     lo = np.maximum(start[span], tile * tq) - tile * tq
     hi = np.minimum(end[span], (tile + 1) * tq) - tile * tq
+    return tq, live[span], tile, lo, hi, start[span]
+
+
+def count_walks(cu_q_lens, stream_tokens: int, group: int,
+                q_tile: int | None = None) -> tuple[int, int]:
+    """(walks, narrow walks) of one dispatch, on the host: the non-empty
+    (tile, span) pairs the kernel walks for these span offsets, and how
+    many of them meet ``narrow_walk``."""
+    tq, span, _, lo, hi, _ = _walk_offsets(
+        cu_q_lens, stream_tokens, group, q_tile)
     narrow, _ = narrow_walk(lo, hi, group, tq * group, xp=np)
     return len(span), int(np.sum(narrow))
+
+
+def count_windows(cu_q_lens, context_lens, stream_tokens: int, group: int,
+                  block_size: int, q_tile: int | None = None,
+                  windows: int = WINDOWS) -> tuple[int, int]:
+    """(windows, interior windows) of one dispatch, on the host: the
+    context windows of ``windows * block_size`` tokens that the kernel's
+    walks stream up to each walk's causal reach, and those among them that
+    a full-tile walk runs through its interior body
+    (``interior_windows``, the kernel's own predicate)."""
+    tq, span, tile, lo, hi, start = _walk_offsets(
+        cu_q_lens, stream_tokens, group, q_tile)
+    cu = np.asarray(cu_q_lens, np.int64)
+    # absolute position of a span's first token: ctx - q_len
+    pos0 = np.asarray(context_lens, np.int64)[span] - (
+        cu[span + 1] - cu[span])
+    win_tokens = windows * block_size
+    at = tile * tq - start  # tile-relative 0 as an offset into the span
+    nwin = -(-(pos0 + at + hi) // win_tokens)  # reach = last row's + 1
+    interior = np.minimum(
+        interior_windows(lo, hi, tq, pos0 + at, win_tokens, xp=np), nwin)
+    return int(np.sum(nwin)), int(np.sum(interior))
+
+
+def _lanes(x, n: int):
+    """``x`` (..., LANES), equal along its last axis, at ``n`` lanes."""
+    return x if x.shape[-1] == n else jnp.broadcast_to(
+        x[..., 0:1], x.shape[:-1] + (n,))
+
+
+def _window_heads(buf, slot, q_dtype):
+    """Every head's K and V ``(win_tokens, D)`` of the window landed in
+    ``buf[slot]`` ``(W, bs, 2KH, D)``, as two lists, in the type the MXU
+    takes them. Where a token's bf16 slab is whole ``(16, 128)`` tiles the
+    window is read as 32-bit words, two heads a word: one sublane-strided
+    load a head pair gathers the pair's rows of all tokens (the slab is
+    never cut into per-head slices), and a shift or a mask leaves either
+    head as the high half of a float32 that converts to bf16 exactly.
+    Elsewhere (narrow test shapes, float32 caches) plain slices."""
+    _, W, bs, KH2, D = buf.shape
+    T = W * bs
+    if (buf.dtype == jnp.bfloat16 and q_dtype == jnp.bfloat16
+            and KH2 % 16 == 0 and D % 128 == 0):
+        words = buf.at[slot].reshape(T * KH2, D).bitcast(jnp.uint32)
+        heads = []
+        for j in range(KH2 // 2):  # word row j of a token: heads 2j, 2j+1
+            pair = words[pl.ds(j, T, stride=KH2 // 2), :]
+            heads += [pltpu.bitcast(half, jnp.float32).astype(jnp.bfloat16)
+                      for half in (pair << 16, pair & jnp.uint32(0xFFFF0000))]
+    else:
+        kv = buf[slot].reshape(T, KH2, D)
+        if kv.dtype != q_dtype:
+            kv = kv.astype(jnp.float32)
+        heads = [kv[:, h, :] for h in range(KH2)]
+    return heads[:KH2 // 2], heads[KH2 // 2:]
 
 
 def _ragged_kernel(
@@ -128,8 +222,8 @@ def _ragged_kernel(
     # scratch
     buf,  # (2, W, bs, 2KH, D) VMEM
     sems,  # (2, W) DMA sems
-    m_ref,  # (KH, R, 1) f32 VMEM — flash running max
-    l_ref,  # (KH, R, 1) f32 VMEM — flash running sum
+    m_ref,  # (KH, R, LANES) f32 VMEM — flash running max
+    l_ref,  # (KH, R, LANES) f32 VMEM — flash running sum
     acc_ref,  # (KH, R, D) f32 VMEM — flash accumulator
     *,
     block_size: int,
@@ -208,7 +302,7 @@ def _ragged_kernel(
             issue(0, 0)
 
             def win_body(w, _):
-                m, l = m_ref[:, rs, :], l_ref[:, rs, :]
+                m, l = m_ref[:, rs, 0:1], l_ref[:, rs, 0:1]
                 slot = jax.lax.rem(w, 2)
 
                 @pl.when(w + 1 < nwin)
@@ -248,9 +342,9 @@ def _ragged_kernel(
                 # EXPLICITLY rather than through the exp underflow the
                 # bucketed kernels rely on.
                 p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
-                l_ref[:, rs, :] = l * alpha + jnp.sum(
+                l_ref[:, rs, 0:1] = l * alpha + jnp.sum(
                     p, axis=-1, keepdims=True)
-                m_ref[:, rs, :] = m_new
+                m_ref[:, rs, 0:1] = m_new
                 # blocks past `reach` were never DMA'd: zero their V rows
                 # — 0 x NaN = NaN would poison the accumulator through
                 # masked-out weights
@@ -271,7 +365,66 @@ def _ragged_kernel(
                     acc_ref[:, rs, :] * alpha + jnp.stack(acc_heads))
                 return 0
 
-            jax.lax.fori_loop(0, nwin, win_body, 0)
+            n_int = 0
+            if rows == R:
+                def interior_body(w, _):
+                    """A window nothing can mask, for the whole tile: every
+                    block of it was fetched, every row reaches every key. Head
+                    by head, operands as stored (bf16 in, float32 out of the
+                    MXU, the weights rounded to the cache's type as the MXU
+                    rounds them in ``win_body``), the state lane-replicated so
+                    that no update broadcasts along lanes."""
+                    slot = jax.lax.rem(w, 2)
+
+                    @pl.when(w + 1 < nwin)
+                    def _():
+                        issue(jax.lax.rem(w + 1, 2), w + 1)
+
+                    for j in range(W):
+                        dma(slot, w, j).wait()
+
+                    k, v = _window_heads(buf, slot, q_ref.dtype)
+                    for h in range(KH):
+                        sc = jax.lax.dot_general(
+                            q_ref[0, h].astype(k[h].dtype), k[h],
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        ) * scale  # (R, T)
+                        if soft_cap:
+                            sc = soft_cap * jnp.tanh(sc / soft_cap)
+                        m = m_ref[h]
+                        m_new = jnp.maximum(
+                            m, jnp.max(sc, axis=-1, keepdims=True))
+                        alpha = jnp.exp(m - m_new)
+                        p = jnp.exp(sc - _lanes(m_new, win_tokens))
+                        # a window as wide as the state adds its weights lane
+                        # by lane: the row sum is taken once, after the loop
+                        l_ref[h] = l_ref[h] * alpha + (
+                            p if win_tokens == LANES
+                            else jnp.sum(p, axis=-1, keepdims=True))
+                        m_ref[h] = m_new
+                        acc_ref[h] = acc_ref[h] * _lanes(alpha, D) + (
+                            jax.lax.dot_general(
+                                p.astype(v[h].dtype), v[h],
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32))
+                    return 0
+
+                # a span that owns the whole tile is the only one to touch
+                # its state, so the interior body finds (m, l) as the
+                # kernel's first lines left them, in every lane
+                n_int = jnp.minimum(nwin, interior_windows(
+                    jnp.maximum(q_start, tile0) - tile0,
+                    jnp.minimum(q_end, tile0 + TQ) - tile0, TQ,
+                    ctx - q_len + (tile0 - q_start), win_tokens))
+                jax.lax.fori_loop(0, n_int, interior_body, 0)
+                if win_tokens == LANES:
+                    @pl.when(n_int > 0)
+                    def _():
+                        l_ref[...] = jnp.broadcast_to(
+                            jnp.sum(l_ref[...], axis=-1, keepdims=True),
+                            l_ref.shape)
+            jax.lax.fori_loop(n_int, nwin, win_body, 0)
 
         # the rows this span owns in the tile decide the block it pays for
         narrow, r0 = narrow_walk(
@@ -290,7 +443,7 @@ def _ragged_kernel(
     jax.lax.fori_loop(0, cnt, seq_body, 0)
     # rows owned by no sequence (tail padding) kept l = 0 → output 0
     o_ref[0] = (
-        acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        acc_ref[...] / jnp.maximum(l_ref[:, :, 0:1], 1e-30)
     ).astype(o_ref.dtype)
 
 
@@ -328,7 +481,7 @@ def ragged_paged_attention_pallas(
     context_lens: jnp.ndarray,  # (S,) int32 total context per slot
     layer_idx: jnp.ndarray | int = 0,
     q_tile: int | None = None,  # default: q_tile_for(G)
-    windows: int = 8,
+    windows: int = WINDOWS,
     interpret: bool = False,
     soft_cap: float = 0.0,
 ) -> jnp.ndarray:
@@ -363,8 +516,8 @@ def ragged_paged_attention_pallas(
         scratch_shapes=[
             pltpu.VMEM((2, windows, bs, KH2, D), kv_cache.dtype),
             pltpu.SemaphoreType.DMA((2, windows)),
-            pltpu.VMEM((KH, R, 1), jnp.float32),
-            pltpu.VMEM((KH, R, 1), jnp.float32),
+            pltpu.VMEM((KH, R, LANES), jnp.float32),
+            pltpu.VMEM((KH, R, LANES), jnp.float32),
             pltpu.VMEM((KH, R, D), jnp.float32),
         ],
     )

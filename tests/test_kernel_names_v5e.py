@@ -258,3 +258,40 @@ def test_the_ragged_tile_is_keyed_by_the_group():
 
     assert [q_tile_for(g) for g in (1, 3, 4)] == [Q_TILE] * 3 == [128] * 3
     assert (q_tile_for(8), q_tile_for(16)) == (64, 32)
+
+
+# -- the ragged kernel's two window bodies at the cells' three geometries ----
+# A 2048-token stream over 64 slots of 8192 positions, as the engines run
+# it: KH=8, G=4 (qwen3-8b-l16) and KH=8, G=8 (solar-open2-250b-ep16-l8)
+# under the 32 MiB their manifests set, KH=16, G=1 (olmoe-1b-7b-l8,
+# ouro-2.6b) under the DEFAULT limit. The interior body (strided 32-bit
+# loads of the landed slab, lane-wide flash state) has to get through
+# Mosaic at each, and may ask no more scoped VMEM than the kernel did
+# with one body: 18.50 MiB at 512 rows a tile, 10.60 MiB at KH=16, G=1
+# (the compiler's own count, read off its refusal at a limit just below).
+
+RAGGED_VMEM_MIB = {(8, 4): 18.50, (16, 1): 10.60, (8, 8): 18.50}
+
+
+@pytest.mark.parametrize("kh,g", sorted(RAGGED_VMEM_MIB))
+def test_ragged_kernel_compiles_at_the_cells_geometries(one_chip, kh, g):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((2048, kh * g, D), jnp.bfloat16),
+        ((2, 2048, BS, 2 * kh, D), jnp.bfloat16),
+        ((64, 512), I32), ((65,), I32), ((64,), I32))]
+    lowered = jax.jit(
+        lambda q, c, bt, cu, cl: ragged_paged_attention_pallas(
+            q, c, bt, cu, cl, layer_idx=1)).lower(*args)
+    limit_kib = 16384 if g == 1 else 32768
+    text = lowered.compile(compiler_options={
+        "xla_tpu_scoped_vmem_limit_kib": limit_kib}).as_text()
+    assert re.search(r"^\s*(?:ROOT )?%ragged_paged_attention[.\d]* = "
+                     r".*? custom-call\(", text, flags=re.M)
+    # what it asks: the compiler names the size where the limit is short
+    ask = RAGGED_VMEM_MIB[kh, g]
+    with pytest.raises(Exception, match="Scoped allocation") as refusal:
+        lowered.compile(compiler_options={
+            "xla_tpu_scoped_vmem_limit_kib": int((ask - 0.5) * 1024)})
+    size = re.search(r"Scoped allocation with size ([\d.]+)M",
+                     str(refusal.value))
+    assert size and float(size.group(1)) <= ask

@@ -40,9 +40,12 @@ from production_stack_tpu.ops.paged_attention import (
     ragged_paged_attention,
     write_kv,
 )
+from production_stack_tpu.ops import ragged_paged_attention_pallas as rpa
 from production_stack_tpu.ops.ragged_paged_attention_pallas import (
     ROW_BLOCK,
     count_walks,
+    count_windows,
+    interior_windows,
     narrow_walk,
     ragged_paged_attention_pallas,
     tile_metadata,
@@ -185,17 +188,67 @@ def _row_block_cases():
 
 
 ROW_BLOCK_CASES = _row_block_cases()
+WIN = 2 * BS  # tokens of a context window at the tests' windows=2
+
+
+def _interior_cases():
+    """Spans around the kernel's interior body (the leading windows of a
+    walk that owns its whole tile, up to the one holding the tile's first
+    token's position; ``interior_windows``) at G = 1, 4, 8 on tiles of 64
+    rows, wider than ``ROW_BLOCK``: (id, G, q_tile, q_lens, ctx_lens,
+    narrow walks or None, soft cap, interior windows expected). Windows
+    are ``WIN`` = 8 tokens; every case reads cache layer 1 of 2."""
+    cases = []
+    for G, tq in ((1, 64), (4, 16), (8, 8)):
+        def whole(prior, tiles):
+            # a span of whole tiles behind ``prior`` tokens of context:
+            # tile i starts at position prior + i * tq
+            return sum((prior + i * tq + 1) // WIN for i in range(tiles))
+
+        cases += [
+            # three whole tiles over a context of many windows
+            (f"g{G}-whole-tiles", G, tq, [3 * tq], [3 * tq + 40], None,
+             0.0, whole(40, 3)),
+            # the prior context ends mid-window: the window holding the
+            # first row's reach (position 21) takes the masked body
+            (f"g{G}-reach-mid-window", G, tq, [tq], [tq + 21], None, 0.0,
+             2),
+            # first rows at the last and the last-but-one position of a
+            # window: the window is interior only for the former
+            (f"g{G}-window-edge", G, tq, [tq, tq], [tq + 23, tq + 22],
+             None, 0.0, 3 + 2),
+            # the context ends one token into a window (8k + 1)
+            (f"g{G}-one-token-tail", G, tq, [2 * tq], [2 * tq + 33], None,
+             0.0, whole(33, 2)),
+            # every tile shared by a chunk's tail and the next one's head:
+            # long contexts, no interior window
+            (f"g{G}-shared-tiles", G, tq, [tq // 2, tq, tq // 2],
+             [tq // 2 + 30, tq + 50, tq // 2 + 17], None, 0.0, 0),
+            # a whole tile, then a decode row and a chunk sharing the next
+            # one; Gemma-2's score cap through both window bodies
+            (f"g{G}-softcap", G, tq, [tq, 1, tq - 1],
+             [tq + 26, 30, tq + 8], None, 5.0, 3),
+            # a fresh prompt of whole tiles: the first tile has nothing
+            # below its diagonal, the second the first one's tokens
+            (f"g{G}-fresh-prompt", G, tq, [2 * tq], [2 * tq], None, 0.0,
+             tq // WIN),
+        ]
+    return cases
+
+
+INTERIOR_CASES = _interior_cases()
 
 
 @pytest.mark.parametrize(
     "case", list(range(len(FUZZ_CASES)))
-    + [pytest.param(c, id=c[0]) for c in ROW_BLOCK_CASES])
+    + [pytest.param(c, id=c[0]) for c in ROW_BLOCK_CASES + INTERIOR_CASES])
 def test_ragged_pallas_matches_reference(case):
+    interior = None
     if isinstance(case, int):
         q_lens, ctx_lens, M = FUZZ_CASES[case]
         G, tq, narrow, cap, seed = H // KH, 8, 0, 0.0, case
     else:
-        _, G, tq, q_lens, ctx_lens, narrow, cap = case
+        _, G, tq, q_lens, ctx_lens, narrow, cap, *interior = case
         M = max(-(-c // BS) for c in ctx_lens)
         seed = len(q_lens) + G
     rng = np.random.default_rng(seed)
@@ -204,7 +257,11 @@ def test_ragged_pallas_matches_reference(case):
         num_blocks=2 + sum(-(-c // BS) for c in ctx_lens), G=G,
     )
     # the case runs the path it was written for
-    assert count_walks(cu, int(cu[-1]), G, q_tile=tq)[1] == narrow
+    if narrow is not None:
+        assert count_walks(cu, int(cu[-1]), G, q_tile=tq)[1] == narrow
+    if interior:
+        assert count_windows(cu, ctx_lens, int(cu[-1]), G, BS, q_tile=tq,
+                             windows=2)[1] == interior[0]
     want = ragged_paged_attention(
         jnp.asarray(q), cache[1], jnp.asarray(tables),
         jnp.asarray(ctx_lens, jnp.int32), jnp.asarray(seq_ids),
@@ -247,6 +304,151 @@ def test_count_walks_equals_brute_force(G, tq):
         assert count_walks(cu, T, G, q_tile=tq) == (walks, narrow)
     # an idle dispatch holds no walk
     assert count_walks(np.zeros(5, np.int32), 64, G, q_tile=tq) == (0, 0)
+
+
+@pytest.mark.parametrize("G,tq", [(1, 64), (4, 16), (4, 128), (8, 8)])
+def test_count_windows_equals_brute_force(G, tq):
+    """The engine's host-side count (``vllm:ragged_attn_windows_total``
+    and ``..._interior_windows_total``) is the kernel's own iteration:
+    every window up to a walk's causal reach, and of a walk that owns its
+    whole tile those in which a per-key loop finds nothing to mask."""
+    rng = np.random.default_rng(G * 1000 + tq)
+    for draw in range(20):
+        S = int(rng.integers(1, 12))
+        q_lens = rng.choice([0, 1, 1, 5, tq - 1, tq, tq, 2 * tq, 3 * tq + 2,
+                             300], S)
+        ctx = q_lens + np.where(q_lens > 0, rng.integers(0, 90, S), 0)
+        cu = np.concatenate([[0], np.cumsum(q_lens)]).astype(np.int32)
+        T = max(int(cu[-1]), 1) + int(rng.integers(0, 50))
+        TQ = min(tq, T)
+        windows = interior = 0
+        for t in range(-(-T // TQ)):
+            for s in range(S):
+                lo = max(cu[s], t * TQ)
+                hi = min(cu[s + 1], (t + 1) * TQ)
+                if hi <= lo:
+                    continue
+                # positions of the rows the span owns in this tile
+                pos = [ctx[s] - q_lens[s] + (g - cu[s])
+                       for g in range(lo, hi)]
+                nwin = -(-(pos[-1] + 1) // WIN)
+                windows += nwin
+                for w in range(nwin):
+                    unmasked = all(
+                        k <= p and k < ctx[s]
+                        for p in pos for k in range(w * WIN, (w + 1) * WIN))
+                    interior += unmasked and hi - lo == TQ
+        assert count_windows(cu, ctx, T, G, BS, q_tile=tq, windows=2) == (
+            windows, interior)
+    assert count_windows(np.zeros(5, np.int32), np.zeros(4, np.int32), 64,
+                         G, BS, q_tile=tq, windows=2) == (0, 0)
+
+
+@pytest.mark.parametrize("G,tq", [(1, 64), (4, 16), (8, 8)])
+@pytest.mark.parametrize("seed", range(2))
+def test_ragged_pallas_interior_fuzz(seed, G, tq):
+    """Span layouts around whole tiles: chunks of one to three tiles, a
+    token short or long of one, decode rows and empty slots between them,
+    behind prior contexts of any length."""
+    rng = np.random.default_rng(700 + 10 * G + seed)
+    q_lens, ctx_lens = [], []
+    for _ in range(int(rng.integers(3, 8))):
+        n = int(rng.choice([0, 1, tq - 1, tq, tq, 2 * tq, 3 * tq, tq + 1]))
+        q_lens.append(n)
+        ctx_lens.append(n + int(rng.integers(0, 60)) if n else 0)
+    M = max(1, max(-(-c // BS) for c in ctx_lens))
+    cache, tables, cu, q, seq_ids, q_pos, _ = _build_ragged_case(
+        rng, q_lens, ctx_lens, M,
+        num_blocks=2 + sum(-(-c // BS) for c in ctx_lens), G=G,
+    )
+    want = ragged_paged_attention(
+        jnp.asarray(q), cache[1], jnp.asarray(tables),
+        jnp.asarray(ctx_lens, jnp.int32), jnp.asarray(seq_ids),
+        jnp.asarray(q_pos),
+    )
+    got = ragged_paged_attention_pallas(
+        jnp.asarray(q), cache, jnp.asarray(tables),
+        jnp.asarray(cu), jnp.asarray(ctx_lens, jnp.int32),
+        layer_idx=1, q_tile=tq, windows=2, interpret=True,
+    )
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
+    )
+
+
+# (KH, G, D, block, windows, q_tile): the tests' narrow shapes, whose heads
+# the interior body slices out of the landed window, and the cells' three
+# geometries at serving widths (128-token windows of whole (16, 128) bf16
+# tiles), whose heads it gathers with strided 32-bit loads
+INTERIOR_GEOMETRIES = [(2, 4, 16, 4, 2, 16), (8, 4, 128, 16, 8, 16),
+                       (16, 1, 128, 16, 8, 64), (8, 8, 128, 16, 8, 8)]
+
+
+@pytest.mark.parametrize("geometry", INTERIOR_GEOMETRIES,
+                         ids=lambda g: "kh%d-g%d-d%d" % g[:3])
+def test_interior_body_agrees_with_the_masked_body_in_bf16(geometry):
+    """The same bf16 inputs through the kernel as it is and with its
+    interior predicate answering "none" (every window through the masked
+    body): the two bodies differ only in where they round. Both take bf16
+    q, K and V and accumulate products in float32; the interior body
+    rounds a window's weights p to bf16 before PV, as the MXU does for
+    either body on the chip, while the interpreter's float32 product in
+    the masked body does not. An output is sum(p v) / sum(p), so rounding
+    each p by at most 2^-9 of itself moves it by at most 2^-9 max|v| =
+    0.0088 at |v| <= 4.5 (standard normal draws), and the bf16 output adds
+    half a step of 2^-8 |out| on either side: atol 0.01, rtol 2^-7. A
+    wrong key, mask or head shows as O(1)."""
+    kh, G, d, bs, W, tq = geometry
+    rng = np.random.default_rng(kh + G)
+    q_lens, ctx_lens = [3 * tq, 1, tq, 5], [3 * tq + 19 * bs + 3, 40,
+                                            tq + 2 * W * bs, 5]
+    blocks = [-(-c // bs) for c in ctx_lens]
+    cache = jnp.asarray(rng.standard_normal(
+        (2, 1 + sum(blocks), bs, 2 * kh, d)), jnp.bfloat16)
+    tables = np.zeros((len(q_lens), max(blocks)), np.int32)
+    ids = iter(range(1, 1 + sum(blocks)))
+    for s, nb in enumerate(blocks):
+        tables[s, :nb] = [next(ids) for _ in range(nb)]
+    cu = np.concatenate([[0], np.cumsum(q_lens)]).astype(np.int32)
+    q = jnp.asarray(rng.standard_normal((int(cu[-1]), kh * G, d)),
+                    jnp.bfloat16)
+    windows, interior = count_windows(cu, ctx_lens, int(cu[-1]), G, bs,
+                                      q_tile=tq, windows=W)
+    assert 0 < interior < windows
+
+    def run():
+        return np.asarray(ragged_paged_attention_pallas(
+            q, cache, jnp.asarray(tables), jnp.asarray(cu),
+            jnp.asarray(ctx_lens, jnp.int32), layer_idx=1, q_tile=tq,
+            windows=W, interpret=True).astype(jnp.float32))
+
+    got = run()
+    with mock.patch.object(
+            rpa, "interior_windows",
+            lambda lo, hi, q_tile, first_pos, win_tokens, xp=jnp:
+            jnp.zeros_like(first_pos)):
+        masked = run()
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, masked, rtol=2 ** -7, atol=0.01)
+    assert np.abs(got - masked).mean() < 1e-3  # rounding, not a bias
+    # and both are the attention of the reference
+    seq_ids = np.repeat(np.arange(len(q_lens)), q_lens).astype(np.int32)
+    q_pos = np.concatenate([np.arange(c - n, c) for n, c
+                            in zip(q_lens, ctx_lens)]).astype(np.int32)
+    want = ragged_paged_attention(
+        q.astype(jnp.float32), cache[1].astype(jnp.float32),
+        jnp.asarray(tables), jnp.asarray(ctx_lens, jnp.int32),
+        jnp.asarray(seq_ids), jnp.asarray(q_pos))
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2 ** -7,
+                               atol=0.02)
+
+
+def test_interior_windows_is_none_unless_the_span_owns_the_tile():
+    args = dict(q_tile=16, win_tokens=8, xp=np)
+    lo, hi = np.array([0, 1, 0, 0]), np.array([16, 16, 15, 16])
+    pos = np.array([40, 40, 40, 6])
+    np.testing.assert_array_equal(
+        interior_windows(lo, hi, first_pos=pos, **args), [5, 0, 0, 0])
 
 
 @pytest.mark.parametrize("G,tq", [(1, 128), (3, 32), (4, 32)])

@@ -8,12 +8,14 @@ import asyncio
 import dataclasses
 import functools
 import time
+import types
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from production_stack_tpu.engine import model_runner
+from production_stack_tpu.engine import model_runner, tracing
 from production_stack_tpu.engine.async_engine import AsyncEngine
 from production_stack_tpu.engine.config import (
     CacheConfig,
@@ -290,28 +292,42 @@ def test_early_handovers_counter_counts_each_hand_over(server):
 
 
 def test_deliver_entered_twice_in_a_step_is_one_phase_and_loses_no_time():
-    clock = StepClock()
-    t0 = time.monotonic()
-    clock.begin_step()
-    clock.describe("decode", rows=1, tokens=1)
-    for phase in ("schedule", "build", "snapshot", "commit"):
-        clock.enter(phase)
-    clock.launch()
-    clock.enter("postprocess")
-    clock.enter("deliver")      # the hand-over, mid-step
-    time.sleep(0.01)
-    clock.enter("wait")
-    time.sleep(0.01)
-    clock.enter("postprocess")
-    clock.enter("deliver")      # what step() returned
-    time.sleep(0.01)
-    seconds = clock.end_step()
-    wall = time.monotonic() - t0
+    # the clock reads a fake ``time`` that moves only when the test says
+    # so: the sums below are then StepClock's own arithmetic, not how the
+    # machine scheduled this worker between two stamps
+    fake = types.SimpleNamespace(now=100.0)
+    fake.monotonic = lambda: fake.now
+    fake.thread_time = lambda: fake.now / 2  # on the CPU half the time
+
+    def sleep(seconds):
+        fake.now += seconds
+
+    with mock.patch.object(tracing, "time", fake):
+        clock = StepClock()
+        t0 = fake.monotonic()
+        clock.begin_step()
+        clock.describe("decode", rows=1, tokens=1)
+        for phase in ("schedule", "build", "snapshot", "commit"):
+            clock.enter(phase)
+            sleep(0.001)
+        clock.launch()
+        clock.enter("postprocess")
+        clock.enter("deliver")      # the hand-over, mid-step
+        sleep(0.01)
+        clock.enter("wait")
+        sleep(0.01)
+        clock.enter("postprocess")
+        clock.enter("deliver")      # what step() returned
+        sleep(0.01)
+        seconds = clock.end_step()
+        wall = fake.monotonic() - t0
     by = clock.seconds["decode"]
     assert by["deliver"][0] >= 0.02 and by["wait"][0] >= 0.01
+    assert by["deliver"] == pytest.approx([0.02, 0.01])
     # (begin_step to the first phase is in no phase: microseconds)
     assert seconds == pytest.approx(sum(w for w, _ in by.values()), abs=1e-3)
     assert seconds <= wall and wall - seconds < 0.005
+    assert seconds == pytest.approx(0.034)
     assert clock.steps == {"decode": 1, "ragged": 0, "prefill": 0, "other": 0}
 
 
@@ -433,6 +449,50 @@ def test_ragged_attn_walk_counters_follow_the_dispatched_spans():
                  ("hi", "x" * 40)]
         assert after[1] - before[1] == sum(
             n * G <= ROW_BLOCK for n in spans) == 1
+
+    asyncio.run(_with_client(server, fn))
+
+
+def test_ragged_attn_window_counters_follow_the_dispatched_spans():
+    """vllm:ragged_attn_windows_total / ..._interior_windows_total on
+    /metrics and the same two on /debug/perf, there from start-up: the
+    32-token context windows (8 blocks of 4) the kernel's walks stream,
+    and those of a span owning a whole 64-token tile that end at or below
+    its first token's position."""
+    names = ("vllm:ragged_attn_windows_total",
+             "vllm:ragged_attn_interior_windows_total")
+
+    async def read(client):
+        text = await (await client.get("/metrics")).text()
+        samples = [_samples(text, n) for n in names]
+        assert all(samples), "both series exported, moved or not"
+        values = [sum(s.values()) for s in samples]
+        perf = await (await client.get("/debug/perf")).json()
+        assert [perf["ragged_attn_windows"],
+                perf["ragged_attn_interior_windows"]] == values
+        return values
+
+    server = EngineServer(make_config(attention_impl="ragged"))
+
+    async def fn(client):
+        eng = server.engine
+        assert await read(client) == [0, 0]
+        # 3 tokens (with BOS): one window, cut by the diagonal
+        # 150 tokens: chunks of 64, 64 and 22 at the 64-token budget. The
+        # first owns its tile at position 0 (2 windows, none interior),
+        # the second at position 64 (4 windows to its reach of 128, the 2
+        # below position 64 interior), the tail owns 22 rows of its tile
+        # (5 windows to 150, none interior: not a whole tile)
+        want = {"hi": (1, [1, 0]), "x" * 149: (3, [2 + 4 + 5, 2])}
+        for prompt, (steps, delta) in want.items():
+            before, dispatches = await read(client), eng.ragged_dispatches
+            r = await client.post("/v1/completions", json={
+                "model": "tiny-llama", "prompt": prompt, "max_tokens": 1,
+                "temperature": 0, "ignore_eos": True})
+            assert r.status == 200
+            after = await read(client)
+            assert eng.ragged_dispatches - dispatches == steps
+            assert [a - b for a, b in zip(after, before)] == delta
 
     asyncio.run(_with_client(server, fn))
 
