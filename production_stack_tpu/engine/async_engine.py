@@ -67,13 +67,15 @@ class AsyncEngine:
     # -- worker thread -------------------------------------------------------
     def _worker(self) -> None:
         # every moment of this loop belongs to a phase of the engine's
-        # step clock (engine/tracing.py): idle and intake here, the rest
-        # inside engine.step(). `deliver` is entered twice at most: inside
-        # the step, where the engine hands what it has resolved to
-        # `_hand_over` before it blocks on a decode program, and after it
-        # for what step() returns. An output takes one way or the other,
-        # never both, so a step that raises after a hand-over leaves
-        # nothing to deliver again
+        # step clock (engine/tracing.py): idle, intake and observe here
+        # (`observe`: from a step's end to the next intake or, if nothing
+        # has arrived, the next step: the step observer's call is in it),
+        # the rest inside engine.step(). `deliver` is entered twice at
+        # most: inside the step, where the engine hands what it has
+        # resolved to `_hand_over` before it blocks on a decode program,
+        # and after it for what step() returns. An output takes one way or
+        # the other, never both, so a step that raises after a hand-over
+        # leaves nothing to deliver again
         clock = self.engine.clock
         while self.running:
             self._drain_intake(block=not self.engine.has_unfinished())
@@ -102,7 +104,7 @@ class AsyncEngine:
             if outputs:
                 clock.enter("deliver")
                 self._hand_over(outputs)
-            step_seconds = clock.end_step()
+            step_seconds = clock.end_step(then="observe")
             if self.step_observer is not None:
                 try:
                     self.step_observer(step_seconds)
@@ -112,7 +114,14 @@ class AsyncEngine:
 
     def _drain_intake(self, block: bool) -> None:
         clock = self.engine.clock
-        clock.enter("idle" if block else "intake")
+        if block:
+            clock.idle()
+        elif self.intake.empty():
+            # nothing arrived during the step: the phase stays `observe`
+            # (what comes in from here on is taken after the next step)
+            return
+        else:
+            clock.enter("intake")
         try:
             item = self.intake.get(timeout=0.05 if block else 0)
         except queue.Empty:
@@ -123,13 +132,13 @@ class AsyncEngine:
             clock.enter("intake")
             kind, payload = item
             if kind == "add":
-                # 4-tuple (legacy) or 5-tuple with the tenant identity
-                rid, prompt_ids, sampling, adapter_slot = payload[:4]
-                tenant = payload[4] if len(payload) > 4 else "anonymous"
+                (rid, prompt_ids, sampling, adapter_slot, tenant,
+                 enqueued) = payload
                 try:
                     self.engine.add_request(
                         rid, prompt_token_ids=prompt_ids, sampling=sampling,
                         adapter_slot=adapter_slot, tenant=tenant,
+                        enqueued=enqueued,
                     )
                 except Exception as e:  # surfaced on the request's stream
                     if self.loop is not None:
@@ -193,6 +202,12 @@ class AsyncEngine:
             q.put_nowait(err)
 
     # -- async API ------------------------------------------------------------
+    def _enqueued(self) -> tuple:
+        """(stamp, engine step) of an add reaching the intake queue: the
+        `enqueued` stamp of the request's time to first token."""
+        clock = self.engine.clock
+        return clock.now(), clock.step_num
+
     async def generate(
         self,
         prompt_token_ids: Seq[int],
@@ -206,7 +221,7 @@ class AsyncEngine:
         self.streams[rid] = q
         self.intake.put(
             ("add", (rid, list(prompt_token_ids), sampling, adapter_slot,
-                     tenant))
+                     tenant, self._enqueued()))
         )
         async for item in self._consume(rid, q):
             yield item
@@ -231,6 +246,8 @@ class AsyncEngine:
             qs[rid] = q
             self.streams[rid] = q  # registered first: no output dropped
 
+        enqueued = self._enqueued()
+
         def add_all(eng):
             added = []
             try:
@@ -239,7 +256,7 @@ class AsyncEngine:
                     tenant = req[4] if len(req) > 4 else "anonymous"
                     eng.add_request(rid, prompt_token_ids=list(ids),
                                     sampling=sp, adapter_slot=slot,
-                                    tenant=tenant)
+                                    tenant=tenant, enqueued=enqueued)
                     added.append(rid)
             except Exception:
                 for r in added:
@@ -279,10 +296,13 @@ class AsyncEngine:
         q: asyncio.Queue = asyncio.Queue()
         self.streams[request_id] = q
 
+        enqueued = self._enqueued()
+
         def do_splice(eng):
             eng.splice_request(request_id, list(prompt_token_ids),
                                first_token, sampling, blocks,
-                               adapter_slot=adapter_slot, tenant=tenant)
+                               adapter_slot=adapter_slot, tenant=tenant,
+                               enqueued=enqueued)
 
         try:
             await self.run_on_engine(do_splice)

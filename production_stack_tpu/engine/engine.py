@@ -101,6 +101,7 @@ class LLMEngine:
             max_model_len=config.model.max_model_len,
             recurrent_state=config.model.has_recurrent_state,
         )
+        self.scheduler.now = self.clock.now
         # what the recurrent layers of a hybrid stack ran
         # (engine/tracing.py); None for every other model
         self.recurrent = None
@@ -307,7 +308,12 @@ class LLMEngine:
 
             self.perf = PerfAccountant.from_runner(config, self.runner)
             if hasattr(self.runner, "install_compile_observer"):
-                self.runner.install_compile_observer(self.perf.on_compile)
+                self.runner.install_compile_observer(self._on_compile)
+
+    def _on_compile(self, kind: str, bucket: str, seconds: float) -> None:
+        # the step clock names a slow step's cause `compile` if this moved
+        self.clock.compiles += 1
+        self.perf.on_compile(kind, bucket, seconds)
 
     # -- request intake ------------------------------------------------------
     def add_request(
@@ -318,7 +324,10 @@ class LLMEngine:
         sampling: Optional[SamplingParams] = None,
         adapter_slot: int = 0,
         tenant: str = "anonymous",
+        enqueued: Optional[tuple] = None,
     ) -> Sequence:
+        """``enqueued``: (stamp, engine step) at which the event loop put
+        this add on the intake queue (AsyncEngine), where one did."""
         if prompt_token_ids is None:
             assert prompt is not None, "prompt or prompt_token_ids required"
             prompt_token_ids = self.tokenizer.encode(prompt)
@@ -358,6 +367,7 @@ class LLMEngine:
                        tenant=tenant or "anonymous",
                        token_ctrl=make_token_controls(
                            sampling, self.config.model.vocab_size))
+        self._stamp_arrival(seq, enqueued)
         if sampling.guided_regex is not None or sampling.guided_json is not None:
             if not hasattr(self.runner, "register_grammar"):
                 raise ValueError(
@@ -371,6 +381,13 @@ class LLMEngine:
         self.scheduler.add(seq)
         self.total_prompt_tokens += len(prompt_token_ids)
         return seq
+
+    def _stamp_arrival(self, seq: Sequence, enqueued: Optional[tuple]) -> None:
+        seq.arrival_time = self.clock.now()
+        seq.arrival_step = self.clock.step_num
+        seq.arrival_after = self.clock.last_wait
+        if enqueued is not None:
+            seq.enqueue_time, seq.enqueue_step = enqueued
 
     def abort_request(self, request_id: str) -> bool:
         seq = self.scheduler.abort(request_id)
@@ -461,7 +478,7 @@ class LLMEngine:
             self.clock.end_step()
 
     def _step(self) -> list[RequestOutput]:
-        self.clock.enter("schedule")
+        # (begin_step opened the `schedule` phase)
         # land finished warm-tier fetches first so their sequences become
         # schedulable in THIS step's decision
         self._poll_prefetches()
@@ -584,10 +601,11 @@ class LLMEngine:
         outputs.clear()
         self.early_handovers += 1
 
-    def _fetch(self, result_dev) -> tuple:
-        """Block on a dispatch's results: the step clock's `wait` phase.
-        Returns (the results on the host, the seconds blocked)."""
-        t0 = self.clock.enter("wait")
+    def _fetch(self, result_dev, kind: str) -> tuple:
+        """Block on the results of a dispatch of ``kind``: the step
+        clock's `wait` phase. Returns (the results on the host, the
+        seconds blocked)."""
+        t0 = self.clock.wait(kind)
         fetched = jax.device_get(result_dev)
         return fetched, self.clock.enter("postprocess") - t0
 
@@ -597,7 +615,7 @@ class LLMEngine:
             return []
         prefills, result_dev = self._pending_prefill
         self._pending_prefill = None
-        fetched, _ = self._fetch(result_dev)
+        fetched, _ = self._fetch(result_dev, "prefill")
         if isinstance(fetched, (tuple, list)):  # (sampled, *logprob arrays)
             fetched = tuple(np.asarray(x) for x in fetched)
         else:  # staged PP runner: bare sampled tokens
@@ -774,6 +792,7 @@ class LLMEngine:
             ),
         )
         dispatch_s = self.clock.enter("postprocess") - t_call
+        self._note_prompt_dispatch(seq)
         if self.perf is not None:
             entries = [(seq, "prefill", n, n)]
             self.perf.record_prefill(n, n, 1, seconds=dispatch_s,
@@ -909,6 +928,8 @@ class LLMEngine:
             fetch=False,
         )
         dispatch_s = self.clock.enter("postprocess") - t_call
+        for sp in prefills:
+            self._note_prompt_dispatch(sp.seq)
         if self.perf is not None:
             entries = [(sp.seq, "prefill", sp.chunk_len, sp.chunk_len)
                        for sp in prefills]
@@ -1147,6 +1168,8 @@ class LLMEngine:
             fetch=False,
         )
         dispatch_s = self.clock.enter("postprocess") - t_call
+        for sp in prefills:
+            self._note_prompt_dispatch(sp.seq)
         if self.perf is not None:
             # draft/verify spans are prefill-shaped work with zero goodput;
             # accepted tokens land as decode goodput at resolve time
@@ -1221,7 +1244,7 @@ class LLMEngine:
             return []
         pending = self._pending_ragged
         self._pending_ragged = None
-        fetched, fetch_s = self._fetch(pending["result"])
+        fetched, fetch_s = self._fetch(pending["result"], "ragged")
         fetched = self.runner.take_counters(
             tuple(np.asarray(x) for x in fetched))
         if self.perf is not None:
@@ -1489,7 +1512,7 @@ class LLMEngine:
         runner's counters. Returns the seconds blocked."""
         (sampled, counters, *lp), wait_s = self._fetch(
             (pending["sampled"], pending.get("counters"),
-             *pending.get("lp", ())))
+             *pending.get("lp", ())), "decode")
         pending["sampled"] = np.asarray(sampled)
         pending["lp"] = [np.asarray(x) for x in lp]
         if counters is not None:
@@ -1539,8 +1562,20 @@ class LLMEngine:
             lp_lists.append(new_lps)
         return self._postprocess(live, token_lists, lp_lists)
 
+    def _note_prompt_dispatch(self, seq: Sequence) -> None:
+        """The dispatch just launched carried rows of ``seq``'s prompt:
+        the first such launch is where its prefill starts, and until its
+        first token each one counts (a prompt recomputed after a
+        preemption has its first token behind it)."""
+        if seq.output_token_ids:
+            return
+        if seq.first_launch_time is None:
+            seq.first_launch_time = self.clock.launch_t
+            seq.first_launch_step = self.clock.step_num
+        seq.prefill_dispatches += 1
+
     def _stamp_first_token(self, seq: Sequence) -> None:
-        seq.first_token_time = time.monotonic()
+        seq.first_token_time = self.clock.now()
         seq.first_token_step = self.clock.step_num
 
     def _postprocess(
@@ -1556,7 +1591,7 @@ class LLMEngine:
                 self.scheduler.finish(seq, status)
                 self._slot_seq.pop(seq.slot, None)
                 self._release_grammar(seq)
-                seq.finish_time = time.monotonic()
+                seq.finish_time = self.clock.now()
                 seq.finish_step = self.clock.step_num
                 if self.perf is not None and seq.admit_time is not None:
                     self.perf.note_request(
@@ -1582,10 +1617,20 @@ class LLMEngine:
                                       if status is not None else None),
                     finish_time=(seq.finish_time if status is not None
                                  else None),
-                    steps=({"admitted": seq.admit_step,
+                    enqueue_time=(seq.enqueue_time if status is not None
+                                  else None),
+                    first_launch_time=(seq.first_launch_time
+                                       if status is not None else None),
+                    steps=({"enqueued": seq.enqueue_step,
+                            "arrival": seq.arrival_step,
+                            "admitted": seq.admit_step,
+                            "first_launch": seq.first_launch_step,
                             "first_token": seq.first_token_step,
                             "last_token": seq.finish_step}
                            if status is not None else None),
+                    arrival_after=(seq.arrival_after if status is not None
+                                   else None),
+                    prefill_dispatches=seq.prefill_dispatches,
                     new_logprobs=(lp_lists[j] if lp_lists is not None
                                   else None),
                 )
@@ -1664,6 +1709,7 @@ class LLMEngine:
         blocks: list[int],
         adapter_slot: int = 0,
         tenant: str = "anonymous",
+        enqueued: Optional[tuple] = None,
     ) -> Sequence:
         """Engine-thread: turn a completed P→D transfer into a RUNNING
         decode row. The sequence enters with the prompt fully computed
@@ -1689,6 +1735,7 @@ class LLMEngine:
                        tenant=tenant or "anonymous",
                        token_ctrl=make_token_controls(
                            sampling, self.config.model.vocab_size))
+        self._stamp_arrival(seq, enqueued)
         seq.output_token_ids = [int(first_token)]
         seq.num_computed_tokens = len(prompt_token_ids)
         seq.num_cached_tokens = len(prompt_token_ids)
@@ -1764,6 +1811,8 @@ class LLMEngine:
             "decode_attn_slab_calls_total": self.decode_attn_slab_calls,
             "early_handovers_total": self.early_handovers,
             "step_phases": self.clock.snapshot(),
+            "slow_step_seconds": {k: dict(v) for k, v
+                                  in self.clock.slow_seconds.items()},
             "ragged_stream_utilization": (
                 self.ragged_live_tokens
                 / max(1, self.ragged_dispatches

@@ -256,6 +256,19 @@ class EngineStatsCollector:
                 "with nothing unfinished",
                 phases["idle_seconds"],
             )
+            slow = CounterMetricFamily(
+                "vllm:engine_slow_step_seconds",
+                "Seconds of the steps that took over twice the median of "
+                "the 32 steps like them before them (same kind, after the "
+                "same kind of step), by the phase "
+                "that overran most (wait: the device, or whatever blocks "
+                "the fetch) or compile if a program compiled inside",
+                labels=["model_name", "kind", "cause"],
+            )
+            for kind, by_cause in s.get("slow_step_seconds", {}).items():
+                for cause, sec in by_cause.items():
+                    slow.add_metric([self.model_name, kind, cause], sec)
+            yield slow
         # MoE routing (engine/tracing.py MoeCounters): exported by MoE
         # models only, summed over layers and dispatches
         if "moe_routed_tokens_total" in s:
@@ -554,6 +567,74 @@ class EngineStatsCollector:
                 )
 
 
+class RequestPathCollector:
+    """A request's time to first token in parts and the event loop's
+    heartbeat (engine/tracing.py ``TtftParts``, ``LoopLag``): plain
+    numbers the server's event loop adds up, read here at scrape time on
+    that same loop."""
+
+    def __init__(self, ttft, loop_lag, model_name: str):
+        self.ttft, self.loop_lag = ttft, loop_lag
+        self.model_name = model_name
+
+    def collect(self):
+        def family(cls, name, doc, label, values):
+            fam = cls(name, doc, labels=["model_name", *label])
+            for key, value in values:
+                fam.add_metric([self.model_name, *key], value)
+            return fam
+
+        yield family(
+            CounterMetricFamily, "vllm:request_ttft_part_seconds",
+            "Seconds from the handler's entry to the first chunk written, "
+            "by part, over the requests that got a first token: "
+            "server_prep (received to enqueued), intake_wait (to the "
+            "engine thread's intake), queue_wait (to admission), "
+            "stream_wait (to the first launch that carried the prompt), "
+            "prefill_steps (to the first token), server_deliver (to the "
+            "first chunk written)", ["part"],
+            (((p,), sec) for p, sec in self.ttft.seconds.items()))
+        yield family(
+            CounterMetricFamily, "vllm:request_ttft_parts",
+            "Requests counted in vllm:request_ttft_part_seconds_total, by "
+            "part (a response that is not streamed has no server_deliver)",
+            ["part"], (((p,), n) for p, n in self.ttft.count.items()))
+        lag = self.loop_lag
+        yield family(
+            CounterMetricFamily, "vllm:server_loop_lag_seconds",
+            "Seconds the server's event loop ran behind its heartbeat, by "
+            "the engine thread's phase at the instant the beat was due "
+            "(a host phase, wait, idle, or none where it is not known)",
+            ["during"],
+            (((d,), sec) for d, sec in sorted(lag.lag_seconds.items())))
+        yield family(
+            CounterMetricFamily, "vllm:server_loop_lag_in_wait_seconds",
+            "vllm:server_loop_lag_seconds_total{during=\"wait\"} as a "
+            "family of its own: the beats due while the engine thread "
+            "waited for the device", [],
+            [((), lag.lag_seconds.get("wait", 0.0))])
+        yield family(
+            CounterMetricFamily, "vllm:server_loop_ticks",
+            "Heartbeats of the server's event loop (about ten a second, "
+            "re-armed from the wake): lag seconds over ticks is the mean "
+            "wait of an arrival for the loop", [], [((), lag.ticks)])
+        yield family(
+            CounterMetricFamily, "vllm:server_loop_wall_seconds",
+            "Wall seconds of the server's event loop, heartbeat start to "
+            "its last wake", [], [((), lag.wall_seconds)])
+        yield family(
+            CounterMetricFamily, "vllm:server_loop_cpu_seconds",
+            "On-CPU seconds of the event loop's thread over "
+            "vllm:server_loop_wall_seconds_total: a loop that lags and is "
+            "on the CPU has work of its own (a step's chunks to write), "
+            "one that lags and is not was kept from running", [],
+            [((), lag.cpu_seconds)])
+        yield family(
+            GaugeMetricFamily, "vllm:server_loop_lag_max_seconds",
+            "Largest heartbeat lag since the last scrape", [],
+            [((), lag.take_max())])
+
+
 class LifecycleCollector:
     """Drain / watchdog lifecycle families, read at scrape time from a
     server-provided snapshot callable — the drain state machine and the
@@ -805,6 +886,12 @@ class ServerMetrics:
         #: per-direction {bytes, seconds, count} mirror of the transfer
         #: counters, for JSON debug surfaces
         self.transfer_totals: dict = {}
+
+    def register_request_path(self, ttft, loop_lag) -> None:
+        """Attach the time-to-first-token parts and the event loop's
+        heartbeat, which EngineServer owns."""
+        self.registry.register(
+            RequestPathCollector(ttft, loop_lag, self.model_name))
 
     def register_lifecycle(self, source) -> None:
         """Attach the drain/watchdog snapshot source (EngineServer

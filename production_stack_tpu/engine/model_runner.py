@@ -794,7 +794,7 @@ class ModelRunner:
             )
         if not fetch:
             return result
-        self.clock.enter("wait")
+        self.clock.wait("prefill")
         return tuple(np.asarray(x) for x in jax.device_get(result))
 
     def prefill_ring(self, tokens: np.ndarray, positions: np.ndarray,
@@ -829,7 +829,7 @@ class ModelRunner:
                 greedy_only=greedy_only,
                 use_controls=ctrl is not None,
             )
-        self.clock.enter("wait")
+        self.clock.wait("prefill")
         return tuple(np.asarray(x) for x in jax.device_get(result))
 
     def _ensure_counts(self):
@@ -998,7 +998,7 @@ class ModelRunner:
             self.token_counts = new_counts
         if not fetch:
             return result
-        self.clock.enter("wait")
+        self.clock.wait("ragged")
         return self.take_counters(
             tuple(np.asarray(x) for x in jax.device_get(result)))
 

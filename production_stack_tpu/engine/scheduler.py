@@ -89,8 +89,11 @@ class Scheduler:
         # consumes no budget until the engine flips it back
         self.admission_hook = None
         # the engine's step number (StepClock.step_num), set by the engine
-        # before each schedule(): stamped on a sequence beside admit_time
+        # before each schedule(): stamped on a sequence beside admit_time,
+        # which is read from the engine's clock (StepClock.now: the engine
+        # sets it) so that it subtracts from the engine's other stamps
         self.step_num = 0
+        self.now = time.monotonic
         # set by the engine when the mesh has a seq axis > 1: long fresh
         # prompts prefill whole via ring attention instead of chunking
         self.ring_enabled = False
@@ -328,7 +331,7 @@ class Scheduler:
             # queue-exit stamp; kept across preemption-readmits so
             # queue_time measures the FIRST wait (the user-visible one)
             if seq.admit_time is None:
-                seq.admit_time = time.monotonic()
+                seq.admit_time = self.now()
                 seq.admit_step = self.step_num
             self.seqs[seq.request_id] = seq
             self._note_admitted(seq)
@@ -351,7 +354,7 @@ class Scheduler:
         seq.slot = self.free_slots.pop()
         seq.status = SequenceStatus.RUNNING
         if seq.admit_time is None:
-            seq.admit_time = time.monotonic()
+            seq.admit_time = self.now()
             seq.admit_step = self.step_num
         self.seqs[seq.request_id] = seq
 

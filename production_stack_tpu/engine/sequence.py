@@ -75,6 +75,22 @@ class Sequence:
     admit_step: Optional[int] = None
     first_token_step: Optional[int] = None
     finish_step: Optional[int] = None
+    # the way to the first token, on the same clock and with the engine
+    # step beside each stamp: when the event loop put the add on the
+    # intake queue; the step that had begun last when the engine thread
+    # took it off (arrival_time) and what the thread had done just before
+    # (StepClock.last_wait: the kind of dispatch the step it had ended
+    # waited for, "none" if that step only launched, "idle" if the thread
+    # had waited for work); the launch of the first dispatch that carried
+    # a row of the prompt, and the dispatches that carried one until the
+    # first token
+    enqueue_time: Optional[float] = None
+    enqueue_step: Optional[int] = None
+    arrival_step: Optional[int] = None
+    arrival_after: Optional[str] = None
+    first_launch_time: Optional[float] = None
+    first_launch_step: Optional[int] = None
+    prefill_dispatches: int = 0
     # block ids held at release time (they stay content-addressed in the
     # allocator until evicted — the handle for P→D KV export)
     released_block_ids: list[int] = dataclasses.field(default_factory=list)
@@ -141,9 +157,15 @@ class RequestOutput:
     admit_time: Optional[float] = None
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
-    # {"admitted", "first_token", "last_token"}: the engine steps of the
-    # stamps above (Sequence.*_step), set on finish
+    enqueue_time: Optional[float] = None
+    first_launch_time: Optional[float] = None
+    # the engine steps of the stamps above (Sequence.*_step), under the
+    # flight record's names: "enqueued", "arrival", "admitted",
+    # "first_launch", "first_token", "last_token"; set on finish
     steps: Optional[dict] = None
+    # Sequence.arrival_after and .prefill_dispatches, set on finish
+    arrival_after: Optional[str] = None
+    prefill_dispatches: int = 0
     # aligned with new_token_ids when the request asked for logprobs: each
     # entry is (token_logprob, [(token_id, logprob), ...] top-N) — the
     # server slices top-N down to the request's asked-for count

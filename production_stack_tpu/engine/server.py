@@ -247,6 +247,12 @@ class EngineServer:
                                     otel_secure)
         self.flight_recorder = FlightRecorder(flight_recorder_size)
         self._inflight: dict = {}  # root rid → open flight record
+        # a request's time to first token in parts, summed as its record
+        # closes, and the event loop's heartbeat against the engine
+        # thread's phases (engine/tracing.py): always on, read at scrape
+        self.ttft_parts = etracing.TtftParts()
+        self.loop_lag = etracing.LoopLag(self.engine.clock)
+        self.metrics.register_request_path(self.ttft_parts, self.loop_lag)
         # pushed P→D transfers awaiting their decode hop: transfer id →
         # {blocks, layers_done, meta, created, ready}. Blocks are owned by
         # this table until the attach splices them into a sequence (then
@@ -435,6 +441,7 @@ class EngineServer:
     async def _on_start(self, app) -> None:
         self.metrics.ensure_registered()
         await self.async_engine.start()
+        self.loop_lag.start(asyncio.get_running_loop())
         self.watchdog.start()
         if self.drain_on_sigterm:
             self._install_signal_drain()
@@ -477,6 +484,7 @@ class EngineServer:
         if self._drain_task is not None:
             self._drain_task.cancel()
         self.watchdog.stop()
+        self.loop_lag.stop()
         self.async_engine.stop()
         self.metrics.unregister()
 
@@ -1861,6 +1869,15 @@ class EngineServer:
         # phase (wall and on-CPU), steps by kind, idle seconds — the
         # numbers behind vllm:engine_host_seconds_total and its siblings
         step_phases = self.engine.clock.snapshot()
+        # the steps found slow against the steps like them before them
+        # (vllm:engine_slow_step_seconds_total) and the last of them;
+        # a request's time to first token in parts, seconds and requests
+        # by part (vllm:request_ttft_part_seconds_total, ..._parts_total);
+        # the event loop's heartbeat lag by the engine thread's phase
+        # (vllm:server_loop_lag_seconds_total, vllm:server_loop_ticks_total)
+        request_path = {"slow_steps": self.engine.clock.slow_snapshot(),
+                        "ttft_parts": self.ttft_parts.snapshot(),
+                        "loop_lag": self.loop_lag.snapshot()}
         # hand-overs made before a wait (vllm:engine_early_handovers_total)
         handovers = self.engine.early_handovers
         # the ragged attention kernel's walks, and those on its narrow
@@ -1886,11 +1903,12 @@ class EngineServer:
                                       "kv_tier": tier_block,
                                       "step_phases": step_phases,
                                       "early_handovers": handovers,
-                                      **walks,
+                                      **request_path, **walks,
                                       "tenants": self.engine.tenant_stats()})
         snap = perf.snapshot()
         snap["step_phases"] = step_phases
         snap["early_handovers"] = handovers
+        snap.update(request_path)
         snap.update(walks)
         eng = self.engine
         drafted = getattr(eng, "spec_drafted", 0)
@@ -2227,7 +2245,18 @@ class EngineServer:
             streaming=bool(body.get("stream", False)),
             trace_id=None, outcome=None, status=None,
             num_prompt_tokens=0, num_output_tokens=0,
+            steps={"received": self.engine.clock.step_num},
         )
+        # the wall-clock instant the router forwarded the request, beside
+        # `received_unix`: their difference is the hop and the wait for
+        # this handler (exact on one host; across nodes it carries the
+        # nodes' clock skew)
+        sent = request.headers.get("x-router-sent-unix")
+        if sent is not None:
+            try:
+                rec["router_sent_unix"] = float(sent)
+            except ValueError:
+                pass
         self._inflight[rid] = rec
         status = 500
         try:
@@ -2247,7 +2276,8 @@ class EngineServer:
                             "x-request-id" not in resp.headers:
                         resp.headers["x-request-id"] = client_rid
                 finally:
-                    self._finalize_span(span, rec, status)
+                    self._finalize_span(span, rec, status,
+                                        self._note_ttft_parts(rec))
                 return resp
         except asyncio.CancelledError:
             if rec.get("outcome") is None:
@@ -2270,19 +2300,36 @@ class EngineServer:
                 tl["finished"] - tl["received"],
             )
 
-    def _finalize_span(self, span, rec: dict, status: int) -> None:
-        """Stamp per-stage durations (from the sequence lifecycle stamps
-        merged into the flight record) onto the engine SERVER span."""
+    def _note_ttft_parts(self, rec: dict) -> dict:
+        """A request's time to first token in parts (tracing.ttft_parts),
+        from the stamps its record holds by now: kept in the record and
+        added to the totals if it got a first token. The one place the
+        record, /debug/perf, /metrics and the span read them from."""
+        parts = etracing.ttft_parts(rec["timeline"])
+        if "first_token" in rec["timeline"]:
+            rec["ttft_parts"] = parts
+            self.ttft_parts.add(parts)
+        return parts
+
+    def _finalize_span(self, span, rec: dict, status: int,
+                       parts: dict) -> None:
+        """Stamp per-stage durations (``parts``: the record's time to
+        first token in parts) onto the engine SERVER span: queue is what
+        lies before admission, prefill what lies between it and the first
+        token."""
         if span is None:
             return
         tl = rec["timeline"]
         span.set_attribute("http.status_code", status)
-        if "admitted" in tl:
-            span.set_attribute("stage.queue_s", tl["admitted"] - tl["received"])
+        for part, seconds in parts.items():
+            span.set_attribute(f"stage.{part}_s", seconds)
+        if "queue_wait" in parts:
+            span.set_attribute("stage.queue_s", sum(
+                parts[p] for p in etracing.QUEUE_PARTS if p in parts))
             span.add_event("admitted")
-        if "first_token" in tl and "admitted" in tl:
-            span.set_attribute("stage.prefill_s",
-                               tl["first_token"] - tl["admitted"])
+        if "prefill_steps" in parts:
+            span.set_attribute("stage.prefill_s", sum(
+                parts[p] for p in etracing.PREFILL_PARTS if p in parts))
             span.add_event("first_token")
         if "last_token" in tl and "first_token" in tl:
             span.set_attribute("stage.decode_s",
@@ -2303,7 +2350,16 @@ class EngineServer:
         # the engine step (`engine_step` annotation of a profiler trace)
         # that took it
         at_step = out.steps or {}
-        for key, val, pick in (("admitted", out.admit_time, min),
+        first = rec["timeline"].get("first_token")
+        if out.first_token_time is not None and (
+                first is None or out.first_token_time < first):
+            # of the choice whose first token came first
+            rec["intake_after"] = out.arrival_after
+            rec["prefill_dispatches"] = out.prefill_dispatches
+        for key, val, pick in (("enqueued", out.enqueue_time, min),
+                               ("arrival", out.arrival_time, min),
+                               ("admitted", out.admit_time, min),
+                               ("first_launch", out.first_launch_time, min),
                                ("first_token", out.first_token_time, min),
                                ("last_token", out.finish_time, max)):
             for into, v in ((rec["timeline"], val),
@@ -3107,6 +3163,8 @@ class EngineServer:
                         if inflight is not None:
                             self.flight_recorder.stamp(
                                 inflight, "first_chunk_written")
+                            inflight["steps"]["first_chunk_written"] = (
+                                self.engine.clock.step_num)
                 if finish_reason is not None:
                     break
             return n_kept

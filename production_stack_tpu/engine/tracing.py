@@ -18,7 +18,9 @@ image (init degrades gracefully otherwise).
 from __future__ import annotations
 
 import logging
+import random
 import time
+from collections import deque
 from typing import Optional
 
 import numpy as np
@@ -160,60 +162,150 @@ class request_span:
 # order listed, except that `deliver` may come twice: mid-step, between
 # `launch` and `wait`, when a decode-only step hands over what it resolved
 # before it blocks on the decode program (LLMEngine._hand_over), and after
-# `postprocess` for what step() returns
+# `postprocess` for what step() returns. `observe` is the worker's note of
+# the step it has just ended (the step histogram's observer), `other` the
+# time with no phase open: between two steps driven by hand
 HOST_PHASES = ("intake", "schedule", "build", "snapshot", "commit", "launch",
-               "postprocess", "deliver", "prefetch_wait")
+               "postprocess", "deliver", "prefetch_wait", "observe", "other")
 STEP_KINDS = ("decode", "ragged", "prefill", "other")
+DISPATCH_KINDS = STEP_KINDS[:-1]  # `other` dispatched nothing
+# a step is slow when it took more than SLOW_FACTOR times the median of
+# the SLOW_WINDOW steps like it before it (of its kind, after a step of the
+# same kind as the one before it: a step waits out the dispatch before it,
+# so a decode step after a ragged step is held against its own like); the
+# last SLOW_RING are kept
+SLOW_WINDOW, SLOW_FACTOR, SLOW_RING = 32, 2.0, 32
+_SWITCHES = 16  # phase switches kept for `phase_at`: more than a step makes
 
 
 class StepClock:
-    """Owned by the engine thread (no lock: `stats()` on another thread
-    reads floats). A step's kind is known only after scheduling, so the
-    open step's phases collect in a scratch table and are charged to the
-    kind when the step ends."""
+    """Owned by the engine thread (no lock: `stats()` and the event
+    loop's heartbeat on other threads read floats and tuples). A step's
+    kind is known only after scheduling, so the open step's phases collect
+    in a scratch table and are charged to the kind when the step ends.
+    From the first switch on some phase is always open (`other` when the
+    caller named none), so host + wait + idle is the thread's wall time."""
 
     def __init__(self):
         self.step_num = 0        # engine_step annotations opened so far
         self.in_step = False
         self.kind = "other"      # of the open step; `describe` sets it
+        # of the last step ended ("idle" once the thread has waited for
+        # work since), and the kind of dispatch that step waited for first
+        # ("none": it only launched): what a request taken in now stood
+        # behind. A ragged dispatch is launched by a `ragged` step and
+        # waited for by the step after it, whatever that one's kind
+        self.last_kind = self.last_wait = "idle"
+        self._waited: Optional[str] = None  # by the open step, first
+        self.launch_t = 0.0      # stamp of the last `launch`
+        self.compiles = 0        # XLA compiles seen (the engine counts)
         # kind -> phase -> [wall seconds, on-CPU seconds]
         self.seconds = {k: {p: [0.0, 0.0] for p in (*HOST_PHASES, "wait")}
                         for k in STEP_KINDS}
         self.steps = dict.fromkeys(STEP_KINDS, 0)
         self.idle_seconds = 0.0
+        # the first switch and the last flush: between them the seconds
+        # above add up to flushed_at - started_at, nothing dropped
+        self.started_at = self.flushed_at = 0.0
+        # kind -> cause -> seconds of the steps found slow; every kind is
+        # there from the start, so the family is exported at 0
+        self.slow_seconds = {k: {"wait": 0.0} for k in DISPATCH_KINDS}
+        self.slow_steps: deque = deque(maxlen=SLOW_RING)
+        # (kind of the step before, kind) -> the last SLOW_WINDOW such
+        # steps' (seconds, {phase: seconds})
+        self._recent: dict = {}
         self._scratch: dict = {}  # phase -> [wall, cpu] since the last flush
-        self._phase: Optional[str] = None
+        self._at_begin: dict = {}  # phase -> wall in _scratch at begin_step
+        self._phase: Optional[str] = None  # None: not started
         self._t = self._cpu = self._t_begin = 0.0
+        self._compiles_at_begin = 0
         self._ann = self._step_ann = None
         self._launch: dict = {}
+        # (stamp, phase) of the last switches, newest at _n - 1
+        self._switches = [(0.0, "none")] * _SWITCHES
+        self._n = 0
+
+    @staticmethod
+    def now() -> float:
+        """The clock of every stamp the engine takes of a request, so
+        that they subtract from the phase switches and from each other."""
+        return time.monotonic()
 
     def enter(self, phase: str, **attrs) -> float:
         """End the open phase and start ``phase``; returns the stamp, so a
-        caller that needs a duration subtracts two of them."""
-        t = self._close()
-        self._phase = phase
-        self._ann = TraceAnnotation("step." + phase, **attrs)
-        self._ann.__enter__()
+        caller that needs a duration subtracts two of them. Entering the
+        phase that is open (and naming nothing new for its annotation)
+        only reads the clock."""
+        if phase == self._phase and not attrs:
+            return time.monotonic()
+        ann = TraceAnnotation("step." + phase, **attrs)
+        t = self._switch(phase)
+        self._hand_over(ann)
         return t
 
-    def _close(self) -> float:
+    def _switch(self, phase: str) -> float:
+        """Charge the time since the last switch to the phase that was
+        open and note the switch for `phase_at`. The caller hands the
+        annotation over (`_hand_over`) once its own books are done."""
         t, cpu = time.monotonic(), time.thread_time()
         if self._phase is not None:
             acc = self._scratch.setdefault(self._phase, [0.0, 0.0])
             acc[0] += t - self._t
             acc[1] += cpu - self._cpu
-            self._ann.__exit__(None, None, None)
-            self._phase = None
-        self._t, self._cpu = t, cpu
+        else:
+            self.started_at = t
+        self._phase, self._t, self._cpu = phase, t, cpu
+        self._switches[self._n % _SWITCHES] = (t, phase)
+        self._n += 1
         return t
 
+    def _hand_over(self, ann, *between) -> None:
+        """Close the open phase's annotation and open ``ann`` (None: the
+        phase `other`, which has none) with nothing in between but
+        ``between`` (the step's own annotation, entered or left at a
+        step's edge), so that a profile shows no hole at a switch."""
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        for call in between:
+            call()
+        if ann is not None:
+            ann.__enter__()
+        self._ann = ann
+
+    def wait(self, kind: str) -> float:
+        """Enter `wait` for the results of a dispatch of ``kind``."""
+        if self._waited is None:
+            self._waited = kind
+        return self.enter("wait")
+
+    def phase_at(self, t: float) -> str:
+        """The phase that was open at ``t``, for another thread: a host
+        phase, ``wait``, ``idle``, or ``none`` where ``t`` lies before the
+        switches kept (or the clock never started)."""
+        n, ring = self._n, list(self._switches)
+        for i in range(n - 1, max(n - _SWITCHES, 0) - 1, -1):
+            at, phase = ring[i % _SWITCHES]
+            if at <= t:
+                return phase
+        return "none"
+
+    def idle(self) -> float:
+        """The thread is about to block on its intake queue."""
+        self.last_kind = self.last_wait = "idle"
+        return self.enter("idle")
+
     def begin_step(self) -> None:
-        self._t_begin = self._close()
+        """Open a step in its first phase, `schedule`: the step's own
+        annotation first, so that the phase nests in it."""
         self.step_num += 1
         self.in_step = True
         self._step_ann = StepTraceAnnotation("engine_step",
                                              step_num=self.step_num)
-        self._step_ann.__enter__()
+        ann = TraceAnnotation("step.schedule")
+        self._t_begin = self._switch("schedule")
+        self._hand_over(ann, self._step_ann.__enter__)
+        self._at_begin = {p: acc[0] for p, acc in self._scratch.items()}
+        self._compiles_at_begin = self.compiles
 
     def describe(self, kind: str, rows: int, tokens: int) -> None:
         """What the open step dispatches: its kind for the counters, and
@@ -224,13 +316,23 @@ class StepClock:
     def launch(self, **attrs) -> float:
         """``attrs``: what the runner adds to the annotation (a looped
         stack's ``passes``)."""
-        return self.enter("launch", **self._launch, **attrs)
+        self.launch_t = self.enter("launch", **self._launch, **attrs)
+        return self.launch_t
 
-    def end_step(self) -> float:
+    def end_step(self, then: str = "other") -> float:
         """Charge what collected since the last flush to the step's kind
         (outside a step: to "other") and return the step's seconds,
-        begin to end."""
-        t = self._close()
+        begin to end. ``then``: the phase the caller is in from here on
+        (the worker: `observe`), opened before the bookkeeping below so
+        that it, too, is inside a phase and an annotation."""
+        ann = TraceAnnotation("step." + then) if then != "other" else None
+        t = self._switch(then)
+        if self.in_step:
+            self._hand_over(
+                ann, lambda: self._step_ann.__exit__(None, None, None))
+        else:
+            self._hand_over(ann)
+        self.flushed_at = t
         by_phase = self.seconds[self.kind]
         for phase, (wall, cpu) in self._scratch.items():
             if phase == "idle":
@@ -238,25 +340,190 @@ class StepClock:
             else:
                 by_phase[phase][0] += wall
                 by_phase[phase][1] += cpu
-        self._scratch.clear()
         if not self.in_step:
+            self._scratch.clear()
             return 0.0
+        seconds = t - self._t_begin
+        if self.kind != "other":
+            self._note_step(seconds)
+        self._scratch.clear()
         self.steps[self.kind] += 1
         self.in_step = False
-        self.kind, self._launch = "other", {}
-        self._step_ann.__exit__(None, None, None)
-        return t - self._t_begin
+        self.last_kind, self.last_wait = self.kind, self._waited or "none"
+        self.kind, self._launch, self._waited = "other", {}, None
+        return seconds
+
+    def _note_step(self, seconds: float) -> None:
+        """Hold the step that ends against the steps like it before it
+        (a running median over the last SLOW_WINDOW: one stall does not
+        move it, a change of regime is taken in after half a window), and
+        keep it if it is slow, with the phase that overran most."""
+        in_step = {p: acc[0] - self._at_begin.get(p, 0.0)
+                   for p, acc in self._scratch.items()}
+        recent = self._recent.setdefault((self.last_kind, self.kind),
+                                         deque(maxlen=SLOW_WINDOW))
+        if len(recent) == SLOW_WINDOW:
+            ref = _median([s for s, _ in recent])
+            if seconds > SLOW_FACTOR * ref:
+                if self.compiles != self._compiles_at_begin:
+                    cause = "compile"
+                else:
+                    cause = max(in_step, key=lambda p: in_step[p] - _median(
+                        [by.get(p, 0.0) for _, by in recent]))
+                by_cause = self.slow_seconds[self.kind]
+                by_cause[cause] = by_cause.get(cause, 0.0) + seconds
+                self.slow_steps.append({
+                    "step": self.step_num, **self._launch,
+                    "kind": self.kind, "after": self.last_kind,
+                    "seconds": seconds,
+                    "reference": ref, "phases": in_step, "cause": cause})
+        recent.append((seconds, in_step))
 
     def snapshot(self) -> dict:
         """The `step_phases` block of /debug/perf: seconds by kind and
-        phase (wall and on-CPU), steps by kind, idle seconds."""
+        phase (wall and on-CPU), steps by kind, idle seconds, and the
+        stamps of the first switch and the last flush (StepClock.now's
+        clock): all the seconds together are their difference."""
         return {
             "steps": dict(self.steps),
             "idle_seconds": self.idle_seconds,
+            "started_at": self.started_at,
+            "flushed_at": self.flushed_at,
             "seconds": {k: {p: {"wall": w, "cpu": c}
                             for p, (w, c) in by_phase.items()}
                         for k, by_phase in self.seconds.items()},
         }
+
+    def slow_snapshot(self) -> dict:
+        """`slow_steps` of /debug/perf: the seconds of the steps found
+        slow by kind and cause (vllm:engine_slow_step_seconds_total), and
+        the last of them."""
+        return {"seconds": {k: dict(v) for k, v in self.slow_seconds.items()},
+                "last": list(self.slow_steps)}
+
+
+def _median(values: list) -> float:
+    values = sorted(values)
+    mid = len(values) // 2
+    return (values[mid] if len(values) % 2
+            else (values[mid - 1] + values[mid]) / 2)
+
+
+# -- the event loop's heartbeat ------------------------------------------------
+
+class LoopLag:
+    """How late the server's event loop runs, and what the engine thread
+    was doing meanwhile. One timer on the loop, due ``PERIOD`` seconds
+    (half to one and a half of it, drawn anew each time) after its last
+    wake (no catch-up): at each wake the lag is now minus due, charged to
+    the step clock's phase at the DUE instant. The draw keeps due instants
+    uniform against a step period of any length (a fixed period re-armed
+    from a late wake would fall on a lattice behind each step's end), so
+    lag over ticks
+    is the mean time until the loop next runs: what a request that
+    arrives on its own pays before its handler stamps ``received``. An
+    idle loop reads up to a millisecond (epoll's timeout is whole
+    milliseconds), a mean of half of one. Lag alone does not say whether
+    the loop was kept from running or had work of its own before the
+    beat, so each wake also reads the loop thread's CPU clock: on-CPU
+    seconds over wall seconds is how busy the loop itself is. Plain
+    floats, read at scrape time; owned by the event loop's thread."""
+
+    # ten beats a second: at a hundred the beats cost OLMoE's cell 0.6 %
+    # of its rate on the chip (PERF.md section 6, PR 41)
+    PERIOD = 0.100
+
+    def __init__(self, clock: StepClock):
+        self.clock = clock
+        self.ticks = 0
+        self.lag_seconds: dict = {"wait": 0.0}  # phase at due -> seconds
+        self.max_lag = 0.0   # since `take_max`
+        # the loop thread's wall and on-CPU seconds, start to last wake
+        self.wall_seconds = self.cpu_seconds = 0.0
+        self._due = self._t0 = self._cpu0 = 0.0
+        self._loop = self._handle = None
+
+    def start(self, loop) -> None:
+        """On the loop's own thread (its CPU clock is read here)."""
+        self._loop = loop
+        self._t0, self._cpu0 = self.clock.now(), time.thread_time()
+        self._arm()
+
+    def stop(self) -> None:
+        if self._handle is not None:
+            self._handle.cancel()
+        self._loop = self._handle = None
+
+    def _arm(self) -> None:
+        delay = self.PERIOD * random.uniform(0.5, 1.5)
+        self._due = self.clock.now() + delay
+        self._handle = self._loop.call_later(delay, self._wake)
+
+    def _wake(self) -> None:
+        now = self.clock.now()
+        lag = max(0.0, now - self._due)
+        self.wall_seconds = now - self._t0
+        self.cpu_seconds = time.thread_time() - self._cpu0
+        during = self.clock.phase_at(self._due)
+        self.lag_seconds[during] = self.lag_seconds.get(during, 0.0) + lag
+        self.ticks += 1
+        self.max_lag = max(self.max_lag, lag)
+        self._arm()
+
+    def take_max(self) -> float:
+        """The largest lag since the last call (the scrape's)."""
+        largest, self.max_lag = self.max_lag, 0.0
+        return largest
+
+    def snapshot(self) -> dict:
+        """`loop_lag` of /debug/perf."""
+        return {"period_seconds": self.PERIOD, "ticks": self.ticks,
+                "lag_seconds": dict(self.lag_seconds),
+                "max_lag_seconds": self.max_lag,
+                "wall_seconds": self.wall_seconds,
+                "cpu_seconds": self.cpu_seconds}
+
+
+# -- a request's time to first token, in parts --------------------------------
+# The stamps of a flight record's timeline, in the order a request takes
+# them, all on StepClock.now(); a part is the time between two neighbours,
+# so the parts of a request that has them all add up to first_chunk_written
+# - received exactly. `received`, `enqueued` and `first_chunk_written` are
+# taken on the event loop, the rest on the engine thread.
+TTFT_STAMPS = ("received", "enqueued", "arrival", "admitted", "first_launch",
+               "first_token", "first_chunk_written")
+TTFT_PARTS = ("server_prep", "intake_wait", "queue_wait", "stream_wait",
+              "prefill_steps", "server_deliver")
+# what the span's two older stages are made of: before admission, and
+# from there to the first token
+QUEUE_PARTS, PREFILL_PARTS = TTFT_PARTS[:3], TTFT_PARTS[3:5]
+
+
+def ttft_parts(timeline: dict) -> dict:
+    """{part: seconds} for every part whose two stamps ``timeline`` holds
+    (a response that is not streamed has no ``first_chunk_written``)."""
+    return {part: timeline[b] - timeline[a]
+            for part, a, b in zip(TTFT_PARTS, TTFT_STAMPS, TTFT_STAMPS[1:])
+            if a in timeline and b in timeline}
+
+
+class TtftParts:
+    """Seconds and requests by part since start: `ttft_parts` of
+    /debug/perf and vllm:request_ttft_part_seconds_total{part} beside
+    vllm:request_ttft_parts_total{part}. Owned by the event loop."""
+
+    def __init__(self):
+        self.seconds = dict.fromkeys(TTFT_PARTS, 0.0)
+        self.count = dict.fromkeys(TTFT_PARTS, 0)
+
+    def add(self, parts: dict) -> None:
+        for part, s in parts.items():
+            self.seconds[part] += s
+            self.count[part] += 1
+
+    def snapshot(self) -> dict:
+        return {part: {"seconds": self.seconds[part],
+                       "count": self.count[part]} for part in TTFT_PARTS}
 
 
 # -- looped stacks ------------------------------------------------------------

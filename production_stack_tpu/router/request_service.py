@@ -803,6 +803,11 @@ class RequestService:
         tracing.inject_headers(headers)
         rec = request.get("flight_record") if hasattr(request, "get") else None
         attempt_info = _record_attempt(rec, url, t_start)
+        # the wall-clock instant of this forward: the engine's record
+        # keeps it beside its own `received_unix`, and the difference is
+        # the hop plus the wait for the engine's handler (exact on one
+        # host; across nodes it carries their clock skew)
+        headers["x-router-sent-unix"] = repr(time.time())
         try:
             resp = await self._attempt(
                 request, endpoint_path, body, url, model, request_id, t_start,

@@ -8,6 +8,7 @@ attention) → SSE stream back.
 
 import asyncio
 import json
+import time
 
 import pytest
 
@@ -513,6 +514,7 @@ def test_request_lifecycle_observability_acceptance(monkeypatch):
     async def main():
         import aiohttp
 
+        t_test = time.time()
         servers, urls = await spawn_engines(1)
         router, client = await router_client(urls)
         # patch AFTER both tiers booted: initialize_tracing (called at
@@ -547,6 +549,13 @@ def test_request_lifecycle_observability_acceptance(monkeypatch):
             for key in ("stage.queue_s", "stage.prefill_s", "stage.decode_s"):
                 assert es.attributes[key] >= 0.0, es.attributes
             assert "admitted" in es.events and "first_token" in es.events
+            # the stages are sums of the record's time-to-first-token parts
+            at = es.attributes
+            assert at["stage.queue_s"] == pytest.approx(
+                at["stage.server_prep_s"] + at["stage.intake_wait_s"]
+                + at["stage.queue_wait_s"])
+            assert at["stage.prefill_s"] == pytest.approx(
+                at["stage.stream_wait_s"] + at["stage.prefill_steps_s"])
 
             # (b) new per-stage histograms exported and non-empty
             async with aiohttp.ClientSession() as s:
@@ -577,9 +586,13 @@ def test_request_lifecycle_observability_acceptance(monkeypatch):
                         if x["client_request_id"] == "acc-1")
             assert erec["trace_id"] == trace_id
             tl = erec["timeline"]
-            stamps = [tl[k] for k in ("received", "admitted", "first_token",
-                                      "last_token", "finished")]
+            stamps = [tl[k] for k in ("received", "enqueued", "arrival",
+                                      "admitted", "first_launch",
+                                      "first_token", "last_token",
+                                      "finished")]
             assert stamps == sorted(stamps), f"out of order: {tl}"
+            # the router's forward instant, beside the handler's own
+            assert t_test <= erec["router_sent_unix"] <= erec["received_unix"]
         finally:
             await teardown(servers, client)
 

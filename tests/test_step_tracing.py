@@ -43,6 +43,9 @@ PARENT_TOKENS = [
     [218, 400, 218, 400, 218, 400, 430, 36, 319, 218, 400, 218],
 ]
 GREEDY = SamplingParams(max_tokens=12, temperature=0.0, ignore_eos=True)
+# the phases of the worker loop outside a step: taking what arrived, the
+# note of the step just ended, and (a step driven by hand) no phase at all
+BETWEEN_STEPS = ("intake", "observe", "other")
 FAMILIES = ("vllm:engine_host_seconds_total",
             "vllm:engine_host_cpu_seconds_total",
             "vllm:engine_device_wait_seconds_total",
@@ -143,9 +146,9 @@ def test_phases_sum_to_the_worker_wall_time_and_each_step_has_one_kind(chain):
     for kind in STEP_KINDS:
         if not clock.steps[kind]:
             # a kind that never ran holds no step phase (idle iterations
-            # flush their intake under "other")
+            # flush what lies between steps under "other")
             assert all(w == 0.0 for p, (w, _) in clock.seconds[kind].items()
-                       if p != "intake"), kind
+                       if p not in BETWEEN_STEPS), kind
     decode = clock.seconds["decode"]
     for phase in ("schedule", "build", "snapshot", "commit", "launch",
                   "postprocess", "deliver", "wait"):
@@ -156,10 +159,12 @@ def test_phases_sum_to_the_worker_wall_time_and_each_step_has_one_kind(chain):
              for v in by_phase.values()]
     assert 0 < sum(c for _, c in pairs) <= sum(w for w, _ in pairs) + 0.05
     # the step histogram's observer got one positive duration per step,
-    # and together they are the steps' phases (idle and intake excluded)
+    # and together they are the steps' phases (not idle, nor what lies
+    # between two steps)
     assert len(seen) == step_count and min(seen) > 0
     in_steps = sum(w for by_phase in clock.seconds.values()
-                   for p, (w, _) in by_phase.items() if p != "intake")
+                   for p, (w, _) in by_phase.items()
+                   if p not in BETWEEN_STEPS)
     assert sum(seen) == pytest.approx(in_steps, rel=0.02)
 
 
@@ -324,9 +329,10 @@ def test_deliver_entered_twice_in_a_step_is_one_phase_and_loses_no_time():
     by = clock.seconds["decode"]
     assert by["deliver"][0] >= 0.02 and by["wait"][0] >= 0.01
     assert by["deliver"] == pytest.approx([0.02, 0.01])
-    # (begin_step to the first phase is in no phase: microseconds)
-    assert seconds == pytest.approx(sum(w for w, _ in by.values()), abs=1e-3)
-    assert seconds <= wall and wall - seconds < 0.005
+    # a step opens in its first phase and drops nothing: its seconds are
+    # its phases' and the clock's own stamps, begin to end
+    assert seconds == pytest.approx(sum(w for w, _ in by.values()), abs=1e-12)
+    assert seconds == pytest.approx(wall, abs=1e-12)
     assert seconds == pytest.approx(0.034)
     assert clock.steps == {"decode": 1, "ragged": 0, "prefill": 0, "other": 0}
 
@@ -544,5 +550,364 @@ def test_decode_attn_call_counters_follow_the_decode_dispatches():
         calls = await generate()
         last = await read(client)
         assert [last[0] - after[0], last[1] - after[1]] == [calls, calls]
+
+    asyncio.run(_with_client(server, fn))
+
+
+# -- a request's time to first token, in parts --------------------------------
+# On a clock the test moves (every reading is a millisecond after the one
+# before, so no two stamps are equal) and with step() driven by hand: what
+# is held below is the stamps' order and arithmetic, not a machine's speed.
+
+def _fake_time(tick: float = 0.001):
+    fake = types.SimpleNamespace(now=1000.0)
+
+    def monotonic():
+        fake.now += tick
+        return fake.now
+    fake.monotonic = monotonic
+    fake.time = lambda: 1.7e9 + fake.monotonic()
+    fake.thread_time = lambda: fake.now / 2
+    return fake
+
+
+@pytest.fixture
+def fake_time():
+    from production_stack_tpu import flight_recorder
+
+    fake = _fake_time()
+    with mock.patch.object(tracing, "time", fake), \
+            mock.patch.object(flight_recorder, "time", fake):
+        yield fake
+
+
+def _serve_by_hand(server, eng, root, prompts, streamed=True):
+    """What the server does for one request of ``len(prompts)`` choices,
+    with the engine stepped by hand: open the record, enqueue the adds,
+    step until every choice has finished, stamp the first chunk, close
+    the parts. Returns the record."""
+    clock = eng.clock
+    rec = server.flight_recorder.begin(
+        request_id=root, num_prompt_tokens=0, num_output_tokens=0,
+        steps={"received": clock.step_num})
+    server._inflight[root] = rec
+    enqueued = (clock.now(), clock.step_num)
+    live = set()
+    for i, ids in enumerate(prompts):
+        eng.add_request(f"{root}-{i}", prompt_token_ids=ids,
+                        sampling=dataclasses.replace(GREEDY, max_tokens=3),
+                        enqueued=enqueued)
+        live.add(f"{root}-{i}")
+    try:
+        while live:
+            for out in eng.step():
+                if out.finished and out.request_id in live:
+                    live.discard(out.request_id)
+                    server._observe_finished(root, out)
+        if streamed:
+            server.flight_recorder.stamp(rec, "first_chunk_written")
+        server._note_ttft_parts(rec)
+    finally:
+        server._inflight.pop(root, None)
+    return rec
+
+
+@pytest.mark.parametrize("case,choices,prompt_len,slots,dispatches", [
+    ("single", 1, 9, 4, 1),
+    ("two_choices", 2, 9, 4, 1),
+    ("admitted_a_step_late", 1, 9, 1, 1),
+    ("three_dispatches", 1, 150, 4, 3),
+    ("not_streamed", 1, 9, 4, 1),
+])
+def test_ttft_parts_telescope(server, fake_time, case, choices, prompt_len,
+                              slots, dispatches):
+    cfg = make_config(attention_impl="ragged")
+    eng = LLMEngine(dataclasses.replace(cfg, scheduler=dataclasses.replace(
+        cfg.scheduler, max_num_seqs=slots)))
+    if case == "admitted_a_step_late":
+        # the one slot is taken: the request waits in the queue for it
+        eng.add_request("blocker", prompt_token_ids=[5, 6, 7],
+                        sampling=dataclasses.replace(GREEDY, max_tokens=4))
+        eng.step()
+    parts0 = dict(server.ttft_parts.count)
+    rec = _serve_by_hand(
+        server, eng, f"root-{case}",
+        [[7 + (j + k) % 50 for j in range(prompt_len)]
+         for k in range(choices)],
+        streamed=case != "not_streamed")
+    tl, steps, parts = rec["timeline"], rec["steps"], rec["ttft_parts"]
+    stamps = [s for s in tracing.TTFT_STAMPS if s in tl]
+    assert stamps == list(tracing.TTFT_STAMPS[:len(stamps)])
+    assert [tl[a] < tl[b] for a, b in zip(stamps, stamps[1:])] == \
+        [True] * (len(stamps) - 1)
+    assert list(parts) == list(tracing.TTFT_PARTS[:len(stamps) - 1])
+    assert sum(parts.values()) == pytest.approx(
+        tl[stamps[-1]] - tl["received"], abs=1e-9)
+    if case == "not_streamed":
+        assert stamps[-1] == "first_token"
+    else:
+        assert stamps[-1] == "first_chunk_written"
+    # every stamp the engine thread took names its engine step, in order
+    named = [steps[k] for k in ("received", "enqueued", "arrival", "admitted",
+                                "first_launch", "first_token", "last_token")]
+    assert named == sorted(named) and steps["first_launch"] >= 1
+    # (`arrival` names the step begun last before the intake: the step
+    # after it is the first that can admit)
+    assert (steps["admitted"] > steps["arrival"] + 1) == (
+        case == "admitted_a_step_late")
+    assert rec["prefill_dispatches"] == dispatches
+    assert steps["first_token"] - steps["first_launch"] >= dispatches - 1
+    # the same parts feed the totals (/debug/perf, /metrics), once a request
+    for part in parts:
+        assert server.ttft_parts.count[part] == parts0[part] + 1
+
+
+@pytest.mark.parametrize("steps_before,kind,waited", [
+    (0, "idle", "idle"),
+    (1, "ragged", "none"),      # the ragged step launched and waited for nothing
+    (2, "decode", "ragged"),    # the step after it waited the prompt's program out
+    (3, "decode", "decode")])
+def test_arrival_carries_what_the_step_before_its_intake_waited_for(
+        fake_time, steps_before, kind, waited):
+    eng = LLMEngine(make_config(attention_impl="ragged"))
+    eng.add_request("first", prompt_token_ids=[5, 6, 7], sampling=GREEDY)
+    for _ in range(steps_before):
+        eng.step()
+    assert (eng.clock.last_kind, eng.clock.last_wait) == (kind, waited)
+    seq = eng.add_request("probe", prompt_token_ids=[8, 9], sampling=GREEDY)
+    assert seq.arrival_after == waited
+    assert seq.arrival_step == eng.clock.step_num == steps_before
+    # a thread that has waited for work since its last step was idle
+    eng.clock.idle()
+    late = eng.add_request("late", prompt_token_ids=[8, 9], sampling=GREEDY)
+    assert late.arrival_after == "idle"
+
+
+class _Loop:
+    """call_later of an event loop, fired by hand."""
+
+    def __init__(self):
+        self.calls = []
+
+    def call_later(self, delay, fn):
+        self.calls.append((delay, fn))
+        return types.SimpleNamespace(cancel=lambda: None)
+
+
+@pytest.mark.parametrize("due_in,late_by,during", [
+    (0.5, 0.0, "launch"),     # on time, in a host phase
+    (1.2, 21.0, "wait"),      # due in the wait, run after it ended
+    (0.9, 22.0, "launch"),    # due just before the wait began
+    (21.5, 1.5, "postprocess"),
+    (-50.0, 80.0, "none"),    # due before the switches the clock keeps
+])
+def test_a_late_heartbeat_is_charged_to_the_phase_at_its_due_instant(
+        due_in, late_by, during):
+    """Milliseconds: the step launches at 0, waits from 1 to 21, then
+    postprocesses; the beat is due ``due_in`` and runs ``late_by`` late."""
+    fake = types.SimpleNamespace(now=50.0)
+    fake.monotonic = lambda: fake.now
+    fake.thread_time = lambda: fake.now / 4   # on the CPU a quarter of it
+    draws = types.SimpleNamespace(uniform=lambda a, b: 1.0)
+    with mock.patch.object(tracing, "time", fake), \
+            mock.patch.object(tracing, "random", draws):
+        clock = StepClock()
+        assert clock.phase_at(fake.now) == "none"   # never started
+        t0 = fake.now
+        clock.begin_step()
+        clock.launch()
+        fake.now = t0 + 0.001
+        clock.enter("wait")
+        fake.now = t0 + 0.021
+        clock.enter("postprocess")
+        lag, loop = tracing.LoopLag(clock), _Loop()
+        fake.now = t0 + due_in / 1e3 - lag.PERIOD
+        lag.start(loop)
+        (delay, wake), = loop.calls
+        assert delay == lag.PERIOD
+        fake.now = t0 + (due_in + late_by) / 1e3
+        wake()
+        assert lag.ticks == 1 and len(loop.calls) == 2
+        assert lag.lag_seconds.get(during, 0.0) == pytest.approx(
+            late_by / 1e3, abs=1e-9)
+        assert sum(lag.lag_seconds.values()) == pytest.approx(late_by / 1e3,
+                                                              abs=1e-9)
+        # re-armed from the wake, not from the beat it missed
+        assert lag._due == pytest.approx(fake.now + lag.PERIOD)
+        assert lag.take_max() == pytest.approx(late_by / 1e3, abs=1e-9)
+        assert lag.take_max() == 0.0
+        # the loop thread's own clocks, start to this wake
+        assert lag.wall_seconds == pytest.approx(
+            lag.PERIOD + late_by / 1e3, abs=1e-9)
+        assert lag.cpu_seconds == pytest.approx(lag.wall_seconds / 4)
+
+
+def test_heartbeat_delays_are_drawn_around_the_period():
+    """Half to one and a half periods after each wake, so that due
+    instants do not fall on a lattice behind a step's end."""
+    lag, loop = tracing.LoopLag(StepClock()), _Loop()
+    lag.start(loop)
+    for _ in range(200):
+        loop.calls[-1][1]()     # the wake re-arms
+    delays = [d for d, _ in loop.calls]
+    assert lag.ticks == 200 and len(delays) == 201
+    assert 0.5 * lag.PERIOD <= min(delays) < 0.6 * lag.PERIOD
+    assert 1.4 * lag.PERIOD < max(delays) <= 1.5 * lag.PERIOD
+    assert sum(delays) / len(delays) == pytest.approx(lag.PERIOD, rel=0.1)
+    lag.stop()
+
+
+def _clocked_step(clock, fake, kind, phases, compile_inside=False):
+    clock.begin_step()
+    clock.describe(kind, rows=2, tokens=2)
+    for phase, seconds in phases:
+        if phase == "launch":
+            clock.launch()
+        elif phase != "schedule":
+            clock.enter(phase)
+        fake.now += seconds
+    if compile_inside:
+        clock.compiles += 1
+    return clock.end_step()
+
+
+NORMAL = (("schedule", 0.001), ("launch", 0.001), ("wait", 0.020),
+          ("postprocess", 0.002))
+
+
+@pytest.mark.parametrize("overrun,compile_inside,cause", [
+    ("wait", False, "wait"), ("postprocess", False, "postprocess"),
+    ("schedule", False, "schedule"), ("wait", True, "compile")])
+def test_a_step_at_three_times_its_kinds_reference_is_slow_and_names_its_cause(
+        overrun, compile_inside, cause):
+    fake = types.SimpleNamespace(now=10.0)
+    fake.monotonic = lambda: fake.now
+    fake.thread_time = lambda: fake.now
+    # three times a normal step's 24 ms, all of the excess in one phase
+    stalled = tuple((p, s + 0.048 if p == overrun else s) for p, s in NORMAL)
+    with mock.patch.object(tracing, "time", fake):
+        clock = StepClock()
+        # among the first 32 of a kind nothing is slow, whatever it took
+        # (the first of all follows no step: a reference of its own)
+        for i in range(tracing.SLOW_WINDOW + 1):
+            _clocked_step(clock, fake, "decode",
+                          stalled if i in (0, 7, 31) else NORMAL)
+        _clocked_step(clock, fake, "decode", NORMAL)
+        assert not clock.slow_steps
+        seconds = _clocked_step(clock, fake, "decode", stalled,
+                                compile_inside)
+        assert seconds == pytest.approx(0.072)
+        (slow,) = clock.slow_steps
+        assert slow["cause"] == cause and slow["kind"] == "decode"
+        assert slow["step"] == clock.step_num
+        assert (slow["rows"], slow["tokens"]) == (2, 2)
+        assert slow["seconds"] == pytest.approx(0.072)
+        assert slow["reference"] == pytest.approx(0.024)
+        assert slow["phases"][overrun] == pytest.approx(
+            dict(NORMAL)[overrun] + 0.048)
+        assert sum(slow["phases"].values()) == pytest.approx(0.072)
+        assert clock.slow_snapshot()["seconds"]["decode"][cause] == \
+            pytest.approx(0.072)
+        assert slow["after"] == "decode"
+        # just under twice the reference is not slow
+        _clocked_step(clock, fake, "decode",
+                      tuple((p, 1.9 * s) for p, s in NORMAL))
+        assert len(clock.slow_steps) == 1
+        # a step waits out the dispatch before it: a decode step after a
+        # ragged step is held against its own like, not against these
+        _clocked_step(clock, fake, "ragged", stalled)
+        _clocked_step(clock, fake, "decode", stalled)
+        assert len(clock.slow_steps) == 1
+        assert sum(clock.slow_seconds["ragged"].values()) == 0.0
+
+
+def test_no_moment_of_the_worker_loop_is_dropped_with_an_observer_set():
+    """host + wait + idle is the thread's time by the clock's own stamps,
+    first switch to last flush: the observer's call, the hole between two
+    steps and the time before a step's first phase are all in a phase."""
+    eng = LLMEngine(make_config())
+    clock, seen = eng.clock, []
+
+    async def fn():
+        ae = AsyncEngine(eng)
+        ae.step_observer = seen.append
+        await ae.start()
+        toks = []
+        async for out in ae.generate(eng.tokenizer.encode(PROMPTS[0]),
+                                     GREEDY):
+            toks.extend(out.new_token_ids)
+        ae.stop()
+        return toks
+
+    with mock.patch.object(tracing, "time", _fake_time(0.0005)):
+        assert asyncio.run(fn()) == PARENT_TOKENS[0]
+    assert len(seen) == clock.step_num > 0
+    assert clock.flushed_at > clock.started_at > 0
+    assert _clock_total(clock) == pytest.approx(
+        clock.flushed_at - clock.started_at, abs=1e-9)
+    observed = sum(by["observe"][0] for by in clock.seconds.values())
+    assert observed > 0
+    snap = clock.snapshot()
+    assert (snap["started_at"], snap["flushed_at"]) == (
+        clock.started_at, clock.flushed_at)
+
+
+def test_the_server_exports_parts_lag_slow_steps_and_the_routers_stamp(server):
+    families = ("vllm:request_ttft_part_seconds_total",
+                "vllm:request_ttft_parts_total",
+                "vllm:server_loop_lag_seconds_total",
+                "vllm:server_loop_lag_in_wait_seconds_total",
+                "vllm:server_loop_ticks_total",
+                "vllm:server_loop_wall_seconds_total",
+                "vllm:server_loop_cpu_seconds_total",
+                "vllm:server_loop_lag_max_seconds",
+                "vllm:engine_slow_step_seconds_total")
+
+    async def fn(client):
+        first = await (await client.get("/metrics")).text()
+        for fam in families:   # there from the first scrape, at 0 or more
+            assert _samples(first, fam), fam
+        sent = time.time()
+        for rid, stream, hdr in (("parts-stream", True, repr(sent)),
+                                 ("parts-plain", False, None),
+                                 ("parts-bad", True, "soon")):
+            r = await client.post("/v1/completions", json={
+                "model": "tiny-llama", "prompt": PROMPTS[1], "max_tokens": 4,
+                "temperature": 0, "ignore_eos": True, "stream": stream},
+                headers={"x-request-id": rid, **(
+                    {"x-router-sent-unix": hdr} if hdr else {})})
+            assert r.status == 200
+            await r.text()
+        recs = {x["client_request_id"]: x for x in (await (
+            await client.get("/debug/requests")).json())["requests"]}
+        rec = recs["parts-stream"]
+        assert rec["router_sent_unix"] == sent <= rec["received_unix"]
+        assert "router_sent_unix" not in recs["parts-plain"]
+        assert "router_sent_unix" not in recs["parts-bad"]
+        tl, parts = rec["timeline"], rec["ttft_parts"]
+        assert list(parts) == list(tracing.TTFT_PARTS)
+        assert min(parts.values()) >= 0.0
+        assert sum(parts.values()) == pytest.approx(
+            tl["first_chunk_written"] - tl["received"], abs=1e-9)
+        assert rec["intake_after"] in (*STEP_KINDS, "idle")
+        assert rec["prefill_dispatches"] == 1
+        assert "server_deliver" not in recs["parts-plain"]["ttft_parts"]
+        perf = await (await client.get("/debug/perf")).json()
+        second = await (await client.get("/metrics")).text()
+        for part in tracing.TTFT_PARTS:
+            got = perf["ttft_parts"][part]
+            label = f'{{model_name="tiny-llama",part="{part}"}}'
+            assert got["count"] == _samples(second, families[1])[
+                families[1] + label] >= 2
+            assert got["seconds"] == pytest.approx(_samples(
+                second, families[0])[families[0] + label])
+        lag = perf["loop_lag"]
+        assert lag["period_seconds"] == 0.1 and lag["ticks"] > 0
+        assert 0.0 <= lag["cpu_seconds"] <= lag["wall_seconds"] + 0.05
+        assert set(lag["lag_seconds"]) <= {*HOST_PHASES, "wait", "idle",
+                                          "none"}
+        assert set(perf["slow_steps"]["seconds"]) == set(
+            tracing.DISPATCH_KINDS)
+        assert isinstance(perf["slow_steps"]["last"], list)
 
     asyncio.run(_with_client(server, fn))
