@@ -726,6 +726,12 @@ class CacheConfig:
     kv_prefetch_workers: int = 2
 
 
+# a narrow ragged stream is a whole number of the ragged attention kernel's
+# largest tile (ops/ragged_paged_attention_pallas.py Q_TILE; q_tile_for
+# gives divisors of it)
+STREAM_WIDTH_ALIGN = 128
+
+
 @dataclasses.dataclass
 class SchedulerConfig:
     max_num_seqs: int = 64  # decode slots
@@ -786,6 +792,32 @@ class SchedulerConfig:
         except (TypeError, ValueError):
             return 1.0
         return w if w > 0 else 1.0
+
+    @property
+    def ragged_stream_widths(self) -> tuple[int, ...]:
+        """The widths the ragged program exists at, ascending, the token
+        budget last: a ragged step runs at the first that holds the tokens
+        it carries (``LLMEngine._run_ragged``), one compile signature a
+        width. One narrow width, a quarter of the budget cut to a multiple
+        of the ragged kernel's tile: 512 at 2048 / 64 holds 63 decode rows
+        and two average short prompts, and the layer matmuls of a stream
+        that carries them cost a quarter of the budget-wide ones (PERF.md
+        section 6, PR 42). A function of the two numbers beside it and
+        nothing else. The budget alone where the narrow width would be
+        under two rows a slot (a step of decode rows and one short prompt
+        would not fit; and ``max_num_seqs`` itself is never a width: a
+        stream that wide has the decode program's row count) or under one
+        tile: every tiny configuration."""
+        budget = self.max_num_batched_tokens
+        narrow = budget // 4 // STREAM_WIDTH_ALIGN * STREAM_WIDTH_ALIGN
+        if narrow < max(2 * self.max_num_seqs, 1):
+            return (budget,)
+        return (narrow, budget)
+
+    def stream_width_for(self, tokens: int) -> int:
+        """The narrowest of ``ragged_stream_widths`` that holds ``tokens``
+        (the scheduler never packs more than the budget, the last)."""
+        return next(w for w in self.ragged_stream_widths if w >= tokens)
 
     @property
     def decode_horizon(self) -> int:

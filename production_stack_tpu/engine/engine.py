@@ -272,9 +272,11 @@ class LLMEngine:
         self.aborted_seqs = 0  # cancelled/expired, KV freed early
         self.spliced_seqs = 0  # pushed P→D transfers attached decode-ready
         # unified ragged dispatch accounting (attention_impl == "ragged"):
-        # live packed tokens vs the always-budget-wide stream is the
-        # padding-waste signal the bucketed path hid in bucket geometry
+        # live packed tokens vs the budget is the padding-waste signal the
+        # bucketed path hid in bucket geometry; narrow: the dispatches that
+        # ran under the budget's width (SchedulerConfig.ragged_stream_widths)
         self.ragged_dispatches = 0
+        self.ragged_narrow_dispatches = 0
         self.ragged_live_tokens = 0
         # (tile, span) context walks the ragged attention kernel makes for
         # the dispatched span offsets, and those it runs on its narrow row
@@ -993,8 +995,11 @@ class LLMEngine:
         """ONE dispatch for a mixed step: every decode row contributes one
         token (or a 1 + drafts speculative span), FCFS prefill chunks fill
         the rest of the token budget, packed in slot order into a single
-        (1, T) stream (T is always max_num_batched_tokens — one
-        steady-state compile signature, verify included). Draft-free
+        (1, W) stream. W is the narrowest of the scheduler's
+        ``ragged_stream_widths`` that holds the tokens packed (the budget
+        is the last of them): the step is as wide as what it carries, one
+        steady-state compile signature a width, verify included, and what
+        the scheduler decided is not looked at again. Draft-free
         decode-only steps still take _run_decode (multi-step fusion,
         chaining)."""
         bs = self.config.cache.block_size
@@ -1129,6 +1134,9 @@ class LLMEngine:
             self._r_cu[slot + 1] = cu
             seqs_in_step.append(seq)
         assert cu <= T, f"packed {cu} tokens over budget {T}"
+        # the rows past W are padding the packing above left as it found
+        # them: the program runs the same computation on fewer of them
+        W = self.config.scheduler.stream_width_for(cu)
 
         greedy_only = all(
             s.sampling.temperature <= 0.0 for s in seqs_in_step
@@ -1147,18 +1155,20 @@ class LLMEngine:
             self._count_reset_slots.clear()
         use_controls = any(s.token_ctrl is not None for s in seqs_in_step)
         use_grammar = bool((self._g_ids >= 0).any())
-        self.clock.describe("ragged", rows=len(seqs_in_step), tokens=cu)
+        self.clock.describe("ragged", rows=len(seqs_in_step), tokens=cu,
+                            width=W)
         t_call = self.clock.enter("snapshot")
         result_dev = self.runner.ragged_step(
-            self._r_tokens, self._r_positions, self._block_tables,
-            self._context_lens, self._r_cu, self._r_slot_mapping,
+            self._r_tokens[:, :W], self._r_positions[:, :W],
+            self._block_tables, self._context_lens, self._r_cu,
+            self._r_slot_mapping[:W],
             self._r_last_idx, self._r_sample_mask,
             self._temps, self._top_ps, self._top_ks, self._seeds,
             self._steps,
             greedy_only=greedy_only,
             presence=self._presence if use_penalties else None,
             frequency=self._frequency if use_penalties else None,
-            adapter_ids=self._r_adapter_ids if use_lora else None,
+            adapter_ids=self._r_adapter_ids[:W] if use_lora else None,
             ctrl=((self._ctrl_ids, self._ctrl_vals, self._ctrl_mode)
                   if use_controls else None),
             g_ids=self._g_ids if use_grammar else None,
@@ -1181,17 +1191,18 @@ class LLMEngine:
                                     tenants=self._tenant_map(t_entries))
             self._attribute_seq_seconds(dispatch_s, t_entries)
         self.ragged_dispatches += 1
+        self.ragged_narrow_dispatches += W < T
         self.ragged_live_tokens += cu
         if self.recurrent is not None:
             q_len = np.diff(self._r_cu)
             self.recurrent.record_ragged(
                 q_len, continues_one_row(q_len, self._context_lens),
                 sum(sp.chunk_start == 0 for sp in prefills))
-        walks, narrow = count_walks(self._r_cu, T, self.config.model.q_per_kv)
+        walks, narrow = count_walks(self._r_cu, W, self.config.model.q_per_kv)
         self.ragged_attn_walks += walks
         self.ragged_attn_narrow_walks += narrow
         windows, interior = count_windows(
-            self._r_cu, self._context_lens, T, self.config.model.q_per_kv,
+            self._r_cu, self._context_lens, W, self.config.model.q_per_kv,
             self.config.cache.block_size)
         self.ragged_attn_windows += windows
         self.ragged_attn_interior_windows += interior
@@ -1796,10 +1807,11 @@ class LLMEngine:
                                 / max(1, self.config.scheduler.max_num_seqs)),
             "kv_blocks_total": self.runner.num_blocks,
             "kv_blocks_free": self.scheduler.num_free_blocks,
-            # unified ragged path: dispatch count + live-token fill of the
-            # budget-wide stream (engine/metrics.py turns these into
+            # unified ragged path: dispatch counts + live tokens over the
+            # token budget (engine/metrics.py turns these into
             # vllm:ragged_* series)
             "ragged_dispatches_total": self.ragged_dispatches,
+            "ragged_narrow_dispatches_total": self.ragged_narrow_dispatches,
             "ragged_live_tokens_total": self.ragged_live_tokens,
             "ragged_attn_walks_total": self.ragged_attn_walks,
             "ragged_attn_narrow_walks_total": self.ragged_attn_narrow_walks,
@@ -2026,35 +2038,57 @@ class LLMEngine:
             if b <= self.config.model.max_model_len
         ]
 
-        def run(prompts, temperature):
-            sp = SamplingParams(
-                temperature=temperature,
-                max_tokens=max(sched.multi_step, 1) + 1,  # forces one decode
-                ignore_eos=True,
-            )
+        decoding = max(sched.multi_step, 1) + 1  # forces one decode
+        longest = max(self.config.model.max_model_len
+                      - sched.multi_step - 2, 1)
+
+        def run(prompts, temperature, max_tokens=decoding, **feature):
+            sp = SamplingParams(temperature=temperature,
+                                max_tokens=max_tokens, ignore_eos=True,
+                                **feature)
             for i, p in enumerate(prompts):
                 self.add_request(f"warmup-{time.monotonic_ns()}-{i}",
                                  prompt_token_ids=p, sampling=sp)
             while self.has_unfinished():
                 self.step()
 
+        def packed(total, spans=1):
+            """Prompts of ``total`` tokens together (as far as slots and
+            ``max_model_len`` allow), at least ``spans`` of them: what one
+            ragged step packs when they arrive together."""
+            k = min(max(spans, -(-total // longest)), sched.max_num_seqs,
+                    total)
+            return [rng.integers(
+                1, vocab, min(total // k + (i < total % k), longest)).tolist()
+                for i in range(k)]
+
+        # token totals that land a ragged step in each stream width
+        # (SchedulerConfig.ragged_stream_widths), narrowest first: the
+        # 8-token prompts of the feature runs below, then one token over
+        # each width but the budget. The bucketed path has no widths
+        lands = [8]
         if self.attention_impl == "ragged":
+            lands += [w + 1 for w in sched.ragged_stream_widths[:-1]]
             # the ragged program's signature is shape-independent of the
-            # traffic (the stream is always budget-wide, slots always
-            # max_num_seqs): ONE greedy + ONE sampled run covers the whole
-            # bucket x row-class matrix the bucketed path has to walk. The
-            # feature-variant runs below (logprobs / grammar / penalties /
-            # controls) flow through the same unified step and compile
-            # their static-flag variants.
-            n = max(min(sched.max_num_batched_tokens,
-                        self.config.model.max_model_len
-                        - sched.multi_step - 2), 1)
-            run([rng.integers(1, vocab, n).tolist()], 0.0)
-            # a mixed multi-prompt batch: same signature, but exercises the
-            # packed multi-span path once before traffic does
-            m = max(n // 4, 1)
-            run([rng.integers(1, vocab, m).tolist()
-                 for _ in range(min(4, sched.max_num_seqs))], 0.7)
+            # traffic but for the stream's width (slots always
+            # max_num_seqs): ONE greedy + ONE sampled run a width covers
+            # the whole bucket x row-class matrix the bucketed path has to
+            # walk. The sampled run is a mixed multi-prompt batch: same
+            # signature, but exercises the packed multi-span path once
+            # before traffic does. A narrow width's runs end at their
+            # first token (the decode program is not theirs). The
+            # feature-variant runs below flow through the same unified
+            # step and compile their static-flag variants, at every width:
+            # grammar and controls. Logprobs ride every ragged dispatch, a
+            # penalty gates on decode rows (a prompt alone runs the plain
+            # program) and speculation's verify columns ride every
+            # dispatch: those three reach no ragged signature but these.
+            for total in lands[:-1]:
+                run(packed(total), 0.0, max_tokens=1)
+                run(packed(total, spans=4), 0.7, max_tokens=1)
+            n = min(sched.max_num_batched_tokens, longest)
+            run(packed(n), 0.0)
+            run(packed(n, spans=4), 0.7)
         else:
             for b in buckets:
                 n = max(min(b, sched.max_num_batched_tokens,
@@ -2109,40 +2143,19 @@ class LLMEngine:
         for temp in ((0.0, 0.7)
                      if getattr(self.runner, "supports_logprobs", False)
                      else ()):
-            sp = SamplingParams(temperature=temp, logprobs=5,
-                                max_tokens=max(sched.multi_step, 1) + 1,
-                                ignore_eos=True)
-            self.add_request(f"warmup-lp-{time.monotonic_ns()}",
-                             prompt_token_ids=rng.integers(1, vocab, 8).tolist(),
-                             sampling=sp)
-            while self.has_unfinished():
-                self.step()
+            run(packed(lands[0]), temp, logprobs=5)
         # guided-decoding variants (static use_grammar flag): prefill's
         # first-token mask + the fused decode FSM advance, greedy and
         # sampled. Also pays the one-time vocab byte-image build here
         # instead of on the first live guided request.
         if hasattr(self.runner, "register_grammar"):
             for temp in (0.0, 0.7):
-                sp = SamplingParams(
-                    temperature=temp, guided_regex="[ -~]*",
-                    max_tokens=max(sched.multi_step, 1) + 1,
-                    ignore_eos=True,
-                )
-                self.add_request(f"warmup-gram-{time.monotonic_ns()}",
-                                 prompt_token_ids=rng.integers(
-                                     1, vocab, 8).tolist(),
-                                 sampling=sp)
-                while self.has_unfinished():
-                    self.step()
+                run(packed(lands[0]), temp, guided_regex="[ -~]*")
+                for total in lands[1:]:  # the mask at the wider streams
+                    run(packed(total), temp, max_tokens=1,
+                        guided_regex="[ -~]*")
         # penalised decode variant (static use_penalties flag)
-        sp = SamplingParams(temperature=0.0, presence_penalty=0.5,
-                            max_tokens=max(sched.multi_step, 1) + 1,
-                            ignore_eos=True)
-        self.add_request(f"warmup-pen-{time.monotonic_ns()}",
-                         prompt_token_ids=rng.integers(1, vocab, 8).tolist(),
-                         sampling=sp)
-        while self.has_unfinished():
-            self.step()
+        run(packed(lands[0]), 0.0, presence_penalty=0.5)
         # token-controls variants (static use_controls flag): the first
         # logit_bias/allowed_token_ids request must not stall on a
         # mid-traffic recompile of the fused decode + prefill graphs
@@ -2150,14 +2163,9 @@ class LLMEngine:
         # first guided request doesn't compile mid-traffic
         self.choice_logprobs([1, 2, 3, 4], [[5], [6, 7]])
         for temp in (0.0, 0.7):  # greedy and sampled control variants
-            sp = SamplingParams(temperature=temp, logit_bias={1: 0.0},
-                                max_tokens=max(sched.multi_step, 1) + 1,
-                                ignore_eos=True)
-            self.add_request(f"warmup-ctrl-{time.monotonic_ns()}",
-                             prompt_token_ids=rng.integers(1, vocab, 8).tolist(),
-                             sampling=sp)
-            while self.has_unfinished():
-                self.step()
+            run(packed(lands[0]), temp, logit_bias={1: 0.0})
+            for total in lands[1:]:  # and at the wider streams
+                run(packed(total), temp, max_tokens=1, logit_bias={1: 0.0})
         # ring-prefill variants: each power-of-two size class from the
         # threshold up to max_model_len, greedy + sampled
         if self.scheduler.ring_enabled:

@@ -148,14 +148,21 @@ class EngineStatsCollector:
             "Free KV blocks (allocatable right now)",
             s.get("kv_blocks_free", 0),
         )
-        # unified ragged attention path: mixed prefill+decode dispatches
-        # and how much of the budget-wide token stream carried live tokens
-        # (the ragged path's goodput/padding-waste signal)
+        # unified ragged attention path: mixed prefill+decode dispatches,
+        # those that ran at a narrow stream width, and how much of the
+        # token budget carried live tokens (how full the scheduler's
+        # steps are)
         yield counter(
             "vllm:ragged_dispatches",
             "Unified mixed prefill+decode dispatches issued "
             "(attention_impl=ragged)",
             s.get("ragged_dispatches_total", 0),
+        )
+        yield counter(
+            "vllm:ragged_narrow_dispatches",
+            "Ragged dispatches that ran under the token budget's width: "
+            "at a narrow stream width that held their live tokens",
+            s.get("ragged_narrow_dispatches_total", 0),
         )
         yield counter(
             "vllm:ragged_live_tokens",
@@ -373,8 +380,9 @@ class EngineStatsCollector:
             )
         yield gauge(
             "vllm:ragged_stream_utilization",
-            "Cumulative live-token fill of the budget-wide ragged stream "
-            "(live tokens / dispatches x max_num_batched_tokens)",
+            "Cumulative live tokens of the ragged dispatches over the "
+            "token budget (live tokens / dispatches x "
+            "max_num_batched_tokens), whatever width each ran at",
             s.get("ragged_stream_utilization", 0.0),
         )
         # goodput accounting (engine/perf_accounting.py): live roofline

@@ -8,10 +8,13 @@ Static-shape discipline (XLA traces once per shape):
   AND decode rows in the same dispatch — per-slot spans described by
   ``cu_q_lens (S+1,)`` with ``S = max_num_seqs`` slots in slot order
   (decode rows span 1 token, prefilling slots span their chunk, inactive
-  slots span 0). ``T`` is always the token budget
-  (``max_num_batched_tokens``), so the steady-state compile-signature
-  space collapses to ONE signature per program kind: no shape buckets, no
-  padded batch dim, no prefill/decode phase barrier. Sampling happens per
+  slots span 0). ``T`` is one of the scheduler's few stream widths
+  (``SchedulerConfig.ragged_stream_widths``: the token budget
+  ``max_num_batched_tokens`` and, where it pays, one narrow width), the
+  narrowest that holds the step's tokens, and the program reads it off
+  its input: the steady-state compile-signature space is ONE signature a
+  width per program kind, no shape buckets, no padded batch dim, no
+  prefill/decode phase barrier. Sampling happens per
   slot at each span's last token; rows whose sample is not consumed
   (mid-prompt chunks, inactive slots) produce masked garbage the host
   discards.
@@ -412,7 +415,9 @@ class ModelRunner:
         """Worst-case prefill transient, per attention impl + backend.
 
         Ragged: the token budget is the single source of shape truth — the
-        stream is always ``max_num_batched_tokens`` wide, no bucket or
+        stream is at most ``max_num_batched_tokens`` wide (a narrower
+        width of ``ragged_stream_widths`` needs less, so the pool is sized
+        for the budget), no bucket or
         prefill_batch dimension exists. Pallas keeps KV windows in VMEM
         scratch, so only hidden/logits-scale HBM transients remain; the
         XLA ragged reference gathers each token's full context.
@@ -929,7 +934,8 @@ class ModelRunner:
                     fetch: bool = True):
         """ONE unified dispatch over the packed mixed prefill+decode stream.
 
-        tokens/positions: (1, T) with T the token budget (-1 position = tail
+        tokens/positions: (1, T) with T the stream width the engine chose
+        for this step, one of ``ragged_stream_widths`` (-1 position = tail
         padding); block_tables (S, M), context_lens (S,), cu_q_lens (S+1,)
         per-slot span offsets in slot order; slot_mapping (T,) flat KV
         slots (-1 = skip); last_idx (S,) stream index of each slot's final
@@ -952,10 +958,12 @@ class ModelRunner:
         Returns (sampled (S,)[, verify (S, W)], tok_lp (S,),
         top_ids (S, N), top_lps (S, N)) on host — or the un-fetched
         device tuple with ``fetch=False`` so the dispatch overlaps the
-        host's next-step work. T and S never change between dispatches:
-        ONE steady-state compile signature per static-flag variant
-        (CompileTracker treats any post-warmup fresh signature here as a
-        bug signal).
+        host's next-step work. S never changes between dispatches and T
+        is one of a few widths (``StepLayout.of`` keys the layout by the
+        arrays' shapes, so a width is a signature of the one program):
+        ONE steady-state compile signature a width per static-flag
+        variant, each compiled by warmup (CompileTracker treats any
+        post-warmup fresh signature here as a bug signal).
 
         The always-present inputs (verify_idx included when compiled in)
         reach the device as ONE packed buffer in one transfer
@@ -972,7 +980,7 @@ class ModelRunner:
         ``__init__``) so the fetch is a local host copy — no per-step
         cross-chip sync on the host path, and the fused KV-write + verify
         columns run inside the same ``shard_map`` as single-chip. Warmup
-        exercises exactly this signature, so steady state must tick zero
+        exercises exactly these signatures, so steady state must tick zero
         ``vllm:unexpected_recompiles_total`` at TP=4/8 just as at TP=1
         (regression-tested in tests/test_multichip_ragged.py)."""
         arrays = [tokens, positions, block_tables, context_lens, cu_q_lens,

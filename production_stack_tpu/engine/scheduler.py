@@ -6,8 +6,10 @@ Two policies share admission/preemption/blocks:
 ragged attention impl: every step collects ALL decodable sequences (one
 stream token each), then FCFS prefill chunks fill whatever budget decode
 left (``max_num_batched_tokens`` is the only shape knob — no buckets, no
-prefill/decode phase barrier). One mixed batch per step; the runner packs
-it into a single ragged dispatch.
+prefill/decode phase barrier). One mixed batch per step; the engine packs
+it into a single ragged dispatch, as wide as the narrowest of
+``SchedulerConfig.ragged_stream_widths`` that holds it: the width follows
+from what was scheduled here and never bears on it.
 
 **Bucketed (prefill-priority)** — the fallback, per step, in order:
 

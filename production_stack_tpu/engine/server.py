@@ -1882,7 +1882,12 @@ class EngineServer:
         handovers = self.engine.early_handovers
         # the ragged attention kernel's walks, and those on its narrow
         # row block (vllm:ragged_attn_walks_total, ..._narrow_walks_total)
-        walks = {"ragged_attn_walks": self.engine.ragged_attn_walks,
+        walks = {"ragged_dispatches": self.engine.ragged_dispatches,
+                 # those that ran at a narrow stream width
+                 # (vllm:ragged_narrow_dispatches_total)
+                 "ragged_narrow_dispatches":
+                     self.engine.ragged_narrow_dispatches,
+                 "ragged_attn_walks": self.engine.ragged_attn_walks,
                  "ragged_attn_narrow_walks":
                      self.engine.ragged_attn_narrow_walks,
                  # the context windows those walks stream, and those on

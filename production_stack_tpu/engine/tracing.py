@@ -170,10 +170,11 @@ HOST_PHASES = ("intake", "schedule", "build", "snapshot", "commit", "launch",
 STEP_KINDS = ("decode", "ragged", "prefill", "other")
 DISPATCH_KINDS = STEP_KINDS[:-1]  # `other` dispatched nothing
 # a step is slow when it took more than SLOW_FACTOR times the median of
-# the SLOW_WINDOW steps like it before it (of its kind, after a step of the
-# same kind as the one before it: a step waits out the dispatch before it,
-# so a decode step after a ragged step is held against its own like); the
-# last SLOW_RING are kept
+# the SLOW_WINDOW steps like it before it (of its kind and stream width,
+# after a step of the same kind and width as the one before it: a step
+# waits out the dispatch before it, so a decode step after a ragged step is
+# held against its own like, and a budget-wide ragged step among narrow
+# ones is no stall); the last SLOW_RING are kept
 SLOW_WINDOW, SLOW_FACTOR, SLOW_RING = 32, 2.0, 32
 _SWITCHES = 16  # phase switches kept for `phase_at`: more than a step makes
 
@@ -196,6 +197,9 @@ class StepClock:
         # behind. A ragged dispatch is launched by a `ragged` step and
         # waited for by the step after it, whatever that one's kind
         self.last_kind = self.last_wait = "idle"
+        # the stream width of the last step's dispatch (0: not a ragged
+        # step), for `_note_step`
+        self.last_width = 0
         self._waited: Optional[str] = None  # by the open step, first
         self.launch_t = 0.0      # stamp of the last `launch`
         self.compiles = 0        # XLA compiles seen (the engine counts)
@@ -211,8 +215,8 @@ class StepClock:
         # there from the start, so the family is exported at 0
         self.slow_seconds = {k: {"wait": 0.0} for k in DISPATCH_KINDS}
         self.slow_steps: deque = deque(maxlen=SLOW_RING)
-        # (kind of the step before, kind) -> the last SLOW_WINDOW such
-        # steps' (seconds, {phase: seconds})
+        # (kind and width of the step before, kind, width) -> the last
+        # SLOW_WINDOW such steps' (seconds, {phase: seconds})
         self._recent: dict = {}
         self._scratch: dict = {}  # phase -> [wall, cpu] since the last flush
         self._at_begin: dict = {}  # phase -> wall in _scratch at begin_step
@@ -292,6 +296,7 @@ class StepClock:
     def idle(self) -> float:
         """The thread is about to block on its intake queue."""
         self.last_kind = self.last_wait = "idle"
+        self.last_width = 0
         return self.enter("idle")
 
     def begin_step(self) -> None:
@@ -307,11 +312,15 @@ class StepClock:
         self._at_begin = {p: acc[0] for p, acc in self._scratch.items()}
         self._compiles_at_begin = self.compiles
 
-    def describe(self, kind: str, rows: int, tokens: int) -> None:
+    def describe(self, kind: str, rows: int, tokens: int,
+                 width: int = 0) -> None:
         """What the open step dispatches: its kind for the counters, and
-        what `step.launch` carries into the trace."""
+        what `step.launch` carries into the trace. ``width``: the stream
+        width a ragged step runs at (`LLMEngine._run_ragged`)."""
         self.kind = kind
         self._launch = {"kind": kind, "rows": rows, "tokens": tokens}
+        if width:
+            self._launch["width"] = width
 
     def launch(self, **attrs) -> float:
         """``attrs``: what the runner adds to the annotation (a looped
@@ -350,6 +359,7 @@ class StepClock:
         self.steps[self.kind] += 1
         self.in_step = False
         self.last_kind, self.last_wait = self.kind, self._waited or "none"
+        self.last_width = self._launch.get("width", 0)
         self.kind, self._launch, self._waited = "other", {}, None
         return seconds
 
@@ -360,8 +370,10 @@ class StepClock:
         keep it if it is slow, with the phase that overran most."""
         in_step = {p: acc[0] - self._at_begin.get(p, 0.0)
                    for p, acc in self._scratch.items()}
-        recent = self._recent.setdefault((self.last_kind, self.kind),
-                                         deque(maxlen=SLOW_WINDOW))
+        recent = self._recent.setdefault(
+            (self.last_kind, self.last_width, self.kind,
+             self._launch.get("width", 0)),
+            deque(maxlen=SLOW_WINDOW))
         if len(recent) == SLOW_WINDOW:
             ref = _median([s for s, _ in recent])
             if seconds > SLOW_FACTOR * ref:
@@ -375,6 +387,7 @@ class StepClock:
                 self.slow_steps.append({
                     "step": self.step_num, **self._launch,
                     "kind": self.kind, "after": self.last_kind,
+                    "after_width": self.last_width,
                     "seconds": seconds,
                     "reference": ref, "phases": in_step, "cause": cause})
         recent.append((seconds, in_step))

@@ -196,6 +196,36 @@ def test_zero_unexpected_recompiles_after_warmup_tp4():
     assert eng.perf.stats_fields()["unexpected_recompiles"] == 0
 
 
+def test_zero_unexpected_recompiles_at_both_stream_widths_tp4():
+    """A budget with a narrow stream width (128 of 512; PR 42): warmup
+    compiles the sharded signatures at both, so a narrow step and then a
+    wide one, greedy, sampled and with logprobs, hit compiled programs,
+    and the replicated packed buffer takes either length."""
+    eng = _engine(4, max_num_seqs=4, max_num_batched_tokens=512)
+    assert eng.config.scheduler.ragged_stream_widths == (128, 512)
+    eng.warmup()
+    fields = eng.perf.stats_fields()
+    assert fields["unexpected_recompiles"] == 0
+    assert sorted(n for k, n in fields["compile_counts"].items()
+                  if k[0] == "ragged") == [6, 6]
+    rng = np.random.default_rng(5)
+    narrow = eng.ragged_narrow_dispatches
+    for i, n in enumerate((20, 200)):
+        before = eng.ragged_dispatches
+        _drain(eng, [
+            (f"g{i}", rng.integers(1, 200, n).tolist(), GREEDY),
+            (f"s{i}", rng.integers(1, 200, n).tolist(),
+             SamplingParams(temperature=0.7, max_tokens=4, ignore_eos=True,
+                            logprobs=3)),
+            (f"l{i}", rng.integers(1, 200, n).tolist(),
+             dataclasses.replace(GREEDY, logprobs=3)),
+        ], stagger_at=(3, 6))
+        assert eng.ragged_dispatches > before
+    # the 20-token prompts ran narrow, the 200-token ones did not
+    assert eng.ragged_narrow_dispatches - narrow == 3
+    assert eng.perf.stats_fields()["unexpected_recompiles"] == 0
+
+
 # ---- ICI roofline accounting (unit) ---------------------------------------
 
 V5E_TFLOPS, V5E_HBM_GBPS, V5E_PEAK_ICI_GBPS = DEVICE_PEAKS["TPU v5 lite"]
