@@ -23,13 +23,15 @@ from production_stack_tpu.parallel.mesh import MeshConfig
 class ModelConfig:
     name: str = "tiny-llama"
     # "llama" | "mixtral" | "olmoe" | "gemma" | "gemma2" | "phi3" | "ouro"
-    # | "solar_open2"
+    # | "solar_open2" | "pangu_ultra_moe"
     # — Mistral and Qwen run as "llama" (their deltas are knobs:
     # sliding_window, qkv_bias, qk_norm); "phi3" differs only in its fused
     # HF weight layout, "mixtral" and "olmoe" in their HF tensor names
     # (the MoE block itself is chosen by num_experts > 0, see is_moe),
     # "ouro" in its tensor names and in loop_passes > 1, "solar_open2" in
-    # its layer pattern (attn_period > 1) and its sparse block's knobs
+    # its layer pattern (attn_period > 1) and its sparse block's knobs,
+    # "pangu_ultra_moe" in its latent attention (kv_lora_rank > 0) and its
+    # leading dense layers
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -101,6 +103,26 @@ class ModelConfig:
     # required to be <= sliding_window (enforced at engine init), where
     # local and global attention coincide.
     sliding_window: int = 0
+    # latent attention (MLA; "pangu_ultra_moe"): queries and keys-values
+    # pass through low-rank projections with a norm each; a head's key is
+    # qk_nope_head_dim values expanded from the kv_lora_rank-wide latent
+    # plus qk_rope_head_dim rotated values all heads share. The cache
+    # holds ONE row of kv_lora_rank + qk_rope_head_dim values a token and
+    # cache layer, keys and values in one (latent_lanes in the pool), and
+    # attention runs absorbed: the query is carried into the latent space
+    # (models/llama.py _mla_mixer). head_dim is then the query head's
+    # qk_nope + qk_rope and num_kv_heads what the file publishes; neither
+    # sizes the cache. 0 = keys and values per head
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # leading dense layers: the first dense_layers of num_layers have a
+    # plain MLP of dense_intermediate_size in place of the sparse block and
+    # run before the scan over the expert layers, cache layers 0.. in order
+    dense_layers: int = 0
+    dense_intermediate_size: int = 0
     # weight-tied stacks ("ouro"): every token passes through the SAME
     # num_layers layers loop_passes times; the final norm closes each pass
     # and every (pass, layer) pair attends over keys and values of its
@@ -161,6 +183,28 @@ class ModelConfig:
         return self.attn_period > 1
 
     @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Values of a latent cache row: the latent and the shared rotated
+        key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_lanes(self) -> int:
+        """A latent row as the pool holds it: ``latent_width`` padded with
+        zeros to whole 128-lane tiles (576 -> 640). At 576 the TPU
+        compiler copied the whole pool into a 640-lane layout at every
+        update (PERF.md section 6, PR 43)."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def num_expert_layers(self) -> int:
+        return self.num_layers - self.dense_layers
+
+    @property
     def num_attn_layers(self) -> int:
         if not self.has_recurrent_state:
             return self.num_layers
@@ -175,11 +219,21 @@ class ModelConfig:
         """Layers of KV cache: one per (pass, attention layer) pair."""
         return self.num_attn_layers * self.loop_passes
 
+    def kv_pool_shape(self, num_blocks: int, block_size: int) -> tuple:
+        """The paged pool's shape, the one place that says what a token of
+        context holds: a ``(2*KH, D)`` slab of keys and values a cache
+        layer, or with latent attention one row of ``latent_lanes``."""
+        token = ((self.latent_lanes,) if self.is_latent
+                 else (2 * self.num_kv_heads, self.head_dim))
+        return (self.cache_layers, num_blocks, block_size, *token)
+
     @property
     def kv_bytes_per_token(self) -> int:
-        """Keys and values one token of context holds, all cache layers."""
-        return (2 * self.cache_layers * self.num_kv_heads * self.head_dim
-                * jnp.dtype(self.jax_dtype).itemsize)
+        """What one token of context holds in the pool, all cache layers."""
+        n = 1
+        for d in self.kv_pool_shape(1, 1):
+            n *= d
+        return n * jnp.dtype(self.jax_dtype).itemsize
 
     def recurrent_state_bytes(self, slots: int) -> int:
         """What the recurrent layers keep for ``slots`` decode slots: a
@@ -235,6 +289,8 @@ class ModelConfig:
             arch = "ouro"
         elif cfg.get("model_type") == "solar_open2":
             return ModelConfig._solar_open2_from_hf(cfg, name)
+        elif cfg.get("model_type") == "pangu_ultra_moe":
+            return ModelConfig._pangu_ultra_moe_from_hf(cfg, name)
         elif any("Phi3" in a for a in archs):
             # only the standard Phi-3 maps onto the fused-Llama layout;
             # Phi-3-small (query_key_value naming, gegelu, blocksparse)
@@ -429,6 +485,85 @@ class ModelConfig:
             kda_rank=kda_dim,
             kda_neg_eigval=bool(cfg.get("kda_allow_neg_eigval", False)),
             attn_gate=bool(cfg.get("use_gqa_gate", False)),
+        )
+
+    @staticmethod
+    def _pangu_ultra_moe_from_hf(cfg: dict, name: str = "") -> "ModelConfig":
+        """``model_type: pangu_ultra_moe``: latent attention (MLA) in every
+        layer, norms before and after each sublayer, ``first_k_dense_replace``
+        leading dense layers and then sparse blocks with sigmoid routing and
+        a shared expert. ``n_routed_experts_held`` / ``routed_expert_offset``
+        state the chip's share of the routed experts, as for solar_open2.
+        What is not computed is refused by name."""
+        what = "pangu_ultra_moe"
+        if int(cfg.get("n_group", 1) or 1) > 1:
+            raise ValueError(
+                f"{what} with n_group={cfg['n_group']} is not supported "
+                "(no group-limited routing)")
+        if cfg.get("rope_scaling"):
+            raise ValueError(
+                f"{what} with rope_scaling={cfg['rope_scaling']!r} is not "
+                "supported (plain rotary frequencies from rope_theta only)")
+        if not cfg.get("sandwich_norm", False):
+            raise ValueError(
+                f"{what} with sandwich_norm: false is not supported (a norm "
+                "before and after each sublayer, as published)")
+        if int(cfg.get("num_nextn_predict_layers", 0) or 0) > 0:
+            raise ValueError(
+                f"{what} with num_nextn_predict_layers="
+                f"{cfg['num_nextn_predict_layers']} is not supported: the "
+                "multi-token-prediction module is not loaded and nothing "
+                "drafts with it (serve the file with it set to 0)")
+        if cfg.get("attention_bias", False):
+            raise ValueError(
+                f"{what} with attention_bias: true is not supported")
+        layers = int(cfg["num_hidden_layers"])
+        dense = int(cfg.get("first_k_dense_replace", 0))
+        if not 0 <= dense < layers:
+            raise ValueError(
+                f"{what}: first_k_dense_replace={dense} leaves no expert "
+                f"layer of num_hidden_layers={layers}")
+        experts = int(cfg["n_routed_experts"])
+        held = int(cfg.get("n_routed_experts_held", experts))
+        offset = int(cfg.get("routed_expert_offset", 0))
+        if not 0 < held <= experts or not 0 <= offset <= experts - held:
+            raise ValueError(
+                f"{what}: n_routed_experts_held={held} from "
+                f"routed_expert_offset={offset} is not a share of "
+                f"n_routed_experts={experts}")
+        heads = cfg["num_attention_heads"]
+        nope, rope = int(cfg["qk_nope_head_dim"]), int(cfg["qk_rope_head_dim"])
+        return ModelConfig(
+            name=name or cfg.get("_name_or_path", "hf-model"),
+            architecture="pangu_ultra_moe",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["moe_intermediate_size"],
+            num_layers=layers,
+            num_heads=heads,
+            num_kv_heads=cfg.get("num_key_value_heads", heads),
+            head_dim=nope + rope,
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_model_len=cfg.get("max_position_embeddings", 4096),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            num_experts=experts,
+            num_experts_per_tok=cfg.get("num_experts_per_tok", 8),
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+            experts_held=held if held < experts else 0,
+            expert_offset=offset,
+            moe_scoring="sigmoid",
+            routed_scaling=float(cfg.get("routed_scaling_factor", 1.0)),
+            shared_expert_size=(int(cfg.get("n_shared_experts", 0))
+                                * cfg["moe_intermediate_size"]),
+            post_norms=True,
+            kv_lora_rank=int(cfg["kv_lora_rank"]),
+            q_lora_rank=int(cfg["q_lora_rank"]),
+            qk_nope_head_dim=nope,
+            qk_rope_head_dim=rope,
+            v_head_dim=int(cfg["v_head_dim"]),
+            dense_layers=dense,
+            dense_intermediate_size=cfg["intermediate_size"] if dense else 0,
         )
 
     @staticmethod
@@ -656,6 +791,20 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         num_heads=16, num_kv_heads=16, head_dim=128, rope_theta=1000000.0,
         rms_norm_eps=1e-6, max_model_len=65536, post_norms=True,
         loop_passes=4, residual_f32=True,
+    ),
+    "tiny-pangu": ModelConfig(
+        # openPangu-Ultra-MoE's block at test size: latent attention (a
+        # 32 + 16 = 48-value cache row, 128 lanes in the pool), norms before
+        # and after each sublayer, one leading dense layer, then sigmoid
+        # routing over 8 experts (2 a token) beside a shared one
+        name="tiny-pangu", architecture="pangu_ultra_moe", vocab_size=512,
+        hidden_size=128, intermediate_size=64, num_layers=3, num_heads=4,
+        num_kv_heads=4, head_dim=48, rope_theta=25600000.0,
+        max_model_len=512, num_experts=8, num_experts_per_tok=2,
+        moe_scoring="sigmoid", routed_scaling=2.5, shared_expert_size=64,
+        post_norms=True, kv_lora_rank=32, q_lora_rank=48,
+        qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+        dense_layers=1, dense_intermediate_size=256, dtype="float32",
     ),
     "tiny-whisper": ModelConfig(
         # CPU-testable Whisper: 1 s audio window (n_audio_ctx 50 -> 100

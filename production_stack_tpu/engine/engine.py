@@ -85,6 +85,10 @@ class LLMEngine:
         self.tokenizer = get_tokenizer(config.model.tokenizer)
         from production_stack_tpu.parallel.mesh import AXIS_STAGE
 
+        if config.model.is_latent:
+            # the one call at start-up, before a runner is chosen: a staged
+            # runner builds its stages' runners on one-device submeshes
+            ModelRunner._refuse_for_latent_cache(config, self.mesh)
         if self.mesh.shape[AXIS_STAGE] > 1:
             # pipeline-parallel serving: per-stage submeshes + KV pools
             from production_stack_tpu.engine.pp_runner import StagedModelRunner
@@ -102,6 +106,14 @@ class LLMEngine:
             recurrent_state=config.model.has_recurrent_state,
         )
         self.scheduler.now = self.clock.now
+        # what the latent attention kernel of an MLA model was asked to
+        # score (engine/tracing.py); None for every other model
+        self.latent = None
+        if config.model.is_latent:
+            from production_stack_tpu.engine.tracing import LatentCounters
+
+            self.latent = LatentCounters(config.model.cache_layers,
+                                         config.model.kv_bytes_per_token)
         # what the recurrent layers of a hybrid stack ran
         # (engine/tracing.py); None for every other model
         self.recurrent = None
@@ -1198,14 +1210,20 @@ class LLMEngine:
             self.recurrent.record_ragged(
                 q_len, continues_one_row(q_len, self._context_lens),
                 sum(sp.chunk_start == 0 for sp in prefills))
-        walks, narrow = count_walks(self._r_cu, W, self.config.model.q_per_kv)
-        self.ragged_attn_walks += walks
-        self.ragged_attn_narrow_walks += narrow
-        windows, interior = count_windows(
-            self._r_cu, self._context_lens, W, self.config.model.q_per_kv,
-            self.config.cache.block_size)
-        self.ragged_attn_windows += windows
-        self.ragged_attn_interior_windows += interior
+        if self.latent is not None:
+            # another kernel: the ragged kernel's walks and windows stay 0
+            self.latent.record("ragged", np.diff(self._r_cu),
+                               self._context_lens)
+        else:
+            walks, narrow = count_walks(self._r_cu, W,
+                                        self.config.model.q_per_kv)
+            self.ragged_attn_walks += walks
+            self.ragged_attn_narrow_walks += narrow
+            windows, interior = count_windows(
+                self._r_cu, self._context_lens, W,
+                self.config.model.q_per_kv, self.config.cache.block_size)
+            self.ragged_attn_windows += windows
+            self.ragged_attn_interior_windows += interior
 
         # scheduler-visible state advances NOW; results land next step
         # (same deferral contract as _run_prefill / chained decode). A spec
@@ -1468,6 +1486,9 @@ class LLMEngine:
         self.decode_dispatches += 1
         if self.recurrent is not None:
             self.recurrent.record_decode(K)
+        if self.latent is not None:
+            self.latent.record("decode", self._context_lens > 0,
+                               self._context_lens, iterations=K)
         attn_calls = K * self.config.model.cache_layers
         self.decode_attn_calls += attn_calls
         if getattr(self.runner, "decode_attn_slab", False):
@@ -1836,6 +1857,8 @@ class LLMEngine:
             if counters is not None:
                 out.update(counters.snapshot())
         out.update(self.recurrent_stats())
+        if self.latent is not None:
+            out.update(self.latent.snapshot())
         if self.host_kv is not None:
             out["cpu_cache_usage_perc"] = self.host_kv.usage
             out["cpu_prefix_cache_hits_total"] = self.host_kv.hits

@@ -2,7 +2,11 @@
 
 TPU-first design:
 
-- The device cache is of two kinds. Attention layers keep paged keys and
+- The device cache is of three kinds. Latent-attention layers keep one
+  row a token, keys and values in one: ``(L, num_blocks, block_size,
+  lanes)``, ``lanes`` the latent and the shared rotated key padded to
+  whole 128-lane tiles (``ModelConfig.kv_pool_shape`` gives every pool's
+  shape). Other attention layers keep paged keys and
   values: one fused array ``(L, num_blocks, block_size, 2*KH, D)`` living
   in HBM (L is ``ModelConfig.cache_layers``: the ATTENTION layers; a
   looped stack keeps a cache layer for every (pass, layer) pair),
@@ -75,12 +79,13 @@ def init_kv_cache(
         raise ValueError("num_blocks must be resolved before init (see sizing)")
     # KV cache never shards the layer axis onto pipeline stages here; when
     # stage > 1 the per-stage engine owns its own slice of layers.
-    axes = (None, None, None, ln.KV_HEADS, ln.HEAD_DIM)
+    # A latent pool, (L, N, block, lanes), is one row a token that every
+    # head reads: nothing of it shards (one device, model_runner.py
+    # _refuse_for_latent_cache)
+    axes = ((None,) * 4 if model.is_latent
+            else (None, None, None, ln.KV_HEADS, ln.HEAD_DIM))
     sharding = logical_to_sharding(axes, mesh, rules)
-    shape = (
-        model.cache_layers, n, cache.block_size, 2 * model.num_kv_heads,
-        model.head_dim,
-    )
+    shape = model.kv_pool_shape(n, cache.block_size)
     dt = model.jax_dtype
 
     def _zeros():

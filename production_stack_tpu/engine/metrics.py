@@ -320,6 +320,32 @@ class EngineStatsCollector:
                 "vllm:moe_decode_experts_touched",
                 s["moe_decode_layer_steps_total"],
             )
+        # latent attention (engine/tracing.py LatentCounters): exported by
+        # models that keep a latent cache only
+        if "mla_scored_pairs_total" in s:
+            for name, key, doc in (
+                ("vllm:mla_query_tokens", "mla_query_tokens_total",
+                 "Query tokens the latent attention kernel took, by step "
+                 "kind, times cache layers"),
+                ("vllm:mla_scored_pairs", "mla_scored_pairs_total",
+                 "(query token, context row) pairs the latent attention "
+                 "kernel scored causally, by step kind, times cache "
+                 "layers: q (ctx - q) + q (q + 1) / 2 a span"),
+                ("vllm:mla_context_rows", "mla_context_rows_total",
+                 "Context rows the spans of the latent attention kernel "
+                 "reach, each once a span and cache layer, by step kind"),
+            ):
+                fam = CounterMetricFamily(
+                    name, doc, labels=["model_name", "kind"])
+                for kind, value in s[key].items():
+                    fam.add_metric([self.model_name, kind], value)
+                yield fam
+            yield gauge(
+                "vllm:kv_bytes_per_token",
+                "Bytes one token of context holds in the paged pool, all "
+                "cache layers (a latent row padded to whole lane tiles)",
+                s["kv_bytes_per_token"],
+            )
         # recurrent-state layers (engine/tracing.py RecurrentCounters):
         # exported by hybrid stacks only
         if "kda_decode_calls_total" in s:

@@ -142,6 +142,14 @@ _RAGGED_INPUTS = (
 )
 
 
+def _owning_slots(cu_q_lens, tokens: int, slots: int):
+    """Owning slot per token of a packed stream, recovered from the span
+    offsets (the XLA attention forms ask it; the kernels walk the offsets)."""
+    return jnp.clip(jnp.searchsorted(
+        cu_q_lens, jnp.arange(tokens, dtype=jnp.int32), side="right"
+    ).astype(jnp.int32) - 1, 0, slots - 1)
+
+
 def _pallas_ok(cfg: ModelConfig, mesh: Mesh, block_size: int) -> bool:
     """Whether the Pallas attention kernels can serve this model here. On
     an accelerator a False is a slow path (XLA gather attention), so the
@@ -151,9 +159,12 @@ def _pallas_ok(cfg: ModelConfig, mesh: Mesh, block_size: int) -> bool:
         return False
     tp = mesh.shape[AXIS_TENSOR]
     # Mosaic tiling: head_dim must fill the 128-lane dim, block_size the
-    # sublane dim (8 f32 / 16 bf16)
+    # sublane dim (8 f32 / 16 bf16). A latent pool's rows are whole lane
+    # tiles as stored and no head of it shards: the block alone decides
     failed = [
-        what for ok, what in (
+        what for ok, what in ((
+            (block_size % 16 == 0, f"block_size {block_size} % 16 != 0"),
+        ) if cfg.is_latent else (
             (cfg.num_kv_heads % tp == 0,
              f"num_kv_heads {cfg.num_kv_heads} % tensor {tp} != 0"),
             (cfg.num_heads % tp == 0,
@@ -161,7 +172,7 @@ def _pallas_ok(cfg: ModelConfig, mesh: Mesh, block_size: int) -> bool:
             (cfg.head_dim % 128 == 0,
              f"head_dim {cfg.head_dim} % 128 != 0"),
             (block_size % 16 == 0, f"block_size {block_size} % 16 != 0"),
-        ) if not ok
+        )) if not ok
     ]
     if failed:
         _log.warning(
@@ -227,7 +238,8 @@ class ModelRunner:
         # whether a decode step's attention calls run the Pallas decode
         # kernel's slab body: the kernel's own predicate at this runner's
         # per-shard geometry (vllm:decode_attn_slab_calls_total)
-        self.decode_attn_slab = self.use_pallas and decode_slab_path(
+        self.decode_attn_slab = (
+            self.use_pallas and not self.cfg.is_latent) and decode_slab_path(
             self.cfg.num_kv_heads // self.tp,
             self.cfg.q_per_kv, self.cfg.head_dim, self.cfg.jax_dtype)
         impl = getattr(config, "attention_impl", "auto") or "auto"
@@ -239,7 +251,8 @@ class ModelRunner:
         # ragged path stays reachable by forcing "ragged" (parity tests)
         self.attention_impl = (
             impl if impl != "auto"
-            else ("ragged" if self.use_pallas or self.cfg.has_recurrent_state
+            else ("ragged" if (self.use_pallas or self.cfg.has_recurrent_state
+                               or self.cfg.is_latent)
                   else "bucketed")
         )
         if self.cfg.has_recurrent_state and self.attention_impl != "ragged":
@@ -247,6 +260,12 @@ class ModelRunner:
                 f"{self.cfg.name}: attention_impl={self.attention_impl} is "
                 "not supported for a recurrent-state model: only the ragged "
                 "step carries span boundaries to its recurrent layers")
+        if self.cfg.is_latent and self.attention_impl != "ragged":
+            raise ValueError(
+                f"{self.cfg.name}: attention_impl={self.attention_impl} is "
+                "not supported for a latent cache: the latent attention "
+                "kernel takes the ragged stream (a decode step its "
+                "one-token spans); there is no bucketed prefill over it")
         self.num_blocks = self._resolve_num_blocks(num_blocks)
         self.kv = self._init_cache()
         # block-table width padded to a multiple of the kernels' DMA window
@@ -395,6 +414,46 @@ class ModelRunner:
                 f"{name} keeps recurrent state per decode slot; not "
                 "supported with it: " + "; ".join(refused))
 
+    @staticmethod
+    def _refuse_for_latent_cache(config: EngineConfig, mesh: Mesh,
+                                 lora: bool = False) -> None:
+        """A latent-attention (MLA) model keeps one row a token that every
+        query head reads, written and read by ONE chip's ragged and decode
+        step programs. Whatever would shard, frame, quantize or draft over
+        that row in another form is refused here by name, not served
+        wrongly (``lora``: an adapter is being loaded)."""
+        name = config.model.name
+        refused = [
+            what for bad, what in (
+                (mesh.devices.size > 1,
+                 f"a mesh of {mesh.devices.size} devices (tensor, sequence "
+                 "or pipeline parallelism): the heads would shard and the "
+                 "one latent row would not; pipeline stages hold no latent "
+                 "pool"),
+                (config.model.quant is not None,
+                 f"quant={config.model.quant}: the low-rank projections "
+                 "and the absorbed expansions are not quantized"),
+                (lora,
+                 "LoRA adapters: their wq / wk / wv targets do not exist "
+                 "on the low-rank query and key-value paths"),
+                (config.scheduler.spec_ngram_k > 0,
+                 "n-gram speculative decoding (spec_ngram_k > 0): verify "
+                 "spans through the latent kernel are not held against "
+                 "plain decoding by any test"),
+                (config.role != "unified",
+                 f"role={config.role}: a P->D transfer frames (2*KH, D) "
+                 "slabs of keys and values, not latent rows"),
+                (bool(config.cache.host_offload_blocks
+                      or config.cache.kv_host_cache_bytes
+                      or config.cache.remote_kv_url),
+                 "a host or remote KV tier: the tiers hold (2*KH, D) "
+                 "slabs of keys and values, not latent rows"),
+            ) if bad]
+        if refused:
+            raise ValueError(
+                f"{name} keeps a latent cache (one row a token for all "
+                "heads); not supported with it: " + "; ".join(refused))
+
     def _init_cache(self):
         return kvmod.init_kv_cache(
             self.cfg, self.config.cache, self.mesh, self.rules,
@@ -444,12 +503,27 @@ class ModelRunner:
                 # kernel, its output both ways
                 hidden += (T * self.cfg.kda_heads * self.cfg.kda_head_dim
                            * (6 * 2 + 12 * 4)) // 8
+            if self.cfg.is_latent:
+                # a latent layer's rows of all heads: the two parts of the
+                # query, the absorbed query at the pool's lanes, the
+                # kernel's output and its expansion to values, in the
+                # model dtype; the absorbed query once more in float32
+                c = self.cfg
+                hidden += (T * c.num_heads * (
+                    2 * (c.head_dim + c.latent_lanes + c.kv_lora_rank
+                         + c.v_head_dim)
+                    + 4 * c.latent_lanes)) // 8
             if self.use_pallas:
                 return int(8 * hidden + 4 * logits)
             ctx = self.cfg.max_model_len
-            scores = (T * ctx * self.cfg.num_kv_heads
-                      * self.cfg.q_per_kv * 4)
-            gather = 2 * T * ctx * self.cfg.num_kv_heads * self.cfg.head_dim * 2
+            if self.cfg.is_latent:  # one row of latent_lanes, all heads
+                scores = T * ctx * self.cfg.num_heads * 4
+                gather = T * ctx * self.cfg.latent_lanes * (2 + 4)
+            else:
+                scores = (T * ctx * self.cfg.num_kv_heads
+                          * self.cfg.q_per_kv * 4)
+                gather = (2 * T * ctx * self.cfg.num_kv_heads
+                          * self.cfg.head_dim * 2)
             return int(3.5 * scores + 2 * gather + 8 * hidden + 4 * logits)
         Pb = max(sched.prefill_batch, 1)
         # the bucketed scheduler never issues a chunk past the largest bucket
@@ -631,8 +705,48 @@ class ModelRunner:
         )
         return out, caches
 
+    def _attend_latent(self, q, rows, caches, layer_idx, block_tables,
+                       context_lens, q_positions, slot_mapping, cu_q_lens):
+        """Latent attention over a packed stream: absorbed queries q
+        (T, H, latent_lanes), the tokens' own rows (T, latent_lanes) to
+        write first, spans by cu_q_lens (S+1,). Returns ((T, H,
+        kv_lora_rank), caches). The rows go in by the XLA scatter on the
+        chip too: a (16, lanes) bf16 block is whole tiles, two tokens a
+        sublane, so no DMA writes one token's row, and the scatter keeps
+        the donated pool in place (tests/test_kernel_names_v5e.py)."""
+        from production_stack_tpu.ops.paged_attention import (
+            latent_ragged_paged_attention,
+            write_latent,
+        )
+
+        C = self.cfg.kv_lora_rank
+        caches = write_latent(caches, layer_idx, rows, slot_mapping)
+        if self.use_pallas:
+            from production_stack_tpu.ops.latent_paged_attention_pallas import (  # noqa: E501
+                latent_paged_attention_pallas,
+            )
+
+            return latent_paged_attention_pallas(
+                q, caches, block_tables, cu_q_lens, context_lens, layer_idx,
+                value_dim=C), caches
+        seq_ids = _owning_slots(cu_q_lens, q.shape[0], block_tables.shape[0])
+        layer = jax.lax.dynamic_index_in_dim(caches, layer_idx, 0, False)
+        return latent_ragged_paged_attention(
+            q, layer, block_tables, context_lens, seq_ids, q_positions,
+            value_dim=C), caches
+
     def _attend_decode(self, q, k, v, caches, layer_idx, block_tables,
                        context_lens, q_positions, slot_mapping):
+        if self.cfg.is_latent:
+            # the same kernel on one-token spans, a slot a token; an idle
+            # slot (context 0, position < 0) walks nothing
+            B = q.shape[0]
+            out, caches = self._attend_latent(
+                q[:, 0], k[:, 0, 0], caches, layer_idx, block_tables,
+                context_lens, jnp.where(context_lens > 0,
+                                        q_positions[:, 0], -1),
+                slot_mapping, jnp.arange(B + 1, dtype=jnp.int32))
+            return out[:, None], caches
         if not self.use_pallas:
             caches = write_kv(caches, layer_idx, k[:, 0], v[:, 0], slot_mapping,
                               self.tp)
@@ -670,6 +784,11 @@ class ModelRunner:
         for the XLA reference path; the Pallas kernel derives positions
         from cu_q_lens/context_lens on its own."""
         T = q.shape[1]
+        if self.cfg.is_latent:
+            out, caches = self._attend_latent(
+                q[0], k[0, :, 0], caches, layer_idx, block_tables,
+                context_lens, q_positions[0], slot_mapping, cu_q_lens)
+            return out[None], caches
         k_flat = k.reshape(T, -1, self.cfg.head_dim)
         v_flat = v.reshape(T, -1, self.cfg.head_dim)
         if not self.use_pallas:
@@ -682,14 +801,7 @@ class ModelRunner:
             layer = jax.lax.dynamic_index_in_dim(
                 caches, layer_idx, 0, keepdims=False
             )
-            S = block_tables.shape[0]
-            # owning slot per token, recovered from the span offsets
-            seq_ids = (
-                jnp.searchsorted(
-                    cu_q_lens, jnp.arange(T, dtype=jnp.int32), side="right"
-                ).astype(jnp.int32) - 1
-            )
-            seq_ids = jnp.clip(seq_ids, 0, S - 1)
+            seq_ids = _owning_slots(cu_q_lens, T, block_tables.shape[0])
             out = ragged_paged_attention(
                 q[0], layer, block_tables, context_lens, seq_ids,
                 q_positions[0], tp=self.tp,
@@ -1240,6 +1352,8 @@ class ModelRunner:
     # -- multi-LoRA bank -----------------------------------------------------
     def register_lora(self, slot: int, bank_np: dict) -> None:
         """Write an adapter's stacked (A, B) pairs into bank slot ``slot``."""
+        if self.cfg.is_latent:
+            self._refuse_for_latent_cache(self.config, self.mesh, lora=True)
         N = self.config.max_loras
         dt = self.cfg.jax_dtype
         if self.lora_bank is None:

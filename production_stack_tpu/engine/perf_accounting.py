@@ -64,13 +64,26 @@ def _stack_param_count(model_cfg) -> int:
     """Matrices of the decoder layers, Llama geometry, from config."""
     h = model_cfg.hidden_size
     inter = model_cfg.intermediate_size
-    qkv = (h * model_cfg.num_heads * model_cfg.head_dim
-           + 2 * h * model_cfg.num_kv_heads * model_cfg.head_dim
-           + model_cfg.num_heads * model_cfg.head_dim * h)
+    heads = model_cfg.num_heads
+    if getattr(model_cfg, "is_latent", False):
+        # latent attention: the two low-rank paths and the output projection
+        c, r = model_cfg.kv_lora_rank, model_cfg.q_lora_rank
+        qkv = (h * r + r * heads * model_cfg.head_dim
+               + h * model_cfg.latent_width
+               + c * heads * (model_cfg.qk_nope_head_dim
+                              + model_cfg.v_head_dim)
+               + heads * model_cfg.v_head_dim * h)
+    else:
+        qkv = (h * heads * model_cfg.head_dim
+               + 2 * h * model_cfg.num_kv_heads * model_cfg.head_dim
+               + heads * model_cfg.head_dim * h)
     # every expert: this is what is HELD; what a token multiplies with is
     # PerfAccountant.active_param_count
     mlp = 3 * h * inter * max(getattr(model_cfg, "num_experts", 0) or 1, 1)
-    return int(model_cfg.num_layers * (qkv + mlp))
+    dense = getattr(model_cfg, "dense_layers", 0)
+    return int((model_cfg.num_layers - dense) * (qkv + mlp)
+               + dense * (qkv + 3 * h * getattr(
+                   model_cfg, "dense_intermediate_size", 0)))
 
 
 def estimate_param_count(model_cfg) -> int:
@@ -205,8 +218,12 @@ class PerfAccountant:
         self.active_param_count += repeats
         self._loop_extra_bytes = (repeats * self.param_bytes
                                   / self.param_count)
-        self._attn_per_tok_ctx = (4 * self.cache_layers * cfg.num_heads
-                                  * cfg.head_dim)
+        # operations a (query token, context token) pair costs: QK and PV
+        # over head_dim a head, or, absorbed, over the latent row and the
+        # latent
+        self._attn_per_tok_ctx = 2 * self.cache_layers * cfg.num_heads * (
+            cfg.latent_width + cfg.kv_lora_rank if cfg.is_latent
+            else 2 * cfg.head_dim)
         self._kv_bytes_per_tok = cfg.kv_bytes_per_token
         # ICI cost model (docs/roofline.md "Multi-chip"), zero at tp=1:
         # each layer's two row-parallel matmuls (attention out-proj, MLP

@@ -1456,9 +1456,8 @@ class EngineServer:
                     or default_group(cfg.model.cache_layers)),
                 cfg.model.cache_layers,
             ))
-            shape = (cfg.model.cache_layers, len(blocks),
-                     cfg.cache.block_size, 2 * cfg.model.num_kv_heads,
-                     cfg.model.head_dim)
+            shape = cfg.model.kv_pool_shape(len(blocks),
+                                            cfg.cache.block_size)
             resp = web.StreamResponse(headers={
                 "Content-Type": "application/octet-stream",
                 "X-KV-Shape": ",".join(map(str, shape)),
@@ -1692,8 +1691,7 @@ class EngineServer:
         from production_stack_tpu.engine.kv_transfer import push_kv
 
         cfg = self.config
-        shape = (cfg.model.cache_layers, len(blocks), cfg.cache.block_size,
-                 2 * cfg.model.num_kv_heads, cfg.model.head_dim)
+        shape = cfg.model.kv_pool_shape(len(blocks), cfg.cache.block_size)
         dtype = str(cfg.model.dtype)
         meta = {"transfer_id": transfer_id,
                 "prompt_token_ids": [int(t) for t in prompt_ids],

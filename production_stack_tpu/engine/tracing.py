@@ -612,6 +612,55 @@ class RecurrentCounters:
                 "prefix_lookups_bypassed_total": lookups_bypassed}
 
 
+# -- latent attention ---------------------------------------------------------
+
+class LatentCounters:
+    """What the latent attention kernel (``ops/latent_paged_attention_
+    pallas.py``) was asked to score, always on: plain numbers the engine
+    thread adds up where it builds a dispatch, from the span offsets and
+    context lengths it already holds; no device result. Both the ragged
+    and the decode step programs call the one kernel once a cache layer,
+    so the numbers are by step ``kind`` and already times the layers.
+
+    A span of ``q`` tokens ending a context of ``ctx`` scores, causally,
+    ``q * (ctx - q) + q * (q + 1) / 2`` (query, context row) pairs: its
+    past whole, its own triangle."""
+
+    KINDS = ("ragged", "decode")
+
+    def __init__(self, cache_layers: int, kv_bytes_per_token: int):
+        self.cache_layers = cache_layers
+        self.kv_bytes_per_token = kv_bytes_per_token
+        self.query_tokens = dict.fromkeys(self.KINDS, 0)
+        self.scored_pairs = dict.fromkeys(self.KINDS, 0)
+        # context rows the spans reach (each once a span and layer): what
+        # a kernel has to read of the pool at least
+        self.context_rows = dict.fromkeys(self.KINDS, 0)
+
+    def record(self, kind: str, q_len, context_lens, iterations: int = 1
+               ) -> None:
+        """``q_len`` (slots,) tokens of each slot's span in one dispatch
+        of ``kind``, ``context_lens`` (slots,) their contexts, span
+        included; a decode dispatch's ``iterations`` fused steps each
+        lengthen every live context by one."""
+        q = np.asarray(q_len, np.int64)
+        ctx = np.where(q > 0, np.asarray(context_lens, np.int64), 0)
+        K, L = iterations, self.cache_layers
+        # iteration i finds every live context i rows longer: i rows and,
+        # for a one-token span, i pairs more a slot
+        longer = int((q > 0).sum()) * (K * (K - 1) // 2)
+        self.query_tokens[kind] += K * int(q.sum()) * L
+        self.scored_pairs[kind] += (K * int(
+            (q * (ctx - q) + q * (q + 1) // 2).sum()) + longer) * L
+        self.context_rows[kind] += (K * int(ctx.sum()) + longer) * L
+
+    def snapshot(self) -> dict:
+        return {"mla_query_tokens_total": dict(self.query_tokens),
+                "mla_scored_pairs_total": dict(self.scored_pairs),
+                "mla_context_rows_total": dict(self.context_rows),
+                "kv_bytes_per_token": self.kv_bytes_per_token}
+
+
 # -- MoE routing counters -----------------------------------------------------
 
 class MoeCounters:

@@ -327,13 +327,14 @@ def _check_ouro_names(cfg: ModelConfig, index: dict) -> None:
 
 
 def load_safetensors(cfg: ModelConfig, mesh: Mesh, rules: ShardingRules) -> dict:
-    if cfg.architecture == "solar_open2":
+    if cfg.architecture in ("solar_open2", "pangu_ultra_moe"):
         # the published tensor names (the KDA layers' conv, decay and gate
-        # tensors, the router's bias) are not known here and there is no
+        # tensors, the router's bias; the latent paths' projections and
+        # norms) are not known here and there is no
         # network to read them from: a guessed map would load silently
         # wrong or die mid-load, so a checkpoint is refused up front
         raise ValueError(
-            f"{cfg.name}: loading a solar_open2 checkpoint is not supported "
+            f"{cfg.name}: loading a {cfg.architecture} checkpoint is not supported "
             "(its tensor names are not mapped); serve it with random "
             "weights from a directory that holds config.json alone")
     from safetensors import safe_open

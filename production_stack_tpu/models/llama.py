@@ -56,9 +56,68 @@ RecurFn = Callable[..., Tuple[jnp.ndarray, Any]]
 # Parameter construction
 # ---------------------------------------------------------------------------
 
+def _latent_layer_specs(cfg: ModelConfig, sparse: bool) -> dict:
+    """One stack of latent-attention (MLA) layers: the mixer's two low-rank
+    paths with their norms, the four norms around the sublayers, and the
+    sparse block or a dense MLP."""
+    L = lax_names
+    layer = {
+        "attn_norm": (L.LAYERS, L.EMBED),
+        "wq_a": (L.LAYERS, L.EMBED, None),
+        "q_a_norm": (L.LAYERS, None),
+        # W_qb and W_kvb split by what each part feeds, so that no step
+        # slices a stacked matrix: the unrotated and the rotated columns of
+        # the heads' queries, plain matrices (rank, H * d); a head's key
+        # and value expansions of the latent, head-major (H, rank, d), as
+        # the matmuls batched over heads read them (laid out otherwise the
+        # TPU compiler copied ~100 MB of them a layer and step)
+        "wq_nope": (L.LAYERS, None, L.HEADS),
+        "wq_rope": (L.LAYERS, None, L.HEADS),
+        "wkv_a": (L.LAYERS, L.EMBED, None),
+        "kv_a_norm": (L.LAYERS, None),
+        "w_uk": (L.LAYERS, L.HEADS, None, L.HEAD_DIM),
+        "w_uv": (L.LAYERS, L.HEADS, None, L.HEAD_DIM),
+        "wo": (L.LAYERS, L.HEADS, L.HEAD_DIM, L.EMBED),
+        "post_attn_norm": (L.LAYERS, L.EMBED),
+        "mlp_norm": (L.LAYERS, L.EMBED),
+        "post_mlp_norm": (L.LAYERS, L.EMBED),
+    }
+    if not sparse:
+        layer.update({
+            "w_gate": (L.LAYERS, L.EMBED, L.MLP),
+            "w_up": (L.LAYERS, L.EMBED, L.MLP),
+            "w_down": (L.LAYERS, L.MLP, L.EMBED),
+        })
+        return layer
+    layer.update({
+        "router_bias": (L.LAYERS, L.EXPERTS),
+        "shared_gate": (L.LAYERS, L.EMBED, L.MLP),
+        "shared_up": (L.LAYERS, L.EMBED, L.MLP),
+        "shared_down": (L.LAYERS, L.MLP, L.EMBED),
+        "router": (L.LAYERS, L.EMBED, L.EXPERTS),
+        "w_gate": (L.LAYERS, L.EXPERTS, L.EMBED, L.MLP),
+        "w_up": (L.LAYERS, L.EXPERTS, L.EMBED, L.MLP),
+        "w_down": (L.LAYERS, L.EXPERTS, L.MLP, L.EMBED),
+    })
+    return layer
+
+
 def param_specs(cfg: ModelConfig) -> dict:
     """Pytree of logical-axes tuples mirroring the param pytree."""
     L = lax_names
+    if cfg.is_latent:
+        # the leading dense layers are a stack of their own, "dense",
+        # before the expert layers' "layers"
+        specs = {
+            "embed": (L.VOCAB, L.EMBED),
+            "layers": _latent_layer_specs(cfg, sparse=True),
+            "final_norm": (L.EMBED,),
+        }
+        if cfg.dense_layers:
+            specs["dense"] = _latent_layer_specs(cfg, sparse=False)
+        if not cfg.tie_word_embeddings:
+            specs["lm_head"] = (L.EMBED, L.VOCAB)
+        return specs
     layer = {
         "attn_norm": (L.LAYERS, L.EMBED),
         "wq": (L.LAYERS, L.EMBED, L.HEADS, L.HEAD_DIM),
@@ -178,9 +237,101 @@ def param_specs(cfg: ModelConfig) -> dict:
 LOOPED_POST_NORM_GAIN = 0.05
 
 
+# Random stand-in weights of a latent-attention stack with norms after
+# each sublayer: the gain of those norms, and an embedding of unit RMS. At
+# the shared stack's values (gain 1, embedding rows of RMS hidden^-1/2)
+# the first sublayer's output buries the embedding and every later one is
+# as large as the stream it joins, as HYBRID_INIT found of a stack without
+# such norms: the served bf16 path then read 0.21-0.46 / 0.012-0.017
+# against the float32 reference (CPU, width 512; limits 0.15 / 0.03). A
+# post norm fixes its sublayer's size at the gain whatever the matrices
+# are, so the gain is what HYBRID_INIT's 1 / sqrt(2 x layers) on the
+# writing matrices is there. It is set by the sparse block: a routed
+# expert weighs routed_scaling / top_k = 0.31 of the shared one here
+# (Solar-Open2: 0.125), so where bf16 rounding picks the other of two
+# near-tied experts the block's output turns by a third, and the norm
+# behind it hands the stream that third at the gain's size whatever the
+# experts' matrices are. With the 2 x layers sublayers together moving
+# the stream by half its size (gain 0.158 at five layers) one position of
+# a probe read up to 0.142 on the chip (six seeds 0.063-0.142, mean
+# 0.0062-0.0076); by a quarter of its size it reads half that (PERF.md
+# section 6, PR 43).
+
+
+def _latent_post_norm_gain(cfg: ModelConfig) -> float:
+    return 0.25 * (2 * cfg.num_layers) ** -0.5
+
+
+def _init_latent(cfg: ModelConfig, key: jax.Array) -> dict:
+    E, H, V = cfg.hidden_size, cfg.num_heads, cfg.vocab_size
+    Rq, C = cfg.q_lora_rank, cfg.kv_lora_rank
+    nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+    dt = cfg.jax_dtype
+    gain = _latent_post_norm_gain(cfg)
+
+    def normal(k, shape, fan_in):
+        return (jax.random.normal(k, shape, jnp.float32)
+                * (fan_in ** -0.5)).astype(dt)
+
+    def stack(k, n, sparse):
+        ks = jax.random.split(k, 16)
+        layers = {
+            "attn_norm": jnp.ones((n, E), dt),
+            "wq_a": normal(ks[0], (n, E, Rq), E),
+            "q_a_norm": jnp.ones((n, Rq), dt),
+            "wq_nope": normal(ks[1], (n, Rq, H * nope), Rq),
+            "wq_rope": normal(ks[2], (n, Rq, H * rope), Rq),
+            "wkv_a": normal(ks[3], (n, E, C + rope), E),
+            "kv_a_norm": jnp.ones((n, C), dt),
+            "w_uk": normal(ks[4], (n, H, C, nope), C),
+            "w_uv": normal(ks[5], (n, H, C, vd), C),
+            "wo": normal(ks[6], (n, H, vd, E), H * vd),
+            "post_attn_norm": jnp.full((n, E), gain, dt),
+            "mlp_norm": jnp.ones((n, E), dt),
+            "post_mlp_norm": jnp.full((n, E), gain, dt),
+        }
+        if not sparse:
+            Fd = cfg.dense_intermediate_size
+            layers.update({
+                "w_gate": normal(ks[7], (n, E, Fd), E),
+                "w_up": normal(ks[8], (n, E, Fd), E),
+                "w_down": normal(ks[9], (n, Fd, E), Fd),
+            })
+            return layers
+        F, Fs = cfg.intermediate_size, cfg.shared_expert_size
+        X, Xh = cfg.num_experts, cfg.num_held_experts
+        layers.update({
+            # no selection bias is published for this family: zeros
+            "router_bias": jnp.zeros((n, X), jnp.float32),
+            "shared_gate": normal(ks[10], (n, E, Fs), E),
+            "shared_up": normal(ks[11], (n, E, Fs), E),
+            "shared_down": normal(ks[12], (n, Fs, E), Fs),
+            "router": normal(ks[13], (n, E, X), E),
+            "w_gate": normal(ks[7], (n, Xh, E, F), E),
+            "w_up": normal(ks[8], (n, Xh, E, F), E),
+            "w_down": normal(ks[9], (n, Xh, F, E), F),
+        })
+        return layers
+
+    keys = jax.random.split(key, 4)
+    params = {
+        "embed": normal(keys[0], (V, E), 1),
+        "layers": stack(keys[1], cfg.num_expert_layers, True),
+        "final_norm": jnp.ones((E,), dt),
+    }
+    if cfg.dense_layers:
+        params["dense"] = stack(keys[2], cfg.dense_layers, False)
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = normal(keys[3], (E, V), E)
+    return params
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     """Random-init parameters (tests / synthetic benchmarks; real weights come
     from safetensors via engine/weights.py)."""
+    if cfg.is_latent:
+        return _init_latent(cfg, key)
     E, H, KH, D, F, LN, V = (
         cfg.hidden_size,
         cfg.num_heads,
@@ -604,10 +755,17 @@ def forward_hidden(
             out += (hists,)
         return out
 
-    def layer_fn(carry, scanned, first_cache_layer=None):
+    def layer_fn(carry, scanned, first_cache_layer=None, sparse=cfg.is_moe):
         h, layer_idx, caches = carry
         lp, lb = scanned  # layer params, per-layer lora bank (or None)
         normed = pre_norm(h, lp["attn_norm"])
+        cache_layer = (layer_idx if first_cache_layer is None
+                       else first_cache_layer + layer_idx)
+        if cfg.is_latent:
+            with jax.named_scope("mla"):
+                o, caches = _mla_mixer(cfg, lp, normed, positions, attend,
+                                       caches, cache_layer)
+            return mlp_half(h, o, lp, lb, layer_idx, caches, sparse)
         q = quant_einsum("...te,ehd->...thd", normed, lp["wq"])
         k = quant_einsum("...te,ehd->...thd", normed, lp["wk"])
         v = quant_einsum("...te,ehd->...thd", normed, lp["wv"])
@@ -637,21 +795,23 @@ def forward_hidden(
             )
         q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
-        attn, caches = attend(
-            q, k, v, caches,
-            layer_idx if first_cache_layer is None
-            else first_cache_layer + layer_idx)
+        attn, caches = attend(q, k, v, caches, cache_layer)
         o = quant_einsum("...thd,hde->...te", attn, lp["wo"])
         if lb is not None and "wo" in lb:
             flat = attn.reshape(*attn.shape[:-2], -1)  # (..., T, H*D)
             o = o + _lora_delta(flat, onehot, *lb["wo"])
+        return mlp_half(h, o, lp, lb, layer_idx, caches, sparse)
+
+    def mlp_half(h, o, lp, lb, layer_idx, caches, sparse):
+        """A layer from its mixer's output on: the stream takes it, then
+        the MLP or the sparse block."""
         if cfg.post_norms:
             o = rms_norm(o, lp["post_attn_norm"], cfg.rms_norm_eps,
                          cfg.norm_offset)
         h = h + o
         normed2 = pre_norm(h, lp["mlp_norm"])
         hist = None
-        if cfg.is_moe:  # LoRA on MoE experts: not supported yet
+        if sparse:  # LoRA on MoE experts: not supported yet
             with jax.named_scope("moe"):
                 mlp_out, hist = _sparse_block(cfg, lp, experts, layer_idx,
                                               normed2, live)
@@ -664,10 +824,19 @@ def forward_hidden(
         return (h, layer_idx + 1, caches), hist
 
     bank = None if lora is None else lora["bank"]
+    first = None
+    if cfg.dense_layers:
+        # the leading dense layers, cache layers 0.. in order, before the
+        # scan over the (like) expert layers; they route nothing and have
+        # no histogram row
+        (x, _, kv_caches), _ = lax.scan(
+            functools.partial(layer_fn, sparse=False),
+            (x, jnp.int32(0), kv_caches), (params["dense"], None))
+        first = cfg.dense_layers
     if cfg.loop_passes == 1:
         (x, _, new_caches), hists = lax.scan(
-            layer_fn, (x, jnp.int32(0), kv_caches), (layers, bank)
-        )
+            functools.partial(layer_fn, first_cache_layer=first),
+            (x, jnp.int32(0), kv_caches), (layers, bank))
         passes = jnp.int32(1)
     else:
         def pass_fn(carry, u):
@@ -696,6 +865,60 @@ def forward_hidden(
     if loop_count:
         out += (passes,)
     return out
+
+
+def _mla_mixer(cfg: ModelConfig, lp: dict, x: jnp.ndarray, positions,
+               attend: AttendFn, caches, cache_layer
+               ) -> Tuple[jnp.ndarray, Any]:
+    """Latent attention (MLA) in its absorbed form, the one form for
+    prefill chunks, decode rows and dense forwards alike. As published: a
+    query passes a low-rank path with a norm and splits a head into an
+    unrotated and a rotated part; a token's keys and values pass another,
+    ``c`` (normed) and one rotated key ``r`` all heads share; head i's key
+    is ``[W_UK_i c; r]``, its value ``W_UV_i c``, the score scale
+    ``head_dim ** -0.5``. Absorbed: the cache row is ``[c; r]``, head i's
+    query ``[W_UK_i^T q_nope_i; q_rope_i]`` scores the row itself, the
+    weighted rows' first ``kv_lora_rank`` values go through ``W_UV_i``.
+    The same numbers up to rounding (tests/test_pangu_ultra_moe.py).
+
+    ``attend`` takes the absorbed queries (..., T, H, latent_lanes), the
+    rows as the one key head (..., T, 1, latent_lanes), both padded with
+    zeros to the pool's whole lane tiles, and the rows' latent part as
+    its value, and returns (..., T, H, kv_lora_rank). Attention
+    implementations scale by their query's width ** -0.5, so the score
+    scale is folded into the query, accumulated in float32, as
+    ``query_scale`` is."""
+    f32, C, H = jnp.float32, cfg.kv_lora_rank, cfg.num_heads
+    eps, theta = cfg.rms_norm_eps, cfg.rope_theta
+    c_q = rms_norm(quant_einsum("...te,er->...tr", x, lp["wq_a"]),
+                   lp["q_a_norm"], eps)
+
+    def heads(y):  # (..., T, H * d) -> (..., T, H, d)
+        return y.reshape(*y.shape[:-1], H, -1)
+
+    q_nope = heads(quant_einsum("...tr,rf->...tf", c_q, lp["wq_nope"]))
+    q_rope = apply_rope(
+        heads(quant_einsum("...tr,rf->...tf", c_q, lp["wq_rope"])),
+        positions, theta)
+    kv = quant_einsum("...te,ec->...tc", x, lp["wkv_a"])
+    c = rms_norm(kv[..., :C], lp["kv_a_norm"], eps)
+    r = apply_rope(kv[..., None, C:], positions, theta)  # one head
+    lanes = cfg.latent_lanes
+
+    def padded(*parts):
+        width = sum(p.shape[-1] for p in parts)
+        return jnp.concatenate(
+            [*parts, jnp.zeros((*parts[0].shape[:-1], lanes - width),
+                               parts[0].dtype)], axis=-1)
+
+    row = padded(c[..., None, :], r)
+    q_lat = jnp.einsum("...thd,hcd->...thc", q_nope, lp["w_uk"],
+                       preferred_element_type=f32)
+    fold = cfg.head_dim ** -0.5 * lanes ** 0.5
+    q = (padded(q_lat, q_rope.astype(f32)) * fold).astype(x.dtype)
+    o_lat, caches = attend(q, row, row[..., :C], caches, cache_layer)
+    o = jnp.einsum("...thc,hcd->...thd", o_lat, lp["w_uv"])
+    return quant_einsum("...thd,hde->...te", o, lp["wo"]), caches
 
 
 def _sparse_block(cfg: ModelConfig, lp: dict, experts: dict, layer_idx,

@@ -35,6 +35,13 @@ _REGISTRY: dict[str, ModuleType] = {
     # sparse block with sigmoid routing, a shared expert and, where the
     # engine holds a share of the experts, only the pairs that fall on it
     "solar_open2": llama,
+    # openPangu-Ultra-MoE: the shared file's latent-attention (MLA) mixer
+    # over a one-row-a-token cache (cfg.kv_lora_rank > 0), leading dense
+    # layers before the scanned expert layers, Ouro's norms after each
+    # sublayer and Solar-Open2's sparse block. Served on one chip at the
+    # published widths: chipbench cell
+    # openpangu-ultra-moe-718b-ep16-l5.long-prompt (PERF.md, PR 43)
+    "pangu_ultra_moe": llama,
     # encoder-decoder audio transcription: exposes its own forward
     # surface (encode/cross_kv/decode_tokens) instead of the decoder-only
     # protocol; shares param_specs/init_params so weights.py works

@@ -29,6 +29,8 @@ def dense_causal_attention(
     """Causal multi-head attention with grouped KV (GQA).
 
     q: (B, S, H, D); k, v: (B, S, KH, D) with H = KH * G. Returns (B, S, H, D).
+    A value may be narrower than its key, (B, S, KH, Dv) (latent attention:
+    the rows' latent part); the result is then (B, S, H, Dv).
     ``soft_cap`` > 0 applies Gemma-2-style score capping cap*tanh(s/cap)
     before masking.
     """
@@ -48,7 +50,7 @@ def dense_causal_attention(
     probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
     probs = probs / probs.sum(axis=-1, keepdims=True)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v.astype(jnp.float32))
-    return out.reshape(B, S, H, D).astype(q.dtype)
+    return out.reshape(B, S, H, v.shape[-1]).astype(q.dtype)
 
 
 def segment_causal_attention(

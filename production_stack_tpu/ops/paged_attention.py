@@ -166,3 +166,61 @@ def ragged_paged_attention(
     probs = probs / jnp.maximum(denom, 1e-30)
     out = jnp.einsum("tkgc,tckd->tkgd", probs, v.astype(jnp.float32))
     return out.reshape(T, H, D).astype(q.dtype)
+
+
+# -- latent attention (MLA), absorbed ----------------------------------------
+# The pool holds one row a token, (L, N, bs, lanes): the latent c (its first
+# ``value_dim`` lanes), then the rotated key every head shares, then zeros
+# up to whole 128-lane tiles. A query head scores a row over all its lanes
+# and takes the row's first ``value_dim`` lanes as the value: KH = 1, keys
+# and values the same bytes.
+
+def write_latent(
+    cache: jnp.ndarray,  # (L, N, bs, lanes)
+    layer_idx: jnp.ndarray,
+    rows: jnp.ndarray,  # (T, lanes)
+    slot_mapping: jnp.ndarray,  # (T,) flat slots, -1 = dropped padding
+) -> jnp.ndarray:
+    """Scatter T tokens' latent rows into layer ``layer_idx`` with ONE
+    scatter, in place through a donated scan carry (the TPU compiler keeps
+    the pool where it lies when ``lanes`` is whole tiles)."""
+    L, n, bs, lanes = cache.shape
+    slots = jnp.where(slot_mapping < 0, n * bs, slot_mapping)
+    flat = cache.reshape(L, n * bs, lanes)
+    flat = flat.at[layer_idx, slots].set(rows.astype(cache.dtype),
+                                         mode="drop", unique_indices=True)
+    return flat.reshape(L, n, bs, lanes)
+
+
+def latent_ragged_paged_attention(
+    q: jnp.ndarray,  # (T, H, lanes) packed stream, absorbed queries
+    rows_layer: jnp.ndarray,  # (N, bs, lanes) — one layer of the pool
+    block_tables: jnp.ndarray,  # (S, M)
+    context_lens: jnp.ndarray,  # (S,)
+    seq_ids: jnp.ndarray,  # (T,) owning slot per token
+    q_positions: jnp.ndarray,  # (T,) absolute position, -1 = pad
+    value_dim: int,
+) -> jnp.ndarray:
+    """XLA form of ops/latent_paged_attention_pallas.py (the CPU path and
+    the parity oracle): every token of the packed stream against its
+    slot's paged latent rows, causally, scores scaled by lanes ** -0.5 as
+    the kernel's (the mixer folds the model's own scale into q). Returns
+    (T, H, value_dim)."""
+    T, H, lanes = q.shape
+    n, block_size, _ = rows_layer.shape
+    M = block_tables.shape[1]
+    scale = lanes ** -0.5
+
+    sid = jnp.clip(seq_ids, 0, block_tables.shape[0] - 1)
+    ctx = rows_layer[block_tables[sid]].reshape(
+        T, M * block_size, lanes).astype(jnp.float32)
+    kv_pos = jnp.arange(M * block_size, dtype=jnp.int32)[None, :]
+    mask = ((kv_pos < context_lens[sid][:, None])
+            & (kv_pos <= q_positions[:, None])
+            & (q_positions >= 0)[:, None])  # (T, Tc)
+    scores = jnp.einsum("thw,tcw->thc", q.astype(jnp.float32), ctx) * scale
+    scores = jnp.where(mask[:, None], scores, NEG_INF)
+    probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
+    probs = probs / jnp.maximum(probs.sum(axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("thc,tcv->thv", probs, ctx[..., :value_dim])
+    return out.astype(q.dtype)

@@ -1,4 +1,4 @@
-"""The four Pallas kernels keep their names through the TPU compiler.
+"""The Pallas kernels keep their names through the TPU compiler.
 
 A profiler trace names a device operation by its HLO text, and a Pallas
 kernel appears there as ``%<name>.N = ... custom-call(...)``: the
@@ -295,3 +295,71 @@ def test_ragged_kernel_compiles_at_the_cells_geometries(one_chip, kh, g):
     size = re.search(r"Scoped allocation with size ([\d.]+)M",
                      str(refusal.value))
     assert size and float(size.group(1)) <= ask
+
+
+# -- openPangu-Ultra-MoE's share on one chip (chipbench
+# openpangu-ultra-moe-718b-ep16-l5): the latent attention kernel at 128
+# heads over a 640-lane row, a 2048-token stream and a decode step's 64
+# one-token spans, under the name chipbench/layer_metrics/mla_attn_*.json
+# match on; the row write that keeps the donated pool where it lies ------
+
+LATENT_POOL = ((5, 4096, BS, 640), jnp.bfloat16)
+LATENT_VMEM_MIB = 18.98
+
+
+def _latent():
+    from production_stack_tpu.ops import latent_paged_attention_pallas as k
+
+    return k
+
+
+@pytest.mark.parametrize("tokens", [2048, 64])
+def test_latent_kernel_is_a_named_custom_call_at_the_cells_shapes(
+        one_chip, tokens):
+    k = _latent()
+    text = _compiled_text(
+        lambda q, c, bt, cu, cl: k.latent_paged_attention_pallas(
+            q, c, bt, cu, cl, layer_idx=1, value_dim=512),
+        one_chip, ((tokens, 128, 640), jnp.bfloat16), LATENT_POOL,
+        ((64, 576), I32), ((65,), I32), ((64,), I32))
+    assert re.search(r"^\s*(?:ROOT )?%latent_paged_attention[.\d]* = "
+                     r".*? custom-call\(", text, flags=re.M)
+
+
+def test_latent_kernel_asks_for_its_own_scoped_vmem(one_chip, monkeypatch):
+    """No libtpu flag in the configuration's manifest: the call carries
+    its limit. What it asks: the compiler names the size where the limit
+    is short."""
+    k = _latent()
+    assert k.VMEM_LIMIT_BYTES >= LATENT_VMEM_MIB * 2 ** 20
+    monkeypatch.setattr(k, "VMEM_LIMIT_BYTES",
+                        int((LATENT_VMEM_MIB - 0.5) * 2 ** 20))
+    with pytest.raises(Exception, match="Scoped allocation") as refusal:
+        _compiled_text(
+            lambda q, c, bt, cu, cl: k.latent_paged_attention_pallas(
+                q, c, bt, cu, cl, layer_idx=1, value_dim=512),
+            one_chip, ((2048, 128, 640), jnp.bfloat16), LATENT_POOL,
+            ((64, 576), I32), ((65,), I32), ((64,), I32))
+    size = re.search(r"Scoped allocation with size ([\d.]+)M",
+                     str(refusal.value))
+    assert size and float(size.group(1)) <= LATENT_VMEM_MIB
+
+
+@pytest.mark.parametrize("lanes,in_place", [(640, True), (576, False)])
+def test_latent_row_write_keeps_the_pool_in_place_at_whole_lane_tiles(
+        one_chip, lanes, in_place):
+    """The rows go in by XLA's scatter. At 640 lanes (whole tiles) the
+    donated pool is updated where it lies; at the row's own 576 the
+    compiler copies the whole pool into a 640-lane layout first: why
+    ``ModelConfig.latent_lanes`` pads."""
+    from production_stack_tpu.ops.paged_attention import write_latent
+
+    pool = (5, 4096, BS, lanes)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        (pool, jnp.bfloat16), ((2048, lanes), jnp.bfloat16), ((2048,), I32))]
+    compiled = jax.jit(
+        lambda c, rows, sm: write_latent(c, 1, rows, sm),
+        donate_argnums=0).lower(*args).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    nbytes = 2 * 5 * 4096 * BS * lanes
+    assert (temp < nbytes // 100) if in_place else (temp > nbytes)
