@@ -371,6 +371,13 @@ class EngineStatsCollector:
                 s["kda_chunk_spans_total"],
             )
             yield counter(
+                "vllm:kda_chunk_block_rows",
+                "Rows of the blocks the span scan ran for those spans: a "
+                "span of n rows takes ceil(n / C) blocks of C rows "
+                "(ops/kda_pallas.py CHUNK), the last one padded",
+                s["kda_chunk_block_rows_total"],
+            )
+            yield counter(
                 "vllm:recurrent_state_resets",
                 "Sequences started from a zero recurrent state (new, or "
                 "recomputed after preemption)",

@@ -853,11 +853,15 @@ class ModelRunner:
                             kda_pallas.kda_decode_step))
         tail = jax.lax.dynamic_index_in_dim(caches["conv"], k_idx, 0, False)
         x, tail = conv(jnp.squeeze(qkv, axis), conv_w, tail, *step_inputs)
-        prep = kda.prepare(*kda.split_heads(x, g.shape[-2]),
-                           jnp.squeeze(g, axis), jnp.squeeze(beta, axis),
-                           neg_eigval)
+        g = jnp.squeeze(g, axis)
+        a, *prep = kda.prepare(*kda.split_heads(x, g.shape[-2]), g,
+                               jnp.squeeze(beta, axis), neg_eigval)
+        if ragged and self.use_pallas:
+            # the span kernel sums the log-decay itself (a ratio of two
+            # products of ``a`` would overflow): ops/kda_pallas.py
+            a = g.astype(jnp.float32)
         o, state = (pallas if self.use_pallas else xla)(
-            caches["state"], k_idx, *prep, *step_inputs)
+            caches["state"], k_idx, a, *prep, *step_inputs)
         conv = jax.lax.dynamic_update_index_in_dim(
             caches["conv"], tail, k_idx, 0)
         return (jnp.expand_dims(o, axis),

@@ -26,6 +26,8 @@ from typing import Optional
 import numpy as np
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
+from production_stack_tpu.ops.kda_pallas import CHUNK
+
 logger = logging.getLogger("engine.tracing")
 
 _tracer = None
@@ -580,13 +582,16 @@ class RecurrentCounters:
     stored state, run through the decode step, once a KDA layer a
     dispatch; every other span (a prompt's chunk, a first token) is what
     the span scan carries, and ``chunk_tokens`` / ``chunk_spans`` count
-    those alone."""
+    those alone. The scan takes a span a block of ``kda_pallas.CHUNK``
+    rows at a time, the last one masked: ``chunk_block_rows`` counts the
+    rows of the blocks it runs, padding included."""
 
     def __init__(self, kda_layers: int, state_bytes: int):
         self.kda_layers, self.state_bytes = kda_layers, state_bytes
         self.decode_calls = 0   # decode dispatches x iterations x layers
         self.chunk_tokens = 0   # rows the span scan carried, a layer
         self.chunk_spans = 0    # spans it carried (state loaded, stored)
+        self.chunk_block_rows = 0  # rows of the blocks it ran, a layer
         self.state_resets = 0   # sequences started from a zero state
 
     def record_decode(self, iterations: int) -> None:
@@ -598,6 +603,7 @@ class RecurrentCounters:
         step takes."""
         scanned = np.where(decode_rows, 0, q_len)
         self.chunk_tokens += int(scanned.sum())
+        self.chunk_block_rows += int((-(-scanned // CHUNK) * CHUNK).sum())
         self.chunk_spans += int((scanned > 0).sum())
         self.state_resets += resets
 
@@ -607,6 +613,7 @@ class RecurrentCounters:
         return {"kda_decode_calls_total": self.decode_calls,
                 "kda_chunk_tokens_total": self.chunk_tokens,
                 "kda_chunk_spans_total": self.chunk_spans,
+                "kda_chunk_block_rows_total": self.chunk_block_rows,
                 "recurrent_state_resets_total": self.state_resets,
                 "recurrent_state_bytes": self.state_bytes,
                 "prefix_lookups_bypassed_total": lookups_bypassed}

@@ -20,6 +20,24 @@ float32, (..., H, D): ``a`` = alpha, ``kb`` = beta k, ``k``, ``q`` (L2
 normalised, q scaled by d_k^-1/2), ``vb`` = beta v; with them
 
     S' = Diag(a) S;  w = vb - S'^T kb;  S_t = S' + k w^T;  o = S_t^T q
+
+The same recurrence over a block of ``C`` rows at once (the chunked form
+``kda_chunk_scan`` runs; Kimi Linear's KDA, flash-linear-attention's
+``chunk_kda``): with ``G_t = g_1 + ... + g_t`` the running sum of the
+log-decay ``g = log a`` inside the block, per key channel, and the rows
+of the block stacked into ``Kb, K, Q, Vb`` (C, d),
+
+    A[t, i] = sum_c kb_t[c] k_i[c] exp(G_t[c] - G_i[c])    (i < t)
+    B[t, i] = sum_c  q_t[c] k_i[c] exp(G_t[c] - G_i[c])    (i <= t)
+    W = (I + A)^-1 (Vb - (exp(G) * Kb) S_0)
+    O = (exp(G) * Q) S_0 + B W
+    S_C = Diag(exp(G_C)) S_0 + (exp(G_C - G) * K)^T W
+
+row ``t`` of ``W`` being the ``w`` of step ``t``. A decay is never
+divided by: ``exp(-G_i)`` leaves float32 after a few strongly decayed
+rows (``g`` is unbounded below), so every ratio is formed as
+``exp(G_t - G_i)`` with ``t >= i``, which is at most 1, from sums of ``g``
+itself (``log a`` of an ``a`` that underflowed would be ``-inf``).
 """
 
 from __future__ import annotations

@@ -192,22 +192,29 @@ def _kda_cases():
     )
 
     rows = [((64, 64, D), jnp.float32)] * 5
-    stream = [((2048, 64, D), jnp.float32)] * 5
+
+    def scan(width):  # the ragged program's stream widths (PR 42)
+        return (
+            lambda st, g, kb, k, q, vb, cu, ctx: kda_chunk_scan(
+                st, 3, g, kb, k, q, vb, cu, ctx),
+            (KDA_STATE, *[((width, 64, D), jnp.float32)] * 5,
+             ((65,), I32), ((64,), I32)))
+
     return {
         "kda_decode_step": (
             lambda st, a, kb, k, q, vb, act: kda_decode_step(
                 st, 3, a, kb, k, q, vb, act),
             (KDA_STATE, *rows, ((64,), jnp.bool_))),
-        "kda_chunk_scan": (
-            lambda st, a, kb, k, q, vb, cu, ctx: kda_chunk_scan(
-                st, 3, a, kb, k, q, vb, cu, ctx),
-            (KDA_STATE, *stream, ((65,), I32), ((64,), I32))),
+        "kda_chunk_scan": scan(2048),
+        "kda_chunk_scan@512": scan(512),
     }
 
 
-@pytest.mark.parametrize("name", ["kda_chunk_scan", "kda_decode_step"])
-def test_kda_kernel_is_a_named_custom_call_at_the_cells_shapes(one_chip, name):
-    fn, shapes = _kda_cases()[name]
+@pytest.mark.parametrize(
+    "case", ["kda_chunk_scan", "kda_chunk_scan@512", "kda_decode_step"])
+def test_kda_kernel_is_a_named_custom_call_at_the_cells_shapes(one_chip, case):
+    fn, shapes = _kda_cases()[case]
+    name = case.partition("@")[0]
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
     compiled = jax.jit(fn, donate_argnums=0).lower(*args).compile()

@@ -288,13 +288,21 @@ def test_preemption_then_recompute_equals_an_undisturbed_run(served):
 
 # -- ops/kda.py: the stream's forms against token by token ---------------------
 
-def _stream(seed=0, T=24, H=4, D=32, S=5, Lk=2):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+def _stream(seed=0, T=24, H=4, D=32, S=5, Lk=2, strong_decay=False,
+            neg_eigval=True):
+    """(a, kb, k, q, vb), the stored states, and the log-decay g that the
+    span kernel takes in a's place. ``strong_decay``: a quarter of the
+    channels lose up to e^-30 a row, so that a block's running sum of g
+    leaves the range float32's exp can invert."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
     q, k, v = (jax.random.normal(ks[i], (T, H, D)) for i in range(3))
     g = -jnp.exp(jax.random.normal(ks[3], (T, H, D)) - 3)
+    if strong_decay:
+        g = jnp.where(jax.random.uniform(ks[6], (1, H, D)) < 0.25,
+                      -30 * jax.random.uniform(ks[7], (T, H, D)), g)
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (T, H)))
     state = jax.random.normal(ks[5], (Lk, S, H, D, D)) * 0.1
-    return kda.prepare(q, k, v, g, beta, True), state
+    return kda.prepare(q, k, v, g, beta, neg_eigval), state, g
 
 
 # spans (start, end) in slot order and each slot's context after its span:
@@ -323,7 +331,7 @@ def test_the_stream_forms_equal_token_by_token(form):
     state, a decode row, one slot with no rows, rows of padding behind the
     last span; the span kernel alone, and as a ragged step calls it: the
     decode row through the decode kernel."""
-    prep, state = _stream()
+    prep, state, g = _stream()
     cu = jnp.asarray([0] + [e for _, e in SPANS], jnp.int32)
     ctx = jnp.asarray(CONTEXT, jnp.int32)
     if form == "xla":
@@ -331,7 +339,7 @@ def test_the_stream_forms_equal_token_by_token(form):
     else:
         fn = (kda_pallas.kda_chunk_scan if form == "pallas"
               else kda_pallas.kda_ragged)
-        o, new = fn(state, 1, *prep, cu, ctx, interpret=True)
+        o, new = fn(state, 1, g, *prep[1:], cu, ctx, interpret=True)
     outs, states = _token_by_token(prep, state, 1)
     for s, (a0, a1) in enumerate(SPANS):
         if a1 > a0:
@@ -342,9 +350,58 @@ def test_the_stream_forms_equal_token_by_token(form):
     np.testing.assert_array_equal(o[21:], 0)
 
 
+_C = kda_pallas.CHUNK
+# the spans' lengths in slot order (they lie one behind the other in a
+# stream of 4 blocks' rows), each slot's context after its span (a longer
+# one continues the stored state), and what else the case changes
+BLOCKED = {
+    "a_span_of_one_block": ([_C], [_C], {}),
+    "one_row_over_a_block": ([_C + 1], [_C + 1], {}),
+    "one_row_under_three_blocks": ([3 * _C - 1], [3 * _C - 1], {}),
+    "from_mid_block_continuing_a_state":
+        ([_C // 2 + 3, 2 * _C - 20], [_C // 2 + 3, 5 * _C], {}),
+    # a span's last block reaches over the next span's rows
+    "two_partial_last_blocks_side_by_side":
+        ([_C + 7, _C + 18, 9], [_C + 7, 9 * _C, 9], {}),
+    # the last block's window would pass the stream's end
+    "a_span_up_to_the_last_row":
+        ([7, 0, 4 * _C - 7], [40, 0, 5 * _C], {}),
+    "beta_up_to_two":
+        ([2 * _C + 5, _C - 5], [2 * _C + 5, 7 * _C], {"neg_eigval": True}),
+    "strong_decay":
+        ([2 * _C + 5, 2 * _C - 6], [2 * _C + 5, 7 * _C],
+         {"strong_decay": True}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BLOCKED))
+def test_the_span_kernel_takes_a_span_a_block_at_a_time(case):
+    """The chunked form at the served head size and the shipped block,
+    against the row-by-row recurrence: whole and partial blocks, windows
+    that start mid-block, reach over the next span or would pass the
+    stream's end, and a running sum of g past what exp can invert. This,
+    not the benchmark's probe, holds the kernel's precision (float32
+    state, products at HIGHEST): PERF.md section 7."""
+    lens, context, kwargs = BLOCKED[case]
+    T, S = 4 * _C, len(lens) + 1  # the last slot has no span
+    prep, state, g = _stream(seed=len(case), T=T, H=1, D=128, S=S,
+                             **{"neg_eigval": False, **kwargs})
+    cu = jnp.cumsum(jnp.asarray([0] + lens + [0], jnp.int32))
+    ctx = jnp.asarray(context + [0], jnp.int32)
+    want_o, want_s = kda.recurrence_ragged(state, 1, *prep, cu, ctx)
+    o, new = kda_pallas.kda_chunk_scan(state, 1, g, *prep[1:], cu, ctx,
+                                       interpret=True)
+    assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(new).all())
+    np.testing.assert_allclose(o, want_o, rtol=1e-4, atol=2e-6)
+    np.testing.assert_allclose(new, want_s, rtol=1e-4, atol=2e-6)
+    np.testing.assert_array_equal(o[sum(lens):], 0)
+    np.testing.assert_array_equal(new[0], state[0])  # the other layer
+    np.testing.assert_array_equal(new[1, -1], state[1, -1])  # no span
+
+
 @pytest.mark.parametrize("form", ["xla", "pallas"])
 def test_the_decode_step_moves_live_slots_only(form):
-    prep, state = _stream(seed=1)
+    prep, state, _ = _stream(seed=1)
     rows = tuple(x[:5] for x in prep)
     active = jnp.asarray([True, False, True, True, False])
     step = (kda.recurrence_decode if form == "xla" else
@@ -585,7 +642,7 @@ def test_prefix_lookups_are_bypassed_for_recurrent_state_only(recurrent):
 
 
 COUNTERS = ("kda_decode_calls", "kda_chunk_tokens", "kda_chunk_spans",
-            "recurrent_state_resets", "prefix_lookups_bypassed",
+            "kda_chunk_block_rows", "recurrent_state_resets", "prefix_lookups_bypassed",
             "moe_held_pairs", "moe_routed_tokens",
             "moe_decode_experts_touched", "moe_decode_layer_steps")
 
@@ -600,6 +657,12 @@ def test_the_counters_are_exported_and_add_up(served):
     assert s["kda_chunk_tokens_total"] == s["prompt_tokens_total"]
     assert s["kda_chunk_tokens_total"] < s["ragged_live_tokens_total"]
     assert s["kda_chunk_spans_total"] > s["recurrent_state_resets_total"]
+    # whole blocks of the span kernel's: at least one a span, and none
+    # more than the spans' rows need
+    blocks, rem = divmod(s["kda_chunk_block_rows_total"], kda_pallas.CHUNK)
+    assert rem == 0 and s["kda_chunk_spans_total"] <= blocks
+    assert blocks < (s["kda_chunk_tokens_total"] / kda_pallas.CHUNK
+                     + s["kda_chunk_spans_total"])
     assert s["recurrent_state_resets_total"] >= 3
     assert s["prefix_lookups_bypassed_total"] >= 3
     assert s["gpu_prefix_cache_hits_total"] == 0
@@ -627,6 +690,51 @@ def test_the_span_scans_counters_leave_out_the_decode_rows():
     s = c.snapshot(0)
     assert (s["kda_chunk_tokens_total"], s["kda_chunk_spans_total"],
             s["recurrent_state_resets_total"]) == (6, 2, 1)
+
+
+def test_the_block_rows_counter_counts_by_the_kernels_block():
+    """A mix of spans and decode rows in one ragged dispatch: a span of n
+    rows is ceil(n / C) blocks of the span kernel's C rows, the decode
+    rows and the idle slots none; `kda_chunk_fill_pct` is the rows over
+    them."""
+    from production_stack_tpu.engine.tracing import RecurrentCounters
+
+    C = kda_pallas.CHUNK
+    q_len = np.array([1, C, 0, C + 1, 1, 3 * C - 1, 5])
+    ctx = np.array([70, C, 0, 4 * C, 1, 3 * C - 1, 900])
+    one = kda.continues_one_row(q_len, ctx)
+    assert one.tolist() == [True] + [False] * 6
+    c = RecurrentCounters(kda_layers=6, state_bytes=0)
+    c.record_ragged(q_len, one, resets=3)
+    c.record_ragged(np.array([1, 1]), np.array([True, True]), resets=0)
+    s = c.snapshot(0)
+    assert s["kda_chunk_tokens_total"] == C + C + 1 + 1 + 3 * C - 1 + 5
+    assert s["kda_chunk_spans_total"] == 5
+    assert s["kda_chunk_block_rows_total"] == (1 + 2 + 1 + 3 + 1) * C
+
+
+def test_the_span_kernels_products_are_float32_at_highest():
+    """Every product of the chunked form on float32 operands with
+    ``Precision.HIGHEST`` (a default-precision product is one bf16 pass
+    on the chip, and the CPU's interpret mode would not show it)."""
+    prep, state, g = _stream(T=kda_pallas.CHUNK, H=1, D=128)
+    cu = jnp.asarray([0, 5, 5, 17, 18, 21], jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda *a: kda_pallas.kda_chunk_scan(
+        state, 1, *a, cu, jnp.asarray(CONTEXT, jnp.int32)))(g, *prep[1:])
+
+    def dots(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from dots(sub)
+
+    found = list(dots(jaxpr.jaxpr))
+    assert len(found) >= 4  # at the least the block against its state
+    for eqn in found:
+        assert {v.aval.dtype for v in eqn.invars} == {jnp.dtype("float32")}
+        assert eqn.params["preferred_element_type"] == jnp.float32
+        assert set(eqn.params["precision"]) == {jax.lax.Precision.HIGHEST}
 
 
 def test_a_dense_model_exports_none_of_them():
