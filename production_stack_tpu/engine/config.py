@@ -23,7 +23,7 @@ from production_stack_tpu.parallel.mesh import MeshConfig
 class ModelConfig:
     name: str = "tiny-llama"
     # "llama" | "mixtral" | "olmoe" | "gemma" | "gemma2" | "phi3" | "ouro"
-    # | "solar_open2" | "pangu_ultra_moe"
+    # | "solar_open2" | "pangu_ultra_moe" | "phi4flash"
     # — Mistral and Qwen run as "llama" (their deltas are knobs:
     # sliding_window, qkv_bias, qk_norm); "phi3" differs only in its fused
     # HF weight layout, "mixtral" and "olmoe" in their HF tensor names
@@ -31,7 +31,9 @@ class ModelConfig:
     # "ouro" in its tensor names and in loop_passes > 1, "solar_open2" in
     # its layer pattern (attn_period > 1) and its sparse block's knobs,
     # "pangu_ultra_moe" in its latent attention (kv_lora_rank > 0) and its
-    # leading dense layers
+    # leading dense layers, "phi4flash" in its layer pattern (mamba_period
+    # > 0: state-space, window, full and cross-attention layers, gated
+    # memory units), differential attention and LayerNorm
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -82,6 +84,30 @@ class ModelConfig:
     # of a hybrid stack's attention layers, which apply no positional
     # encoding (the shared stack's own layers rotate and have no gate)
     attn_gate: bool = False  # sigmoid(W x) on the attention output
+    # the decoder-hybrid-decoder stack (SambaY, "phi4flash"): layer l is a
+    # Mamba-1 state-space layer where l % mamba_period == 0 and attention
+    # elsewhere, with a window of sliding_window rows, up to layer
+    # num_layers // 2 + 1, the ONE attention layer without a window, whose
+    # keys and values every later attention layer reads in place of its
+    # own (cross-attention: W_q and W_o only); from layer num_layers // 2
+    # + 2 on, a state-space layer's place is taken by a gated memory unit
+    # that reads the scan output of layer num_layers // 2 (``layer_kinds``).
+    # 0 = no such stack. A state-space layer keeps a state (mamba_state,
+    # mamba_expand x hidden_size) float32 and a conv tail per decode slot
+    mamba_period: int = 0
+    mamba_state: int = 16    # N, values of state a channel
+    mamba_conv: int = 4      # width of the causal depthwise convolution
+    mamba_expand: int = 2    # inner width over hidden_size
+    mamba_dt_rank: int = 0   # low-rank width of the Delta projection
+    # differential attention: query and key heads pair up (2i, 2i + 1),
+    # a pair's two softmaxes weigh the pair's values (2 x head_dim wide)
+    # and the second is subtracted, lambda times; then RMSNorm and W_o.
+    # The cache holds a pair as one head of 2 x head_dim (cache_kv_heads,
+    # which also says why there are empty heads; cache_head_dim;
+    # models/sambay.py has the identity)
+    diff_attn: bool = False
+    # LayerNorm with weight and bias where the other families have RMSNorm
+    layer_norm: bool = False
     # Qwen2-family: biases on the QKV projections
     qkv_bias: bool = False
     # RMSNorm on q and k, pre-rope. "head": one weight of head_dim shared
@@ -101,7 +127,10 @@ class ModelConfig:
     # local-attention window (Gemma-2 alternates local/global layers). We
     # serve such models exactly ONLY within the window: max_model_len is
     # required to be <= sliding_window (enforced at engine init), where
-    # local and global attention coincide.
+    # local and global attention coincide. A "phi4flash" stack's window
+    # layers are served AS windows (``window_binds``): row t sees rows
+    # t - sliding_window + 1 .. t, in blocks of a pool of their own that
+    # a sequence gives back once no row to come can see them
     sliding_window: int = 0
     # latent attention (MLA; "pangu_ultra_moe"): queries and keys-values
     # pass through low-rank projections with a norm each; a head's key is
@@ -168,7 +197,28 @@ class ModelConfig:
 
     @property
     def q_per_kv(self) -> int:
-        return self.num_heads // self.num_kv_heads
+        """Query heads a head of the cache serves (the attention kernels'
+        group)."""
+        return self.num_heads // (self.num_kv_heads // 2 if self.diff_attn
+                                  else self.num_kv_heads)
+
+    @property
+    def cache_kv_heads(self) -> int:
+        """Key / value heads as the cache holds them: a differential pair
+        is one head, and the pairs are filled up with empty heads to a
+        multiple of four: a token's slab of keys and values, (2 x heads,
+        head size), is then whole 8-row tiles, which the kernels' DMAs
+        need (10 pairs' 20 rows are refused by the TPU compiler: "Slice
+        shape along dimension 3 must be aligned to tiling (8)"; 12 heads'
+        24 rows pass). The empty heads hold zeros and their (zero) queries
+        read zeros."""
+        if not self.diff_attn:
+            return self.num_kv_heads
+        return -(-(self.num_kv_heads // 2) // 4) * 4
+
+    @property
+    def cache_head_dim(self) -> int:
+        return self.head_dim * 2 if self.diff_attn else self.head_dim
 
     @property
     def is_moe(self) -> bool:
@@ -179,8 +229,58 @@ class ModelConfig:
         return self.experts_held or self.num_experts
 
     @property
+    def layer_kinds(self) -> tuple:
+        """What each layer's token mixer is, the one description of a
+        stack's pattern: "attn" (every layer of the shared stack); "gqa" /
+        "kda" (attn_period); "mamba", "swa" (window attention), "full",
+        "gmu" and "cross" (mamba_period)."""
+        n = self.num_layers
+        if self.mamba_period:
+            full = n // 2 + 1  # the last layer before the cross-decoder
+            return tuple(
+                ("mamba" if l < full else "gmu") if l % self.mamba_period == 0
+                else "swa" if l < full else "full" if l == full else "cross"
+                for l in range(n))
+        if self.attn_period > 1:
+            return tuple("gqa" if l % self.attn_period == 0 else "kda"
+                         for l in range(n))
+        return ("attn",) * n
+
+    def count_layers(self, *kinds: str) -> int:
+        return sum(k in kinds for k in self.layer_kinds)
+
+    @property
+    def stack_segments(self) -> tuple:
+        """A patterned stack as runs of like periods, ((kinds of a period,
+        periods), ...): one scan each (models/llama.py _forward_hybrid)."""
+        kinds = self.layer_kinds
+        period = self.mamba_period or self.attn_period
+        runs = []
+        for i in range(0, len(kinds), period):
+            p = kinds[i:i + period]
+            if runs and runs[-1][0] == p:
+                runs[-1][1] += 1
+            else:
+                runs.append([p, 1])
+        return tuple((p, n) for p, n in runs)
+
+    @property
     def has_recurrent_state(self) -> bool:
-        return self.attn_period > 1
+        return self.num_recurrent_layers > 0
+
+    @property
+    def num_recurrent_layers(self) -> int:
+        return self.count_layers("kda", "mamba")
+
+    @property
+    def window_binds(self) -> bool:
+        """Whether the window layers are served as windows (past
+        ``sliding_window`` positions), from a block pool of their own."""
+        return self.mamba_period > 0 and self.sliding_window > 0
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
 
     @property
     def is_latent(self) -> bool:
@@ -206,44 +306,63 @@ class ModelConfig:
 
     @property
     def num_attn_layers(self) -> int:
-        if not self.has_recurrent_state:
-            return self.num_layers
-        return self.num_layers // self.attn_period
+        """Attention layers that own keys and values (a cross-attention
+        layer reads another's)."""
+        return self.count_layers("attn", "gqa", "swa", "full")
 
     @property
     def num_kda_layers(self) -> int:
-        return self.num_layers - self.num_attn_layers
+        return self.count_layers("kda")
 
     @property
     def cache_layers(self) -> int:
         """Layers of KV cache: one per (pass, attention layer) pair."""
         return self.num_attn_layers * self.loop_passes
 
-    def kv_pool_shape(self, num_blocks: int, block_size: int) -> tuple:
+    def kv_pool_shape(self, num_blocks: int, block_size: int,
+                      window: bool = False) -> tuple:
         """The paged pool's shape, the one place that says what a token of
         context holds: a ``(2*KH, D)`` slab of keys and values a cache
-        layer, or with latent attention one row of ``latent_lanes``."""
+        layer, or with latent attention one row of ``latent_lanes``. Where
+        the window binds there are two pools: the window layers' (``window``
+        True), whose rows live while a row to come can see them, and the
+        pool of the layers whose rows live for the whole context."""
         token = ((self.latent_lanes,) if self.is_latent
-                 else (2 * self.num_kv_heads, self.head_dim))
-        return (self.cache_layers, num_blocks, block_size, *token)
+                 else (2 * self.cache_kv_heads, self.cache_head_dim))
+        layers = self.cache_layers
+        if self.window_binds:
+            layers = (self.count_layers("swa") if window
+                      else layers - self.count_layers("swa"))
+        return (layers, num_blocks, block_size, *token)
 
-    @property
-    def kv_bytes_per_token(self) -> int:
-        """What one token of context holds in the pool, all cache layers."""
+    def _token_bytes(self, window: bool) -> int:
         n = 1
-        for d in self.kv_pool_shape(1, 1):
+        for d in self.kv_pool_shape(1, 1, window):
             n *= d
         return n * jnp.dtype(self.jax_dtype).itemsize
 
+    @property
+    def kv_bytes_per_token(self) -> int:
+        """What one token of context holds in the pool, all cache layers
+        (where the window binds: for the whole context, the other pool's
+        ``window_kv_bytes_per_token`` for ``sliding_window`` rows)."""
+        return self._token_bytes(False)
+
+    @property
+    def window_kv_bytes_per_token(self) -> int:
+        return self._token_bytes(True) if self.window_binds else 0
+
     def recurrent_state_bytes(self, slots: int) -> int:
         """What the recurrent layers keep for ``slots`` decode slots: a
-        float32 state per head and a conv tail in the model dtype."""
-        if not self.has_recurrent_state:
-            return 0
+        float32 state and a conv tail in the model dtype, a KDA layer's per
+        head, a state-space layer's per channel."""
+        item = jnp.dtype(self.jax_dtype).itemsize
         h, d = self.kda_heads, self.kda_head_dim
-        tail = (self.kda_conv - 1) * 3 * h * d
-        return self.num_kda_layers * slots * (
-            h * d * d * 4 + tail * jnp.dtype(self.jax_dtype).itemsize)
+        kda = h * d * d * 4 + (self.kda_conv - 1) * 3 * h * d * item
+        mamba = self.mamba_inner * (self.mamba_state * 4
+                                    + (self.mamba_conv - 1) * item)
+        return slots * (self.num_kda_layers * kda
+                        + self.count_layers("mamba") * mamba)
 
     @staticmethod
     def from_hf_config(cfg: dict[str, Any], name: str = "") -> "ModelConfig":
@@ -291,6 +410,8 @@ class ModelConfig:
             return ModelConfig._solar_open2_from_hf(cfg, name)
         elif cfg.get("model_type") == "pangu_ultra_moe":
             return ModelConfig._pangu_ultra_moe_from_hf(cfg, name)
+        elif cfg.get("model_type") == "phi4flash":
+            return ModelConfig._phi4flash_from_hf(cfg, name)
         elif any("Phi3" in a for a in archs):
             # only the standard Phi-3 maps onto the fused-Llama layout;
             # Phi-3-small (query_key_value naming, gegelu, blocksparse)
@@ -567,6 +688,58 @@ class ModelConfig:
         )
 
     @staticmethod
+    def _phi4flash_from_hf(cfg: dict, name: str = "") -> "ModelConfig":
+        """``model_type: phi4flash`` (SambaY, arXiv:2507.06607): see
+        ``mamba_period``. The file states the widths, ``mb_per_layer``, the
+        window and the norm's eps; the state-space sizes are the family's
+        modelling code's defaults (d_state 16, d_conv 4, expand 2, dt_rank
+        ceil(hidden / 16)) unless the file gives them. No positional
+        encoding anywhere. What is not computed is refused by name."""
+        what = "phi4flash"
+        layers, period = int(cfg["num_hidden_layers"]), int(cfg["mb_per_layer"])
+        if period != 2 or layers % 4 or layers < 8:
+            raise ValueError(
+                f"{what} with mb_per_layer={period}, num_hidden_layers="
+                f"{layers} is not supported: only a state-space layer "
+                "before every attention layer (mb_per_layer 2) and a depth "
+                "that splits into whole (state-space, attention) pairs on "
+                "both sides of the cross-decoder's start, layers // 2 + 2")
+        if cfg.get("mlp_bias") or cfg.get("lm_head_bias"):
+            raise ValueError(
+                f"{what} with mlp_bias / lm_head_bias is not supported")
+        heads, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+        if heads % 2 or kv % 2 or (heads // 2) % (kv // 2):
+            raise ValueError(
+                f"{what}: differential attention pairs heads (2i, 2i + 1); "
+                f"{heads} query / {kv} key-value heads do not pair up")
+        if not cfg.get("tie_word_embeddings", True):
+            raise ValueError(f"{what} with an untied head is not supported")
+        hidden = cfg["hidden_size"]
+        return ModelConfig(
+            name=name or cfg.get("_name_or_path", "hf-model"),
+            architecture="phi4flash",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=hidden,
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=layers,
+            num_heads=heads,
+            num_kv_heads=kv,
+            head_dim=cfg.get("head_dim") or hidden // heads,
+            rms_norm_eps=cfg.get("layer_norm_eps", 1e-5),
+            max_model_len=cfg.get("max_position_embeddings", 4096),
+            tie_word_embeddings=True,
+            qkv_bias=True,
+            sliding_window=int(cfg.get("sliding_window") or 0),
+            mamba_period=period,
+            mamba_state=int(cfg.get("mamba_d_state", 16)),
+            mamba_conv=int(cfg.get("mamba_d_conv", 4)),
+            mamba_expand=int(cfg.get("mamba_expand", 2)),
+            mamba_dt_rank=int(cfg.get("mamba_dt_rank") or -(-hidden // 16)),
+            diff_attn=True,
+            layer_norm=True,
+        )
+
+    @staticmethod
     def _whisper_from_hf(cfg: dict, name: str = "") -> "ModelConfig":
         """WhisperForConditionalGeneration config.json → ModelConfig.
 
@@ -805,6 +978,19 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         post_norms=True, kv_lora_rank=32, q_lora_rank=48,
         qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
         dense_layers=1, dense_intermediate_size=256, dtype="float32",
+    ),
+    "tiny-phi4flash": ModelConfig(
+        # Phi-4-mini-flash's stack at test size under the published rule
+        # (cross-decoder from block 12 // 2 + 2 = 8): 3 x (Mamba, window) +
+        # (Mamba, full) + 2 x (GMU, cross), window 8 in blocks of 4,
+        # differential attention over 4 query / 2 key-value heads of 16
+        # (one cache head of 32), LayerNorm, a tied head
+        name="tiny-phi4flash", architecture="phi4flash", vocab_size=512,
+        hidden_size=128, intermediate_size=256, num_layers=12, num_heads=4,
+        num_kv_heads=2, head_dim=16, max_model_len=512,
+        tie_word_embeddings=True, qkv_bias=True, sliding_window=8,
+        mamba_period=2, mamba_dt_rank=8, diff_attn=True, layer_norm=True,
+        dtype="float32",
     ),
     "tiny-whisper": ModelConfig(
         # CPU-testable Whisper: 1 s audio window (n_audio_ctx 50 -> 100

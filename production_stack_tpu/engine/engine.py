@@ -100,10 +100,16 @@ class LLMEngine:
         # where this thread's time goes, always on (engine/tracing.py);
         # the runner switches its own phases (snapshot, commit, launch)
         self.clock = self.runner.clock = StepClock()
+        # where the model's window binds, the window layers' blocks are a
+        # second pool with a block table of its own (engine/kv_cache.py)
+        self.window = (config.model.sliding_window
+                       if config.model.window_binds else 0)
         self.scheduler = Scheduler(
             config.scheduler, config.cache, self.runner.num_blocks,
             max_model_len=config.model.max_model_len,
             recurrent_state=config.model.has_recurrent_state,
+            window=self.window,
+            window_blocks=getattr(self.runner, "window_blocks", 0),
         )
         self.scheduler.now = self.clock.now
         # what the latent attention kernel of an MLA model was asked to
@@ -124,14 +130,24 @@ class LLMEngine:
 
             slots = config.scheduler.max_num_seqs
             self.recurrent = RecurrentCounters(
-                config.model.num_kda_layers,
-                config.model.recurrent_state_bytes(slots))
+                config.model.num_recurrent_layers,
+                config.model.recurrent_state_bytes(slots),
+                kind="mamba" if config.model.mamba_period else "kda")
             logging.getLogger(__name__).info(
-                "%s keeps recurrent state per decode slot (%d KDA layers x "
+                "%s keeps recurrent state per decode slot (%d %s layers x "
                 "%d slots, %.2f GB): prefix-cache lookups are served as "
                 "misses, a preempted sequence recomputes from position 0",
-                config.model.name, config.model.num_kda_layers, slots,
-                self.recurrent.state_bytes / 1e9)
+                config.model.name, config.model.num_recurrent_layers,
+                self.recurrent.kind, slots, self.recurrent.state_bytes / 1e9)
+        # what the window layers' walks stream beside what their calls
+        # would read without a window, and the cross-attention layers'
+        # calls (engine/tracing.py); None where no window binds
+        self.window_counters = None
+        if self.window:
+            from production_stack_tpu.engine.tracing import WindowCounters
+
+            self.window_counters = WindowCounters(
+                config.model, config.cache.block_size)
         # ragged unified step (ops/ragged_paged_attention_pallas.py): the
         # scheduler mixes decode rows and prefill chunks into one
         # token-budget batch, packed here into a single (1, T) stream
@@ -152,6 +168,7 @@ class LLMEngine:
             self._r_tokens = np.zeros((1, T), np.int32)
             self._r_positions = np.full((1, T), -1, np.int32)
             self._r_slot_mapping = np.full(T, -1, np.int32)
+            self._r_window_slot_mapping = np.full(T, -1, np.int32)
             self._r_adapter_ids = np.zeros(T, np.int32)
             self._r_cu = np.zeros(sched.max_num_seqs + 1, np.int32)
             self._r_last_idx = np.zeros(sched.max_num_seqs, np.int32)
@@ -208,8 +225,10 @@ class LLMEngine:
         self._tokens = np.zeros(B, np.int32)
         self._positions = np.zeros(B, np.int32)
         self._block_tables = np.zeros((B, M), np.int32)
+        self._window_tables = np.zeros((B, M), np.int32)
         self._context_lens = np.zeros(B, np.int32)
         self._slot_mapping = np.full(B, -1, np.int32)
+        self._window_slot_mapping = np.full(B, -1, np.int32)
         self._temps = np.zeros(B, np.float32)
         self._top_ps = np.ones(B, np.float32)
         self._top_ks = np.full(B, -1, np.int32)
@@ -1038,6 +1057,8 @@ class LLMEngine:
         self._r_tokens[:] = 0
         self._r_positions[:] = -1
         self._r_slot_mapping[:] = -1
+        if self.window:
+            self._r_window_slot_mapping[:] = -1
         self._r_adapter_ids[:] = 0
         self._r_last_idx[:] = 0
         self._r_sample_mask[:] = 0.0
@@ -1080,6 +1101,9 @@ class LLMEngine:
                 self._r_slot_mapping[cu : cu + n] = slot_mapping_for(
                     seq.block_ids, pos, n, bs
                 )
+                if self.window:
+                    self._r_window_slot_mapping[cu : cu + n] = (
+                        slot_mapping_for(seq.window_block_ids, pos, n, bs))
                 self._r_adapter_ids[cu : cu + n] = seq.adapter_slot
                 self._context_lens[slot] = pos + n
                 self._steps[slot] = pos - seq.num_prompt_tokens + 1
@@ -1116,6 +1140,10 @@ class LLMEngine:
                 self._r_slot_mapping[cu : cu + n] = slot_mapping_for(
                     seq.block_ids, sp.chunk_start, n, bs
                 )
+                if self.window:
+                    self._r_window_slot_mapping[cu : cu + n] = (
+                        slot_mapping_for(seq.window_block_ids,
+                                         sp.chunk_start, n, bs))
                 self._r_adapter_ids[cu : cu + n] = seq.adapter_slot
                 self._context_lens[slot] = sp.chunk_start + n
                 self._steps[slot] = 0
@@ -1135,6 +1163,9 @@ class LLMEngine:
                 t_entries.append((seq, "prefill", n, n))
             nb = len(seq.block_ids)
             self._block_tables[slot, :nb] = seq.block_ids
+            if self.window:
+                self._window_tables[slot, :len(seq.window_block_ids)] = (
+                    seq.window_block_ids)
             self._r_last_idx[slot] = cu - 1
             self._temps[slot] = s.temperature
             self._top_ps[slot] = s.top_p
@@ -1188,6 +1219,9 @@ class LLMEngine:
             verify_idx=(self._r_verify_idx
                         if self._spec is not None else None),
             fetch=False,
+            **({"window": (self._window_tables,
+                           self._r_window_slot_mapping[:W])}
+               if self.window else {}),
         )
         dispatch_s = self.clock.enter("postprocess") - t_call
         for sp in prefills:
@@ -1222,6 +1256,12 @@ class LLMEngine:
             windows, interior = count_windows(
                 self._r_cu, self._context_lens, W,
                 self.config.model.q_per_kv, self.config.cache.block_size)
+            if self.window_counters is not None:
+                self.window_counters.record_ragged(
+                    windows, count_windows(
+                        self._r_cu, self._context_lens, W,
+                        self.config.model.q_per_kv,
+                        self.config.cache.block_size, window=self.window)[0])
             self.ragged_attn_windows += windows
             self.ragged_attn_interior_windows += interior
 
@@ -1412,6 +1452,8 @@ class LLMEngine:
         self.clock.enter("build")
         self._context_lens[:] = 0
         self._slot_mapping[:] = -1
+        if self.window:
+            self._window_slot_mapping[:] = -1
         for seq in decodes:
             i = seq.slot
             pos = seq.num_computed_tokens  # index of the incoming token
@@ -1422,6 +1464,11 @@ class LLMEngine:
             self._block_tables[i, :n] = seq.block_ids
             self._context_lens[i] = pos + 1
             self._slot_mapping[i] = seq.block_ids[pos // bs] * bs + pos % bs
+            if self.window:
+                self._window_tables[i, :len(seq.window_block_ids)] = (
+                    seq.window_block_ids)
+                self._window_slot_mapping[i] = (
+                    seq.window_block_ids[pos // bs] * bs + pos % bs)
             s = seq.sampling
             self._temps[i] = s.temperature
             self._top_ps[i] = s.top_p
@@ -1481,15 +1528,21 @@ class LLMEngine:
             g_ids=self._g_ids if use_grammar else None,
             g_states=self._g_states if use_grammar else None,
             want_logprobs=use_logprobs,
+            **({"window": (self._window_tables, self._window_slot_mapping)}
+               if self.window else {}),
         )
         dispatch_s = self.clock.enter("postprocess") - t_call
         self.decode_dispatches += 1
         if self.recurrent is not None:
             self.recurrent.record_decode(K)
+        if self.window_counters is not None:
+            self.window_counters.record_decode(self._context_lens, K)
         if self.latent is not None:
             self.latent.record("decode", self._context_lens > 0,
                                self._context_lens, iterations=K)
-        attn_calls = K * self.config.model.cache_layers
+        # a cross-attention layer calls the kernel on another's cache layer
+        attn_calls = K * (self.config.model.cache_layers
+                          + self.config.model.count_layers("cross"))
         self.decode_attn_calls += attn_calls
         if getattr(self.runner, "decode_attn_slab", False):
             self.decode_attn_slab_calls += attn_calls
@@ -1828,6 +1881,8 @@ class LLMEngine:
                                 / max(1, self.config.scheduler.max_num_seqs)),
             "kv_blocks_total": self.runner.num_blocks,
             "kv_blocks_free": self.scheduler.num_free_blocks,
+            **(self.window_counters.snapshot(self.scheduler.window_allocator)
+               if self.window_counters is not None else {}),
             # unified ragged path: dispatch counts + live tokens over the
             # token budget (engine/metrics.py turns these into
             # vllm:ragged_* series)
@@ -1971,6 +2026,10 @@ class LLMEngine:
             bypass_prefix=self.scheduler.recurrent_state,
         )
         self.scheduler.allocator.lookups_bypassed = bypassed
+        if self.window:
+            self.scheduler.window_allocator = PrefixCachingBlockAllocator(
+                self.runner.window_blocks, self.config.cache.block_size,
+                bypass_prefix=True)
         self._wire_tier_hooks()  # the rebuilt allocator must keep demoting
         if level >= 2:
             self.runner.drop_params()

@@ -15,7 +15,15 @@ TPU-first design:
   slots, H, d, d) float32 and the short convolution's tail ``conv``
   (KDA layers, slots, K-1, 3*H*d). A model without recurrent layers has
   the paged array alone, as it is; a hybrid stack a dict ``{"kv",
-  "state", "conv"}`` (``init_kv_cache``). A slot's state belongs to the
+  "state", "conv"}`` (``init_kv_cache``). State-space (Mamba) layers keep
+  ``state`` (layers, slots, N, d_i) float32 and ``conv`` (layers, slots,
+  K-1, d_i) the same way. Where a model's window binds
+  (``ModelConfig.window_binds``) the window layers' keys and values are a
+  second pool, ``"win"``, of blocks of the same size from an allocator of
+  its own (``window_pool_blocks``): a sequence holds a window block only
+  while a row to come can still see it (engine/scheduler.py), and ``"kv"``
+  holds the layers whose rows live for the whole context. A slot's state
+  belongs to the
   sequence that holds the slot and is taken as zeros by a span that
   starts at position 0, so nothing on the host resets it. Block tables
   and slot mappings are tiny int32 host arrays recomputed each step — all
@@ -67,10 +75,12 @@ def init_kv_cache(
     rules: Optional[ShardingRules] = None,
     num_blocks: Optional[int] = None,
     slots: int = 0,
+    window_blocks: int = 0,
 ):
     """Allocate the fused HBM block pool, sharded over the mesh; for a
     model with recurrent layers, the pool and the per-slot state of
-    ``slots`` decode slots as {"kv", "state", "conv"}."""
+    ``slots`` decode slots as {"kv", "state", "conv"}, and where its
+    window binds the window layers' pool of ``window_blocks`` as "win"."""
     from production_stack_tpu.parallel.shardings import rules_for_model
 
     rules = rules or rules_for_model(model, mesh)
@@ -101,6 +111,18 @@ def init_kv_cache(
             return pool
         if slots <= 0:
             raise ValueError("a recurrent-state model needs its slot count")
+        if model.mamba_period:
+            lm, di = model.count_layers("mamba"), model.mamba_inner
+            caches = {
+                "kv": pool,
+                "state": jnp.zeros((lm, slots, model.mamba_state, di),
+                                   jnp.float32),
+                "conv": jnp.zeros((lm, slots, model.mamba_conv - 1, di), dt),
+            }
+            if model.window_binds:
+                caches["win"] = jnp.zeros(model.kv_pool_shape(
+                    window_blocks, cache.block_size, window=True), dt)
+            return caches
         h, d = model.kda_heads, model.kda_head_dim
         lk = model.num_kda_layers
         return {
@@ -112,6 +134,22 @@ def init_kv_cache(
 
 def kv_cache_bytes_per_block(model: ModelConfig, cache: CacheConfig) -> int:
     return cache.block_size * model.kv_bytes_per_token
+
+
+def window_pool_blocks(model: ModelConfig, block_size: int, slots: int,
+                       token_budget: int) -> int:
+    """Blocks of the window layers' pool, from the configuration alone: a
+    sequence that computes rows ``c .. c + n`` holds the blocks from the
+    one with row ``c - (window - 1)`` to the one with row ``c + n``, at
+    most ``(n + window) / block + 2``; a step's ``n`` add up to the token
+    budget at most. So every slot can hold its ``window / block + 2`` (and
+    one spare) and a step its chunks whatever the others hold: no sequence
+    ever waits for a window block. 0 where no window binds."""
+    if not model.window_binds:
+        return 0
+    per_slot = -(-model.sliding_window // block_size) + 3
+    return slots * per_slot + -(-(token_budget + model.sliding_window)
+                                // block_size)
 
 
 def resolve_num_blocks(

@@ -346,6 +346,69 @@ class EngineStatsCollector:
                 "cache layers (a latent row padded to whole lane tiles)",
                 s["kv_bytes_per_token"],
             )
+        # a window that binds (engine/tracing.py WindowCounters): exported
+        # by models whose window layers have a block pool of their own
+        if "window_kv_blocks_total" in s:
+            yield counter(
+                "vllm:window_attn_context_tokens",
+                "Tokens of context the window layers' attention calls "
+                "would stream if no window bound, each once a call",
+                s["window_attn_context_tokens_total"],
+            )
+            yield counter(
+                "vllm:window_attn_read_tokens",
+                "Tokens of context the window layers' attention calls "
+                "stream: from the block (a ragged walk: the context window) "
+                "that holds a row's floor to its causal reach",
+                s["window_attn_read_tokens_total"],
+            )
+            yield counter(
+                "vllm:shared_kv_attn_calls",
+                "Attention calls of cross-attention layers, which read "
+                "the one full-attention layer's cache rows and write none",
+                s["shared_kv_attn_calls_total"],
+            )
+            yield gauge(
+                "vllm:window_kv_blocks_total",
+                "Block pool capacity of the window layers (HBM)",
+                s["window_kv_blocks_total"],
+            )
+            yield gauge(
+                "vllm:window_kv_blocks_free",
+                "Free blocks of the window layers' pool",
+                s["window_kv_blocks_free"],
+            )
+            yield gauge(
+                "vllm:kv_bytes_per_token",
+                "Bytes one token of context holds in the paged pool for "
+                "the whole context (the window layers' rows live in a pool "
+                "of their own for sliding_window rows)",
+                s["kv_bytes_per_token"],
+            )
+        # state-space layers of a hybrid stack (RecurrentCounters, kind
+        # "mamba"): the KDA counters' meanings under their own names
+        if "mamba_decode_calls_total" in s:
+            yield counter(
+                "vllm:mamba_decode_calls",
+                "State-space decode steps the decode program ran (decode "
+                "dispatches x fused iterations x Mamba layers); a ragged "
+                "dispatch runs one more a layer for its decode rows",
+                s["mamba_decode_calls_total"],
+            )
+            yield counter(
+                "vllm:mamba_chunk_tokens",
+                "Rows of the ragged dispatches that the state-space "
+                "layers' span scan carried: every span's but the decode "
+                "rows'",
+                s["mamba_chunk_tokens_total"],
+            )
+            yield counter(
+                "vllm:mamba_chunk_spans",
+                "Spans of the ragged dispatches that the span scan carried: "
+                "each loads and stores its slot's state once a layer and "
+                "channel block",
+                s["mamba_chunk_spans_total"],
+            )
         # recurrent-state layers (engine/tracing.py RecurrentCounters):
         # exported by hybrid stacks only
         if "kda_decode_calls_total" in s:
@@ -377,6 +440,7 @@ class EngineStatsCollector:
                 "(ops/kda_pallas.py CHUNK), the last one padded",
                 s["kda_chunk_block_rows_total"],
             )
+        if "recurrent_state_bytes" in s:
             yield counter(
                 "vllm:recurrent_state_resets",
                 "Sequences started from a zero recurrent state (new, or "

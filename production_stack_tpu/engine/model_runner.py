@@ -142,6 +142,24 @@ _RAGGED_INPUTS = (
 )
 
 
+# where a model's window binds (ModelConfig.window_binds), behind the step's
+# own: the window layers' pool has a block table and a slot mapping of its
+# own, shaped like ``block_tables`` and ``slot_mapping``
+_WINDOW_INPUTS = (("window_block_tables", "int32"),
+                  ("window_slot_mapping", "int32"))
+
+
+def _window_kw(f: dict, slots=None) -> dict:
+    """An attention call's window inputs out of a step's unpacked fields
+    (``slots``: the slot mapping where the step advances its own); nothing
+    for a model with one pool."""
+    if "window_block_tables" not in f:
+        return {}
+    return {"window_tables": f["window_block_tables"],
+            "window_slots": (f["window_slot_mapping"] if slots is None
+                             else slots)}
+
+
 def _owning_slots(cu_q_lens, tokens: int, slots: int):
     """Owning slot per token of a packed stream, recovered from the span
     offsets (the XLA attention forms ask it; the kernels walk the offsets)."""
@@ -169,8 +187,10 @@ def _pallas_ok(cfg: ModelConfig, mesh: Mesh, block_size: int) -> bool:
              f"num_kv_heads {cfg.num_kv_heads} % tensor {tp} != 0"),
             (cfg.num_heads % tp == 0,
              f"num_heads {cfg.num_heads} % tensor {tp} != 0"),
-            (cfg.head_dim % 128 == 0,
-             f"head_dim {cfg.head_dim} % 128 != 0"),
+            # as the cache holds a head: a differential pair of 64-wide
+            # heads is one of 128 (models/sambay.py)
+            (cfg.cache_head_dim % 128 == 0,
+             f"head_dim {cfg.cache_head_dim} % 128 != 0"),
             (block_size % 16 == 0, f"block_size {block_size} % 16 != 0"),
         )) if not ok
     ]
@@ -198,7 +218,7 @@ class ModelRunner:
         # the engine's step clock (engine/tracing.py); the engine replaces
         # this one with its own, a runner driven alone keeps it
         self.clock = StepClock()
-        if (self.cfg.sliding_window
+        if (self.cfg.sliding_window and not self.cfg.window_binds
                 and self.cfg.max_model_len > self.cfg.sliding_window):
             # local/global attention layers coincide only within the window;
             # beyond it the global-attention approximation would silently
@@ -240,8 +260,8 @@ class ModelRunner:
         # per-shard geometry (vllm:decode_attn_slab_calls_total)
         self.decode_attn_slab = (
             self.use_pallas and not self.cfg.is_latent) and decode_slab_path(
-            self.cfg.num_kv_heads // self.tp,
-            self.cfg.q_per_kv, self.cfg.head_dim, self.cfg.jax_dtype)
+            self.cfg.cache_kv_heads // self.tp,
+            self.cfg.q_per_kv, self.cfg.cache_head_dim, self.cfg.jax_dtype)
         impl = getattr(config, "attention_impl", "auto") or "auto"
         if impl not in ("auto", "ragged", "bucketed"):
             raise ValueError(
@@ -266,6 +286,12 @@ class ModelRunner:
                 "not supported for a latent cache: the latent attention "
                 "kernel takes the ragged stream (a decode step its "
                 "one-token spans); there is no bucketed prefill over it")
+        # the window layers' pool (0 where no window binds) follows from the
+        # configuration; the other pool takes what memory is left
+        self.window_blocks = kvmod.window_pool_blocks(
+            self.cfg, config.cache.block_size,
+            config.scheduler.max_num_seqs,
+            config.scheduler.max_num_batched_tokens)
         self.num_blocks = self._resolve_num_blocks(num_blocks)
         self.kv = self._init_cache()
         # block-table width padded to a multiple of the kernels' DMA window
@@ -309,11 +335,12 @@ class ModelRunner:
             **self._mh_gate,
         )
         recurrent = self.cfg.has_recurrent_state
+        recur = self._recur_mamba if self.cfg.mamba_period else self._recur
         self._decode_multi = jax.jit(
             _named_partial(
                 _decode_multi_step, self.cfg, self._attend_decode,
                 max(config.scheduler.multi_step, 1), self._eos_id,
-                recur_impl=(functools.partial(self._recur, False)
+                recur_impl=(functools.partial(recur, False)
                             if recurrent else None),
             ),
             donate_argnums=(1,),
@@ -333,7 +360,7 @@ class ModelRunner:
                 _named_partial(_ragged_step, self.cfg,
                                self._attend_ragged, self._eos_id,
                                self.spec_width,
-                               recur_impl=(functools.partial(self._recur, True)
+                               recur_impl=(functools.partial(recur, True)
                                            if recurrent else None)),
                 donate_argnums=(1,),
                 static_argnames=("layout", "greedy_only", "use_penalties",
@@ -382,11 +409,14 @@ class ModelRunner:
         self.grammar_accept = None
 
     @staticmethod
-    def _refuse_for_recurrent_state(config: EngineConfig, mesh: Mesh) -> None:
-        """A model with recurrent (KDA) layers keeps state per decode slot
-        that only the ragged and decode step programs of ONE chip carry.
-        Whatever would split, move, skip or guess at that state is refused
-        here by name, not served wrongly."""
+    def _refuse_for_recurrent_state(config: EngineConfig, mesh: Mesh,
+                                    lora: bool = False) -> None:
+        """A model with recurrent (KDA or state-space) layers keeps state
+        per decode slot that only the ragged and decode step programs of
+        ONE chip carry; one whose window binds keeps blocks of two kinds
+        besides. Whatever would split, move, skip or guess at either is
+        refused here by name, not served wrongly (``lora``: an adapter is
+        being loaded)."""
         name = config.model.name
         refused = [
             what for bad, what in (
@@ -397,6 +427,9 @@ class ModelRunner:
                 (config.model.quant is not None,
                  f"quant={config.model.quant}: the recurrent layers' "
                  "projections are not quantized"),
+                (lora,
+                 "LoRA adapters: the stack walker of a patterned stack "
+                 "applies none"),
                 (config.scheduler.spec_ngram_k > 0,
                  "n-gram speculative decoding (spec_ngram_k > 0): a "
                  "rejected draft cannot be taken out of the state again"),
@@ -457,7 +490,8 @@ class ModelRunner:
     def _init_cache(self):
         return kvmod.init_kv_cache(
             self.cfg, self.config.cache, self.mesh, self.rules,
-            self.num_blocks, slots=self.config.scheduler.max_num_seqs)
+            self.num_blocks, slots=self.config.scheduler.max_num_seqs,
+            window_blocks=self.window_blocks)
 
     def install_compile_observer(self, observer) -> None:
         """Proxy every jitted program through a compile tracker so the
@@ -503,6 +537,11 @@ class ModelRunner:
                 # kernel, its output both ways
                 hidden += (T * self.cfg.kda_heads * self.cfg.kda_head_dim
                            * (6 * 2 + 12 * 4)) // 8
+                # a state-space layer's rows: [x; z] and the convolved x
+                # in the model dtype, x, Delta and y in float32 as the
+                # span kernel takes and returns them, the gated product
+                hidden += (T * self.cfg.mamba_inner * (4 * 2 + 5 * 4)
+                           if self.cfg.mamba_period else 0) // 8
             if self.cfg.is_latent:
                 # a latent layer's rows of all heads: the two parts of the
                 # query, the absorbed query at the pool's lanes, the
@@ -564,10 +603,13 @@ class ModelRunner:
             raise RuntimeError(
                 f"{jax.default_backend()} device reports no memory_stats(): "
                 "cannot size the KV pool — pass --num-blocks")
-        # the recurrent layers' per-slot state comes out of the same memory
+        # the recurrent layers' per-slot state and the window layers' pool
+        # come out of the same memory
         free = (hbm - used - self._prefill_temp_bytes() - 2 * 1024**3
                 - self.cfg.recurrent_state_bytes(
-                    self.config.scheduler.max_num_seqs))
+                    self.config.scheduler.max_num_seqs)
+                - (self.window_blocks * self.config.cache.block_size
+                   * self.cfg.window_kv_bytes_per_token))
         n_dev = max(self.mesh.devices.size, 1)
         total_free = free * n_dev  # cache is sharded over the mesh
         return max(int(total_free * self.config.cache.hbm_utilization) // per_block, 16)
@@ -661,12 +703,34 @@ class ModelRunner:
         )
 
     def _xla_attend(self, q, caches, layer_idx, block_tables, context_lens,
-                    q_positions):
+                    q_positions, **window):
         layer = jax.lax.dynamic_index_in_dim(caches, layer_idx, 0, keepdims=False)
         return paged_attention(
             q, layer, block_tables, context_lens, q_positions, tp=self.tp,
-            soft_cap=self.cfg.attn_logit_softcap,
+            soft_cap=self.cfg.attn_logit_softcap, **window,
         )
+
+    def _attend_kind(self, attend, kind, q, k, v, caches, layer_idx,
+                     block_tables, context_lens, q_positions, slot_mapping,
+                     *cu_q_lens, window_tables, window_slots):
+        """An attention layer of a stack whose layers differ in where their
+        keys and values live (``kind``, models/llama.py _forward_hybrid):
+        a "swa" layer writes and reads the window pool by its own table,
+        within the window; the "full" layer the other pool; a "cross"
+        layer reads the full layer's rows there and writes none.
+        ``attend`` is the step form's call (``_attend_decode`` /
+        ``_attend_ragged``), ``caches`` the whole cache pytree."""
+        if kind == "swa":
+            pool, tables, slots = "win", window_tables, window_slots
+            how = {"window": self.cfg.sliding_window}
+        else:
+            pool, tables, slots = "kv", block_tables, slot_mapping
+            how = {} if kind == "full" else {"write": False}
+        out, cache = attend(q, k, v, caches[pool], layer_idx, tables,
+                            context_lens, q_positions, slots, *cu_q_lens,
+                            **how)
+        return out, {**caches, pool: cache}
+
 
     def _attend_prefill(self, q, k, v, caches, layer_idx, block_tables,
                         context_lens, q_positions, slot_mapping):
@@ -736,7 +800,17 @@ class ModelRunner:
             value_dim=C), caches
 
     def _attend_decode(self, q, k, v, caches, layer_idx, block_tables,
-                       context_lens, q_positions, slot_mapping):
+                       context_lens, q_positions, slot_mapping, *,
+                       window: int = 0, write: bool = True, kind=None,
+                       **window_inputs):
+        """``window`` > 0: the query sees the last ``window`` rows;
+        ``write`` False: the keys and values are another layer's, already
+        in ``caches``, and k, v are not used."""
+        if kind is not None:
+            return self._attend_kind(
+                self._attend_decode, kind, q, k, v, caches, layer_idx,
+                block_tables, context_lens, q_positions, slot_mapping,
+                **window_inputs)
         if self.cfg.is_latent:
             # the same kernel on one-token spans, a slot a token; an idle
             # slot (context 0, position < 0) walks nothing
@@ -747,11 +821,13 @@ class ModelRunner:
                                         q_positions[:, 0], -1),
                 slot_mapping, jnp.arange(B + 1, dtype=jnp.int32))
             return out[:, None], caches
+        how = {"window": window} if window else {}
         if not self.use_pallas:
-            caches = write_kv(caches, layer_idx, k[:, 0], v[:, 0], slot_mapping,
-                              self.tp)
+            if write:
+                caches = write_kv(caches, layer_idx, k[:, 0], v[:, 0],
+                                  slot_mapping, self.tp)
             out = self._xla_attend(q, caches, layer_idx, block_tables,
-                                   context_lens, q_positions)
+                                   context_lens, q_positions, **how)
             return out, caches
 
         from production_stack_tpu.ops.paged_attention_pallas import (
@@ -759,14 +835,18 @@ class ModelRunner:
             paged_decode_attention_pallas,
         )
 
-        newkv = combine_kv(k[:, 0].astype(caches.dtype),
-                           v[:, 0].astype(caches.dtype), self.tp)
+        if write:
+            newkv = combine_kv(k[:, 0].astype(caches.dtype),
+                               v[:, 0].astype(caches.dtype), self.tp)
+        else:  # one chip (no shard_map below splits this placeholder)
+            newkv = slot_mapping = jnp.zeros((1,), jnp.int32)
 
         def inner(q3, nk, fused, bt, cl, sm, li, _unused):
-            fused = kv_cache_write_pallas(fused, nk, sm, li)
+            if write:
+                fused = kv_cache_write_pallas(fused, nk, sm, li)
             out = paged_decode_attention_pallas(
                 q3, fused, bt, cl, li,
-                soft_cap=self.cfg.attn_logit_softcap,
+                soft_cap=self.cfg.attn_logit_softcap, **how,
             )
             return out, fused
 
@@ -777,27 +857,38 @@ class ModelRunner:
         return out[:, None], caches
 
     def _attend_ragged(self, q, k, v, caches, layer_idx, block_tables,
-                       context_lens, q_positions, slot_mapping, cu_q_lens):
+                       context_lens, q_positions, slot_mapping, cu_q_lens, *,
+                       window: int = 0, write: bool = True, kind=None,
+                       **window_inputs):
         """Unified ragged step: q (1, T, H, D) over the packed mixed
         prefill+decode stream; per-slot spans via cu_q_lens (S+1,).
         q_positions (1, T) carries each token's absolute position (-1 pad)
         for the XLA reference path; the Pallas kernel derives positions
-        from cu_q_lens/context_lens on its own."""
+        from cu_q_lens/context_lens on its own. ``window``, ``write``: as
+        ``_attend_decode``'s."""
+        if kind is not None:
+            return self._attend_kind(
+                self._attend_ragged, kind, q, k, v, caches, layer_idx,
+                block_tables, context_lens, q_positions, slot_mapping,
+                cu_q_lens, **window_inputs)
         T = q.shape[1]
         if self.cfg.is_latent:
             out, caches = self._attend_latent(
                 q[0], k[0, :, 0], caches, layer_idx, block_tables,
                 context_lens, q_positions[0], slot_mapping, cu_q_lens)
             return out[None], caches
-        k_flat = k.reshape(T, -1, self.cfg.head_dim)
-        v_flat = v.reshape(T, -1, self.cfg.head_dim)
+        how = {"window": window} if window else {}
+        if write:
+            k_flat = k.reshape(T, -1, self.cfg.cache_head_dim)
+            v_flat = v.reshape(T, -1, self.cfg.cache_head_dim)
         if not self.use_pallas:
             from production_stack_tpu.ops.paged_attention import (
                 ragged_paged_attention,
             )
 
-            caches = write_kv(caches, layer_idx, k_flat, v_flat,
-                              slot_mapping, self.tp)
+            if write:
+                caches = write_kv(caches, layer_idx, k_flat, v_flat,
+                                  slot_mapping, self.tp)
             layer = jax.lax.dynamic_index_in_dim(
                 caches, layer_idx, 0, keepdims=False
             )
@@ -805,7 +896,7 @@ class ModelRunner:
             out = ragged_paged_attention(
                 q[0], layer, block_tables, context_lens, seq_ids,
                 q_positions[0], tp=self.tp,
-                soft_cap=self.cfg.attn_logit_softcap,
+                soft_cap=self.cfg.attn_logit_softcap, **how,
             )
             return out[None], caches
 
@@ -816,14 +907,18 @@ class ModelRunner:
             ragged_paged_attention_pallas,
         )
 
-        newkv = combine_kv(k_flat.astype(caches.dtype),
-                           v_flat.astype(caches.dtype), self.tp)
+        if write:
+            newkv = combine_kv(k_flat.astype(caches.dtype),
+                               v_flat.astype(caches.dtype), self.tp)
+        else:  # one chip (no shard_map below splits this placeholder)
+            newkv = slot_mapping = jnp.zeros((1,), jnp.int32)
 
         def inner(q3, nk, fused, bt, cl, sm, li, cu):
-            fused = kv_cache_write_pallas(fused, nk, sm, li)
+            if write:
+                fused = kv_cache_write_pallas(fused, nk, sm, li)
             out = ragged_paged_attention_pallas(
                 q3, fused, bt, cu, cl, li,
-                soft_cap=self.cfg.attn_logit_softcap,
+                soft_cap=self.cfg.attn_logit_softcap, **how,
             )
             return out, fused
 
@@ -866,6 +961,39 @@ class ModelRunner:
             caches["conv"], tail, k_idx, 0)
         return (jnp.expand_dims(o, axis),
                 {**caches, "state": state, "conv": conv})
+
+    def _recur_mamba(self, ragged: bool, mp, xs, caches, m_idx,
+                     *step_inputs):
+        """A state-space layer's stateful call (a ``sambay.MambaFn`` with
+        the step's inputs bound behind it), as ``_recur`` is a KDA
+        layer's: ``xs`` (1, T, d_i) the packed stream, or (B, 1, d_i) one
+        row a slot."""
+        from production_stack_tpu.ops import mamba, mamba_pallas
+
+        axis = 0 if ragged else 1  # of the axis the step form lacks
+        conv_impl, xla, pallas = (
+            (kda.conv_ragged, mamba.scan_ragged, mamba_pallas.mamba_ragged)
+            if ragged else (kda.conv_decode, mamba.scan_decode,
+                            mamba_pallas.mamba_decode_step))
+        new = {}
+
+        def conv(x, taps):
+            tail = jax.lax.dynamic_index_in_dim(caches["conv"], m_idx, 0,
+                                                False)
+            out, new["tail"] = conv_impl(x, taps, tail, *step_inputs)
+            return out
+
+        def scan(A, *rows):
+            y, new["state"] = (pallas if self.use_pallas else xla)(
+                caches["state"], m_idx, A, *rows, *step_inputs)
+            return y
+
+        y = mamba.mix(mp, jnp.squeeze(xs, axis), self.cfg.mamba_state, conv,
+                      scan)
+        conv_state = jax.lax.dynamic_update_index_in_dim(
+            caches["conv"], new["tail"], m_idx, 0)
+        return (jnp.expand_dims(y, axis),
+                {**caches, "state": new["state"], "conv": conv_state})
 
     # -- public step API (host numpy in, device out) -------------------------
     def prefill(self, tokens: np.ndarray, positions: np.ndarray,
@@ -994,7 +1122,7 @@ class ModelRunner:
                      presence=None, frequency=None,
                      adapter_ids=None, ctrl=None, tokens_dev=None,
                      g_ids=None, g_states=None,
-                     want_logprobs: bool = False):
+                     want_logprobs: bool = False, window=None):
         """Launch multi_step fused decode+sample iterations and return
         without waiting for them: ``(sampled (num_steps, B), next_tok,
         counters[, tok_lp (K, B), ids (K, B, N), lps (K, B, N)])``, all
@@ -1017,8 +1145,10 @@ class ModelRunner:
         this returns."""
         arrays = (tokens, positions, block_tables, context_lens,
                   slot_mapping, temps, top_ps, top_ks, seeds, steps,
-                  np.full(1, tokens_dev is not None, np.int32))
-        layout = StepLayout.of(_DECODE_INPUTS, arrays)
+                  np.full(1, tokens_dev is not None, np.int32),
+                  *(window or ()))
+        layout = StepLayout.of(
+            _DECODE_INPUTS + (_WINDOW_INPUTS if window else ()), arrays)
         buf = layout.pack(arrays)
         if tokens_dev is None:
             tokens_dev = self._no_tokens_dev
@@ -1047,7 +1177,7 @@ class ModelRunner:
                     adapter_ids=None, ctrl=None,
                     g_ids=None, g_states=None,
                     verify_idx=None,
-                    fetch: bool = True):
+                    fetch: bool = True, window=None):
         """ONE unified dispatch over the packed mixed prefill+decode stream.
 
         tokens/positions: (1, T) with T the stream width the engine chose
@@ -1106,7 +1236,12 @@ class ModelRunner:
             arrays.append(
                 np.zeros((context_lens.shape[0], self.spec_width), np.int32)
                 if verify_idx is None else verify_idx)
-        layout = StepLayout.of(_RAGGED_INPUTS[:len(arrays)], arrays)
+        spec = _RAGGED_INPUTS[:len(arrays)]
+        if window:  # ``window``: (the window pool's block tables (S, M),
+            # its slot mapping): a model whose window binds
+            arrays += window
+            spec += _WINDOW_INPUTS
+        layout = StepLayout.of(spec, arrays)
         buf = layout.pack(arrays)
         self.clock.enter("commit")
         with jax.set_mesh(self.mesh):
@@ -1183,18 +1318,13 @@ class ModelRunner:
     def pooled_embed(self, tokens: np.ndarray, mask: np.ndarray) -> np.ndarray:
         """Mean-pooled final hidden state over a dense causal forward."""
         if getattr(self, "_pooled_fn", None) is None:
-            from production_stack_tpu.ops.attention import (
-                dense_causal_attention,
-            )
+            from production_stack_tpu.models.llama import dense_attend
 
             model = self.model
             cfg = self.cfg
 
             def _embed(params, tokens, mask):
-                def attend(q, k, v, caches, layer_idx):
-                    return dense_causal_attention(
-                        q, k, v, soft_cap=cfg.attn_logit_softcap
-                    ), caches
+                attend = dense_attend(cfg)
 
                 S = tokens.shape[1]
                 positions = jnp.broadcast_to(
@@ -1226,18 +1356,13 @@ class ModelRunner:
         logits one position earlier). Returns (N,) float32 sums.
         """
         if getattr(self, "_seqlp_fn", None) is None:
-            from production_stack_tpu.ops.attention import (
-                dense_causal_attention,
-            )
+            from production_stack_tpu.models.llama import dense_attend
 
             model = self.model
             cfg = self.cfg
 
             def _score(params, tokens, cont_mask):
-                def attend(q, k, v, caches, layer_idx):
-                    return dense_causal_attention(
-                        q, k, v, soft_cap=cfg.attn_logit_softcap
-                    ), caches
+                attend = dense_attend(cfg)
 
                 S = tokens.shape[1]
                 positions = jnp.broadcast_to(
@@ -1273,18 +1398,13 @@ class ModelRunner:
         the live length are garbage the caller slices off."""
         if getattr(self, "_prompt_lp_fn", None) is None:
             from production_stack_tpu.engine.sampling import compute_logprobs
-            from production_stack_tpu.ops.attention import (
-                dense_causal_attention,
-            )
+            from production_stack_tpu.models.llama import dense_attend
 
             model = self.model
             cfg = self.cfg
 
             def _score(params, tokens):
-                def attend(q, k, v, caches, layer_idx):
-                    return dense_causal_attention(
-                        q, k, v, soft_cap=cfg.attn_logit_softcap
-                    ), caches
+                attend = dense_attend(cfg)
 
                 S = tokens.shape[1]
                 positions = jnp.broadcast_to(
@@ -1358,6 +1478,9 @@ class ModelRunner:
         """Write an adapter's stacked (A, B) pairs into bank slot ``slot``."""
         if self.cfg.is_latent:
             self._refuse_for_latent_cache(self.config, self.mesh, lora=True)
+        if self.cfg.has_recurrent_state:
+            self._refuse_for_recurrent_state(self.config, self.mesh,
+                                             lora=True)
         N = self.config.max_loras
         dt = self.cfg.jax_dtype
         if self.lora_bank is None:
@@ -1514,9 +1637,8 @@ def _recur_kw(recur_impl, *step_inputs) -> dict:
     if recur_impl is None:
         return {}
 
-    def recur(conv_w, qkv, g, beta, caches, k_idx, *, neg_eigval):
-        return recur_impl(conv_w, qkv, g, beta, caches, k_idx,
-                          *step_inputs, neg_eigval=neg_eigval)
+    def recur(*layer_inputs, **kw):
+        return recur_impl(*layer_inputs, *step_inputs, **kw)
 
     return {"recur": recur}
 
@@ -1681,11 +1803,11 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
     else:
         g_ids = g_states0 = jnp.zeros(B, jnp.int32)  # carry placeholder
 
-    def one(kv, tok, pos, ctx, slots, step_ctr, counts, g_state):
-        def attend(q, k, v, caches, layer_idx):
+    def one(kv, tok, pos, ctx, slots, wslots, step_ctr, counts, g_state):
+        def attend(q, k, v, caches, layer_idx, **kind):
             return attend_impl(
                 q, k, v, caches, layer_idx, block_tables, ctx, pos[:, None],
-                slots,
+                slots, **(_window_kw(f, wslots) if kind else {}), **kind,
             )
 
         # idle slots stay out of an MoE model's routing, whose per-layer
@@ -1734,9 +1856,9 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
         return kv, g_state, (sampled, *moe_hist)
 
     def body(carry, _):
-        kv, tok, pos, ctx, slots, step_ctr, counts, g_state = carry
+        kv, tok, pos, ctx, slots, wslots, step_ctr, counts, g_state = carry
         kv, g_state, (sampled, *lp) = one(
-            kv, tok, pos, ctx, slots, step_ctr, counts, g_state
+            kv, tok, pos, ctx, slots, wslots, step_ctr, counts, g_state
         )
         new_pos = jnp.where(active, pos + 1, pos)
         new_ctx = jnp.where(active, ctx + 1, ctx)
@@ -1749,20 +1871,25 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
         new_slots = jnp.where(
             valid, block * block_size + new_pos % block_size, -1
         )
+        if wslots is not None:  # the window layers' pool, by its own table
+            block = f["window_block_tables"][
+                jnp.arange(B), jnp.clip(new_pos, 0, None) // block_size]
+            wslots = jnp.where(
+                valid, block * block_size + new_pos % block_size, -1)
         tok = jnp.where(active, sampled, tok)
         if use_penalties:
             counts = counts.at[jnp.arange(B), sampled].add(
                 active.astype(counts.dtype)
             )
         return (
-            (kv, tok, new_pos, new_ctx, new_slots, step_ctr + 1, counts,
-             g_state),
+            (kv, tok, new_pos, new_ctx, new_slots, wslots, step_ctr + 1,
+             counts, g_state),
             (sampled, *lp),
         )
 
     init = (kv, tokens, f["positions"], context_lens, f["slot_mapping"],
-            f["steps"], token_counts, g_states0)
-    (kv, _, _, _, _, _, counts, _), (sampled, *lp) = jax.lax.scan(
+            f.get("window_slot_mapping"), f["steps"], token_counts, g_states0)
+    (kv, _, _, _, _, _, _, counts, _), (sampled, *lp) = jax.lax.scan(
         body, init, None, length=num_steps
     )
     # next_tok comes out of the SAME program: an eager slice on the result
@@ -1822,10 +1949,11 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
     f = layout.unpack(packed)
     tokens, positions, last_idx = f["tokens"], f["positions"], f["last_idx"]
 
-    def attend(q, k, v, caches, layer_idx):
+    def attend(q, k, v, caches, layer_idx, **kind):
         return attend_impl(
             q, k, v, caches, layer_idx, f["block_tables"],
             f["context_lens"], positions, f["slot_mapping"], f["cu_q_lens"],
+            **(_window_kw(f) if kind else {}), **kind,
         )
 
     lora = None

@@ -69,7 +69,8 @@ class SchedulerOutput:
 class Scheduler:
     def __init__(self, sched: SchedulerConfig, cache: CacheConfig,
                  num_blocks: int, max_model_len: int = 1 << 30,
-                 recurrent_state: bool = False):
+                 recurrent_state: bool = False, window: int = 0,
+                 window_blocks: int = 0):
         self.config = sched
         self.cache_config = cache
         self.max_model_len = max_model_len
@@ -80,6 +81,14 @@ class Scheduler:
             num_blocks, cache.block_size, cache.enable_prefix_caching,
             bypass_prefix=recurrent_state,
         )
+        # a model whose window binds: its window layers' blocks come from a
+        # pool of their own, taken as a sequence's rows reach them and
+        # given back once no row to come can see them (``_trim_window``)
+        self.window = window
+        self.window_allocator = (
+            PrefixCachingBlockAllocator(window_blocks, cache.block_size,
+                                        bypass_prefix=True)
+            if window else None)
         self.waiting: collections.deque[Sequence] = collections.deque()
         self.seqs: dict[str, Sequence] = {}  # admitted, not finished
         self.free_slots = list(range(sched.max_num_seqs - 1, -1, -1))
@@ -194,6 +203,10 @@ class Scheduler:
             seq.released_block_ids = list(seq.block_ids)
             self.allocator.free_blocks(seq.block_ids)
             seq.block_ids = []
+        if seq.window_block_ids:
+            self.window_allocator.free_blocks(
+                seq.window_block_ids[seq.window_released:])
+            seq.window_block_ids, seq.window_released = [], 0
         if seq.slot >= 0:
             self.free_slots.append(seq.slot)
             seq.slot = -1
@@ -230,6 +243,35 @@ class Scheduler:
         victim.num_computed_tokens = 0
         victim.num_cached_tokens = 0
         self.waiting.appendleft(victim)
+
+    def _trim_window(self, seq: Sequence) -> None:
+        """Give back the window blocks wholly below the rows that the next
+        row to compute, at ``num_computed_tokens``, can see. Safe while an
+        earlier step that reads them is in flight: whoever writes them
+        next is dispatched after it."""
+        dead = min((seq.num_computed_tokens - (self.window - 1))
+                   // self.cache_config.block_size,
+                   len(seq.window_block_ids))
+        if dead > seq.window_released:
+            self.window_allocator.free_blocks(
+                seq.window_block_ids[seq.window_released:dead])
+            seq.window_released = dead
+
+    def _extend(self, seq: Sequence, target: int) -> bool:
+        """Blocks of both kinds for tokens ``[0, target)``; False where a
+        pool is dry (what was taken stays with the sequence)."""
+        bs = self.cache_config.block_size
+        pools = [(self.allocator, seq.block_ids)]
+        if self.window:
+            self._trim_window(seq)
+            pools.append((self.window_allocator, seq.window_block_ids))
+        for allocator, ids in pools:
+            while len(ids) * bs < target:
+                bid = allocator.append_block()
+                if bid is None:
+                    return False
+                ids.append(bid)
+        return True
 
     def _next_waiting(self) -> Sequence:
         """The sequence the admission loop should try next.
@@ -316,8 +358,14 @@ class Scheduler:
         }
 
     def _try_admit(self) -> None:
+        bs = self.cache_config.block_size
         while self.waiting and self.free_slots:
             seq = self._next_waiting()
+            if self.window and (
+                    self.window_allocator.num_free_blocks * bs
+                    < min(len(seq.token_ids),
+                          self.config.max_num_batched_tokens)):
+                break  # no window blocks for its first chunk
             got = self.allocator.allocate_sequence(seq.token_ids)
             if got is None:
                 break
@@ -453,6 +501,10 @@ class Scheduler:
                 break
             remaining = seq.prefill_target - seq.num_computed_tokens
             chunk = min(remaining, budget)
+            if self.window and not self._extend(
+                    seq, seq.num_computed_tokens + chunk):
+                continue  # waits for window blocks (the pool's size rules
+                # it out: kv_cache.window_pool_blocks)
             out.prefills.append(
                 ScheduledPrefill(seq, seq.num_computed_tokens, chunk)
             )
@@ -533,6 +585,9 @@ class Scheduler:
             if quota <= 0:
                 continue
             chunk = min(seq.prefill_target - seq.num_computed_tokens, quota)
+            if self.window and not self._extend(
+                    seq, seq.num_computed_tokens + chunk):
+                continue
             out.prefills.append(
                 ScheduledPrefill(seq, seq.num_computed_tokens, chunk)
             )
@@ -598,7 +653,6 @@ class Scheduler:
              and not self._decode_exhausted(s)),
             key=lambda s: s.slot,
         )
-        bs = self.cache_config.block_size
         horizon = self.config.decode_horizon
         survivors = []
         for seq in decodes:
@@ -610,24 +664,18 @@ class Scheduler:
             # near the length cap the table row may have no slack
             target = min(seq.num_computed_tokens + horizon,
                          self.max_model_len)
-            while len(seq.block_ids) * bs < target:
-                bid = self.allocator.append_block()
-                while bid is None:
-                    victim = self._pick_victim(exclude=seq)
-                    if victim is None:
-                        # no one else to evict: preempt this sequence itself
-                        self._preempt(seq)
-                        out.preempted.append(seq)
-                        preempted_self = True
-                        break
-                    self._preempt(victim)
-                    out.preempted.append(victim)
-                    if victim in survivors:
-                        survivors.remove(victim)
-                    bid = self.allocator.append_block()
-                if preempted_self:
+            while not self._extend(seq, target):
+                victim = self._pick_victim(exclude=seq)
+                if victim is None:
+                    # no one else to evict: preempt this sequence itself
+                    self._preempt(seq)
+                    out.preempted.append(seq)
+                    preempted_self = True
                     break
-                seq.block_ids.append(bid)
+                self._preempt(victim)
+                out.preempted.append(victim)
+                if victim in survivors:
+                    survivors.remove(victim)
             if not preempted_self:
                 survivors.append(seq)
         return survivors

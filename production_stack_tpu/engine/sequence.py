@@ -62,6 +62,12 @@ class Sequence:
     output_token_ids: list[int] = dataclasses.field(default_factory=list)
     status: SequenceStatus = SequenceStatus.WAITING
     block_ids: list[int] = dataclasses.field(default_factory=list)
+    # where the model's window binds (ModelConfig.window_binds): the window
+    # layers' blocks, of a pool of their own, by logical index like
+    # block_ids; the first window_released of them were given back (no row
+    # to come can see them) and their entries are stale
+    window_block_ids: list[int] = dataclasses.field(default_factory=list)
+    window_released: int = 0
     num_computed_tokens: int = 0  # tokens whose KV sits in the cache
     num_cached_tokens: int = 0  # prefix-cache hits at admission (for metrics)
     slot: int = -1  # decode slot index, -1 = none

@@ -1938,6 +1938,20 @@ class EngineServer:
                 **recurrent, "prefix_cache": "bypassed",
                 "kv_pool_bytes": (self.engine.runner.num_blocks
                                   * self.engine._kv_bytes_per_block)}
+        window = getattr(self.engine, "window_counters", None)
+        if window is not None:
+            # a window that binds: the window layers' pool of blocks beside
+            # the other one, what their walks stream and what they would
+            # without a window, the cross-attention layers' calls
+            model = self.engine.config.model
+            snap["window_pool"] = {
+                **window.snapshot(self.engine.scheduler.window_allocator),
+                "sliding_window": model.sliding_window,
+                "window_kv_bytes_per_token": model.window_kv_bytes_per_token,
+                "window_pool_bytes": (
+                    self.engine.runner.window_blocks
+                    * self.engine.config.cache.block_size
+                    * model.window_kv_bytes_per_token)}
         snap["tenants"] = self.engine.tenant_stats()
         snap["fingerprint"] = self._perf_fingerprint()
         snap["startup_seconds"] = {

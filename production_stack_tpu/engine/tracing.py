@@ -586,8 +586,13 @@ class RecurrentCounters:
     rows at a time, the last one masked: ``chunk_block_rows`` counts the
     rows of the blocks it runs, padding included."""
 
-    def __init__(self, kda_layers: int, state_bytes: int):
+    def __init__(self, kda_layers: int, state_bytes: int,
+                 kind: str = "kda"):
+        """``kind``: "kda", or "mamba" for a stack of state-space layers,
+        whose span kernel takes rows one by one (no blocks to count) and
+        whose counters carry that name."""
         self.kda_layers, self.state_bytes = kda_layers, state_bytes
+        self.kind = kind
         self.decode_calls = 0   # decode dispatches x iterations x layers
         self.chunk_tokens = 0   # rows the span scan carried, a layer
         self.chunk_spans = 0    # spans it carried (state loaded, stored)
@@ -610,13 +615,66 @@ class RecurrentCounters:
     def snapshot(self, lookups_bypassed: int) -> dict:
         """``lookups_bypassed``: prefix lookups answered "miss", which the
         block allocator counts."""
-        return {"kda_decode_calls_total": self.decode_calls,
-                "kda_chunk_tokens_total": self.chunk_tokens,
-                "kda_chunk_spans_total": self.chunk_spans,
-                "kda_chunk_block_rows_total": self.chunk_block_rows,
+        blocks = ({"kda_chunk_block_rows_total": self.chunk_block_rows}
+                  if self.kind == "kda" else {})
+        return {f"{self.kind}_decode_calls_total": self.decode_calls,
+                f"{self.kind}_chunk_tokens_total": self.chunk_tokens,
+                f"{self.kind}_chunk_spans_total": self.chunk_spans,
+                **blocks,
                 "recurrent_state_resets_total": self.state_resets,
                 "recurrent_state_bytes": self.state_bytes,
                 "prefix_lookups_bypassed_total": lookups_bypassed}
+
+
+# -- a window that binds ------------------------------------------------------
+
+class WindowCounters:
+    """What the window layers' attention calls stream beside what they
+    would stream without a window, and the cross-attention layers' calls
+    (which read another layer's cache rows), always on: plain numbers the
+    engine thread adds up where it builds a dispatch, from the span offsets
+    and context lengths it already holds. In tokens of context, each once
+    a call: a decode step's call streams a live slot's blocks from the one
+    that holds its floor (``ctx - window``) to its last, a ragged step's
+    walks the context windows ``count_windows`` counts, with the floor and
+    without. Both already times the window layers."""
+
+    def __init__(self, cfg, block_size: int):
+        from production_stack_tpu.ops.ragged_paged_attention_pallas import (
+            WINDOWS,
+        )
+
+        self.window, self.bs = cfg.sliding_window, block_size
+        self.kv_bytes_per_token = cfg.kv_bytes_per_token
+        self.window_layers = cfg.count_layers("swa")
+        self.cross_layers = cfg.count_layers("cross")
+        self.win_tokens = WINDOWS * block_size
+        self.context_tokens = 0  # streamed if no window bound
+        self.read_tokens = 0     # streamed
+        self.shared_kv_calls = 0
+
+    def record_decode(self, context_lens, iterations: int) -> None:
+        ctx = np.asarray(context_lens, np.int64)
+        end = -(-ctx // self.bs) * self.bs
+        floor = np.maximum(ctx - self.window, 0) // self.bs * self.bs
+        n = iterations * self.window_layers
+        self.context_tokens += n * int(end.sum())
+        self.read_tokens += n * int((end - floor).sum())
+        self.shared_kv_calls += iterations * self.cross_layers
+
+    def record_ragged(self, windows: int, windows_read: int) -> None:
+        n = self.window_layers * self.win_tokens
+        self.context_tokens += n * windows
+        self.read_tokens += n * windows_read
+        self.shared_kv_calls += self.cross_layers
+
+    def snapshot(self, allocator) -> dict:
+        return {"window_attn_context_tokens_total": self.context_tokens,
+                "window_attn_read_tokens_total": self.read_tokens,
+                "shared_kv_attn_calls_total": self.shared_kv_calls,
+                "kv_bytes_per_token": self.kv_bytes_per_token,
+                "window_kv_blocks_total": allocator.num_blocks,
+                "window_kv_blocks_free": allocator.num_free_blocks}
 
 
 # -- latent attention ---------------------------------------------------------

@@ -327,10 +327,11 @@ def _check_ouro_names(cfg: ModelConfig, index: dict) -> None:
 
 
 def load_safetensors(cfg: ModelConfig, mesh: Mesh, rules: ShardingRules) -> dict:
-    if cfg.architecture in ("solar_open2", "pangu_ultra_moe"):
+    if cfg.architecture in ("solar_open2", "pangu_ultra_moe", "phi4flash"):
         # the published tensor names (the KDA layers' conv, decay and gate
         # tensors, the router's bias; the latent paths' projections and
-        # norms) are not known here and there is no
+        # norms; the state-space layers' and the fused attention and MLP
+        # tensors) are not known here and there is no
         # network to read them from: a guessed map would load silently
         # wrong or die mid-load, so a checkpoint is refused up front
         raise ValueError(

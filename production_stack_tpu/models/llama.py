@@ -35,9 +35,10 @@ from production_stack_tpu.engine.quant import (
     quant_einsum,
     ragged_quant_dot,
 )
+from production_stack_tpu.models import sambay
 from production_stack_tpu.ops import kda
 from production_stack_tpu.ops.attention import dense_causal_attention
-from production_stack_tpu.ops.norms import rms_norm
+from production_stack_tpu.ops.norms import layer_norm, rms_norm
 from production_stack_tpu.ops.rope import apply_rope
 from production_stack_tpu.parallel import shardings as lax_names
 
@@ -158,7 +159,14 @@ def param_specs(cfg: ModelConfig) -> dict:
             }
         )
     mixers = {}
-    if cfg.has_recurrent_state:
+    if cfg.mamba_period:
+        # every block's norms (LayerNorm: a bias each) and MLP stay in
+        # "layers"; the mixers are stacked by kind (models/sambay.py)
+        layer = {k: layer[k] for k in ("attn_norm", "mlp_norm")}
+        layer.update({"attn_norm_b": (L.LAYERS, L.EMBED),
+                      "mlp_norm_b": (L.LAYERS, L.EMBED)})
+        mixers = sambay.param_specs(cfg)
+    elif cfg.has_recurrent_state:
         # the token mixers differ by layer kind and are stacked by kind:
         # "gqa" over the periods, "kda" over the KDA layers; what every
         # layer has (norms, the sparse block) stays in "layers"
@@ -217,6 +225,8 @@ def param_specs(cfg: ModelConfig) -> dict:
         **mixers,
         "final_norm": (L.EMBED,),
     }
+    if cfg.layer_norm:
+        specs["final_norm_b"] = (L.EMBED,)
     if not cfg.tie_word_embeddings:
         specs["lm_head"] = (L.EMBED, L.VOCAB)
     return specs
@@ -347,7 +357,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     def normal(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32) * (fan_in**-0.5)).astype(dt)
 
-    # a hybrid stack's stand-in: see HYBRID_INIT
+    # a hybrid stack's stand-in: see HYBRID_INIT (a SambaY stack's:
+    # SAMBAY_INIT)
     hybrid = cfg.has_recurrent_state
     out = 2 * LN if hybrid else 1  # x the fan-in of what writes the stream
 
@@ -390,7 +401,16 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             }
         )
     mixers = {}
-    if cfg.has_recurrent_state:
+    if cfg.mamba_period:
+        layers = {k: layers[k] for k in ("attn_norm", "mlp_norm")}
+        layers.update({"attn_norm_b": jnp.zeros((Ln, E), dt),
+                       "mlp_norm_b": jnp.zeros((Ln, E), dt)})
+        mixers = sambay.init_params(cfg, keys[13], normal, out,
+                                    (KDA_DT_MIN, KDA_DT_MAX))
+        # SAMBAY_INIT: block 0 writes at the usual size
+        mixers["mamba"]["w_out"] = _first_unscaled(
+            mixers["mamba"]["w_out"], out)
+    elif cfg.has_recurrent_state:
         Pn = cfg.num_attn_layers
         mixers["gqa"] = {k: layers.pop(k)[:Pn]
                          for k in ("wq", "wk", "wv", "wo")}
@@ -427,15 +447,20 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             {
                 "w_gate": normal(keys[5], (Ln, E, F), E),
                 "w_up": normal(keys[6], (Ln, E, F), E),
-                "w_down": normal(keys[7], (Ln, F, E), F),
+                "w_down": normal(keys[7], (Ln, F, E), F * out),
             }
         )
+        if cfg.mamba_period:
+            layers["w_down"] = _first_unscaled(layers["w_down"], out)
     params = {
-        "embed": normal(keys[8], (V, E), 1 if hybrid else E),
+        "embed": normal(keys[8], (V, E),
+                        1 if hybrid and not cfg.mamba_period else E),
         "layers": layers,
         **mixers,
         "final_norm": jnp.full((E,), norm_one, dt),
     }
+    if cfg.layer_norm:
+        params["final_norm_b"] = jnp.zeros((E,), dt)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = normal(keys[9], (E, V), E)
     return params
@@ -470,6 +495,32 @@ KDA_DT_MIN, KDA_DT_MAX = 1e-3, 1e-1
 # layers) of the usual size, the residual scaling GPT-2 initialises with.
 # At width 512 that reads 0.04-0.05 / 0.008. Every other family keeps the
 # shared values: its programs and its cells are what they were.
+
+
+# SAMBAY_INIT. Random stand-in weights of a SambaY stack, whose head is
+# TIED: HYBRID_INIT's unit-RMS embedding would, as the head, score the
+# final hidden row (which that embedding dominates) at sqrt(E) for the
+# input token against ~1 for every other, so the stand-in would repeat its
+# input whatever its layers compute and no fault in a layer would read in
+# the log-probabilities (a state not carried across a chunk read 2e-4 on
+# the CPU; the chip's probe 0.161 / 0.077). With the shared stack's values
+# instead (embedding rows of RMS hidden^-1/2, every sublayer's output as
+# large as the stream it joins) 64 sublayers in a row amplified bf16
+# rounding: 0.161 / 0.039 on the chip against limits of 0.15 / 0.03, and
+# 0.18-0.21 / 0.046-0.050 on the CPU at width 512, a float32 residual
+# stream or none (PERF.md section 6, PR 45). So the stream's scale is set
+# by BLOCK 0: its two writers (the Mamba W_out, the MLP's W_down) keep the
+# usual size and bury the small embedding at once, and every later writer
+# has HYBRID_INIT's 1 / sqrt(2 x layers): each later sublayer then adds
+# ~7 % to the stream, as a trained stack's do, and nothing of the stream
+# points at the input token's embedding row. At width 512 that reads
+# 0.041 / 0.011.
+
+def _first_unscaled(w: jnp.ndarray, out: int) -> jnp.ndarray:
+    """A stack of writing matrices drawn at ``out`` x the fan-in, layer 0
+    brought back to the fan-in itself."""
+    scale = jnp.ones((w.shape[0],), jnp.float32).at[0].set(out ** 0.5)
+    return (w.astype(jnp.float32) * scale[:, None, None]).astype(w.dtype)
 
 
 def _init_kda(cfg: ModelConfig, key: jax.Array, normal, out: int) -> dict:
@@ -738,8 +789,11 @@ def forward_hidden(
     if cfg.residual_f32:
         x = x.astype(jnp.float32)
 
-    def pre_norm(h, weight):
-        normed = rms_norm(h, weight, cfg.rms_norm_eps, cfg.norm_offset)
+    def pre_norm(h, weight, bias=None):
+        if cfg.layer_norm:
+            normed = layer_norm(h, weight, bias, cfg.rms_norm_eps)
+        else:
+            normed = rms_norm(h, weight, cfg.rms_norm_eps, cfg.norm_offset)
         # a float32 stream feeds the matmuls in the model dtype
         return normed.astype(cfg.jax_dtype) if cfg.residual_f32 else normed
 
@@ -749,7 +803,9 @@ def forward_hidden(
                              "not supported")
         x, new_caches, hists = _forward_hybrid(
             cfg, params, layers, experts, x, attend,
-            recur or _recur_dense, kv_caches, live, pre_norm)
+            recur or (functools.partial(sambay.mamba_dense, cfg)
+                      if cfg.mamba_period else _recur_dense),
+            kv_caches, live, pre_norm)
         out = (x, new_caches)
         if moe_hist:
             out += (hists,)
@@ -978,75 +1034,138 @@ def _kda_mixer(cfg: ModelConfig, kp: dict, x: jnp.ndarray, recur: RecurFn,
 
 def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
                     experts: dict, x, attend: AttendFn,
-                    recur: RecurFn, caches, live, pre_norm):
-    """The hybrid stack: a scan over periods of ``cfg.attn_period``
-    layers, in each the softmax-attention layer first (no positional
-    encoding: order comes from the recurrence), then the KDA layers,
-    every one followed by the sparse block. ``layers`` holds what
-    every layer has, (L, ...); ``params["gqa"]`` the attention mixers,
-    (periods, ...); ``params["kda"]`` the KDA mixers, (KDA layers, ...);
-    ``experts`` the routed experts of all layers, outside the scan (see
-    _moe_mlp). ``caches`` is {"kv": the paged pool of the attention
-    layers alone, "state", "conv": the KDA layers' per-slot state} or
-    None; it rides the scan carry whole. Returns (hidden, caches, routing
-    histograms (L, ...)).
+                    recur, caches, live, pre_norm):
+    """A patterned stack (``cfg.layer_kinds``): one scan over the periods
+    of each run of like periods (``cfg.stack_segments``; a run of one
+    period is not scanned). Solar-Open2 is one run of (gqa, kda, kda, kda):
+    in each period the softmax-attention layer first (no positional
+    encoding: order comes from the recurrence), then the KDA layers, every
+    one followed by the sparse block. A SambaY stack (models/sambay.py) is
+    three: (mamba, swa) periods, one (mamba, full), (gmu, cross) periods,
+    every block followed by the MLP; what the second run hands the third
+    (``m``: the state-space layer's scan output; the full layer's keys and
+    values, which a dense forward's cross layers attend over, a paged one's
+    read from the cache) enters the third's scan as constants.
 
-    The scan runs over the period's index alone and every layer takes its
+    ``layers`` holds what every layer has, (L, ...); ``params[<stack>]``
+    the mixers of a kind, (layers of the kind, ...) (``sambay.STACK_OF``;
+    "gqa" over the periods, "kda" over the KDA layers); ``experts`` the
+    routed experts of all layers, outside the scan (see _moe_mlp).
+    ``caches`` is the stack's whole cache pytree (engine/kv_cache.py:
+    {"kv", "state", "conv"}, and "win" where the window binds) or None; it
+    rides the scan carry whole. ``recur`` is the recurrent layers' stateful
+    call (a RecurFn, or a ``sambay.MambaFn``). An attention layer of a
+    SambaY stack calls ``attend`` with the whole pytree and its ``kind``.
+    Returns (hidden, caches, routing histograms (L, ...) or None).
+
+    A scan runs over the period's index alone and every layer takes its
     own parameters out of the stacks by its absolute index, one dynamic
     slice a layer, which XLA fuses into the matmul that reads it, as it
     does with a scan's own slicing. Scanning over stacks reshaped to
     (periods, layers a period, ...) made the compiler materialise a whole
     period's slice first: 3 x 200 MB of copies a period in the decode
     step, counted by the TPU compiler at the published widths."""
-    period = cfg.attn_period
+    stack_of = {"gqa": "gqa", "kda": "kda", **sambay.STACK_OF}
 
     def at(tree, i):
         return jax.tree.map(
             lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
 
-    def period_fn(carry, p):
+    def period_fn(kinds, l0, before, shared, carry, p):
+        """Period ``p`` of a run of ``kinds`` periods that starts at layer
+        ``l0`` with ``before[stack]`` layers of each stack ahead of it."""
         h, _, caches = carry
         hists = []
-        for j in range(period):
-            lp = at(layers, p * period + j)
-            normed = pre_norm(h, lp["attn_norm"])
-            if j == 0:
-                with jax.named_scope("gqa"):
-                    gp = at(params["gqa"], p)
+        stacks = [stack_of[k] for k in kinds]
+        for j, kind in enumerate(kinds):
+            depth = p * len(kinds) + (l0 + j)
+            lp = at(layers, depth)
+            normed = pre_norm(h, lp["attn_norm"], lp.get("attn_norm_b"))
+            with jax.named_scope(kind):
+                # this layer's place in its kind's stack: the period's own
+                # layers of the kind, behind those of the periods before
+                i, first = stacks.count(stacks[j]), (
+                    before[stacks[j]] + stacks[:j].count(stacks[j]))
+                i = p if (i, first) == (1, 0) else p * i + first
+                if kind == "gqa":
+                    gp = at(params["gqa"], i)
                     q = quant_einsum("...te,ef->...tf", normed, gp["wq"])
                     q = q.reshape(*q.shape[:-1], cfg.num_heads, cfg.head_dim)
                     k = quant_einsum("...te,ehd->...thd", normed, gp["wk"])
                     v = quant_einsum("...te,ehd->...thd", normed, gp["wv"])
                     kv = None if caches is None else caches["kv"]
-                    attn, kv = attend(q, k, v, kv, p)
+                    attn, kv = attend(q, k, v, kv, i)
                     if caches is not None:
                         caches = {**caches, "kv": kv}
                     if cfg.attn_gate:
                         attn = _gated(attn, normed, gp["wg"])
                     o = quant_einsum("...thd,hde->...te", attn, gp["wo"])
-            else:
-                with jax.named_scope("kda"):
-                    k_idx = p * (period - 1) + (j - 1)
-                    o, caches = _kda_mixer(cfg, at(params["kda"], k_idx),
-                                           normed, recur, caches, k_idx)
+                elif kind == "kda":
+                    o, caches = _kda_mixer(cfg, at(params["kda"], i),
+                                           normed, recur, caches, i)
+                elif kind == "mamba":
+                    o, y, caches = sambay.mamba_mixer(
+                        cfg, at(params["mamba"], i), normed, recur, caches, i)
+                    if "full" in kinds:  # the layer the memory units read
+                        shared["m"] = y
+                elif kind == "gmu":
+                    o = sambay.gmu_mixer(at(params["gmu"], i), normed,
+                                         shared["m"])
+                else:  # "swa", "full", "cross": differential attention
+                    ap = at(params[stacks[j]], i)
+                    q = sambay.packed_queries(cfg, ap, normed)
+                    if kind == "cross":
+                        k, v = shared["kv"]
+                    else:
+                        k, v = sambay.packed_keys_values(cfg, ap, normed)
+                    if kind == "full":
+                        shared["kv"] = (k, v)
+                    # a window layer's place in its pool; the other pool
+                    # holds the one full layer
+                    attn, caches = attend(q, k, v, caches,
+                                          i if kind == "swa" else 0,
+                                          kind=kind)
+                    o = sambay.diff_combine(cfg, ap, attn, depth)
             h = h + o
-            with jax.named_scope("moe"):
-                mlp_out, hist = _sparse_block(
-                    cfg, lp, experts, p * period + j,
-                    pre_norm(h, lp["mlp_norm"]), live)
+            if cfg.is_moe:
+                with jax.named_scope("moe"):
+                    mlp_out, hist = _sparse_block(
+                        cfg, lp, experts, p * len(kinds) + (l0 + j),
+                        pre_norm(h, lp["mlp_norm"]), live)
+                hists.append(hist)
+            else:
+                mlp_out = _mlp(cfg, lp, pre_norm(h, lp["mlp_norm"],
+                                                 lp.get("mlp_norm_b")))
             h = h + mlp_out
-            hists.append(hist)
-        return (h, p + 1, caches), jnp.stack(hists)
+        return (h, p + 1, caches), (jnp.stack(hists) if hists else None)
 
-    (x, _, caches), hists = lax.scan(
-        period_fn, (x, jnp.int32(0), caches),
-        jnp.arange(cfg.num_attn_layers, dtype=jnp.int32))
+    l0, before, shared, hists = 0, dict.fromkeys(stack_of.values(), 0), {}, []
+    for kinds, count in cfg.stack_segments:
+        run = functools.partial(period_fn, kinds, l0, dict(before), shared)
+        if count == 1:
+            (x, _, caches), hist = run((x, 0, caches), 0)
+            hist = None if hist is None else hist[None]
+        else:
+            (x, _, caches), hist = lax.scan(
+                run, (x, jnp.int32(0), caches),
+                jnp.arange(count, dtype=jnp.int32))
+        hists.append(hist)
+        l0 += count * len(kinds)
+        for k in kinds:
+            before[stack_of[k]] += count
+    if not cfg.is_moe:
+        return x, caches, None
+    hists = hists[0] if len(hists) == 1 else jnp.concatenate(hists)
     return x, caches, hists.reshape(-1, hists.shape[-1])
 
 
 def logits_from_hidden(cfg: ModelConfig, params: dict, hidden: jnp.ndarray) -> jnp.ndarray:
-    hidden = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps,
-                      cfg.norm_offset)
+    if cfg.layer_norm:
+        hidden = layer_norm(hidden, params["final_norm"],
+                            params["final_norm_b"], cfg.rms_norm_eps)
+    else:
+        hidden = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps,
+                          cfg.norm_offset)
     if cfg.residual_f32:
         hidden = hidden.astype(cfg.jax_dtype)
     head = (head_from_embed(params["embed"]) if cfg.tie_word_embeddings
@@ -1060,6 +1179,18 @@ def logits_from_hidden(cfg: ModelConfig, params: dict, hidden: jnp.ndarray) -> j
     return logits
 
 
+def dense_attend(cfg: ModelConfig) -> AttendFn:
+    """A dense forward's attention call, for any family: causal over the
+    rows given, within the window for a "swa" layer of a stack whose
+    window binds (``kind``: models/sambay.py)."""
+    def attend(q, k, v, caches, layer_idx, kind=None):
+        return dense_causal_attention(
+            q, k, v, soft_cap=cfg.attn_logit_softcap,
+            window=cfg.sliding_window if kind == "swa" else 0), caches
+
+    return attend
+
+
 def forward_dense(
     cfg: ModelConfig,
     params: dict,
@@ -1071,10 +1202,6 @@ def forward_dense(
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
 
-    def attend(q, k, v, caches, layer_idx):
-        return dense_causal_attention(
-            q, k, v, soft_cap=cfg.attn_logit_softcap
-        ), caches
-
-    hidden, _ = forward_tokens(cfg, params, tokens, positions, attend, None)
+    hidden, _ = forward_tokens(cfg, params, tokens, positions,
+                               dense_attend(cfg), None)
     return logits_from_hidden(cfg, params, hidden)

@@ -42,6 +42,13 @@ _REGISTRY: dict[str, ModuleType] = {
     # published widths: chipbench cell
     # openpangu-ultra-moe-718b-ep16-l5.long-prompt (PERF.md, PR 43)
     "pangu_ultra_moe": llama,
+    # Phi-4-mini-flash-reasoning (SambaY): the shared file's walker of
+    # patterned stacks (cfg.mamba_period > 0) over models/sambay.py's
+    # mixers: state-space layers, differential attention within a window
+    # that binds, one full-attention cache that the cross-attention layers
+    # read, gated memory units; LayerNorm, a tied head. Served uncut on one
+    # chip: chipbench cell phi-4-mini-flash-reasoning.long-decode (PR 45)
+    "phi4flash": llama,
     # encoder-decoder audio transcription: exposes its own forward
     # surface (encode/cross_kv/decode_tokens) instead of the decoder-only
     # protocol; shares param_specs/init_params so weights.py works

@@ -25,6 +25,7 @@ def dense_causal_attention(
     v: jnp.ndarray,
     scale: float | None = None,
     soft_cap: float = 0.0,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Causal multi-head attention with grouped KV (GQA).
 
@@ -32,7 +33,7 @@ def dense_causal_attention(
     A value may be narrower than its key, (B, S, KH, Dv) (latent attention:
     the rows' latent part); the result is then (B, S, H, Dv).
     ``soft_cap`` > 0 applies Gemma-2-style score capping cap*tanh(s/cap)
-    before masking.
+    before masking. ``window`` > 0: row t sees rows t - window + 1 .. t.
     """
     B, S, H, D = q.shape
     KH = k.shape[2]
@@ -46,6 +47,8 @@ def dense_causal_attention(
     if soft_cap:
         scores = soft_cap * jnp.tanh(scores / soft_cap)
     causal = jnp.tril(jnp.ones((S, S), dtype=bool))
+    if window:
+        causal &= ~jnp.tril(jnp.ones((S, S), dtype=bool), -window)
     scores = jnp.where(causal[None, None, None], scores, NEG_INF)
     probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
     probs = probs / probs.sum(axis=-1, keepdims=True)

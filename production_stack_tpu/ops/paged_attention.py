@@ -82,6 +82,7 @@ def paged_attention(
     tp: int = 1,
     scale: float | None = None,
     soft_cap: float = 0.0,
+    window: int = 0,
 ) -> jnp.ndarray:
     B, S, H, D = q.shape
     n, block_size, KH2, _ = kv_layer.shape
@@ -97,6 +98,8 @@ def paged_attention(
     kv_pos = jnp.arange(M * block_size, dtype=jnp.int32)[None, :]  # (1, Tc)
     valid_kv = kv_pos < context_lens[:, None]  # (B, Tc)
     causal = kv_pos[:, None, :] <= q_positions[:, :, None]  # (B, S, Tc)
+    if window:  # row t sees rows t - window + 1 .. t
+        causal &= kv_pos[:, None, :] > q_positions[:, :, None] - window
     valid_q = q_positions >= 0  # (B, S)
     mask = valid_kv[:, None, :] & causal & valid_q[:, :, None]
 
@@ -124,6 +127,7 @@ def ragged_paged_attention(
     tp: int = 1,
     scale: float | None = None,
     soft_cap: float = 0.0,
+    window: int = 0,
 ) -> jnp.ndarray:
     """XLA reference for the ragged kernel: the packed mixed
     prefill+decode stream — including speculative verify spans, which are
@@ -152,6 +156,9 @@ def ragged_paged_attention(
     kv_pos = jnp.arange(M * block_size, dtype=jnp.int32)[None, :]  # (1, Tc)
     valid_kv = kv_pos < context_lens[sid][:, None]  # (T, Tc)
     causal = kv_pos <= q_positions[:, None]  # (T, Tc)
+    if window:  # row t sees rows t - window + 1 .. t; blocks wholly below
+        # may have been given back (engine/scheduler.py): masked, not read
+        causal &= kv_pos > q_positions[:, None] - window
     mask = valid_kv & causal & (q_positions >= 0)[:, None]
 
     qg = q.reshape(T, KH, G, D)

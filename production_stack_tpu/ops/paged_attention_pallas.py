@@ -70,10 +70,13 @@ def _flash_weights(m, l, sc):
     return m_new, alpha, p, l_new
 
 
-def _head_window(q_ref, buf, slot, w, *, W, win_tokens, scale, soft_cap):
+def _head_window(q_ref, buf, slot, w, *, W, win_tokens, scale, soft_cap,
+                 window=0):
     """A landed window's flash update ``(s, ctx, (m, l, acc)) -> (m, l,
     acc)`` for one of its sequences, KV head by KV head: q (KH, G, D)
-    against each head's (T, D) slice of the slab."""
+    against each head's (T, D) slice of the slab. With a sliding
+    ``window`` the keys below ``ctx - window`` are masked (the query sits
+    at ``ctx - 1``), and their blocks were not fetched."""
     KH = q_ref.shape[1]
     kvpos = w * win_tokens + jax.lax.broadcasted_iota(
         jnp.int32, (1, 1, win_tokens), 2
@@ -97,15 +100,21 @@ def _head_window(q_ref, buf, slot, w, *, W, win_tokens, scale, soft_cap):
         sc = jnp.stack(s_heads) * scale  # (KH, G, T)
         if soft_cap:  # Gemma-2 score capping, before masking
             sc = soft_cap * jnp.tanh(sc / soft_cap)
-        sc = jnp.where(kvpos < ctx, sc, NEG_INF)
+        seen = kvpos < ctx
+        if window:
+            seen &= kvpos >= ctx - window
+        sc = jnp.where(seen, sc, NEG_INF)
 
         m_new, alpha, p, l_new = _flash_weights(m, l, sc)
         # per-block DMA predication leaves tail blocks UNWRITTEN: their
         # V rows can be NaN/Inf, and the PV contraction sums p*v over
         # ALL T — 0 x NaN = NaN, so masked weights alone don't protect
         # the accumulator. Zero the invalid V rows explicitly.
-        vvalid = (w * win_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, (win_tokens, 1), 0) < ctx)
+        vpos = w * win_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, (win_tokens, 1), 0)
+        vvalid = vpos < ctx
+        if window:
+            vvalid &= vpos >= ctx - window
         acc_heads = []
         for h in range(KH):
             v_h = jnp.where(
@@ -206,6 +215,7 @@ def _decode_kernel(
     scale: float,
     soft_cap: float = 0.0,
     slab: bool = False,
+    window: int = 0,
 ):
     """Batched paged decode attention.
 
@@ -220,7 +230,12 @@ def _decode_kernel(
     The DMA walk, the flash carry and the epilogue are one; what a landed
     window computes is ``_slab_window`` where ``slab`` (the wrapper's
     ``decode_slab_path``; q and o then come as (SPB, KH, D)) and
-    ``_head_window`` otherwise."""
+    ``_head_window`` otherwise.
+
+    With a sliding ``window`` (the head-by-head body only) a sequence's
+    walk has a floor, ``ctx - window``: it starts at the context window
+    that holds the floor (each of a cell's sequences at its own), and a
+    block wholly below the floor is neither fetched nor scored."""
     cell = pl.program_id(0)
     layer = layer_ref[0]
     SPB = seqs_per_cell
@@ -234,8 +249,29 @@ def _decode_kernel(
     for s in range(1, SPB):
         nwin = jnp.maximum(nwin, pl.cdiv(cl_ref[base + s], win_tokens))
 
+    def at(s, w):
+        """The context window sequence ``s`` takes at step ``w`` of the
+        cell's walk: ``w`` itself, or with a sliding ``window`` its own
+        first one (the one that holds its floor) plus ``w``, so that a
+        cell's sequences of different lengths all walk their last
+        ``window`` rows in the same few steps."""
+        return w
+
+    if window:
+        def floor(s):
+            return jnp.maximum(cl_ref[base + s] - window, 0)
+
+        def at(s, w):  # noqa: F811
+            return floor(s) // win_tokens + w
+
+        # the cell's steps: its longest walk (a dead slot's is empty)
+        nwin = pl.cdiv(cl_ref[base], win_tokens) - at(0, 0)
+        for s in range(1, SPB):
+            nwin = jnp.maximum(
+                nwin, pl.cdiv(cl_ref[base + s], win_tokens) - at(s, 0))
+
     def dma(slot, s, w, j):
-        bid = bt_ref[base + s, w * W + j]
+        bid = bt_ref[base + s, at(s, w) * W + j]
         return pltpu.make_async_copy(
             kv_hbm.at[layer, bid], buf.at[slot, s, j], sems.at[slot, s, j]
         )
@@ -248,10 +284,13 @@ def _decode_kernel(
     # skipped traffic is pure win. wait() uses the same predicate so waits
     # match issues exactly.
     def seq_active(s, w):
-        return w * win_tokens < cl_ref[base + s]
+        return at(s, w) * win_tokens < cl_ref[base + s]
 
     def block_active(s, w, j):
-        return w * win_tokens + j * bs < cl_ref[base + s]
+        active = at(s, w) * win_tokens + j * bs < cl_ref[base + s]
+        if window:  # a block wholly below the floor is not fetched
+            active &= at(s, w) * win_tokens + (j + 1) * bs > floor(s)
+        return active
 
     def issue(slot, w):
         for s in range(SPB):
@@ -264,9 +303,10 @@ def _decode_kernel(
     def _():
         issue(0, 0)
 
-    window = functools.partial(
+    landed = functools.partial(
         _slab_window if slab else _head_window, q_ref, buf,
-        W=W, win_tokens=win_tokens, scale=scale, soft_cap=soft_cap)
+        W=W, win_tokens=win_tokens, scale=scale, soft_cap=soft_cap,
+        **({"window": window} if window else {}))
 
     # per-seq tensors stay <=3D throughout (Mosaic's layout inference
     # rejects middle-dim squeezes/merges on 4D); the flash state is a flat
@@ -284,10 +324,12 @@ def _decode_kernel(
                 def _():
                     dma(slot, s, w, j).wait()
 
-        update = window(slot, w)
+        update = landed(slot, w)
         out = []
         for s in range(SPB):
             old = carry[3 * s : 3 * s + 3]
+            if window:  # the sequence's own window of this step
+                update = landed(slot, at(s, w))
             new = update(s, cl_ref[base + s], old)
             # a seq inactive this window skipped its DMAs: buf holds
             # unwritten bits that can be NaN/Inf, and 0 x NaN = NaN — keep
@@ -337,12 +379,13 @@ def paged_decode_attention_pallas(
     windows: int = 8,
     interpret: bool = False,
     soft_cap: float = 0.0,
+    window: int = 0,  # the query sees the last ``window`` rows; 0 = all
 ) -> jnp.ndarray:
     B, H, D = q.shape
     L, N, bs, KH2, _ = kv_cache.shape
     KH = KH2 // 2
     G = H // KH
-    slab = decode_slab_path(KH, G, D, kv_cache.dtype)
+    slab = not window and decode_slab_path(KH, G, D, kv_cache.dtype)
     # q heads are shard-grouped like the cache: here a single shard's view,
     # heads ordered [h0..h_{KH-1}] matching [K_0..K_{KH-1}] halves; on the
     # slab path (G = 1) the heads are the rows of one (KH, D) tile
@@ -370,6 +413,7 @@ def paged_decode_attention_pallas(
     kernel = functools.partial(
         _decode_kernel, block_size=bs, windows=windows, seqs_per_cell=spb,
         scale=D**-0.5, soft_cap=soft_cap, slab=slab,
+        **({"window": window} if window else {}),
     )
     out = pl.pallas_call(
         kernel,

@@ -42,6 +42,15 @@ register width. The one or two windows left (the causal diagonal, the
 context's tail) take the masked body, as every walk of a narrow block or
 of a tile shared between spans does.
 
+A layer with a sliding ``window`` (row t sees rows t - window + 1 .. t;
+0 = none, and the traced program is then what it was) gives every walk a
+floor beside its causal reach: the walk starts at the context window that
+holds the floor of its first row, blocks wholly below the floor are neither
+fetched nor scored (the engine may have given them back: engine/
+scheduler.py), and keys below a row's own floor are masked. A windowed
+walk takes the masked body throughout (the interior body assumes that
+nothing can be masked and that it runs first).
+
 There are no padding lanes between spans and no shape buckets: the only
 compile-relevant shape is the budget-padded ``T`` (tokens the scheduler
 may batch) and the fixed ``S`` slot count, so the steady-state engine
@@ -153,12 +162,15 @@ def count_walks(cu_q_lens, stream_tokens: int, group: int,
 
 def count_windows(cu_q_lens, context_lens, stream_tokens: int, group: int,
                   block_size: int, q_tile: int | None = None,
-                  windows: int = WINDOWS) -> tuple[int, int]:
+                  windows: int = WINDOWS, window: int = 0
+                  ) -> tuple[int, int]:
     """(windows, interior windows) of one dispatch, on the host: the
     context windows of ``windows * block_size`` tokens that the kernel's
     walks stream up to each walk's causal reach, and those among them that
     a full-tile walk runs through its interior body
-    (``interior_windows``, the kernel's own predicate)."""
+    (``interior_windows``, the kernel's own predicate). With a sliding
+    ``window`` a walk starts at the context window that holds its first
+    row's floor and has no interior."""
     tq, span, tile, lo, hi, start = _walk_offsets(
         cu_q_lens, stream_tokens, group, q_tile)
     cu = np.asarray(cu_q_lens, np.int64)
@@ -168,6 +180,9 @@ def count_windows(cu_q_lens, context_lens, stream_tokens: int, group: int,
     win_tokens = windows * block_size
     at = tile * tq - start  # tile-relative 0 as an offset into the span
     nwin = -(-(pos0 + at + hi) // win_tokens)  # reach = last row's + 1
+    if window:
+        floor = np.maximum(pos0 + at + lo - (window - 1), 0)
+        return int(np.sum(nwin - floor // win_tokens)), 0
     interior = np.minimum(
         interior_windows(lo, hi, tq, pos0 + at, win_tokens, xp=np), nwin)
     return int(np.sum(nwin)), int(np.sum(interior))
@@ -232,6 +247,7 @@ def _ragged_kernel(
     group: int,
     scale: float,
     soft_cap: float = 0.0,
+    window: int = 0,
 ):
     t = pl.program_id(0)
     layer = layer_ref[0]
@@ -268,6 +284,14 @@ def _ragged_kernel(
         # whole context walk
         reach = jnp.where(q_len > 0, reach, 0)
         nwin = pl.cdiv(reach, win_tokens)
+        w0 = 0
+        if window:
+            # the floor of the span's FIRST row in this tile: no row of
+            # the walk sees a key below it
+            first_g = jnp.maximum(q_start, tile0)
+            floor = jnp.maximum(
+                ctx - q_len + (first_g - q_start) - (window - 1), 0)
+            w0 = floor // win_tokens
 
         def dma(slot, w, j):
             bid = bt_ref[s, w * W + j]
@@ -276,7 +300,10 @@ def _ragged_kernel(
             )
 
         def block_active(w, j):
-            return w * win_tokens + j * bs < reach
+            active = w * win_tokens + j * bs < reach
+            if window:  # a block wholly below the floor is not fetched
+                active &= w * win_tokens + (j + 1) * bs > floor
+            return active
 
         def issue(slot, w):
             for j in range(W):
@@ -299,7 +326,7 @@ def _ragged_kernel(
             # elsewhere (masked by row_in)
             qpos = ctx - q_len + (g_idx - q_start)
 
-            issue(0, 0)
+            issue(jax.lax.rem(w0, 2) if window else 0, w0)
 
             def win_body(w, _):
                 m, l = m_ref[:, rs, 0:1], l_ref[:, rs, 0:1]
@@ -331,6 +358,8 @@ def _ragged_kernel(
                     jnp.int32, (1, 1, win_tokens), 2
                 )
                 valid = row_in & (kvpos <= qpos) & (kvpos < ctx)
+                if window:
+                    valid &= kvpos > qpos - window
                 sc = jnp.where(valid, sc, NEG_INF)
 
                 m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
@@ -348,8 +377,11 @@ def _ragged_kernel(
                 # blocks past `reach` were never DMA'd: zero their V rows
                 # — 0 x NaN = NaN would poison the accumulator through
                 # masked-out weights
-                vvalid = (w * win_tokens + jax.lax.broadcasted_iota(
-                    jnp.int32, (win_tokens, 1), 0) < reach)
+                vpos = w * win_tokens + jax.lax.broadcasted_iota(
+                    jnp.int32, (win_tokens, 1), 0)
+                vvalid = vpos < reach
+                if window:  # nor were the blocks below the floor
+                    vvalid &= vpos >= floor // bs * bs
                 acc_heads = []
                 for h in range(KH):
                     v_h = jnp.where(
@@ -365,8 +397,8 @@ def _ragged_kernel(
                     acc_ref[:, rs, :] * alpha + jnp.stack(acc_heads))
                 return 0
 
-            n_int = 0
-            if rows == R:
+            n_int = w0
+            if rows == R and not window:
                 def interior_body(w, _):
                     """A window nothing can mask, for the whole tile: every
                     block of it was fetched, every row reaches every key. Head
@@ -484,6 +516,7 @@ def ragged_paged_attention_pallas(
     windows: int = WINDOWS,
     interpret: bool = False,
     soft_cap: float = 0.0,
+    window: int = 0,  # row t sees rows t - window + 1 .. t; 0 = all
 ) -> jnp.ndarray:
     T, H, D = q.shape
     L, N, bs, KH2, _ = kv_cache.shape
@@ -524,6 +557,7 @@ def ragged_paged_attention_pallas(
     kernel = functools.partial(
         _ragged_kernel, block_size=bs, windows=windows, q_tile=TQ,
         group=G, scale=D**-0.5, soft_cap=soft_cap,
+        **({"window": window} if window else {}),
     )
     out = pl.pallas_call(
         kernel,

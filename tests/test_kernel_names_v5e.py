@@ -370,3 +370,122 @@ def test_latent_row_write_keeps_the_pool_in_place_at_whole_lane_tiles(
     temp = compiled.memory_analysis().temp_size_in_bytes
     nbytes = 2 * 5 * 4096 * BS * lanes
     assert (temp < nbytes // 100) if in_place else (temp > nbytes)
+
+
+# -- Phi-4-mini-flash-reasoning on one chip (chipbench
+# phi-4-mini-flash-reasoning.long-decode): the state-space kernels at 9
+# layers x 64 slots of a (16, 5120) float32 state, under the names
+# chipbench/layer_metrics/mamba_*.json match on; both attention kernels and
+# the KV write at the packed geometry (10 head pairs filled up to 12 cache
+# heads of 128, G = 4; ModelConfig.cache_kv_heads), with the 512-row window
+# and without; and what of it needs the manifest's 32 MiB flag ---------------
+
+SSM_STATE = ((9, 64, 16, 5120), jnp.float32)
+
+
+def _mamba_cases():
+    from production_stack_tpu.ops.mamba_pallas import (
+        mamba_decode_step,
+        mamba_ragged,
+    )
+
+    def rows(t):
+        return [((t, 5120), jnp.float32)] * 2 + [((t, 16), jnp.float32)] * 2
+
+    def ragged(width):  # the ragged program's stream widths (PR 42)
+        return (
+            lambda st, a, x, d, b, c, cu, ctx: mamba_ragged(
+                st, 3, a, x, d, b, c, cu, ctx),
+            (SSM_STATE, ((16, 5120), jnp.float32), *rows(width),
+             ((65,), I32), ((64,), I32)))
+
+    return {
+        "mamba_decode_step": (
+            lambda st, a, x, d, b, c, act: mamba_decode_step(
+                st, 3, a, x, d, b, c, act),
+            (SSM_STATE, ((16, 5120), jnp.float32), *rows(64),
+             ((64,), jnp.bool_))),
+        "mamba_chunk_scan": ragged(2048),
+        "mamba_chunk_scan@512": ragged(512),
+    }
+
+
+@pytest.mark.parametrize(
+    "case", ["mamba_chunk_scan", "mamba_chunk_scan@512", "mamba_decode_step"])
+def test_mamba_kernel_is_a_named_custom_call_at_the_cells_shapes(
+        one_chip, case):
+    """Under the DEFAULT scoped-VMEM limit: the decode kernel's blocks fit
+    it and the span kernel sets its own."""
+    fn, shapes = _mamba_cases()[case]
+    name = case.partition("@")[0]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(fn, donate_argnums=0).lower(*args).compile()
+    assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = .*? custom-call\(",
+                     compiled.as_text(), flags=re.M)
+    # the state of all layers (189 MB) is updated in place: no second copy
+    # of it among the program's temporaries (a stream's float32 rows are)
+    assert compiled.memory_analysis().temp_size_in_bytes < 150 * 2 ** 20
+
+
+PACKED_KH, PACKED_G = 12, 4
+PACKED_CACHE = ((8, 2400, BS, 2 * PACKED_KH, D), jnp.bfloat16)
+PACKED_RAGGED_VMEM_MIB = 27.45
+
+
+def _packed_cases(window):
+    h = PACKED_KH * PACKED_G
+    return {
+        "ragged_paged_attention": (
+            lambda q, c, bt, cu, cl: ragged_paged_attention_pallas(
+                q, c, bt, cu, cl, layer_idx=1, window=window),
+            (((2048, h, D), jnp.bfloat16), PACKED_CACHE, ((64, 512), I32),
+             ((65,), I32), ((64,), I32))),
+        "paged_decode_attention": (
+            lambda q, c, bt, cl: paged_decode_attention_pallas(
+                q, c, bt, cl, layer_idx=1, window=window),
+            (((64, h, D), jnp.bfloat16), PACKED_CACHE, ((64, 512), I32),
+             ((64,), I32))),
+        "kv_cache_write": (
+            lambda c, new, sm: kv_cache_write_pallas(c, new, sm, layer_idx=1),
+            (PACKED_CACHE, ((2048, 2 * PACKED_KH, D), jnp.bfloat16),
+             ((2048,), I32))),
+    }
+
+
+@pytest.mark.parametrize("window", [0, 512])
+@pytest.mark.parametrize("name", sorted(_packed_cases(0)))
+def test_attention_kernels_compile_at_the_packed_geometry(one_chip, name,
+                                                          window):
+    """The decode kernel and the KV write fit the default 16 MiB; the
+    ragged kernel asks 27.45 MiB at its 512-row tile of 12 cache heads,
+    window or none: Qwen3's 32 MiB flag, in the manifest's engine_env."""
+    fn, shapes = _packed_cases(window)[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    head = rf"^\s*(?:ROOT )?%{name}[.\d]* = .*? custom-call\("
+    if name != "ragged_paged_attention":
+        assert re.search(head, lowered.compile().as_text(), flags=re.M)
+        return
+    text = lowered.compile(compiler_options={
+        "xla_tpu_scoped_vmem_limit_kib": 32768}).as_text()
+    assert re.search(head, text, flags=re.M)
+    with pytest.raises(Exception, match="Scoped allocation") as refusal:
+        lowered.compile(compiler_options={
+            "xla_tpu_scoped_vmem_limit_kib": 17000})
+    size = re.search(r"Scoped allocation with size ([\d.]+)M",
+                     str(refusal.value))
+    assert size and float(size.group(1)) <= PACKED_RAGGED_VMEM_MIB
+
+
+def test_ten_head_pairs_unpadded_are_refused_by_the_tpu_compiler(one_chip):
+    """Why ``ModelConfig.cache_kv_heads`` fills 10 pairs up to 12: a
+    token's slab of 20 rows is no whole 8-row tile, and the kernels' DMAs
+    of it do not get through Mosaic."""
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((64, 40, D), jnp.bfloat16), ((1, 256, BS, 20, D), jnp.bfloat16),
+        ((64, 512), I32), ((64,), I32))]
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(lambda q, c, bt, cl: paged_decode_attention_pallas(
+            q, c, bt, cl, layer_idx=0)).lower(*args).compile()

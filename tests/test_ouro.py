@@ -389,8 +389,11 @@ def test_from_hf_config_reads_the_catalog_file_as_a_looped_stack():
     named = {k: v for k, v in CATALOG.items() if k != "model_type"}
     named["architectures"] = ["OuroForCausalLM"]
     assert ModelConfig.from_hf_config(named).loop_passes == 4
-    # every other family runs its layers once
-    assert all(c.loop_passes == 1 and c.cache_layers == c.num_layers
+    # every other family runs its layers once (a patterned stack keeps a
+    # cache layer for the layers that own keys and values alone)
+    assert all(c.loop_passes == 1
+               and c.cache_layers == (c.num_attn_layers if c.mamba_period
+                                      else c.num_layers)
                for n, c in MODEL_PRESETS.items() if "ouro" not in n)
 
 
