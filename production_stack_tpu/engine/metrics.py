@@ -320,6 +320,20 @@ class EngineStatsCollector:
                 "vllm:moe_decode_experts_touched",
                 s["moe_decode_layer_steps_total"],
             )
+            yield counter(
+                "vllm:moe_layer_steps",
+                "MoE layers run by the step programs of every kind (layers "
+                "x forwards x dispatches)",
+                s["moe_layer_steps_total"],
+            )
+            yield counter(
+                "vllm:moe_grouped_kernel_layer_steps",
+                "Of vllm:moe_layer_steps, those of programs whose grouped "
+                "matmuls are the Pallas kernel %moe_grouped_matmul and not "
+                "jax.lax.ragged_dot (the runner's choice: TPU, unquantized "
+                "experts, no sharded expert axis)",
+                s["moe_grouped_kernel_layer_steps_total"],
+            )
         # latent attention (engine/tracing.py LatentCounters): exported by
         # models that keep a latent cache only
         if "mla_scored_pairs_total" in s:

@@ -49,7 +49,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from production_stack_tpu.engine.config import EngineConfig, ModelConfig
 from production_stack_tpu.engine import kv_cache as kvmod
-from production_stack_tpu.engine.quant import maybe_quantize
+from production_stack_tpu.engine.quant import is_quantized, maybe_quantize
 from production_stack_tpu.engine.sampling import sample_tokens
 from production_stack_tpu.engine.tracing import (
     LoopCounters,
@@ -63,6 +63,10 @@ from production_stack_tpu.ops.paged_attention import (
     combine_kv,
     paged_attention,
     write_kv,
+)
+from production_stack_tpu.ops.moe_grouped_matmul_pallas import (
+    grouped_kernel_path,
+    moe_grouped_matmul,
 )
 from production_stack_tpu.ops.paged_attention_pallas import decode_slab_path
 from production_stack_tpu.parallel.mesh import AXIS_TENSOR
@@ -202,6 +206,29 @@ def _pallas_ok(cfg: ModelConfig, mesh: Mesh, block_size: int) -> bool:
     return not failed
 
 
+def _moe_grouped_kernel_ok(cfg: ModelConfig, mesh: Mesh, rules,
+                           params: dict) -> bool:
+    """Whether the MoE block's grouped matmuls run the Pallas kernel
+    (ops/moe_grouped_matmul_pallas.py) in this runner's step programs, from
+    what can be observed here: a TPU backend, experts in the model dtype
+    (int8 experts keep ``ragged_dot``'s W8A8), no mesh axis that splits an
+    axis of the expert matrices (GSPMD cannot partition the custom call),
+    and both projections' (K, N) within the kernel's own shape rule."""
+    if not cfg.is_moe or jax.default_backend() != "tpu":
+        return False
+    from production_stack_tpu.parallel import shardings as ln
+
+    w_gate, w_down = (params["layers"][k] for k in ("w_gate", "w_down"))
+    if is_quantized(w_gate):
+        return False
+    split = [a for a in (ln.LAYERS, ln.EXPERTS, ln.EMBED, ln.MLP)
+             if rules.rules.get(a) is not None
+             and mesh.shape[rules.rules[a]] > 1]
+    return not split and all(
+        grouped_kernel_path(*w.shape[-2:], w.dtype.itemsize)
+        for w in (w_gate, w_down))
+
+
 class ModelRunner:
     """Owns params, the KV block pool and the compiled step functions."""
 
@@ -233,13 +260,6 @@ class ModelRunner:
             self._refuse_for_recurrent_state(config, mesh)
         self.rules = rules_for_model(self.cfg, mesh)
         self.model = get_model(self.cfg)
-        # routing counters of an MoE model (engine/tracing.py): the step
-        # programs then return a per-layer routing histogram as their last
-        # result leaf, which is fetched with the step's own results
-        self.moe = (MoeCounters(self.cfg.num_held_experts,
-                                self.cfg.num_experts_per_tok,
-                                share=bool(self.cfg.experts_held))
-                    if self.cfg.is_moe else None)
         # a looped stack's step programs return the passes they made, one
         # int32 a forward, after the histogram: same fetch, same route
         self.loop = (LoopCounters(self.cfg.num_layers)
@@ -255,6 +275,20 @@ class ModelRunner:
                 else init_or_load(self.cfg, mesh, self.rules, config.seed),
             )
         self.use_pallas = _pallas_ok(self.cfg, mesh, config.cache.block_size)
+        # what runs the MoE block's grouped matmuls in every step program
+        # built here: the Pallas kernel, or None for jax.lax.ragged_dot
+        # (vllm:moe_grouped_kernel_layer_steps_total)
+        self.moe_grouped_matmul = (
+            moe_grouped_matmul if _moe_grouped_kernel_ok(
+                self.cfg, mesh, self.rules, self.params) else None)
+        # routing counters of an MoE model (engine/tracing.py): the step
+        # programs then return a per-layer routing histogram as their last
+        # result leaf, which is fetched with the step's own results
+        self.moe = (MoeCounters(
+            self.cfg.num_held_experts, self.cfg.num_experts_per_tok,
+            share=bool(self.cfg.experts_held),
+            grouped_kernel=self.moe_grouped_matmul is not None)
+            if self.cfg.is_moe else None)
         # whether a decode step's attention calls run the Pallas decode
         # kernel's slab body: the kernel's own predicate at this runner's
         # per-shard geometry (vllm:decode_attn_slab_calls_total)
@@ -342,6 +376,7 @@ class ModelRunner:
                 max(config.scheduler.multi_step, 1), self._eos_id,
                 recur_impl=(functools.partial(recur, False)
                             if recurrent else None),
+                grouped_matmul=self.moe_grouped_matmul,
             ),
             donate_argnums=(1,),
             static_argnames=("layout", "block_size", "greedy_only",
@@ -361,7 +396,8 @@ class ModelRunner:
                                self._attend_ragged, self._eos_id,
                                self.spec_width,
                                recur_impl=(functools.partial(recur, True)
-                                           if recurrent else None)),
+                                           if recurrent else None),
+                               grouped_matmul=self.moe_grouped_matmul),
                 donate_argnums=(1,),
                 static_argnames=("layout", "greedy_only", "use_penalties",
                                  "use_controls", "use_grammar"),
@@ -1768,7 +1804,7 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
                        token_counts=None, presence=None, frequency=None,
                        lora_bank=None, adapter_ids=None, ctrl=None,
                        grammar=None, *, layout: StepLayout,
-                       recur_impl=None,
+                       recur_impl=None, grouped_matmul=None,
                        block_size: int, greedy_only: bool = False,
                        use_penalties: bool = False,
                        use_controls: bool = False,
@@ -1817,7 +1853,7 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
             cfg, params, tok[:, None], pos[:, None], attend, kv,
             lora=_make_lora(lora_bank, adapter_ids, 1),
             live=active[:, None], moe_hist=cfg.is_moe,
-            loop_count=cfg.loop_passes > 1,
+            loop_count=cfg.loop_passes > 1, grouped_matmul=grouped_matmul,
             **_recur_kw(recur_impl, active),
         )
         logits = model.logits_from_hidden(cfg, params, hidden)[:, 0]
@@ -1905,7 +1941,7 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
                  packed,
                  token_counts=None, presence=None, frequency=None,
                  lora_bank=None, adapter_ids=None, ctrl=None, grammar=None,
-                 *, layout: StepLayout, recur_impl=None,
+                 *, layout: StepLayout, recur_impl=None, grouped_matmul=None,
                  greedy_only: bool = False,
                  use_penalties: bool = False,
                  use_controls: bool = False,
@@ -1968,6 +2004,7 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
     hidden, new_kv, *moe_hist = model.forward_tokens(
         cfg, params, tokens, positions, attend, kv, lora=lora,
         moe_hist=cfg.is_moe, loop_count=cfg.loop_passes > 1,
+        grouped_matmul=grouped_matmul,
         **_recur_kw(recur_impl, f["cu_q_lens"], f["context_lens"]),
     )
     last_hidden = jnp.take(hidden[0], last_idx, axis=0)  # (S, E)

@@ -183,16 +183,26 @@ def quant_einsum(eq: str, x: jnp.ndarray, w: Any,
 
 
 def ragged_quant_dot(x: jnp.ndarray, w: Any, group_sizes: jnp.ndarray,
-                     group_of_row: jnp.ndarray) -> jnp.ndarray:
-    """Grouped matmul ``jax.lax.ragged_dot(x, w, group_sizes)`` accepting
-    a quantized ``w``: x (M, K) rows sorted by group, w (G, K, N), row i
-    multiplied by the matrix of its group; rows past sum(group_sizes) are
-    left undefined. With a quantized weight it runs W8A8 (rows quantized
-    per row, int8 x int8 -> int32), rescaled by each row's own scale and
-    its group's weight scale (G, 1, N), looked up through
-    ``group_of_row`` (M,), which may point one past the last group for
-    the undefined rows."""
+                     group_of_row: jnp.ndarray,
+                     grouped_matmul=None) -> jnp.ndarray:
+    """The MoE block's grouped matmul, accepting a quantized ``w``: x
+    (M, K) rows sorted by group, w (G, K, N), row i multiplied by the
+    matrix of its group; rows past sum(group_sizes) are left undefined.
+
+    ``grouped_matmul`` is what runs it on unquantized experts: the
+    runner's choice for the program it builds, the Pallas kernel of
+    ``ops/moe_grouped_matmul_pallas.py`` (``(x, w, group_sizes) ->
+    (M, N)``) where that serves, else None: ``jax.lax.ragged_dot``, XLA's
+    own ``ragged-dot`` kernel on the TPU and a masked loop elsewhere.
+    Both take bf16 operands, accumulate in float32 and give the same
+    numbers. A quantized weight always goes through ``ragged_dot``, W8A8
+    (rows quantized per row, int8 x int8 -> int32), rescaled by each
+    row's own scale and its group's weight scale (G, 1, N), looked up
+    through ``group_of_row`` (M,), which may point one past the last
+    group for the undefined rows."""
     if not is_quantized(w):
+        if grouped_matmul is not None:
+            return grouped_matmul(x, w, group_sizes)
         return jax.lax.ragged_dot(x, w, group_sizes)
     xf = x.astype(jnp.float32)
     sx = jnp.maximum(jnp.max(jnp.abs(xf), axis=-1, keepdims=True) / 127.0,

@@ -1900,6 +1900,12 @@ class EngineServer:
                  "decode_attn_calls": self.engine.decode_attn_calls,
                  "decode_attn_slab_calls":
                      self.engine.decode_attn_slab_calls}
+        moe = getattr(self.engine.runner, "moe", None)
+        if moe is not None:
+            # what the MoE block routed, and the layer-steps whose grouped
+            # matmuls ran the Pallas kernel (vllm:moe_*_total;
+            # engine/tracing.py MoeCounters)
+            walks["moe"] = moe.snapshot()
         if perf is None:
             return web.json_response({"enabled": False,
                                       "kv_transfer": kv_block,
@@ -3098,6 +3104,10 @@ class EngineServer:
 
         async def stream_one(gen, crid, idx) -> int:
             token_ids: list[int] = []
+            # the text so far without decoding the whole list a token
+            # (engine/tokenizer.py WindowedDecoder); the last output's
+            # text is the whole list's, whatever a window held back
+            text_of = tk.stream_decoder()
             all_lps: list = []
             lp_emitted = 0
             sent_len = 0
@@ -3111,7 +3121,8 @@ class EngineServer:
                 token_ids.extend(out.new_token_ids)
                 if out.new_logprobs:
                     all_lps.extend(out.new_logprobs)
-                text = tk.decode(token_ids)
+                text = (tk.decode(token_ids) if out.finished
+                        else text_of(token_ids))
                 stopped = self._check_stop_str(text, sampling)
                 if stopped is not None:
                     self.async_engine.abort(crid)

@@ -8,7 +8,7 @@ bos/eos/pad. Any model config with vocab_size >= 259 can serve under it.
 from __future__ import annotations
 
 import logging
-from typing import Optional, Protocol, Sequence
+from typing import Callable, Optional, Protocol, Sequence
 
 
 class Tokenizer(Protocol):
@@ -17,6 +17,7 @@ class Tokenizer(Protocol):
 
     def encode(self, text: str, add_bos: bool = True) -> list[int]: ...
     def decode(self, ids: Sequence[int]) -> str: ...
+    def stream_decoder(self) -> Callable[[Sequence[int]], str]: ...
     @property
     def vocab_size(self) -> int: ...
 
@@ -37,6 +38,10 @@ class ByteTokenizer:
 
     def decode(self, ids: Sequence[int]) -> str:
         return bytes(i for i in ids if 0 <= i < 256).decode("utf-8", errors="replace")
+
+    def stream_decoder(self) -> Callable[[Sequence[int]], str]:
+        # a thousand bytes decode in microseconds: the whole list each time
+        return self.decode
 
 
 class HFTokenizer:
@@ -62,6 +67,38 @@ class HFTokenizer:
 
     def decode(self, ids: Sequence[int]) -> str:
         return self.tk.decode(ids, skip_special_tokens=True)
+
+    def stream_decoder(self) -> Callable[[Sequence[int]], str]:
+        return WindowedDecoder(self.decode)
+
+
+class WindowedDecoder:
+    """The text of ONE growing token list, decoded a window at a time:
+    ``decoder(ids)`` returns what ``decode(ids)`` would, for the cost of
+    two decodes of the last few tokens instead of one of the whole list
+    (an HF decode is ~10 us + 0.25 us a token: a stream of 768 tokens paid
+    200 us a token at its end, on the server's one event-loop thread).
+
+    The window is the tokens since the last text taken plus the ones
+    before them that decided how those rendered (a leading space, a
+    merged byte sequence): the new tokens' text is what the window reads
+    beyond what its head read alone. Text that ends in U+FFFD is an
+    unfinished multi-byte character and is held back until the tokens
+    that complete it arrive; the caller flushes the tail with one whole
+    ``decode`` at the stream's end."""
+
+    def __init__(self, decode: Callable[[Sequence[int]], str]):
+        self.decode = decode
+        self.text = ""
+        self.head = self.read = 0  # ids[head:read]: the window's old part
+
+    def __call__(self, ids: Sequence[int]) -> str:
+        old = self.decode(ids[self.head:self.read])
+        new = self.decode(ids[self.head:])
+        if len(new) > len(old) and not new.endswith("\ufffd"):
+            self.text += new[len(old):]
+            self.head, self.read = self.read, len(ids)
+        return self.text
 
 
 def get_tokenizer(path: Optional[str]) -> Tokenizer:

@@ -741,17 +741,24 @@ class MoeCounters:
     the experts routed over (``share``: ``ModelConfig.experts_held``), the
     histogram has one more column before the null group's, the pairs on
     experts that lie elsewhere: they are routed pairs and not held ones,
-    and loads, their mean and the experts touched are of the held."""
+    and loads, their mean and the experts touched are of the held.
 
-    def __init__(self, num_experts: int, top_k: int, share: bool = False):
+    ``grouped_kernel``: the runner built its step programs with the Pallas
+    grouped matmul (``ops/moe_grouped_matmul_pallas.py``) and not with
+    ``jax.lax.ragged_dot``: its one choice for every program it builds,
+    so a layer-step recorded here ran what it says."""
+
+    def __init__(self, num_experts: int, top_k: int, share: bool = False,
+                 grouped_kernel: bool = False):
         self.num_experts, self.top_k = num_experts, top_k
-        self.share = share
+        self.share, self.grouped_kernel = share, grouped_kernel
         self.routed_tokens = 0        # (token, choice) pairs sent to experts
         self.held_pairs = 0           # of them, on experts held here
         self.padding_rows = 0         # stream rows kept out of the routing
         self.expert_load_max = 0      # pairs of the busiest expert
         self.decode_experts_touched = 0  # experts with a pair, decode steps
         self.decode_layer_steps = 0   # layers x fused iterations, decode steps
+        self.layer_steps = 0          # the same of every kind of dispatch
 
     def record(self, kind: str, hist) -> None:
         """``hist``: (..., X + 1) integers on the host, one row per layer
@@ -763,6 +770,7 @@ class MoeCounters:
         self.routed_tokens += int(h[:, :-1].sum())
         self.padding_rows += int(h[:, -1].sum()) // self.top_k
         self.expert_load_max += int(loads.max(axis=1).sum())
+        self.layer_steps += len(loads)
         if kind == "decode":
             self.decode_experts_touched += int((loads > 0).sum())
             self.decode_layer_steps += len(loads)
@@ -778,4 +786,8 @@ class MoeCounters:
                                            / self.num_experts),
             "moe_decode_experts_touched_total": self.decode_experts_touched,
             "moe_decode_layer_steps_total": self.decode_layer_steps,
+            "moe_layer_steps_total": self.layer_steps,
+            # of them, on the Pallas kernel: all or none, as built
+            "moe_grouped_kernel_layer_steps_total":
+                self.layer_steps if self.grouped_kernel else 0,
         }

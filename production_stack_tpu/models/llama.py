@@ -51,6 +51,12 @@ AttendFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, jnp.ndarray], T
 # float32, new caches). ``caches`` is the hybrid
 # stack's whole cache pytree {"kv", "state", "conv"} (engine/kv_cache.py)
 RecurFn = Callable[..., Tuple[jnp.ndarray, Any]]
+# GroupedMatmulFn, the MoE block's grouped matmul on unquantized experts:
+# (rows (M, K) sorted by group, matrices (G, K, N), group sizes (G,)) ->
+# (M, N); None leaves it to jax.lax.ragged_dot (engine/quant.py
+# ragged_quant_dot)
+GroupedMatmulFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray],
+                           jnp.ndarray]
 
 
 # ---------------------------------------------------------------------------
@@ -578,7 +584,7 @@ _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 def _moe_mlp(cfg: ModelConfig, router: jnp.ndarray, experts: dict,
              layer_idx, x: jnp.ndarray, live: Optional[jnp.ndarray] = None,
-             bias: Optional[jnp.ndarray] = None
+             bias: Optional[jnp.ndarray] = None, grouped_matmul=None
              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Sparse MoE block (Mixtral, OLMoE), dropless: route, sort the
     (token, choice) pairs by expert, one grouped matmul per projection
@@ -586,9 +592,14 @@ def _moe_mlp(cfg: ModelConfig, router: jnp.ndarray, experts: dict,
 
     Every pair is computed whatever the routing looks like: there is no
     capacity, so the block equals "every expert on every token, weighted
-    by the routing weight" exactly. ``jax.lax.ragged_dot`` is the grouped
-    matmul; on TPU it compiles to XLA's own ``ragged-dot`` Mosaic kernel
-    (the instruction a device trace shows), elsewhere to a masked loop.
+    by the routing weight" exactly. The grouped matmul is
+    ``engine/quant.py`` ``ragged_quant_dot``'s: ``grouped_matmul`` where
+    the runner hands one in (the Pallas kernel of
+    ``ops/moe_grouped_matmul_pallas.py``, the instruction
+    ``%moe_grouped_matmul`` of a device trace), else
+    ``jax.lax.ragged_dot`` (on TPU XLA's own ``%ragged-dot`` Mosaic
+    kernel, elsewhere a masked loop). Either way three a layer, each one
+    (rows, width) array.
 
     ``router`` (E, X) is this layer's; ``experts`` holds the expert
     matrices of ALL the stack's layers, (L, X, in, out) each, and
@@ -663,7 +674,7 @@ def _moe_mlp(cfg: ModelConfig, router: jnp.ndarray, experts: dict,
 
     def grouped(y, name):
         w = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), experts[name])
-        return ragged_quant_dot(y, w, sizes, group)
+        return ragged_quant_dot(y, w, sizes, group, grouped_matmul)
 
     out = grouped(_act(cfg)(grouped(rows, "w_gate")) * grouped(rows, "w_up"),
                   "w_down")
@@ -716,11 +727,12 @@ def forward_tokens(
     moe_hist: bool = False,
     loop_count: bool = False,
     recur: Optional[RecurFn] = None,
+    grouped_matmul: Optional[GroupedMatmulFn] = None,
 ) -> Tuple[jnp.ndarray, Any]:
     """Embed tokens then run the decoder stack (see forward_hidden)."""
     x = embed_tokens(cfg, params, tokens)
     return forward_hidden(cfg, params, x, positions, attend, kv_caches, lora,
-                          live, moe_hist, loop_count, recur)
+                          live, moe_hist, loop_count, recur, grouped_matmul)
 
 
 def embed_tokens(cfg: ModelConfig, params: dict, tokens: jnp.ndarray) -> jnp.ndarray:
@@ -744,6 +756,7 @@ def forward_hidden(
     moe_hist: bool = False,
     loop_count: bool = False,
     recur: Optional[RecurFn] = None,
+    grouped_matmul: Optional[GroupedMatmulFn] = None,
 ) -> Tuple[jnp.ndarray, Any]:
     """Run the decoder stack from pre-embedded activations.
 
@@ -777,6 +790,8 @@ def forward_hidden(
     A hybrid stack (``cfg.attn_period`` > 1) scans over PERIODS of its
     layer pattern, see ``_forward_hybrid``; ``recur`` is its recurrent
     layers' stateful call (default: whole sequences from a zero state).
+    ``grouped_matmul`` is the MoE block's (a GroupedMatmulFn; default:
+    ``jax.lax.ragged_dot``).
     """
     layers, experts = params["layers"], None
     if cfg.is_moe:
@@ -805,7 +820,7 @@ def forward_hidden(
             cfg, params, layers, experts, x, attend,
             recur or (functools.partial(sambay.mamba_dense, cfg)
                       if cfg.mamba_period else _recur_dense),
-            kv_caches, live, pre_norm)
+            kv_caches, live, pre_norm, grouped_matmul)
         out = (x, new_caches)
         if moe_hist:
             out += (hists,)
@@ -870,7 +885,7 @@ def forward_hidden(
         if sparse:  # LoRA on MoE experts: not supported yet
             with jax.named_scope("moe"):
                 mlp_out, hist = _sparse_block(cfg, lp, experts, layer_idx,
-                                              normed2, live)
+                                              normed2, live, grouped_matmul)
         else:
             mlp_out = _mlp(cfg, lp, normed2, lb=lb, onehot=onehot)
         if cfg.post_norms:
@@ -978,11 +993,13 @@ def _mla_mixer(cfg: ModelConfig, lp: dict, x: jnp.ndarray, positions,
 
 
 def _sparse_block(cfg: ModelConfig, lp: dict, experts: dict, layer_idx,
-                  x: jnp.ndarray, live) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                  x: jnp.ndarray, live, grouped_matmul=None
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The routed experts and, where the family has one, the shared
     expert every token passes through, added once."""
     out, hist = _moe_mlp(cfg, lp["router"], experts, layer_idx, x, live,
-                         bias=lp.get("router_bias"))
+                         bias=lp.get("router_bias"),
+                         grouped_matmul=grouped_matmul)
     if cfg.shared_expert_size:
         out = out + _mlp(cfg, {"w_gate": lp["shared_gate"],
                                "w_up": lp["shared_up"],
@@ -1034,7 +1051,7 @@ def _kda_mixer(cfg: ModelConfig, kp: dict, x: jnp.ndarray, recur: RecurFn,
 
 def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
                     experts: dict, x, attend: AttendFn,
-                    recur, caches, live, pre_norm):
+                    recur, caches, live, pre_norm, grouped_matmul=None):
     """A patterned stack (``cfg.layer_kinds``): one scan over the periods
     of each run of like periods (``cfg.stack_segments``; a run of one
     period is not scanned). Solar-Open2 is one run of (gqa, kda, kda, kda):
@@ -1131,7 +1148,7 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
                 with jax.named_scope("moe"):
                     mlp_out, hist = _sparse_block(
                         cfg, lp, experts, p * len(kinds) + (l0 + j),
-                        pre_norm(h, lp["mlp_norm"]), live)
+                        pre_norm(h, lp["mlp_norm"]), live, grouped_matmul)
                 hists.append(hist)
             else:
                 mlp_out = _mlp(cfg, lp, pre_norm(h, lp["mlp_norm"],

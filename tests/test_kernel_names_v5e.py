@@ -141,14 +141,9 @@ def test_kernel_compiles_at_olmoe_geometry_under_default_vmem(one_chip, name):
                      text, flags=re.M)
 
 
-@pytest.mark.parametrize("rows", [64 * 8, 2048 * 8])  # decode step, stream
-def test_moe_block_compiles_to_named_grouped_matmuls(one_chip, rows):
-    """``jax.lax.ragged_dot`` becomes XLA's own Mosaic kernel on the TPU,
-    three a layer, each an instruction headed ``%ragged-dot-none[.N]``
-    over the sorted (token, choice) rows: no dense expansion over the 64
-    experts, no one-hot dispatch, and no copy of a layer's experts out of
-    the 8-layer stack (3 x 268 MB a layer if the kernel were handed a
-    slice)."""
+def _olmoe_block(one_chip, rows, grouped_matmul, int8=False):
+    """``_moe_mlp`` at OLMoE's widths over an 8-layer stack, compiled for
+    the chip with default options (16 MiB of scoped VMEM)."""
     from production_stack_tpu.engine.config import ModelConfig
     from production_stack_tpu.models import llama
 
@@ -159,20 +154,107 @@ def test_moe_block_compiles_to_named_grouped_matmuls(one_chip, rows):
     def sds(shape, dt=bf):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    experts = {"w_gate": sds((8, X, E, F)), "w_up": sds((8, X, E, F)),
-               "w_down": sds((8, X, F, E))}
-    compiled = jax.jit(
+    def stacked(k, n):
+        if int8:
+            return {"q": sds((8, X, k, n), jnp.int8),
+                    "s": sds((8, X, 1, n), jnp.float32)}
+        return sds((8, X, k, n))
+
+    experts = {"w_gate": stacked(E, F), "w_up": stacked(E, F),
+               "w_down": stacked(F, E)}
+    return jax.jit(
         lambda router, experts, layer, x, live: llama._moe_mlp(
-            cfg, router, experts, layer, x, live)
+            cfg, router, experts, layer, x, live,
+            grouped_matmul=grouped_matmul)
     ).lower(sds((E, X)), experts, sds((), I32), sds((T, E)),
             sds((T,), jnp.bool_)).compile()
-    heads = re.findall(
-        rf"^\s*(?:ROOT )?%(ragged-dot-none[.\d]*) = bf16\[{rows},",
-        compiled.as_text(), flags=re.M)
-    assert len(heads) == 3, heads
+
+
+def _grouped_matmul_heads(text: str, rows: int) -> list:
+    """The block's grouped-matmul instructions as chipbench's rooflines
+    match them (layer_metrics/moe_expert_hbm_floor_pct.json): a name, one
+    bf16 array of ``rows`` rows."""
+    return re.findall(
+        rf"^\s*(?:ROOT )?%((?:ragged-dot-none|moe_grouped_matmul)[.\d]*)"
+        rf" = bf16\[{rows},", text, flags=re.M)
+
+
+# decode step, narrow and full stream; what the runner hands the block on
+# an unsharded TPU (the kernel, at every row count: PERF.md section 5's
+# table) and what it hands it anywhere else
+@pytest.mark.parametrize("rows", [64 * 8, 512 * 8, 2048 * 8])
+@pytest.mark.parametrize("impl", ["kernel", "ragged_dot"])
+def test_moe_block_compiles_to_named_grouped_matmuls(one_chip, rows, impl):
+    """Three grouped matmuls a layer over the sorted (token, choice) rows,
+    each one instruction with one array result: ``%moe_grouped_matmul[.N]``
+    where the Pallas kernel runs them, ``%ragged-dot-none[.N]`` (XLA's own
+    Mosaic kernel for ``jax.lax.ragged_dot``) where it does not. No dense
+    expansion over the 64 experts, no one-hot dispatch, and no copy of a
+    layer's experts out of the 8-layer stack (3 x 268 MB a layer if the
+    grouped matmul were handed a slice)."""
+    from production_stack_tpu.ops.moe_grouped_matmul_pallas import (
+        moe_grouped_matmul,
+    )
+
+    compiled = _olmoe_block(one_chip, rows,
+                            moe_grouped_matmul if impl == "kernel" else None)
+    heads = _grouped_matmul_heads(compiled.as_text(), rows)
+    want = "moe_grouped_matmul" if impl == "kernel" else "ragged-dot-none"
+    assert len(heads) == 3 and all(h.startswith(want) for h in heads), heads
     # the block's transients are its sorted rows (64 MB each at stream
     # size), never a layer's experts (805 MB)
     assert compiled.memory_analysis().temp_size_in_bytes < 400 * 2 ** 20
+
+
+def test_int8_experts_compile_to_ragged_dot_whatever_is_handed_in(one_chip):
+    """The W8A8 branch of ``ragged_quant_dot`` keeps ``jax.lax.ragged_dot``
+    (int8 x int8 -> int32): the kernel serves experts in the model dtype."""
+    from production_stack_tpu.ops.moe_grouped_matmul_pallas import (
+        moe_grouped_matmul,
+    )
+
+    text = _olmoe_block(one_chip, 512, moe_grouped_matmul,
+                        int8=True).as_text()
+    assert not re.search(r"%moe_grouped_matmul", text)
+    assert len(re.findall(r"^\s*(?:ROOT )?%ragged-dot-none[.\d]* = s32\[512,",
+                          text, flags=re.M)) == 3
+
+
+# the kernel alone at the other two MoE cells' geometries: Solar-Open2's
+# share (20 held experts a layer, 8 layers, 4096 x 1280) at a decode step's
+# and the two streams' rows, the Pangu share's (16 held, 4 expert layers,
+# 7680 x 2048) at its stream's; gate / up and down. It carries its own
+# VMEM limit (two weight blocks of up to 16 MiB), so none of them needs a
+# libtpu flag: all compile with default options
+GROUPED_CASES = {
+    f"{name}.{rows}.{proj}": (rows, groups, k, n)
+    for name, groups, e, f, row_counts in (
+        ("solar", 8 * 20, 4096, 1280, (512, 4096, 16384)),
+        ("pangu", 4 * 16, 7680, 2048, (16384,)))
+    for rows in row_counts
+    for proj, (k, n) in (("gate", (e, f)), ("down", (f, e)))
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED_CASES))
+def test_grouped_matmul_compiles_at_the_held_shares_geometries(
+        one_chip, case):
+    from production_stack_tpu.ops.moe_grouped_matmul_pallas import (
+        grouped_kernel_path,
+        moe_grouped_matmul,
+    )
+
+    rows, groups, k, n = GROUPED_CASES[case]
+    assert grouped_kernel_path(k, n)
+    compiled = jax.jit(moe_grouped_matmul).lower(
+        *(jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+            ((rows, k), jnp.bfloat16), ((groups, k, n), jnp.bfloat16),
+            ((groups,), I32)))).compile()
+    heads = _grouped_matmul_heads(compiled.as_text(), rows)
+    assert len(heads) == 1 and heads[0].startswith("moe_grouped_matmul")
+    # the experts are read where they lie: no copy of the stack (1.7 GB,
+    # 2 GB) among the temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 # -- Solar-Open2's share on one chip (chipbench solar-open2-250b-ep16-l8) ----

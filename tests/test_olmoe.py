@@ -159,7 +159,15 @@ def test_padding_is_counted_and_not_routed(tiny):
     names = {m.name for m in EngineStatsCollector(eng, "tiny-olmoe").collect()}
     assert {"vllm:moe_routed_tokens", "vllm:moe_padding_rows",
             "vllm:moe_expert_load_max", "vllm:moe_expert_load_mean",
-            "vllm:moe_decode_experts_touched"} <= names
+            "vllm:moe_decode_experts_touched", "vllm:moe_layer_steps",
+            "vllm:moe_grouped_kernel_layer_steps"} <= names
+    # layer-steps of every kind of dispatch; on the CPU none of them ran
+    # the Pallas grouped matmul (the runner's choice: model_runner.py)
+    assert moe.layer_steps == L * (
+        eng.ragged_dispatches
+        + eng.decode_dispatches * max(sched.multi_step, 1))
+    assert moe.decode_layer_steps < moe.layer_steps
+    assert moe.snapshot()["moe_grouped_kernel_layer_steps_total"] == 0
 
 
 def test_a_dense_model_exports_no_moe_counters():
