@@ -320,9 +320,10 @@ class LLMEngine:
         self.ragged_attn_interior_windows = 0
         self.decode_dispatches = 0  # decode_multi dispatches
         # attention calls those dispatches made (fused iterations x cache
-        # layers each), and those that ran the Pallas decode kernel's slab
-        # body (ops/paged_attention_pallas.decode_slab_path, decided by
-        # the runner for its per-shard geometry)
+        # layers each), and those in which the Pallas decode kernel scored
+        # a window from the slab as stored (ops/paged_attention_pallas.
+        # decode_slab_path, decided by the runner for its per-shard
+        # geometry, the window layers' calls apart)
         self.decode_attn_calls = 0
         self.decode_attn_slab_calls = 0
         # where a decode-only step's already resolved outputs go before
@@ -1544,8 +1545,12 @@ class LLMEngine:
         attn_calls = K * (self.config.model.cache_layers
                           + self.config.model.count_layers("cross"))
         self.decode_attn_calls += attn_calls
-        if getattr(self.runner, "decode_attn_slab", False):
-            self.decode_attn_slab_calls += attn_calls
+        windowed = K * self.config.model.count_layers("swa")
+        self.decode_attn_slab_calls += (
+            (attn_calls - windowed)
+            * getattr(self.runner, "decode_attn_slab", False)
+            + windowed
+            * getattr(self.runner, "decode_attn_slab_windowed", False))
         pend = {"decodes": list(decodes), "slots": [s.slot for s in decodes]}
         if launches:
             pend["sampled"], next_tok, pend["counters"], *lp = result

@@ -206,8 +206,9 @@ class EngineStatsCollector:
         )
         yield counter(
             "vllm:decode_attn_slab_calls",
-            "Those that ran the Pallas decode kernel's slab body (one "
-            "query row a KV head, bf16 cache, 128-wide heads)",
+            "Those in which the Pallas decode kernel scored a window from "
+            "the slab as stored (bf16 cache, 128-wide heads: MHA's slab "
+            "body, the grouped-query body)",
             s.get("decode_attn_slab_calls_total", 0),
         )
         yield counter(

@@ -289,13 +289,16 @@ class ModelRunner:
             share=bool(self.cfg.experts_held),
             grouped_kernel=self.moe_grouped_matmul is not None)
             if self.cfg.is_moe else None)
-        # whether a decode step's attention calls run the Pallas decode
-        # kernel's slab body: the kernel's own predicate at this runner's
-        # per-shard geometry (vllm:decode_attn_slab_calls_total)
-        self.decode_attn_slab = (
-            self.use_pallas and not self.cfg.is_latent) and decode_slab_path(
-            self.cfg.cache_kv_heads // self.tp,
-            self.cfg.q_per_kv, self.cfg.cache_head_dim, self.cfg.jax_dtype)
+        # whether a decode step's attention calls have the Pallas decode
+        # kernel score a window from the slab as stored: the kernel's own
+        # predicate at this runner's per-shard geometry, for the calls
+        # without a window and for the window layers' ("swa", _attend_kind)
+        # (vllm:decode_attn_slab_calls_total)
+        self.decode_attn_slab, self.decode_attn_slab_windowed = (
+            self.use_pallas and not self.cfg.is_latent and decode_slab_path(
+                self.cfg.cache_kv_heads // self.tp, self.cfg.q_per_kv,
+                self.cfg.cache_head_dim, self.cfg.jax_dtype, window)
+            for window in (0, self.cfg.sliding_window))
         impl = getattr(config, "attention_impl", "auto") or "auto"
         if impl not in ("auto", "ragged", "bucketed"):
             raise ValueError(

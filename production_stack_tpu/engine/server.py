@@ -1894,9 +1894,9 @@ class EngineServer:
                  "ragged_attn_windows": self.engine.ragged_attn_windows,
                  "ragged_attn_interior_windows":
                      self.engine.ragged_attn_interior_windows,
-                 # the decode dispatches' attention calls, and those on
-                 # the decode kernel's slab body (vllm:decode_attn_calls_
-                 # total, vllm:decode_attn_slab_calls_total)
+                 # the decode dispatches' attention calls, and those the
+                 # decode kernel scored from the slab as stored (vllm:
+                 # decode_attn_calls_total, ..._slab_calls_total)
                  "decode_attn_calls": self.engine.decode_attn_calls,
                  "decode_attn_slab_calls":
                      self.engine.decode_attn_slab_calls}

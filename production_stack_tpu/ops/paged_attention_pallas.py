@@ -6,9 +6,11 @@ Three kernels over the fused cache layout ``(L, N, block_size, 2*KH, D)``
 - ``paged_decode_attention_pallas``: one grid cell per sequence; walks the
   block table in windows of W blocks, one async DMA per block moving the
   whole ``(bs, 2KH, D)`` K+V slab, double-buffered windows, flash running
-  softmax batched over heads; a landed window is scored head by head, or,
-  at one query row a KV head (``decode_slab_path``), from the slab as it
-  is stored.
+  softmax batched over heads; a landed window is scored from the slab as
+  it is stored (``decode_window_body``): as one (tokens x heads, D) matrix
+  at one query row a KV head, head pair by head pair through strided
+  32-bit loads at grouped queries; head by head from a float32 copy only
+  at the geometries no served model has.
 - ``paged_prefill_attention_pallas``: one grid cell per query tile of a
   single sequence's chunk; same windowed context walk with causal masking —
   this replaces the XLA dynamic-slice + gather path over the whole pool.
@@ -43,20 +45,46 @@ NEG_INF = -1e30
 # decode
 # ---------------------------------------------------------------------------
 
-def decode_slab_path(kh: int, group: int, head_dim: int, cache_dtype) -> bool:
-    """Whether the decode kernel scores a window from the slab as stored
-    (``_slab_window``) or head by head (``_head_window``); of the call's
-    per-shard geometry alone. True at one query row a KV head (MHA), a
-    bf16 cache whose K half of a token's ``(2KH, D)`` slab is whole
-    ``(16, 128)`` tiles, and 128-wide heads: a window's K rows then *are*
-    a ``(tokens * KH, D)`` matrix in (token, head) order, V the same, and
-    every head's one query row goes past each 128-row tile of it in one
-    bf16 MXU pass. KH 16 (OLMoE, Ouro) and 32 are the shapes a test and a
-    chip run have covered; grouped queries (G > 1), other head sizes, a
-    shard with fewer than 16 KV heads and float32 caches keep the
-    per-head body."""
-    return (group == 1 and head_dim == 128 and kh in (16, 32)
-            and jnp.dtype(cache_dtype) == jnp.bfloat16)
+def decode_window_body(kh: int, group: int, head_dim: int, cache_dtype,
+                       window: int = 0) -> str:
+    """Which body scores a landed window of the decode kernel; of the
+    call's per-shard geometry alone. ``"slab"`` and ``"grouped"`` read the
+    window from the slab as it lies in the landing buffer, ``"head"`` from
+    a float32 copy cut into per-head values. All three want nothing else
+    of the caller.
+
+    ``"slab"`` (``_slab_window``): one query row a KV head (MHA), a bf16
+    cache whose K half of a token's ``(2KH, D)`` slab is whole ``(16,
+    128)`` tiles, 128-wide heads, no sliding window: a window's K rows
+    then *are* a ``(tokens * KH, D)`` matrix in (token, head) order, V the
+    same, and every head's one query row goes past each 128-row tile of it
+    in one bf16 MXU pass. KH 16 (OLMoE, Ouro) and 32.
+
+    ``"grouped"`` (``_grouped_window``): grouped queries over a bf16 cache
+    with 128-wide heads whose token slab is whole 32-bit word rows in
+    fours (KH a multiple of 4: the landing buffer's ``(8, 128)`` tiles of
+    row pairs), with or without a window. KH 8 at G 3, 4 and 8 (Qwen3,
+    Solar-Open2's GQA layers, Llama-3.2-3B) and KH 12 at G 4
+    (Phi-4-mini-flash, whose 24 rows are one and a half vector registers:
+    the strided load does not care), KH 4 at G 4 (a TP-2 shard of KH 8)
+    and KH 16 at G 2 are the shapes a test and a chip run have covered.
+
+    ``"head"`` (``_head_window``): everything else: float32 caches, heads
+    that are not 128 wide, a shard with fewer than 4 KV heads (TP-4 of KH
+    8: 2KH = 4), MHA at another head count or under a window."""
+    if head_dim != 128 or jnp.dtype(cache_dtype) != jnp.bfloat16:
+        return "head"
+    if group == 1:
+        return "slab" if kh in (16, 32) and not window else "head"
+    return "grouped" if kh in (4, 8, 12, 16) else "head"
+
+
+def decode_slab_path(kh: int, group: int, head_dim: int, cache_dtype,
+                     window: int = 0) -> bool:
+    """Whether the decode kernel scores a window from the slab as stored:
+    what ``vllm:decode_attn_slab_calls_total`` counts."""
+    return decode_window_body(kh, group, head_dim, cache_dtype,
+                              window) != "head"
 
 
 def _flash_weights(m, l, sc):
@@ -195,6 +223,132 @@ def _slab_window(q_ref, buf, slot, w, *, W, win_tokens, scale, soft_cap):
     return update
 
 
+def _zero_unseen_rows(buf, slot, s, w, ctx, *, W, win_tokens, window=0):
+    """Make the rows a walk did not fetch harmless where a product sums
+    over them (0 x NaN = NaN): in the landing buffer, and only in the
+    blocks of window ``w`` that hold any: the context's tail block, the
+    blocks past it and, under a sliding ``window``, the blocks wholly below
+    the floor. Nothing runs for a window that was fetched whole."""
+    bs = win_tokens // W
+    start = w * win_tokens
+    floor = jnp.maximum(ctx - window, 0) if window else 0
+    ragged = start + win_tokens > ctx
+    if window:
+        ragged |= start + bs <= floor
+
+    @pl.when(ragged & (start < ctx))
+    def _():
+        for j in range(W):
+            first = start + j * bs
+            unseen = first + bs > ctx
+            if window:
+                unseen |= first + bs <= floor
+
+            @pl.when(unseen)
+            def _():
+                # a token's bf16 rows are whole 32-bit words: cleared as such
+                words = pltpu.bitcast(buf[slot, s, j], jnp.uint32)
+                tok = first + jax.lax.broadcasted_iota(
+                    jnp.int32, words.shape, 0)
+                keep = tok < ctx
+                if window:
+                    keep &= first + bs > floor
+                buf[slot, s, j] = pltpu.bitcast(
+                    jnp.where(keep, words, jnp.uint32(0)), buf.dtype)
+
+
+def slab_heads(window):
+    """Every head's K and V ``(win_tokens, D)`` of a landed window, a ref
+    ``(W, bs, 2KH, D)`` into a bf16 landing buffer, as two lists of bf16
+    tiles. The window is read as 32-bit words, two heads a word (word row
+    j of a token: heads 2j and 2j + 1): one sublane-strided load a head
+    pair gathers the pair's rows of all tokens, so the slab is never cut
+    into per-head slices, and a shift or a mask leaves either head as the
+    high half of a float32 that converts to bf16 exactly (PR 38, for the
+    ragged kernel's interior body). Any even number of word rows a token
+    will do: 2KH = 24 is twelve."""
+    W, bs, KH2, D = window.shape
+    T = W * bs
+    words = window.reshape(T * KH2, D).bitcast(jnp.uint32)
+    heads = []
+    for j in range(KH2 // 2):
+        pair = words[pl.ds(j, T, stride=KH2 // 2), :]
+        heads += [pltpu.bitcast(half, jnp.float32).astype(jnp.bfloat16)
+                  for half in (pair << 16, pair & jnp.uint32(0xFFFF0000))]
+    return heads[:KH2 // 2], heads[KH2 // 2:]
+
+
+def _grouped_window(q_ref, buf, slot, w, *, W, win_tokens, scale, soft_cap,
+                    group, window=0):
+    """The same update for grouped queries (``decode_window_body``), on
+    the slab as it lies in ``buf``: q ``(KH * G, D)``, row r of KV head
+    ``r // G``. K and V tiles come off the landing buffer by
+    ``slab_heads``; each is the stationary operand of one product, past
+    which go the 16 query rows (one packed bf16 tile) that hold the head's
+    own, the other heads' rows of the block dropped by a select (all rows
+    where G does not divide 16). QK has bf16 operands and float32
+    accumulation; the float32 weights go past V as a bf16 high and low
+    part stacked on the sublanes, as in ``_slab_window``. The scores of a
+    window are ``(KH * G, win_tokens)``, four vector registers at Qwen3's
+    shape, so the flash state stays ``(rows, 1)``: lane-wide ``m`` and
+    ``l`` read the same on the chip (PERF.md section 5).
+
+    0 x NaN: a K row the walk did not fetch can only reach a score, and
+    the ``where`` below replaces that score whatever it is; a V row
+    reaches the accumulator through a zero weight, so those are cleared
+    in the buffer first (``_zero_unseen_rows``). With a sliding ``window``
+    the keys below ``ctx - window`` are masked and their blocks were not
+    fetched."""
+    R, D = q_ref.shape[1], q_ref.shape[2]
+    T = win_tokens
+    kvpos = w * T + jax.lax.broadcasted_iota(jnp.int32, (R, T), 1)
+    step = 16 if R % 16 == 0 and 16 % group == 0 else R
+    heads = -(-step // group)  # KV heads a block of query rows spans
+    own_s = jax.lax.broadcasted_iota(jnp.int32, (step, T), 0) // group
+    own_d = jax.lax.broadcasted_iota(jnp.int32, (step, D), 0) // group
+
+    def update(s, ctx, carry):
+        m, l, acc = carry  # (R, 1), (R, 1), (R, D)
+        k, v = slab_heads(buf.at[slot, s])
+        blocks = []
+        for r0 in range(0, R, step):
+            q = q_ref[s, r0:r0 + step, :]
+            sc = None
+            for i in range(heads):
+                sc_h = jax.lax.dot_general(
+                    q, k[r0 // group + i], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # (step, T)
+                sc = sc_h if sc is None else jnp.where(own_s == i, sc_h, sc)
+            blocks.append(sc)
+        sc = jnp.concatenate(blocks, axis=0) * scale  # (R, T)
+        if soft_cap:
+            sc = soft_cap * jnp.tanh(sc / soft_cap)
+        seen = kvpos < ctx
+        if window:
+            seen &= kvpos >= ctx - window
+        sc = jnp.where(seen, sc, NEG_INF)
+
+        m_new, alpha, p, l_new = _flash_weights(m, l, sc)
+        p_hi = p.astype(buf.dtype)
+        p_lo = (p - p_hi.astype(jnp.float32)).astype(buf.dtype)
+        blocks = []
+        for r0 in range(0, R, step):
+            rows = slice(r0, r0 + step)
+            weights = jnp.concatenate([p_hi[rows], p_lo[rows]], axis=0)
+            pv = None
+            for i in range(heads):
+                pv_h = jax.lax.dot_general(
+                    weights, v[r0 // group + i], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # (2 step, D)
+                pv_h = pv_h[:step] + pv_h[step:]
+                pv = pv_h if pv is None else jnp.where(own_d == i, pv_h, pv)
+            blocks.append(pv)
+        acc_new = acc * alpha + jnp.concatenate(blocks, axis=0)
+        return m_new, l_new, acc_new
+
+    return update
+
+
 def _decode_kernel(
     # scalar prefetch
     bt_ref,  # (B, M) SMEM
@@ -214,7 +368,8 @@ def _decode_kernel(
     seqs_per_cell: int,
     scale: float,
     soft_cap: float = 0.0,
-    slab: bool = False,
+    window_body: str = "head",
+    group: int = 1,
     window: int = 0,
 ):
     """Batched paged decode attention.
@@ -228,11 +383,12 @@ def _decode_kernel(
     Mosaic's batched matmul requires.
 
     The DMA walk, the flash carry and the epilogue are one; what a landed
-    window computes is ``_slab_window`` where ``slab`` (the wrapper's
-    ``decode_slab_path``; q and o then come as (SPB, KH, D)) and
-    ``_head_window`` otherwise.
+    window computes is ``window_body``'s (the wrapper's
+    ``decode_window_body``): ``_slab_window`` (q and o then come as (SPB,
+    KH, D)), ``_grouped_window`` ((SPB, KH * G, D)) or ``_head_window``
+    ((SPB, KH, G, D)).
 
-    With a sliding ``window`` (the head-by-head body only) a sequence's
+    With a sliding ``window`` (not the slab body) a sequence's
     walk has a floor, ``ctx - window``: it starts at the context window
     that holds the floor (each of a cell's sequences at its own), and a
     block wholly below the floor is neither fetched nor scored."""
@@ -304,7 +460,9 @@ def _decode_kernel(
         issue(0, 0)
 
     landed = functools.partial(
-        _slab_window if slab else _head_window, q_ref, buf,
+        {"head": _head_window, "slab": _slab_window,
+         "grouped": functools.partial(_grouped_window, group=group),
+         }[window_body], q_ref, buf,
         W=W, win_tokens=win_tokens, scale=scale, soft_cap=soft_cap,
         **({"window": window} if window else {}))
 
@@ -324,6 +482,14 @@ def _decode_kernel(
                 def _():
                     dma(slot, s, w, j).wait()
 
+        if window_body == "grouped":
+            # every sequence's unfetched rows first: the conditional stores
+            # then stand before the updates, not between them, and the
+            # compiler schedules the cell's products as one block
+            for s in range(SPB):
+                _zero_unseen_rows(
+                    buf, slot, s, at(s, w), cl_ref[base + s], W=W,
+                    win_tokens=win_tokens, window=window)
         update = landed(slot, w)
         out = []
         for s in range(SPB):
@@ -338,7 +504,7 @@ def _decode_kernel(
             out += [jnp.where(act, n, o) for n, o in zip(new, old)]
         return tuple(out)
 
-    rows = q_ref.shape[1:-1]  # (KH, G), or (KH,) on the slab path
+    rows = q_ref.shape[1:-1]  # (KH, G); (KH,) or (KH * G,) from the slab
     D = q_ref.shape[-1]
     init = []
     for _ in range(SPB):
@@ -385,11 +551,12 @@ def paged_decode_attention_pallas(
     L, N, bs, KH2, _ = kv_cache.shape
     KH = KH2 // 2
     G = H // KH
-    slab = not window and decode_slab_path(KH, G, D, kv_cache.dtype)
+    body = decode_window_body(KH, G, D, kv_cache.dtype, window)
     # q heads are shard-grouped like the cache: here a single shard's view,
-    # heads ordered [h0..h_{KH-1}] matching [K_0..K_{KH-1}] halves; on the
-    # slab path (G = 1) the heads are the rows of one (KH, D) tile
-    qshape = (KH, D) if slab else (KH, G, D)
+    # heads ordered [h0..h_{KH-1}] matching [K_0..K_{KH-1}] halves; the
+    # bodies that read the slab as stored take the heads as the rows of
+    # one matrix
+    qshape = {"head": (KH, G, D), "slab": (KH, D), "grouped": (H, D)}[body]
     zeros = (0,) * len(qshape)
     q4 = q.reshape(B, *qshape)
     layer_arr = jnp.asarray(layer_idx, jnp.int32).reshape(1)
@@ -412,7 +579,7 @@ def paged_decode_attention_pallas(
     )
     kernel = functools.partial(
         _decode_kernel, block_size=bs, windows=windows, seqs_per_cell=spb,
-        scale=D**-0.5, soft_cap=soft_cap, slab=slab,
+        scale=D**-0.5, soft_cap=soft_cap, window_body=body, group=G,
         **({"window": window} if window else {}),
     )
     out = pl.pallas_call(

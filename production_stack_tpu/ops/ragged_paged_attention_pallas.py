@@ -74,6 +74,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from production_stack_tpu.ops.paged_attention_pallas import slab_heads
+
 NEG_INF = -1e30
 WINDOWS = 8  # KV blocks a context window holds: 128 tokens at block 16
 # lanes of the flash state's scratch: a vector register's width. The
@@ -198,26 +200,17 @@ def _window_heads(buf, slot, q_dtype):
     """Every head's K and V ``(win_tokens, D)`` of the window landed in
     ``buf[slot]`` ``(W, bs, 2KH, D)``, as two lists, in the type the MXU
     takes them. Where a token's bf16 slab is whole ``(16, 128)`` tiles the
-    window is read as 32-bit words, two heads a word: one sublane-strided
-    load a head pair gathers the pair's rows of all tokens (the slab is
-    never cut into per-head slices), and a shift or a mask leaves either
-    head as the high half of a float32 that converts to bf16 exactly.
-    Elsewhere (narrow test shapes, float32 caches) plain slices."""
+    heads come off the buffer by ``slab_heads``' strided 32-bit loads (the
+    slab is never cut into per-head slices). Elsewhere (narrow test
+    shapes, float32 caches) plain slices."""
     _, W, bs, KH2, D = buf.shape
-    T = W * bs
     if (buf.dtype == jnp.bfloat16 and q_dtype == jnp.bfloat16
             and KH2 % 16 == 0 and D % 128 == 0):
-        words = buf.at[slot].reshape(T * KH2, D).bitcast(jnp.uint32)
-        heads = []
-        for j in range(KH2 // 2):  # word row j of a token: heads 2j, 2j+1
-            pair = words[pl.ds(j, T, stride=KH2 // 2), :]
-            heads += [pltpu.bitcast(half, jnp.float32).astype(jnp.bfloat16)
-                      for half in (pair << 16, pair & jnp.uint32(0xFFFF0000))]
-    else:
-        kv = buf[slot].reshape(T, KH2, D)
-        if kv.dtype != q_dtype:
-            kv = kv.astype(jnp.float32)
-        heads = [kv[:, h, :] for h in range(KH2)]
+        return slab_heads(buf.at[slot])
+    kv = buf[slot].reshape(W * bs, KH2, D)
+    if kv.dtype != q_dtype:
+        kv = kv.astype(jnp.float32)
+    heads = [kv[:, h, :] for h in range(KH2)]
     return heads[:KH2 // 2], heads[KH2 // 2:]
 
 

@@ -132,6 +132,52 @@ MHA_CASES["paged_decode_attention.ouro"] = _decode_case(192, 330, 16)
 MHA_CASES["paged_decode_attention.kh32"] = _decode_case(4, 1024, 32)
 
 
+# the decode kernel at the shapes that take its grouped body
+# (decode_window_body: grouped queries, bf16, D = 128), 64 slots, each
+# cell's own pool: Qwen3-8B cut to 16 layers (KH 8, G 4), Solar-Open2's two
+# GQA layers (KH 8, G 8), Phi-4-mini-flash's 12 cache heads at G 4 with and
+# without its 512-row window, and the shapes no cell has (Llama-3.2-3B's
+# G 3, a TP-2 shard's KH 4, Qwen2-7B's G 7). All under the DEFAULT 16 MiB
+# of scoped VMEM, whatever flag the cells' manifests set for the ragged
+# kernel.
+def _grouped_decode_case(layers, blocks, kh, group, window=0):
+    return (
+        lambda q, c, bt, cl: paged_decode_attention_pallas(
+            q, c, bt, cl, layer_idx=1, window=window),
+        (((64, kh * group, D), jnp.bfloat16),
+         ((layers, blocks, BS, 2 * kh, D), jnp.bfloat16),
+         ((64, 256), I32), ((64,), I32)))
+
+
+GROUPED_DECODE_CASES = {
+    "qwen3-8b-l16": _grouped_decode_case(16, 4859, 8, 4),
+    "solar-open2-gqa": _grouped_decode_case(2, 4859, 8, 8),
+    "phi-4-mini-flash-full": _grouped_decode_case(1, 14000, 12, 4),
+    "phi-4-mini-flash-window": _grouped_decode_case(8, 2400, 12, 4, 512),
+    "qwen3-window": _grouped_decode_case(16, 4859, 8, 4, 512),
+    "llama-3b-g3": _grouped_decode_case(2, 1024, 8, 3),
+    "tp2-shard-kh4": _grouped_decode_case(2, 1024, 4, 4),
+    "qwen2-7b-kh4-g7": _grouped_decode_case(2, 1024, 4, 7),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GROUPED_DECODE_CASES))
+def test_grouped_decode_body_compiles_under_default_vmem(one_chip, name):
+    from production_stack_tpu.ops.paged_attention_pallas import (
+        decode_window_body,
+    )
+
+    fn, shapes = GROUPED_DECODE_CASES[name]
+    (_, heads, _), _ = shapes[0]
+    (_, _, _, rows, _), dtype = shapes[1]
+    assert decode_window_body(rows // 2, heads // (rows // 2), D,
+                              dtype) == "grouped"
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert re.search(
+        r"^\s*(?:ROOT )?%paged_decode_attention[.\d]* = .*? custom-call\(",
+        text, flags=re.M)
+
+
 @pytest.mark.parametrize("name", sorted(MHA_CASES))
 def test_kernel_compiles_at_olmoe_geometry_under_default_vmem(one_chip, name):
     fn, shapes = MHA_CASES[name]

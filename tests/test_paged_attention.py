@@ -120,12 +120,24 @@ def build_random_cache(rng, layers, n, kh, d, bs=BS, dtype=jnp.float32):
 
 
 # Decode-kernel geometries: (id, kh, G, d, bs, windows, dtype, lens,
-# layer, soft_cap). The small float32 ones run the per-head body; those at
-# KH 16 (and 32), G 1, D 128 with a bf16 cache meet ``decode_slab_path``
-# (OLMoE's and Ouro's shape) and run the slab body: contexts that end
-# mid-block, mid-window, on a window's edge and at 0, a dead slot inside a
-# live cell (all four sequences of B = 4 share one grid cell), a cell that
-# is dead altogether (B = 8), soft_cap > 0 and layer_idx > 0.
+# layer, soft_cap[, window]); the id's first word is the body
+# ``decode_window_body`` gives the geometry. The small float32 ones run the
+# per-head body ("head"); those at KH 16 (and 32), G 1, D 128 with a bf16
+# cache run the slab body (OLMoE's and Ouro's shape); grouped queries over
+# a bf16 cache with 128-wide heads run the grouped body: KH 8 at G 4
+# (Qwen3) and G 8 (Solar-Open2's GQA layers) and 12 cache heads at G 4
+# (Phi-4-mini-flash), at the served block of 16 and window of 8 blocks.
+# Each of the two bodies that read the slab as stored meets contexts that
+# end mid-block, mid-window, on a window's edge and at 0, a dead slot
+# inside a live cell (the sequences of a case share one grid cell unless
+# it says otherwise), a cell that is dead altogether, soft_cap > 0 and
+# layer_idx > 0; the grouped body also a sliding window of 512 rows whose
+# floor falls mid-block, on a window's edge, at 0 and below it.
+#
+# Every case's cache is poisoned: the rows past each context in its tail
+# block, every block past it and, under a window, every block wholly below
+# the floor hold NaN, so a body that lets an unfetched row reach a product
+# (0 x NaN) fails here.
 #
 # Tolerance. float32 cases: 2e-4, as before. bf16 cases: kernel and XLA
 # reference read the same bf16 q and cache and accumulate in float32; the
@@ -137,9 +149,9 @@ def build_random_cache(rng, layers, n, kh, d, bs=BS, dtype=jnp.float32):
 # ~2^-17 x max|v| ~ 3e-5 where values cancel near zero (atol 1e-4).
 F32, BF16 = jnp.float32, jnp.bfloat16
 DECODE_GEOMETRIES = [
-    ("kh4-g2-f32", 4, 2, 16, 4, 2, F32, [9, 16, 3], 1, 0.0),
-    ("kh2-g1-f32", 2, 1, 16, 4, 2, F32, [5, 0, 8, 13], 0, 0.0),
-    ("kh4-g2-f32-softcap", 4, 2, 16, 4, 2, F32, [9, 16, 3], 1, 30.0),
+    ("head-kh4-g2-f32", 4, 2, 16, 4, 2, F32, [9, 16, 3], 1, 0.0),
+    ("head-kh2-g1-f32", 2, 1, 16, 4, 2, F32, [5, 0, 8, 13], 0, 0.0),
+    ("head-kh4-g2-f32-softcap", 4, 2, 16, 4, 2, F32, [9, 16, 3], 1, 30.0),
     ("slab-kh16-mid-block-window-zero", 16, 1, 128, 16, 2, BF16,
      [37, 0, 64, 5], 1, 0.0),
     ("slab-kh16-dead-cell-long", 16, 1, 128, 16, 2, BF16,
@@ -149,9 +161,44 @@ DECODE_GEOMETRIES = [
     ("slab-kh16-serving-window", 16, 1, 128, 16, 8, BF16,
      [130, 7, 0, 128], 1, 0.0),
     ("slab-kh32", 32, 1, 128, 16, 2, BF16, [19, 40], 1, 0.0),
-    ("kh16-g1-f32-not-slab", 16, 1, 128, 16, 2, F32, [37, 0], 1, 0.0),
-    ("kh8-g4-bf16-not-slab", 8, 4, 128, 16, 2, BF16, [37, 0, 20, 64], 1,
-     0.0),
+    ("head-kh16-g1-f32", 16, 1, 128, 16, 2, F32, [37, 0], 1, 0.0),
+    ("grouped-kh8-g4-two-block-window", 8, 4, 128, 16, 2, BF16,
+     [37, 0, 20, 64], 1, 0.0),
+    # the served shapes: eight sequences a cell at KH 8, four at 12 heads
+    ("grouped-kh8-g4-edges", 8, 4, 128, 16, 8, BF16,
+     [37, 200, 128, 0, 256, 130, 16, 1], 1, 0.0),
+    ("grouped-kh8-g4-dead-cell", 8, 4, 128, 16, 8, BF16,
+     [0] * 8 + [97, 32, 1, 128, 0, 300, 5, 129], 0, 0.0),
+    ("grouped-kh8-g8-softcap-layer2", 8, 8, 128, 16, 8, BF16,
+     [50, 133, 0, 16, 128, 1, 0, 260], 2, 30.0),
+    ("grouped-kh8-g8-dead-cell", 8, 8, 128, 16, 8, BF16,
+     [0] * 8 + [0, 70, 0, 129, 256, 3, 0, 40], 1, 0.0),
+    # floors at 88 and 488 (mid-block), 3, below 0, 128 (a window's edge),
+    # 1 and 0
+    ("grouped-kh8-g4-window512", 8, 4, 128, 16, 8, BF16,
+     [600, 515, 100, 0, 1000, 640, 513, 512], 1, 0.0, 512),
+    ("grouped-kh8-g8-window512", 8, 8, 128, 16, 8, BF16,
+     [700, 90, 0, 530, 0, 0, 0, 0] + [0] * 8, 2, 30.0, 512),
+    ("grouped-kh12-g4-edges", 12, 4, 128, 16, 8, BF16,
+     [37, 200, 128, 0], 1, 0.0),
+    ("grouped-kh12-g4-dead-cell-softcap", 12, 4, 128, 16, 8, BF16,
+     [0, 0, 0, 0, 97, 0, 256, 1], 2, 30.0),
+    ("grouped-kh12-g4-window512", 12, 4, 128, 16, 8, BF16,
+     [600, 515, 0, 100, 1000, 640, 513, 512], 1, 0.0, 512),
+    # query rows that do not fill 16-row blocks of whole heads: all rows
+    # go past every head's tiles (Llama-3.2-3B's G 3, Qwen2-7B's KH 4, G 7)
+    ("grouped-kh8-g3-all-rows", 8, 3, 128, 16, 2, BF16,
+     [37, 0, 130, 64], 1, 0.0),
+    ("grouped-kh4-g7-all-rows", 4, 7, 128, 16, 2, BF16,
+     [20, 64, 0, 33], 0, 0.0, 24),
+    ("grouped-kh16-g2", 16, 2, 128, 16, 2, BF16, [37, 0, 64, 5], 1, 0.0),
+    # what keeps the per-head body: MHA under a window, a TP-4 shard's
+    # four-row slab, heads of 256
+    ("head-kh16-g1-bf16-window", 16, 1, 128, 16, 2, BF16,
+     [37, 0, 70, 5], 1, 0.0, 24),
+    ("head-kh2-g4-bf16-tp4-shard", 2, 4, 128, 16, 2, BF16,
+     [37, 0, 20, 64], 1, 0.0),
+    ("head-kh8-g2-d256-bf16", 8, 2, 256, 16, 2, BF16, [37, 0], 1, 30.0),
 ]
 
 
@@ -164,32 +211,60 @@ def _f32(x):
     return np.asarray(jnp.asarray(x).astype(jnp.float32))
 
 
+def _poisoned_cache(rng, layers, layer, lens, kh, d, bs, dtype, window=0):
+    """(poisoned cache, clean cache, tables): a block of its own for every
+    table entry, in shuffled order; in ``layer``, NaN in every row no walk
+    may read: past each context, and in the blocks wholly below a sliding
+    window's floor. The clean cache holds 0 there."""
+    B = len(lens)
+    M = -(-int(max(lens)) // bs) + 2
+    cache = np.array(rng.standard_normal((layers, B * M, bs, 2 * kh, d)),
+                     np.float32)
+    tables = rng.permutation(B * M).astype(np.int32).reshape(B, M)
+    unread = np.zeros(cache.shape[1:3], bool)  # (block, row)
+    for b, ctx in enumerate(lens):
+        pos = np.arange(M * bs).reshape(M, bs)
+        unread[tables[b]] = pos >= ctx
+        if window:
+            floor = max(int(ctx) - window, 0)
+            unread[tables[b]] |= pos // bs < floor // bs
+    clean = cache.copy()
+    cache[layer][unread] = np.nan
+    clean[layer][unread] = 0.0
+    return (jnp.asarray(cache, dtype), jnp.asarray(clean, dtype),
+            jnp.asarray(tables))
+
+
 @pytest.mark.parametrize(
     "geometry", [pytest.param(g, id=g[0]) for g in DECODE_GEOMETRIES])
 def test_pallas_decode_matches_xla_interpret(geometry):
     from production_stack_tpu.ops.paged_attention_pallas import (
         decode_slab_path,
+        decode_window_body,
         paged_decode_attention_pallas,
     )
 
-    name, kh, G, d, bs, windows, dtype, lens, layer, soft_cap = geometry
-    assert decode_slab_path(kh, G, d, dtype) == name.startswith("slab")
+    name, kh, G, d, bs, windows, dtype, lens, layer, soft_cap = geometry[:10]
+    window = geometry[10] if len(geometry) > 10 else 0
+    body = decode_window_body(kh, G, d, dtype, window)
+    assert body == name.split("-")[0]
+    assert decode_slab_path(kh, G, d, dtype, window) == (body != "head")
     rng = np.random.default_rng(2)
     lens = np.array(lens, np.int32)
-    B, layers = len(lens), 3
-    M = -(-int(lens.max()) // bs) + 2
-    N = 24
-    cache = build_random_cache(rng, layers, N, kh, d, bs, dtype)
-    tables = rng.integers(0, N, (B, M)).astype(np.int32)
+    B = len(lens)
+    cache, clean, tables = _poisoned_cache(rng, 3, layer, lens, kh, d, bs,
+                                           dtype, window)
     q = jnp.asarray(rng.standard_normal((B, kh * G, d)), dtype)
+    how = {"window": window} if window else {}
 
     got = paged_decode_attention_pallas(
-        q, cache, jnp.asarray(tables), jnp.asarray(lens),
-        layer, windows=windows, interpret=True, soft_cap=soft_cap,
+        q, cache, tables, jnp.asarray(lens),
+        layer, windows=windows, interpret=True, soft_cap=soft_cap, **how,
     )
     want = paged_attention(
-        q[:, None], cache[layer], jnp.asarray(tables),
+        q[:, None], clean[layer], tables,
         jnp.asarray(lens), jnp.asarray(lens - 1)[:, None], soft_cap=soft_cap,
+        **how,
     )[:, 0]
     assert got.dtype == q.dtype
     live = lens > 0
@@ -199,36 +274,79 @@ def test_pallas_decode_matches_xla_interpret(geometry):
 
 
 def test_decode_slab_path_predicate():
-    """The decode kernel's choice of body, from (KH, G, D, cache dtype)
-    alone: true for OLMoE and Ouro as served (per shard at TP 1), false
-    for grouped queries, a shard with fewer than 16 KV heads, other head
-    sizes and float32 caches."""
+    """The decode kernel's choice of body, from (KH, G, D, cache dtype,
+    window) alone, per shard: the slab body for OLMoE and Ouro as served,
+    the grouped body for the GQA families, the per-head body for a shard
+    with fewer than 4 KV heads, other head sizes, float32 caches and MHA
+    at another head count or under a window."""
     from production_stack_tpu.engine.config import ModelConfig
     from production_stack_tpu.ops.paged_attention_pallas import (
         decode_slab_path,
+        decode_window_body,
     )
 
-    def served(name, tp=1):
+    def served(name, tp=1, window=0):
         cfg = ModelConfig.from_pretrained(name)
-        return decode_slab_path(cfg.num_kv_heads // tp, cfg.q_per_kv,
-                                cfg.head_dim, cfg.jax_dtype)
+        return decode_window_body(
+            cfg.cache_kv_heads // tp, cfg.q_per_kv, cfg.cache_head_dim,
+            cfg.jax_dtype, window)
 
-    assert served("olmoe-1b-7b") and served("ouro-2.6b")
-    assert not served("qwen3-8b-class")      # KH 8, G 4
-    assert not served("olmoe-1b-7b", tp=4)   # a TP-4 shard: KH 4
-    assert not served("gemma-7b-class")      # D 256
-    assert not served("gemma2-9b-class")     # KH 8, G 2
-    assert not served("phi3-mini-class")     # KH 32, G 1, D 96
-    assert not served("tiny-llama")          # float32, small heads
-    assert not served("tiny-olmoe") and not served("tiny-ouro")  # G 1, f32
+    assert served("olmoe-1b-7b") == served("ouro-2.6b") == "slab"
+    assert served("qwen3-8b-class") == "grouped"      # KH 8, G 4
+    assert served("llama-3-8b") == served("mixtral-8x7b") == "grouped"
+    assert served("llama-3b-class") == "grouped"      # KH 8, G 3
+    assert served("qwen2-7b-class") == "grouped"      # KH 4, G 7
+    assert served("llama-3-70b", tp=2) == "grouped"   # a shard's KH 4, G 8
+    assert served("llama-3-70b", tp=4) == "head"      # KH 2: a 4-row slab
+    assert served("qwen3-8b-class", tp=4) == "head"
+    assert served("mistral-7b-class", window=4096) == "grouped"
+    assert served("olmoe-1b-7b", tp=4) == "head"      # a TP-4 shard: KH 4
+    assert served("olmoe-1b-7b", window=512) == "head"
+    assert served("gemma-7b-class") == "head"         # D 256
+    assert served("gemma2-9b-class") == "head"        # KH 8, G 2, D 256
+    assert served("phi3-mini-class") == "head"        # KH 32, G 1, D 96
+    assert served("tiny-llama") == "head"             # float32, small heads
+    assert served("tiny-olmoe") == served("tiny-ouro") == "head"  # G 1, f32
+    assert served("tiny-qwen3") == served("tiny-phi4flash") == "head"
+    for cell in ("qwen3-8b-l16", "solar-open2-250b-ep16-l8",
+                 "phi-4-mini-flash-reasoning"):
+        assert _cell_body(cell) == "grouped", cell
+    assert _cell_body("phi-4-mini-flash-reasoning", windowed=True) == "grouped"
+    assert _cell_body("olmoe-1b-7b-l8") == _cell_body("ouro-2.6b") == "slab"
     assert decode_slab_path(16, 1, 128, "bfloat16")
     assert decode_slab_path(32, 1, 128, jnp.bfloat16)
+    assert decode_slab_path(8, 4, 128, jnp.bfloat16)
+    assert decode_slab_path(12, 4, 128, jnp.bfloat16, 512)
+    assert not decode_slab_path(16, 1, 128, jnp.bfloat16, 512)
     assert not decode_slab_path(16, 1, 128, jnp.float32)
-    assert not decode_slab_path(16, 2, 128, jnp.bfloat16)
+    assert not decode_slab_path(8, 4, 128, jnp.float32)
     assert not decode_slab_path(8, 1, 128, jnp.bfloat16)
     assert not decode_slab_path(16, 1, 256, jnp.bfloat16)
+    assert not decode_slab_path(8, 4, 64, jnp.bfloat16)
     assert not decode_slab_path(24, 1, 128, jnp.bfloat16)
     assert not decode_slab_path(64, 1, 128, jnp.bfloat16)  # no chip run
+    assert not decode_slab_path(2, 4, 128, jnp.bfloat16)
+    assert not decode_slab_path(6, 4, 128, jnp.bfloat16)   # 12 rows a token
+    assert not decode_slab_path(32, 2, 128, jnp.bfloat16)  # no chip run
+
+
+def _cell_body(config: str, windowed: bool = False) -> str:
+    """The body a benchmark cell's decode calls take: the predicate at the
+    geometry of the cell's own configuration (chipbench/configs)."""
+    import pathlib
+
+    from production_stack_tpu.engine.config import ModelConfig
+    from production_stack_tpu.ops.paged_attention_pallas import (
+        decode_window_body,
+    )
+
+    cfg = ModelConfig.from_pretrained(str(
+        pathlib.Path(__file__).parent.parent / "chipbench" / "configs"
+        / config))
+    assert not windowed or cfg.sliding_window
+    return decode_window_body(
+        cfg.cache_kv_heads, cfg.q_per_kv, cfg.cache_head_dim, cfg.jax_dtype,
+        cfg.sliding_window if windowed else 0)
 
 
 def test_pallas_prefill_matches_xla_interpret():
@@ -332,6 +450,8 @@ def test_slot_mapping():
     pytest.param(4, 2, 16, 4, F32, [9, 21], id="kh4-g2-f32"),
     pytest.param(16, 1, 128, 16, BF16, [37, 70, 0, 16], id="slab-kh16"),
     pytest.param(16, 1, 128, 16, BF16, [1, 33], id="slab-kh16-one-token"),
+    pytest.param(8, 4, 128, 16, BF16, [37, 70, 0, 16], id="grouped-kh8-g4"),
+    pytest.param(12, 4, 128, 16, BF16, [1, 33], id="grouped-kh12-one-token"),
 ])
 def test_pallas_decode_poisoned_tail_blocks_ignored(kh, G, d, bs, dtype,
                                                     lens):

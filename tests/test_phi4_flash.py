@@ -589,6 +589,25 @@ def test_the_counters_say_what_ran(served):
     assert s["decode_attn_calls_total"] == 6 * steps  # 3 + 1 + 2 cross
 
 
+@pytest.mark.parametrize("plain,windowed,calls", [
+    (False, False, 0), (False, True, 3), (True, False, 3), (True, True, 6)])
+def test_slab_calls_are_counted_by_call_kind(plain, windowed, calls):
+    """vllm:decode_attn_slab_calls_total where a stack's layers differ: the
+    three window layers' calls count when the kernel's predicate holds
+    under the window, the full layer's and the two cross layers' when it
+    holds without one (on the CPU the runner says neither: set by hand)."""
+    eng = engine()
+    assert (eng.runner.decode_attn_slab,
+            eng.runner.decode_attn_slab_windowed) == (False, False)
+    eng.runner.decode_attn_slab = plain
+    eng.runner.decode_attn_slab_windowed = windowed
+    serve(eng, {"one": prompt(9, 3)}, max_tokens=5)
+    s = eng.stats()
+    assert eng.decode_dispatches > 0
+    assert s["decode_attn_calls_total"] == 6 * eng.decode_dispatches
+    assert s["decode_attn_slab_calls_total"] == calls * eng.decode_dispatches
+
+
 @pytest.mark.parametrize("ctx,read", [(5, 8), (8, 8), (9, 12), (64, 8),
                                       (66, 12)])
 def test_a_decode_rows_window_walk_in_tokens(ctx, read):
