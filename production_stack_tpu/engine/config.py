@@ -1072,20 +1072,10 @@ class SchedulerConfig:
     max_num_seqs: int = 64  # decode slots
     max_num_batched_tokens: int = 2048  # prefill chunk budget per step
     max_queue_len: int = 4096
-    prefill_chunk_size: int = 1024
-    # shape buckets: prefill token-lengths are padded up to one of these
-    prefill_buckets: tuple[int, ...] = (128, 256, 512, 1024, 2048, 4096, 8192)
     # decode iterations fused into one device dispatch (vLLM's
     # num-scheduler-steps): amortises host→device dispatch latency; stop
     # conditions are checked every multi_step tokens, surplus is discarded
     multi_step: int = 1
-    # prefill chunks batched into one dispatch (padded to a fixed P)
-    prefill_batch: int = 4
-    # prompts at least this long prefill via ring attention over the seq
-    # mesh axis (sequence parallelism; 0 = disabled). Takes effect only when
-    # the mesh has seq > 1 — the long-context path the reference lacks
-    # (SURVEY.md §5.7).
-    ring_prefill_threshold: int = 0
     # chain decode dispatches through device-resident tokens with the
     # sample fetch deferred one dispatch. Off: one chip run read TPOT
     # -9 % and TTFT +11 % (PERF.md section 7; ROADMAP D2 decides).
@@ -1093,11 +1083,11 @@ class SchedulerConfig:
     # n-gram (prompt-lookup) speculative decoding: propose up to this many
     # draft tokens per step from the sequence's own token history and
     # verify them inside the ragged unified dispatch (vLLM's ngram
-    # --speculative-config equivalent). 0 = off; requires
-    # attention_impl=ragged. Eligibility is per sequence — greedy rows
-    # speculate while sampled/penalised/controlled rows in the SAME batch
-    # decode normally — and a per-sequence acceptance EWMA adapts the
-    # width downward on cold sequences (spec.SpecController). Decode is
+    # --speculative-config equivalent). 0 = off. Eligibility is per
+    # sequence — greedy rows speculate while sampled/penalised/controlled
+    # rows in the SAME batch decode normally — and a per-sequence
+    # acceptance EWMA adapts the width downward on cold sequences
+    # (spec.SpecController). Decode is
     # weight-bandwidth bound at moderate batch, so accepting n drafts
     # multiplies tokens per weight read by (n+1); the verify span's extra
     # FLOPs ride the MXU headroom (docs/roofline.md).
@@ -1163,16 +1153,6 @@ class SchedulerConfig:
         blanket horizon."""
         return max(self.multi_step, 1)
 
-    def bucket_for(self, n: int, max_model_len: Optional[int] = None) -> int:
-        """The padded token length a chunk of n tokens compiles at — the ONE
-        source of bucket rounding (scheduler truncation and engine padding
-        must agree)."""
-        for b in self.prefill_buckets:
-            if b >= n:
-                return b if max_model_len is None else min(b, max_model_len)
-        top = max(self.prefill_buckets)
-        return top if max_model_len is None else min(top, max_model_len)
-
 
 @dataclasses.dataclass
 class PerfConfig:
@@ -1215,14 +1195,6 @@ class EngineConfig:
     # seconds an un-attached /kv/recv transfer may hold pool blocks
     # before the sweep reclaims them (leaked-transfer backstop)
     kv_transfer_ttl: float = 120.0
-    # attention dispatch shape: "ragged" packs prefill chunks and decode
-    # rows into ONE token stream per step (token-budget scheduling, a
-    # single steady-state compile signature — ops/
-    # ragged_paged_attention_pallas.py); "bucketed" is the legacy
-    # prefill-bucket + padded-decode path kept for rollback; "auto"
-    # picks ragged when the Pallas kernels are usable (TPU) and bucketed
-    # otherwise (CPU / head-geometry fallback)
-    attention_impl: str = "auto"  # "auto" | "ragged" | "bucketed"
     seed: int = 0
     # multi-LoRA bank: slot 0 is the base model, adapters occupy 1..max-1
     max_loras: int = 4
@@ -1250,7 +1222,7 @@ class EngineConfig:
     def for_model(name: str, **kw) -> "EngineConfig":
         model_kw = {k: v for k, v in kw.items() if hasattr(ModelConfig, k) and k != "mesh"}
         cfg = EngineConfig(model=ModelConfig.from_pretrained(name, **model_kw))
-        for field in ("cache", "scheduler", "mesh", "seed", "attention_impl"):
+        for field in ("cache", "scheduler", "mesh", "seed"):
             if field in kw:
                 setattr(cfg, field, kw[field])
         return cfg

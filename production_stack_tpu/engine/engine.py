@@ -83,20 +83,17 @@ class LLMEngine:
         self.config = config
         self.mesh = mesh if mesh is not None else build_mesh(config.mesh)
         self.tokenizer = get_tokenizer(config.model.tokenizer)
-        from production_stack_tpu.parallel.mesh import AXIS_STAGE
+        from production_stack_tpu.parallel.mesh import AXIS_SEQ, AXIS_STAGE
 
+        for axis in (AXIS_STAGE, AXIS_SEQ):
+            if self.mesh.shape[axis] > 1:
+                raise ValueError(
+                    f"a mesh with {axis}={self.mesh.shape[axis]} is not "
+                    "supported: pipeline stages and ring prefill were "
+                    "removed, shard a model over the tensor axis")
         if config.model.is_latent:
-            # the one call at start-up, before a runner is chosen: a staged
-            # runner builds its stages' runners on one-device submeshes
             ModelRunner._refuse_for_latent_cache(config, self.mesh)
-        if self.mesh.shape[AXIS_STAGE] > 1:
-            # pipeline-parallel serving: per-stage submeshes + KV pools
-            from production_stack_tpu.engine.pp_runner import StagedModelRunner
-
-            self.runner = StagedModelRunner(config, self.mesh, params,
-                                            num_blocks)
-        else:
-            self.runner = ModelRunner(config, self.mesh, params, num_blocks)
+        self.runner = ModelRunner(config, self.mesh, params, num_blocks)
         # where this thread's time goes, always on (engine/tracing.py);
         # the runner switches its own phases (snapshot, commit, launch)
         self.clock = self.runner.clock = StepClock()
@@ -109,7 +106,7 @@ class LLMEngine:
             max_model_len=config.model.max_model_len,
             recurrent_state=config.model.has_recurrent_state,
             window=self.window,
-            window_blocks=getattr(self.runner, "window_blocks", 0),
+            window_blocks=self.runner.window_blocks,
         )
         self.scheduler.now = self.clock.now
         # what the latent attention kernel of an MLA model was asked to
@@ -148,31 +145,27 @@ class LLMEngine:
 
             self.window_counters = WindowCounters(
                 config.model, config.cache.block_size)
-        # ragged unified step (ops/ragged_paged_attention_pallas.py): the
+        # the ragged step (ops/ragged_paged_attention_pallas.py): the
         # scheduler mixes decode rows and prefill chunks into one
         # token-budget batch, packed here into a single (1, T) stream
-        self.attention_impl = getattr(self.runner, "attention_impl",
-                                      "bucketed")
         self._pending_ragged = None
-        if self.attention_impl == "ragged":
-            sched = config.scheduler
-            if sched.max_num_batched_tokens < sched.max_num_seqs:
-                raise ValueError(
-                    "ragged attention needs max_num_batched_tokens "
-                    f"({sched.max_num_batched_tokens}) >= max_num_seqs "
-                    f"({sched.max_num_seqs}): every decode row claims one "
-                    "stream token per step"
-                )
-            self.scheduler.unified = True
-            T = sched.max_num_batched_tokens
-            self._r_tokens = np.zeros((1, T), np.int32)
-            self._r_positions = np.full((1, T), -1, np.int32)
-            self._r_slot_mapping = np.full(T, -1, np.int32)
-            self._r_window_slot_mapping = np.full(T, -1, np.int32)
-            self._r_adapter_ids = np.zeros(T, np.int32)
-            self._r_cu = np.zeros(sched.max_num_seqs + 1, np.int32)
-            self._r_last_idx = np.zeros(sched.max_num_seqs, np.int32)
-            self._r_sample_mask = np.zeros(sched.max_num_seqs, np.float32)
+        sched = config.scheduler
+        if sched.max_num_batched_tokens < sched.max_num_seqs:
+            raise ValueError(
+                "the ragged step needs max_num_batched_tokens "
+                f"({sched.max_num_batched_tokens}) >= max_num_seqs "
+                f"({sched.max_num_seqs}): every decode row claims one "
+                "stream token per step"
+            )
+        T = sched.max_num_batched_tokens
+        self._r_tokens = np.zeros((1, T), np.int32)
+        self._r_positions = np.full((1, T), -1, np.int32)
+        self._r_slot_mapping = np.full(T, -1, np.int32)
+        self._r_window_slot_mapping = np.full(T, -1, np.int32)
+        self._r_adapter_ids = np.zeros(T, np.int32)
+        self._r_cu = np.zeros(sched.max_num_seqs + 1, np.int32)
+        self._r_last_idx = np.zeros(sched.max_num_seqs, np.int32)
+        self._r_sample_mask = np.zeros(sched.max_num_seqs, np.float32)
         from production_stack_tpu.engine.kv_cache import (
             kv_cache_bytes_per_block,
         )
@@ -186,12 +179,6 @@ class LLMEngine:
         self.host_kv = maybe_make_store(
             config.cache, bytes_per_block=self._kv_bytes_per_block)
         self.remote_kv = maybe_make_remote(config.cache)
-        from production_stack_tpu.parallel.mesh import AXIS_SEQ
-
-        if (self.mesh.shape[AXIS_SEQ] > 1
-                and config.scheduler.ring_prefill_threshold > 0
-                and getattr(self.runner, "seq_parallel", False)):
-            self.scheduler.ring_enabled = True
         # tiered-KV closed loop (engine/kv_offload.py): admission starts an
         # async warm-tier prefix fetch (the sequence parks in PREFETCHING),
         # HBM eviction demotes to host, host eviction demotes to remote.
@@ -252,12 +239,6 @@ class LLMEngine:
         self._token_bytes = None  # lazy per-vocab byte images
         self._count_reset_slots: list[Sequence] = []
         self._slot_seq: dict[int, Sequence] = {}
-        # deferred prefill resolution: (prefills, device sampled array).
-        # The fetch of step i's sampled tokens is delayed until step i+1 has
-        # been DISPATCHED, so device compute + the result round trip overlap
-        # the host's next-step work (prefill dispatches don't consume the
-        # previous step's samples — only finished prompts' postprocess does)
-        self._pending_prefill = None
         # deferred decode resolution: consecutive decode dispatches with
         # identical slot membership chain their input tokens DEVICE-side
         # (the last sampled row feeds the next dispatch un-fetched), and the
@@ -270,19 +251,10 @@ class LLMEngine:
         self._pending_decode = None
         # n-gram speculative decoding (engine/spec.py): drafts ride the
         # ragged stream as short prefill-shaped spans and verification is
-        # fused into the one ragged program (no standalone verify) — so
-        # speculation requires the ragged attention impl. Eligibility is
-        # per sequence and the draft width adapts via acceptance EWMA.
+        # fused into the one ragged program (no standalone verify).
+        # Eligibility is per sequence and the draft width adapts via
+        # acceptance EWMA.
         k = config.scheduler.spec_ngram_k
-        if k > 0 and self.attention_impl != "ragged":
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "speculative decoding disabled: verification is fused into "
-                "the ragged unified dispatch and attention_impl=%s has none "
-                "(spec_ngram_k=%d ignored)", self.attention_impl, k
-            )
-            config.scheduler.spec_ngram_k = k = 0
         self._spec = None
         if k > 0:
             from production_stack_tpu.engine.spec import SpecController
@@ -302,10 +274,9 @@ class LLMEngine:
         self.spec_step_tokens = 0  # tokens those row-steps emitted
         self.aborted_seqs = 0  # cancelled/expired, KV freed early
         self.spliced_seqs = 0  # pushed P→D transfers attached decode-ready
-        # unified ragged dispatch accounting (attention_impl == "ragged"):
-        # live packed tokens vs the budget is the padding-waste signal the
-        # bucketed path hid in bucket geometry; narrow: the dispatches that
-        # ran under the budget's width (SchedulerConfig.ragged_stream_widths)
+        # ragged dispatch accounting: live packed tokens vs the budget is
+        # the padding-waste signal; narrow: the dispatches that ran under
+        # the budget's width (SchedulerConfig.ragged_stream_widths)
         self.ragged_dispatches = 0
         self.ragged_narrow_dispatches = 0
         self.ragged_live_tokens = 0
@@ -331,9 +302,7 @@ class LLMEngine:
         # async worker sets it; None means step() returns everything
         self.output_sink = None
         self.early_handovers = 0  # hand-overs made before a wait
-        # goodput accounting + compile tracking (perf_accounting.py); the
-        # staged PP runner exposes no single param tree or jit programs to
-        # wrap, so it only gets dispatch accounting
+        # goodput accounting + compile tracking (perf_accounting.py)
         self.perf = None
         if config.perf.enabled:
             from production_stack_tpu.engine.perf_accounting import (
@@ -341,8 +310,7 @@ class LLMEngine:
             )
 
             self.perf = PerfAccountant.from_runner(config, self.runner)
-            if hasattr(self.runner, "install_compile_observer"):
-                self.runner.install_compile_observer(self._on_compile)
+            self.runner.install_compile_observer(self._on_compile)
 
     def _on_compile(self, kind: str, bucket: str, seconds: float) -> None:
         # the step clock names a slow step's cause `compile` if this moved
@@ -378,10 +346,6 @@ class LLMEngine:
         if sampling.logprobs is not None:
             from production_stack_tpu.engine.sampling import MAX_LOGPROBS
 
-            if not getattr(self.runner, "supports_logprobs", False):
-                raise ValueError(
-                    "logprobs are not supported with pipeline parallelism"
-                )
             if not 0 <= sampling.logprobs <= MAX_LOGPROBS:
                 raise ValueError(
                     f"logprobs must be in [0, {MAX_LOGPROBS}]"
@@ -403,11 +367,6 @@ class LLMEngine:
                            sampling, self.config.model.vocab_size))
         self._stamp_arrival(seq, enqueued)
         if sampling.guided_regex is not None or sampling.guided_json is not None:
-            if not hasattr(self.runner, "register_grammar"):
-                raise ValueError(
-                    "guided decoding is not supported with pipeline "
-                    "parallelism"
-                )
             ent = self._acquire_grammar(sampling)
             seq.grammar_slot = ent["slot"]
             seq.fsm = ent["fsm"]
@@ -520,7 +479,6 @@ class LLMEngine:
         out = self.scheduler.schedule()
         if out.is_empty:
             outputs = self._resolve_pending_ragged()
-            outputs.extend(self._resolve_pending_prefill())
             outputs.extend(self._resolve_pending_decode())
             if (not outputs and self._prefetcher is not None
                     and self._prefetcher.jobs):
@@ -534,22 +492,13 @@ class LLMEngine:
                     self.clock.enter("postprocess") - t0)
             return outputs
         if out.prefills:
-            if self.attention_impl == "ragged" and not out.prefills[0].ring:
-                # unified path: prefill chunks and decode rows share ONE
-                # packed dispatch (a single steady-state compile signature)
-                return self._run_ragged(out)
-            # stream out any decode tokens still in flight before the
-            # prefill phase takes over the device
-            outputs = self._resolve_pending_ragged()
-            outputs.extend(self._resolve_pending_decode())
-            outputs.extend(self._run_prefill(out.prefills))
-            return outputs
-        # decode consumes the first sampled token: the deferred prefill
-        # must land before decode inputs are built — and resolving may
-        # FINISH sequences (max_tokens=1) the scheduler already put in
+            # prefill chunks and decode rows share ONE packed dispatch
+            return self._run_ragged(out)
+        # decode consumes the first sampled token: the deferred ragged
+        # step must land before decode inputs are built — and resolving
+        # may FINISH sequences (max_tokens=1) the scheduler already put in
         # this step's decode batch
         outputs = self._resolve_pending_ragged()
-        outputs.extend(self._resolve_pending_prefill())
         decodes = [s for s in out.decodes
                    if s.status is SequenceStatus.RUNNING]
         if decodes:
@@ -642,19 +591,6 @@ class LLMEngine:
         t0 = self.clock.wait(kind)
         fetched = jax.device_get(result_dev)
         return fetched, self.clock.enter("postprocess") - t0
-
-    def _resolve_pending_prefill(self) -> list[RequestOutput]:
-        """Fetch + postprocess the previous prefill dispatch (if any)."""
-        if self._pending_prefill is None:
-            return []
-        prefills, result_dev = self._pending_prefill
-        self._pending_prefill = None
-        fetched, _ = self._fetch(result_dev, "prefill")
-        if isinstance(fetched, (tuple, list)):  # (sampled, *logprob arrays)
-            fetched = tuple(np.asarray(x) for x in fetched)
-        else:  # staged PP runner: bare sampled tokens
-            fetched = (np.asarray(fetched),)
-        return self._finish_prefill(prefills, fetched)
 
     # -- tiered KV (HBM ↔ host ↔ remote; see engine/kv_offload.py) -----------
     def _wire_tier_hooks(self) -> None:
@@ -783,72 +719,6 @@ class LLMEngine:
                 self.remote_kv.put_slab(h, slab)
                 self.tier_bytes[("remote", "out")] += slab.nbytes
 
-    def _bucket(self, n: int) -> int:
-        return self.config.scheduler.bucket_for(n, self.config.model.max_model_len)
-
-    def _run_prefill_ring(self, sp) -> list[RequestOutput]:
-        """Whole-prompt sequence-parallel prefill (ring attention over the
-        seq mesh axis) for one long fresh prompt; decode continues on the
-        normal paged path."""
-        from production_stack_tpu.parallel.mesh import AXIS_SEQ
-
-        bs = self.config.cache.block_size
-        seq = sp.seq
-        n = sp.chunk_len
-        n_seq = self.mesh.shape[AXIS_SEQ]
-        self.clock.enter("build")
-        # pad to a power of two (one compile per size class), then up to a
-        # multiple of the seq axis so shard_map can split it
-        S = max(2 * n_seq, 1 << (n - 1).bit_length())
-        S = -(-S // n_seq) * n_seq
-        tokens = np.zeros((1, S), np.int32)
-        tokens[0, :n] = seq.token_ids[:n]
-        positions = np.broadcast_to(np.arange(S, dtype=np.int32), (1, S))
-        slot_mapping = np.full(S, -1, np.int32)
-        slot_mapping[:n] = slot_mapping_for(seq.block_ids, 0, n, bs)
-        s = seq.sampling
-        self.clock.describe("prefill", rows=1, tokens=n)
-        t_call = self.clock.enter("snapshot")
-        result = self.runner.prefill_ring(
-            tokens, positions, slot_mapping,
-            np.asarray([n - 1], np.int32),
-            np.asarray([s.temperature], np.float32),
-            np.asarray([s.top_p], np.float32),
-            np.asarray([s.top_k], np.int32),
-            np.asarray([s.seed or 0], np.uint32),
-            greedy_only=s.temperature <= 0.0,
-            adapter_ids=(np.asarray([seq.adapter_slot], np.int32)
-                         if seq.adapter_slot else None),
-            ctrl=(
-                (seq.token_ctrl[0][None, :], seq.token_ctrl[1][None, :],
-                 np.asarray([seq.token_ctrl[2]], np.int32))
-                if seq.token_ctrl is not None else None
-            ),
-        )
-        dispatch_s = self.clock.enter("postprocess") - t_call
-        self._note_prompt_dispatch(seq)
-        if self.perf is not None:
-            entries = [(seq, "prefill", n, n)]
-            self.perf.record_prefill(n, n, 1, seconds=dispatch_s,
-                                     tenants=self._tenant_map(entries))
-            self._attribute_seq_seconds(dispatch_s, entries)
-        seq.num_computed_tokens = n
-        seq.status = SequenceStatus.RUNNING
-        self._slot_seq[seq.slot] = seq
-        if s.presence_penalty or s.frequency_penalty:
-            self._count_reset_slots.append(seq)
-        if seq.output_token_ids:
-            return []  # preemption-recompute: newest token still pending
-        token = int(result[0][0])
-        self._stamp_first_token(seq)
-        seq.output_token_ids.append(token)
-        self.total_output_tokens += 1
-        lp_lists = (
-            [[_lp_row(result[1:], 0)]]
-            if seq.sampling.logprobs is not None else [None]
-        )
-        return self._postprocess([seq], [[token]], lp_lists)
-
     # -- tenant attribution (observe-only; production_stack_tpu/tenancy.py) --
     def _tenant_map(self, entries) -> Optional[dict]:
         """Per-tenant token shares of one dispatch, from ``(seq, phase,
@@ -879,150 +749,7 @@ class LLMEngine:
         for seq, _, _, _ in entries:
             seq.chip_seconds += shares.get(seq.request_id, 0.0)
 
-    def _run_prefill(self, prefills: list) -> list[RequestOutput]:
-        if prefills[0].ring:
-            outputs = self._resolve_pending_prefill()
-            outputs.extend(self._run_prefill_ring(prefills[0]))
-            return outputs
-        bs = self.config.cache.block_size
-        self.clock.enter("build")
-        # batch-dim padded to the next power of two: inactive rows skip
-        # attention but still pay QKV/MLP, so padding 2 live 512-token
-        # chunks to P=8 would burn 4x the prefill FLOPs (measured: the
-        # long-context phase ran at 1/3 of the raw prefill rate). Pow-2
-        # classes keep the compile-variant count logarithmic.
-        P = 1 << (len(prefills) - 1).bit_length()
-        P = min(P, self.config.scheduler.prefill_batch)
-        M = self.runner.max_blocks_per_seq
-        bucket = self._bucket(max(sp.chunk_len for sp in prefills))
-
-        tokens = np.zeros((P, bucket), np.int32)
-        positions = np.full((P, bucket), -1, np.int32)
-        slot_mapping = np.full((P, bucket), -1, np.int32)
-        tables = np.zeros((P, M), np.int32)
-        context_lens = np.zeros(P, np.int32)  # 0 = inactive row
-        last_idx = np.zeros(P, np.int32)
-        temps = np.zeros(P, np.float32)
-        top_ps = np.ones(P, np.float32)
-        top_ks = np.full(P, -1, np.int32)
-        seeds = np.zeros(P, np.uint32)
-        adapter_ids = np.zeros(P, np.int32)
-        g_ids = np.full(P, -1, np.int32)
-
-        for i, sp in enumerate(prefills):
-            seq = sp.seq
-            tokens[i, : sp.chunk_len] = seq.token_ids[
-                sp.chunk_start : sp.chunk_start + sp.chunk_len
-            ]
-            positions[i, : sp.chunk_len] = np.arange(
-                sp.chunk_start, sp.chunk_start + sp.chunk_len
-            )
-            slot_mapping[i, : sp.chunk_len] = slot_mapping_for(
-                seq.block_ids, sp.chunk_start, sp.chunk_len, bs
-            )
-            tables[i, : len(seq.block_ids)] = seq.block_ids
-            context_lens[i] = sp.chunk_start + sp.chunk_len
-            last_idx[i] = sp.chunk_len - 1
-            s = seq.sampling
-            temps[i] = s.temperature
-            top_ps[i] = s.top_p
-            top_ks[i] = s.top_k
-            seeds[i] = s.seed or 0
-            adapter_ids[i] = seq.adapter_slot
-            # the grammar constrains the FIRST sampled token only when this
-            # chunk completes the prompt
-            if seq.grammar_slot >= 0 and sp.chunk_start + sp.chunk_len >= seq.prefill_target:
-                g_ids[i] = seq.grammar_slot
-
-        greedy_only = all(sp.seq.sampling.temperature <= 0.0 for sp in prefills)
-        use_lora = any(sp.seq.adapter_slot for sp in prefills)
-        ctrl = None
-        if any(sp.seq.token_ctrl is not None for sp in prefills):
-            from production_stack_tpu.engine.sampling import (
-                MAX_TOKEN_CONTROLS,
-            )
-
-            c_ids = np.full((P, MAX_TOKEN_CONTROLS), -1, np.int32)
-            c_vals = np.zeros((P, MAX_TOKEN_CONTROLS), np.float32)
-            c_mode = np.zeros(P, np.int32)
-            for i, sp in enumerate(prefills):
-                if sp.seq.token_ctrl is not None:
-                    c_ids[i], c_vals[i], c_mode[i] = sp.seq.token_ctrl
-            ctrl = (c_ids, c_vals, c_mode)
-        use_grammar = bool((g_ids >= 0).any())
-        self.clock.describe("prefill", rows=len(prefills),
-                            tokens=sum(sp.chunk_len for sp in prefills))
-        t_call = self.clock.enter("snapshot")
-        sampled_dev = self.runner.prefill(
-            tokens, positions, tables, context_lens, slot_mapping.reshape(-1),
-            last_idx, temps, top_ps, top_ks, seeds, greedy_only=greedy_only,
-            adapter_ids=adapter_ids if use_lora else None,
-            ctrl=ctrl,
-            g_ids=g_ids if use_grammar else None,
-            fetch=False,
-        )
-        dispatch_s = self.clock.enter("postprocess") - t_call
-        for sp in prefills:
-            self._note_prompt_dispatch(sp.seq)
-        if self.perf is not None:
-            entries = [(sp.seq, "prefill", sp.chunk_len, sp.chunk_len)
-                       for sp in prefills]
-            self.perf.record_prefill(
-                sum(sp.chunk_len for sp in prefills),
-                int(context_lens.sum()), len(prefills),
-                seconds=dispatch_s, tenants=self._tenant_map(entries),
-            )
-            self._attribute_seq_seconds(dispatch_s, entries)
-
-        # scheduler-visible state advances NOW (the next step's scheduling
-        # depends on it); the sampled tokens are fetched one step LATER so
-        # this dispatch's device time + result round trip overlap the
-        # host's next-step work (see _resolve_pending_prefill)
-        resolve_list = []
-        for i, sp in enumerate(prefills):
-            seq = sp.seq
-            seq.num_computed_tokens = sp.chunk_start + sp.chunk_len
-            if not seq.prefill_done:
-                continue  # more chunks to go
-            seq.status = SequenceStatus.RUNNING
-            self._slot_seq[seq.slot] = seq
-            s = seq.sampling
-            if s.presence_penalty or s.frequency_penalty:
-                # fresh prompt: the prefill-sampled token must count;
-                # recompute: restore the full output history
-                self._count_reset_slots.append(seq)
-            if seq.output_token_ids:
-                # preemption-recompute: context rebuilt, newest token still
-                # the pending decode input — nothing sampled this step
-                continue
-            resolve_list.append((i, seq))
-        outputs = self._resolve_pending_prefill()
-        self._pending_prefill = (resolve_list, sampled_dev)
-        return outputs
-
-    def _finish_prefill(self, resolve_list, fetched) -> list[RequestOutput]:
-        sampled = fetched[0]
-        lp = fetched[1:] if len(fetched) > 1 else None
-        finished_prompts, first_tokens, lp_lists = [], [], []
-        for i, seq in resolve_list:
-            if seq.status.is_finished:
-                continue  # aborted while the dispatch was in flight
-            token = int(sampled[i])
-            self._stamp_first_token(seq)
-            seq.output_token_ids.append(token)
-            if seq.grammar_slot >= 0 and seq.fsm is not None:
-                seq.fsm_state = int(seq.fsm.trans[0, token])
-            self.total_output_tokens += 1
-            finished_prompts.append(seq)
-            first_tokens.append([token])
-            lp_lists.append(
-                [_lp_row(lp, i)]
-                if lp is not None and seq.sampling.logprobs is not None
-                else None
-            )
-        return self._postprocess(finished_prompts, first_tokens, lp_lists)
-
-    # -- unified ragged step (attention_impl == "ragged") --------------------
+    # -- the ragged step ----------------------------------------------------
     def _run_ragged(self, out, proposed: bool = False) -> list[RequestOutput]:
         """ONE dispatch for a mixed step: every decode row contributes one
         token (or a 1 + drafts speculative span), FCFS prefill chunks fill
@@ -1037,7 +764,6 @@ class LLMEngine:
         bs = self.config.cache.block_size
         outputs = self._resolve_pending_ragged()
         outputs.extend(self._resolve_pending_decode())
-        outputs.extend(self._resolve_pending_prefill())
         decodes = [s for s in out.decodes
                    if s.status is SequenceStatus.RUNNING]
         prefills = [sp for sp in out.prefills
@@ -1186,8 +912,8 @@ class LLMEngine:
             s.sampling.temperature <= 0.0 for s in seqs_in_step
         )
         use_lora = any(s.adapter_slot for s in seqs_in_step)
-        # prefill rows never penalize their first sample (matches the
-        # bucketed path); penalties gate on the decode rows only
+        # prefill rows never penalize their first sample; penalties gate
+        # on the decode rows only
         use_penalties = any(
             s.sampling.presence_penalty or s.sampling.frequency_penalty
             for s in decodes
@@ -1267,7 +993,7 @@ class LLMEngine:
             self.ragged_attn_interior_windows += interior
 
         # scheduler-visible state advances NOW; results land next step
-        # (same deferral contract as _run_prefill / chained decode). A spec
+        # (same deferral contract as chained decode). A spec
         # row advances only its guaranteed token here — position pos holds
         # the last ACCEPTED token's KV regardless of draft outcome; the
         # accepted-draft advance happens at resolve, which for spec steps
@@ -1418,19 +1144,15 @@ class LLMEngine:
     def _run_decode(self, decodes: list[Sequence],
                     outputs: list[RequestOutput]) -> None:
         """One decode dispatch over ``decodes``. ``outputs`` holds what
-        the step resolved before it (the pending ragged or prefill
-        dispatch's tokens): it goes to the output sink once the decode
+        the step resolved before it (the pending ragged dispatch's
+        tokens): it goes to the output sink once the decode
         program is launched and before the thread waits for it
         (`_hand_over`), and this dispatch's outputs are appended to what
         is left of it."""
         bs = self.config.cache.block_size
-        use_logprobs = (
-            getattr(self.runner, "supports_logprobs", False)
-            and any(s.sampling.logprobs is not None for s in decodes)
-        )
+        use_logprobs = any(s.sampling.logprobs is not None for s in decodes)
         use_grammar = any(s.grammar_slot >= 0 for s in decodes)
         can_chain = (self.config.scheduler.chain_decode
-                     and getattr(self.runner, "supports_chaining", False)
                      and not use_logprobs  # chained results stay on device
                      and not use_grammar)  # host mirrors the FSM state
         pending = self._pending_decode
@@ -1438,8 +1160,7 @@ class LLMEngine:
             # identity check on request ids, not slots: a freed slot can
             # be reused by a different sequence within one step window
             same = (can_chain
-                    and [s.request_id for s in decodes] == pending["rids"]
-                    and self._pending_prefill is None)
+                    and [s.request_id for s in decodes] == pending["rids"])
             if not same:
                 # membership changed: land the in-flight tokens, then
                 # rebuild from post-resolution state
@@ -1508,12 +1229,6 @@ class LLMEngine:
         K = max(self.config.scheduler.multi_step, 1)
         self.clock.describe("decode", rows=len(decodes),
                             tokens=K * len(decodes))
-        # a runner that cannot chain (the staged pipeline) relays every
-        # decode step through the host and returns with the tokens on it:
-        # there the hand-over comes before the call, not after the launch
-        launches = getattr(self.runner, "supports_chaining", False)
-        if not launches:
-            self._hand_over(outputs)
         t_call = self.clock.enter("snapshot")
         result = self.runner.decode_multi(
             self._tokens, self._positions, self._block_tables,
@@ -1548,20 +1263,16 @@ class LLMEngine:
         windowed = K * self.config.model.count_layers("swa")
         self.decode_attn_slab_calls += (
             (attn_calls - windowed)
-            * getattr(self.runner, "decode_attn_slab", False)
-            + windowed
-            * getattr(self.runner, "decode_attn_slab_windowed", False))
+            * self.runner.decode_attn_slab
+            + windowed * self.runner.decode_attn_slab_windowed)
         pend = {"decodes": list(decodes), "slots": [s.slot for s in decodes]}
-        if launches:
-            pend["sampled"], next_tok, pend["counters"], *lp = result
-            pend["lp"] = lp  # empty unless the variant returns logprobs
-            if not can_chain:
-                # the program is in flight: the event loop works on what
-                # is handed over while this thread waits in the fetch
-                self._hand_over(outputs)
-                dispatch_s += self._fetch_decode(pend)
-        else:
-            pend["sampled"] = result  # (K, B) on the host already
+        pend["sampled"], next_tok, pend["counters"], *lp = result
+        pend["lp"] = lp  # empty unless the variant returns logprobs
+        if not can_chain:
+            # the program is in flight: the event loop works on what is
+            # handed over while this thread waits in the fetch
+            self._hand_over(outputs)
+            dispatch_s += self._fetch_decode(pend)
         if self.perf is not None:
             entries = [(seq, "decode", K, K) for seq in decodes]
             self.perf.record_decode(
@@ -1612,9 +1323,9 @@ class LLMEngine:
     def _finish_decode(self, pending,
                        advance: bool = False) -> list[RequestOutput]:
         """Append + stop-check one decode dispatch's sampled tokens, on
-        the host by now (`_fetch_decode`, or a runner that returned them
-        there). ``advance`` moves num_computed here, for a dispatch that
-        was not deferred (the chained path advances it at dispatch)."""
+        the host by now (`_fetch_decode`). ``advance`` moves num_computed
+        here, for a dispatch that was not deferred (the chained path
+        advances it at dispatch)."""
         sampled = pending["sampled"]
         # [tok_lp (K, B), ids (K, B, N), lps (K, B, N)], or nothing
         lp = pending.get("lp")
@@ -2045,16 +1756,23 @@ class LLMEngine:
         self.runner.restore_kv()
         self.sleep_level = 0
 
+    def _dense_len(self, n: int) -> int:
+        """The padded length a dense (cache-free) scoring pass over ``n``
+        tokens compiles at: powers of two from 128, within the model's
+        positions."""
+        return min(max(128, 1 << (n - 1).bit_length()),
+                   self.config.model.max_model_len)
+
     def embed(self, prompt_token_ids: list[int]) -> "np.ndarray":
         """Mean-pooled final hidden state — the /v1/embeddings surface (the
         reference proxies this to vLLM embedding models; a causal LM's
         pooled hidden is the standard fallback encoder)."""
         import numpy as np
 
-        bucket = self._bucket(len(prompt_token_ids))
-        tokens = np.zeros((1, bucket), np.int32)
+        S = self._dense_len(len(prompt_token_ids))
+        tokens = np.zeros((1, S), np.int32)
         tokens[0, : len(prompt_token_ids)] = prompt_token_ids
-        mask = np.zeros((1, bucket), np.int32)
+        mask = np.zeros((1, S), np.int32)
         mask[0, : len(prompt_token_ids)] = 1
         return self.runner.pooled_embed(tokens, mask)[0]
 
@@ -2069,9 +1787,9 @@ class LLMEngine:
         n = len(choices_ids)
         N = 1 << (n - 1).bit_length() if n else 1  # pow-2 compile classes
         total = len(prompt_token_ids) + max(len(c) for c in choices_ids)
-        S = self._bucket(total)
-        if S < total:  # bucket_for clamps at the top prefill bucket —
-            # scoring runs dense, so pad to the next power of two instead
+        S = self._dense_len(total)
+        if S < total:  # past the model's positions: the pass runs dense,
+            # so pad to the next power of two all the same
             S = 1 << (total - 1).bit_length()
         tokens = np.zeros((N, S), np.int32)
         cont = np.zeros((N, S), bool)
@@ -2098,8 +1816,8 @@ class LLMEngine:
 
     def warmup(self) -> None:
         """Pre-compile every serving shape variant so no live request pays a
-        compile: each prefill bucket at P=1, the P=prefill_batch variant,
-        the greedy and general samplers, and the decode program."""
+        compile: the ragged program at each stream width and the decode
+        program, greedy and sampled, and their static-flag variants."""
         # the admission bound is client back-pressure; warmup's internal
         # bursts must not trip it (a small --max-queue-len would otherwise
         # kill the server at startup)
@@ -2120,11 +1838,6 @@ class LLMEngine:
         rng = np.random.default_rng(0)
         sched = self.config.scheduler
         vocab = self.config.model.vocab_size
-        buckets = [
-            b for b in sched.prefill_buckets
-            if b <= self.config.model.max_model_len
-        ]
-
         decoding = max(sched.multi_step, 1) + 1  # forces one decode
         longest = max(self.config.model.max_model_len
                       - sched.multi_step - 2, 1)
@@ -2152,61 +1865,27 @@ class LLMEngine:
         # token totals that land a ragged step in each stream width
         # (SchedulerConfig.ragged_stream_widths), narrowest first: the
         # 8-token prompts of the feature runs below, then one token over
-        # each width but the budget. The bucketed path has no widths
-        lands = [8]
-        if self.attention_impl == "ragged":
-            lands += [w + 1 for w in sched.ragged_stream_widths[:-1]]
-            # the ragged program's signature is shape-independent of the
-            # traffic but for the stream's width (slots always
-            # max_num_seqs): ONE greedy + ONE sampled run a width covers
-            # the whole bucket x row-class matrix the bucketed path has to
-            # walk. The sampled run is a mixed multi-prompt batch: same
-            # signature, but exercises the packed multi-span path once
-            # before traffic does. A narrow width's runs end at their
-            # first token (the decode program is not theirs). The
-            # feature-variant runs below flow through the same unified
-            # step and compile their static-flag variants, at every width:
-            # grammar and controls. Logprobs ride every ragged dispatch, a
-            # penalty gates on decode rows (a prompt alone runs the plain
-            # program) and speculation's verify columns ride every
-            # dispatch: those three reach no ragged signature but these.
-            for total in lands[:-1]:
-                run(packed(total), 0.0, max_tokens=1)
-                run(packed(total, spans=4), 0.7, max_tokens=1)
-            n = min(sched.max_num_batched_tokens, longest)
-            run(packed(n), 0.0)
-            run(packed(n, spans=4), 0.7)
-        else:
-            for b in buckets:
-                n = max(min(b, sched.max_num_batched_tokens,
-                            self.config.model.max_model_len
-                            - sched.multi_step - 2),
-                        1)
-                if self._bucket(n) != b:
-                    continue  # budget caps chunks below this bucket: unused
-                run([rng.integers(1, vocab, n).tolist()], 0.0)
-            # every reachable (pow-2 rows, bucket) prefill variant, greedy
-            # and sampled: rows pad to the next power of two of the live
-            # chunk count (capped at prefill_batch — the cap itself is a
-            # class when prefill_batch isn't a power of two), and a
-            # bucket-b step can carry at most budget//(b/2+1)+1 chunks
-            budget = sched.max_num_batched_tokens
-            row_classes = sorted({
-                min(1 << i, sched.prefill_batch)
-                for i in range(
-                    1, max((sched.prefill_batch - 1).bit_length(), 0) + 1)
-            })
-            for b in buckets:
-                lo = b // 2 + 1 if b > buckets[0] else 1
-                max_rows = min(sched.prefill_batch, budget // lo + 1)
-                for p in row_classes:
-                    if p > max_rows:
-                        break
-                    n = min(lo + 1, b)
-                    batch = [rng.integers(1, vocab, n).tolist()
-                             for _ in range(p)]
-                    run(batch, 0.0)
-                    run(batch, 0.7)
+        # each width but the budget
+        lands = [8] + [w + 1 for w in sched.ragged_stream_widths[:-1]]
+        # the ragged program's signature is shape-independent of the
+        # traffic but for the stream's width (slots always max_num_seqs):
+        # ONE greedy + ONE sampled run a width. The sampled run is a mixed
+        # multi-prompt batch: same signature, but exercises the packed
+        # multi-span path once before traffic does. A narrow width's runs
+        # end at their first token (the decode program is not theirs). The
+        # feature-variant runs below flow through the same step and
+        # compile their static-flag variants, at every width: grammar and
+        # controls. Logprobs ride every ragged dispatch, a penalty gates
+        # on decode rows (a prompt alone runs the plain program) and
+        # speculation's verify columns ride every dispatch: those three
+        # reach no ragged signature but these.
+        for total in lands[:-1]:
+            run(packed(total), 0.0, max_tokens=1)
+            run(packed(total, spans=4), 0.7, max_tokens=1)
+        # the budget's own width: as many prompts as make it up, where
+        # the model's positions are fewer than the budget
+        run(packed(sched.max_num_batched_tokens), 0.0)
+        run(packed(sched.max_num_batched_tokens, spans=4), 0.7)
         # speculative decoding needs no dedicated warmup program: verify is
         # fused into the ragged step and verify_idx rides EVERY dispatch,
         # so the runs above already compiled the verify-bearing signature.
@@ -2222,30 +1901,25 @@ class LLMEngine:
             while self.has_unfinished():
                 self.step()
         # logprob decode variants (static want_logprobs flag), greedy and
-        # sampled; the prefill program carries logprobs unconditionally so
-        # no per-bucket variant exists. Combinations with penalties/
-        # controls compile lazily if ever used (same tradeoff as the
-        # penalties x controls cross). The staged PP runner has no logprob
-        # programs (add_request rejects such requests there).
-        for temp in ((0.0, 0.7)
-                     if getattr(self.runner, "supports_logprobs", False)
-                     else ()):
+        # sampled; the ragged program carries logprobs unconditionally.
+        # Combinations with penalties/controls compile lazily if ever
+        # used (same tradeoff as the penalties x controls cross).
+        for temp in (0.0, 0.7):
             run(packed(lands[0]), temp, logprobs=5)
-        # guided-decoding variants (static use_grammar flag): prefill's
-        # first-token mask + the fused decode FSM advance, greedy and
-        # sampled. Also pays the one-time vocab byte-image build here
-        # instead of on the first live guided request.
-        if hasattr(self.runner, "register_grammar"):
-            for temp in (0.0, 0.7):
-                run(packed(lands[0]), temp, guided_regex="[ -~]*")
-                for total in lands[1:]:  # the mask at the wider streams
-                    run(packed(total), temp, max_tokens=1,
-                        guided_regex="[ -~]*")
+        # guided-decoding variants (static use_grammar flag): the first
+        # token's mask + the fused decode FSM advance, greedy and sampled.
+        # Also pays the one-time vocab byte-image build here instead of
+        # on the first live guided request.
+        for temp in (0.0, 0.7):
+            run(packed(lands[0]), temp, guided_regex="[ -~]*")
+            for total in lands[1:]:  # the mask at the wider streams
+                run(packed(total), temp, max_tokens=1,
+                    guided_regex="[ -~]*")
         # penalised decode variant (static use_penalties flag)
         run(packed(lands[0]), 0.0, presence_penalty=0.5)
         # token-controls variants (static use_controls flag): the first
         # logit_bias/allowed_token_ids request must not stall on a
-        # mid-traffic recompile of the fused decode + prefill graphs
+        # mid-traffic recompile of the fused decode + ragged graphs
         # guided-choice scorer: one representative (N, S) variant so the
         # first guided request doesn't compile mid-traffic
         self.choice_logprobs([1, 2, 3, 4], [[5], [6, 7]])
@@ -2253,19 +1927,6 @@ class LLMEngine:
             run(packed(lands[0]), temp, logit_bias={1: 0.0})
             for total in lands[1:]:  # and at the wider streams
                 run(packed(total), temp, max_tokens=1, logit_bias={1: 0.0})
-        # ring-prefill variants: each power-of-two size class from the
-        # threshold up to max_model_len, greedy + sampled
-        if self.scheduler.ring_enabled:
-            n = sched.ring_prefill_threshold
-            limit = self.config.model.max_model_len
-            sizes = []
-            while n < limit:
-                sizes.append(n)
-                n = (1 << n.bit_length())  # next power of two above
-            for size in sizes:
-                size = min(size, limit - max(sched.multi_step, 1) - 1)
-                run([rng.integers(1, vocab, size).tolist()], 0.0)
-                run([rng.integers(1, vocab, size).tolist()], 0.7)
 
     # -- convenience for tests / offline use ---------------------------------
     def generate(

@@ -154,8 +154,7 @@ class EngineStatsCollector:
         # steps are)
         yield counter(
             "vllm:ragged_dispatches",
-            "Unified mixed prefill+decode dispatches issued "
-            "(attention_impl=ragged)",
+            "Unified mixed prefill+decode dispatches issued",
             s.get("ragged_dispatches_total", 0),
         )
         yield counter(

@@ -1,37 +1,35 @@
-"""Jitted device steps over the paged cache: the ragged unified step, plus
-the bucketed prefill/decode fallback.
+"""Jitted device steps over the paged cache: the ragged step, which runs
+every prompt, and the fused decode step.
 
 Static-shape discipline (XLA traces once per shape):
 
-- **ragged** (``attention_impl="ragged"``, the default on TPU): ONE program
-  consumes a packed token stream ``tokens (1, T)`` covering prefill chunks
-  AND decode rows in the same dispatch — per-slot spans described by
-  ``cu_q_lens (S+1,)`` with ``S = max_num_seqs`` slots in slot order
-  (decode rows span 1 token, prefilling slots span their chunk, inactive
-  slots span 0). ``T`` is one of the scheduler's few stream widths
+- **ragged step** (``ragged_step``): ONE program consumes a packed token
+  stream ``tokens (1, T)`` covering prefill chunks AND decode rows in the
+  same dispatch — per-slot spans described by ``cu_q_lens (S+1,)`` with
+  ``S = max_num_seqs`` slots in slot order (decode rows span 1 token,
+  prefilling slots span their chunk, inactive slots span 0). ``T`` is one
+  of the scheduler's few stream widths
   (``SchedulerConfig.ragged_stream_widths``: the token budget
   ``max_num_batched_tokens`` and, where it pays, one narrow width), the
   narrowest that holds the step's tokens, and the program reads it off
   its input: the steady-state compile-signature space is ONE signature a
-  width per program kind, no shape buckets, no padded batch dim, no
-  prefill/decode phase barrier. Sampling happens per
-  slot at each span's last token; rows whose sample is not consumed
-  (mid-prompt chunks, inactive slots) produce masked garbage the host
-  discards.
-- **bucketed** (fallback / rollback): decode is one compiled program
-  (``decode_multi``: ``multi_step`` fused decode + sample iterations) over
-  a fixed (max_num_seqs, 1) batch; prefill compiles once per token-length
-  bucket (powers of two) with chunks padded up. Block tables are always
+  width per static-flag variant, and a step carries prompts and decode
+  rows together. Sampling happens per slot at each span's last token;
+  rows whose sample is not consumed (mid-prompt chunks, inactive slots)
+  produce masked garbage the host discards.
+- **decode step** (``decode_multi``): a step of decode rows alone is one
+  compiled program of ``multi_step`` fused decode + sample iterations
+  over a fixed (max_num_seqs, 1) batch. Block tables are always
   (B, max_blocks_per_seq).
 - KV cache buffers are donated through every step, so XLA updates them in
   place in HBM — the pool is allocated once at startup and never copied.
 
-Attention backend selection: Pallas kernels on TPU (wrapped in shard_map
-over the tensor axis when tp > 1 — heads are independent, so the kernels
-need no cross-chip traffic); XLA gather path on CPU/tests and as fallback
-when head counts don't divide the mesh. ``attention_impl="auto"`` resolves
-to ragged exactly when the Pallas kernels are usable, bucketed otherwise;
-either impl can be forced (the ragged XLA path is the CPU parity oracle).
+Attention backend selection (``use_pallas``, decided by ``_pallas_ok``):
+Pallas kernels on TPU (wrapped in shard_map over the tensor axis when
+tp > 1 — heads are independent, so the kernels need no cross-chip
+traffic); the XLA gather forms of ops/paged_attention.py on the CPU, and
+on an accelerator for a geometry the kernels cannot take. The same two
+programs either way: the XLA forms are the kernels' reference.
 """
 
 from __future__ import annotations
@@ -201,8 +199,8 @@ def _pallas_ok(cfg: ModelConfig, mesh: Mesh, block_size: int) -> bool:
     if failed:
         _log.warning(
             "%s on %s: Pallas attention kernels unusable (%s) — serving "
-            "through XLA gather attention, attention_impl=auto resolves "
-            "to bucketed", cfg.name, backend, "; ".join(failed))
+            "through XLA gather attention", cfg.name, backend,
+            "; ".join(failed))
     return not failed
 
 
@@ -299,30 +297,6 @@ class ModelRunner:
                 self.cfg.cache_kv_heads // self.tp, self.cfg.q_per_kv,
                 self.cfg.cache_head_dim, self.cfg.jax_dtype, window)
             for window in (0, self.cfg.sliding_window))
-        impl = getattr(config, "attention_impl", "auto") or "auto"
-        if impl not in ("auto", "ragged", "bucketed"):
-            raise ValueError(
-                f"attention_impl must be auto|ragged|bucketed, got {impl!r}"
-            )
-        # auto: the ragged step exists to feed the Pallas kernel; the XLA
-        # ragged path stays reachable by forcing "ragged" (parity tests)
-        self.attention_impl = (
-            impl if impl != "auto"
-            else ("ragged" if (self.use_pallas or self.cfg.has_recurrent_state
-                               or self.cfg.is_latent)
-                  else "bucketed")
-        )
-        if self.cfg.has_recurrent_state and self.attention_impl != "ragged":
-            raise ValueError(
-                f"{self.cfg.name}: attention_impl={self.attention_impl} is "
-                "not supported for a recurrent-state model: only the ragged "
-                "step carries span boundaries to its recurrent layers")
-        if self.cfg.is_latent and self.attention_impl != "ragged":
-            raise ValueError(
-                f"{self.cfg.name}: attention_impl={self.attention_impl} is "
-                "not supported for a latent cache: the latent attention "
-                "kernel takes the ragged stream (a decode step its "
-                "one-token spans); there is no bucketed prefill over it")
         # the window layers' pool (0 where no window binds) follows from the
         # configuration; the other pool takes what memory is left
         self.window_blocks = kvmod.window_pool_blocks(
@@ -364,13 +338,6 @@ class ModelRunner:
             self._mh_gate = {}
             self._mh_gate_all = {}
 
-        self._prefill = jax.jit(
-            _named_partial(_prefill_step, self.cfg, self._attend_prefill,
-                           self._eos_id),
-            donate_argnums=(1,),
-            static_argnames=("greedy_only", "use_controls", "use_grammar"),
-            **self._mh_gate,
-        )
         recurrent = self.cfg.has_recurrent_state
         recur = self._recur_mamba if self.cfg.mamba_period else self._recur
         self._decode_multi = jax.jit(
@@ -387,46 +354,25 @@ class ModelRunner:
                              "want_logprobs", "use_grammar"),
             **self._mh_gate,
         )
-        if self.attention_impl == "ragged":
-            # speculative verify is FUSED into the ragged program: the
-            # draft width is baked in as a compile-time constant, so the
-            # one steady-state signature covers plain decode, mixed
-            # prefill+decode and verify-bearing steps alike (no separate
-            # _verify program, no lazy verify compile after warmup)
-            self.spec_width = max(config.scheduler.spec_ngram_k, 0)
-            self._ragged = jax.jit(
-                _named_partial(_ragged_step, self.cfg,
-                               self._attend_ragged, self._eos_id,
-                               self.spec_width,
-                               recur_impl=(functools.partial(recur, True)
-                                           if recurrent else None),
-                               grouped_matmul=self.moe_grouped_matmul),
-                donate_argnums=(1,),
-                static_argnames=("layout", "greedy_only", "use_penalties",
-                                 "use_controls", "use_grammar"),
-                **self._mh_gate,
-            )
-        else:
-            self.spec_width = 0
+        # speculative verify is FUSED into the ragged program: the draft
+        # width is baked in as a compile-time constant, so the one
+        # steady-state signature covers plain decode, mixed prefill+decode
+        # and verify-bearing steps alike (no separate _verify program, no
+        # lazy verify compile after warmup)
+        self.spec_width = max(config.scheduler.spec_ngram_k, 0)
+        self._ragged = jax.jit(
+            _named_partial(_ragged_step, self.cfg,
+                           self._attend_ragged, self._eos_id,
+                           self.spec_width,
+                           recur_impl=(functools.partial(recur, True)
+                                       if recurrent else None),
+                           grouped_matmul=self.moe_grouped_matmul),
+            donate_argnums=(1,),
+            static_argnames=("layout", "greedy_only", "use_penalties",
+                             "use_controls", "use_grammar"),
+            **self._mh_gate,
+        )
         self._sample = jax.jit(sample_tokens)
-        from production_stack_tpu.parallel.mesh import AXIS_SEQ
-
-        self.seq_parallel = mesh.shape[AXIS_SEQ] > 1
-        if self.seq_parallel:
-            # long-prompt prefill via ring attention over the seq axis
-            from production_stack_tpu.parallel import shardings as ln
-
-            head_axis = (AXIS_TENSOR
-                         if self.rules.rules.get(ln.KV_HEADS) is not None
-                         else None)
-            self._prefill_ring = jax.jit(
-                _named_partial(
-                    _prefill_ring_step, self.cfg, mesh, head_axis, self.tp
-                ),
-                donate_argnums=(1,),
-                static_argnames=("greedy_only", "use_controls"),
-                **self._mh_gate,
-            )
         # per-slot output-token counts for presence/frequency penalties
         # ((B, V) int32; allocated on first penalised batch)
         self.token_counts = None
@@ -460,9 +406,8 @@ class ModelRunner:
         refused = [
             what for bad, what in (
                 (mesh.devices.size > 1,
-                 f"a mesh of {mesh.devices.size} devices (tensor, sequence "
-                 "or pipeline parallelism): the recurrent kernels are not "
-                 "partitioned and ring prefill carries no state"),
+                 f"a mesh of {mesh.devices.size} devices (tensor "
+                 "parallelism): the recurrent kernels are not partitioned"),
                 (config.model.quant is not None,
                  f"quant={config.model.quant}: the recurrent layers' "
                  "projections are not quantized"),
@@ -498,10 +443,9 @@ class ModelRunner:
         refused = [
             what for bad, what in (
                 (mesh.devices.size > 1,
-                 f"a mesh of {mesh.devices.size} devices (tensor, sequence "
-                 "or pipeline parallelism): the heads would shard and the "
-                 "one latent row would not; pipeline stages hold no latent "
-                 "pool"),
+                 f"a mesh of {mesh.devices.size} devices (tensor "
+                 "parallelism): the heads would shard and the one latent "
+                 "row would not"),
                 (config.model.quant is not None,
                  f"quant={config.model.quant}: the low-rank projections "
                  "and the absorbed expansions are not quantized"),
@@ -544,78 +488,59 @@ class ModelRunner:
 
     # -- sizing ------------------------------------------------------------
     def _prefill_temp_bytes(self) -> int:
-        """Worst-case prefill transient, per attention impl + backend.
+        """Worst-case transient of a ragged step, per attention backend.
 
-        Ragged: the token budget is the single source of shape truth — the
-        stream is at most ``max_num_batched_tokens`` wide (a narrower
-        width of ``ragged_stream_widths`` needs less, so the pool is sized
-        for the budget), no bucket or
-        prefill_batch dimension exists. Pallas keeps KV windows in VMEM
-        scratch, so only hidden/logits-scale HBM transients remain; the
-        XLA ragged reference gathers each token's full context.
-
-        Bucketed: per batched sequence, (KH, G, S, ctx) f32 score/softmax
-        buffers plus the gathered context — times the prefill_batch
-        dimension (this path keeps its own bucket clamp)."""
+        The token budget is the single source of shape truth — the stream
+        is at most ``max_num_batched_tokens`` wide (a narrower width of
+        ``ragged_stream_widths`` needs less, so the pool is sized for the
+        budget). Pallas keeps KV windows in VMEM scratch, so only
+        hidden/logits-scale HBM transients remain; the XLA form gathers
+        each token's full context."""
         sched = self.config.scheduler
-        if self.attention_impl == "ragged":
-            T = min(sched.max_num_batched_tokens, self.cfg.max_model_len)
-            hidden = T * self.cfg.hidden_size * 4
-            logits = sched.max_num_seqs * self.cfg.vocab_size * 4
-            if self.cfg.is_moe:
-                # the MoE block's sorted (token, choice) rows: gathered
-                # input, gate, up, their product and the output in the
-                # model dtype, the weighted output in float32
-                E, F = self.cfg.hidden_size, self.cfg.intermediate_size
-                hidden += (T * self.cfg.num_experts_per_tok
-                           * (2 * (2 * E + 3 * F) + 4 * E)) // 8
-            if self.cfg.has_recurrent_state:
-                # a KDA layer's rows: q, k, v before and after the
-                # convolution in the model dtype, the five float32 inputs
-                # of the recurrence, head-major copies of them for the
-                # kernel, its output both ways
-                hidden += (T * self.cfg.kda_heads * self.cfg.kda_head_dim
-                           * (6 * 2 + 12 * 4)) // 8
-                # a state-space layer's rows: [x; z] and the convolved x
-                # in the model dtype, x, Delta and y in float32 as the
-                # span kernel takes and returns them, the gated product
-                hidden += (T * self.cfg.mamba_inner * (4 * 2 + 5 * 4)
-                           if self.cfg.mamba_period else 0) // 8
-            if self.cfg.is_latent:
-                # a latent layer's rows of all heads: the two parts of the
-                # query, the absorbed query at the pool's lanes, the
-                # kernel's output and its expansion to values, in the
-                # model dtype; the absorbed query once more in float32
-                c = self.cfg
-                hidden += (T * c.num_heads * (
-                    2 * (c.head_dim + c.latent_lanes + c.kv_lora_rank
-                         + c.v_head_dim)
-                    + 4 * c.latent_lanes)) // 8
-            if self.use_pallas:
-                return int(8 * hidden + 4 * logits)
-            ctx = self.cfg.max_model_len
-            if self.cfg.is_latent:  # one row of latent_lanes, all heads
-                scores = T * ctx * self.cfg.num_heads * 4
-                gather = T * ctx * self.cfg.latent_lanes * (2 + 4)
-            else:
-                scores = (T * ctx * self.cfg.num_kv_heads
-                          * self.cfg.q_per_kv * 4)
-                gather = (2 * T * ctx * self.cfg.num_kv_heads
-                          * self.cfg.head_dim * 2)
-            return int(3.5 * scores + 2 * gather + 8 * hidden + 4 * logits)
-        Pb = max(sched.prefill_batch, 1)
-        # the bucketed scheduler never issues a chunk past the largest bucket
-        chunk = min(sched.max_num_batched_tokens, self.cfg.max_model_len,
-                    max(sched.prefill_buckets))
-        s_max = sched.bucket_for(chunk)
+        T = min(sched.max_num_batched_tokens, self.cfg.max_model_len)
+        hidden = T * self.cfg.hidden_size * 4
+        logits = sched.max_num_seqs * self.cfg.vocab_size * 4
+        if self.cfg.is_moe:
+            # the MoE block's sorted (token, choice) rows: gathered
+            # input, gate, up, their product and the output in the
+            # model dtype, the weighted output in float32
+            E, F = self.cfg.hidden_size, self.cfg.intermediate_size
+            hidden += (T * self.cfg.num_experts_per_tok
+                       * (2 * (2 * E + 3 * F) + 4 * E)) // 8
+        if self.cfg.has_recurrent_state:
+            # a KDA layer's rows: q, k, v before and after the
+            # convolution in the model dtype, the five float32 inputs
+            # of the recurrence, head-major copies of them for the
+            # kernel, its output both ways
+            hidden += (T * self.cfg.kda_heads * self.cfg.kda_head_dim
+                       * (6 * 2 + 12 * 4)) // 8
+            # a state-space layer's rows: [x; z] and the convolved x
+            # in the model dtype, x, Delta and y in float32 as the
+            # span kernel takes and returns them, the gated product
+            hidden += (T * self.cfg.mamba_inner * (4 * 2 + 5 * 4)
+                       if self.cfg.mamba_period else 0) // 8
+        if self.cfg.is_latent:
+            # a latent layer's rows of all heads: the two parts of the
+            # query, the absorbed query at the pool's lanes, the
+            # kernel's output and its expansion to values, in the
+            # model dtype; the absorbed query once more in float32
+            c = self.cfg
+            hidden += (T * c.num_heads * (
+                2 * (c.head_dim + c.latent_lanes + c.kv_lora_rank
+                     + c.v_head_dim)
+                + 4 * c.latent_lanes)) // 8
         if self.use_pallas:
-            hidden = Pb * s_max * self.cfg.hidden_size * 4
-            logits = Pb * self.cfg.vocab_size * 4
             return int(8 * hidden + 4 * logits)
         ctx = self.cfg.max_model_len
-        scores = Pb * s_max * ctx * self.cfg.num_kv_heads * self.cfg.q_per_kv * 4
-        gather = Pb * 2 * ctx * self.cfg.num_kv_heads * self.cfg.head_dim * 2
-        return int(3.5 * scores + 2 * gather)
+        if self.cfg.is_latent:  # one row of latent_lanes, all heads
+            scores = T * ctx * self.cfg.num_heads * 4
+            gather = T * ctx * self.cfg.latent_lanes * (2 + 4)
+        else:
+            scores = (T * ctx * self.cfg.num_kv_heads
+                      * self.cfg.q_per_kv * 4)
+            gather = (2 * T * ctx * self.cfg.num_kv_heads
+                      * self.cfg.head_dim * 2)
+        return int(3.5 * scores + 2 * gather + 8 * hidden + 4 * logits)
 
     def _resolve_num_blocks(self, explicit: Optional[int]) -> int:
         if explicit is not None:
@@ -668,15 +593,12 @@ class ModelRunner:
             return 1
         return self.mesh.shape[AXIS_TENSOR]
 
-    def _sharded(self, inner, q_rank: int):
-        """shard_map wrapper over the tensor axis; q_rank distinguishes the
-        decode (B, H, D) and prefill (P, S, H, D) query shapes."""
+    def _sharded(self, inner):
+        """shard_map wrapper over the tensor axis, for the (T, H, D)
+        queries of both step forms."""
         if self.tp == 1:
             return inner
-        q_spec = (
-            P(None, AXIS_TENSOR, None) if q_rank == 3
-            else P(None, None, AXIS_TENSOR, None)
-        )
+        q_spec = P(None, AXIS_TENSOR, None)
         in_specs = (
             q_spec,
             P(None, AXIS_TENSOR, None),  # newkv (T, 2KH, D)
@@ -689,7 +611,7 @@ class ModelRunner:
         )
         out_specs = (q_spec, P(None, None, None, AXIS_TENSOR, None))
         # stackcheck: disable=jit-cache-hygiene — _sharded is only ever
-        # called at TRACE time inside the jitted step programs (prefill/
+        # called at TRACE time inside the jitted step programs (ragged/
         # decode), so the shard_map it builds is baked into the caller's
         # cached trace; no per-dispatch reconstruction happens
         return jax.shard_map(
@@ -769,44 +691,6 @@ class ModelRunner:
                             context_lens, q_positions, slots, *cu_q_lens,
                             **how)
         return out, {**caches, pool: cache}
-
-
-    def _attend_prefill(self, q, k, v, caches, layer_idx, block_tables,
-                        context_lens, q_positions, slot_mapping):
-        """Batched prefill: q (P, S, H, D), inactive rows carry ctx 0."""
-        Pn, S, H, D = q.shape
-        KH = k.shape[-2]
-        k_flat = k.reshape(Pn * S, KH, D)
-        v_flat = v.reshape(Pn * S, KH, D)
-        if not self.use_pallas:
-            caches = write_kv(caches, layer_idx, k_flat, v_flat, slot_mapping,
-                              self.tp)
-            out = self._xla_attend(q, caches, layer_idx, block_tables,
-                                   context_lens, q_positions)
-            return out, caches
-
-        from production_stack_tpu.ops.paged_attention_pallas import (
-            kv_cache_write_pallas,
-            paged_prefill_attention_pallas,
-        )
-
-        newkv = combine_kv(k_flat.astype(caches.dtype),
-                           v_flat.astype(caches.dtype), self.tp)
-        q_starts = q_positions[:, 0]
-
-        def inner(q4, nk, fused, bt, cl, sm, li, qstarts):
-            fused = kv_cache_write_pallas(fused, nk, sm, li)
-            out = paged_prefill_attention_pallas(
-                q4, fused, bt, qstarts, cl, li,
-                soft_cap=self.cfg.attn_logit_softcap,
-            )
-            return out, fused
-
-        out, caches = self._sharded(inner, q_rank=4)(
-            q, newkv, caches, block_tables, context_lens, slot_mapping,
-            layer_idx, q_starts,
-        )
-        return out, caches
 
     def _attend_latent(self, q, rows, caches, layer_idx, block_tables,
                        context_lens, q_positions, slot_mapping, cu_q_lens):
@@ -889,7 +773,7 @@ class ModelRunner:
             )
             return out, fused
 
-        out, caches = self._sharded(inner, q_rank=3)(
+        out, caches = self._sharded(inner)(
             q[:, 0], newkv, caches, block_tables, context_lens, slot_mapping,
             layer_idx, jnp.zeros((1,), jnp.int32),
         )
@@ -961,7 +845,7 @@ class ModelRunner:
             )
             return out, fused
 
-        out, caches = self._sharded(inner, q_rank=3)(
+        out, caches = self._sharded(inner)(
             q[0], newkv, caches, block_tables, context_lens, slot_mapping,
             layer_idx, cu_q_lens,
         )
@@ -1035,91 +919,6 @@ class ModelRunner:
                 {**caches, "state": new["state"], "conv": conv_state})
 
     # -- public step API (host numpy in, device out) -------------------------
-    def prefill(self, tokens: np.ndarray, positions: np.ndarray,
-                block_tables: np.ndarray, context_lens: np.ndarray,
-                slot_mapping: np.ndarray, last_idx: np.ndarray,
-                temps: np.ndarray, top_ps: np.ndarray, top_ks: np.ndarray,
-                seeds: np.ndarray, greedy_only: bool = True,
-                adapter_ids: Optional[np.ndarray] = None,
-                ctrl: Optional[tuple] = None,
-                g_ids: Optional[np.ndarray] = None,
-                fetch: bool = True):
-        """A batch of prefill chunks (shapes padded: tokens (P, S), tables
-        (P, M), slot_mapping (P*S,)). Each chunk's next token is sampled in
-        the same dispatch; returns (P,) host tokens — or, with
-        ``fetch=False``, the un-fetched device array so the caller can
-        overlap the next dispatch with this one's compute + result fetch
-        (JAX dispatch is async; the engine defers the device_get one step,
-        hiding the per-dispatch round trip — docs/roofline.md).
-
-        Returns (sampled (P,), tok_lp (P,), top_ids (P, N), top_lps (P, N))
-        — logprobs ride every prefill (see _prefill_step)."""
-        use_lora = adapter_ids is not None and self.lora_bank is not None
-        use_grammar = g_ids is not None and self.grammar_bank is not None
-        with jax.set_mesh(self.mesh):
-            self.clock.enter("commit")
-            args = [jnp.asarray(x) for x in (
-                tokens, positions, block_tables, context_lens, slot_mapping,
-                last_idx, temps, top_ps, top_ks, seeds)]
-            kwargs = dict(
-                lora_bank=self.lora_bank if use_lora else None,
-                adapter_ids=(jnp.asarray(adapter_ids, jnp.int32)
-                             if use_lora else None),
-                ctrl=(tuple(jnp.asarray(c) for c in ctrl)
-                      if ctrl is not None else None),
-                grammar=(
-                    (self.grammar_bank, self.grammar_accept,
-                     jnp.asarray(g_ids, jnp.int32))
-                    if use_grammar else None
-                ),
-            )
-            self.clock.launch(**self._launch_attrs)
-            self.kv, result = self._prefill(
-                self.params, self.kv, *args, **kwargs,
-                greedy_only=greedy_only,
-                use_controls=ctrl is not None,
-                use_grammar=use_grammar,
-            )
-        if not fetch:
-            return result
-        self.clock.wait("prefill")
-        return tuple(np.asarray(x) for x in jax.device_get(result))
-
-    def prefill_ring(self, tokens: np.ndarray, positions: np.ndarray,
-                     slot_mapping: np.ndarray, last_idx: np.ndarray,
-                     temps: np.ndarray, top_ps: np.ndarray,
-                     top_ks: np.ndarray, seeds: np.ndarray,
-                     greedy_only: bool = True,
-                     adapter_ids: Optional[np.ndarray] = None,
-                     ctrl: Optional[tuple] = None) -> np.ndarray:
-        """Whole-prompt prefill sharded over the seq axis (ring attention).
-
-        tokens/positions: (1, S) with S a multiple of the seq-axis size;
-        slot_mapping (S,) with -1 padding. Returns the sampled next token
-        (1,). Long-context path: attention never materialises the full
-        S x S score matrix on one device — K/V shards rotate the ring."""
-        use_lora = adapter_ids is not None and self.lora_bank is not None
-        with jax.set_mesh(self.mesh):
-            self.clock.enter("commit")
-            args = [jnp.asarray(x) for x in (
-                tokens, positions, slot_mapping, last_idx, temps, top_ps,
-                top_ks, seeds)]
-            kwargs = dict(
-                lora_bank=self.lora_bank if use_lora else None,
-                adapter_ids=(jnp.asarray(adapter_ids, jnp.int32)
-                             if use_lora else None),
-                ctrl=(tuple(jnp.asarray(c) for c in ctrl)
-                      if ctrl is not None else None),
-            )
-            self.clock.launch(**self._launch_attrs)
-            self.kv, result = self._prefill_ring(
-                self.params, self.kv, *args, **kwargs,
-                greedy_only=greedy_only,
-                use_controls=ctrl is not None,
-            )
-        self.clock.wait("prefill")
-        return tuple(np.asarray(x) for x in jax.device_get(result))
-
     def _ensure_counts(self):
         if self.token_counts is None:
             with jax.set_mesh(self.mesh):
@@ -1147,13 +946,6 @@ class ModelRunner:
                 self.token_counts, jnp.asarray(slot, jnp.int32),
                 jnp.asarray(row),
             )
-
-    supports_chaining = True  # decode_multi launches and returns device
-    # arrays: the engine fetches them itself and may chain the next
-    # dispatch on them (the staged PP runner relays through the host and
-    # returns only when the tokens are on it)
-    supports_logprobs = True  # prefill/decode programs emit logprobs
-    # (the staged PP runner's per-stage programs don't — server 400s)
 
     def decode_multi(self, tokens, positions, block_tables, context_lens,
                      slot_mapping, temps, top_ps, top_ks, seeds, steps,
@@ -1692,116 +1484,6 @@ def _make_lora(lora_bank, adapter_ids, T: int):
     return {"onehot": onehot, "bank": lora_bank}
 
 
-def _prefill_step(cfg: ModelConfig, attend_impl, eos_id, params, kv, tokens,
-                  positions, block_tables, context_lens, slot_mapping,
-                  last_idx, temps, top_ps, top_ks, seeds, lora_bank=None,
-                  adapter_ids=None, ctrl=None, grammar=None,
-                  greedy_only: bool = False,
-                  use_controls: bool = False,
-                  use_grammar: bool = False):
-    """Batched prefill chunks + fused first-token sampling.
-
-    tokens/positions: (P, S); block_tables (P, M); context_lens (P,) with 0
-    marking inactive padding rows; slot_mapping (P*S,); last_idx (P,) index
-    of each chunk's final token. Returns (new_kv, sampled (P,))."""
-    from production_stack_tpu.engine.sampling import sample_tokens
-    from production_stack_tpu.models.registry import get_model
-
-    model = get_model(cfg)
-
-    def attend(q, k, v, caches, layer_idx):
-        return attend_impl(
-            q, k, v, caches, layer_idx, block_tables, context_lens, positions,
-            slot_mapping,
-        )
-
-    hidden, new_kv = model.forward_tokens(
-        cfg, params, tokens, positions, attend, kv,
-        lora=_make_lora(lora_bank, adapter_ids, tokens.shape[1]),
-    )
-    last_hidden = jnp.take_along_axis(
-        hidden, last_idx[:, None, None], axis=1
-    )[:, 0]  # (P, E)
-    logits = model.logits_from_hidden(cfg, params, last_hidden[:, None])[:, 0]
-    raw_logits = logits  # logprobs report the raw model distribution
-    if use_controls:
-        from production_stack_tpu.engine.sampling import apply_token_controls
-
-        logits = apply_token_controls(logits, *ctrl)
-    if use_grammar:
-        # generation starts at FSM state 0: constrain the first token
-        bank, accept, g_ids = grammar
-        logits, _ = _grammar_mask(
-            logits, bank, accept, g_ids, jnp.zeros_like(g_ids), eos_id
-        )
-    if greedy_only:
-        sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    else:
-        sampled = sample_tokens(
-            logits, temps, top_ps, top_ks, seeds,
-            jnp.zeros_like(last_idx),
-        )
-    # logprobs ride every prefill dispatch (one (P, V) top-k — noise next
-    # to the chunk forward) so no per-bucket logprob compile variant exists
-    from production_stack_tpu.engine.sampling import compute_logprobs
-
-    lp = compute_logprobs(raw_logits, sampled)
-    return new_kv, (sampled, *lp)
-
-
-def _prefill_ring_step(cfg: ModelConfig, mesh, head_axis, tp, params, kv,
-                       tokens, positions, slot_mapping, last_idx,
-                       temps, top_ps, top_ks, seeds,
-                       lora_bank=None, adapter_ids=None, ctrl=None,
-                       greedy_only: bool = False,
-                       use_controls: bool = False):
-    """Whole-prompt ring-attention prefill + fused next-token sampling.
-
-    The prompt's activations are sequence-sharded end to end (GSPMD
-    propagates the ring shard_map's specs through QKV/MLP); each layer's
-    K/V are scattered into the paged pool so the subsequent paged decode
-    path sees exactly the same cache a chunked prefill would have built."""
-    from production_stack_tpu.engine.sampling import sample_tokens
-    from production_stack_tpu.models.registry import get_model
-    from production_stack_tpu.parallel.mesh import AXIS_SEQ
-    from production_stack_tpu.parallel.ring_attention import (
-        ring_causal_attention,
-    )
-
-    model = get_model(cfg)
-
-    def attend(q, k, v, caches, layer_idx):
-        out = ring_causal_attention(q, k, v, mesh, AXIS_SEQ,
-                                    head_axis=head_axis,
-                                    soft_cap=cfg.attn_logit_softcap)
-        caches = write_kv(caches, layer_idx, k[0], v[0], slot_mapping, tp)
-        return out, caches
-
-    hidden, new_kv = model.forward_tokens(
-        cfg, params, tokens, positions, attend, kv,
-        lora=_make_lora(lora_bank, adapter_ids, tokens.shape[1]),
-    )
-    last_hidden = jnp.take_along_axis(
-        hidden, last_idx[:, None, None], axis=1
-    )[:, 0]  # (1, E)
-    logits = model.logits_from_hidden(cfg, params, last_hidden[:, None])[:, 0]
-    raw_logits = logits  # logprobs report the raw model distribution
-    if use_controls:
-        from production_stack_tpu.engine.sampling import apply_token_controls
-
-        logits = apply_token_controls(logits, *ctrl)
-    if greedy_only:
-        sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    else:
-        sampled = sample_tokens(
-            logits, temps, top_ps, top_ks, seeds, jnp.zeros_like(last_idx)
-        )
-    from production_stack_tpu.engine.sampling import compute_logprobs
-
-    lp = compute_logprobs(raw_logits, sampled)
-    return new_kv, (sampled, *lp)
-
-
 def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
                        params, kv, packed, tokens_dev,
                        token_counts=None, presence=None, frequency=None,
@@ -1958,7 +1640,7 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
     slots their chunk, inactive 0); last_idx (S,) stream index of each
     slot's final token; sample_mask (S,) gates the on-device penalty-count
     update to rows whose sample is actually consumed. Logprobs ride every
-    dispatch (like _prefill_step): one (S, V) top-k next to the stream
+    dispatch: one (S, V) top-k next to the stream
     forward is noise, and it keeps the want_logprobs compile variant from
     existing on the unified path.
 

@@ -79,7 +79,7 @@ _CONFIRM = b"pstpu-mh-confirm"
 # device arrays (unpicklable) and the engine never calls them — the fused
 # ``decode_multi`` is the decode path (r3 advisor).
 MIRRORED_METHODS = (
-    "prefill", "prefill_ring", "decode_multi",
+    "ragged_step", "decode_multi",
     "set_count_row", "register_grammar", "register_lora",
     "unregister_lora", "export_blocks", "export_blocks_range",
     "import_blocks", "import_blocks_range", "drop_kv", "restore_kv",

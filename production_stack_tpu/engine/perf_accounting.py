@@ -153,10 +153,9 @@ class CompileTracker:
 
 def wrap_runner_programs(runner, observer: Callable) -> None:
     """Install ``CompileTracker`` proxies over a runner's jitted programs
-    (the per-bucket prefill variants and every decode variant; speculative
-    verify has no program of its own — it is fused into ``_ragged``)."""
-    for attr in ("_prefill", "_prefill_ring", "_decode_multi", "_sample",
-                 "_ragged"):
+    (speculative verify has no program of its own — it is fused into
+    ``_ragged``)."""
+    for attr in ("_decode_multi", "_sample", "_ragged"):
         fn = getattr(runner, attr, None)
         if fn is None or isinstance(fn, CompileTracker):
             continue
@@ -375,27 +374,6 @@ class PerfAccountant:
         return self._loop_extra_bytes + self.param_bytes * (
             1.0 - self._expert_share * (1.0 - touched))
 
-    def record_prefill(self, live_tokens: int, ctx_tokens: int,
-                       rows: int, ts: Optional[float] = None, *,
-                       seconds: float = 0.0,
-                       tenants: Optional[dict] = None) -> None:
-        """One prefill dispatch: ``live_tokens`` real prompt tokens over
-        ``rows`` chunks whose post-chunk context lengths sum to
-        ``ctx_tokens`` (docs/roofline.md prefill costing). ``seconds`` is
-        the dispatch's wall time and ``tenants`` the per-tenant
-        ``{"prefill": n, "decode": n, "live": n}`` token shares the
-        engine packed — both feed the tenant attribution plane only."""
-        ctx_mean = ctx_tokens / max(rows, 1)
-        flops = (2.0 * self.active_param_count * live_tokens
-                 + self._attn_per_tok_ctx * live_tokens * ctx_mean)
-        hbm = (self._weight_bytes(live_tokens)
-               + (live_tokens + ctx_tokens) * self._kv_bytes_per_tok)
-        ar = live_tokens * self._ar_bytes_per_tok
-        ag = rows * self._ag_bytes_per_row
-        self._record(ts, "prefill", flops, hbm, live_tokens,
-                     ar_bytes=ar, ag_bytes=ag)
-        self.attribute_tenants(seconds, tenants)
-
     def record_decode(self, live_seqs: int, steps: int, ctx_tokens: int,
                       ts: Optional[float] = None, *,
                       seconds: float = 0.0,
@@ -432,8 +410,7 @@ class PerfAccountant:
         share carries the weight pass (param_bytes read once per
         dispatch, attributed to whichever phase is present), the decode
         share adds its attention context FLOPs and KV traffic on top —
-        one fused dispatch never double-counts the weight read the way
-        separate record_prefill + record_decode calls would.
+        one fused dispatch never double-counts the weight read.
 
         Speculative draft/verify spans (``spec_tokens`` draft tokens over
         ``spec_rows`` rows, post-span contexts summing to ``spec_ctx``)
@@ -511,7 +488,7 @@ class PerfAccountant:
         ``seconds`` split by each tenant's ``live`` token share
         (tenancy.split_shares — parts sum to ``seconds`` bit-exactly, the
         conservation invariant). No-op when metering is off or the
-        dispatch carried no tenant map (bucketed warmup probes)."""
+        dispatch carried no tenant map."""
         if not self.tenant_metering or not tenants:
             return
         live = {t: rec.get("live", 0) for t, rec in tenants.items()

@@ -1,27 +1,21 @@
 """Continuous-batching scheduler (vLLM-semantics, TPU-shaped).
 
-Two policies share admission/preemption/blocks:
-
-**Unified (token-budget)** — ``unified = True``, set by the engine on the
-ragged attention impl: every step collects ALL decodable sequences (one
-stream token each), then FCFS prefill chunks fill whatever budget decode
-left (``max_num_batched_tokens`` is the only shape knob — no buckets, no
-prefill/decode phase barrier). One mixed batch per step; the engine packs
-it into a single ragged dispatch, as wide as the narrowest of
-``SchedulerConfig.ragged_stream_widths`` that holds it: the width follows
-from what was scheduled here and never bears on it.
-
-**Bucketed (prefill-priority)** — the fallback, per step, in order:
+Token-budget scheduling, one mixed batch a step, in order:
 
 1. **Admit**: move waiting sequences into decode slots while slots and KV
    blocks last, reusing prefix-cached blocks on admission.
-2. **Prefill priority**: if any admitted sequence still has uncomputed prompt
-   tokens, schedule one prefill chunk (bounded by
-   ``max_num_batched_tokens``); prefill-first keeps TTFT low (the north-star
-   p50 < 200 ms, BASELINE.md).
-3. Otherwise **decode** every running sequence one token, growing block
-   tables; if the pool is exhausted, preempt the youngest sequence
-   (free blocks, recompute later) — vLLM-style recompute preemption.
+2. **Decode rows**: every decodable sequence claims one stream token (one
+   more for each granted draft), growing block tables; if the pool is
+   exhausted, preempt the youngest sequence (free blocks, recompute
+   later) — vLLM-style recompute preemption.
+3. **Prefill chunks**: FCFS (or per-tenant fair share) chunks fill
+   whatever budget decode left; ``max_num_batched_tokens`` is the only
+   shape knob.
+
+The engine packs the batch into a single ragged dispatch, as wide as the
+narrowest of ``SchedulerConfig.ragged_stream_widths`` that holds it: the
+width follows from what was scheduled here and never bears on it. A step
+of decode rows alone goes to the fused decode program.
 
 The scheduler is pure host-side control plane: it never touches device
 arrays, it only decides. Counters here feed ``vllm:num_requests_running/
@@ -52,7 +46,6 @@ class ScheduledPrefill:
     seq: Sequence
     chunk_start: int  # == seq.num_computed_tokens
     chunk_len: int
-    ring: bool = False  # whole-prompt ring-attention prefill (seq axis)
 
 
 @dataclasses.dataclass
@@ -95,7 +88,7 @@ class Scheduler:
         # invoked right after a sequence is admitted, before its first chunk
         # is scheduled. The tiered-KV engine starts an async warm-tier
         # prefix fetch here and may park the sequence in PREFETCHING —
-        # both scheduling paths gate prefill on PREFILLING and decode on
+        # scheduling gates prefill on PREFILLING and decode on
         # RUNNING, so a parked sequence holds its slot and blocks but
         # consumes no budget until the engine flips it back
         self.admission_hook = None
@@ -105,14 +98,6 @@ class Scheduler:
         # sets it) so that it subtracts from the engine's other stamps
         self.step_num = 0
         self.now = time.monotonic
-        # set by the engine when the mesh has a seq axis > 1: long fresh
-        # prompts prefill whole via ring attention instead of chunking
-        self.ring_enabled = False
-        # set by the engine on the ragged attention impl: one token-budget
-        # batch per step mixing decode rows and FCFS prefill chunks —
-        # max_num_batched_tokens is the only shape knob (no prefill
-        # buckets, no prefill/decode phase barrier)
-        self.unified = False
         # set by the engine when speculative decoding is on: returns the
         # draft width to reserve for a decode row (0 = ineligible or cold;
         # see spec.SpecController). The scheduler charges 1 + grant stream
@@ -393,7 +378,7 @@ class Scheduler:
         (disagg P→D handoff): its KV blocks were landed by /kv/recv, its
         first token is already in ``output_token_ids`` and
         ``num_computed_tokens`` covers the whole prompt, so
-        ``prefill_done`` holds and ``_schedule_unified``/``_grow_decodes``
+        ``prefill_done`` holds and ``schedule``/``_grow_decodes``
         pick it up as a decode row on the next step — no pass through the
         waiting queue, no re-prefill. The caller owns the blocks until
         this returns; afterwards the normal finish/abort paths release
@@ -410,72 +395,17 @@ class Scheduler:
 
     # -- the per-step decision ----------------------------------------------
     def schedule(self) -> SchedulerOutput:
-        out = SchedulerOutput()
-        self._try_admit()
-
-        # ring prefill: a long fresh prompt (no cached/computed prefix — the
-        # ring sees only in-flight tokens) goes through whole, alone, sharded
-        # over the seq axis; the token budget doesn't apply because the seq
-        # axis divides the work
-        if self.ring_enabled and self.config.ring_prefill_threshold > 0:
-            for seq in sorted(self.seqs.values(),
-                              key=lambda s: s.arrival_time):
-                if (seq.status is SequenceStatus.PREFILLING
-                        and not seq.prefill_done
-                        and seq.num_computed_tokens == 0
-                        and seq.grammar_slot < 0  # ring samples unmasked
-                        and seq.prefill_target
-                        >= self.config.ring_prefill_threshold):
-                    out.prefills.append(
-                        ScheduledPrefill(seq, 0, seq.prefill_target,
-                                         ring=True)
-                    )
-                    return out
-
-        if self.unified:
-            return self._schedule_unified(out)
-
-        # prefill priority: batch up to prefill_batch chunks per dispatch;
-        # the first (FCFS) chunk picks the shape bucket, later chunks are
-        # truncated to it (they continue next step — chunked prefill)
-        budget = self.config.max_num_batched_tokens
-        bucket_cap = max(self.config.prefill_buckets)
-        for seq in sorted(self.seqs.values(), key=lambda s: s.arrival_time):
-            if seq.status is not SequenceStatus.PREFILLING:
-                continue
-            if seq.prefill_done:
-                # possible when a preempted sequence's context fully
-                # prefix-matched on re-admission: nothing to compute
-                seq.status = SequenceStatus.RUNNING
-                continue
-            if len(out.prefills) >= self.config.prefill_batch or budget <= 0:
-                break
-            remaining = seq.prefill_target - seq.num_computed_tokens
-            chunk = min(remaining, budget, bucket_cap)
-            if out.prefills:
-                first_bucket = self.config.bucket_for(out.prefills[0].chunk_len)
-                chunk = min(chunk, first_bucket)
-            out.prefills.append(
-                ScheduledPrefill(seq, seq.num_computed_tokens, chunk)
-            )
-            budget -= chunk
-        if out.prefills:
-            return out
-
-        out.decodes = self._grow_decodes(out)
-        return out
-
-    def _schedule_unified(self, out: SchedulerOutput) -> SchedulerOutput:
         """Token-budget continuous batching (RTP-LLM-style): decode rows
         claim one stream token each, then FCFS prefill chunks fill
-        whatever budget is left — one mixed batch per step, no
-        prefill/decode phase barrier, and ``max_num_batched_tokens`` as
-        the ONLY shape knob (no bucket truncation: the ragged dispatch
-        has no padded chunk dimension to round up to).
+        whatever budget is left — one mixed batch per step, and
+        ``max_num_batched_tokens`` as the ONLY shape knob (the ragged
+        dispatch has no padded chunk dimension to round up to).
 
         With speculation on, each spec-eligible decode row is charged
         ``1 + grant`` stream tokens so drafts compete fairly with prefill
         chunks for the same budget."""
+        out = SchedulerOutput()
+        self._try_admit()
         out.decodes = self._grow_decodes(out)
         budget = self.config.max_num_batched_tokens - len(out.decodes)
         if self.spec_grant_fn is not None:

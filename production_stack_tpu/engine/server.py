@@ -631,8 +631,9 @@ class EngineServer:
             "model": cfg.model.name,
             "role": cfg.role,
             "tensor_parallel": int(getattr(perf, "tp", 1) or 1),
-            "attention_impl": str(getattr(self.engine, "attention_impl",
-                                          cfg.attention_impl) or ""),
+            # the one way a prompt runs; the key stays for readers of
+            # the fingerprint (ROADMAP D20)
+            "attention_impl": "ragged",
             "dtype": cfg.model.dtype,
             "quantization": cfg.model.quant or "",
             "speculative": bool(cfg.scheduler.spec_ngram_k),
@@ -2436,15 +2437,6 @@ class EngineServer:
                            "type": "invalid_request_error"}},
                 status=400,
             )
-        if (sampling.logprobs is not None
-                and not getattr(self.engine.runner, "supports_logprobs",
-                                False)):
-            return web.json_response(
-                {"error": {"message": "logprobs are not supported with "
-                           "pipeline parallelism",
-                           "type": "invalid_request_error"}},
-                status=400,
-            )
         g_re = body.get("guided_regex")
         g_js = body.get("guided_json")
         if g_re is not None or g_js is not None:
@@ -2455,9 +2447,6 @@ class EngineServer:
                 err = "guided_choice cannot combine with other guidance"
             elif g_re is not None and not isinstance(g_re, str):
                 err = "guided_regex must be a string"
-            elif not hasattr(self.engine.runner, "register_grammar"):
-                err = ("guided decoding is not supported with pipeline "
-                       "parallelism")
             else:
                 try:  # validate the grammar NOW — a 400, not a mid-stream 500
                     from production_stack_tpu.engine.grammar import (
@@ -3268,31 +3257,11 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--served-model-name", default=None)
     p.add_argument("--num-scheduler-steps", type=int, default=None,
                    help="decode iterations fused per dispatch (multi-step)")
-    p.add_argument("--prefill-batch", type=int, default=None,
-                   help="prefill chunks batched per dispatch")
     p.add_argument("--max-num-batched-tokens", type=int, default=None)
-    p.add_argument("--prefill-buckets", default=None,
-                   help="comma-separated token buckets, e.g. 128,512,2048")
-    p.add_argument("--attention-impl", default=None,
-                   choices=["auto", "ragged", "bucketed"],
-                   help="attention dispatch shape: 'ragged' packs prefill "
-                        "chunks and decode rows into ONE token-budget "
-                        "stream per step (single steady-state compile "
-                        "signature; --max-num-batched-tokens is the only "
-                        "shape knob), 'bucketed' keeps the legacy "
-                        "prefill-bucket path, 'auto' picks ragged when "
-                        "the Pallas kernels are usable")
-    p.add_argument("--pipeline-parallel-size", type=int, default=1,
-                   help="pipeline stages (stage mesh axis; per-stage "
-                        "submeshes + KV pools). Parity with the reference's "
-                        "--pipeline-parallel-size passthrough.")
-    p.add_argument("--sequence-parallel-size", type=int, default=1,
-                   help="seq mesh axis size: long prompts prefill via ring "
-                        "attention sharded over this many devices")
-    p.add_argument("--ring-prefill-threshold", type=int, default=4096,
-                   help="prompt length at which prefill switches to the "
-                        "ring-attention sequence-parallel path (needs "
-                        "--sequence-parallel-size > 1)")
+    p.add_argument("--attention-impl", default=None, type=_attention_impl,
+                   help="accepted and ignored ('auto' or 'ragged'): every "
+                        "prompt runs through the ragged step, and "
+                        "--max-num-batched-tokens is its only shape knob")
     p.add_argument("--speculative-ngram", type=int, default=0,
                    help="n-gram (prompt-lookup) speculative decoding: "
                         "propose up to this many draft tokens per step from "
@@ -3302,7 +3271,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "sequence: greedy rows speculate, sampled/penalised "
                         "rows in the same batch decode normally; an "
                         "acceptance EWMA adapts the width per sequence. "
-                        "0 = off; needs --attention-impl ragged")
+                        "0 = off")
     p.add_argument("--speculative-ngram-max", type=int, default=3,
                    help="longest tail n-gram matched against the history")
     p.add_argument("--speculative-ngram-min", type=int, default=1,
@@ -3509,6 +3478,16 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+def _attention_impl(value: str) -> str:
+    """``--attention-impl``: names the one dispatch family there is."""
+    if value in ("auto", "ragged"):
+        return value
+    raise argparse.ArgumentTypeError(
+        f"{value!r}: choose 'auto' or 'ragged', or drop the flag (the "
+        "bucketed prefill family was removed: every prompt runs through "
+        "the ragged step)")
+
+
 def config_from_args(args) -> EngineConfig:
     import dataclasses
 
@@ -3532,16 +3511,8 @@ def config_from_args(args) -> EngineConfig:
         cfg.cache.num_blocks = args.num_blocks
     if args.num_scheduler_steps:
         cfg.scheduler.multi_step = args.num_scheduler_steps
-    if args.prefill_batch:
-        cfg.scheduler.prefill_batch = args.prefill_batch
     if args.max_num_batched_tokens:
         cfg.scheduler.max_num_batched_tokens = args.max_num_batched_tokens
-    if args.prefill_buckets:
-        cfg.scheduler.prefill_buckets = tuple(
-            int(x) for x in args.prefill_buckets.split(",")
-        )
-    if args.attention_impl:
-        cfg.attention_impl = args.attention_impl
     if args.speculative_ngram:
         cfg.scheduler.spec_ngram_k = args.speculative_ngram
         cfg.scheduler.spec_ngram_max = args.speculative_ngram_max
@@ -3575,11 +3546,8 @@ def config_from_args(args) -> EngineConfig:
     cfg.kv_transfer_retries = getattr(args, "kv_transfer_retries", 3) or 3
     cfg.kv_transfer_ttl = getattr(args, "kv_transfer_ttl", 120.0) or 120.0
     cfg.mesh = MeshConfig(
-        data=args.data_parallel_size, stage=args.pipeline_parallel_size,
-        seq=args.sequence_parallel_size, tensor=args.tensor_parallel_size,
+        data=args.data_parallel_size, tensor=args.tensor_parallel_size,
     )
-    if args.sequence_parallel_size > 1:
-        cfg.scheduler.ring_prefill_threshold = args.ring_prefill_threshold
     cfg.perf.enabled = getattr(args, "perf_accounting", True)
     if getattr(args, "perf_window", None):
         cfg.perf.window = args.perf_window
@@ -3715,14 +3683,6 @@ def main(argv=None) -> None:
                 "multi-host serving does not yet compose with the "
                 "host-offload / remote-KV tiers (their device transfers "
                 "run outside the mirrored runner)"
-            )
-        if args.pipeline_parallel_size > 1:
-            raise SystemExit(
-                "multi-host serving does not compose with the staged "
-                "pipeline runner: its per-stage submeshes don't span "
-                "every controller process, so followers outside a stage "
-                "can't address its outputs. Shard across hosts with "
-                "--tensor-parallel-size (GSPMD over ICI+DCN) instead."
             )
         # must precede the first backend touch: afterwards jax.devices()
         # is the GLOBAL device list and one Mesh spans all hosts

@@ -169,7 +169,7 @@ class request_span:
 # time with no phase open: between two steps driven by hand
 HOST_PHASES = ("intake", "schedule", "build", "snapshot", "commit", "launch",
                "postprocess", "deliver", "prefetch_wait", "observe", "other")
-STEP_KINDS = ("decode", "ragged", "prefill", "other")
+STEP_KINDS = ("decode", "ragged", "other")
 DISPATCH_KINDS = STEP_KINDS[:-1]  # `other` dispatched nothing
 # a step is slow when it took more than SLOW_FACTOR times the median of
 # the SLOW_WINDOW steps like it before it (of its kind and stream width,

@@ -737,7 +737,7 @@ def forward_tokens(
 
 def embed_tokens(cfg: ModelConfig, params: dict, tokens: jnp.ndarray) -> jnp.ndarray:
     """Token embedding incl. the Gemma sqrt(E) scale — the ONE site for the
-    normalizer semantics (pipeline stages must embed identically)."""
+    normalizer semantics."""
     x = embed_lookup(params["embed"], tokens, cfg.jax_dtype)
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.hidden_size ** 0.5, cfg.jax_dtype)
@@ -760,18 +760,13 @@ def forward_hidden(
 ) -> Tuple[jnp.ndarray, Any]:
     """Run the decoder stack from pre-embedded activations.
 
-    The hidden-in/hidden-out form is the pipeline-parallel unit: a stage
-    holds a slice of ``params["layers"]`` and its own KV pool, takes the
-    previous stage's activations, and hands its output to the next stage
-    (engine/pp_runner.py).
-
     x: (..., T, E); positions: (..., T) int32.
-    kv_caches: this stage's cache pytree (leading layer axis) or None. It
+    kv_caches: the cache pytree (leading layer axis) or None. It
     rides the scan *carry*, not ys: while-loop carries alias in place under
     XLA, so a donated multi-GiB HBM pool is updated without ever being
     copied (scan ys would allocate a fresh stacked output every step —
     measured as 2× cache HLO-temp on v5e). ``attend`` receives the cache
-    plus the LOCAL layer index and returns the updated cache.
+    plus the layer index and returns the updated cache.
     ``live`` (bool, like positions) marks the rows that are tokens; by
     default those with a position >= 0 (the ragged stream pads its tail
     with -1). Only the MoE block reads it: other rows are kept out of the

@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the paged-KV serving hot path.
 
-Three kernels over the fused cache layout ``(L, N, block_size, 2*KH, D)``
+Two kernels over the fused cache layout ``(L, N, block_size, 2*KH, D)``
 (see ops/paged_attention.py for the layout rationale):
 
 - ``paged_decode_attention_pallas``: one grid cell per sequence; walks the
@@ -11,9 +11,6 @@ Three kernels over the fused cache layout ``(L, N, block_size, 2*KH, D)``
   at one query row a KV head, head pair by head pair through strided
   32-bit loads at grouped queries; head by head from a float32 copy only
   at the geometries no served model has.
-- ``paged_prefill_attention_pallas``: one grid cell per query tile of a
-  single sequence's chunk; same windowed context walk with causal masking —
-  this replaces the XLA dynamic-slice + gather path over the whole pool.
 - ``kv_cache_write_pallas``: scatters T new tokens into the pool as T async
   ``(2KH, D)``-slab DMAs on a semaphore ring, in place of an XLA scatter.
   The cache is aliased input→output, so the donated pool is updated in
@@ -21,8 +18,9 @@ Three kernels over the fused cache layout ``(L, N, block_size, 2*KH, D)``
 
 All kernels take the layer index as a scalar so the full multi-layer pool
 never gets sliced/copied. Grid cells execute sequentially on a TensorCore —
-work per cell is kept coarse (whole sequence / whole tile) and DMAs are
-issued in async batches to hide latency.
+work per cell is kept coarse (a whole sequence) and DMAs are issued in
+async batches to hide latency. A prompt's attention is the ragged kernel's
+(ops/ragged_paged_attention_pallas.py).
 
 Reference context: the reference stack delegates attention kernels to vLLM
 (SURVEY.md §7 step 1); these kernels are the TPU-native equivalent of its
@@ -590,203 +588,6 @@ def paged_decode_attention_pallas(
         name="paged_decode_attention",
     )(block_tables, context_lens, layer_arr, q4, kv_cache)
     return out.reshape(B, H, D)
-
-
-# ---------------------------------------------------------------------------
-# prefill (single sequence, chunked; causal over the paged context)
-# ---------------------------------------------------------------------------
-
-def _prefill_kernel(
-    # scalar prefetch
-    bt_ref,  # (P, M) SMEM — per-sequence block table rows
-    layer_ref,  # (1,) SMEM
-    qstart_ref,  # (P,) SMEM — each chunk's first absolute position
-    ctx_ref,  # (P,) SMEM — q_start + chunk_len per sequence (0 = inactive)
-    # inputs
-    q_ref,  # (1, R, KH, D) VMEM — R = TQ*G rows of this tile
-    kv_hbm,  # (L, N, bs, 2KH, D) ANY
-    # outputs
-    o_ref,  # (1, R, KH, D) VMEM
-    # scratch
-    buf,  # (2, W, bs, 2KH, D) VMEM
-    sems,  # (2, W)
-    *,
-    block_size: int,
-    windows: int,
-    q_tile: int,
-    group: int,
-    scale: float,
-    soft_cap: float = 0.0,
-):
-    p = pl.program_id(0)
-    t = pl.program_id(1)
-    layer = layer_ref[0]
-    q_start = qstart_ref[p]
-    ctx = ctx_ref[p]
-    W = windows
-    bs = block_size
-    win_tokens = W * bs
-    _, R, KH, D = q_ref.shape
-
-    # this tile's queries reach absolute position q_start + (t+1)*q_tile - 1
-    reach = jnp.minimum(ctx, q_start + (t + 1) * q_tile)
-    nwin = pl.cdiv(reach, win_tokens)
-
-    def dma(slot, w, j):
-        bid = bt_ref[p, w * W + j]
-        return pltpu.make_async_copy(
-            kv_hbm.at[layer, bid], buf.at[slot, j], sems.at[slot, j]
-        )
-
-    # per-block predication (same as the decode kernel): the final window
-    # must not stream blocks past this tile's causal reach — the DMA unit
-    # is one block, so the tail over-read is bounded by bs tokens
-    def block_active(w, j):
-        return w * win_tokens + j * bs < reach
-
-    def issue(slot, w):
-        for j in range(W):
-            @pl.when(block_active(w, j))
-            def _():
-                dma(slot, w, j).start()
-
-    @pl.when(nwin > 0)
-    def _():
-        issue(0, 0)
-
-    q = q_ref[0].astype(jnp.float32)  # (R, KH, D)
-    # row r is query token s = t*TQ + r//G at absolute position q_start + s
-    qpos = q_start + t * q_tile + jax.lax.broadcasted_iota(
-        jnp.int32, (1, R, 1), 1
-    ) // group  # (1, R, 1)
-
-    def body(w, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(w, 2)
-
-        @pl.when(w + 1 < nwin)
-        def _():
-            issue(jax.lax.rem(w + 1, 2), w + 1)
-
-        for j in range(W):
-            @pl.when(block_active(w, j))
-            def _():
-                dma(slot, w, j).wait()
-
-        kv = buf[slot].reshape(win_tokens, 2 * KH, D)
-        s_heads = []
-        for h in range(KH):
-            k_h = kv[:, h, :].astype(jnp.float32)  # (T, D)
-            q_h = q[:, h, :]  # (R, D)
-            s_heads.append(
-                jax.lax.dot_general(
-                    q_h, k_h, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            )  # (R, T)
-        s = jnp.stack(s_heads) * scale  # (KH, R, T)
-        if soft_cap:  # Gemma-2 score capping, before masking
-            s = soft_cap * jnp.tanh(s / soft_cap)
-        kvpos = w * win_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, win_tokens), 2
-        )
-        valid = (kvpos <= qpos) & (kvpos < ctx)  # (1, R, T)
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # tail blocks past `reach` were never DMA'd (per-block
-        # predication): zero their V rows — 0 x NaN = NaN would otherwise
-        # poison the PV accumulator through masked-out weights
-        vvalid = (w * win_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, (win_tokens, 1), 0) < reach)
-        acc_heads = []
-        for h in range(KH):
-            v_h = jnp.where(vvalid, kv[:, KH + h, :].astype(jnp.float32),
-                            0.0)
-            acc_heads.append(
-                jax.lax.dot_general(
-                    p[h], v_h, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            )  # (R, D)
-        acc_new = acc * alpha + jnp.stack(acc_heads)
-        return m_new, l_new, acc_new
-
-    init = (
-        jnp.full((KH, R, 1), NEG_INF, jnp.float32),
-        jnp.zeros((KH, R, 1), jnp.float32),
-        jnp.zeros((KH, R, D), jnp.float32),
-    )
-    m, l, acc = jax.lax.fori_loop(0, nwin, body, init)
-    out = acc / jnp.maximum(l, 1e-30)  # (KH, R, D)
-    o_ref[0] = out.transpose(1, 0, 2).astype(o_ref.dtype)
-
-
-def paged_prefill_attention_pallas(
-    q: jnp.ndarray,  # (P, S, H, D) — P sequences' chunks, S padded to a bucket
-    kv_cache: jnp.ndarray,  # (L, N, bs, 2KH, D)
-    block_tables: jnp.ndarray,  # (P, M) per-sequence block rows
-    q_starts: jnp.ndarray,  # (P,) each chunk's first absolute position
-    ctx_totals: jnp.ndarray,  # (P,) q_start + chunk_len; 0 = inactive row
-    layer_idx: jnp.ndarray | int = 0,
-    q_tile: int = 128,
-    windows: int = 8,
-    interpret: bool = False,
-    soft_cap: float = 0.0,
-) -> jnp.ndarray:
-    P, S, H, D = q.shape
-    L, N, bs, KH2, _ = kv_cache.shape
-    KH = KH2 // 2
-    G = H // KH
-    TQ = min(q_tile, S)
-    n_tiles = S // TQ
-    R = TQ * G
-
-    # rows ordered (s, g): (P, S, H, D) -> (P, S*G, KH, D)
-    q_rows = (
-        q.reshape(P, S, KH, G, D).transpose(0, 1, 3, 2, 4).reshape(P, S * G, KH, D)
-    )
-    layer_arr = jnp.asarray(layer_idx, jnp.int32).reshape(1)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(P, n_tiles),
-        in_specs=[
-            pl.BlockSpec((1, R, KH, D), lambda p, t, *_: (p, t, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, R, KH, D), lambda p, t, *_: (p, t, 0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, windows, bs, KH2, D), kv_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, windows)),
-        ],
-    )
-    kernel = functools.partial(
-        _prefill_kernel, block_size=bs, windows=windows, q_tile=TQ,
-        group=G, scale=D**-0.5, soft_cap=soft_cap,
-    )
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((P, S * G, KH, D), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-        name="paged_prefill_attention",
-    )(
-        block_tables,
-        layer_arr,
-        jnp.asarray(q_starts, jnp.int32),
-        jnp.asarray(ctx_totals, jnp.int32),
-        q_rows,
-        kv_cache,
-    )
-    # rows (s, g) back to (P, S, H, D) with h = kh*G + g
-    return (
-        out.reshape(P, S, G, KH, D).transpose(0, 1, 3, 2, 4).reshape(P, S, H, D)
-    )
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,9 @@
 """Ragged paged attention — ONE Pallas kernel for mixed prefill+decode.
 
-The bucketed kernels (ops/paged_attention_pallas.py) split every engine
-step into a decode dispatch over padded slot grids and a prefill dispatch
-compiled once per power-of-two token bucket. This kernel consumes the
-packed token stream directly ("Ragged Paged Attention", PAPERS.md):
+The engine packs every prompt chunk and decode row of a step into one
+token stream, and this kernel consumes that stream as it lies: no padded
+slot grid, no chunk padded up to a shape class ("Ragged Paged Attention",
+PAPERS.md):
 
 - queries arrive as one ``(T, H, D)`` stream — the concatenation of every
   scheduled sequence's span (a prefill chunk of any length, a decode row
@@ -18,8 +18,8 @@ packed token stream directly ("Ragged Paged Attention", PAPERS.md):
   by the wrapper with one ``searchsorted`` over ``cu_q_lens``), carrying
   ONE flash-softmax state across the walk — rows outside the current
   sequence contribute exactly-zero probability mass;
-- per sequence, the paged context is streamed exactly like the bucketed
-  kernels: windowed double-buffered block DMAs with per-BLOCK predication
+- per sequence, the paged context is streamed as the decode kernel streams
+  it: windowed double-buffered block DMAs with per-BLOCK predication
   on the tile's causal reach (the roofline's over-read fix), causal
   masking within the ragged span, NaN-safe V zeroing past the reach.
 
@@ -362,7 +362,7 @@ def _ragged_kernel(
                 # NEG_INF), exp(sc - m_new) = exp(0) = 1 would inflate its
                 # l by T per window — so invalid lanes are zeroed
                 # EXPLICITLY rather than through the exp underflow the
-                # bucketed kernels rely on.
+                # decode kernel relies on.
                 p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
                 l_ref[:, rs, 0:1] = l * alpha + jnp.sum(
                     p, axis=-1, keepdims=True)

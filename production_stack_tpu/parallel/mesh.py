@@ -7,10 +7,10 @@ TPU-first parallelism: a single logical ``jax.sharding.Mesh`` with named axes
 - ``data``   replica data parallelism (whole-model replicas within one process;
              cross-pod replica DP is the router's job, as in the reference's
              replicaCount + load balancing — SURVEY.md §2.9).
-- ``stage``  pipeline stages (multi-slice over DCN; reference uses Ray + PP,
-             helm/templates/ray-cluster.yaml — we use GSPMD stage sharding).
-- ``seq``    sequence/context parallelism axis for ring attention (the
-             reference has none, SURVEY.md §5.7; here it is first-class).
+- ``stage``, ``seq``  nothing shards over them any more (pipeline stages
+             and ring prefill were removed): ``LLMEngine`` refuses a size
+             above 1, and the axes go with the next change to the mesh
+             (ROADMAP D19).
 - ``tensor`` tensor parallelism over ICI (reference passes
              --tensor-parallel-size through to vLLM).
 - ``expert`` expert parallelism for MoE layers.

@@ -34,8 +34,7 @@ def setup():
         cache=CacheConfig(block_size=4, num_blocks=128),
         scheduler=SchedulerConfig(
             max_num_seqs=4, max_num_batched_tokens=32,
-            prefill_buckets=(16, 32),
-        ),
+            ),
         mesh=MeshConfig(data=1, tensor=1),
     )
     mesh = build_mesh(cfg.mesh)

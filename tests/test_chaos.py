@@ -586,7 +586,8 @@ def _real_server(**kwargs):
     cfg = EngineConfig(
         model=ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=128),
-        scheduler=SchedulerConfig(max_num_seqs=2, prefill_buckets=(32,)),
+        scheduler=SchedulerConfig(max_num_seqs=2,
+                                  max_num_batched_tokens=64),
     )
     return EngineServer(cfg, **kwargs)
 

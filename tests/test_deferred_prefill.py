@@ -1,10 +1,9 @@
-"""Deferred prefill resolution: the cross-step races the dispatch
-pipelining introduces (engine/engine.py _pending_prefill). A prefill
-dispatch's sampled tokens land one step after scheduler-visible state
-advances, so aborts, preemption, and max_tokens=1 finishes can all occur
-while the dispatch is in flight."""
+"""Deferred resolution of a prompt's step: the cross-step races the
+dispatch pipelining introduces (engine/engine.py _pending_ragged). A
+ragged dispatch's sampled tokens land one step after scheduler-visible
+state advances, so aborts, preemption, and max_tokens=1 finishes can all
+occur while the dispatch is in flight."""
 
-import numpy as np
 import pytest
 
 from production_stack_tpu.engine.config import (
@@ -23,8 +22,7 @@ def make_engine(num_blocks=64):
     cfg = EngineConfig(
         model=ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=num_blocks),
-        scheduler=SchedulerConfig(max_num_seqs=4, max_num_batched_tokens=64,
-                                  prefill_buckets=(16, 32)),
+        scheduler=SchedulerConfig(max_num_seqs=4, max_num_batched_tokens=64),
         mesh=MeshConfig(data=1, tensor=1),
     )
     return LLMEngine(cfg, mesh=build_mesh(cfg.mesh), num_blocks=num_blocks)
@@ -56,8 +54,8 @@ def test_abort_while_prefill_in_flight():
     engine = make_engine()
     sp = SamplingParams(temperature=0.0, max_tokens=8, ignore_eos=True)
     engine.add_request("r0", prompt_token_ids=[1, 2, 3], sampling=sp)
-    engine.step()  # dispatches the prefill; resolution is pending
-    assert engine._pending_prefill is not None
+    engine.step()  # dispatches the prompt's step; resolution is pending
+    assert engine._pending_ragged is not None
     engine.abort_request("r0")
     outs = engine.step()  # resolve must skip the aborted seq
     assert not any(o.request_id == "r0" and o.new_token_ids for o in outs)
@@ -77,7 +75,7 @@ def test_finish_while_preempted_is_not_resurrected():
     # simulate pool pressure preempting it before resolution
     engine.scheduler._preempt(seq)
     assert seq in engine.scheduler.waiting
-    outs = engine._resolve_pending_prefill()
+    outs = engine._resolve_pending_ragged()
     mine = [o for o in outs if o.request_id == "r0"]
     assert sum(o.finished for o in mine) == 1
     assert seq.status.is_finished
@@ -102,7 +100,7 @@ def test_preempted_unfinished_keeps_deferred_token():
                              sampling=sp)
     engine.step()
     engine.scheduler._preempt(seq)
-    outs = engine._resolve_pending_prefill()
+    outs = engine._resolve_pending_ragged()
     got = [t for o in outs for t in o.new_token_ids]
     got += [t for o in drain(engine) for t in o.new_token_ids]
     assert got == ref
@@ -113,9 +111,9 @@ def test_empty_schedule_flushes_pending():
     sp = SamplingParams(temperature=0.0, max_tokens=1, ignore_eos=True)
     engine.add_request("r0", prompt_token_ids=[1, 2, 3], sampling=sp)
     engine.step()
-    assert engine._pending_prefill is not None
+    assert engine._pending_ragged is not None
     outs = engine.step()  # schedule sees RUNNING seq -> resolves + finishes
-    assert engine._pending_prefill is None
+    assert engine._pending_ragged is None
     assert any(o.finished for o in outs)
 
 
@@ -131,7 +129,7 @@ def test_chained_decode_token_identical():
             cache=CacheConfig(block_size=4, num_blocks=128),
             scheduler=SchedulerConfig(
                 max_num_seqs=4, max_num_batched_tokens=64,
-                prefill_buckets=(16, 32), multi_step=2,
+                multi_step=2,
                 chain_decode=chain,
             ),
             mesh=MeshConfig(data=1, tensor=1),
@@ -183,9 +181,8 @@ ARRIVALS = {  # before step n
     4: [("r3", [6, 6, 6, 6], _sp(3))],
 }
 SCHEDULES = {
-    "ragged": dict(attention_impl="ragged"),
-    "bucketed": dict(attention_impl="bucketed"),
-    "chained": dict(attention_impl="ragged", multi_step=2, chain_decode=True),
+    "ragged": dict(),
+    "chained": dict(multi_step=2, chain_decode=True),
 }
 PARENT_EVENTS = {
     "ragged": [
@@ -196,14 +193,6 @@ PARENT_EVENTS = {
         [5, "r0", [385], False, False], [5, "r2", [298], False, True],
         [5, "r0", [27], True, False], [5, "r2", [419], True, True],
         [5, "r3", [415], False, False], [6, "r3", [464], True, False]],
-    "bucketed": [
-        [1, "r0", [400], False, False], [1, "r1", [27], True, False],
-        [1, "r0", [400], False, False], [3, "r2", [408], False, True],
-        [3, "r0", [400], False, False], [3, "r2", [83], False, True],
-        [5, "r3", [233], False, False], [5, "r0", [83], False, False],
-        [5, "r2", [298], False, True], [5, "r3", [415], False, False],
-        [6, "r0", [385], False, False], [6, "r2", [419], True, True],
-        [6, "r3", [464], True, False], [7, "r0", [27], True, False]],
     "chained": [
         [1, "r0", [400], False, False], [1, "r1", [27], True, False],
         [2, "r0", [400, 400], False, False], [3, "r2", [408], False, True],
@@ -215,20 +204,19 @@ PARENT_EVENTS = {
 # waits for: with a sink they take that way, the others are returned
 HANDED_OVER = {
     "ragged": {1: 2, 3: 2, 5: 3},     # step -> leading events of that step
-    "bucketed": {1: 2, 3: 1, 5: 1},
     # chained: step 1 launches and does not wait; step 3's decode program
     # carries logprobs (not chainable), so the thread waits for it
     "chained": {3: 2},
 }
 
 
-def make_scheduled_engine(attention_impl, **sched):
+def make_scheduled_engine(**sched):
     cfg = EngineConfig(
         model=ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=128),
         scheduler=SchedulerConfig(max_num_seqs=4, max_num_batched_tokens=64,
-                                  prefill_buckets=(16, 32), **sched),
-        mesh=MeshConfig(data=1, tensor=1), attention_impl=attention_impl)
+                                  **sched),
+        mesh=MeshConfig(data=1, tensor=1))
     return LLMEngine(cfg, mesh=build_mesh(cfg.mesh), num_blocks=128)
 
 
@@ -289,35 +277,12 @@ def test_a_sink_changes_no_token_and_no_order(case):
         assert [e[3] for e in mine].count(True) == 1 and mine[-1][3]
 
 
-class _BlockingRunner:
-    """What engine/pp_runner.py is to the engine: a runner whose
-    decode_multi returns only when the tokens are on the host."""
-
-    supports_chaining = False
-    supports_logprobs = False
-
-    def __init__(self, inner, log):
-        self._inner, self._log = inner, log
-
-    def __getattr__(self, name):
-        return getattr(self._inner, name)
-
-    def decode_multi(self, *a, want_logprobs=False, **kw):
-        self._log.append("decode_multi")
-        sampled, *_ = self._inner.decode_multi(*a, **kw)
-        return np.asarray(sampled)  # blocks
-
-
-@pytest.mark.parametrize("runner", ["launching", "blocking"])
-def test_first_token_reaches_the_sink_before_the_decode_wait(runner):
+def test_first_token_reaches_the_sink_before_the_decode_wait():
     """The prompt completes in ragged step N; in step N+1 its first token
     is handed over under the clock's `deliver` phase after the decode
-    program is launched and before the thread waits for it. A runner that
-    cannot launch without blocking gets the hand-over before its call."""
-    engine = make_scheduled_engine("ragged")
+    program is launched and before the thread waits for it."""
+    engine = make_scheduled_engine()
     log = []
-    if runner == "blocking":
-        engine.runner = _BlockingRunner(engine.runner, log)
     real_enter = engine.clock.enter
 
     def enter(phase, **attrs):
@@ -336,14 +301,11 @@ def test_first_token_reaches_the_sink_before_the_decode_wait(runner):
     assert len(sink_at) == 1 and log[sink_at[0]] == ("sink", [("r0", [400])])
     assert log[sink_at[0] - 1] == "deliver"
     before, after = log[:sink_at[0]], log[sink_at[0] + 1:]
-    if runner == "launching":
-        # ... wait (the ragged step), build, snapshot, commit, launch,
-        # deliver, SINK, wait (the decode step), postprocess
-        assert "launch" in before and before.index("wait") < before.index(
-            "launch")
-        assert after[0] == "wait" and "launch" not in after
-    else:
-        assert "decode_multi" not in before and "decode_multi" in after
+    # ... wait (the ragged step), build, snapshot, commit, launch,
+    # deliver, SINK, wait (the decode step), postprocess
+    assert "launch" in before and before.index("wait") < before.index(
+        "launch")
+    assert after[0] == "wait" and "launch" not in after
     # the decode step's own token is returned, and only that
     assert [(o.request_id, o.new_token_ids) for o in returned] == [
         ("r0", [400])]

@@ -218,8 +218,7 @@ def engine_server(tmp_path, **server_kw):
     cfg = EngineConfig(
         model=ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=512),
-        scheduler=SchedulerConfig(max_num_seqs=4, max_num_batched_tokens=64,
-                                  prefill_buckets=(32, 64)),
+        scheduler=SchedulerConfig(max_num_seqs=4, max_num_batched_tokens=64),
         mesh=MeshConfig(data=1, tensor=1),
     )
     server_kw.setdefault("diagnostics", DiagnosticsConfig(

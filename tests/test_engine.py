@@ -30,8 +30,7 @@ def setup():
         cache=CacheConfig(block_size=4, num_blocks=256),
         scheduler=SchedulerConfig(
             max_num_seqs=8, max_num_batched_tokens=64,
-            prefill_buckets=(16, 32, 64, 128),
-        ),
+            ),
         mesh=MeshConfig(data=1, tensor=4),
     )
     mesh = build_mesh(cfg.mesh)
@@ -39,15 +38,19 @@ def setup():
     return cfg, mesh, params
 
 
-def naive_greedy(cfg, params, prompt, n_tokens, mesh):
-    """Reference: full dense forward each step, argmax."""
+def naive_greedy(cfg, params, prompt, n_tokens, mesh, pad_to=None):
+    """Reference: full dense forward each step, argmax. ``pad_to``: run
+    every step at that one length, the tokens padded behind (attention is
+    causal: what follows a row cannot reach it), so that a caller with many
+    prompts compiles once a model, not once a length."""
     toks = list(prompt)
     with jax.set_mesh(mesh):
         for _ in range(n_tokens):
+            ids = toks + [0] * max((pad_to or 0) - len(toks), 0)
             logits = jax.jit(llama.forward_dense, static_argnums=0)(
-                cfg, params, jnp.asarray([toks], jnp.int32)
+                cfg, params, jnp.asarray([ids], jnp.int32)
             )
-            toks.append(int(jnp.argmax(logits[0, -1])))
+            toks.append(int(jnp.argmax(logits[0, len(toks) - 1])))
     return toks[len(prompt):]
 
 
@@ -84,10 +87,12 @@ def test_batched_mixed_lengths_match_dense(setup):
 
 def test_chunked_prefill_matches_dense(setup):
     cfg, mesh, params = setup
+    # the budget is at least the slots (every decode row claims a token)
     sched = dataclasses.replace(
-        cfg.scheduler, max_num_batched_tokens=4, prefill_buckets=(4,)
+        cfg.scheduler, max_num_batched_tokens=8
     )
     eng = make_engine(setup, scheduler=sched)
+    assert len(PROMPTS[2]) > 8  # served in chunks
     got = eng.generate([PROMPTS[2]], GREEDY)["offline-0"]
     want = naive_greedy(cfg.model, params, PROMPTS[2], 8, mesh)
     assert got == want
@@ -157,3 +162,38 @@ def test_max_model_len_rejection(setup):
     eng = make_engine(setup)
     with pytest.raises(ValueError):
         eng.add_request("big", prompt_token_ids=list(range(600)))
+
+
+@pytest.mark.parametrize("axis", ["stage", "seq"])
+def test_refused_mesh_axes(setup, axis):
+    """Nothing shards over the stage or the seq axis any more (pipeline
+    stages and ring prefill were removed): a mesh that sets one above 1
+    is refused by name, not served on a path that ignores it."""
+    cfg, _, _ = setup
+    mesh_cfg = MeshConfig(data=1, tensor=1, **{axis: 2})
+    mesh = build_mesh(mesh_cfg, devices=jax.devices()[:2])
+    with pytest.raises(ValueError, match=f"{axis}=2 is not supported"):
+        LLMEngine(dataclasses.replace(cfg, mesh=mesh_cfg), mesh=mesh,
+                  num_blocks=64)
+
+
+@pytest.mark.parametrize("value", ["auto", "ragged", "bucketed"])
+def test_attention_impl_flag(value, capsys):
+    """``--attention-impl`` names the one family there is: the two values
+    still accepted change nothing, the removed one is refused by name."""
+    from production_stack_tpu.engine.server import (
+        build_parser,
+        config_from_args,
+    )
+
+    base = ["--model", "tiny-llama", "--max-num-seqs", "4"]
+    plain = config_from_args(build_parser().parse_args(base))
+    if value == "bucketed":
+        with pytest.raises(SystemExit):
+            build_parser().parse_args(base + ["--attention-impl", value])
+        assert "bucketed prefill family was removed" in " ".join(
+            capsys.readouterr().err.split())
+        return
+    args = build_parser().parse_args(base + ["--attention-impl", value])
+    assert config_from_args(args) == plain
+    assert not hasattr(plain, "attention_impl")

@@ -35,7 +35,7 @@ def test_flaky_engine_masked_by_failover(monkeypatch):
             model=ModelConfig.from_pretrained("tiny-llama"),
             cache=CacheConfig(block_size=4, num_blocks=128),
             scheduler=SchedulerConfig(max_num_seqs=2,
-                                      prefill_buckets=(32,)),
+                                  max_num_batched_tokens=64),
         )
         if fault:
             monkeypatch.setenv("FAULT_INJECTION", fault)
@@ -107,7 +107,7 @@ def test_direct_injected_errors_visible():
             model=ModelConfig.from_pretrained("tiny-llama"),
             cache=CacheConfig(block_size=4, num_blocks=64),
             scheduler=SchedulerConfig(max_num_seqs=2,
-                                      prefill_buckets=(32,)),
+                                  max_num_batched_tokens=64),
         )
         server = EngineServer(cfg)
 
@@ -140,7 +140,8 @@ def test_live_fault_toggle():
     cfg = EngineConfig(
         model=ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=64),
-        scheduler=SchedulerConfig(max_num_seqs=2, prefill_buckets=(32,)),
+        scheduler=SchedulerConfig(max_num_seqs=2,
+                                  max_num_batched_tokens=64),
     )
     os.environ["FAULT_INJECTION"] = ""  # armed, no faults yet
     try:
@@ -185,7 +186,8 @@ def test_fault_toggle_absent_when_unarmed():
     cfg = EngineConfig(
         model=ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=64),
-        scheduler=SchedulerConfig(max_num_seqs=2, prefill_buckets=(32,)),
+        scheduler=SchedulerConfig(max_num_seqs=2,
+                                  max_num_batched_tokens=64),
     )
     server = EngineServer(cfg)
 
@@ -213,7 +215,7 @@ def test_latency_and_drop_faults():
             model=ModelConfig.from_pretrained("tiny-llama"),
             cache=CacheConfig(block_size=4, num_blocks=64),
             scheduler=SchedulerConfig(max_num_seqs=2,
-                                      prefill_buckets=(32,)),
+                                  max_num_batched_tokens=64),
         )
         return EngineServer(cfg)
 

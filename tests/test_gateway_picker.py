@@ -227,7 +227,7 @@ def test_kvaware_against_real_engine(binary):
             model=ModelConfig.from_pretrained("tiny-llama"),
             cache=CacheConfig(block_size=4, num_blocks=128),
             scheduler=SchedulerConfig(max_num_seqs=2,
-                                      prefill_buckets=(32, 64)),
+                                  max_num_batched_tokens=64),
             mesh=MeshConfig(data=1, tensor=1),
         )
         server = EngineServer(cfg)

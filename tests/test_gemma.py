@@ -107,7 +107,7 @@ def test_engine_matches_hf_greedy(family_ckpt):
         cache=CacheConfig(block_size=4, num_blocks=256),
         scheduler=SchedulerConfig(
             max_num_seqs=2, max_num_batched_tokens=32,
-            prefill_buckets=(16, 32), multi_step=2,
+            multi_step=2,
         ),
         mesh=MeshConfig(data=1, tensor=1),
     )
@@ -129,7 +129,7 @@ def test_sliding_window_exactness_gate():
     bad = dataclasses.replace(cfg, max_model_len=cfg.sliding_window * 2)
     ecfg = EngineConfig(
         model=bad, cache=CacheConfig(block_size=4, num_blocks=64),
-        scheduler=SchedulerConfig(max_num_seqs=2, prefill_buckets=(16,)),
+        scheduler=SchedulerConfig(max_num_seqs=2),
         mesh=MeshConfig(),
     )
     mesh = build_mesh(ecfg.mesh, devices=jax.devices()[:1])
@@ -182,36 +182,3 @@ def test_gemma_int8_quant_composes():
         np.linalg.norm(a2, axis=-1) * np.linalg.norm(b2, axis=-1)
     )
     assert cos.min() > 0.99
-
-
-def test_gemma2_ring_prefill_token_identical():
-    """Ring-attention prefill (seq axis) carries the softcap: long-prompt
-    Gemma-2 prefill over seq=4 matches the chunked single-device path."""
-    prompt = [(7 * i + 3) % 500 + 1 for i in range(40)]
-
-    def run(mesh_cfg, ring):
-        cfg = EngineConfig(
-            model=ModelConfig.from_pretrained("tiny-gemma2"),
-            cache=CacheConfig(block_size=4, num_blocks=256),
-            scheduler=SchedulerConfig(
-                max_num_seqs=2, max_num_batched_tokens=32,
-                prefill_buckets=(16, 32, 64), ring_prefill_threshold=ring,
-            ),
-            mesh=mesh_cfg,
-        )
-        n = max(mesh_cfg.data, 1) * max(mesh_cfg.seq, 1)
-        mesh = build_mesh(mesh_cfg, devices=jax.devices()[:n])
-        engine = LLMEngine(cfg, mesh=mesh, num_blocks=256)
-        sp = SamplingParams(temperature=0.0, max_tokens=2, ignore_eos=True)
-        engine.add_request("r", prompt_token_ids=prompt, sampling=sp)
-        toks = []
-        steps = 0
-        while engine.has_unfinished() and steps < 32:
-            for o in engine.step():
-                toks.extend(o.new_token_ids)
-            steps += 1
-        return toks
-
-    ring_toks = run(MeshConfig(data=1, seq=4, tensor=1), ring=16)
-    dense_toks = run(MeshConfig(data=1, tensor=1), ring=0)
-    assert ring_toks == dense_toks

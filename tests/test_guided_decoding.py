@@ -121,7 +121,7 @@ def setup():
         cache=CacheConfig(block_size=4, num_blocks=256),
         scheduler=SchedulerConfig(
             max_num_seqs=4, max_num_batched_tokens=64,
-            prefill_buckets=(16, 32), multi_step=2,
+            multi_step=2,
         ),
         mesh=MeshConfig(data=1, tensor=1),
         max_grammars=2, max_grammar_states=128,

@@ -425,9 +425,7 @@ MULTIHOST_VALUES = {
         "modelRef": "llama-3-70b",
         "engineConfig": {
             "maxModelLen": 8192, "maxNumSeqs": 32, "dtype": "bfloat16",
-            # model sharded across hosts by TP (GSPMD over ICI+DCN) — the
-            # staged PP runner does not compose with multihost (its
-            # per-stage submeshes don't span every controller process)
+            # model sharded across hosts by TP (GSPMD over ICI+DCN)
             "tensorParallelSize": 32,
         },
         "tpu": {"accelerator": "tpu-v5-lite-podslice", "topology": "4x8",
@@ -510,24 +508,6 @@ def test_multihost_requires_control_secret():
     vals["secrets"] = {"create": False, "controlSecret": ""}
     with pytest.raises(Exception, match="controlSecret"):
         render_objects(HELM, vals)
-
-
-def test_multihost_refuses_pipeline_parallel_at_render_time():
-    """engine/server.py main() hard-refuses multihost + PP>1; the chart
-    must fail the RENDER, not ship a crash-looping StatefulSet (r4
-    advisor)."""
-    import copy
-
-    import pytest
-
-    vals = copy.deepcopy(MULTIHOST_VALUES)
-    spec = vals["servingEngineSpec"]["modelSpec"][0]
-    spec["engineConfig"]["pipelineParallelSize"] = 2
-    with pytest.raises(Exception, match="pipelineParallelSize"):
-        render_objects(HELM, vals)
-    # PP=1 stays renderable (explicit 1 is the harmless spelling)
-    spec["engineConfig"]["pipelineParallelSize"] = 1
-    assert by_kind(render_objects(HELM, vals), "StatefulSet")
 
 
 def test_multihost_spec_gets_no_keda_scaledobject():

@@ -23,7 +23,6 @@ from jax.sharding import SingleDeviceSharding
 from production_stack_tpu.ops.paged_attention_pallas import (
     kv_cache_write_pallas,
     paged_decode_attention_pallas,
-    paged_prefill_attention_pallas,
 )
 from production_stack_tpu.ops.ragged_paged_attention_pallas import (
     ragged_paged_attention_pallas,
@@ -70,11 +69,6 @@ CASES = {
             q, c, bt, cl, layer_idx=1),
         (((SLOTS, H, D), jnp.bfloat16), CACHE, ((SLOTS, M), I32),
          ((SLOTS,), I32))),
-    "paged_prefill_attention": (
-        lambda q, c, bt, qs, ct: paged_prefill_attention_pallas(
-            q, c, bt, qs, ct, layer_idx=1),
-        (((2, 256, H, D), jnp.bfloat16), CACHE, ((2, M), I32),
-         ((2,), I32), ((2,), I32))),
     "kv_cache_write": (
         lambda c, new, sm: kv_cache_write_pallas(c, new, sm, layer_idx=1),
         (CACHE, ((64, 2 * KH, D), jnp.bfloat16), ((64,), I32))),

@@ -44,8 +44,7 @@ def setup():
         # HBM pool deliberately tiny (14 blocks) so finished contexts are
         # evicted; host tier holds 64 blocks
         cache=CacheConfig(block_size=4, num_blocks=14, host_offload_blocks=64),
-        scheduler=SchedulerConfig(max_num_seqs=2, max_num_batched_tokens=64,
-                                  prefill_buckets=(32,)),
+        scheduler=SchedulerConfig(max_num_seqs=2, max_num_batched_tokens=64),
         mesh=MeshConfig(data=1, tensor=1),
     )
     mesh = build_mesh(cfg.mesh)

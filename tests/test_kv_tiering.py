@@ -116,8 +116,7 @@ def setup():
         cache=CacheConfig(block_size=4, num_blocks=14,
                           kv_host_cache_bytes=1 << 22,
                           kv_prefetch_workers=1),
-        scheduler=SchedulerConfig(max_num_seqs=2, max_num_batched_tokens=64,
-                                  prefill_buckets=(32,)),
+        scheduler=SchedulerConfig(max_num_seqs=2, max_num_batched_tokens=64),
         mesh=MeshConfig(data=1, tensor=1),
     )
     mesh = build_mesh(cfg.mesh)
@@ -221,8 +220,7 @@ def _remote_engine(mesh, params, cfg_model, url):
     cfg = EngineConfig(
         model=cfg_model,
         cache=CacheConfig(block_size=4, num_blocks=14, remote_kv_url=url),
-        scheduler=SchedulerConfig(max_num_seqs=2, max_num_batched_tokens=64,
-                                  prefill_buckets=(32,)),
+        scheduler=SchedulerConfig(max_num_seqs=2, max_num_batched_tokens=64),
         mesh=MeshConfig(data=1, tensor=1),
     )
     return LLMEngine(cfg, mesh=mesh, params=params, num_blocks=14)

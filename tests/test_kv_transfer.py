@@ -28,12 +28,13 @@ from production_stack_tpu.engine.sampling import SamplingParams
 from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
 
 
-def make_engine(stage=1):
+def make_engine():
     cfg = EngineConfig(
         model=ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=64),
-        scheduler=SchedulerConfig(max_num_seqs=2, prefill_buckets=(32,)),
-        mesh=MeshConfig(data=1, stage=stage, tensor=1),
+        scheduler=SchedulerConfig(max_num_seqs=2,
+                                  max_num_batched_tokens=64),
+        mesh=MeshConfig(data=1, tensor=1),
     )
     return LLMEngine(cfg, mesh=build_mesh(cfg.mesh), num_blocks=64)
 
@@ -64,22 +65,6 @@ def test_range_roundtrip_matches_monolithic():
         dst.runner.import_blocks_range([5, 6], lo, full[lo:lo + n])
     got = dst.runner.export_blocks([5, 6])
     np.testing.assert_array_equal(got, full)
-
-
-def test_range_roundtrip_staged_runner():
-    engine = make_engine(stage=2)
-    fill(engine)
-    blocks = [1, 2]
-    full = engine.runner.export_blocks(blocks)
-    L = full.shape[0]
-    # group size 1 crosses stage boundaries (tiny-llama: 2 layers, 2 stages)
-    parts = [engine.runner.export_blocks_range(blocks, lo, n)
-             for lo, n in layer_groups(L, 1)]
-    np.testing.assert_array_equal(np.concatenate(parts, axis=0), full)
-    dst = make_engine(stage=2)
-    for lo, n in layer_groups(L, 1):
-        dst.runner.import_blocks_range([3, 4], lo, full[lo:lo + n])
-    np.testing.assert_array_equal(dst.runner.export_blocks([3, 4]), full)
 
 
 class Pipe:

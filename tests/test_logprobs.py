@@ -26,7 +26,7 @@ def server():
         cache=CacheConfig(block_size=4, num_blocks=512),
         scheduler=SchedulerConfig(
             max_num_seqs=4, max_num_batched_tokens=64,
-            prefill_buckets=(32, 64), multi_step=2,
+            multi_step=2,
         ),
         mesh=MeshConfig(data=1, tensor=1),
     )
@@ -247,34 +247,6 @@ def test_prompt_logprobs_consistency(server):
     assert len(toks) == len(lps) == 3
     for a, (b, _top) in zip(lps, entries[-3:]):
         assert a == pytest.approx(b, abs=2e-3)
-
-
-def test_logprobs_rejected_with_pipeline_parallelism():
-    """The staged runner has no logprob programs: requests must 400/raise
-    up-front, and warmup must not emit logprob requests there."""
-    import jax
-
-    from production_stack_tpu.engine.engine import LLMEngine
-    from production_stack_tpu.engine.sampling import SamplingParams
-    from production_stack_tpu.parallel.mesh import build_mesh
-
-    cfg = EngineConfig(
-        model=ModelConfig.from_pretrained("tiny-llama"),
-        cache=CacheConfig(block_size=4, num_blocks=128),
-        scheduler=SchedulerConfig(max_num_seqs=2, max_num_batched_tokens=32,
-                                  prefill_buckets=(16, 32)),
-        mesh=MeshConfig(data=1, stage=2, tensor=1),
-    )
-    mesh = build_mesh(cfg.mesh, devices=jax.devices()[:2])
-    eng = LLMEngine(cfg, mesh=mesh, num_blocks=128)
-    with pytest.raises(ValueError, match="pipeline parallelism"):
-        eng.add_request("lp", prompt_token_ids=[1, 2, 3],
-                        sampling=SamplingParams(logprobs=2))
-    # plain requests still serve
-    out = eng.generate([[1, 2, 3]], SamplingParams(temperature=0.0,
-                                                   max_tokens=2,
-                                                   ignore_eos=True))
-    assert len(out["offline-0"]) == 2
 
 
 def test_logprobs_with_stop_string_truncation(server):

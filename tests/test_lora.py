@@ -55,7 +55,8 @@ def make_server() -> EngineServer:
         model=ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=256,
                           enable_prefix_caching=False),
-        scheduler=SchedulerConfig(max_num_seqs=4, prefill_buckets=(32,)),
+        scheduler=SchedulerConfig(max_num_seqs=4,
+                                  max_num_batched_tokens=64),
         mesh=MeshConfig(data=1, tensor=1),
     )
     return EngineServer(cfg)

@@ -128,7 +128,8 @@ def test_qwen2_bias_engine_matches_dense():
     cfg = EngineConfig(
         model=ModelConfig.from_pretrained("tiny-qwen2"),
         cache=CacheConfig(block_size=4, num_blocks=128),
-        scheduler=SchedulerConfig(max_num_seqs=2, prefill_buckets=(32,)),
+        scheduler=SchedulerConfig(max_num_seqs=2,
+                                  max_num_batched_tokens=64),
     )
     mesh = build_mesh(MeshConfig(data=1, tensor=2))
     params = init_or_load(cfg.model, mesh, seed=0)

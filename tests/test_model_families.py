@@ -147,7 +147,7 @@ def test_engine_matches_hf_greedy(family_ckpt):
         cache=CacheConfig(block_size=4, num_blocks=256),
         scheduler=SchedulerConfig(
             max_num_seqs=2, max_num_batched_tokens=32,
-            prefill_buckets=(16, 32), multi_step=2,
+            multi_step=2,
         ),
         mesh=MeshConfig(data=1, tensor=1),
     )
@@ -240,7 +240,7 @@ def test_qwen3_spec_decode_composes(family_ckpt):
             cache=CacheConfig(block_size=4, num_blocks=256),
             scheduler=SchedulerConfig(
                 max_num_seqs=2, max_num_batched_tokens=32,
-                prefill_buckets=(16, 32), spec_ngram_k=spec_k,
+                spec_ngram_k=spec_k,
             ),
             mesh=MeshConfig(data=1, tensor=1),
         )

@@ -52,8 +52,7 @@ GREEDY = SamplingParams(temperature=0.0, max_tokens=12, ignore_eos=True)
 
 
 def _cfg(tp, **sched):
-    kw = dict(max_num_seqs=8, max_num_batched_tokens=32,
-              prefill_buckets=(16, 32, 64, 128))
+    kw = dict(max_num_seqs=8, max_num_batched_tokens=32)
     kw.update(sched)
     from production_stack_tpu.parallel.mesh import MeshConfig
 
@@ -62,8 +61,7 @@ def _cfg(tp, **sched):
         cache=CacheConfig(block_size=4, num_blocks=256),
         scheduler=SchedulerConfig(**kw),
         mesh=MeshConfig(data=1, tensor=tp),
-        attention_impl="ragged",
-    )
+        )
 
 
 def _engine(tp, **sched):
@@ -181,8 +179,7 @@ def test_zero_unexpected_recompiles_after_warmup_tp4():
     the TP=4 mesh after warmup() hits only pre-compiled programs —
     vllm:unexpected_recompiles_total stays 0 (the regression the
     tentpole must hold at TP=4/8 just as at TP=1)."""
-    eng = _engine(4, max_num_seqs=4, max_num_batched_tokens=16,
-                  prefill_buckets=(16, 32))
+    eng = _engine(4, max_num_seqs=4, max_num_batched_tokens=16)
     assert eng.perf is not None
     eng.warmup()
     assert eng.perf.stats_fields()["unexpected_recompiles"] == 0

@@ -324,27 +324,6 @@ def test_frame_size_cap_rejects_unauthenticated_giant_header():
         b.close()
 
 
-def test_server_refuses_multihost_with_pipeline_stages():
-    """The staged PP runner's per-stage submeshes don't span every
-    controller process — the combination must be refused at startup."""
-    env = dict(os.environ)
-    env.update({
-        "PSTPU_CONTROL_SECRET": "s",
-        "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
-    })
-    proc = subprocess.run(
-        [sys.executable, "-m", "production_stack_tpu.engine.server",
-         "--model", "tiny-llama",
-         "--num-processes", "2", "--process-id", "0",
-         "--distributed-coordinator", "127.0.0.1:1",
-         "--pipeline-parallel-size", "2"],
-        env=env, cwd=REPO, timeout=60, stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT, text=True,
-    )
-    assert proc.returncode != 0
-    assert "pipeline" in proc.stdout.lower()
-
-
 @pytest.mark.slow
 def test_real_server_two_process_group_serves_completions():
     """The ACTUAL server binary in both roles (caught a follower-path

@@ -60,13 +60,11 @@ def tiny():
 
 
 def make_engine(cfg, mesh, params, **sched) -> LLMEngine:
-    kw = dict(max_num_seqs=4, max_num_batched_tokens=16,
-              prefill_buckets=(16,))
+    kw = dict(max_num_seqs=4, max_num_batched_tokens=16)
     kw.update(sched)
     ecfg = EngineConfig(
         model=cfg, cache=CacheConfig(block_size=4, num_blocks=256),
-        scheduler=SchedulerConfig(**kw), mesh=MeshConfig(data=1, tensor=1),
-        attention_impl="ragged")
+        scheduler=SchedulerConfig(**kw), mesh=MeshConfig(data=1, tensor=1))
     return LLMEngine(ecfg, mesh=mesh, params=params)
 
 

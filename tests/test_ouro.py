@@ -78,13 +78,12 @@ def tiny(mesh):
 
 
 def make_engine(cfg, mesh, params, role="unified", **sched) -> LLMEngine:
-    kw = dict(max_num_seqs=4, max_num_batched_tokens=16,
-              prefill_buckets=(16,))
+    kw = dict(max_num_seqs=4, max_num_batched_tokens=16)
     kw.update(sched)
     ecfg = EngineConfig(
         model=cfg, cache=CacheConfig(block_size=4, num_blocks=256),
         scheduler=SchedulerConfig(**kw), mesh=MeshConfig(data=1, tensor=1),
-        attention_impl="ragged", role=role)
+        role=role)
     return LLMEngine(ecfg, mesh=mesh, params=params)
 
 
@@ -355,18 +354,6 @@ def test_a_transfer_lands_every_cache_layer(tiny):
                              full.shape, str(full.dtype), 6)
     asyncio.run(main())
     np.testing.assert_array_equal(dst.runner.export_blocks([7, 8, 9]), full)
-
-
-def test_pipeline_stages_refuse_a_looped_stack():
-    from production_stack_tpu.engine.pp_runner import StagedModelRunner
-
-    cfg = EngineConfig(
-        model=dataclasses.replace(ModelConfig.from_pretrained("tiny-ouro"),
-                                  num_layers=2),
-        cache=CacheConfig(block_size=4, num_blocks=64),
-        mesh=MeshConfig(data=1, stage=2, tensor=1))
-    with pytest.raises(ValueError, match="loop_passes=4.*pipeline"):
-        StagedModelRunner(cfg, build_mesh(cfg.mesh))
 
 
 # -- (f) the configuration file ----------------------------------------------

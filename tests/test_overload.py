@@ -246,7 +246,6 @@ def make_sched(budget=16, max_seqs=8, fair=False, weights=None):
     sched = Scheduler(
         SchedulerConfig(
             max_num_seqs=max_seqs, max_num_batched_tokens=budget,
-            prefill_buckets=(4, 8), prefill_batch=2,
             fair_share=fair, tenant_weights=weights or {},
         ),
         CacheConfig(block_size=4, num_blocks=512),

@@ -349,40 +349,6 @@ def _cell_body(config: str, windowed: bool = False) -> str:
         cfg.sliding_window if windowed else 0)
 
 
-def test_pallas_prefill_matches_xla_interpret():
-    rng = np.random.default_rng(3)
-    from production_stack_tpu.ops.paged_attention_pallas import (
-        paged_prefill_attention_pallas,
-    )
-
-    kh, d, H = 4, 16, 8
-    N, M, layers = 16, 8, 2
-    layer = 0
-    S_pad, chunk, q_start = 8, 6, 5  # chunked continuation: ctx = 11
-    ctx = q_start + chunk
-    cache = build_random_cache(rng, layers, N, kh, d)
-    table = np.arange(M, dtype=np.int32)
-    q = rng.standard_normal((S_pad, H, d), dtype=np.float32)
-
-    # batch of 2: one real chunk + one inactive padding row (ctx 0)
-    q2 = np.stack([q, np.zeros_like(q)])
-    got = paged_prefill_attention_pallas(
-        jnp.asarray(q2), cache, jnp.asarray(np.stack([table, table])),
-        jnp.asarray([q_start, 0], jnp.int32), jnp.asarray([ctx, 0], jnp.int32),
-        layer, q_tile=8, windows=2, interpret=True,
-    )
-    positions = np.full((1, S_pad), -1, np.int32)
-    positions[0, :chunk] = np.arange(q_start, ctx)
-    want = paged_attention(
-        jnp.asarray(q[None]), cache[layer], jnp.asarray(table[None]),
-        jnp.asarray([ctx], jnp.int32), jnp.asarray(positions),
-    )[0]
-    np.testing.assert_allclose(
-        np.asarray(got[0, :chunk]), np.asarray(want[:chunk]), rtol=2e-4, atol=2e-4
-    )
-    assert np.all(np.asarray(got[1]) == 0)  # inactive row untouched
-
-
 def test_pallas_kv_write_matches_scatter_interpret():
     rng = np.random.default_rng(4)
     from production_stack_tpu.ops.paged_attention_pallas import (
@@ -495,42 +461,3 @@ def test_pallas_decode_poisoned_tail_blocks_ignored(kh, G, d, bs, dtype,
     live = lens > 0
     np.testing.assert_allclose(_f32(got)[live], _f32(want)[live],
                                **_decode_tol(dtype))
-
-
-def test_pallas_prefill_poisoned_tail_blocks_ignored():
-    """Same hazard pin for the PREFILL kernel: table blocks past a tile's
-    causal reach are never DMA'd; poison must not leak into outputs."""
-    rng = np.random.default_rng(8)
-    from production_stack_tpu.ops.paged_attention_pallas import (
-        paged_prefill_attention_pallas,
-    )
-
-    kh, d, H = 4, 16, 8
-    N, M, layers = 16, 8, 1
-    S_pad, chunk, q_start = 8, 6, 5  # ctx = 11: partial block at BS=4
-    ctx = q_start + chunk
-    cache = np.array(build_random_cache(rng, layers, N, kh, d))
-    table = np.arange(M, dtype=np.int32)
-    live_blocks = -(-ctx // BS)
-    for m in range(live_blocks, M):
-        cache[0, table[m]] = np.nan     # never-read whole blocks
-    if ctx % BS:
-        cache[0, table[live_blocks - 1], ctx % BS:] = np.inf  # in-block tail
-    q = rng.standard_normal((1, S_pad, H, d), dtype=np.float32)
-    got = paged_prefill_attention_pallas(
-        jnp.asarray(q), jnp.asarray(cache), jnp.asarray(table[None]),
-        jnp.asarray([q_start], jnp.int32), jnp.asarray([ctx], jnp.int32),
-        0, q_tile=8, windows=2, interpret=True,
-    )
-    assert np.isfinite(np.asarray(got[0, :chunk])).all()
-    positions = np.full((1, S_pad), -1, np.int32)
-    positions[0, :chunk] = np.arange(q_start, ctx)
-    want = paged_attention(
-        jnp.asarray(q), jnp.asarray(np.nan_to_num(cache, posinf=0.0))[0],
-        jnp.asarray(table[None]), jnp.asarray([ctx], jnp.int32),
-        jnp.asarray(positions),
-    )[0]
-    np.testing.assert_allclose(
-        np.asarray(got[0, :chunk]), np.asarray(want[:chunk]),
-        rtol=2e-4, atol=2e-4,
-    )

@@ -75,7 +75,7 @@ def engine_config(cfg=None, num_blocks=64, slots=4, **over) -> EngineConfig:
         cache=CacheConfig(block_size=16, num_blocks=num_blocks),
         scheduler=SchedulerConfig(max_num_seqs=slots,
                                   max_num_batched_tokens=BUDGET),
-        mesh=MeshConfig(data=1, tensor=1), attention_impl="ragged", **over)
+        mesh=MeshConfig(data=1, tensor=1), **over)
 
 
 def engine(cfg=None, params=None, **kw) -> LLMEngine:
@@ -521,14 +521,6 @@ def test_the_engine_refuses_at_start_up_and_an_adapter_when_it_comes(served):
     config.scheduler.spec_ngram_k = 2
     with pytest.raises(ValueError, match="n-gram speculative decoding"):
         LLMEngine(config, mesh=one_device())
-    # pipeline stages: refused before a staged runner is chosen
-    staged = build_mesh(MeshConfig(stage=2), devices=jax.devices()[:2])
-    with pytest.raises(ValueError, match="pipeline stages hold no latent"):
-        LLMEngine(engine_config(), mesh=staged)
-    with pytest.raises(ValueError, match="attention_impl=bucketed"):
-        cfg = engine_config()
-        cfg.attention_impl = "bucketed"
-        LLMEngine(cfg, mesh=one_device())
     with pytest.raises(ValueError, match="LoRA adapters"):
         served[0].runner.register_lora(1, {})
     # nothing of an allowed configuration is refused

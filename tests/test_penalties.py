@@ -21,8 +21,7 @@ def make_engine(multi_step=3):
     cfg = EngineConfig(
         model=ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=256),
-        scheduler=SchedulerConfig(max_num_seqs=2, prefill_buckets=(32,),
-                                  multi_step=multi_step),
+        scheduler=SchedulerConfig(max_num_seqs=2, multi_step=multi_step),
         mesh=MeshConfig(data=1, tensor=1),
     )
     mesh = build_mesh(cfg.mesh)

@@ -67,7 +67,7 @@ def test_perf_accountant_prefill_decode_arithmetic():
     acc = make_accountant()
     # attn flops/token/ctx = 4*L*H*D = 4*2*2*4 = 64
     # kv bytes/token       = 2*L*KH*D*2 = 2*2*1*4*2 = 32
-    acc.record_prefill(live_tokens=10, ctx_tokens=30, rows=2, ts=100.0)
+    acc.record_ragged(10, 30, 2, 0, 0, ts=100.0)
     acc.record_decode(live_seqs=4, steps=2, ctx_tokens=40, ts=101.0)
     rates = acc._window_rates(101.0)  # span = 1s
     prefill_flops = 2 * 1000 * 10 + 64 * 10 * 15      # ctx_mean = 30/2
@@ -84,7 +84,7 @@ def test_perf_accountant_prefill_decode_arithmetic():
 
 def test_perf_accountant_window_trim_keeps_totals():
     acc = make_accountant(window=60.0)
-    acc.record_prefill(live_tokens=10, ctx_tokens=10, rows=1, ts=100.0)
+    acc.record_ragged(10, 10, 1, 0, 0, ts=100.0)
     acc.record_decode(live_seqs=1, steps=1, ctx_tokens=4, ts=200.0)
     rates = acc._window_rates(200.0)
     assert len(acc._events) == 1  # the ts=100 prefill fell out
@@ -150,14 +150,14 @@ def test_compile_tracker_counts_new_signatures_only():
 def test_wrap_runner_programs_is_idempotent():
     class Runner:
         def __init__(self):
-            self._prefill = lambda *a: "p"
+            self._ragged = lambda *a: "p"
             self._decode_multi = None  # absent variants are skipped
 
     runner = Runner()
     wrap_runner_programs(runner, lambda *a: None)
     wrap_runner_programs(runner, lambda *a: None)
-    assert isinstance(runner._prefill, CompileTracker)
-    assert not isinstance(runner._prefill.fn, CompileTracker)
+    assert isinstance(runner._ragged, CompileTracker)
+    assert not isinstance(runner._ragged.fn, CompileTracker)
     assert runner._decode_multi is None
 
 
@@ -169,8 +169,7 @@ def make_server() -> EngineServer:
         cache=CacheConfig(block_size=4, num_blocks=512),
         scheduler=SchedulerConfig(
             max_num_seqs=4, max_num_batched_tokens=64,
-            prefill_buckets=(32, 64),
-        ),
+            ),
         mesh=MeshConfig(data=1, tensor=1),
         # the CPU has no entry in DEVICE_PEAKS: utilization needs peaks
         perf=PerfConfig(peak_tflops=1e-3, peak_hbm_gbps=1.0),
@@ -271,9 +270,10 @@ def test_unknown_device_kind_exports_no_utilization(server):
 
 def test_unexpected_recompile_after_steady(server):
     async def fn(client):
-        # byte tokenizer: a short prompt sits in the 32 bucket; warm it,
-        # declare steady, then a >32-byte prompt forces the 64 bucket —
-        # exactly the shape-leak the counter exists to catch
+        # warm the plain programs, declare steady, then send the first
+        # request of a static variant nothing warmed (a logit bias: the
+        # use_controls programs). The ragged step compiles for no prompt
+        # length, so a variant is what is left to leak a compile
         r = await client.post(
             "/v1/completions",
             json={"model": "tiny-llama", "prompt": "warm",
@@ -285,7 +285,8 @@ def test_unexpected_recompile_after_steady(server):
         r = await client.post(
             "/v1/completions",
             json={"model": "tiny-llama", "prompt": "x" * 50,
-                  "max_tokens": 2, "temperature": 0, "ignore_eos": True},
+                  "max_tokens": 2, "temperature": 0, "ignore_eos": True,
+                  "logit_bias": {"7": 0.5}},
         )
         assert r.status == 200
         after = server.engine.perf.stats_fields()["unexpected_recompiles"]

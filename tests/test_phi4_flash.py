@@ -74,7 +74,7 @@ def engine(params=None, slots=4, num_blocks=64, budget=BUDGET, **over):
             cache=CacheConfig(block_size=BLOCK, num_blocks=num_blocks),
             scheduler=SchedulerConfig(max_num_seqs=slots,
                                       max_num_batched_tokens=budget),
-            mesh=MeshConfig(data=1, tensor=1), attention_impl="ragged"),
+            mesh=MeshConfig(data=1, tensor=1)),
         mesh=one_device(), params=params)
 
 
@@ -208,7 +208,7 @@ def _engine_config(**over):
     cfg = EngineConfig(
         model=CFG, cache=CacheConfig(block_size=BLOCK, num_blocks=32),
         scheduler=SchedulerConfig(max_num_seqs=2, max_num_batched_tokens=16),
-        mesh=MeshConfig(data=1, tensor=1), attention_impl="ragged")
+        mesh=MeshConfig(data=1, tensor=1))
     for k, v in over.items():
         obj, _, field = k.rpartition(".")
         setattr(getattr(cfg, obj) if obj else cfg, field, v)
@@ -219,8 +219,7 @@ def _engine_config(**over):
     ({"scheduler.spec_ngram_k": 2}, "n-gram speculative"),
     ({"role": "prefill"}, "role=prefill"),
     ({"cache.host_offload_blocks": 8}, "host or remote KV tier"),
-    ({"cache.remote_kv_url": "http://x"}, "host or remote KV tier"),
-    ({"attention_impl": "bucketed"}, "attention_impl=bucketed")])
+    ({"cache.remote_kv_url": "http://x"}, "host or remote KV tier")])
 def test_what_would_move_or_guess_at_state_or_blocks_is_refused(over, match):
     with pytest.raises(ValueError, match=match):
         LLMEngine(_engine_config(**over), mesh=one_device())

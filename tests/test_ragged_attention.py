@@ -1,6 +1,7 @@
 """Ragged paged attention: one mixed prefill+decode dispatch.
 
-Four layers of coverage for the unified path:
+Three layers of coverage (the serving path end to end on the tiny models
+is tests/test_serving_path.py):
 
 1. ``tile_metadata`` unit arithmetic (tile → overlapping-span ranges).
 2. Interpret-mode fuzz: the Pallas ragged kernel vs the XLA ragged
@@ -8,25 +9,18 @@ Four layers of coverage for the unified path:
    empty (inactive) spans, single-token prefills, decode rows, and
    block tables at their edge widths; plus the XLA ragged reference vs
    the padded ``paged_attention`` reference per sequence.
-3. Scheduler token-budget policy units (decode rows first, FCFS chunks,
-   no bucket caps) and PerfAccountant ``record_ragged`` split units.
-4. End-to-end on the tiny model: greedy outputs bit-identical between
-   ``attention_impl="ragged"`` and ``"bucketed"``, mixed staggered
-   traffic with penalties/logprobs, and zero unexpected recompiles
-   after warmup on the ragged path (ONE steady-state signature set).
+3. Scheduler token-budget policy units (decode rows first, FCFS chunks)
+   and PerfAccountant ``record_ragged`` split units.
 """
 
-import dataclasses
 from unittest import mock
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from production_stack_tpu.engine.config import (
     CacheConfig,
-    EngineConfig,
     ModelConfig,
     SchedulerConfig,
 )
@@ -562,12 +556,10 @@ def _make_sched(budget=16, max_seqs=4):
     sched = Scheduler(
         SchedulerConfig(
             max_num_seqs=max_seqs, max_num_batched_tokens=budget,
-            prefill_buckets=(4, 8), prefill_batch=2,
-        ),
+            ),
         CacheConfig(block_size=4, num_blocks=128),
         num_blocks=128, max_model_len=256,
     )
-    sched.unified = True
     return sched
 
 
@@ -582,8 +574,7 @@ def test_unified_schedule_fcfs_budget_no_bucket_cap():
     sched.add(_seq("a", 30, t=1.0))
     sched.add(_seq("b", 5, t=2.0))
     out = sched.schedule()
-    # FCFS: the whole budget goes to the older prompt — and the 16-token
-    # chunk ignores the (4, 8) buckets entirely (no bucket truncation)
+    # FCFS: the whole budget goes to the older prompt, in one chunk
     assert [(sp.seq.request_id, sp.chunk_len) for sp in out.prefills] == [
         ("a", 16)
     ]
@@ -675,369 +666,3 @@ def test_record_ragged_prefill_only_and_empty():
     assert len(acc._events) == 1 and acc._events[0][1] == "prefill"
     acc.record_ragged(0, 0, 0, 0, 0, ts=100.0)
     assert len(acc._events) == 1  # empty dispatch records nothing
-
-
-# ---- end-to-end on the tiny model -----------------------------------------
-
-@pytest.fixture(scope="module")
-def setup():
-    from production_stack_tpu.engine.weights import init_or_load
-    from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
-
-    cfg = EngineConfig(
-        model=ModelConfig.from_pretrained("tiny-llama"),
-        cache=CacheConfig(block_size=4, num_blocks=256),
-        scheduler=SchedulerConfig(
-            max_num_seqs=8, max_num_batched_tokens=32,
-            prefill_buckets=(16, 32, 64, 128),
-        ),
-        mesh=MeshConfig(data=1, tensor=4),
-    )
-    mesh = build_mesh(cfg.mesh)
-    params = init_or_load(cfg.model, mesh, seed=0)
-    return cfg, mesh, params
-
-
-def make_engine(setup, **overrides):
-    from production_stack_tpu.engine.engine import LLMEngine
-
-    cfg, mesh, params = setup
-    cfg = dataclasses.replace(cfg, **overrides) if overrides else cfg
-    return LLMEngine(cfg, mesh=mesh, params=params,
-                     num_blocks=cfg.cache.num_blocks)
-
-
-def _drain(eng, reqs, stagger_at=(), abort_at=None, top=False):
-    """Submit requests (optionally staggered mid-flight), collect tokens
-    and token-logprobs per request id (with ``top`` the whole entries:
-    the chosen token's and the top list). A request is ``(id, prompt,
-    sampling)`` or ``(id, prompt, sampling, adapter_slot)``;
-    ``abort_at=(step, id)`` aborts that request between two steps."""
-    toks = {rid: [] for rid, *_ in reqs}
-    lps = {rid: [] for rid, *_ in reqs}
-
-    def submit(rid, prompt, sampling, slot=0):
-        eng.add_request(rid, prompt_token_ids=prompt, sampling=sampling,
-                        adapter_slot=slot)
-
-    queue = list(reqs)
-    if not stagger_at:  # submit everything up front
-        for req in queue:
-            submit(*req)
-        queue = []
-    else:  # first request now, the rest at the named step numbers
-        submit(*queue.pop(0))
-    n = 0
-    while True:
-        outs = eng.step()
-        n += 1
-        if queue and n in stagger_at:
-            submit(*queue.pop(0))
-        for o in outs:
-            toks[o.request_id].extend(o.new_token_ids)
-            if o.new_logprobs:
-                lps[o.request_id].extend(
-                    e if top else e[0] for e in o.new_logprobs)
-        if abort_at and n == abort_at[0]:
-            assert eng.abort_request(abort_at[1])
-        if not eng.has_unfinished() and not queue:
-            break
-    return toks, lps
-
-
-GREEDY = SamplingParams(temperature=0.0, max_tokens=12, ignore_eos=True)
-
-
-def test_greedy_bit_identity_ragged_vs_bucketed(setup):
-    reqs = [
-        ("r0", [1, 5, 9, 13, 2, 6], GREEDY),
-        ("r1", [3, 7, 11], GREEDY),
-        # longer than the 32-token budget: forces chunked prefill under
-        # the unified policy
-        ("r2", list(range(1, 70)), GREEDY),
-        ("r3", [2, 4], GREEDY),
-    ]
-    t_b, _ = _drain(make_engine(setup, attention_impl="bucketed"),
-                    list(reqs))
-    t_r, _ = _drain(make_engine(setup, attention_impl="ragged"),
-                    list(reqs))
-    assert t_b == t_r
-    for rid in t_b:
-        assert len(t_b[rid]) == 12
-
-
-def test_ragged_mixed_staggered_penalties_logprobs(setup):
-    reqs = [
-        ("long", list(range(1, 60)),
-         SamplingParams(temperature=0.0, max_tokens=8, ignore_eos=True)),
-        ("pen", [5, 6, 7, 8],
-         SamplingParams(temperature=0.0, max_tokens=8,
-                        presence_penalty=0.8, frequency_penalty=0.3,
-                        ignore_eos=True)),
-        ("lp", [9, 10, 11],
-         SamplingParams(temperature=0.0, max_tokens=6, logprobs=3,
-                        ignore_eos=True)),
-        ("short", [2, 3],
-         SamplingParams(temperature=0.0, max_tokens=10, ignore_eos=True)),
-    ]
-    t_b, l_b = _drain(make_engine(setup, attention_impl="bucketed"),
-                      list(reqs), stagger_at=(2, 3, 4))
-    t_r, l_r = _drain(make_engine(setup, attention_impl="ragged"),
-                      list(reqs), stagger_at=(2, 3, 4))
-    assert t_b == t_r
-    for rid in l_b:
-        assert len(l_b[rid]) == len(l_r[rid])
-        for a, b in zip(l_b[rid], l_r[rid]):
-            assert a == pytest.approx(b, abs=1e-3)
-
-
-def test_ragged_requires_budget_at_least_max_seqs(setup):
-    with pytest.raises(ValueError, match="max_num_batched_tokens"):
-        make_engine(
-            setup, attention_impl="ragged",
-            scheduler=SchedulerConfig(max_num_seqs=8,
-                                      max_num_batched_tokens=4),
-        )
-
-
-def test_ragged_auto_resolves_by_backend(setup):
-    # CPU CI: no Pallas → auto lands on bucketed; forcing "ragged" runs
-    # the XLA ragged reference (the kernel's parity oracle)
-    eng = make_engine(setup)
-    assert eng.runner.attention_impl == "bucketed"
-    assert eng.scheduler.unified is False
-    eng = make_engine(setup, attention_impl="ragged")
-    assert eng.runner.attention_impl == "ragged"
-    assert eng.scheduler.unified is True
-
-
-def test_ragged_no_recompiles_after_warmup(setup):
-    eng = make_engine(
-        setup, attention_impl="ragged",
-        scheduler=SchedulerConfig(max_num_seqs=4,
-                                  max_num_batched_tokens=16,
-                                  prefill_buckets=(16, 32)),
-    )
-    assert eng.perf is not None
-    eng.warmup()
-    assert eng.perf.stats_fields()["unexpected_recompiles"] == 0
-    # live mixed traffic after warmup: staggered greedy + sampled +
-    # chunked prefill must all hit pre-compiled signatures
-    reqs = [
-        ("g", list(range(1, 40)), GREEDY),
-        ("s", [4, 8, 12],
-         SamplingParams(temperature=0.7, max_tokens=8, ignore_eos=True)),
-        ("g2", [3, 5], GREEDY),
-    ]
-    _drain(eng, reqs, stagger_at=(2, 3))
-    fields = eng.perf.stats_fields()
-    assert fields["unexpected_recompiles"] == 0, fields["compile_counts"]
-    # the unified program was actually exercised (and tracked)
-    assert any(kind == "ragged" for kind, _ in fields["compile_counts"])
-    assert eng.ragged_dispatches > 0
-    stats = eng.stats()
-    assert 0.0 < stats["ragged_stream_utilization"] <= 1.0
-
-
-# ---- the serving path, feature by feature ----------------------------------
-# Every cell of the benchmark serves through the ragged step + decode_multi;
-# on the CPU ``attention_impl="auto"`` resolves to bucketed, so the
-# feature tests elsewhere run prefill programs no cell runs. Each case
-# below sends the same requests through both families on the CPU and asks
-# for equal tokens (log-probabilities within 1e-3). A configuration's two
-# engines are built once and shared by its cases; both see the same
-# history, so what an earlier case left in the prefix cache is the same on
-# both sides.
-
-def _sp(max_tokens=8, temperature=0.0, ignore_eos=True, **kw):
-    return SamplingParams(max_tokens=max_tokens, temperature=temperature,
-                          ignore_eos=ignore_eos, **kw)
-
-
-SHORT = [1, 5, 9, 13, 2, 6]
-LONG = list(range(1, 70))  # more than the 32-token step budget
-FAMILIES = ("tiny-gemma", "tiny-gemma2", "tiny-qwen3", "tiny-phi3",
-            "tiny-mistral", "tiny-mixtral")
-
-
-@pytest.fixture(scope="module")
-def pair(setup):
-    """``pair(name)`` -> the (bucketed, ragged) engines of a configuration."""
-    from production_stack_tpu.engine.weights import init_or_load
-    from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
-
-    built = {}
-
-    def build(name):
-        base, over = setup, {}
-        if name == "small-pool":  # the case's 3 sequences need 16 blocks
-            over = {"cache": CacheConfig(block_size=4, num_blocks=12)}
-        elif name != "llama":
-            # its own weights: one device, so that every head count divides
-            model = (ModelConfig.from_pretrained("tiny-llama", quant="int8")
-                     if name == "int8" else ModelConfig.from_pretrained(name))
-            cfg = dataclasses.replace(setup[0], model=model,
-                                      mesh=MeshConfig(data=1, tensor=1))
-            mesh = build_mesh(cfg.mesh, devices=jax.devices()[:1])
-            base = (cfg, mesh, init_or_load(cfg.model, mesh, seed=0))
-        return tuple(make_engine(base, attention_impl=impl, **over)
-                     for impl in ("bucketed", "ragged"))
-
-    def get(name):
-        if name not in built:
-            built[name] = build(name)
-            assert [e.runner.attention_impl for e in built[name]] == [
-                "bucketed", "ragged"]
-        return built[name]
-
-    return get
-
-
-def _serve(reqs, **kw):
-    return lambda eng: _drain(eng, list(reqs), **kw)
-
-
-def _stop_at_fourth_token(eng):
-    free, _ = _drain(eng, [("free", SHORT, _sp(12))])
-    stop = free["free"][3]
-    toks, _ = _drain(eng, [("stop", SHORT, SamplingParams(
-        temperature=0.0, max_tokens=12, stop_token_ids=[stop]))])
-    assert toks["stop"] and toks["stop"][-1] == stop
-    assert len(toks["stop"]) <= 4
-    return free, toks
-
-
-def _lora_beside_plain(eng):
-    import shutil
-
-    from production_stack_tpu.engine.lora import LoraManager
-    from tests.test_lora import make_adapter_dir
-
-    lora = LoraManager(eng)
-    path = make_adapter_dir(eng.config.model, seed=1)
-    try:
-        lora.load("parity-adapter", path)
-        slot = lora.slot_of("parity-adapter")
-        toks, _ = _drain(eng, [("plain", SHORT, _sp(8)),
-                               ("lora", SHORT, _sp(8), slot)])
-    finally:
-        lora.unload("parity-adapter")
-        shutil.rmtree(path)
-    assert toks["plain"] != toks["lora"]  # the adapter was applied
-    return toks
-
-
-def _prefix_hit(eng):
-    prompt = [int(t) for t in
-              np.random.default_rng(3).integers(1, 500, 40)]
-    first, _ = _drain(eng, [("first", prompt, _sp(8))])
-    hits = eng.stats()["gpu_prefix_cache_hits_total"]
-    second, _ = _drain(eng, [("second", prompt, _sp(8))])
-    assert eng.stats()["gpu_prefix_cache_hits_total"] > hits
-    assert first["first"] == second["second"]
-    return second
-
-
-def _preempt_and_recompute(eng):
-    sched = eng.scheduler
-    with mock.patch.object(sched, "_preempt", wraps=sched._preempt) as spy:
-        toks, _ = _drain(eng, [("a", SHORT, _sp(12)),
-                               ("b", [3, 3, 3, 100, 200], _sp(12)),
-                               ("c", list(range(42, 51)), _sp(12))])
-    assert spy.called, "the pool was meant to be too small for the batch"
-    assert all(len(t) == 12 for t in toks.values())
-    return toks
-
-
-def _abort_between_steps(eng):
-    free = eng.scheduler.num_free_blocks
-    toks, _ = _drain(eng, [("keep", SHORT, _sp(10)),
-                           ("gone", LONG, _sp(10)),
-                           ("keep2", [2, 4], _sp(10))],
-                     abort_at=(2, "gone"))
-    assert len(toks.pop("gone")) < 10
-    # what the aborted sequence held is back in the pool (cached prefix
-    # blocks count as free)
-    assert eng.scheduler.num_free_blocks == free
-    return toks
-
-
-def _guided_choice(eng):
-    return eng.choice_logprobs([5, 6, 7, 8], [[10, 11], [12], [13, 14, 15]])
-
-
-JSON_SCHEMA = {"type": "object",
-               "properties": {"sentiment": {"enum": ["pos", "neg"]},
-                              "score": {"type": "integer"}}}
-
-# name -> (configuration, what to run on each of its two engines)
-PARITY_CASES = {
-    "logprobs_top5_chunked_prompt": ("llama", _serve(
-        [("lp", LONG, _sp(8, logprobs=5)), ("side", SHORT, _sp(8))],
-        top=True)),
-    "seeded_sampling": ("llama", _serve(
-        [("s", SHORT, _sp(10, temperature=0.8, top_p=0.9, top_k=20,
-                          seed=1234)),
-         ("s2", LONG, _sp(10, temperature=1.0, top_k=5, seed=7))])),
-    "guided_regex": ("llama", _serve(
-        [("g", [5, 6, 7], SamplingParams(
-            temperature=0.0, max_tokens=16,
-            guided_regex=r"(yes|no)( indeed)?")),
-         ("free", SHORT, _sp(8))])),
-    "guided_json": ("llama", _serve(
-        [("j", [9, 8, 7, 6], SamplingParams(
-            temperature=0.9, seed=3, max_tokens=48,
-            guided_json=JSON_SCHEMA))])),
-    "guided_choice": ("llama", _guided_choice),
-    "logit_bias": ("llama", _serve(
-        [("bias", SHORT, _sp(8, logit_bias={7: 0.3, 93: -5.0})),
-         ("plain", SHORT, _sp(8))])),
-    "allowed_token_ids": ("llama", _serve(
-        [("allow", SHORT, _sp(8, temperature=1.0, seed=5,
-                              allowed_token_ids=[3, 5, 9, 200]))])),
-    "stop_token_ids": ("llama", _stop_at_fourth_token),
-    "max_tokens_1": ("llama", _serve(
-        [("one", SHORT, _sp(1)), ("one_long", LONG, _sp(1, logprobs=2))])),
-    "penalties": ("llama", _serve(
-        [("pen", [5, 6, 7, 8], _sp(10, presence_penalty=0.8,
-                                   frequency_penalty=0.3))])),
-    "lora_beside_plain": ("llama", _lora_beside_plain),
-    "int8_weights": ("int8", _serve(
-        [("q", SHORT, _sp(8)), ("q_long", LONG, _sp(8, logprobs=1))])),
-    "prefix_cache_hit": ("llama", _prefix_hit),
-    "preempt_and_recompute": ("small-pool", _preempt_and_recompute),
-    "abort_between_steps": ("llama", _abort_between_steps),
-    **{name: (name, _serve([("g", SHORT, _sp(8)), ("g_long", LONG, _sp(8))]))
-       for name in FAMILIES},
-}
-
-
-def _assert_same(got, want):
-    """Tokens (ints) equal, log-probabilities (floats) within 1e-3, over
-    whatever nesting of dicts, lists and tuples a case returns."""
-    if isinstance(want, dict):
-        assert got.keys() == want.keys()
-        for k in want:
-            _assert_same(got[k], want[k])
-    elif isinstance(want, (list, tuple)):
-        assert len(got) == len(want)
-        for g, w in zip(got, want):
-            _assert_same(g, w)
-    elif isinstance(want, float):
-        assert got == pytest.approx(want, abs=1e-3)
-    else:
-        assert got == want
-
-
-@pytest.mark.parametrize("case", PARITY_CASES)
-def test_serving_path_matches_bucketed(pair, case):
-    config, run = PARITY_CASES[case]
-    bucketed, ragged = pair(config)
-    before = ragged.ragged_dispatches
-    want = run(bucketed)
-    got = run(ragged)
-    assert not bucketed.has_unfinished() and not ragged.has_unfinished()
-    assert bucketed.ragged_dispatches == 0
-    # guided choice scores in one dense program under either family
-    assert ragged.ragged_dispatches > before or case == "guided_choice"
-    _assert_same(got, want)

@@ -154,8 +154,7 @@ def make_engine(cfg, mesh, params, budget=BUDGET, slots=SLOTS) -> LLMEngine:
                      cache=CacheConfig(block_size=16, num_blocks=160),
                      scheduler=SchedulerConfig(
                          max_num_seqs=slots, max_num_batched_tokens=budget),
-                     mesh=MeshConfig(data=1, tensor=1),
-                     attention_impl="ragged"),
+                     mesh=MeshConfig(data=1, tensor=1)),
         mesh=mesh, params=params)
 
 
@@ -376,7 +375,7 @@ def test_the_counter_is_exported_from_start_up_and_follows_the_steps():
         model=dense_cfg(), cache=CacheConfig(block_size=16, num_blocks=160),
         scheduler=SchedulerConfig(max_num_seqs=SLOTS,
                                   max_num_batched_tokens=BUDGET),
-        mesh=MeshConfig(data=1, tensor=1), attention_impl="ragged"))
+        mesh=MeshConfig(data=1, tensor=1)))
 
     async def read(client):
         text = await (await client.get("/metrics")).text()
@@ -480,3 +479,37 @@ def test_a_wide_step_after_thirty_two_narrow_ones_is_not_a_slow_step():
         assert (slow["kind"], slow["after"], slow["after_width"],
                 slow["cause"]) == ("decode", "ragged", 512, "wait")
         assert slow["reference"] == pytest.approx(0.034)
+
+
+def test_warmup_of_a_default_configuration_builds_what_the_chip_builds():
+    """The default widths (512 and the 2048-token budget, 64 slots) on the
+    CPU: warm-up compiles the ragged and the decode program and nothing
+    else that runs a prompt, at both widths, and a mixed batch after it
+    compiles nothing. (The model has fewer positions than the budget: the
+    budget-wide runs are made up of several prompts.)"""
+    cfg = ModelConfig.from_pretrained("tiny-llama")
+    mesh = one_device()
+    eng = LLMEngine(
+        EngineConfig(model=cfg, cache=CacheConfig(num_blocks=512),
+                     mesh=MeshConfig(data=1, tensor=1)),
+        mesh=mesh, params=init_or_load(cfg, mesh, seed=3))
+    assert eng.config.scheduler.ragged_stream_widths == (512, 2048)
+    eng.warmup()
+    fields = eng.perf.stats_fields()
+    assert {kind for kind, _ in fields["compile_counts"]} == {
+        "ragged", "decode_multi"}
+    ragged = [n for k, n in fields["compile_counts"].items()
+              if k[0] == "ragged"]
+    assert sorted(ragged) == [6, 6], fields["compile_counts"]
+    assert fields["unexpected_recompiles"] == 0
+    # six prompts that arrive together fill a budget-wide step, the
+    # stragglers join the others' decode rows in narrow ones
+    sampled = SamplingParams(temperature=0.7, max_tokens=4, ignore_eos=True)
+    requests = [(f"w{i}", prompt(i, 200), greedy(4) if i % 2 else sampled)
+                for i in range(6)]
+    requests += [("late", prompt(7, 30), greedy(4)),
+                 ("later", prompt(8, 9), sampled)]
+    _, _, steps = serve(eng, requests, arrive_at=(0, 0, 0, 0, 0, 1, 2))
+    assert {w for w, _ in steps} == {512, 2048}, steps
+    fields = eng.perf.stats_fields()
+    assert fields["unexpected_recompiles"] == 0, fields["compile_counts"]

@@ -55,7 +55,8 @@ def make_engine(mesh, params, cfg_model, remote_url):
         model=cfg_model,
         cache=CacheConfig(block_size=4, num_blocks=128,
                           remote_kv_url=remote_url),
-        scheduler=SchedulerConfig(max_num_seqs=2, prefill_buckets=(32,)),
+        scheduler=SchedulerConfig(max_num_seqs=2,
+                                  max_num_batched_tokens=64),
         mesh=MeshConfig(data=1, tensor=1),
     )
     return LLMEngine(cfg, mesh=mesh, params=params, num_blocks=128)

@@ -327,7 +327,8 @@ def test_deadline_propagation_and_kv_reclamation():
     cfg = EngineConfig(
         model=ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=128),
-        scheduler=SchedulerConfig(max_num_seqs=2, prefill_buckets=(32,)),
+        scheduler=SchedulerConfig(max_num_seqs=2,
+                                  max_num_batched_tokens=64),
     )
     server = EngineServer(cfg)
 
@@ -408,7 +409,8 @@ def test_client_disconnect_frees_kv_blocks():
     cfg = EngineConfig(
         model=ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=128),
-        scheduler=SchedulerConfig(max_num_seqs=2, prefill_buckets=(32,)),
+        scheduler=SchedulerConfig(max_num_seqs=2,
+                                  max_num_batched_tokens=64),
     )
     server = EngineServer(cfg)
 
@@ -465,8 +467,7 @@ def test_queue_full_returns_429_with_retry_after():
     cfg = EngineConfig(
         model=ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=64),
-        scheduler=SchedulerConfig(max_num_seqs=1, prefill_buckets=(32,),
-                                  max_queue_len=2),
+        scheduler=SchedulerConfig(max_num_seqs=1, max_queue_len=2),
     )
     engine = LLMEngine(cfg)
     sp = SamplingParams(max_tokens=4, ignore_eos=True)

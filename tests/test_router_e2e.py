@@ -27,8 +27,7 @@ def engine_server() -> EngineServer:
     cfg = EngineConfig(
         model=ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=512),
-        scheduler=SchedulerConfig(max_num_seqs=4, max_num_batched_tokens=64,
-                                  prefill_buckets=(32, 64)),
+        scheduler=SchedulerConfig(max_num_seqs=4, max_num_batched_tokens=64),
         mesh=MeshConfig(data=1, tensor=1),
     )
     return EngineServer(cfg)

@@ -66,7 +66,7 @@ def engine(cfg=None, params=None, num_blocks=64, slots=4) -> LLMEngine:
             cache=CacheConfig(block_size=16, num_blocks=num_blocks),
             scheduler=SchedulerConfig(max_num_seqs=slots,
                                       max_num_batched_tokens=BUDGET),
-            mesh=MeshConfig(data=1, tensor=1), attention_impl="ragged"),
+            mesh=MeshConfig(data=1, tensor=1)),
         mesh=one_device(), params=params)
 
 
@@ -577,8 +577,6 @@ def _engine_config(**kw):
     ({"role": "prefill"}, "role=prefill: a P->D transfer"),
     ({"cache.kv_host_cache_bytes": 1 << 20}, "a host or remote KV tier"),
     ({"cache.remote_kv_url": "http://kv"}, "a host or remote KV tier"),
-    ({"attention_impl": "bucketed"},
-     "attention_impl=bucketed is not supported for a recurrent-state"),
 ])
 def test_the_engine_refuses_what_would_move_or_skip_the_state(over, message):
     with pytest.raises(ValueError, match=message):
@@ -590,9 +588,6 @@ def test_more_than_one_device_is_refused_by_name():
     two = jax.devices()[:2]
     cfg.mesh = MeshConfig(data=1, tensor=2)
     with pytest.raises(ValueError, match="a mesh of 2 devices"):
-        LLMEngine(cfg, mesh=build_mesh(cfg.mesh, devices=two))
-    cfg.mesh = MeshConfig(data=1, stage=2, tensor=1)
-    with pytest.raises(ValueError, match="not supported with pipeline stages"):
         LLMEngine(cfg, mesh=build_mesh(cfg.mesh, devices=two))
 
 

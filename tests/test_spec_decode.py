@@ -69,8 +69,7 @@ def setup():
         cache=CacheConfig(block_size=4, num_blocks=256),
         scheduler=SchedulerConfig(
             max_num_seqs=4, max_num_batched_tokens=64,
-            prefill_buckets=(16, 32, 64),
-        ),
+            ),
         mesh=MeshConfig(data=1, tensor=1),
     )
     mesh = build_mesh(cfg.mesh)
@@ -83,7 +82,7 @@ def make_engine(setup, spec_k=0, **sched_overrides):
     sched = dataclasses.replace(cfg.scheduler, spec_ngram_k=spec_k,
                                 **sched_overrides)
     # speculation is ragged-only (verify spans ride the unified dispatch)
-    cfg = dataclasses.replace(cfg, scheduler=sched, attention_impl="ragged")
+    cfg = dataclasses.replace(cfg, scheduler=sched)
     return LLMEngine(cfg, mesh=mesh, params=params,
                      num_blocks=cfg.cache.num_blocks)
 
@@ -153,8 +152,7 @@ def test_spec_near_model_len_cap(setup):
     model = dataclasses.replace(cfg.model, max_model_len=32)
     sched = dataclasses.replace(cfg.scheduler, spec_ngram_k=4)
     eng = LLMEngine(
-        dataclasses.replace(cfg, model=model, scheduler=sched,
-                            attention_impl="ragged"),
+        dataclasses.replace(cfg, model=model, scheduler=sched),
         mesh=mesh, params=params, num_blocks=cfg.cache.num_blocks,
     )
     sp = SamplingParams(temperature=0.0, max_tokens=64, ignore_eos=True)

@@ -48,11 +48,9 @@ def setup():
         cache=CacheConfig(block_size=4, num_blocks=256),
         scheduler=SchedulerConfig(
             max_num_seqs=4, max_num_batched_tokens=32,
-            prefill_buckets=(16, 32, 64),
-        ),
+            ),
         mesh=MeshConfig(data=1, tensor=1),
-        attention_impl="ragged",
-    )
+        )
     mesh = build_mesh(cfg.mesh)
     params = init_or_load(cfg.model, mesh, seed=0)
     return cfg, mesh, params
@@ -256,7 +254,7 @@ def _sched(num_blocks, spec_k=4, budget=16, max_seqs=2):
     sched = Scheduler(
         SchedulerConfig(max_num_seqs=max_seqs,
                         max_num_batched_tokens=budget,
-                        prefill_buckets=(4, 8), spec_ngram_k=spec_k),
+                        spec_ngram_k=spec_k),
         CacheConfig(block_size=4, num_blocks=num_blocks),
         num_blocks=num_blocks, max_model_len=64,
     )

@@ -52,14 +52,13 @@ SHARDABLE = dataclasses.replace(
 
 
 def make_engine(tp: int = 1, model=None, **sched) -> LLMEngine:
-    kw = dict(max_num_seqs=4, max_num_batched_tokens=64,
-              prefill_buckets=(32, 64))
+    kw = dict(max_num_seqs=4, max_num_batched_tokens=64)
     kw.update(sched)
     cfg = EngineConfig(
         model=model or ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=512),
         scheduler=SchedulerConfig(**kw),
-        mesh=MeshConfig(data=1, tensor=tp), attention_impl="ragged")
+        mesh=MeshConfig(data=1, tensor=tp))
     return LLMEngine(cfg, mesh=build_mesh(cfg.mesh,
                                           devices=jax.devices()[:tp]))
 

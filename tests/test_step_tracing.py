@@ -35,9 +35,8 @@ from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
 
 PROMPTS = ["hello world", "the quick brown fox jumps over the lazy dog"]
 # greedy tokens of the parent commit (3c635ce) for PROMPTS on tiny-llama,
-# seed 0, 12 tokens, ignore_eos: the same under the ragged and the
-# bucketed attention path (computed from an unpacked `git archive` of the
-# parent, on the CPU)
+# seed 0, 12 tokens, ignore_eos (computed from an unpacked `git archive`
+# of the parent, on the CPU)
 PARENT_TOKENS = [
     [263, 351, 351, 351, 358, 351, 351, 351, 351, 351, 263, 331],
     [218, 400, 218, 400, 218, 400, 430, 36, 319, 218, 400, 218],
@@ -59,8 +58,7 @@ def make_config(**kw) -> EngineConfig:
         cache=CacheConfig(block_size=4, num_blocks=512),
         scheduler=SchedulerConfig(
             max_num_seqs=4, max_num_batched_tokens=64,
-            prefill_buckets=(32, 64),
-        ),
+            ),
         mesh=MeshConfig(data=1, tensor=1), **kw)
 
 
@@ -141,7 +139,7 @@ def test_phases_sum_to_the_worker_wall_time_and_each_step_has_one_kind(chain):
     # every step was charged to exactly one kind, and only steps were
     assert sum(clock.steps.values()) == clock.step_num == step_count
     assert clock.steps["decode"] > 0
-    assert clock.steps["ragged"] + clock.steps["prefill"] > 0
+    assert clock.steps["ragged"] > 0
     assert not clock.in_step
     for kind in STEP_KINDS:
         if not clock.steps[kind]:
@@ -169,26 +167,23 @@ def test_phases_sum_to_the_worker_wall_time_and_each_step_has_one_kind(chain):
 
 
 def test_a_step_driven_directly_opens_and_closes_its_own_step():
-    eng = LLMEngine(make_config(attention_impl="bucketed"))
+    eng = LLMEngine(make_config())
     outs = eng.generate(PROMPTS, GREEDY)
     assert list(outs.values()) == PARENT_TOKENS
     clock = eng.clock
     assert not clock.in_step and clock.step_num == sum(clock.steps.values())
-    assert clock.steps["prefill"] > 0 and clock.steps["decode"] > 0
-    assert clock.steps["ragged"] == 0
+    assert clock.steps["ragged"] > 0 and clock.steps["decode"] > 0
+    assert eng.ragged_dispatches == clock.steps["ragged"]
     assert eng.decode_dispatches == clock.steps["decode"]
     snap = clock.snapshot()
     assert set(snap["seconds"]) == set(STEP_KINDS)
     assert set(snap["seconds"]["decode"]) == {*HOST_PHASES, "wait"}
 
 
-@pytest.mark.parametrize("impl,record,kind", [
-    ("ragged", "record_ragged", "ragged"),
-    ("bucketed", "record_prefill", "prefill")])
-def test_perf_accountant_receives_the_clocks_seconds(monkeypatch, impl,
-                                                     record, kind):
-    eng = LLMEngine(make_config(attention_impl=impl))
-    calls = {"record_decode": [], "record_ragged": [], "record_prefill": []}
+def test_perf_accountant_receives_the_clocks_seconds(monkeypatch):
+    record, kind = "record_ragged", "ragged"
+    eng = LLMEngine(make_config())
+    calls = {"record_decode": [], "record_ragged": []}
     for name, got in calls.items():
         real = getattr(eng.perf, name)
 
@@ -334,7 +329,7 @@ def test_deliver_entered_twice_in_a_step_is_one_phase_and_loses_no_time():
     assert seconds == pytest.approx(sum(w for w, _ in by.values()), abs=1e-12)
     assert seconds == pytest.approx(wall, abs=1e-12)
     assert seconds == pytest.approx(0.034)
-    assert clock.steps == {"decode": 1, "ragged": 0, "prefill": 0, "other": 0}
+    assert clock.steps == {"decode": 1, "ragged": 0, "other": 0}
 
 
 def test_flight_record_names_its_steps_and_the_first_chunk(server):
@@ -384,12 +379,8 @@ def test_debug_profile_python_tracer_level(server, monkeypatch, body, level):
 # -- names --------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
-def ring_runner():
+def runner():
     cfg = make_config()
-    cfg = EngineConfig(model=cfg.model, cache=cfg.cache,
-                       scheduler=cfg.scheduler,
-                       mesh=MeshConfig(data=1, tensor=1, seq=2),
-                       attention_impl="ragged")
     return model_runner.ModelRunner(cfg, build_mesh(cfg.mesh),
                                     num_blocks=64)
 
@@ -397,11 +388,9 @@ def ring_runner():
 @pytest.mark.parametrize("attr,name", [
     ("_ragged", "ragged_step"),
     ("_decode_multi", "decode_multi_step"),
-    ("_prefill", "prefill_step"),
-    ("_prefill_ring", "prefill_ring_step"),
 ])
-def test_jitted_programs_carry_their_names(ring_runner, attr, name):
-    assert getattr(ring_runner, attr).__name__ == name
+def test_jitted_programs_carry_their_names(runner, attr, name):
+    assert getattr(runner, attr).__name__ == name
 
 
 def test_named_partial_names_the_compiled_module():
@@ -435,7 +424,7 @@ def test_ragged_attn_walk_counters_follow_the_dispatched_spans():
                 perf["ragged_attn_narrow_walks"]] == values
         return values
 
-    server = EngineServer(make_config(attention_impl="ragged"))
+    server = EngineServer(make_config())
 
     async def fn(client):
         eng = server.engine
@@ -478,7 +467,7 @@ def test_ragged_attn_window_counters_follow_the_dispatched_spans():
                 perf["ragged_attn_interior_windows"]] == values
         return values
 
-    server = EngineServer(make_config(attention_impl="ragged"))
+    server = EngineServer(make_config())
 
     async def fn(client):
         eng = server.engine
@@ -523,7 +512,7 @@ def test_decode_attn_call_counters_follow_the_decode_dispatches():
                 perf["decode_attn_slab_calls"]] == values
         return values
 
-    server = EngineServer(make_config(attention_impl="ragged"))
+    server = EngineServer(make_config())
 
     async def fn(client):
         eng = server.engine
@@ -621,7 +610,7 @@ def _serve_by_hand(server, eng, root, prompts, streamed=True):
 ])
 def test_ttft_parts_telescope(server, fake_time, case, choices, prompt_len,
                               slots, dispatches):
-    cfg = make_config(attention_impl="ragged")
+    cfg = make_config()
     eng = LLMEngine(dataclasses.replace(cfg, scheduler=dataclasses.replace(
         cfg.scheduler, max_num_seqs=slots)))
     if case == "admitted_a_step_late":
@@ -669,7 +658,7 @@ def test_ttft_parts_telescope(server, fake_time, case, choices, prompt_len,
     (3, "decode", "decode")])
 def test_arrival_carries_what_the_step_before_its_intake_waited_for(
         fake_time, steps_before, kind, waited):
-    eng = LLMEngine(make_config(attention_impl="ragged"))
+    eng = LLMEngine(make_config())
     eng.add_request("first", prompt_token_ids=[5, 6, 7], sampling=GREEDY)
     for _ in range(steps_before):
         eng.step()

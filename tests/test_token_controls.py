@@ -73,17 +73,17 @@ def test_apply_token_controls_math():
     assert out[1, 0] <= sm.NEG_INF
 
 
-def _engine(multi_step=1, stage=1):
+def _engine(multi_step=1):
     cfg = EngineConfig(
         model=ModelConfig.from_pretrained("tiny-llama"),
         cache=CacheConfig(block_size=4, num_blocks=128),
         scheduler=SchedulerConfig(
             max_num_seqs=4, max_num_batched_tokens=32,
-            prefill_buckets=(16, 32), multi_step=multi_step,
+            multi_step=multi_step,
         ),
-        mesh=MeshConfig(data=1, stage=stage, tensor=1),
+        mesh=MeshConfig(data=1, tensor=1),
     )
-    mesh = build_mesh(cfg.mesh, devices=jax.devices()[: max(stage, 1)])
+    mesh = build_mesh(cfg.mesh, devices=jax.devices()[:1])
     return LLMEngine(cfg, mesh=mesh, num_blocks=128)
 
 
@@ -168,13 +168,3 @@ def test_controls_compose_with_penalties():
     )
     out = _run(_engine(multi_step=2), sp)
     assert set(out) <= {5, 6, 7, 8}
-
-
-def test_controls_pp2_engine():
-    """Pipeline-parallel last-stage sampling honors the controls."""
-    sp = SamplingParams(
-        temperature=0.0, max_tokens=4, ignore_eos=True,
-        logit_bias={77: 1e9},
-    )
-    out = _run(_engine(stage=2), sp)
-    assert out == [77] * 4

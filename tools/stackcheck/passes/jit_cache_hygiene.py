@@ -56,7 +56,7 @@ _CACHED_DECOS = {"functools.cached_property", "cached_property",
                  "functools.lru_cache", "lru_cache",
                  "functools.cache", "cache", "property.setter"}
 # self.<container>.append(jax.jit(...)) and friends: caching via
-# container mutation (pp_runner's per-stage step lists)
+# container mutation (a list of per-part step programs)
 _CACHE_MUTATORS = {"append", "add", "insert", "setdefault", "update",
                    "extend"}
 
