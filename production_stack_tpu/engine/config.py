@@ -19,11 +19,27 @@ import jax.numpy as jnp
 from production_stack_tpu.parallel.mesh import MeshConfig
 
 
+def _expert_share(what: str, cfg: dict, experts_key: str) -> tuple:
+    """(routed experts, those this engine holds, the first one's index) of
+    a file that may state the chip's share of a wider expert layer
+    (``n_routed_experts_held`` from ``routed_expert_offset``; absent: all
+    of them), refused where it is no share."""
+    experts = int(cfg[experts_key])
+    held = int(cfg.get("n_routed_experts_held", experts))
+    offset = int(cfg.get("routed_expert_offset", 0))
+    if not 0 < held <= experts or not 0 <= offset <= experts - held:
+        raise ValueError(
+            f"{what}: n_routed_experts_held={held} from "
+            f"routed_expert_offset={offset} is not a share of "
+            f"{experts_key}={experts}")
+    return experts, held, offset
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str = "tiny-llama"
     # "llama" | "mixtral" | "olmoe" | "gemma" | "gemma2" | "phi3" | "ouro"
-    # | "solar_open2" | "pangu_ultra_moe" | "phi4flash"
+    # | "solar_open2" | "pangu_ultra_moe" | "phi4flash" | "kimi_linear"
     # — Mistral and Qwen run as "llama" (their deltas are knobs:
     # sliding_window, qkv_bias, qk_norm); "phi3" differs only in its fused
     # HF weight layout, "mixtral" and "olmoe" in their HF tensor names
@@ -33,7 +49,9 @@ class ModelConfig:
     # "pangu_ultra_moe" in its latent attention (kv_lora_rank > 0) and its
     # leading dense layers, "phi4flash" in its layer pattern (mamba_period
     # > 0: state-space, window, full and cross-attention layers, gated
-    # memory units), differential attention and LayerNorm
+    # memory units), differential attention and LayerNorm, "kimi_linear" in
+    # KDA and latent-attention layers in one stack (mla_layers) behind a
+    # leading dense layer
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -84,6 +102,12 @@ class ModelConfig:
     # of a hybrid stack's attention layers, which apply no positional
     # encoding (the shared stack's own layers rotate and have no gate)
     attn_gate: bool = False  # sigmoid(W x) on the attention output
+    # a hybrid stack whose attention layers are latent attention (MLA) and
+    # are named one by one ("kimi_linear"): the 0-based layers that are
+    # MLA, every other layer KDA. attn_period is then the period the stack
+    # is walked in (the first MLA layer closes the first), and the last
+    # period may be short. () = the attn_period rule
+    mla_layers: tuple = ()
     # the decoder-hybrid-decoder stack (SambaY, "phi4flash"): layer l is a
     # Mamba-1 state-space layer where l % mamba_period == 0 and attention
     # elsewhere, with a window of sliding_window rows, up to layer
@@ -143,13 +167,16 @@ class ModelConfig:
     # qk_nope + qk_rope and num_kv_heads what the file publishes; neither
     # sizes the cache. 0 = keys and values per head
     kv_lora_rank: int = 0
-    q_lora_rank: int = 0
+    q_lora_rank: int = 0  # 0: one direct query projection, no norm
+    mla_rope: bool = True  # False: nothing is rotated (a NoPE stack's MLA)
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     # leading dense layers: the first dense_layers of num_layers have a
     # plain MLP of dense_intermediate_size in place of the sparse block and
     # run before the scan over the expert layers, cache layers 0.. in order
+    # (in a patterned stack they keep their place in the pattern, their
+    # period a run of its own: stack_segments)
     dense_layers: int = 0
     dense_intermediate_size: int = 0
     # weight-tied stacks ("ouro"): every token passes through the SAME
@@ -232,9 +259,12 @@ class ModelConfig:
     def layer_kinds(self) -> tuple:
         """What each layer's token mixer is, the one description of a
         stack's pattern: "attn" (every layer of the shared stack); "gqa" /
-        "kda" (attn_period); "mamba", "swa" (window attention), "full",
-        "gmu" and "cross" (mamba_period)."""
+        "kda" (attn_period); "mla" / "kda" (mla_layers); "mamba", "swa"
+        (window attention), "full", "gmu" and "cross" (mamba_period)."""
         n = self.num_layers
+        if self.mla_layers:
+            return tuple("mla" if l in self.mla_layers else "kda"
+                         for l in range(n))
         if self.mamba_period:
             full = n // 2 + 1  # the last layer before the cross-decoder
             return tuple(
@@ -252,13 +282,16 @@ class ModelConfig:
     @property
     def stack_segments(self) -> tuple:
         """A patterned stack as runs of like periods, ((kinds of a period,
-        periods), ...): one scan each (models/llama.py _forward_hybrid)."""
+        periods), ...): one scan each (models/llama.py _forward_hybrid). A
+        period that holds a leading dense layer is like no other and a run
+        of its own; the last period may be short."""
         kinds = self.layer_kinds
         period = self.mamba_period or self.attn_period
         runs = []
         for i in range(0, len(kinds), period):
             p = kinds[i:i + period]
-            if runs and runs[-1][0] == p:
+            if (runs and runs[-1][0] == p
+                    and i - period >= self.dense_layers):
                 runs[-1][1] += 1
             else:
                 runs.append([p, 1])
@@ -308,7 +341,7 @@ class ModelConfig:
     def num_attn_layers(self) -> int:
         """Attention layers that own keys and values (a cross-attention
         layer reads another's)."""
-        return self.count_layers("attn", "gqa", "swa", "full")
+        return self.count_layers("attn", "gqa", "mla", "swa", "full")
 
     @property
     def num_kda_layers(self) -> int:
@@ -412,6 +445,8 @@ class ModelConfig:
             return ModelConfig._pangu_ultra_moe_from_hf(cfg, name)
         elif cfg.get("model_type") == "phi4flash":
             return ModelConfig._phi4flash_from_hf(cfg, name)
+        elif cfg.get("model_type") == "kimi_linear":
+            return ModelConfig._kimi_linear_from_hf(cfg, name)
         elif any("Phi3" in a for a in archs):
             # only the standard Phi-3 maps onto the fused-Llama layout;
             # Phi-3-small (query_key_value naming, gegelu, blocksparse)
@@ -565,14 +600,7 @@ class ModelConfig:
                 f"{what} with linear_attn_config.num_kv_heads="
                 f"{lin['num_kv_heads']} is not supported (a key and value "
                 "head per query head)")
-        experts = int(cfg["n_routed_experts"])
-        held = int(cfg.get("n_routed_experts_held", experts))
-        offset = int(cfg.get("routed_expert_offset", 0))
-        if not 0 < held <= experts or not 0 <= offset <= experts - held:
-            raise ValueError(
-                f"{what}: n_routed_experts_held={held} from "
-                f"routed_expert_offset={offset} is not a share of "
-                f"n_routed_experts={experts}")
+        experts, held, offset = _expert_share(what, cfg, "n_routed_experts")
         kda_dim = int(lin.get("head_dim", cfg["head_dim"]))
         return ModelConfig(
             name=name or cfg.get("_name_or_path", "hf-model"),
@@ -644,14 +672,7 @@ class ModelConfig:
             raise ValueError(
                 f"{what}: first_k_dense_replace={dense} leaves no expert "
                 f"layer of num_hidden_layers={layers}")
-        experts = int(cfg["n_routed_experts"])
-        held = int(cfg.get("n_routed_experts_held", experts))
-        offset = int(cfg.get("routed_expert_offset", 0))
-        if not 0 < held <= experts or not 0 <= offset <= experts - held:
-            raise ValueError(
-                f"{what}: n_routed_experts_held={held} from "
-                f"routed_expert_offset={offset} is not a share of "
-                f"n_routed_experts={experts}")
+        experts, held, offset = _expert_share(what, cfg, "n_routed_experts")
         heads = cfg["num_attention_heads"]
         nope, rope = int(cfg["qk_nope_head_dim"]), int(cfg["qk_rope_head_dim"])
         return ModelConfig(
@@ -683,6 +704,116 @@ class ModelConfig:
             qk_nope_head_dim=nope,
             qk_rope_head_dim=rope,
             v_head_dim=int(cfg["v_head_dim"]),
+            dense_layers=dense,
+            dense_intermediate_size=cfg["intermediate_size"] if dense else 0,
+        )
+
+    @staticmethod
+    def _kimi_linear_from_hf(cfg: dict, name: str = "") -> "ModelConfig":
+        """``model_type: kimi_linear``: KDA layers and latent-attention
+        (MLA) layers in one stack, each named in a 1-based list of
+        ``linear_attn_config`` (``kda_layers``, ``full_attn_layers``); the
+        MLA layers project their queries directly (``q_lora_rank: null``)
+        and rotate nothing (``mla_use_nope``); ``first_k_dense_replace``
+        leading dense layers, then sparse blocks with sigmoid routing and
+        a shared expert. ``n_routed_experts_held`` /
+        ``routed_expert_offset`` state the chip's share of the routed
+        experts, as for solar_open2. What is not computed is refused by
+        name."""
+        what = "kimi_linear"
+        if not cfg.get("mla_use_nope", False):
+            raise ValueError(
+                f"{what} with mla_use_nope: false is not supported: this "
+                "stack's latent attention rotates nothing (order comes "
+                "from the KDA layers)")
+        if cfg.get("q_lora_rank") is not None:
+            raise ValueError(
+                f"{what} with q_lora_rank={cfg['q_lora_rank']!r} is not "
+                "supported: no test holds the low-rank query path inside a "
+                "patterned stack (only q_lora_rank: null)")
+        if cfg.get("rope_scaling"):
+            raise ValueError(
+                f"{what} with rope_scaling={cfg['rope_scaling']!r} is not "
+                "supported (nothing is rotated)")
+        if int(cfg.get("num_expert_group", 1) or 1) > 1:
+            raise ValueError(
+                f"{what} with num_expert_group={cfg['num_expert_group']} is "
+                "not supported (no group-limited routing)")
+        if int(cfg.get("num_nextn_predict_layers", 0) or 0) > 0:
+            raise ValueError(
+                f"{what} with num_nextn_predict_layers="
+                f"{cfg['num_nextn_predict_layers']} is not supported: the "
+                "multi-token-prediction module is not loaded and nothing "
+                "drafts with it (serve the file with it set to 0)")
+        dense = int(cfg.get("first_k_dense_replace", 0))
+        if dense > 1:
+            raise ValueError(
+                f"{what} with first_k_dense_replace={dense} is not "
+                "supported: no test holds more than one leading dense layer "
+                "inside a patterned stack")
+        if int(cfg.get("moe_layer_freq", 1)) != 1:
+            raise ValueError(
+                f"{what} with moe_layer_freq={cfg['moe_layer_freq']} is not "
+                "supported (every layer behind the dense ones is sparse)")
+        if cfg.get("moe_router_activation_func", "sigmoid") != "sigmoid":
+            raise ValueError(
+                f"{what} with moe_router_activation_func="
+                f"{cfg['moe_router_activation_func']!r} is not supported "
+                "(sigmoid scores with a selection bias)")
+        layers = int(cfg["num_hidden_layers"])
+        lin = cfg.get("linear_attn_config") or {}
+        kda = [int(l) for l in lin.get("kda_layers") or ()]
+        mla = [int(l) for l in lin.get("full_attn_layers") or ()]
+        if sorted(kda + mla) != list(range(1, layers + 1)) or not (kda
+                                                                  and mla):
+            raise ValueError(
+                f"{what}: linear_attn_config.kda_layers={kda} and "
+                f"full_attn_layers={mla} do not name every layer 1.."
+                f"{layers} once, with a layer of each kind")
+        heads = cfg["num_attention_heads"]
+        kda_heads = int(lin.get("num_heads", heads))
+        experts, held, offset = _expert_share(what, cfg, "num_experts")
+        kda_dim = int(lin.get("head_dim", cfg["head_dim"]))
+        nope, rope = int(cfg["qk_nope_head_dim"]), int(cfg["qk_rope_head_dim"])
+        return ModelConfig(
+            name=name or cfg.get("_name_or_path", "hf-model"),
+            architecture="kimi_linear",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["moe_intermediate_size"],
+            num_layers=layers,
+            num_heads=heads,
+            num_kv_heads=cfg.get("num_key_value_heads", heads),
+            # the query head's two parts; the file's head_dim (hidden /
+            # heads) sizes nothing
+            head_dim=nope + rope,
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_model_len=(cfg.get("max_position_embeddings")
+                           or cfg.get("model_max_length", 4096)),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            num_experts=experts,
+            num_experts_per_tok=cfg.get("num_experts_per_token", 8),
+            norm_topk_prob=bool(cfg.get("moe_renormalize", True)),
+            experts_held=held if held < experts else 0,
+            expert_offset=offset,
+            moe_scoring="sigmoid",
+            routed_scaling=float(cfg.get("routed_scaling_factor", 1.0)),
+            shared_expert_size=(int(cfg.get("num_shared_experts", 0))
+                                * cfg["moe_intermediate_size"]),
+            # the first MLA layer closes the first period
+            attn_period=min(mla),
+            mla_layers=tuple(l - 1 for l in sorted(mla)),
+            kda_heads=kda_heads,
+            kda_head_dim=kda_dim,
+            kda_conv=int(lin.get("short_conv_kernel_size", 4)),
+            # not a key of the published file: the low-rank width of the
+            # decay and output-gate pairs is the KDA head size
+            kda_rank=kda_dim,
+            kv_lora_rank=int(cfg["kv_lora_rank"]),
+            qk_nope_head_dim=nope,
+            qk_rope_head_dim=rope,
+            v_head_dim=int(cfg["v_head_dim"]),
+            mla_rope=False,
             dense_layers=dense,
             dense_intermediate_size=cfg["intermediate_size"] if dense else 0,
         )
@@ -990,6 +1121,22 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         num_kv_heads=2, head_dim=16, max_model_len=512,
         tie_word_embeddings=True, qkv_bias=True, sliding_window=8,
         mamba_period=2, mamba_dt_rank=8, diff_attn=True, layer_norm=True,
+        dtype="float32",
+    ),
+    "tiny-kimi-linear": ModelConfig(
+        # Kimi-Linear's stack at test size: (dense kda, kda, kda, mla) +
+        # (kda, kda, mla), the short last period; 4 KDA heads of 16, MLA
+        # over a 32 + 16 = 48-value cache row that nothing rotates, a
+        # direct query projection; one leading dense layer, then sigmoid
+        # routing over 8 experts (2 a token) beside a shared one
+        name="tiny-kimi-linear", architecture="kimi_linear", vocab_size=512,
+        hidden_size=128, intermediate_size=64, num_layers=7, num_heads=4,
+        num_kv_heads=4, head_dim=48, max_model_len=512, num_experts=8,
+        num_experts_per_tok=2, moe_scoring="sigmoid", routed_scaling=2.446,
+        shared_expert_size=64, attn_period=4, mla_layers=(3, 6),
+        kda_heads=4, kda_head_dim=16, kda_rank=16, kv_lora_rank=32,
+        qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+        mla_rope=False, dense_layers=1, dense_intermediate_size=256,
         dtype="float32",
     ),
     "tiny-whisper": ModelConfig(

@@ -1597,6 +1597,9 @@ class LLMEngine:
                                 / max(1, self.config.scheduler.max_num_seqs)),
             "kv_blocks_total": self.runner.num_blocks,
             "kv_blocks_free": self.scheduler.num_free_blocks,
+            "kv_pool_bytes": (self.runner.num_blocks
+                              * self.config.cache.block_size
+                              * self.config.model.kv_bytes_per_token),
             **(self.window_counters.snapshot(self.scheduler.window_allocator)
                if self.window_counters is not None else {}),
             # unified ragged path: dispatch counts + live tokens over the

@@ -15,7 +15,9 @@ TPU-first design:
   slots, H, d, d) float32 and the short convolution's tail ``conv``
   (KDA layers, slots, K-1, 3*H*d). A model without recurrent layers has
   the paged array alone, as it is; a hybrid stack a dict ``{"kv",
-  "state", "conv"}`` (``init_kv_cache``). State-space (Mamba) layers keep
+  "state", "conv"}`` (``init_kv_cache``), whose ``"kv"`` is a latent pool
+  of the latent-attention layers alone where those are its attention
+  layers. State-space (Mamba) layers keep
   ``state`` (layers, slots, N, d_i) float32 and ``conv`` (layers, slots,
   K-1, d_i) the same way. Where a model's window binds
   (``ModelConfig.window_binds``) the window layers' keys and values are a

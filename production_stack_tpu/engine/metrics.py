@@ -144,6 +144,13 @@ class EngineStatsCollector:
             s.get("kv_blocks_total", 0),
         )
         yield gauge(
+            "vllm:kv_pool_bytes",
+            "Bytes the paged pool holds on the device (blocks x block size "
+            "x vllm:kv_bytes_per_token), fixed at start-up; beside "
+            "vllm:recurrent_state_bytes where a model keeps both",
+            s.get("kv_pool_bytes", 0),
+        )
+        yield gauge(
             "vllm:kv_blocks_free",
             "Free KV blocks (allocatable right now)",
             s.get("kv_blocks_free", 0),

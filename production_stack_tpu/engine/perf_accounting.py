@@ -68,7 +68,9 @@ def _stack_param_count(model_cfg) -> int:
     if getattr(model_cfg, "is_latent", False):
         # latent attention: the two low-rank paths and the output projection
         c, r = model_cfg.kv_lora_rank, model_cfg.q_lora_rank
-        qkv = (h * r + r * heads * model_cfg.head_dim
+        # the query's low-rank pair, or (rank 0) its one direct projection
+        qkv = ((h * r + r * heads * model_cfg.head_dim if r
+                else h * heads * model_cfg.head_dim)
                + h * model_cfg.latent_width
                + c * heads * (model_cfg.qk_nope_head_dim
                               + model_cfg.v_head_dim)

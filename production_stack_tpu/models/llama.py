@@ -112,7 +112,7 @@ def _latent_layer_specs(cfg: ModelConfig, sparse: bool) -> dict:
 def param_specs(cfg: ModelConfig) -> dict:
     """Pytree of logical-axes tuples mirroring the param pytree."""
     L = lax_names
-    if cfg.is_latent:
+    if cfg.is_latent and not cfg.has_recurrent_state:
         # the leading dense layers are a stack of their own, "dense",
         # before the expert layers' "layers"
         specs = {
@@ -181,8 +181,19 @@ def param_specs(cfg: ModelConfig) -> dict:
         # compiler chose a per-head layout for it and copied the whole
         # stack at the start of every decode step (5 copies, 1.5 GB, at
         # the published widths)
-        mixers["gqa"] = {k: layer.pop(k) for k in ("wq", "wk", "wv", "wo")}
-        mixers["gqa"]["wq"] = (L.LAYERS, L.EMBED, L.HEADS)
+        attn = {k: layer.pop(k) for k in ("wq", "wk", "wv", "wo")}
+        if cfg.is_latent:
+            # "mla" over the latent-attention layers: _latent_layer_specs'
+            # mixer with one direct query projection (cfg.q_lora_rank 0)
+            latent = _latent_layer_specs(cfg, sparse=False)
+            mixers["mla"] = {
+                **{k: latent[k] for k in (
+                    "wkv_a", "kv_a_norm", "w_uk", "w_uv", "wo")},
+                "wq_nope": (L.LAYERS, L.EMBED, L.HEADS),
+                "wq_rope": (L.LAYERS, L.EMBED, L.HEADS),
+            }
+        else:
+            mixers["gqa"] = {**attn, "wq": (L.LAYERS, L.EMBED, L.HEADS)}
         mixers["kda"] = {
             "w_qkv": (L.LAYERS, L.EMBED, None),  # [q | k | v], each H*D
             "wo": (L.LAYERS, L.HEADS, L.HEAD_DIM, L.EMBED),
@@ -198,6 +209,17 @@ def param_specs(cfg: ModelConfig) -> dict:
         }
         if cfg.attn_gate:
             mixers["gqa"]["wg"] = (L.LAYERS, L.EMBED, L.HEADS)
+        if cfg.dense_layers:
+            # a leading dense layer's norms and MLP, a stack of their own
+            # before the expert layers' "layers"; its mixer lies with its
+            # kind's
+            mixers["dense"] = {
+                "attn_norm": (L.LAYERS, L.EMBED),
+                "mlp_norm": (L.LAYERS, L.EMBED),
+                "w_gate": (L.LAYERS, L.EMBED, L.MLP),
+                "w_up": (L.LAYERS, L.EMBED, L.MLP),
+                "w_down": (L.LAYERS, L.MLP, L.EMBED),
+            }
     if cfg.moe_scoring == "sigmoid":
         layer["router_bias"] = (L.LAYERS, L.EXPERTS)
     if cfg.shared_expert_size:
@@ -346,7 +368,7 @@ def _init_latent(cfg: ModelConfig, key: jax.Array) -> dict:
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     """Random-init parameters (tests / synthetic benchmarks; real weights come
     from safetensors via engine/weights.py)."""
-    if cfg.is_latent:
+    if cfg.is_latent and not cfg.has_recurrent_state:
         return _init_latent(cfg, key)
     E, H, KH, D, F, LN, V = (
         cfg.hidden_size,
@@ -371,8 +393,11 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     # stored norm weight giving an effective scale of 1 (Gemma stores
     # zero-centred weights; forward adds cfg.norm_offset)
     norm_one = 1.0 - cfg.norm_offset
+    # "layers" of a patterned stack with leading dense layers holds the
+    # expert layers alone (no other family here has dense layers)
     layers = {
-        "attn_norm": jnp.full((Ln := LN, E), norm_one, dt),
+        "attn_norm": jnp.full((Ln := cfg.num_expert_layers, E), norm_one,
+                              dt),
         "wq": normal(keys[0], (Ln, E, H, D), E),
         "wk": normal(keys[1], (Ln, E, KH, D), E),
         "wv": normal(keys[2], (Ln, E, KH, D), E),
@@ -418,12 +443,25 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             mixers["mamba"]["w_out"], out)
     elif cfg.has_recurrent_state:
         Pn = cfg.num_attn_layers
-        mixers["gqa"] = {k: layers.pop(k)[:Pn]
-                         for k in ("wq", "wk", "wv", "wo")}
-        mixers["gqa"]["wq"] = mixers["gqa"]["wq"].reshape(Pn, E, H * D)
+        attn = {k: layers.pop(k) for k in ("wq", "wk", "wv", "wo")}
+        if cfg.is_latent:
+            mixers["mla"] = _init_mla(cfg, keys[14], normal, out)
+        else:
+            mixers["gqa"] = {k: v[:Pn] for k, v in attn.items()}
+            mixers["gqa"]["wq"] = mixers["gqa"]["wq"].reshape(Pn, E, H * D)
         mixers["kda"] = _init_kda(cfg, keys[13], normal, out)
         if cfg.attn_gate:
             mixers["gqa"]["wg"] = normal(keys[14], (Pn, E, H * D), E)
+        if cfg.dense_layers:
+            Dn, Fd = cfg.dense_layers, cfg.dense_intermediate_size
+            ks = jax.random.split(keys[12], 3)
+            mixers["dense"] = {
+                "attn_norm": jnp.ones((Dn, E), dt),
+                "mlp_norm": jnp.ones((Dn, E), dt),
+                "w_gate": normal(ks[0], (Dn, E, Fd), E),
+                "w_up": normal(ks[1], (Dn, E, Fd), E),
+                "w_down": normal(ks[2], (Dn, Fd, E), Fd * out),
+            }
     if cfg.moe_scoring == "sigmoid":
         # the selection bias (balancing state of a trained router): zeros
         layers["router_bias"] = jnp.zeros((Ln, cfg.num_experts), jnp.float32)
@@ -445,7 +483,13 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
                 "router": normal(keys[4], (Ln, E, X), E),
                 "w_gate": normal(keys[5], (Ln, Xh, E, F), E),
                 "w_up": normal(keys[6], (Ln, Xh, E, F), E),
-                "w_down": normal(keys[7], (Ln, Xh, F, E), F * out),
+                # HYBRID_INIT: a chosen expert weighs routed_scaling /
+                # top_k of the shared one; the stand-in's routed experts
+                # write at 1 / routed_scaling besides, so that it is
+                # 1 / top_k whatever the family's factor
+                "w_down": normal(keys[7], (Ln, Xh, F, E),
+                                 F * out * (cfg.routed_scaling ** 2
+                                            if hybrid else 1)),
             }
         )
     else:
@@ -500,7 +544,16 @@ KDA_DT_MIN, KDA_DT_MAX = 1e-3, 1e-1
 # experts' and the shared expert's down projections) at 1 / sqrt(2 x
 # layers) of the usual size, the residual scaling GPT-2 initialises with.
 # At width 512 that reads 0.04-0.05 / 0.008. Every other family keeps the
-# shared values: its programs and its cells are what they were.
+# shared values: its programs and its cells are what they were. One more
+# term since PR 49, general and 1 for Solar-Open2: the routed experts' down
+# projections write at 1 / routed_scaling besides. A chosen expert weighs
+# routed_scaling / top_k of the shared one, and where bf16 rounding picks
+# the other of two near-tied experts (rank 8 and 9 of 256 lie ~0.06 apart
+# in logit, the rounded stream moves a logit by a third of that) a whole
+# expert's output comes or goes: at Kimi-Linear's 2.446 / 8 the probe's
+# largest difference read 0.066 / 0.072 / 0.078 / 0.114 on four seeds on
+# the chip (mean 0.012-0.015; limit 0.15) and 0.061-0.088 on the CPU at
+# width 512, with the term 0.046-0.055 there: Solar-Open2's 1 / 8.
 
 
 # SAMBAY_INIT. Random stand-in weights of a SambaY stack, whose head is
@@ -527,6 +580,26 @@ def _first_unscaled(w: jnp.ndarray, out: int) -> jnp.ndarray:
     brought back to the fan-in itself."""
     scale = jnp.ones((w.shape[0],), jnp.float32).at[0].set(out ** 0.5)
     return (w.astype(jnp.float32) * scale[:, None, None]).astype(w.dtype)
+
+
+def _init_mla(cfg: ModelConfig, key: jax.Array, normal, out: int) -> dict:
+    """The latent-attention mixers of a patterned stack (HYBRID_INIT: W_o
+    writes at 1 / sqrt(out) of the usual size), _init_latent's with one
+    direct query projection."""
+    E, H, C = cfg.hidden_size, cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+    n = cfg.count_layers("mla")
+    ks = jax.random.split(key, 6)
+    return {
+        "wq_nope": normal(ks[0], (n, E, H * nope), E),
+        "wq_rope": normal(ks[1], (n, E, H * rope), E),
+        "wkv_a": normal(ks[2], (n, E, C + rope), E),
+        "kv_a_norm": jnp.ones((n, C), cfg.jax_dtype),
+        "w_uk": normal(ks[3], (n, H, C, nope), C),
+        "w_uv": normal(ks[4], (n, H, C, vd), C),
+        "wo": normal(ks[5], (n, H, vd, E), H * vd * out),
+    }
 
 
 def _init_kda(cfg: ModelConfig, key: jax.Array, normal, out: int) -> dict:
@@ -938,8 +1011,11 @@ def _mla_mixer(cfg: ModelConfig, lp: dict, x: jnp.ndarray, positions,
                ) -> Tuple[jnp.ndarray, Any]:
     """Latent attention (MLA) in its absorbed form, the one form for
     prefill chunks, decode rows and dense forwards alike. As published: a
-    query passes a low-rank path with a norm and splits a head into an
-    unrotated and a rotated part; a token's keys and values pass another,
+    query passes a low-rank path with a norm (``cfg.q_lora_rank`` 0: one
+    direct projection) and splits a head into an unrotated and a rotated
+    part (``cfg.mla_rope`` False: nothing is rotated, ``positions`` is not
+    read and the "rotated" parts are plain values); a token's keys and
+    values pass another,
     ``c`` (normed) and one rotated key ``r`` all heads share; head i's key
     is ``[W_UK_i c; r]``, its value ``W_UV_i c``, the score scale
     ``head_dim ** -0.5``. Absorbed: the cache row is ``[c; r]``, head i's
@@ -956,19 +1032,23 @@ def _mla_mixer(cfg: ModelConfig, lp: dict, x: jnp.ndarray, positions,
     ``query_scale`` is."""
     f32, C, H = jnp.float32, cfg.kv_lora_rank, cfg.num_heads
     eps, theta = cfg.rms_norm_eps, cfg.rope_theta
-    c_q = rms_norm(quant_einsum("...te,er->...tr", x, lp["wq_a"]),
-                   lp["q_a_norm"], eps)
+    c_q = x
+    if cfg.q_lora_rank:
+        c_q = rms_norm(quant_einsum("...te,er->...tr", x, lp["wq_a"]),
+                       lp["q_a_norm"], eps)
 
     def heads(y):  # (..., T, H * d) -> (..., T, H, d)
         return y.reshape(*y.shape[:-1], H, -1)
 
+    def rotated(y):
+        return apply_rope(y, positions, theta) if cfg.mla_rope else y
+
     q_nope = heads(quant_einsum("...tr,rf->...tf", c_q, lp["wq_nope"]))
-    q_rope = apply_rope(
-        heads(quant_einsum("...tr,rf->...tf", c_q, lp["wq_rope"])),
-        positions, theta)
+    q_rope = rotated(
+        heads(quant_einsum("...tr,rf->...tf", c_q, lp["wq_rope"])))
     kv = quant_einsum("...te,ec->...tc", x, lp["wkv_a"])
     c = rms_norm(kv[..., :C], lp["kv_a_norm"], eps)
-    r = apply_rope(kv[..., None, C:], positions, theta)  # one head
+    r = rotated(kv[..., None, C:])  # one head
     lanes = cfg.latent_lanes
 
     def padded(*parts):
@@ -1052,7 +1132,11 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
     period is not scanned). Solar-Open2 is one run of (gqa, kda, kda, kda):
     in each period the softmax-attention layer first (no positional
     encoding: order comes from the recurrence), then the KDA layers, every
-    one followed by the sparse block. A SambaY stack (models/sambay.py) is
+    one followed by the sparse block. Kimi-Linear is three: (kda, kda, kda,
+    mla) once with its leading dense layer (an MLP in place of the sparse
+    block), the same scanned, and a short (kda, kda, mla); the latent
+    attention rotates nothing and shares ``caches["kv"]``, a latent pool.
+    A SambaY stack (models/sambay.py) is
     three: (mamba, swa) periods, one (mamba, full), (gmu, cross) periods,
     every block followed by the MLP; what the second run hands the third
     (``m``: the state-space layer's scan output; the full layer's keys and
@@ -1077,7 +1161,8 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
     (periods, layers a period, ...) made the compiler materialise a whole
     period's slice first: 3 x 200 MB of copies a period in the decode
     step, counted by the TPU compiler at the published widths."""
-    stack_of = {"gqa": "gqa", "kda": "kda", **sambay.STACK_OF}
+    stack_of = {"gqa": "gqa", "kda": "kda", "mla": "mla", **sambay.STACK_OF}
+    dense = cfg.dense_layers
 
     def at(tree, i):
         return jax.tree.map(
@@ -1091,7 +1176,15 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
         stacks = [stack_of[k] for k in kinds]
         for j, kind in enumerate(kinds):
             depth = p * len(kinds) + (l0 + j)
-            lp = at(layers, depth)
+            # a leading dense layer's norms and MLP come from "dense", the
+            # others' from the expert layers' "layers" behind them; its
+            # period is a run of its own and not scanned
+            # (cfg.stack_segments), so which layer is dense is known here
+            # (with no dense layer the index is ``depth`` itself, so that
+            # the other stacks' traced programs stay what they were)
+            is_dense = l0 + j < dense
+            lp = (at(params["dense"], l0 + j) if is_dense
+                  else at(layers, depth - dense if dense else depth))
             normed = pre_norm(h, lp["attn_norm"], lp.get("attn_norm_b"))
             with jax.named_scope(kind):
                 # this layer's place in its kind's stack: the period's own
@@ -1115,6 +1208,12 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
                 elif kind == "kda":
                     o, caches = _kda_mixer(cfg, at(params["kda"], i),
                                            normed, recur, caches, i)
+                elif kind == "mla":  # rotates nothing: no positions
+                    kv = None if caches is None else caches["kv"]
+                    o, kv = _mla_mixer(cfg, at(params["mla"], i), normed,
+                                       None, attend, kv, i)
+                    if caches is not None:
+                        caches = {**caches, "kv": kv}
                 elif kind == "mamba":
                     o, y, caches = sambay.mamba_mixer(
                         cfg, at(params["mamba"], i), normed, recur, caches, i)
@@ -1139,10 +1238,13 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
                                           kind=kind)
                     o = sambay.diff_combine(cfg, ap, attn, depth)
             h = h + o
-            if cfg.is_moe:
+            if is_dense:
+                with jax.named_scope("dense_mlp"):
+                    mlp_out = _mlp(cfg, lp, pre_norm(h, lp["mlp_norm"]))
+            elif cfg.is_moe:
                 with jax.named_scope("moe"):
                     mlp_out, hist = _sparse_block(
-                        cfg, lp, experts, p * len(kinds) + (l0 + j),
+                        cfg, lp, experts, p * len(kinds) + (l0 + j - dense),
                         pre_norm(h, lp["mlp_norm"]), live, grouped_matmul)
                 hists.append(hist)
             else:
@@ -1167,8 +1269,9 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
             before[stack_of[k]] += count
     if not cfg.is_moe:
         return x, caches, None
-    hists = hists[0] if len(hists) == 1 else jnp.concatenate(hists)
-    return x, caches, hists.reshape(-1, hists.shape[-1])
+    # a row a sparse layer: a period with a dense layer has one fewer
+    hists = [h.reshape(-1, h.shape[-1]) for h in hists]
+    return x, caches, hists[0] if len(hists) == 1 else jnp.concatenate(hists)
 
 
 def logits_from_hidden(cfg: ModelConfig, params: dict, hidden: jnp.ndarray) -> jnp.ndarray:

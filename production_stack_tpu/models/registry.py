@@ -49,6 +49,13 @@ _REGISTRY: dict[str, ModuleType] = {
     # read, gated memory units; LayerNorm, a tied head. Served uncut on one
     # chip: chipbench cell phi-4-mini-flash-reasoning.long-decode (PR 45)
     "phi4flash": llama,
+    # Kimi-Linear: the same walker over KDA layers and latent-attention
+    # layers that rotate nothing (cfg.mla_layers), the latent pool and the
+    # per-slot state in one cache pytree, a leading dense layer inside the
+    # first period, a short last period. Served with all 27 layers on one
+    # chip as one of sixteen: chipbench cell
+    # kimi-linear-48b-a3b-ep16.long-decode (PR 49)
+    "kimi_linear": llama,
     # encoder-decoder audio transcription: exposes its own forward
     # surface (encode/cross_kv/decode_tokens) instead of the decoder-only
     # protocol; shares param_specs/init_params so weights.py works

@@ -305,6 +305,9 @@ def test_grouped_matmul_compiles_at_the_held_shares_geometries(
 # (the ragged kernel keeps G=4's 512 rows a tile: q_tile_for)
 
 KDA_STATE = ((6, 64, 64, D, D), jnp.float32)
+# Kimi-Linear's share on one chip (chipbench kimi-linear-48b-a3b-ep16): 20
+# KDA layers of 32 heads, the same kernels at half the heads
+KDA_STATE_H32 = ((20, 64, 32, D, D), jnp.float32)
 
 
 def _kda_cases():
@@ -313,27 +316,36 @@ def _kda_cases():
         kda_decode_step,
     )
 
-    rows = [((64, 64, D), jnp.float32)] * 5
+    def step(state):
+        heads = state[0][2]
+        return (
+            lambda st, a, kb, k, q, vb, act: kda_decode_step(
+                st, 3, a, kb, k, q, vb, act),
+            (state, *[((64, heads, D), jnp.float32)] * 5,
+             ((64,), jnp.bool_)))
 
-    def scan(width):  # the ragged program's stream widths (PR 42)
+    def scan(width, state=KDA_STATE):  # the ragged program's stream widths
+        heads = state[0][2]
         return (
             lambda st, g, kb, k, q, vb, cu, ctx: kda_chunk_scan(
                 st, 3, g, kb, k, q, vb, cu, ctx),
-            (KDA_STATE, *[((width, 64, D), jnp.float32)] * 5,
+            (state, *[((width, heads, D), jnp.float32)] * 5,
              ((65,), I32), ((64,), I32)))
 
     return {
-        "kda_decode_step": (
-            lambda st, a, kb, k, q, vb, act: kda_decode_step(
-                st, 3, a, kb, k, q, vb, act),
-            (KDA_STATE, *rows, ((64,), jnp.bool_))),
+        "kda_decode_step": step(KDA_STATE),
         "kda_chunk_scan": scan(2048),
         "kda_chunk_scan@512": scan(512),
+        "kda_decode_step@h32": step(KDA_STATE_H32),
+        "kda_chunk_scan@h32": scan(2048, KDA_STATE_H32),
+        "kda_chunk_scan@512h32": scan(512, KDA_STATE_H32),
     }
 
 
 @pytest.mark.parametrize(
-    "case", ["kda_chunk_scan", "kda_chunk_scan@512", "kda_decode_step"])
+    "case", ["kda_chunk_scan", "kda_chunk_scan@512", "kda_decode_step",
+             "kda_chunk_scan@h32", "kda_chunk_scan@512h32",
+             "kda_decode_step@h32"])
 def test_kda_kernel_is_a_named_custom_call_at_the_cells_shapes(one_chip, case):
     fn, shapes = _kda_cases()[case]
     name = case.partition("@")[0]
@@ -343,7 +355,7 @@ def test_kda_kernel_is_a_named_custom_call_at_the_cells_shapes(one_chip, case):
     assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = .*? custom-call\(",
                      compiled.as_text(), flags=re.M)
     # the state of all layers is updated in place: no second copy of it
-    # (1.6 GB) among the program's temporaries
+    # (1.6 GB; Kimi-Linear's 2.7 GB) among the program's temporaries
     assert compiled.memory_analysis().temp_size_in_bytes < 600 * 2 ** 20
 
 
@@ -442,15 +454,24 @@ def _latent():
     return k
 
 
-@pytest.mark.parametrize("tokens", [2048, 64])
+# Kimi-Linear's share on one chip: 32 heads over a pool of 7 cache layers,
+# at the ragged program's two stream widths and the decode program's 64
+# one-token spans (the kernel's first full-batch decode cell)
+LATENT_POOL_H32 = ((7, 12288, BS, 640), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("tokens,heads", [
+    (2048, 128), (64, 128), (2048, 32), (512, 32), (64, 32)])
 def test_latent_kernel_is_a_named_custom_call_at_the_cells_shapes(
-        one_chip, tokens):
+        one_chip, tokens, heads):
     k = _latent()
     text = _compiled_text(
         lambda q, c, bt, cu, cl: k.latent_paged_attention_pallas(
             q, c, bt, cu, cl, layer_idx=1, value_dim=512),
-        one_chip, ((tokens, 128, 640), jnp.bfloat16), LATENT_POOL,
-        ((64, 576), I32), ((65,), I32), ((64,), I32))
+        one_chip, ((tokens, heads, 640), jnp.bfloat16),
+        LATENT_POOL if heads == 128 else LATENT_POOL_H32,
+        ((64, 576 if heads == 128 else 512), I32), ((65,), I32),
+        ((64,), I32))
     assert re.search(r"^\s*(?:ROOT )?%latent_paged_attention[.\d]* = "
                      r".*? custom-call\(", text, flags=re.M)
 
@@ -474,21 +495,34 @@ def test_latent_kernel_asks_for_its_own_scoped_vmem(one_chip, monkeypatch):
     assert size and float(size.group(1)) <= LATENT_VMEM_MIB
 
 
-@pytest.mark.parametrize("lanes,in_place", [(640, True), (576, False)])
+@pytest.mark.parametrize("lanes,in_place,in_pytree", [
+    (640, True, False), (576, False, False), (640, True, True)])
 def test_latent_row_write_keeps_the_pool_in_place_at_whole_lane_tiles(
-        one_chip, lanes, in_place):
+        one_chip, lanes, in_place, in_pytree):
     """The rows go in by XLA's scatter. At 640 lanes (whole tiles) the
     donated pool is updated where it lies; at the row's own 576 the
     compiler copies the whole pool into a 640-lane layout first: why
-    ``ModelConfig.latent_lanes`` pads."""
+    ``ModelConfig.latent_lanes`` pads. ``in_pytree``: the pool rides a
+    cache pytree beside per-slot state (Kimi-Linear's ``{"kv", "state"}``)
+    and is still updated where it lies, the state untouched."""
     from production_stack_tpu.ops.paged_attention import write_latent
 
     pool = (5, 4096, BS, lanes)
-    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
-        (pool, jnp.bfloat16), ((2048, lanes), jnp.bfloat16), ((2048,), I32))]
-    compiled = jax.jit(
-        lambda c, rows, sm: write_latent(c, 1, rows, sm),
-        donate_argnums=0).lower(*args).compile()
+    shapes = [(pool, jnp.bfloat16), ((2048, lanes), jnp.bfloat16),
+              ((2048,), I32)]
+    caches, rows, sm = (jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                        for s, dt in shapes)
+    if in_pytree:
+        caches = {"kv": caches, "state": jax.ShapeDtypeStruct(
+            (2, 64, 32, D, D), jnp.float32, sharding=one_chip)}
+
+    def fn(c, rows, sm):
+        if in_pytree:
+            return {**c, "kv": write_latent(c["kv"], 1, rows, sm)}
+        return write_latent(c, 1, rows, sm)
+
+    compiled = jax.jit(fn, donate_argnums=0).lower(caches, rows,
+                                                   sm).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     nbytes = 2 * 5 * 4096 * BS * lanes
     assert (temp < nbytes // 100) if in_place else (temp > nbytes)

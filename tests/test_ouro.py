@@ -379,7 +379,8 @@ def test_from_hf_config_reads_the_catalog_file_as_a_looped_stack():
     # every other family runs its layers once (a patterned stack keeps a
     # cache layer for the layers that own keys and values alone)
     assert all(c.loop_passes == 1
-               and c.cache_layers == (c.num_attn_layers if c.mamba_period
+               and c.cache_layers == (c.num_attn_layers
+                                      if c.has_recurrent_state
                                       else c.num_layers)
                for n, c in MODEL_PRESETS.items() if "ouro" not in n)
 

@@ -186,7 +186,9 @@ FAMILIES = ("tiny-gemma", "tiny-gemma2", "tiny-qwen3", "tiny-phi3",
             "tiny-mistral", "tiny-mixtral")
 # the families that joined after the first six, as their own test files
 # build them: the 64-expert block, the looped stack, the KDA hybrid, the
-# latent cache, the Mamba / window / shared-cache stack. (model, block size)
+# latent cache, the Mamba / window / shared-cache stack, KDA state beside a
+# latent pool (the scatter into a pool that rides a cache pytree).
+# (model, block size)
 
 
 def _solar_open2():
@@ -202,6 +204,8 @@ LATER_FAMILIES = {
     "tiny-pangu": (lambda: ModelConfig.from_pretrained("tiny-pangu"), 16),
     "tiny-phi4flash": (
         lambda: ModelConfig.from_pretrained("tiny-phi4flash"), 4),
+    "tiny-kimi-linear": (
+        lambda: ModelConfig.from_pretrained("tiny-kimi-linear"), 16),
 }
 
 
