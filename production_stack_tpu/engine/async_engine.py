@@ -51,9 +51,9 @@ class AsyncEngine:
         if self.thread is not None and self.thread.is_alive():
             return
         self.running = True
-        # what a step has resolved before it blocks on a decode program
-        # leaves through here and not in step()'s return value
-        self.engine.output_sink = self._hand_over
+        # asked at a decode dispatch's landing, with the next one prepared:
+        # what has arrived goes into a ragged step first
+        self.engine.arrival_probe = lambda: not self.intake.empty()
         self.thread = threading.Thread(target=self._worker, daemon=True)
         self.thread.start()
 
@@ -62,7 +62,7 @@ class AsyncEngine:
         if self.thread is not None:
             self.thread.join(timeout=2.0)
             self.thread = None
-        self.engine.output_sink = None  # step() driven by hand again
+        self.engine.arrival_probe = None  # step() driven by hand again
 
     # -- worker thread -------------------------------------------------------
     def _worker(self) -> None:
@@ -70,12 +70,10 @@ class AsyncEngine:
         # step clock (engine/tracing.py): idle, intake and observe here
         # (`observe`: from a step's end to the next intake or, if nothing
         # has arrived, the next step: the step observer's call is in it),
-        # the rest inside engine.step(). `deliver` is entered twice at
-        # most: inside the step, where the engine hands what it has
-        # resolved to `_hand_over` before it blocks on a decode program,
-        # and after it for what step() returns. An output takes one way or
-        # the other, never both, so a step that raises after a hand-over
-        # leaves nothing to deliver again
+        # the rest inside engine.step(). A decode step returns once its
+        # program is launched, with what the landing before it resolved:
+        # delivering, observing, the intake and the next step's schedule
+        # and build all run while the device does
         clock = self.engine.clock
         while self.running:
             self._drain_intake(block=not self.engine.has_unfinished())

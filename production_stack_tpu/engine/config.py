@@ -1223,10 +1223,6 @@ class SchedulerConfig:
     # num-scheduler-steps): amortises host→device dispatch latency; stop
     # conditions are checked every multi_step tokens, surplus is discarded
     multi_step: int = 1
-    # chain decode dispatches through device-resident tokens with the
-    # sample fetch deferred one dispatch. Off: one chip run read TPOT
-    # -9 % and TTFT +11 % (PERF.md section 7; ROADMAP D2 decides).
-    chain_decode: bool = False
     # n-gram (prompt-lookup) speculative decoding: propose up to this many
     # draft tokens per step from the sequence's own token history and
     # verify them inside the ragged unified dispatch (vLLM's ngram

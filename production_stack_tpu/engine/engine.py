@@ -239,11 +239,11 @@ class LLMEngine:
         self._token_bytes = None  # lazy per-vocab byte images
         self._count_reset_slots: list[Sequence] = []
         self._slot_seq: dict[int, Sequence] = {}
-        # deferred decode resolution: consecutive decode dispatches with
-        # identical slot membership chain their input tokens DEVICE-side
-        # (the last sampled row feeds the next dispatch un-fetched), and the
-        # (K, B) sample fetch lags one dispatch. Stop checks therefore lag
-        # one dispatch too: the surplus tokens a finished sequence generates
+        # the decode dispatch in flight (`_run_decode`): the next one, over
+        # the same slots or fewer, takes its input tokens DEVICE-side (the
+        # last sampled row, un-fetched), and is launched when this one's
+        # (K, B) samples have landed. Stop checks come after that launch:
+        # the surplus tokens a finished sequence generates
         # land only in its own uncommitted tail blocks (prefix hashes cover
         # full blocks of host-side token_ids), and any dispatch issued after
         # the blocks are released executes later in device program order —
@@ -289,7 +289,7 @@ class LLMEngine:
         # runs through the kernel's interior body (count_windows)
         self.ragged_attn_windows = 0
         self.ragged_attn_interior_windows = 0
-        self.decode_dispatches = 0  # decode_multi dispatches
+        self.decode_dispatches = 0  # decode dispatches launched
         # attention calls those dispatches made (fused iterations x cache
         # layers each), and those in which the Pallas decode kernel scored
         # a window from the slab as stored (ops/paged_attention_pallas.
@@ -297,11 +297,14 @@ class LLMEngine:
         # geometry, the window layers' calls apart)
         self.decode_attn_calls = 0
         self.decode_attn_slab_calls = 0
-        # where a decode-only step's already resolved outputs go before
-        # the thread blocks on the decode program (`_hand_over`): the
-        # async worker sets it; None means step() returns everything
-        self.output_sink = None
-        self.early_handovers = 0  # hand-overs made before a wait
+        # of them, launched from inputs built and committed while the
+        # dispatch before was still running (`_run_decode`)
+        self.decode_prepared_launches = 0
+        # asked when a decode dispatch lands and the next stands prepared:
+        # has something arrived that the next step should take in first?
+        # The async worker sets it (its intake queue is not empty); None,
+        # as when step() is driven by hand: nothing is ever pending
+        self.arrival_probe = None
         # goodput accounting + compile tracking (perf_accounting.py)
         self.perf = None
         if config.perf.enabled:
@@ -453,7 +456,9 @@ class LLMEngine:
         seq.grammar_slot = -1
 
     def has_unfinished(self) -> bool:
-        return self.scheduler.has_work()
+        # a decode dispatch in flight is unfinished work even where every
+        # sequence in it has been aborted since
+        return self.scheduler.has_work() or self._pending_decode is not None
 
     def live_request_ids(self) -> list[str]:
         """Request ids with scheduler state (waiting or running); aborting
@@ -499,6 +504,9 @@ class LLMEngine:
         # may FINISH sequences (max_tokens=1) the scheduler already put in
         # this step's decode batch
         outputs = self._resolve_pending_ragged()
+        if self._spec is not None:
+            # drafts are proposed from whole token histories
+            outputs.extend(self._resolve_pending_decode())
         decodes = [s for s in out.decodes
                    if s.status is SequenceStatus.RUNNING]
         if decodes:
@@ -508,8 +516,6 @@ class LLMEngine:
                 # verification is fused in the same ragged dispatch
                 outputs.extend(self._run_ragged(out, proposed=True))
             else:
-                # what is resolved leaves through the sink, if there is
-                # one, before the thread waits for the decode program
                 self._run_decode(decodes, outputs)
         else:
             outputs.extend(self._resolve_pending_decode())
@@ -569,20 +575,6 @@ class LLMEngine:
             else:
                 self._spec.update(seq, k, 0)
         return any_drafts
-
-    def _hand_over(self, outputs: list[RequestOutput]) -> None:
-        """Give what the step has resolved so far to the output sink
-        before the thread blocks on the device, and take it out of
-        ``outputs`` so that step() does not return it a second time.
-        Without a sink (step() driven by hand) or with nothing resolved
-        it does nothing."""
-        sink = self.output_sink
-        if sink is None or not outputs:
-            return
-        self.clock.enter("deliver")
-        sink(outputs[:])
-        outputs.clear()
-        self.early_handovers += 1
 
     def _fetch(self, result_dev, kind: str) -> tuple:
         """Block on the results of a dispatch of ``kind``: the step
@@ -760,7 +752,7 @@ class LLMEngine:
         steady-state compile signature a width, verify included, and what
         the scheduler decided is not looked at again. Draft-free
         decode-only steps still take _run_decode (multi-step fusion,
-        chaining)."""
+        inputs prepared under the step before)."""
         bs = self.config.cache.block_size
         outputs = self._resolve_pending_ragged()
         outputs.extend(self._resolve_pending_decode())
@@ -993,7 +985,7 @@ class LLMEngine:
             self.ragged_attn_interior_windows += interior
 
         # scheduler-visible state advances NOW; results land next step
-        # (same deferral contract as chained decode). A spec
+        # (same deferral contract as a decode dispatch). A spec
         # row advances only its guaranteed token here — position pos holds
         # the last ACCEPTED token's KV regardless of draft outcome; the
         # accepted-draft advance happens at resolve, which for spec steps
@@ -1143,34 +1135,33 @@ class LLMEngine:
 
     def _run_decode(self, decodes: list[Sequence],
                     outputs: list[RequestOutput]) -> None:
-        """One decode dispatch over ``decodes``. ``outputs`` holds what
-        the step resolved before it (the pending ragged dispatch's
-        tokens): it goes to the output sink once the decode
-        program is launched and before the thread waits for it
-        (`_hand_over`), and this dispatch's outputs are appended to what
-        is left of it."""
+        """One decode dispatch over ``decodes``, launched and not waited
+        for: its tokens land in the step after. That step schedules,
+        builds, packs and commits its own inputs while this dispatch
+        runs, waits for it, and launches at the landing from the
+        ``next_tok`` this one left on the device: only the launch stands
+        between two decode programs, and one is launched only once the
+        one before has landed, so that an arriving prompt waits for the
+        running program and no other (`_arrival_first` lets its ragged
+        step go first). A dispatch in flight that cannot feed this one is
+        resolved before the build, and the tokens come from the host, in
+        order: its results are wanted there (log-probabilities, the state
+        of a grammar), or a member was not in it, slot for slot.
+        ``outputs`` collects what the step resolves."""
         bs = self.config.cache.block_size
         use_logprobs = any(s.sampling.logprobs is not None for s in decodes)
         use_grammar = any(s.grammar_slot >= 0 for s in decodes)
-        can_chain = (self.config.scheduler.chain_decode
-                     and not use_logprobs  # chained results stay on device
-                     and not use_grammar)  # host mirrors the FSM state
         pending = self._pending_decode
-        if pending is not None:
-            # identity check on request ids, not slots: a freed slot can
-            # be reused by a different sequence within one step window
-            same = (can_chain
-                    and [s.request_id for s in decodes] == pending["rids"])
-            if not same:
-                # membership changed: land the in-flight tokens, then
-                # rebuild from post-resolution state
-                outputs.extend(self._resolve_pending_decode())
-                decodes = [s for s in decodes
-                           if s.status is SequenceStatus.RUNNING]
-                if not decodes:
-                    return
-                pending = None
-        chain = pending is not None
+        if pending is not None and (
+                use_logprobs or use_grammar  # the host mirrors the FSM state
+                or any(pending["rows"].get(s.slot) is not s
+                       for s in decodes)):
+            outputs.extend(self._resolve_pending_decode())
+            decodes = [s for s in decodes
+                       if s.status is SequenceStatus.RUNNING]
+            if not decodes:
+                return
+            pending = None
         self.clock.enter("build")
         self._context_lens[:] = 0
         self._slot_mapping[:] = -1
@@ -1179,7 +1170,7 @@ class LLMEngine:
         for seq in decodes:
             i = seq.slot
             pos = seq.num_computed_tokens  # index of the incoming token
-            if not chain:
+            if pending is None:
                 self._tokens[i] = seq.token_ids[pos]
             self._positions[i] = pos
             n = len(seq.block_ids)
@@ -1230,7 +1221,7 @@ class LLMEngine:
         self.clock.describe("decode", rows=len(decodes),
                             tokens=K * len(decodes))
         t_call = self.clock.enter("snapshot")
-        result = self.runner.decode_multi(
+        launch = self.runner.prepare_decode(
             self._tokens, self._positions, self._block_tables,
             self._context_lens, self._slot_mapping,
             self._temps, self._top_ps, self._top_ks, self._seeds, self._steps,
@@ -1240,15 +1231,29 @@ class LLMEngine:
             adapter_ids=self._adapter_ids if use_lora else None,
             ctrl=((self._ctrl_ids, self._ctrl_vals, self._ctrl_mode)
                   if use_controls else None),
-            tokens_dev=(pending["next_tok"] if chain else None),
+            tokens_dev=pending is not None,
             g_ids=self._g_ids if use_grammar else None,
             g_states=self._g_states if use_grammar else None,
             want_logprobs=use_logprobs,
             **({"window": (self._window_tables, self._window_slot_mapping)}
                if self.window else {}),
         )
-        dispatch_s = self.clock.enter("postprocess") - t_call
+        if pending is not None:
+            self._pending_decode = None
+            self._fetch_decode(pending)  # the device's time ends here
+            if self._arrival_first(pending, len(decodes)):
+                # what was prepared is dropped, nothing of it has reached
+                # the sequences: the next step() schedules the ragged step
+                outputs.extend(self._finish_decode(pending))
+                return
+            t_call = self.clock.now()  # its pack and commit are in the wait
+        pend = {"rows": {s.slot: s for s in decodes},
+                "ctx": int(self._context_lens.sum())}
+        pend["sampled"], pend["next_tok"], pend["counters"], *pend["lp"] = (
+            launch(pending["next_tok"] if pending else None))
+        pend["launch_s"] = self.clock.enter("postprocess") - t_call
         self.decode_dispatches += 1
+        self.decode_prepared_launches += pending is not None
         if self.recurrent is not None:
             self.recurrent.record_decode(K)
         if self.window_counters is not None:
@@ -1265,37 +1270,36 @@ class LLMEngine:
             (attn_calls - windowed)
             * self.runner.decode_attn_slab
             + windowed * self.runner.decode_attn_slab_windowed)
-        pend = {"decodes": list(decodes), "slots": [s.slot for s in decodes]}
-        pend["sampled"], next_tok, pend["counters"], *lp = result
-        pend["lp"] = lp  # empty unless the variant returns logprobs
-        if not can_chain:
-            # the program is in flight: the event loop works on what is
-            # handed over while this thread waits in the fetch
-            self._hand_over(outputs)
-            dispatch_s += self._fetch_decode(pend)
-        if self.perf is not None:
-            entries = [(seq, "decode", K, K) for seq in decodes]
-            self.perf.record_decode(
-                len(decodes), K, int(self._context_lens.sum()),
-                seconds=dispatch_s, tenants=self._tenant_map(entries),
-            )
-            self._attribute_seq_seconds(dispatch_s, entries)
-        if not can_chain:
-            outputs.extend(self._finish_decode(pend, advance=True))
-            return
-        # defer: speculative num_computed advance (the scheduler's block
-        # growth needs it NOW); tokens append at resolution
+        # the scheduler's block growth needs the advance now; the tokens
+        # are appended at the landing
         for seq in decodes:
             seq.num_computed_tokens += K
-        pend["rids"] = [s.request_id for s in decodes]
-        pend["next_tok"] = next_tok
         self._pending_decode = pend
-        if chain:
-            # the previous dispatch's results are fetchable now that this
-            # one is in flight
-            self._hand_over(outputs)
-            self._fetch_decode(pending)
+        if pending is not None:
             outputs.extend(self._finish_decode(pending))
+
+    def _arrival_first(self, pending, n_next: int) -> bool:
+        """Asked at the landing of ``pending`` with the next decode step
+        prepared (``n_next`` rows, all of them ``pending``'s): should a
+        ragged step run before it? Where something has reached the intake
+        queue, or a request waits in the scheduler for a slot or for
+        blocks and this landing frees some: a row gone from the next step
+        (a completion bound reached, which the scheduler knew; an abort),
+        or a landed token that stops its sequence, found by one compare
+        over all of them. A queue that nothing frees stops no launch."""
+        if self.arrival_probe is not None and self.arrival_probe():
+            return True
+        if not self.scheduler.waiting:
+            return False
+        rows = pending["rows"]
+        if n_next < len(rows):
+            return True
+        stops = {t for s in rows.values() for t in s.sampling.stop_token_ids}
+        if (self.tokenizer.eos_id is not None
+                and not all(s.sampling.ignore_eos for s in rows.values())):
+            stops.add(self.tokenizer.eos_id)
+        return bool(np.isin(pending["sampled"][:, list(rows)],
+                            list(stops)).any())
 
     def _resolve_pending_decode(self) -> list[RequestOutput]:
         if self._pending_decode is None:
@@ -1305,34 +1309,41 @@ class LLMEngine:
         self._fetch_decode(pending)
         return self._finish_decode(pending)
 
-    def _fetch_decode(self, pending) -> float:
+    def _fetch_decode(self, pending) -> None:
         """Block on a launched decode dispatch and put its results into
-        ``pending`` in place of the device arrays: sampled tokens (K, B),
-        the log-probability arrays where the variant returns them, and
-        what an MoE model or a looped stack counted, which goes to the
-        runner's counters. Returns the seconds blocked."""
-        (sampled, counters, *lp), wait_s = self._fetch(
-            (pending["sampled"], pending.get("counters"),
-             *pending.get("lp", ())), "decode")
+        ``pending`` in place of the device arrays (sampled tokens (K, B),
+        the log-probability arrays where the variant returns them, what
+        an MoE model or a looped stack counted), with the seconds
+        blocked."""
+        (sampled, pending["counters"], *lp), pending["wait_s"] = self._fetch(
+            (pending["sampled"], pending["counters"], *pending["lp"]),
+            "decode")
         pending["sampled"] = np.asarray(sampled)
         pending["lp"] = [np.asarray(x) for x in lp]
-        if counters is not None:
-            self.runner.record_counters("decode", counters)
-        return wait_s
 
-    def _finish_decode(self, pending,
-                       advance: bool = False) -> list[RequestOutput]:
-        """Append + stop-check one decode dispatch's sampled tokens, on
-        the host by now (`_fetch_decode`). ``advance`` moves num_computed
-        here, for a dispatch that was not deferred (the chained path
-        advances it at dispatch)."""
+    def _finish_decode(self, pending) -> list[RequestOutput]:
+        """Charge, append and stop-check one decode dispatch's sampled
+        tokens, on the host by now (`_fetch_decode`); ``num_computed``
+        moved at its launch. What it is charged is its own launch and its
+        own wait."""
         sampled = pending["sampled"]
         # [tok_lp (K, B), ids (K, B, N), lps (K, B, N)], or nothing
-        lp = pending.get("lp")
+        lp = pending["lp"]
+        if pending["counters"] is not None:
+            self.runner.record_counters("decode", pending["counters"])
+        if self.perf is not None:
+            K, seconds = len(sampled), pending["launch_s"] + pending["wait_s"]
+            entries = [(seq, "decode", K, K)
+                       for seq in pending["rows"].values()]
+            self.perf.record_decode(
+                len(entries), K, pending["ctx"],
+                seconds=seconds, tenants=self._tenant_map(entries),
+            )
+            self._attribute_seq_seconds(seconds, entries)
         token_lists = []
         lp_lists = []
         live = []
-        for seq, slot in zip(pending["decodes"], pending["slots"]):
+        for slot, seq in pending["rows"].items():
             if seq.status.is_finished:
                 continue  # aborted while in flight; surplus tokens dropped
             want_lp = bool(lp) and seq.sampling.logprobs is not None
@@ -1340,8 +1351,6 @@ class LLMEngine:
             new_lps = [] if want_lp else None
             for k in range(sampled.shape[0]):
                 t = int(sampled[k, slot])
-                if advance:
-                    seq.num_computed_tokens += 1
                 seq.output_token_ids.append(t)
                 new_toks.append(t)
                 if seq.grammar_slot >= 0 and seq.fsm is not None:
@@ -1616,7 +1625,7 @@ class LLMEngine:
             "decode_dispatches_total": self.decode_dispatches,
             "decode_attn_calls_total": self.decode_attn_calls,
             "decode_attn_slab_calls_total": self.decode_attn_slab_calls,
-            "early_handovers_total": self.early_handovers,
+            "decode_prepared_launches_total": self.decode_prepared_launches,
             "step_phases": self.clock.snapshot(),
             "slow_step_seconds": {k: dict(v) for k, v
                                   in self.clock.slow_seconds.items()},
@@ -1841,9 +1850,11 @@ class LLMEngine:
         rng = np.random.default_rng(0)
         sched = self.config.scheduler
         vocab = self.config.model.vocab_size
-        decoding = max(sched.multi_step, 1) + 1  # forces one decode
+        # forces two decode steps: one launched from the host's tokens,
+        # one prepared under it and launched from the device's
+        decoding = 2 * max(sched.multi_step, 1) + 1
         longest = max(self.config.model.max_model_len
-                      - sched.multi_step - 2, 1)
+                      - 2 * sched.multi_step - 2, 1)
 
         def run(prompts, temperature, max_tokens=decoding, **feature):
             sp = SamplingParams(temperature=temperature,

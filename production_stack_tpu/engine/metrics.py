@@ -205,6 +205,13 @@ class EngineStatsCollector:
             s.get("decode_dispatches_total", 0),
         )
         yield counter(
+            "vllm:decode_prepared_launches",
+            "Of them, launched at the landing of the dispatch before from "
+            "inputs built and committed while it ran, its tokens left on "
+            "the device",
+            s.get("decode_prepared_launches_total", 0),
+        )
+        yield counter(
             "vllm:decode_attn_calls",
             "Attention calls of the decode dispatches (fused iterations x "
             "cache layers a dispatch)",
@@ -216,12 +223,6 @@ class EngineStatsCollector:
             "the slab as stored (bf16 cache, 128-wide heads: MHA's slab "
             "body, the grouped-query body)",
             s.get("decode_attn_slab_calls_total", 0),
-        )
-        yield counter(
-            "vllm:engine_early_handovers",
-            "Times the engine thread handed a step's resolved outputs to "
-            "the event loop before it blocked on the next decode program",
-            s.get("early_handovers_total", 0),
         )
         # the engine thread's step clock (engine/tracing.py): where its
         # wall time goes, by the kind of step and the phase of the loop.

@@ -17,7 +17,7 @@ Static-shape discipline (XLA traces once per shape):
   rows together. Sampling happens per slot at each span's last token;
   rows whose sample is not consumed (mid-prompt chunks, inactive slots)
   produce masked garbage the host discards.
-- **decode step** (``decode_multi``): a step of decode rows alone is one
+- **decode step** (``prepare_decode``): a step of decode rows alone is one
   compiled program of ``multi_step`` fused decode + sample iterations
   over a fixed (max_num_seqs, 1) batch. Block tables are always
   (B, max_blocks_per_seq).
@@ -376,10 +376,10 @@ class ModelRunner:
         # per-slot output-token counts for presence/frequency penalties
         # ((B, V) int32; allocated on first penalised batch)
         self.token_counts = None
-        # decode_multi's program takes device tokens on every dispatch, so
-        # chained and unchained ones share ONE executable; unchained, it
-        # reads the packed tokens and ignores this constant (placed like
-        # the program's own next_tok output)
+        # the decode program takes device tokens on every dispatch, so a
+        # launch that is given them and one that is not share ONE
+        # executable; the latter reads the packed tokens and ignores this
+        # constant (placed like the program's own next_tok output)
         self._no_tokens_dev = jax.device_put(
             np.zeros((config.scheduler.max_num_seqs, 1), np.int32),
             self._repl)
@@ -947,28 +947,33 @@ class ModelRunner:
                 jnp.asarray(row),
             )
 
-    def decode_multi(self, tokens, positions, block_tables, context_lens,
-                     slot_mapping, temps, top_ps, top_ks, seeds, steps,
-                     greedy_only: bool = False,
-                     presence=None, frequency=None,
-                     adapter_ids=None, ctrl=None, tokens_dev=None,
-                     g_ids=None, g_states=None,
-                     want_logprobs: bool = False, window=None):
-        """Launch multi_step fused decode+sample iterations and return
-        without waiting for them: ``(sampled (num_steps, B), next_tok,
-        counters[, tok_lp (K, B), ids (K, B, N), lps (K, B, N)])``, all
-        still on the device, so whatever the caller does before it fetches
-        (hand over what it has resolved, launch the next dispatch)
-        overlaps this one's compute. ``counters`` is (the routing
+    def prepare_decode(self, tokens, positions, block_tables, context_lens,
+                       slot_mapping, temps, top_ps, top_ks, seeds, steps,
+                       greedy_only: bool = False,
+                       presence=None, frequency=None,
+                       adapter_ids=None, ctrl=None, tokens_dev: bool = False,
+                       g_ids=None, g_states=None,
+                       want_logprobs: bool = False, window=None):
+        """Pack and commit the inputs of multi_step fused decode+sample
+        iterations and return their launch, a call that takes the device
+        tokens: everything a decode dispatch costs the host but the launch
+        itself, so that the engine does it while the dispatch before
+        still runs, and launches (or drops the call) once that one has
+        landed. ``tokens_dev``: the launch will be given the previous
+        dispatch's device-resident ``next_tok`` as the batch's input
+        tokens (no host round trip between two dispatches), which the
+        packed buffer says by a flag; without it the packed ``tokens``
+        are the input. ``greedy_only`` selects the argmax-only compiled
+        variant; presence/frequency arrays activate the penalised variant
+        (counts tracked on device); ``want_logprobs`` the variant that
+        also returns log-probabilities.
+
+        The launch returns without waiting: ``(sampled (num_steps, B),
+        next_tok, counters[, tok_lp (K, B), ids (K, B, N), lps (K, B,
+        N)])``, all still on the device. ``counters`` is (the routing
         histogram of an MoE model, the passes a looped stack made), None
         where the model has no such thing; the caller fetches them with
         the sampled tokens and hands them to ``record_counters``.
-        ``tokens_dev`` feeds the batch's input tokens straight from the
-        previous dispatch's device-resident ``next_tok`` (no host round
-        trip between chained dispatches). ``greedy_only`` selects
-        the argmax-only compiled variant; presence/frequency arrays
-        activate the penalised variant (counts tracked on device);
-        ``want_logprobs`` the variant that also returns log-probabilities.
 
         The ten always-present inputs reach the device as ONE packed
         buffer in one transfer (``StepLayout``, ``_commit``); packing
@@ -976,29 +981,34 @@ class ModelRunner:
         this returns."""
         arrays = (tokens, positions, block_tables, context_lens,
                   slot_mapping, temps, top_ps, top_ks, seeds, steps,
-                  np.full(1, tokens_dev is not None, np.int32),
+                  np.full(1, tokens_dev, np.int32),
                   *(window or ()))
         layout = StepLayout.of(
             _DECODE_INPUTS + (_WINDOW_INPUTS if window else ()), arrays)
         buf = layout.pack(arrays)
-        if tokens_dev is None:
-            tokens_dev = self._no_tokens_dev
         self.clock.enter("commit")
         with jax.set_mesh(self.mesh):
             opt = self._optional_inputs(presence, frequency, adapter_ids,
                                         ctrl, g_ids, g_states)
             packed = self._commit(buf)
-            self.clock.launch(**self._launch_attrs)
-            (self.kv, new_counts), (sampled, next_tok, *lp) = self._decode_multi(
-                self.params, self.kv, packed, tokens_dev, **opt,
-                layout=layout,
-                block_size=self.config.cache.block_size,
-                greedy_only=greedy_only,
-                want_logprobs=want_logprobs,
-            )
-        if opt["use_penalties"]:
-            self.token_counts = new_counts
-        return (sampled, next_tok, self._split_counters(lp), *lp)
+
+        def launch(device_tokens=None):
+            with jax.set_mesh(self.mesh):
+                self.clock.launch(**self._launch_attrs)
+                (self.kv, new_counts), (sampled, next_tok, *lp) = (
+                    self._decode_multi(
+                        self.params, self.kv, packed,
+                        (self._no_tokens_dev if device_tokens is None
+                         else device_tokens),
+                        **opt, layout=layout,
+                        block_size=self.config.cache.block_size,
+                        greedy_only=greedy_only,
+                        want_logprobs=want_logprobs))
+            if opt["use_penalties"]:
+                self.token_counts = new_counts
+            return (sampled, next_tok, self._split_counters(lp), *lp)
+
+        return launch
 
     def ragged_step(self, tokens, positions, block_tables, context_lens,
                     cu_q_lens, slot_mapping, last_idx, sample_mask,

@@ -28,12 +28,13 @@ sequence number rejects replayed frames within a session, and the
 session key rejects frames recorded from any OTHER session. Multi-host
 serving REFUSES to start without a secret.
 
-Device-resident chaining: the engine's chained decode path passes the
-previous dispatch's un-fetched ``next_tok`` device array as
-``tokens_dev`` (engine.py _run_decode). Device arrays can't cross the
-wire — the leader's mirror replaces them with a sentinel and each
-follower substitutes its OWN cached ``next_tok`` from its replay of the
-previous ``decode_multi`` (identical by the SPMD contract).
+Device-resident tokens: the engine prepares a decode dispatch while the
+one before runs (``prepare_decode``) and launches it on that one's
+un-fetched ``next_tok`` device array (engine.py _run_decode), or drops
+it unlaunched. The leader mirrors both halves as it runs them; device
+arrays can't cross the wire, so a sentinel stands for the tokens of a
+launch and each follower substitutes its OWN cached ``next_tok`` from
+its replay of the launch before (identical by the SPMD contract).
 """
 
 from __future__ import annotations
@@ -49,8 +50,6 @@ import struct
 import threading
 import time
 from typing import Optional
-
-import numpy as np
 
 logger = logging.getLogger(__name__)
 
@@ -77,9 +76,10 @@ _CONFIRM = b"pstpu-mh-confirm"
 # work. Host-only accessors (num_blocks, tp, ...) are not mirrored.
 # ``sample``/``decode`` are NOT mirrored: their hot-path callers pass
 # device arrays (unpicklable) and the engine never calls them — the fused
-# ``decode_multi`` is the decode path (r3 advisor).
+# decode step is the decode path (r3 advisor), mirrored in its two halves
+# (``MirroredRunner.prepare_decode``).
 MIRRORED_METHODS = (
-    "ragged_step", "decode_multi",
+    "ragged_step",
     "set_count_row", "register_grammar", "register_lora",
     "unregister_lora", "export_blocks", "export_blocks_range",
     "import_blocks", "import_blocks_range", "drop_kv", "restore_kv",
@@ -287,16 +287,6 @@ class LeaderBroadcaster:
         self.server.close()
 
 
-def _wire_safe(method: str, args: tuple, kwargs: dict) -> tuple:
-    """Strip device-resident args the follower reconstructs locally."""
-    if method == "decode_multi" and kwargs.get("tokens_dev") is not None:
-        td = kwargs["tokens_dev"]
-        if not isinstance(td, np.ndarray):
-            kwargs = dict(kwargs)
-            kwargs["tokens_dev"] = _CHAINED_NEXT_TOK
-    return args, kwargs
-
-
 class MirroredRunner:
     """Leader-side runner wrapper: broadcast the call, then run it locally.
 
@@ -316,11 +306,26 @@ class MirroredRunner:
         fn = getattr(self._inner, name)
 
         def mirrored(*args, **kwargs):
-            w_args, w_kwargs = _wire_safe(name, args, kwargs)
-            self._bcast.broadcast(name, w_args, w_kwargs)
+            self._bcast.broadcast(name, args, kwargs)
             return fn(*args, **kwargs)
 
         mirrored.__name__ = name
+        return mirrored
+
+    def prepare_decode(self, *args, **kwargs):
+        """Both halves are mirrored, each when the leader runs it:
+        committing the packed inputs to a mesh that spans processes is
+        itself a step every process takes together. A prepared step that
+        the leader drops is dropped by the followers' next one."""
+        self._bcast.broadcast("prepare_decode", args, kwargs)
+        launch = self._inner.prepare_decode(*args, **kwargs)
+
+        def mirrored(device_tokens=None):
+            self._bcast.broadcast("launch_decode", (), {
+                "tokens_dev": (None if device_tokens is None
+                               else _CHAINED_NEXT_TOK)})
+            return launch(device_tokens)
+
         return mirrored
 
     def __getattr__(self, name):  # host-only attrs pass straight through
@@ -330,35 +335,38 @@ class MirroredRunner:
 class FollowerReplayer:
     """Replays mirrored calls against the local runner shard.
 
-    Caches the device-resident ``next_tok`` of each ``decode_multi``
-    replay so the leader's chained dispatches (tokens_dev sentinel)
-    resolve to this process's own copy — identical across processes by
-    the SPMD contract. Other outputs are discarded: with the runner's
+    Keeps the launch of the last ``prepare_decode`` replayed and the
+    device-resident ``next_tok`` of the last launch, so the leader's
+    launches on device tokens (tokens_dev sentinel) resolve to this
+    process's own copy — identical across processes by the SPMD
+    contract. Other outputs are discarded: with the runner's
     multihost replicated out_shardings every result is addressable on the
     leader, and followers only need to keep the SPMD program order."""
 
     def __init__(self, runner):
         self.runner = runner
-        self._next_tok = None
+        self._launch = self._next_tok = None
 
     def replay(self, method: str, args: tuple, kwargs: dict) -> None:
-        # isinstance gate first: _wire_safe passes host np.ndarray
-        # tokens_dev through verbatim, and ndarray == str is an
-        # elementwise comparison (ambiguous-truth ValueError under
-        # numpy>=1.25) — r4 advisor
+        if method != "launch_decode":
+            result = getattr(self.runner, method)(*args, **kwargs)
+            if method == "prepare_decode":
+                self._launch = result
+            return
+        # isinstance gate first: a host np.ndarray tokens_dev goes
+        # through verbatim, and ndarray == str is an elementwise
+        # comparison (ambiguous-truth ValueError under numpy>=1.25) —
+        # r4 advisor
         td = kwargs.get("tokens_dev")
         if isinstance(td, str) and td == _CHAINED_NEXT_TOK:
             if self._next_tok is None:
                 raise RuntimeError(
-                    "chained decode_multi replay without a cached "
-                    "next_tok — the SPMD order is broken"
+                    "a decode launch on device tokens replayed without a "
+                    "cached next_tok — the SPMD order is broken"
                 )
-            kwargs = dict(kwargs)
-            kwargs["tokens_dev"] = self._next_tok
-        result = getattr(self.runner, method)(*args, **kwargs)
-        if method == "decode_multi":
-            # (sampled, next_tok, ...) device arrays, un-fetched
-            self._next_tok = result[1]
+            td = self._next_tok
+        # (sampled, next_tok, ...) device arrays, un-fetched
+        self._next_tok = self._launch(td)[1]
 
 
 def follower_loop(runner, leader_host: str, control_port: int,

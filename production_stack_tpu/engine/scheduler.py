@@ -175,11 +175,14 @@ class Scheduler:
         return [s.request_id for s in list(self.waiting)] + list(self.seqs)
 
     def _decode_exhausted(self, seq: Sequence) -> bool:
+        """The dispatches launched so far sample the last token the
+        sequence may have (the row at position p samples token p + 1, and
+        the last one sampled is never fed back)."""
         bound = min(
             seq.num_prompt_tokens + seq.sampling.max_tokens,
             self.max_model_len,
         )
-        return seq.num_computed_tokens >= bound
+        return seq.num_computed_tokens >= bound - 1
 
     # -- internals ------------------------------------------------------------
     def _release(self, seq: Sequence) -> None:

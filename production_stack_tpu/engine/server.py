@@ -1877,8 +1877,12 @@ class EngineServer:
         request_path = {"slow_steps": self.engine.clock.slow_snapshot(),
                         "ttft_parts": self.ttft_parts.snapshot(),
                         "loop_lag": self.loop_lag.snapshot()}
-        # hand-overs made before a wait (vllm:engine_early_handovers_total)
-        handovers = self.engine.early_handovers
+        # decode dispatches, and those launched from inputs prepared under
+        # the dispatch before (vllm:decode_dispatches_total,
+        # vllm:decode_prepared_launches_total)
+        step_loop = {
+            "decode_dispatches": self.engine.decode_dispatches,
+            "decode_prepared_launches": self.engine.decode_prepared_launches}
         # the ragged attention kernel's walks, and those on its narrow
         # row block (vllm:ragged_attn_walks_total, ..._narrow_walks_total)
         walks = {"ragged_dispatches": self.engine.ragged_dispatches,
@@ -1912,12 +1916,12 @@ class EngineServer:
                                       "kv_transfer": kv_block,
                                       "kv_tier": tier_block,
                                       "step_phases": step_phases,
-                                      "early_handovers": handovers,
+                                      "step_loop": step_loop,
                                       **request_path, **walks,
                                       "tenants": self.engine.tenant_stats()})
         snap = perf.snapshot()
         snap["step_phases"] = step_phases
-        snap["early_handovers"] = handovers
+        snap["step_loop"] = step_loop
         snap.update(request_path)
         snap.update(walks)
         eng = self.engine

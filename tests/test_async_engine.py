@@ -118,8 +118,8 @@ def test_admit_batch_failure_aborts_siblings(setup):
     assert asyncio.run(fn())
 
 
-# -- early hand-over: a step's resolved tokens reach the streams before the
-# engine thread blocks on the next decode program, and once only ----------------
+# -- a decode step returns once its program is launched: what the landing
+# before it resolved reaches the streams while the device runs, once only ------
 
 def _collect(ae, prompt, sp, rid):
     async def go():
@@ -133,17 +133,13 @@ def _collect(ae, prompt, sp, rid):
     return go()
 
 
-@pytest.mark.parametrize("chain", [False, True], ids=["unchained", "chained"])
-def test_streams_get_what_the_engine_produced_once_and_in_order(setup, chain):
+@pytest.mark.parametrize("order", ["prepared", "in_order"])
+def test_streams_get_what_the_engine_produced_once_and_in_order(setup, order):
     """Prompts arrive while others decode (one wants a single token, one
     log-probabilities): every stream receives exactly the outputs the
-    engine thread produced for it, in that order, whichever way each took
-    (handed over before a decode wait, or returned by step())."""
-    import dataclasses
-
+    engine thread produced for it, in that order, whether the decode
+    steps were launched prepared or, the probe held true, in order."""
     cfg, mesh, params = setup
-    cfg = dataclasses.replace(cfg, scheduler=dataclasses.replace(
-        cfg.scheduler, chain_decode=chain))
     eng = LLMEngine(cfg, mesh=mesh, params=params,
                     num_blocks=cfg.cache.num_blocks)
     produced = {}
@@ -169,7 +165,9 @@ def test_streams_get_what_the_engine_produced_once_and_in_order(setup, chain):
     async def fn():
         ae = AsyncEngine(eng)
         await ae.start()
-        assert eng.output_sink is not None
+        assert eng.arrival_probe() is False  # the worker's: nothing queued
+        if order == "in_order":
+            eng.arrival_probe = lambda: True
         try:
             tasks = []
             for rid, prompt, sp in requests:
@@ -181,8 +179,9 @@ def test_streams_get_what_the_engine_produced_once_and_in_order(setup, chain):
             ae.stop()
 
     got = asyncio.run(fn())
-    assert eng.output_sink is None  # stop() gives step() back to its caller
-    assert eng.early_handovers > 0
+    assert eng.arrival_probe is None  # stop() gives step() back to its caller
+    assert eng.decode_dispatches >= 8
+    assert (eng.decode_prepared_launches > 0) == (order == "prepared")
     for (rid, _, sp), items in zip(requests, got):
         assert items == produced[rid]  # the same objects, each once
         assert sum(len(o.new_token_ids) for o in items) == sp.max_tokens
@@ -192,17 +191,17 @@ def test_streams_get_what_the_engine_produced_once_and_in_order(setup, chain):
             assert all(o.new_logprobs for o in items)
 
 
-def test_a_step_that_raises_after_the_hand_over_delivers_nothing_twice(setup):
-    """The decode step fails once its program is launched and the first
-    token handed over: the stream has that token once, then the error, and
-    nothing after it."""
+def test_a_step_that_raises_at_a_landing_delivers_nothing_twice(setup):
+    """The first decode step is launched and returns the prompt's first
+    token; the step after it fails waiting for that program: the stream
+    has that token once, then the error, and nothing after it."""
     cfg, mesh, params = setup
     eng = LLMEngine(cfg, mesh=mesh, params=params,
                     num_blocks=cfg.cache.num_blocks)
     handed = []
 
     def fetch_decode(pending):
-        handed.append(eng.early_handovers)
+        handed.append(eng.decode_dispatches)
         raise RuntimeError("device lost")
 
     eng._fetch_decode = fetch_decode
@@ -221,7 +220,7 @@ def test_a_step_that_raises_after_the_hand_over_delivers_nothing_twice(setup):
             ae.stop()
 
     items, busy, streams = asyncio.run(fn())
-    assert handed == [1]  # the hand-over came before the failing wait
+    assert handed == [1]  # the one program launched before the failing wait
     assert len(items) == 2
     first, err = items
     assert len(first.new_token_ids) == 1 and not first.finished

@@ -117,24 +117,28 @@ def test_empty_schedule_flushes_pending():
     assert any(o.finished for o in outs)
 
 
-def test_chained_decode_token_identical():
-    """chain_decode=true (off by default, unmeasured on the chip) must
-    produce identical tokens, including seeded sampling and mid-stream
-    membership changes."""
+@pytest.mark.parametrize("multi_step", [1, 2])
+def test_prepared_decode_token_identical(multi_step):
+    """Decode steps launched prepared, from the tokens the step before
+    left on the device, must produce the tokens of the same engine run in
+    order (its arrival probe held true), including seeded sampling and
+    mid-stream membership changes."""
     from production_stack_tpu.engine.config import SchedulerConfig
 
-    def make(chain):
+    def make(in_order):
         cfg = EngineConfig(
             model=ModelConfig.from_pretrained("tiny-llama"),
             cache=CacheConfig(block_size=4, num_blocks=128),
             scheduler=SchedulerConfig(
                 max_num_seqs=4, max_num_batched_tokens=64,
-                multi_step=2,
-                chain_decode=chain,
+                multi_step=multi_step,
             ),
             mesh=MeshConfig(data=1, tensor=1),
         )
-        return LLMEngine(cfg, mesh=build_mesh(cfg.mesh), num_blocks=128)
+        engine = LLMEngine(cfg, mesh=build_mesh(cfg.mesh), num_blocks=128)
+        if in_order:
+            engine.arrival_probe = lambda: True
+        return engine
 
     sp = SamplingParams(temperature=0.8, top_k=30, seed=7, max_tokens=9,
                        ignore_eos=True)
@@ -153,21 +157,21 @@ def test_chained_decode_token_identical():
                 if o.request_id in toks:
                     toks[o.request_id].extend(o.new_token_ids)
             steps += 1
-        return toks
+        return toks, engine.decode_prepared_launches
 
-    ref = run(make(False))
-    got = run(make(True))
-    assert got == ref
+    ref, none = run(make(True))
+    got, prepared = run(make(False))
+    assert got == ref and none == 0 and prepared >= 2
     for i in range(len(prompts)):
         assert len(ref[f"r{i}"]) == sp.max_tokens - 4 * i
 
 
-# -- a step's resolved tokens leave before the wait for the decode program ----
-# (LLMEngine.output_sink / _hand_over). A mixed run: prompts arrive while
-# others decode, one asks for a single token, one for log-probabilities
-# with seeded sampling. What step() returned for it at the parent commit
-# (e7d0282, computed from an unpacked `git archive` of it, on the CPU) is
-# pinned: [step, request, tokens, finished, has logprobs], in order.
+# -- what a step resolves is returned once its own program is launched ---------
+# A mixed run: prompts arrive while others decode, one asks for a single
+# token, one for log-probabilities with seeded sampling. The tokens of
+# every request, with its finish and its log-probabilities flag, are what
+# step() returned at the commit before the prepared order (21db38c, its
+# PARENT_EVENTS by request): [tokens, has logprobs], and one finish, last.
 
 def _sp(max_tokens, **kw):
     kw.setdefault("temperature", 0.0)
@@ -181,32 +185,12 @@ ARRIVALS = {  # before step n
     4: [("r3", [6, 6, 6, 6], _sp(3))],
 }
 SCHEDULES = {
-    "ragged": dict(),
-    "chained": dict(multi_step=2, chain_decode=True),
+    "one_token_a_step": dict(),
+    "two_tokens_a_step": dict(multi_step=2),
 }
-PARENT_EVENTS = {
-    "ragged": [
-        [1, "r0", [400], False, False], [1, "r1", [27], True, False],
-        [1, "r0", [400], False, False], [3, "r2", [408], False, True],
-        [3, "r0", [400], False, False], [3, "r0", [83], False, False],
-        [3, "r2", [83], False, True], [5, "r3", [233], False, False],
-        [5, "r0", [385], False, False], [5, "r2", [298], False, True],
-        [5, "r0", [27], True, False], [5, "r2", [419], True, True],
-        [5, "r3", [415], False, False], [6, "r3", [464], True, False]],
-    "chained": [
-        [1, "r0", [400], False, False], [1, "r1", [27], True, False],
-        [2, "r0", [400, 400], False, False], [3, "r2", [408], False, True],
-        [3, "r0", [83], False, False], [3, "r0", [385, 27], True, False],
-        [3, "r2", [83, 298], False, True], [5, "r3", [233], False, False],
-        [5, "r2", [419], True, True], [6, "r3", [415, 464], True, False]],
-}
-# the events that are resolved before a decode program the thread then
-# waits for: with a sink they take that way, the others are returned
-HANDED_OVER = {
-    "ragged": {1: 2, 3: 2, 5: 3},     # step -> leading events of that step
-    # chained: step 1 launches and does not wait; step 3's decode program
-    # carries logprobs (not chainable), so the thread waits for it
-    "chained": {3: 2},
+PARENT_TOKENS = {
+    "r0": ([400, 400, 400, 83, 385, 27], False), "r1": ([27], False),
+    "r2": ([408, 83, 298, 419], True), "r3": ([233, 415, 464], False),
 }
 
 
@@ -221,66 +205,46 @@ def make_scheduled_engine(**sched):
 
 
 def run_arrivals(engine):
-    """Drive step() by hand; events in the order they left the engine,
-    each with the way it took."""
-    events, step = [], [0]
-
-    def log(outs, way):
-        events.extend(
-            ([step[0], o.request_id, list(o.new_token_ids), o.finished,
-              o.new_logprobs is not None], way) for o in outs)
-
-    if engine.output_sink is not None:  # the caller asked for one
-        engine.output_sink = lambda outs: log(outs, "sink")
+    """Drive step() by hand; [step, request, tokens, finished, has
+    logprobs] in the order step() returned them."""
+    events = []
     for i in range(64):
         for rid, prompt, sp in ARRIVALS.get(i, ()):
             engine.add_request(rid, prompt_token_ids=prompt, sampling=sp)
         if not engine.has_unfinished() and i > max(ARRIVALS):
             break
-        step[0] = i
-        log(engine.step(), "returned")
+        events.extend(
+            [i, o.request_id, list(o.new_token_ids), o.finished,
+             o.new_logprobs is not None] for o in engine.step())
     assert not engine.has_unfinished()
     return events
 
 
+@pytest.mark.parametrize("order", ["prepared", "in_order"])
 @pytest.mark.parametrize("case", list(SCHEDULES))
-def test_step_without_a_sink_returns_what_the_parent_returned(case):
+def test_step_returns_what_the_parent_generated(case, order):
+    """Same tokens, same log-probabilities flag, one finish a request and
+    that one last, whichever order the decode steps were launched in."""
     engine = make_scheduled_engine(**SCHEDULES[case])
-    assert engine.output_sink is None
+    if order == "in_order":
+        engine.arrival_probe = lambda: True
     events = run_arrivals(engine)
-    assert [e for e, _ in events] == PARENT_EVENTS[case]
-    assert {way for _, way in events} == {"returned"}
-    assert engine.early_handovers == 0
-    assert engine.stats()["early_handovers_total"] == 0
-
-
-@pytest.mark.parametrize("case", list(SCHEDULES))
-def test_a_sink_changes_no_token_and_no_order(case):
-    """Same tokens, same log-probabilities flag, same finishes, in the
-    same order for every request and over all of them; only the way
-    differs, and nothing takes both."""
-    engine = make_scheduled_engine(**SCHEDULES[case])
-    engine.output_sink = print  # run_arrivals puts its own in its place
-    events = run_arrivals(engine)
-    assert [e for e, _ in events] == PARENT_EVENTS[case]
-    want_ways = []
-    for step in sorted({e[0] for e in PARENT_EVENTS[case]}):
-        n = sum(e[0] == step for e in PARENT_EVENTS[case])
-        early = HANDED_OVER[case].get(step, 0)
-        want_ways += ["sink"] * early + ["returned"] * (n - early)
-    assert [way for _, way in events] == want_ways
-    assert engine.early_handovers == len(HANDED_OVER[case])
-    # every request got exactly max_tokens tokens and one finish
-    for rid, _, sp in (a for batch in ARRIVALS.values() for a in batch):
-        mine = [e for e, _ in events if e[1] == rid]
-        assert sum(len(e[2]) for e in mine) == sp.max_tokens
+    for rid, (tokens, has_lp) in PARENT_TOKENS.items():
+        mine = [e for e in events if e[1] == rid]
+        assert [t for e in mine for t in e[2]] == tokens, rid
+        assert {e[4] for e in mine} == {has_lp}
         assert [e[3] for e in mine].count(True) == 1 and mine[-1][3]
+    assert [e[0] for e in events] == sorted(e[0] for e in events)
+    # (two tokens a step: the arrivals leave no two decode steps in a row)
+    assert (engine.decode_prepared_launches > 0) == (
+        order == "prepared" and case == "one_token_a_step")
 
 
-def test_first_token_reaches_the_sink_before_the_decode_wait():
-    """The prompt completes in ragged step N; in step N+1 its first token
-    is handed over under the clock's `deliver` phase after the decode
-    program is launched and before the thread waits for it."""
+def test_a_decode_step_returns_the_first_token_once_it_has_launched():
+    """The prompt completes in ragged step N; step N+1 waits that program
+    out, launches the first decode program and returns the prompt's first
+    token without waiting for it; step N+2 prepares its inputs, waits, and
+    launches at the landing before it looks at the landed tokens."""
     engine = make_scheduled_engine()
     log = []
     real_enter = engine.clock.enter
@@ -290,23 +254,24 @@ def test_first_token_reaches_the_sink_before_the_decode_wait():
         return real_enter(phase, **attrs)
 
     engine.clock.enter = enter
-    engine.output_sink = lambda outs: log.append(
-        ("sink", [(o.request_id, list(o.new_token_ids)) for o in outs]))
     engine.add_request("r0", prompt_token_ids=[1, 2, 3, 4, 5],
                        sampling=_sp(6))
     assert engine.step() == [] and engine._pending_ragged is not None
     del log[:]
     returned = engine.step()  # resolves the ragged step, then decodes
-    sink_at = [i for i, x in enumerate(log) if isinstance(x, tuple)]
-    assert len(sink_at) == 1 and log[sink_at[0]] == ("sink", [("r0", [400])])
-    assert log[sink_at[0] - 1] == "deliver"
-    before, after = log[:sink_at[0]], log[sink_at[0] + 1:]
-    # ... wait (the ragged step), build, snapshot, commit, launch,
-    # deliver, SINK, wait (the decode step), postprocess
-    assert "launch" in before and before.index("wait") < before.index(
-        "launch")
-    assert after[0] == "wait" and "launch" not in after
-    # the decode step's own token is returned, and only that
+    # wait (the ragged step), ..., build, snapshot, commit, launch and out
+    assert log.count("wait") == 1 and log.index("wait") < log.index("launch")
+    assert log[-2:] == ["launch", "postprocess"]
     assert [(o.request_id, o.new_token_ids) for o in returned] == [
         ("r0", [400])]
-    assert engine.early_handovers == 1
+    assert engine._pending_decode is not None
+    assert (engine.decode_dispatches, engine.decode_prepared_launches) == (
+        1, 0)
+    del log[:]
+    returned = engine.step()
+    assert log[log.index("commit"):] == [
+        "commit", "wait", "postprocess", "launch", "postprocess"]
+    assert [(o.request_id, o.new_token_ids) for o in returned] == [
+        ("r0", [400])]
+    assert (engine.decode_dispatches, engine.decode_prepared_launches) == (
+        2, 1)

@@ -243,24 +243,74 @@ def test_leader_rejects_replayed_hello_without_session_confirm():
 
 
 def test_follower_replay_handles_ndarray_tokens_dev():
-    """_wire_safe passes host np.ndarray tokens_dev through the wire;
+    """A host np.ndarray tokens_dev goes over the wire as it is;
     the sentinel check must not trip numpy's elementwise == (ambiguous
     truth ValueError — r4 advisor)."""
     import numpy as np
 
     from production_stack_tpu.engine.multihost import FollowerReplayer
 
-    calls = {}
+    calls = []
 
     class Runner:
-        def decode_multi(self, *a, **kw):
-            calls.update(kw)
-            return ("sampled", "next")
+        def prepare_decode(self, *a, **kw):
+            return lambda tok=None: calls.append(tok) or ("sampled", "next")
 
     rep = FollowerReplayer(Runner())
     arr = np.arange(4, dtype=np.int32)
-    rep.replay("decode_multi", (), {"tokens_dev": arr, "fetch": True})
-    assert calls["tokens_dev"] is arr  # passed through, no ValueError
+    rep.replay("prepare_decode", (arr,), {"tokens_dev": True})
+    rep.replay("launch_decode", (), {"tokens_dev": arr})
+    assert calls[0] is arr  # passed through, no ValueError
+
+
+def test_a_prepared_decode_is_mirrored_in_both_halves_and_dropped_alike():
+    """The engine prepares a decode dispatch while the one before runs and
+    launches it at the landing, or drops it for an arrival. Committing
+    the packed inputs is a step the whole group takes together, so the
+    followers prepare when the leader does, launch when it does, with
+    their own copy of the device's tokens for the sentinel, and a
+    prepared step the leader dropped is never launched by them."""
+    import numpy as np
+
+    from production_stack_tpu.engine.multihost import (
+        FollowerReplayer,
+        MirroredRunner,
+    )
+
+    sent, ran = [], []
+
+    class Bcast:
+        def broadcast(self, method, args, kwargs):
+            sent.append((method, args, dict(kwargs)))
+
+    class Runner:
+        def __init__(self, who):
+            self.who, self.n = who, 0
+
+        def prepare_decode(self, *a, tokens_dev=False, **kw):
+            self.n += 1
+            n = self.n
+            return lambda tok=None: ran.append((self.who, n, tok)) or (
+                "sampled", f"next-{n}-on-{self.who}")
+
+    leader = MirroredRunner(Runner("leader"), Bcast())
+    arr = np.arange(4, dtype=np.int32)
+    first = leader.prepare_decode(arr, greedy_only=True)
+    assert [m for m, _, _ in sent] == ["prepare_decode"]
+    tok = first()[1]
+    leader.prepare_decode(arr, tokens_dev=True)  # dropped at the landing
+    second = leader.prepare_decode(arr, tokens_dev=True, greedy_only=True)
+    second(tok)
+    assert [(m, kw.get("tokens_dev")) for m, _, kw in sent] == [
+        ("prepare_decode", None), ("launch_decode", None),
+        ("prepare_decode", True), ("prepare_decode", True),
+        ("launch_decode", "__pstpu_chained_next_tok__")]
+    follower = FollowerReplayer(Runner("follower"))
+    for method, args, kwargs in sent:
+        follower.replay(method, args, kwargs)
+    assert ran == [("leader", 1, None), ("leader", 3, "next-1-on-leader"),
+                   ("follower", 1, None),
+                   ("follower", 3, "next-1-on-follower")]
 
 
 def test_restricted_unpickler_blocks_forbidden_types():

@@ -123,10 +123,12 @@ def test_forward_matches_the_plain_reference(tiny):
 
 # -- (b) chunked prefill, then decode through the paged cache ----------------
 
-@pytest.mark.parametrize("chain", [False, True])
-def test_prefill_in_two_chunks_then_decode_matches_the_reference(tiny, chain):
+@pytest.mark.parametrize("order", ["prepared", "in_order"])
+def test_prefill_in_two_chunks_then_decode_matches_the_reference(tiny, order):
     cfg, mesh, params = tiny
-    eng = make_engine(cfg, mesh, params, chain_decode=chain)
+    eng = make_engine(cfg, mesh, params)
+    if order == "in_order":  # every prepared decode step is dropped
+        eng.arrival_probe = lambda: True
     assert eng.runner.kv.shape[0] == 12  # a cache layer per (pass, layer)
     prompt = [int(t) for t in np.random.default_rng(1).integers(
         0, cfg.vocab_size, 27)]  # 16 + 11: two chunks of the 16-token budget
@@ -143,6 +145,14 @@ def test_prefill_in_two_chunks_then_decode_matches_the_reference(tiny, chain):
                 zip(got["tokens"], got["lp"]))
             for tid, lp in [(tok, tok_lp), *top[:5]]]
     assert len(errs) == 9 * 6 and max(errs) < LOGPROB_TOL
+    # log-probabilities keep a batch in order; the same prompt without
+    # them runs its decode steps prepared, and generates the same
+    before = eng.decode_prepared_launches
+    eng.add_request("q", prompt_token_ids=prompt, sampling=SamplingParams(
+        max_tokens=9, temperature=0.0, ignore_eos=True))
+    assert run(eng)["q"]["tokens"] == got["tokens"]
+    assert before == 0
+    assert (eng.decode_prepared_launches > 0) == (order == "prepared")
     # every forward ran every pass, and the counters say so
     loop = eng.runner.loop
     forwards = (eng.ragged_dispatches + eng.decode_dispatches

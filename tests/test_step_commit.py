@@ -115,26 +115,33 @@ def test_a_steady_state_step_makes_one_transfer(tp, monkeypatch):
         assert buf.flags.c_contiguous and buf.flags.owndata
 
 
-def test_chained_and_unchained_decode_share_one_executable():
-    """``tokens_dev`` rides every decode dispatch (a constant when the step
-    is not chained), so turning chaining on compiles nothing new: after
-    warm-up neither the tracker nor jit's own cache sees a new entry."""
-    eng = make_engine(chain_decode=True, multi_step=2)
+def test_a_launch_with_and_without_device_tokens_share_one_executable():
+    """``tokens_dev`` rides every decode dispatch (a constant when the
+    launch is given none), so a step launched prepared compiles nothing
+    new: after warm-up neither the tracker nor jit's own cache sees a new
+    entry."""
+    eng = make_engine(multi_step=2)
     eng.warmup()
     runner = eng.runner
     sizes = (runner._ragged.fn._cache_size(),
              runner._decode_multi.fn._cache_size())
-    chained = []
-    real = runner.decode_multi
+    on_device = []
+    real = runner.prepare_decode
 
     def spy(*a, **kw):
-        chained.append(kw.get("tokens_dev") is not None)
-        return real(*a, **kw)
+        launch = real(*a, **kw)
 
-    runner.decode_multi = spy
+        def launched(tokens=None):
+            assert (tokens is not None) == kw["tokens_dev"]
+            on_device.append(tokens is not None)
+            return launch(tokens)
+
+        return launched
+
+    runner.prepare_decode = spy
     eng.generate(PROMPTS, GREEDY)
     eng.generate(PROMPTS, SEEDED)
-    assert True in chained and False in chained
+    assert True in on_device and False in on_device
     assert eng.perf.stats_fields()["unexpected_recompiles"] == 0
     assert (runner._ragged.fn._cache_size(),
             runner._decode_multi.fn._cache_size()) == sizes
@@ -195,19 +202,23 @@ def test_layout_follows_from_shapes_alone():
 
 # -- (c) the pack is the snapshot ----------------------------------------------
 
-@pytest.mark.parametrize("method,sampling,want,chain", [
-    ("ragged_step", GREEDY, GREEDY_TOKENS, True),
-    ("decode_multi", SEEDED, SEEDED_TOKENS, True),
-    ("decode_multi", SEEDED, SEEDED_TOKENS, False)],
-    ids=["ragged_step", "decode_multi-chained", "decode_multi-unchained"])
+@pytest.mark.parametrize("method,sampling,want,in_order", [
+    ("ragged_step", GREEDY, GREEDY_TOKENS, False),
+    ("prepare_decode", SEEDED, SEEDED_TOKENS, False),
+    ("prepare_decode", SEEDED, SEEDED_TOKENS, True)],
+    ids=["ragged_step", "prepare_decode-prepared", "prepare_decode-in_order"])
 def test_host_arrays_may_be_rewritten_once_the_call_returns(method, sampling,
-                                                            want, chain):
+                                                            want, in_order):
     """With the fetch deferred the step may still be pending when the
-    engine rewrites its host arrays in place. Scribble over every one of
-    them the moment the runner returns, let the step finish, then put them
-    back: the results must not have read the scribble. ``decode_multi``
-    never fetches, chained or not: the engine does, after the hand-over."""
-    eng = make_engine(chain_decode=chain)
+    engine rewrites its host arrays in place, and a decode step's inputs
+    are packed before the step ahead of it has landed. Scribble over every
+    one of them the moment the runner returns (``prepare_decode``: before
+    its launch, too), let the step finish, then put them back: the results
+    must not have read the scribble. Neither call fetches: the engine
+    does, a step later."""
+    eng = make_engine()
+    if in_order:
+        eng.arrival_probe = lambda: True
     runner = eng.runner
     real = getattr(runner, method)
     calls = []
@@ -220,11 +231,17 @@ def test_host_arrays_may_be_rewritten_once_the_call_returns(method, sampling,
         kept = [a.copy() for a in mutable]
         for a in mutable:
             a[...] = 3
-        jax.block_until_ready(result)
-        for a, k in zip(mutable, kept):
-            a[...] = k
-        calls.append(len(mutable))
-        return result
+
+        def restore(done):
+            jax.block_until_ready(done)
+            for a, k in zip(mutable, kept):
+                a[...] = k
+            calls.append(len(mutable))
+            return done
+
+        if method == "ragged_step":
+            return restore(result)
+        return lambda tokens=None: restore(result(tokens))
 
     setattr(runner, method, scribbling)
     assert list(eng.generate(PROMPTS, sampling).values()) == want
@@ -233,12 +250,17 @@ def test_host_arrays_may_be_rewritten_once_the_call_returns(method, sampling,
 
 # -- (d) same tokens as the per-array path -------------------------------------
 
-@pytest.mark.parametrize("sched", [{}, {"multi_step": 2, "chain_decode": True},
+@pytest.mark.parametrize("sched", [{}, {"multi_step": 2}, {"in_order": True},
                                    {"spec_ngram_k": 3}],
-                         ids=["default", "chained", "spec"])
+                         ids=["default", "two_a_step", "in_order", "spec"])
 @pytest.mark.parametrize("sampling,want", [(GREEDY, GREEDY_TOKENS),
                                            (SEEDED, SEEDED_TOKENS)],
                          ids=["greedy", "seeded"])
 def test_tokens_equal_the_parents(sched, sampling, want):
+    in_order = sched.pop("in_order", False)
     eng = make_engine(**sched)
+    if in_order:  # every decode step built after the landing before it
+        eng.arrival_probe = lambda: True
     assert list(eng.generate(PROMPTS, sampling).values()) == want
+    assert (eng.decode_prepared_launches > 0) == (
+        not in_order and "spec_ngram_k" not in sched)

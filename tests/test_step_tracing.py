@@ -92,19 +92,15 @@ def _samples(text: str, name: str) -> dict:
 
 # -- the clock over a worker loop ---------------------------------------------
 
-@pytest.mark.parametrize("chain", [False, True], ids=["unchained", "chained"])
-def test_phases_sum_to_the_worker_wall_time_and_each_step_has_one_kind(chain):
-    cfg = make_config()
-    cfg = dataclasses.replace(cfg, scheduler=dataclasses.replace(
-        cfg.scheduler, chain_decode=chain))
-    eng = LLMEngine(cfg)
+@pytest.mark.parametrize("order", ["prepared", "in_order"])
+def test_phases_sum_to_the_worker_wall_time_and_each_step_has_one_kind(order):
+    eng = LLMEngine(make_config())
     clock = eng.clock
     seen = []
-    # `deliver` is entered mid-step too, when the engine hands what it has
-    # resolved to the worker's sink before it waits for a decode program
-    order = []
+    # the phases in the order the thread entered them
+    phases = []
     real_enter = clock.enter
-    clock.enter = lambda phase, **kw: (order.append(phase),
+    clock.enter = lambda phase, **kw: (phases.append(phase),
                                        real_enter(phase, **kw))[1]
 
     async def fn():
@@ -112,6 +108,8 @@ def test_phases_sum_to_the_worker_wall_time_and_each_step_has_one_kind(chain):
         ae.step_observer = seen.append
         t0 = time.monotonic()
         await ae.start()
+        if order == "in_order":  # every prepared decode step is dropped
+            eng.arrival_probe = lambda: True
         outs = []
         for p in PROMPTS:  # one after the other: idle gaps in between
             toks = []
@@ -124,15 +122,19 @@ def test_phases_sum_to_the_worker_wall_time_and_each_step_has_one_kind(chain):
 
     outs, wall, step_count = asyncio.run(fn())
     assert outs == PARENT_TOKENS
-    # one hand-over a prompt: its ragged step is followed by a decode step
-    # the thread waits in (chained: the first of a run only launches, so
-    # the first token is returned at once and no hand-over is made)
-    assert eng.early_handovers == (0 if chain else len(PROMPTS))
-    mid = [i for i, p in enumerate(order[:-1])
-           if p == "deliver" and order[i + 1] == "wait"]
-    assert len(mid) == eng.early_handovers
-    assert all(order[i - 1] == "postprocess" and "launch" in order[i - 3:i]
-               for i in mid)
+    # a decode step waits for the program before it with its own inputs
+    # committed, and launches straight after the landing: between the
+    # wait's end and the launch the thread does nothing but decide
+    # (`postprocess`: the probe, one compare)
+    waits = [i for i, p in enumerate(phases) if p == "wait"]
+    prepared = [i for i in waits if phases[i - 1] == "commit"]
+    assert len(prepared) >= eng.decode_prepared_launches
+    launched = [i for i in prepared
+                if phases[i + 1:i + 3] == ["postprocess", "launch"]]
+    assert len(launched) == eng.decode_prepared_launches
+    assert (len(launched) >= 16) == (order == "prepared")
+    if order == "in_order":
+        assert not launched and len(prepared) >= 10
     # the worker's whole life is in some phase: host + wait + idle = wall
     assert _clock_total(clock) == pytest.approx(wall, rel=0.02)
     assert clock.idle_seconds > 0.2
@@ -149,7 +151,7 @@ def test_phases_sum_to_the_worker_wall_time_and_each_step_has_one_kind(chain):
                        if p not in BETWEEN_STEPS), kind
     decode = clock.seconds["decode"]
     for phase in ("schedule", "build", "snapshot", "commit", "launch",
-                  "postprocess", "deliver", "wait"):
+                  "postprocess", "wait"):
         assert decode[phase][0] > 0.0, phase
     # on-CPU time is part of wall time (summed: a coarse thread clock may
     # charge a tick to a phase shorter than the tick)
@@ -174,7 +176,11 @@ def test_a_step_driven_directly_opens_and_closes_its_own_step():
     assert not clock.in_step and clock.step_num == sum(clock.steps.values())
     assert clock.steps["ragged"] > 0 and clock.steps["decode"] > 0
     assert eng.ragged_dispatches == clock.steps["ragged"]
+    # driven by hand nothing arrives at a landing: every decode step
+    # launches, all but the first after a ragged step prepared
     assert eng.decode_dispatches == clock.steps["decode"]
+    assert eng.decode_prepared_launches == (
+        eng.decode_dispatches - eng.ragged_dispatches)
     snap = clock.snapshot()
     assert set(snap["seconds"]) == set(STEP_KINDS)
     assert set(snap["seconds"]["decode"]) == {*HOST_PHASES, "wait"}
@@ -253,25 +259,33 @@ def test_families_in_metrics_and_debug_perf_only_grow(server):
     asyncio.run(_with_client(server, fn))
 
 
-def test_early_handovers_counter_counts_each_hand_over(server):
-    """vllm:engine_early_handovers_total on /metrics and `early_handovers`
-    on /debug/perf are one plain count: the calls the engine made to its
-    output sink, each before a wait for a decode program."""
-    name = "vllm:engine_early_handovers_total"
+def test_prepared_launches_counter_counts_each_one(server):
+    """vllm:decode_prepared_launches_total beside
+    vllm:decode_dispatches_total on /metrics, and the same two numbers in
+    /debug/perf's `step_loop`: plain counts of the engine's launches."""
+    names = ("vllm:decode_dispatches_total",
+             "vllm:decode_prepared_launches_total")
 
     async def read(client):
         text = await (await client.get("/metrics")).text()
-        (value,) = _samples(text, name).values()
-        perf = await (await client.get("/debug/perf")).json()
-        assert perf["early_handovers"] == value
-        return value
+        values = [next(iter(_samples(text, n).values())) for n in names]
+        loop = (await (await client.get("/debug/perf")).json())["step_loop"]
+        assert [loop["decode_dispatches"],
+                loop["decode_prepared_launches"]] == values
+        return values
 
     async def fn(client):
         eng = server.engine
-        sink, calls = eng.output_sink, []
-        assert sink is not None  # the async worker set it at start
-        eng.output_sink = lambda outs: (calls.append(
-            (eng.clock._phase, len(outs))), sink(outs))[1]
+        assert eng.arrival_probe is not None  # the async worker set it
+        launches = []
+        real = eng.runner.prepare_decode
+
+        def prepare(*a, **kw):
+            launch = real(*a, **kw)
+            return lambda tok=None: (launches.append(tok is not None),
+                                     launch(tok))[1]
+
+        eng.runner.prepare_decode = prepare
         try:
             before = await read(client)
             for i, stream in enumerate((True, False, True)):
@@ -283,15 +297,16 @@ def test_early_handovers_counter_counts_each_hand_over(server):
                 await r.text()
             after = await read(client)
         finally:
-            eng.output_sink = sink
-        # one request at a time: its ragged step, then decode steps
-        assert after - before == len(calls) == 3
-        assert all(phase == "deliver" and n > 0 for phase, n in calls)
+            eng.runner.prepare_decode = real
+        # one request at a time: its ragged step, then four decode steps,
+        # the first from the host's tokens
+        assert [a - b for a, b in zip(after, before)] == [
+            len(launches), sum(launches)] == [12, 9]
 
     asyncio.run(_with_client(server, fn))
 
 
-def test_deliver_entered_twice_in_a_step_is_one_phase_and_loses_no_time():
+def test_a_phase_entered_twice_in_a_step_is_one_phase_and_loses_no_time():
     # the clock reads a fake ``time`` that moves only when the test says
     # so: the sums below are then StepClock's own arithmetic, not how the
     # machine scheduled this worker between two stamps
@@ -310,20 +325,19 @@ def test_deliver_entered_twice_in_a_step_is_one_phase_and_loses_no_time():
         for phase in ("schedule", "build", "snapshot", "commit"):
             clock.enter(phase)
             sleep(0.001)
+        clock.wait("decode")        # for the program before, inputs ready
+        sleep(0.01)
+        clock.enter("postprocess")  # the landing: launch, or an arrival?
+        sleep(0.01)
         clock.launch()
-        clock.enter("postprocess")
-        clock.enter("deliver")      # the hand-over, mid-step
-        sleep(0.01)
-        clock.enter("wait")
-        sleep(0.01)
-        clock.enter("postprocess")
-        clock.enter("deliver")      # what step() returned
+        clock.enter("postprocess")  # the landed step's tokens
         sleep(0.01)
         seconds = clock.end_step()
         wall = fake.monotonic() - t0
     by = clock.seconds["decode"]
-    assert by["deliver"][0] >= 0.02 and by["wait"][0] >= 0.01
-    assert by["deliver"] == pytest.approx([0.02, 0.01])
+    assert by["postprocess"][0] >= 0.02 and by["wait"][0] >= 0.01
+    assert by["postprocess"] == pytest.approx([0.02, 0.01])
+    assert clock.last_wait == "decode"
     # a step opens in its first phase and drops nothing: its seconds are
     # its phases' and the clock's own stamps, begin to end
     assert seconds == pytest.approx(sum(w for w, _ in by.values()), abs=1e-12)
