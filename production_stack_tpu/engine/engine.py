@@ -114,9 +114,13 @@ class LLMEngine:
         self.latent = None
         if config.model.is_latent:
             from production_stack_tpu.engine.tracing import LatentCounters
+            from production_stack_tpu.ops.latent_paged_attention_pallas import (  # noqa: E501
+                EXPAND_ROWS,
+            )
 
-            self.latent = LatentCounters(config.model.cache_layers,
-                                         config.model.kv_bytes_per_token)
+            self.latent = LatentCounters(
+                config.model.cache_layers, config.model.kv_bytes_per_token,
+                expand_rows=EXPAND_ROWS if self.runner.use_pallas else None)
         # what the recurrent layers of a hybrid stack ran
         # (engine/tracing.py); None for every other model
         self.recurrent = None

@@ -353,6 +353,11 @@ class EngineStatsCollector:
                  "(query token, context row) pairs the latent attention "
                  "kernel scored causally, by step kind, times cache "
                  "layers: q (ctx - q) + q (q + 1) / 2 a span"),
+                ("vllm:mla_expanded_pairs", "mla_expanded_pairs_total",
+                 "Of those pairs, the ones of spans long enough in their "
+                 "step that the kernel scored them in the published, "
+                 "expanded form (ops/latent_paged_attention_pallas.py "
+                 "EXPAND_ROWS); none in a decode step"),
                 ("vllm:mla_context_rows", "mla_context_rows_total",
                  "Context rows the spans of the latent attention kernel "
                  "reach, each once a span and cache layer, by step kind"),

@@ -522,12 +522,14 @@ class ModelRunner:
         if self.cfg.is_latent:
             # a latent layer's rows of all heads: the two parts of the
             # query, the absorbed query at the pool's lanes, the
-            # kernel's output and its expansion to values, in the
-            # model dtype; the absorbed query once more in float32
+            # kernel's output and its expansion to values, the heads'
+            # own queries as the kernel's expanded form takes them, in
+            # the model dtype; the absorbed query once more in float32
             c = self.cfg
             hidden += (T * c.num_heads * (
                 2 * (c.head_dim + c.latent_lanes + c.kv_lora_rank
-                     + c.v_head_dim)
+                     + c.v_head_dim + c.qk_nope_head_dim
+                     + c.latent_lanes - c.kv_lora_rank)
                 + 4 * c.latent_lanes)) // 8
         if self.use_pallas:
             return int(8 * hidden + 4 * logits)
@@ -693,14 +695,23 @@ class ModelRunner:
         return out, {**caches, pool: cache}
 
     def _attend_latent(self, q, rows, caches, layer_idx, block_tables,
-                       context_lens, q_positions, slot_mapping, cu_q_lens):
+                       context_lens, q_positions, slot_mapping, cu_q_lens,
+                       expand=None):
         """Latent attention over a packed stream: absorbed queries q
         (T, H, latent_lanes), the tokens' own rows (T, latent_lanes) to
         write first, spans by cu_q_lens (S+1,). Returns ((T, H,
         kv_lora_rank), caches). The rows go in by the XLA scatter on the
         chip too: a (16, lanes) bf16 block is whole tiles, two tokens a
         sublane, so no DMA writes one token's row, and the scatter keeps
-        the donated pool in place (tests/test_kernel_names_v5e.py)."""
+        the donated pool in place (tests/test_kernel_names_v5e.py).
+
+        Two forms of the same scores (models/llama.py _mla_mixer). A
+        decode step and the XLA path are absorbed throughout. The ragged
+        program's kernel, given ``expand`` (the heads' own (T, H, nope) and
+        (T, H, rope) queries, ``W_UK``, ``W_UV``), scores the spans of
+        ``EXPAND_ROWS`` query rows or more in the published form in the
+        same call, and ``llama.ExpandedRows`` comes back in the array's
+        place."""
         from production_stack_tpu.ops.paged_attention import (
             latent_ragged_paged_attention,
             write_latent,
@@ -713,9 +724,24 @@ class ModelRunner:
                 latent_paged_attention_pallas,
             )
 
-            return latent_paged_attention_pallas(
+            if expand is None:
+                return latent_paged_attention_pallas(
+                    q, caches, block_tables, cu_q_lens, context_lens,
+                    layer_idx, value_dim=C), caches
+            from production_stack_tpu.models.llama import ExpandedRows
+
+            q_nope, q_rope, w_uk, w_uv = expand
+            # a head's query as the pool's row lies: [nope; rope; zeros]
+            q_own = jnp.concatenate(
+                [q_nope, q_rope, jnp.zeros(
+                    (*q_rope.shape[:-1],
+                     q.shape[-1] - C - q_rope.shape[-1]), q_rope.dtype)],
+                axis=-1).swapaxes(0, 1)
+            o_lat, o_own, scored = latent_paged_attention_pallas(
                 q, caches, block_tables, cu_q_lens, context_lens, layer_idx,
-                value_dim=C), caches
+                value_dim=C, expand=(q_own, w_uk, w_uv,
+                                     self.cfg.head_dim ** -0.5))
+            return ExpandedRows(o_lat, o_own.swapaxes(0, 1), scored), caches
         seq_ids = _owning_slots(cu_q_lens, q.shape[0], block_tables.shape[0])
         layer = jax.lax.dynamic_index_in_dim(caches, layer_idx, 0, False)
         return latent_ragged_paged_attention(
@@ -725,10 +751,11 @@ class ModelRunner:
     def _attend_decode(self, q, k, v, caches, layer_idx, block_tables,
                        context_lens, q_positions, slot_mapping, *,
                        window: int = 0, write: bool = True, kind=None,
-                       **window_inputs):
+                       expand=None, **window_inputs):
         """``window`` > 0: the query sees the last ``window`` rows;
         ``write`` False: the keys and values are another layer's, already
-        in ``caches``, and k, v are not used."""
+        in ``caches``, and k, v are not used; ``expand``: a latent layer's
+        published form, which one-token spans never take."""
         if kind is not None:
             return self._attend_kind(
                 self._attend_decode, kind, q, k, v, caches, layer_idx,
@@ -782,7 +809,7 @@ class ModelRunner:
     def _attend_ragged(self, q, k, v, caches, layer_idx, block_tables,
                        context_lens, q_positions, slot_mapping, cu_q_lens, *,
                        window: int = 0, write: bool = True, kind=None,
-                       **window_inputs):
+                       expand=None, **window_inputs):
         """Unified ragged step: q (1, T, H, D) over the packed mixed
         prefill+decode stream; per-slot spans via cu_q_lens (S+1,).
         q_positions (1, T) carries each token's absolute position (-1 pad)
@@ -798,8 +825,9 @@ class ModelRunner:
         if self.cfg.is_latent:
             out, caches = self._attend_latent(
                 q[0], k[0, :, 0], caches, layer_idx, block_tables,
-                context_lens, q_positions[0], slot_mapping, cu_q_lens)
-            return out[None], caches
+                context_lens, q_positions[0], slot_mapping, cu_q_lens,
+                expand=expand and (expand[0][0], expand[1][0], *expand[2:]))
+            return jax.tree.map(lambda a: a[None], out), caches
         how = {"window": window} if window else {}
         if write:
             k_flat = k.reshape(T, -1, self.cfg.cache_head_dim)
@@ -1535,10 +1563,11 @@ def _decode_multi_step(cfg: ModelConfig, attend_impl, num_steps: int, eos_id,
         g_ids = g_states0 = jnp.zeros(B, jnp.int32)  # carry placeholder
 
     def one(kv, tok, pos, ctx, slots, wslots, step_ctr, counts, g_state):
-        def attend(q, k, v, caches, layer_idx, **kind):
+        def attend(q, k, v, caches, layer_idx, **how):
             return attend_impl(
                 q, k, v, caches, layer_idx, block_tables, ctx, pos[:, None],
-                slots, **(_window_kw(f, wslots) if kind else {}), **kind,
+                slots, **(_window_kw(f, wslots) if "kind" in how else {}),
+                **how,
             )
 
         # idle slots stay out of an MoE model's routing, whose per-layer
@@ -1680,11 +1709,11 @@ def _ragged_step(cfg: ModelConfig, attend_impl, eos_id, spec_width, params, kv,
     f = layout.unpack(packed)
     tokens, positions, last_idx = f["tokens"], f["positions"], f["last_idx"]
 
-    def attend(q, k, v, caches, layer_idx, **kind):
+    def attend(q, k, v, caches, layer_idx, **how):
         return attend_impl(
             q, k, v, caches, layer_idx, f["block_tables"],
             f["context_lens"], positions, f["slot_mapping"], f["cu_q_lens"],
-            **(_window_kw(f) if kind else {}), **kind,
+            **(_window_kw(f) if "kind" in how else {}), **how,
         )
 
     lora = None

@@ -689,15 +689,24 @@ class LatentCounters:
 
     A span of ``q`` tokens ending a context of ``ctx`` scores, causally,
     ``q * (ctx - q) + q * (q + 1) / 2`` (query, context row) pairs: its
-    past whole, its own triangle."""
+    past whole, its own triangle.
+
+    ``expand_rows``: the kernel's crossover (``EXPAND_ROWS``) where the
+    runner's ragged program is the kernel's, else None. A span of that
+    many tokens or more in a ragged dispatch is scored in the published,
+    expanded form, and its pairs are counted a second time as such; a
+    decode dispatch's one-token spans never are."""
 
     KINDS = ("ragged", "decode")
 
-    def __init__(self, cache_layers: int, kv_bytes_per_token: int):
+    def __init__(self, cache_layers: int, kv_bytes_per_token: int,
+                 expand_rows: Optional[int] = None):
         self.cache_layers = cache_layers
         self.kv_bytes_per_token = kv_bytes_per_token
+        self.expand_rows = expand_rows
         self.query_tokens = dict.fromkeys(self.KINDS, 0)
         self.scored_pairs = dict.fromkeys(self.KINDS, 0)
+        self.expanded_pairs = dict.fromkeys(self.KINDS, 0)
         # context rows the spans reach (each once a span and layer): what
         # a kernel has to read of the pool at least
         self.context_rows = dict.fromkeys(self.KINDS, 0)
@@ -714,14 +723,18 @@ class LatentCounters:
         # iteration i finds every live context i rows longer: i rows and,
         # for a one-token span, i pairs more a slot
         longer = int((q > 0).sum()) * (K * (K - 1) // 2)
+        pairs = q * (ctx - q) + q * (q + 1) // 2
         self.query_tokens[kind] += K * int(q.sum()) * L
-        self.scored_pairs[kind] += (K * int(
-            (q * (ctx - q) + q * (q + 1) // 2).sum()) + longer) * L
+        self.scored_pairs[kind] += (K * int(pairs.sum()) + longer) * L
         self.context_rows[kind] += (K * int(ctx.sum()) + longer) * L
+        if kind == "ragged" and self.expand_rows is not None:
+            self.expanded_pairs[kind] += int(
+                pairs[q >= self.expand_rows].sum()) * L
 
     def snapshot(self) -> dict:
         return {"mla_query_tokens_total": dict(self.query_tokens),
                 "mla_scored_pairs_total": dict(self.scored_pairs),
+                "mla_expanded_pairs_total": dict(self.expanded_pairs),
                 "mla_context_rows_total": dict(self.context_rows),
                 "kv_bytes_per_token": self.kv_bytes_per_token}
 

@@ -21,7 +21,7 @@ reference assumes exists.
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +42,10 @@ from production_stack_tpu.ops.norms import layer_norm, rms_norm
 from production_stack_tpu.ops.rope import apply_rope
 from production_stack_tpu.parallel import shardings as lax_names
 
-# AttendFn: (q, k, v, layer_cache, layer_idx) -> (attn_out, new_layer_cache)
+# AttendFn: (q, k, v, layer_cache, layer_idx) -> (attn_out, new_layer_cache);
+# keywords it must take or drop: ``kind`` from a stack whose attention
+# layers differ (models/sambay.py), ``expand`` from a latent layer
+# (_mla_mixer)
 AttendFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray, Any, jnp.ndarray], Tuple[jnp.ndarray, Any]]
 # RecurFn, a recurrent (KDA) layer's stateful call, as AttendFn is an
 # attention layer's: (conv taps (K, 3*H*D), the projected rows
@@ -1006,22 +1009,36 @@ def forward_hidden(
     return out
 
 
+class ExpandedRows(NamedTuple):
+    """What a latent ``attend`` returns where it scored some rows of the
+    stream in the published form (``_mla_mixer``)."""
+    absorbed: jnp.ndarray  # (..., T, H, kv_lora_rank); those rows undefined
+    heads: jnp.ndarray  # (..., T, H, v_head_dim), of those rows
+    rows: jnp.ndarray  # (..., T) bool: which
+
+
 def _mla_mixer(cfg: ModelConfig, lp: dict, x: jnp.ndarray, positions,
                attend: AttendFn, caches, cache_layer
                ) -> Tuple[jnp.ndarray, Any]:
-    """Latent attention (MLA) in its absorbed form, the one form for
-    prefill chunks, decode rows and dense forwards alike. As published: a
-    query passes a low-rank path with a norm (``cfg.q_lora_rank`` 0: one
-    direct projection) and splits a head into an unrotated and a rotated
-    part (``cfg.mla_rope`` False: nothing is rotated, ``positions`` is not
-    read and the "rotated" parts are plain values); a token's keys and
-    values pass another,
+    """Latent attention (MLA). As published: a query passes a low-rank
+    path with a norm (``cfg.q_lora_rank`` 0: one direct projection) and
+    splits a head into an unrotated and a rotated part (``cfg.mla_rope``
+    False: nothing is rotated, ``positions`` is not read and the "rotated"
+    parts are plain values); a token's keys and values pass another,
     ``c`` (normed) and one rotated key ``r`` all heads share; head i's key
     is ``[W_UK_i c; r]``, its value ``W_UV_i c``, the score scale
-    ``head_dim ** -0.5``. Absorbed: the cache row is ``[c; r]``, head i's
-    query ``[W_UK_i^T q_nope_i; q_rope_i]`` scores the row itself, the
-    weighted rows' first ``kv_lora_rank`` values go through ``W_UV_i``.
-    The same numbers up to rounding (tests/test_pangu_ultra_moe.py).
+    ``head_dim ** -0.5``. The cache row is ``[c; r]`` either way, and the
+    scores have TWO forms, the same numbers up to rounding
+    (tests/test_pangu_ultra_moe.py). ABSORBED: head i's query
+    ``[W_UK_i^T q_nope_i; q_rope_i]`` scores the row itself, the weighted
+    rows' first ``kv_lora_rank`` values go through ``W_UV_i``: 2176
+    operations a pair and head where the published form has 640, and no
+    work a context row; the form of decode rows, short spans, dense
+    forwards and the XLA path. EXPANDED, the published form: a context row
+    is expanded to a head's key and value once a span and head, which a
+    span of many query rows shares; the Pallas kernel of the ragged program
+    scores its long spans so (ops/latent_paged_attention_pallas.py
+    ``EXPAND_ROWS``), from ``expand`` below.
 
     ``attend`` takes the absorbed queries (..., T, H, latent_lanes), the
     rows as the one key head (..., T, 1, latent_lanes), both padded with
@@ -1029,7 +1046,9 @@ def _mla_mixer(cfg: ModelConfig, lp: dict, x: jnp.ndarray, positions,
     its value, and returns (..., T, H, kv_lora_rank). Attention
     implementations scale by their query's width ** -0.5, so the score
     scale is folded into the query, accumulated in float32, as
-    ``query_scale`` is."""
+    ``query_scale`` is. It is also handed ``expand`` = (q_nope, q_rope,
+    ``W_UK``, ``W_UV``), which most implementations drop; one that scores
+    some rows expanded returns ``ExpandedRows`` in place of the array."""
     f32, C, H = jnp.float32, cfg.kv_lora_rank, cfg.num_heads
     eps, theta = cfg.rms_norm_eps, cfg.rope_theta
     c_q = x
@@ -1062,8 +1081,14 @@ def _mla_mixer(cfg: ModelConfig, lp: dict, x: jnp.ndarray, positions,
                        preferred_element_type=f32)
     fold = cfg.head_dim ** -0.5 * lanes ** 0.5
     q = (padded(q_lat, q_rope.astype(f32)) * fold).astype(x.dtype)
-    o_lat, caches = attend(q, row, row[..., :C], caches, cache_layer)
-    o = jnp.einsum("...thc,hcd->...thd", o_lat, lp["w_uv"])
+    o_lat, caches = attend(
+        q, row, row[..., :C], caches, cache_layer,
+        expand=(q_nope, q_rope, lp["w_uk"], lp["w_uv"]))
+    scored = o_lat if isinstance(o_lat, ExpandedRows) else None
+    o = jnp.einsum("...thc,hcd->...thd",
+                   o_lat if scored is None else scored.absorbed, lp["w_uv"])
+    if scored is not None:
+        o = jnp.where(scored.rows[..., None, None], scored.heads, o)
     return quant_einsum("...thd,hde->...te", o, lp["wo"]), caches
 
 
@@ -1298,7 +1323,7 @@ def dense_attend(cfg: ModelConfig) -> AttendFn:
     """A dense forward's attention call, for any family: causal over the
     rows given, within the window for a "swa" layer of a stack whose
     window binds (``kind``: models/sambay.py)."""
-    def attend(q, k, v, caches, layer_idx, kind=None):
+    def attend(q, k, v, caches, layer_idx, kind=None, expand=None):
         return dense_causal_attention(
             q, k, v, soft_cap=cfg.attn_logit_softcap,
             window=cfg.sliding_window if kind == "swa" else 0), caches

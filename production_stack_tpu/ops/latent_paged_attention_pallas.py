@@ -1,5 +1,9 @@
-"""Latent paged attention (MLA, absorbed) — ONE Pallas kernel for the ragged
-stream and for decode rows alike.
+"""Latent paged attention (MLA) — ONE Pallas kernel for the ragged stream and
+for decode rows alike, with TWO forms of the same scores: absorbed for
+decode rows and short spans, expanded (the published form) for a span of
+``EXPAND_ROWS`` query rows or more in this step.
+
+ABSORBED (all of this docstring down to "EXPANDED").
 
 The pool holds one row a token and cache layer, ``(L, N, bs, lanes)``: the
 latent ``c`` (the first ``value_dim`` lanes), the rotated key every head
@@ -24,6 +28,26 @@ context) skip the mask.
 Operands go to the MXU as stored (bf16 at the published widths), the
 scores, the softmax state and the accumulator are float32, the weights are
 rounded to the cache's type before the second matmul.
+
+EXPANDED. The absorbed form spends 2 * (2 * value_dim + rope) operations a
+(query, context row) pair and head where the published form spends
+2 * (nope + rope + v): 2176 against 640 at the published widths. Expanding
+a context row to one head's key ``[W_UK_h c; r]`` and value ``W_UV_h c``
+costs 2 * value_dim * (nope + v) operations, shared by every query row of
+the span: past ~170 rows the published form is the cheaper one, and a
+2048-row chunk pays the expansion back twelve times. So a call that is
+given the heads' own queries and ``W_UK`` / ``W_UV`` (``expand``: the
+ragged program) scores its long spans that way, in the SAME ``pallas_call``:
+the grid's first steps are the stream's tiles as above, which skip the long
+spans, and its last ``H / EXPAND_HEADS`` steps own a block of heads each,
+whose queries for the WHOLE stream sit in VMEM. Such a step walks each long
+span's context once in windows of ``EXPAND_WINDOWS`` blocks; a window is
+fetched once, expanded once a head (two ``(rows, value_dim) x (value_dim,
+128)`` products, rounded once to the cache's type as a checkpoint's own
+``kv_b_proj`` output is), and scored by the span's query rows in blocks of
+``EXPAND_Q_ROWS``, those above the diagonal skipped, those nothing can mask
+without a mask; one flash state a (head, stream row). The form follows what
+the kernel observes, a span's query rows in this step, and nothing else.
 """
 
 from __future__ import annotations
@@ -50,9 +74,32 @@ from production_stack_tpu.ops.ragged_paged_attention_pallas import (
 # call sets its own limit, so the configuration needs no libtpu flag
 Q_TILE = 16
 VMEM_LIMIT_BYTES = 64 * 2 ** 20
+# a span of this many query rows or more in a step is scored expanded:
+# break-even ~170 rows by operations and 190-250 on the chip (one span at
+# 4096 of context, absorbed / expanded ms a call: 192 rows 5.09 / 5.58, 256
+# rows 6.11 / 5.45, 512 rows 10.04 / 5.17; PERF.md section 6, PR 51).
+# engine/tracing.py LatentCounters counts by it too
+EXPAND_ROWS = 256
+# the expanded form's blocking, from the same fixed inputs (a 2048-row chunk
+# at 6144 of context, ms a call): query rows scored against a window at once
+# (128 / 256 / 512 / 1024: 23.2 / 15.5 / 13.8 / 13.6: the MXU holds a
+# 128 x 128 tile of one operand while the other's rows stream past it),
+# KV blocks a window holds (512 rows at block 16; 1024 rows gain 2 % at 6 k
+# and lose 8 % on a fresh chunk), heads a grid step owns (1 / 2 / 4: 16.5 /
+# 15.5 / 14.8: each step walks the context again). With them the call asks
+# for 42.64 MiB of scoped VMEM (tests/test_kernel_names_v5e.py)
+EXPAND_Q_ROWS = 512
+EXPAND_WINDOWS = 32
+EXPAND_HEADS = 4
 
 
-def _latent_kernel(
+def _latent_kernel(*refs, **static):
+    """The absorbed form alone: a decode step's program."""
+    _absorbed_tile(pl.program_id(0), *refs, **static)
+
+
+def _absorbed_tile(
+    t,  # the stream's tile
     # scalar prefetch
     bt_ref,  # (S, M) SMEM — per-slot block-table rows
     cu_ref,  # (S+1,) SMEM — cumulative query-span offsets into the stream
@@ -66,8 +113,8 @@ def _latent_kernel(
     # outputs
     o_ref,  # (1, R, value_dim) VMEM
     # scratch
-    buf,  # (2, W, bs, lanes) VMEM
-    sems,  # (2, W) DMA sems
+    buf,  # (2, >= W, bs, lanes) VMEM
+    sems,  # (2, >= W) DMA sems
     m_ref,  # (R, LANES) f32 — flash running max, lane 0
     l_ref,  # (R, LANES) f32 — flash running sum, lane 0
     acc_ref,  # (R, value_dim) f32 — flash accumulator
@@ -77,8 +124,11 @@ def _latent_kernel(
     q_tile: int,
     heads: int,
     scale: float,
+    expand_rows: int | None = None,
 ):
-    t = pl.program_id(0)
+    """One tile of the stream, absorbed. ``expand_rows``: spans of that
+    many query rows or more are another grid step's (``_expanded_heads``)
+    and walk nothing here."""
     layer = layer_ref[0]
     W, bs, TQ, H = windows, block_size, q_tile, heads
     win_tokens = W * bs
@@ -125,7 +175,7 @@ def _latent_kernel(
         def walk(rows, r0):
             """Stream the context past tile rows [r0, r0 + rows): the
             whole tile, or the one token a decode row owns."""
-            rs = pl.ds(r0, rows)
+            rs = (pl.ds(r0, rows),)
             # row r is stream token tile0 + r // H (rows ordered (token, h))
             g_idx = tile0 + (r0 + jax.lax.broadcasted_iota(
                 jnp.int32, (rows, 1), 0)) // H
@@ -146,36 +196,23 @@ def _latent_kernel(
                     def _():
                         dma(slot, w, j).wait()
 
-                k = buf[slot].reshape(win_tokens, lanes)
+                k = buf[slot, :W].reshape(win_tokens, lanes)
                 sc = jax.lax.dot_general(
-                    q_ref[0, rs, :], k, (((1,), (1,)), ((), ())),
+                    q_ref[(0, *rs, slice(None))], k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale  # (rows, T)
-                m = m_ref[rs, 0:1]
+                valid = None
+                v = k[:, :V]
                 if masked:
                     kvpos = w * win_tokens + jax.lax.broadcasted_iota(
                         jnp.int32, (1, win_tokens), 1)
                     valid = row_in & (kvpos <= qpos) & (kvpos < ctx)
-                    sc = jnp.where(valid, sc, NEG_INF)
-                m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
-                alpha = jnp.exp(m - m_new)
-                p = jnp.exp(sc - m_new)
-                v = k[:, :V]
-                if masked:
-                    # a row this span does not own has every score at
-                    # NEG_INF: exp(sc - m_new) would be 1 there, so the
-                    # masked weights are zeroed explicitly; blocks past the
-                    # reach were never fetched, and 0 x NaN = NaN
-                    p = jnp.where(valid, p, 0.0)
+                    # blocks past the reach were never fetched, and
+                    # 0 x NaN = NaN
                     v = jnp.where(
                         w * win_tokens + jax.lax.broadcasted_iota(
                             jnp.int32, (win_tokens, 1), 0) < reach,
                         v, jnp.zeros_like(v))
-                l_ref[rs, 0:1] = l_ref[rs, 0:1] * alpha + jnp.sum(
-                    p, axis=-1, keepdims=True)
-                m_ref[rs, 0:1] = m_new
-                acc_ref[rs, :] = acc_ref[rs, :] * alpha + jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+                _flash_update(m_ref, l_ref, acc_ref, rs, sc, v, valid)
                 return 0
 
             n_int = 0
@@ -189,6 +226,8 @@ def _latent_kernel(
                 n_int, nwin, functools.partial(win_body, True), 0)
 
         live = nwin > 0
+        if expand_rows is not None:
+            live &= q_len < expand_rows
         if TQ == 1:
             pl.when(live)(lambda: walk(R, 0))
         else:
@@ -204,6 +243,199 @@ def _latent_kernel(
                 ).astype(o_ref.dtype)
 
 
+def _flash_update(m_ref, l_ref, acc_ref, rs, sc, v, valid):
+    """One window's scores ``sc`` (rows, T) float32 into the flash state of
+    rows ``rs`` (an index tuple into the three refs), its values ``v``
+    (T, width). ``valid``: the (rows, T) mask, None where nothing can be
+    masked."""
+    m = m_ref[(*rs, slice(0, 1))]
+    if valid is not None:
+        sc = jnp.where(valid, sc, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(sc - m_new)
+    if valid is not None:
+        # a row this span does not own has every score at NEG_INF:
+        # exp(sc - m_new) would be 1 there, so the masked weights are
+        # zeroed explicitly
+        p = jnp.where(valid, p, 0.0)
+    l_ref[(*rs, slice(0, 1))] = l_ref[(*rs, slice(0, 1))] * alpha + jnp.sum(
+        p, axis=-1, keepdims=True)
+    m_ref[(*rs, slice(0, 1))] = m_new
+    acc_ref[(*rs, slice(None))] = (
+        acc_ref[(*rs, slice(None))] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32))
+
+
+def _expanded_heads(
+    bt_ref, cu_ref, cl_ref, layer_ref,
+    qx_ref,  # (HB, T, nope + rope lanes) VMEM — the heads' own queries
+    kv_hbm,
+    wuk_ref,  # (HB, value_dim, nope) VMEM
+    wuv_ref,  # (HB, value_dim, v) VMEM
+    ox_ref,  # (HB, T, v) VMEM
+    buf,  # (2, >= WX, bs, lanes) VMEM
+    sems,  # (2, >= WX) DMA sems
+    mx_ref, lx_ref,  # (HB, T, LANES) f32 — flash max and sum, lane 0
+    accx_ref,  # (HB, T, v) f32
+    kx_ref,  # (WX * bs, nope + rope lanes) — a window's keys of one head
+    vx_ref,  # (WX * bs, v) — its values
+    *,
+    block_size: int,
+    windows: int,
+    q_rows: int,
+    expand_rows: int,
+    scale: float,
+):
+    """The long spans of the stream for ``HB`` heads, in the published form:
+    head h's key ``[W_UK_h c; r]``, its value ``W_UV_h c``."""
+    HB = qx_ref.shape[0]
+    C, Dn = wuk_ref.shape[1:]
+    W, bs, QB = windows, block_size, q_rows
+    Tw = W * bs
+    lanes = buf.shape[-1]
+    layer = layer_ref[0]
+
+    mx_ref[...] = jnp.full(mx_ref.shape, NEG_INF, jnp.float32)
+    lx_ref[...] = jnp.zeros(lx_ref.shape, jnp.float32)
+    accx_ref[...] = jnp.zeros(accx_ref.shape, jnp.float32)
+
+    def walk(s):
+        q_start = cu_ref[s]
+        q_end = cu_ref[s + 1]
+        ctx = cl_ref[s]  # the span's last row reaches all of it
+        past = ctx - (q_end - q_start)
+        nwin = pl.cdiv(ctx, Tw)
+        b_hi = (q_end - 1) // QB + 1
+
+        def dma(slot, w, j):
+            return pltpu.make_async_copy(
+                kv_hbm.at[layer, bt_ref[s, w * W + j]], buf.at[slot, j],
+                sems.at[slot, j])
+
+        def each_block(w, do):
+            def body(j, _):
+                do(j)
+                return 0
+
+            jax.lax.fori_loop(
+                0, jnp.minimum(W, pl.cdiv(ctx - w * Tw, bs)), body, 0)
+
+        def issue(slot, w):
+            each_block(w, lambda j: dma(slot, w, j).start())
+
+        issue(0, 0)
+
+        def win_body(w, _):
+            slot = jax.lax.rem(w, 2)
+            k0 = w * Tw
+
+            @pl.when(w + 1 < nwin)
+            def _():
+                issue(1 - slot, w + 1)
+
+            each_block(w, lambda j: dma(slot, w, j).wait())
+
+            @pl.when(k0 + Tw > ctx)
+            def _():
+                # rows past the context were never fetched; they are
+                # expanded with the rest, and 0 x NaN = NaN
+                fetched = buf[slot, :W]
+                row = (jax.lax.broadcasted_iota(jnp.int32, fetched.shape, 0)
+                       * bs
+                       + jax.lax.broadcasted_iota(jnp.int32, fetched.shape, 1))
+                buf[slot, :W] = jnp.where(row < ctx - k0, fetched,
+                                          jnp.zeros_like(fetched))
+
+            rows = buf[slot, :W].reshape(Tw, lanes)
+            c = rows[:, :C]
+            kx_ref[:, Dn:] = rows[:, C:]  # the key every head shares
+            # the first query block a row of which reaches this window
+            b_lo = jnp.maximum(q_start, k0 - past + q_start) // QB
+
+            def head_body(h, _):
+                kx_ref[:, :Dn] = jnp.dot(
+                    c, wuk_ref[h], preferred_element_type=jnp.float32
+                ).astype(kx_ref.dtype)
+                vx_ref[...] = jnp.dot(
+                    c, wuv_ref[h], preferred_element_type=jnp.float32
+                ).astype(vx_ref.dtype)
+
+                def q_body(b, _):
+                    r0 = pl.multiple_of(b * QB, QB)
+                    rs = (h, pl.ds(r0, QB))
+                    sc = jax.lax.dot_general(
+                        qx_ref[(*rs, slice(None))], kx_ref[...],
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+                    first_pos = past + (r0 - q_start)
+                    # every row the span's, and sees the window whole
+                    interior = ((r0 >= q_start) & (r0 + QB <= q_end)
+                                & (k0 + Tw - 1 <= first_pos))
+
+                    @pl.when(interior)
+                    def _():
+                        _flash_update(mx_ref, lx_ref, accx_ref, rs, sc,
+                                      vx_ref[...], None)
+
+                    @pl.when(jnp.logical_not(interior))
+                    def _():
+                        g_idx = r0 + jax.lax.broadcasted_iota(
+                            jnp.int32, (QB, 1), 0)
+                        kvpos = k0 + jax.lax.broadcasted_iota(
+                            jnp.int32, (1, Tw), 1)
+                        valid = ((g_idx >= q_start) & (g_idx < q_end)
+                                 & (kvpos <= first_pos + g_idx - r0))
+                        _flash_update(mx_ref, lx_ref, accx_ref, rs, sc,
+                                      vx_ref[...], valid)
+
+                    return 0
+
+                jax.lax.fori_loop(b_lo, b_hi, q_body, 0)
+                return 0
+
+            jax.lax.fori_loop(0, HB, head_body, 0)
+            return 0
+
+        jax.lax.fori_loop(0, nwin, win_body, 0)
+
+    def span_body(s, _):
+        pl.when(cu_ref[s + 1] - cu_ref[s] >= expand_rows)(lambda: walk(s))
+        return 0
+
+    jax.lax.fori_loop(0, cl_ref.shape[0], span_body, 0)
+    # rows of no long span kept l = 0: they read 0 here, and the caller
+    # takes them from the absorbed output
+    ox_ref[...] = (accx_ref[...] / jnp.maximum(lx_ref[:, :, 0:1], 1e-30)
+                   ).astype(ox_ref.dtype)
+
+
+def _two_form_kernel(bt_ref, cu_ref, cl_ref, tfirst_ref, tcnt_ref, layer_ref,
+                     nlong_ref, tblock_ref, q_ref, kv_hbm, qx_ref, wuk_ref,
+                     wuv_ref, o_ref, ox_ref, buf, sems, m_ref, l_ref, acc_ref,
+                     mx_ref, lx_ref, accx_ref, kx_ref, vx_ref, *, tiles: int,
+                     absorbed: dict, expanded: dict):
+    """Grid steps [0, tiles): the stream's tiles, absorbed, short spans
+    only; the steps behind them: a block of heads each, the long spans
+    expanded. A tile whose rows are all a long span's has no block of its
+    own (``tblock_ref``): nothing of it is fetched, computed or written."""
+    t = pl.program_id(0)
+
+    @pl.when(tblock_ref[jnp.minimum(t, tiles - 1)] == t)
+    def _():
+        _absorbed_tile(t, bt_ref, cu_ref, cl_ref, tfirst_ref, tcnt_ref,
+                       layer_ref, q_ref, kv_hbm, o_ref, buf, sems, m_ref,
+                       l_ref, acc_ref, **absorbed)
+
+    @pl.when((t >= tiles) & (nlong_ref[0] > 0))
+    def _():
+        _expanded_heads(bt_ref, cu_ref, cl_ref, layer_ref,
+                        qx_ref, kv_hbm, wuk_ref, wuv_ref,
+                        ox_ref, buf, sems, mx_ref, lx_ref, accx_ref, kx_ref,
+                        vx_ref, **expanded)
+
+
 def latent_paged_attention_pallas(
     q: jnp.ndarray,  # (T, H, lanes) packed stream of absorbed queries
     kv_cache: jnp.ndarray,  # (L, N, bs, lanes)
@@ -213,13 +445,26 @@ def latent_paged_attention_pallas(
     layer_idx: jnp.ndarray | int = 0,
     *,
     value_dim: int,
+    expand: tuple | None = None,
     q_tile: int = Q_TILE,
     windows: int = WINDOWS,
+    expand_rows: int = EXPAND_ROWS,
+    expand_q_rows: int = EXPAND_Q_ROWS,
+    expand_windows: int = EXPAND_WINDOWS,
+    expand_heads: int = EXPAND_HEADS,
     interpret: bool = False,
-) -> jnp.ndarray:
+):
     """Returns (T, H, value_dim). A decode step calls it with one-token
     spans (``cu_q_lens = arange(S + 1)``; an idle slot's context 0 walks
-    nothing and reads zeros)."""
+    nothing and reads zeros).
+
+    ``expand``: (the heads' own queries (H, T, nope + lanes - value_dim),
+    each ``[q_nope; q_rope; zeros]`` as the pool's row is ``[c; r;
+    zeros]``; ``W_UK`` (H, value_dim, nope); ``W_UV`` (H, value_dim, v);
+    the score scale). Spans of ``expand_rows`` query rows or more are then
+    scored in the published form, and the call returns (the absorbed
+    output, whose rows of such spans are not computed; the expanded one
+    (H, T, v), of those rows; which rows of the stream they are (T,))."""
     T, H, lanes = q.shape
     L, N, bs, _ = kv_cache.shape
     TQ = min(q_tile, T)
@@ -229,44 +474,111 @@ def latent_paged_attention_pallas(
     nt = Tp // TQ
     R = TQ * H
 
-    tfirst, tcnt = tile_metadata(cu_q_lens, nt, TQ)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1, R, lanes), lambda t, *_: (t, 0, 0),
+    cu = jnp.asarray(cu_q_lens, jnp.int32)
+    tfirst, tcnt = tile_metadata(cu, nt, TQ)
+    prefetch = [jnp.asarray(block_tables, jnp.int32), cu,
+                jnp.asarray(context_lens, jnp.int32), tfirst, tcnt,
+                jnp.asarray(layer_idx, jnp.int32).reshape(1)]
+    absorbed = dict(block_size=bs, windows=windows, q_tile=TQ, heads=H,
+                    scale=lanes ** -0.5)
+
+    def tile(t, *scalars):
+        """The stream's tile of grid step t (behind the tiles: the last
+        one); the tile before, so that nothing moves, where every row of
+        it is a long span's (``tile_block``, the last scalar)."""
+        t = jnp.minimum(t, nt - 1)
+        return (t if expand is None else jnp.maximum(scalars[-1][t], 0),
+                0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, R, lanes), tile, memory_space=pltpu.VMEM),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    out_specs = [pl.BlockSpec((1, R, value_dim), tile,
+                              memory_space=pltpu.VMEM)]
+    out_shape = [jax.ShapeDtypeStruct((nt, R, value_dim), q.dtype)]
+    scratch = [
+        pltpu.VMEM((R, LANES), jnp.float32),
+        pltpu.VMEM((R, LANES), jnp.float32),
+        pltpu.VMEM((R, value_dim), jnp.float32),
+    ]
+    operands = [q.reshape(nt, R, lanes), kv_cache]
+    if expand is None:
+        kernel = functools.partial(_latent_kernel, **absorbed)
+        steps, buf_blocks = nt, windows
+    else:
+        qx, w_uk, w_uv, scale = expand
+        HB = expand_heads if H % expand_heads == 0 else 1
+        QB = min(expand_q_rows, T)
+        Tx = -(-T // QB) * QB
+        if Tx != T:
+            qx = jnp.pad(qx, ((0, 0), (0, Tx - T), (0, 0)))
+        DQ, Dv = qx.shape[-1], w_uv.shape[-1]
+        is_long = cu[1:] - cu[:-1] >= expand_rows
+        # a token's slot, as the kernel's walk has it, and whether its span
+        # is long; a tile's own block, or for a tile of such rows alone the
+        # last one before it that has one (-1: none has)
+        slot = jnp.clip(jnp.searchsorted(
+            cu, jnp.arange(Tp, dtype=jnp.int32), side="right",
+            method="compare_all") - 1, 0, cu.shape[0] - 2)
+        rows = is_long[slot] & (jnp.arange(Tp) < cu[-1])
+        tile_block = jax.lax.cummax(jnp.where(
+            rows.reshape(nt, TQ).all(axis=1), -1,
+            jnp.arange(nt, dtype=jnp.int32)))
+        prefetch += [jnp.sum(is_long, dtype=jnp.int32).reshape(1),
+                     tile_block]
+
+        def block(t, *scalars):
+            """The heads' block of grid step t (before them: the first
+            one; all through where no span is long, the last scalar but
+            one: nothing moves)."""
+            return (jnp.maximum(t - nt, 0) * (scalars[-2][0] > 0), 0, 0)
+
+        in_specs += [
+            pl.BlockSpec((HB, Tx, DQ), block, memory_space=pltpu.VMEM),
+            pl.BlockSpec((HB, *w_uk.shape[1:]), block,
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, R, value_dim), lambda t, *_: (t, 0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, windows, bs, lanes), kv_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, windows)),
-            pltpu.VMEM((R, LANES), jnp.float32),
-            pltpu.VMEM((R, LANES), jnp.float32),
-            pltpu.VMEM((R, value_dim), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _latent_kernel, block_size=bs, windows=windows, q_tile=TQ, heads=H,
-        scale=lanes ** -0.5)
+            pl.BlockSpec((HB, *w_uv.shape[1:]), block,
+                         memory_space=pltpu.VMEM),
+        ]
+        out_specs.append(pl.BlockSpec((HB, Tx, Dv), block,
+                                      memory_space=pltpu.VMEM))
+        out_shape.append(jax.ShapeDtypeStruct((H, Tx, Dv), q.dtype))
+        scratch += [
+            pltpu.VMEM((HB, Tx, LANES), jnp.float32),
+            pltpu.VMEM((HB, Tx, LANES), jnp.float32),
+            pltpu.VMEM((HB, Tx, Dv), jnp.float32),
+            pltpu.VMEM((expand_windows * bs, DQ), kv_cache.dtype),
+            pltpu.VMEM((expand_windows * bs, Dv), kv_cache.dtype),
+        ]
+        operands += [qx, w_uk, w_uv]
+        kernel = functools.partial(
+            _two_form_kernel, tiles=nt,
+            absorbed=dict(absorbed, expand_rows=expand_rows),
+            expanded=dict(block_size=bs, windows=expand_windows, q_rows=QB,
+                          expand_rows=expand_rows, scale=scale))
+        steps, buf_blocks = nt + H // HB, max(windows, expand_windows)
+
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((nt, R, value_dim), q.dtype),
-        grid_spec=grid_spec,
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(steps,),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((2, buf_blocks, bs, lanes), kv_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, buf_blocks)),
+                *scratch,
+            ],
+        ),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         name="latent_paged_attention",
-    )(
-        jnp.asarray(block_tables, jnp.int32),
-        jnp.asarray(cu_q_lens, jnp.int32),
-        jnp.asarray(context_lens, jnp.int32),
-        tfirst,
-        tcnt,
-        jnp.asarray(layer_idx, jnp.int32).reshape(1),
-        q.reshape(nt, R, lanes),
-        kv_cache,
-    )
-    return out.reshape(Tp, H, value_dim)[:T]
+    )(*prefetch, *operands)
+    o_lat = out[0].reshape(Tp, H, value_dim)[:T]
+    if expand is None:
+        return o_lat
+    return o_lat, out[1][:, :T], rows[:T]

@@ -446,6 +446,10 @@ def test_ragged_kernel_compiles_at_the_cells_geometries(one_chip, kh, g):
 
 LATENT_POOL = ((5, 4096, BS, 640), jnp.bfloat16)
 LATENT_VMEM_MIB = 18.98
+# with the expanded form's blocks beside the absorbed tile's: four heads'
+# queries, outputs and flash state for the whole 2048-row stream, a
+# 512-row window of the pool twice and its expansion for one head
+LATENT_TWO_FORM_VMEM_MIB = 42.64
 
 
 def _latent():
@@ -460,39 +464,64 @@ def _latent():
 LATENT_POOL_H32 = ((7, 12288, BS, 640), jnp.bfloat16)
 
 
-@pytest.mark.parametrize("tokens,heads", [
-    (2048, 128), (64, 128), (2048, 32), (512, 32), (64, 32)])
+def _latent_shapes(tokens, heads, expand):
+    """The call's arguments at a cell's shapes: the ragged program's
+    (``expand``: the heads' own 128 + 64 + 64-wide queries, head-major,
+    ``W_UK``, ``W_UV``) or the decode program's."""
+    shapes = [((tokens, heads, 640), jnp.bfloat16),
+              LATENT_POOL if heads == 128 else LATENT_POOL_H32,
+              ((64, 576 if heads == 128 else 512), I32), ((65,), I32),
+              ((64,), I32)]
+    if expand:
+        shapes += [((heads, tokens, 256), jnp.bfloat16),
+                   ((heads, 512, 128), jnp.bfloat16),
+                   ((heads, 512, 128), jnp.bfloat16)]
+    return shapes
+
+
+def _latent_call(q, c, bt, cu, cl, *expand):
+    return _latent().latent_paged_attention_pallas(
+        q, c, bt, cu, cl, layer_idx=1, value_dim=512,
+        expand=(*expand, 192 ** -0.5) if expand else None)
+
+
+@pytest.mark.parametrize("tokens,heads,expand", [
+    (2048, 128, True), (512, 128, True), (64, 128, False),
+    (2048, 32, True), (512, 32, True), (64, 32, False)])
 def test_latent_kernel_is_a_named_custom_call_at_the_cells_shapes(
-        one_chip, tokens, heads):
-    k = _latent()
-    text = _compiled_text(
-        lambda q, c, bt, cu, cl: k.latent_paged_attention_pallas(
-            q, c, bt, cu, cl, layer_idx=1, value_dim=512),
-        one_chip, ((tokens, heads, 640), jnp.bfloat16),
-        LATENT_POOL if heads == 128 else LATENT_POOL_H32,
-        ((64, 576 if heads == 128 else 512), I32), ((65,), I32),
-        ((64,), I32))
-    assert re.search(r"^\s*(?:ROOT )?%latent_paged_attention[.\d]* = "
-                     r".*? custom-call\(", text, flags=re.M)
+        one_chip, tokens, heads, expand):
+    """The ragged program's call (both stream widths) holds its two forms
+    in ONE custom call under the one name the trace reductions match on:
+    a second call, under this name or another, would halve or hide the
+    time ``mla_attn_roofline_pct`` divides by. The decode program's call
+    is the absorbed kernel alone, one output, as before."""
+    text = _compiled_text(_latent_call, one_chip,
+                          *_latent_shapes(tokens, heads, expand))
+    calls = re.findall(
+        r"^\s*(?:ROOT )?(%[\w.-]+) = (\(?).*? custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\"", text, flags=re.M)
+    assert len(calls) == 1 and re.fullmatch(
+        r"%latent_paged_attention[.\d]*", calls[0][0]), calls
+    assert (calls[0][1] == "(") == expand  # two outputs, or the one
 
 
-def test_latent_kernel_asks_for_its_own_scoped_vmem(one_chip, monkeypatch):
+@pytest.mark.parametrize("expand,mib", [(False, LATENT_VMEM_MIB),
+                                        (True, LATENT_TWO_FORM_VMEM_MIB)])
+def test_latent_kernel_asks_for_its_own_scoped_vmem(one_chip, monkeypatch,
+                                                    expand, mib):
     """No libtpu flag in the configuration's manifest: the call carries
     its limit. What it asks: the compiler names the size where the limit
     is short."""
     k = _latent()
-    assert k.VMEM_LIMIT_BYTES >= LATENT_VMEM_MIB * 2 ** 20
-    monkeypatch.setattr(k, "VMEM_LIMIT_BYTES",
-                        int((LATENT_VMEM_MIB - 0.5) * 2 ** 20))
+    assert k.VMEM_LIMIT_BYTES >= mib * 2 ** 20
+    monkeypatch.setattr(k, "VMEM_LIMIT_BYTES", int((mib - 0.5) * 2 ** 20))
     with pytest.raises(Exception, match="Scoped allocation") as refusal:
-        _compiled_text(
-            lambda q, c, bt, cu, cl: k.latent_paged_attention_pallas(
-                q, c, bt, cu, cl, layer_idx=1, value_dim=512),
-            one_chip, ((2048, 128, 640), jnp.bfloat16), LATENT_POOL,
-            ((64, 576), I32), ((65,), I32), ((64,), I32))
+        # a function of its own: jit would hand back the other test's trace
+        _compiled_text(lambda *a: _latent_call(*a), one_chip,
+                       *_latent_shapes(2048, 128, expand))
     size = re.search(r"Scoped allocation with size ([\d.]+)M",
                      str(refusal.value))
-    assert size and float(size.group(1)) <= LATENT_VMEM_MIB
+    assert size and float(size.group(1)) <= mib
 
 
 @pytest.mark.parametrize("lanes,in_place,in_pytree", [

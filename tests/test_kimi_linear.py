@@ -180,11 +180,17 @@ def test_served_logprobs_match_the_reference(served, name):
     assert len(toks) == STEPS and err.max() < LOGPROB_TOL, err
 
 
+@pytest.mark.parametrize("how", [
+    {}, dict(expand_rows=16, expand_q_rows=8, expand_windows=2,
+             expand_heads=2)], ids=["absorbed", "long-spans-expanded"])
 def test_served_through_the_kernels_matches_the_reference(served,
-                                                          monkeypatch):
+                                                          monkeypatch, how):
     """The same engine with the Pallas kernels (interpreted) in both step
     programs: the latent attention kernel over the pool that rides the
-    cache pytree, the KDA span and decode kernels over the slot state."""
+    cache pytree, the KDA span and decode kernels over the slot state.
+    With the latent kernel's crossover at 16 rows (its own is 256: nothing
+    here is that long) the prompt's chunks of 16 tokens or more are scored
+    in the published form, here without a rotated part."""
     from production_stack_tpu.ops import kda_pallas
     from production_stack_tpu.ops import latent_paged_attention_pallas as lat
 
@@ -192,7 +198,7 @@ def test_served_through_the_kernels_matches_the_reference(served,
     monkeypatch.setattr(
         lat, "latent_paged_attention_pallas",
         functools.partial(lat.latent_paged_attention_pallas,
-                          interpret=True, q_tile=4, windows=2))
+                          interpret=True, q_tile=4, windows=2, **how))
     for name in ("kda_ragged", "kda_decode_step"):
         monkeypatch.setattr(kda_pallas, name, functools.partial(
             getattr(kda_pallas, name), interpret=True))

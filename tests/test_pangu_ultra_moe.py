@@ -141,23 +141,52 @@ def test_served_logprobs_match_the_reference(served, name):
     assert len(toks) == STEPS and err.max() < LOGPROB_TOL, err
 
 
-def test_served_through_the_kernel_matches_the_reference(served, monkeypatch):
+# the expanded body at toy size: a span of 16 rows or more (the long
+# prompt's chunks of 32 or 25 with the short prompt beside them, not its
+# last 6 or 13, nor the 7-token prompt) in query blocks of 8, windows of
+# two blocks, two heads a step
+EXPANDED_AT_16 = dict(expand_rows=16, expand_q_rows=8, expand_windows=2,
+                      expand_heads=2)
+
+
+@pytest.mark.parametrize("how", [{}, EXPANDED_AT_16],
+                         ids=["absorbed", "long-spans-expanded"])
+def test_served_through_the_kernel_matches_the_reference(served, monkeypatch,
+                                                         how):
     """The same engine with the Pallas kernel (interpreted) in both step
     programs: the ragged stream's chunks and the decode step's one-token
-    spans."""
+    spans; with the crossover at the kernel's own 256 rows nothing here is
+    long, with it at 16 the prompt's chunks are scored in the published
+    form, the later ones over the earlier ones' rows, then over a cached
+    prefix."""
     first, _ = served
-    monkeypatch.setattr(
-        kernel, "latent_paged_attention_pallas",
-        functools.partial(kernel.latent_paged_attention_pallas,
-                          interpret=True, q_tile=4, windows=2))
+    traced = []  # (stream rows, given the heads' own queries) a trace
+
+    def call(q, *args, expand=None, **kw):
+        traced.append((q.shape[0], expand is not None))
+        return kernel_call(q, *args, expand=expand, interpret=True,
+                           q_tile=4, windows=2, **how, **kw)
+
+    kernel_call = kernel.latent_paged_attention_pallas
+    monkeypatch.setattr(kernel, "latent_paged_attention_pallas", call)
     eng = engine(params=first.runner.params)
     eng.runner.use_pallas = True  # read where the programs are traced
     prompts = {"long": PROMPTS["long"], "short": PROMPTS["short"]}
     out = serve(eng, prompts, max_tokens=6)
+    # the ragged program hands the kernel both forms' inputs, the decode
+    # program (a row a slot) the absorbed one's alone
+    assert {rows for rows, both in traced if not both} == {4}
+    assert {rows for rows, both in traced if both} == {BUDGET}
     for name, (toks, lps) in out.items():
         err = errors(HF, eng.runner.params, prompts[name], toks, lps)
         assert len(toks) == 6 and err.max() < LOGPROB_TOL, (name, err)
         assert toks == served[1][name][0][:6]
+    if how:  # four cached blocks, then a 25-row span over them
+        prompt = PROMPTS["long"][:64] + _ids(7, 25)
+        toks, lps = serve(eng, {"again": prompt})["again"]
+        assert eng.stats()["gpu_prefix_cache_hits_total"] == 4
+        err = errors(HF, eng.runner.params, prompt, toks, lps)
+        assert err.max() < LOGPROB_TOL, err
 
 
 def test_a_shared_prefix_hits_the_cache_and_changes_nothing(served):
@@ -184,7 +213,7 @@ def test_absorbed_scoring_equals_expanded_scoring(params):
     x = jax.random.normal(jax.random.PRNGKey(5), (1, 40, cfg.hidden_size))
     pos = jnp.arange(40, dtype=jnp.int32)[None]
 
-    def attend(q, k, v, caches, layer_idx):
+    def attend(q, k, v, caches, layer_idx, **_):
         return llama.dense_causal_attention(q, k, v), caches
 
     got, _ = llama._mla_mixer(cfg, lp, x, pos, attend, None, 0)
@@ -197,21 +226,25 @@ def test_absorbed_scoring_equals_expanded_scoring(params):
 
 # -- the kernel against its XLA form -------------------------------------------
 
-def _stream(seed=0):
+MIXED = ([37, 20, 1, 0, 3, 1], [37, 70, 33, 0, 50, 128])
+
+
+def _stream(seed=0, spans=MIXED, T=80, H=4):
     """Mixed spans in one stream: a fresh chunk, a chunk that continues a
     context, a decode row, an idle slot, a three-token span, a decode row
-    deep in its context; rows of padding behind the last span."""
+    deep in its context; rows of padding behind the last span. ``spans``:
+    (query rows, context) a slot, of another stream."""
     rng = np.random.default_rng(seed)
-    H, width, lanes, V, bs = 4, 48, 128, 32, 16
-    L, N, S, M, T = 2, 64, 6, 8, 80
-    q_lens, ctxs = [37, 20, 1, 0, 3, 1], [37, 70, 33, 0, 50, 128]
+    width, lanes, V, bs = 48, 128, 32, 16
+    q_lens, ctxs = spans
+    L, N, S, M = 2, 64, len(q_lens), 8
     cu = np.concatenate([[0], np.cumsum(q_lens)]).astype(np.int32)
     bt = np.zeros((S, M), np.int32)
-    perm = rng.permutation(N - 1)[:S * M].reshape(S, M) + 1
+    free = iter(rng.permutation(N - 1) + 1)
     seq_ids, pos = np.zeros(T, np.int32), -np.ones(T, np.int32)
     for s in range(S):
         nb = -(-ctxs[s] // bs)
-        bt[s, :nb] = perm[s, :nb]
+        bt[s, :nb] = [next(free) for _ in range(nb)]
         for i in range(q_lens[s]):
             seq_ids[cu[s] + i] = s
             pos[cu[s] + i] = ctxs[s] - q_lens[s] + i
@@ -231,6 +264,85 @@ def test_the_kernel_equals_its_xla_form(q_tile, windows):
     live = pos >= 0
     np.testing.assert_allclose(got[live], want[live], atol=3e-6)
     assert float(jnp.abs(got[~live]).max()) == 0.0  # padding reads zeros
+
+
+def _published_form(q_nope, q_rope, w_uk, w_uv, layer, bt, ctx, seq_ids, pos,
+                    C, rope, scale):
+    """A token's output a head, (T, H, v), as the model is published:
+    head h's key ``[W_UK_h c; r]``, its value ``W_UV_h c``; numpy,
+    float64, a token at a time."""
+    layer, bt = np.asarray(layer, np.float64), np.asarray(bt)
+    q_nope, q_rope, w_uk, w_uv = (np.asarray(a, np.float64)
+                                  for a in (q_nope, q_rope, w_uk, w_uv))
+    out = np.zeros((*q_nope.shape[:2], w_uv.shape[-1]))
+    for t in np.flatnonzero(pos >= 0):
+        rows = layer[bt[seq_ids[t]]].reshape(-1, layer.shape[-1])[
+            :min(ctx[seq_ids[t]], pos[t] + 1)]
+        c, r = rows[:, :C], rows[:, C:C + rope]
+        keys = np.einsum("rc,hcd->hrd", c, w_uk)
+        sc = (np.einsum("hd,hrd->hr", q_nope[t], keys)
+              + q_rope[t] @ r.T) * scale
+        w = np.exp(sc - sc.max(-1, keepdims=True))
+        w /= w.sum(-1, keepdims=True)
+        out[t] = np.einsum("hr,rc,hcd->hd", w, c, w_uv)
+    return out
+
+
+# (what the stream holds, (query rows, context) a slot, stream width): the
+# crossover at 16 rows, query blocks of 8, windows of two blocks (32
+# rows), so a block of a long span's rows is skipped above the diagonal,
+# masked on it and unmasked below it
+TWO_FORM_STREAMS = {
+    "one long span alone": (([64], [64]), 64),
+    "a later chunk of its prompt": (([40], [120]), 48),
+    "a chunk behind a cached prefix of whole blocks": (([25], [89]), 32),
+    "two long spans": (([30, 34], [30, 100]), 64),
+    "long, decode rows and short in one stream": (MIXED, 80),
+    "one row under the crossover and one at it": (
+        ([15, 16, 1], [15, 16, 99]), 32),
+    "a span that ends mid-window and mid-block": (([37, 2], [93, 45]), 48),
+}
+
+
+@pytest.mark.parametrize("heads,rope", [(4, 16), (32, 0)],
+                         ids=["rope", "32-heads-no-rope"])
+@pytest.mark.parametrize("name", sorted(TWO_FORM_STREAMS))
+def test_the_two_forms_in_one_call_equal_the_xla_form(name, heads, rope):
+    """Spans at or over the crossover scored in the published form, the
+    others absorbed, in one call, against the absorbed XLA form and
+    against the published form computed plainly. ``rope`` 0: Kimi-Linear's
+    latent attention, whose rows end with the latent."""
+    spans, T = TWO_FORM_STREAMS[name]
+    _, pool, bt, cu, ctx, seq_ids, pos, C = _stream(spans=spans, T=T)
+    lanes, nope, v = pool.shape[-1], 24, 40
+    pool = pool.at[..., C + rope:].set(0)
+    k = jax.random.split(jax.random.PRNGKey(1), 4)
+    q_nope = jax.random.normal(k[0], (T, heads, nope))
+    q_rope = jax.random.normal(k[1], (T, heads, lanes - C)).at[
+        ..., rope:].set(0)
+    w_uk = jax.random.normal(k[2], (heads, C, nope)) * C ** -0.5
+    w_uv = jax.random.normal(k[3], (heads, C, v)) * C ** -0.5
+    scale = (nope + rope) ** -0.5
+    q = jnp.concatenate([jnp.einsum("thd,hcd->thc", q_nope, w_uk), q_rope],
+                        axis=-1) * scale * lanes ** 0.5
+    o_lat, o_own, scored = kernel.latent_paged_attention_pallas(
+        q, pool, bt, cu, ctx, 1, value_dim=C,
+        expand=(jnp.concatenate([q_nope, q_rope], -1).swapaxes(0, 1), w_uk,
+                w_uv, scale),
+        q_tile=4, windows=2, interpret=True, **EXPANDED_AT_16)
+    q_lens = np.diff(cu)
+    assert (np.asarray(scored) == ((q_lens[seq_ids] >= 16) & (pos >= 0))).all()
+    assert scored.sum() > 0
+    got = jnp.where(scored[:, None, None], o_own.swapaxes(0, 1),
+                    jnp.einsum("thc,hcd->thd", o_lat, w_uv))
+    live = pos >= 0
+    absorbed = jnp.einsum("thc,hcd->thd", latent_ragged_paged_attention(
+        q, pool[1], bt, ctx, seq_ids, pos, C), w_uv)
+    np.testing.assert_allclose(got[live], absorbed[live], atol=3e-6)
+    published = _published_form(q_nope, q_rope[..., :rope], w_uk, w_uv,
+                                pool[1], bt, ctx, seq_ids, pos, C, rope, scale)
+    np.testing.assert_allclose(got[live], published[live], atol=3e-6)
+    assert float(jnp.abs(got[~live]).max(initial=0.0)) == 0.0
 
 
 # -- the cache kind ------------------------------------------------------------
@@ -302,7 +414,7 @@ def test_the_leading_dense_layer_routes_nothing(served):
     toks = jnp.asarray([PROMPTS["mid"]])
     pos = jnp.arange(toks.shape[1], dtype=jnp.int32)[None]
 
-    def attend(q, k, v, caches, layer_idx):
+    def attend(q, k, v, caches, layer_idx, **_):
         return llama.dense_causal_attention(q, k, v), caches
 
     _, _, hist = llama.forward_tokens(cfg, p, toks, pos, attend, None,
@@ -337,8 +449,9 @@ def _faulty_mixer(fault, cfg, lp, x, positions, attend, caches, cache_layer):
         finally:
             llama.rms_norm = real
     elif fault == "the value read over all the row's lanes":
-        def leaky(q, k, v, c, i):  # the rotated key's lanes join the value
-            out, c = attend(q, k, k[..., :cfg.latent_width], c, i)
+        # the rotated key's lanes join the value
+        def leaky(q, k, v, c, i, **kw):
+            out, c = attend(q, k, k[..., :cfg.latent_width], c, i, **kw)
             extra = out[..., cfg.kv_lora_rank:]
             return out[..., :cfg.kv_lora_rank].at[
                 ..., :extra.shape[-1]].add(extra), c
@@ -537,22 +650,30 @@ def test_a_checkpoint_is_refused(tmp_path):
 
 # -- counters --------------------------------------------------------------------
 
-def test_latent_counters_count_pairs_from_spans():
-    c = LatentCounters(cache_layers=5, kv_bytes_per_token=6400)
+@pytest.mark.parametrize("expand_rows,expanded", [
+    (None, 0),  # the XLA path: nothing is scored expanded
+    (5, 0), (4, 4 * 5 // 2), (3, 4 * 5 // 2 + 3 * 10 + 3 * 4 // 2)])
+def test_latent_counters_count_pairs_from_spans(expand_rows, expanded):
+    c = LatentCounters(cache_layers=5, kv_bytes_per_token=6400,
+                       expand_rows=expand_rows)
     # a fresh 4-token chunk, a 3-token chunk that continues 10, a decode
     # row at context 8, an idle slot
     c.record("ragged", [4, 3, 1, 0], [4, 13, 8, 99])
     pairs = (4 * 5 // 2) + (3 * 10 + 3 * 4 // 2) + 8
     assert c.scored_pairs == {"ragged": 5 * pairs, "decode": 0}
+    # the spans at or over the crossover, their pairs whole
+    assert c.expanded_pairs == {"ragged": 5 * expanded, "decode": 0}
     assert c.query_tokens["ragged"] == 5 * 8
     assert c.context_rows["ragged"] == 5 * (4 + 13 + 8)
     # two live slots, three fused iterations: contexts grow by one each
     c.record("decode", [1, 0, 1], [7, 0, 20], iterations=3)
     assert c.scored_pairs["decode"] == 5 * (27 + 29 + 31)
     assert c.query_tokens["decode"] == 5 * 2 * 3
+    assert c.expanded_pairs["decode"] == 0  # one-token spans never are
     snap = c.snapshot()
     assert snap["kv_bytes_per_token"] == 6400
     assert snap["mla_scored_pairs_total"]["ragged"] == 5 * pairs
+    assert snap["mla_expanded_pairs_total"] == c.expanded_pairs
 
 
 def test_the_counters_move_in_both_step_kinds_and_are_exported(served):
@@ -572,6 +693,77 @@ def test_the_counters_move_in_both_step_kinds_and_are_exported(served):
         for m in EngineStatsCollector(eng, "tiny-pangu").collect()
         for sample in m.samples)
     for name in ("vllm:mla_query_tokens_total", "vllm:mla_scored_pairs_total",
+                 "vllm:mla_expanded_pairs_total",
                  "vllm:mla_context_rows_total", "vllm:kv_bytes_per_token"):
         assert name in text
     assert "'kind': 'ragged'" in text and "'kind': 'decode'" in text
+    # the CPU's ragged program is the XLA form: absorbed throughout
+    assert eng.latent.expand_rows is None
+    assert s["mla_expanded_pairs_total"] == {"ragged": 0, "decode": 0}
+
+
+def test_the_engine_counts_the_pairs_of_its_long_spans(served, monkeypatch):
+    """Where the runner's ragged program is the kernel's, the engine counts
+    by the kernel's own crossover: the long prompt's chunks of 32 tokens
+    over a crossover moved to 16 rows are counted whole, its last 6-token
+    chunk and the decode dispatches not at all; and the benchmark's metric
+    is the share of the two counters."""
+    from production_stack_tpu.engine import model_runner
+
+    first, _ = served
+    monkeypatch.setattr(kernel, "EXPAND_ROWS", 16)
+    monkeypatch.setattr(
+        kernel, "latent_paged_attention_pallas",
+        functools.partial(kernel.latent_paged_attention_pallas,
+                          interpret=True, q_tile=4, windows=2,
+                          **EXPANDED_AT_16))
+    monkeypatch.setattr(model_runner, "_pallas_ok", lambda *a: True)
+    eng = engine(params=first.runner.params)
+    assert eng.latent.expand_rows == 16
+    serve(eng, {"long": PROMPTS["long"]}, max_tokens=3)
+    s = eng.stats()
+    chunks = 32 * 33 // 2 + (32 * 32 + 32 * 33 // 2)  # the first two of 70
+    assert s["mla_expanded_pairs_total"] == {"ragged": 3 * chunks,
+                                             "decode": 0}
+    assert (s["mla_scored_pairs_total"]["ragged"]
+            >= 3 * (chunks + 6 * 64 + 6 * 7 // 2))
+    with open(os.path.join(ROOT, "chipbench", "layer_metrics",
+                           "mla_expanded_pairs_pct.json")) as f:
+        spec = json.load(f)
+    assert (spec["reader"], spec["scale"], spec["num"], spec["den"]) == (
+        "prom_ratio", 100.0, ["vllm:mla_expanded_pairs_total"],
+        ["vllm:mla_scored_pairs_total"])
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = [m for m in json.load(f)["per_layer"]
+                 if m["name"] == "mla_expanded_pairs_pct"]
+    assert entry == [{
+        "name": "mla_expanded_pairs_pct", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "tpot_p50_ms", "workloads": [
+            "openpangu-ultra-moe-718b-ep16-l5.long-prompt",
+            "kimi-linear-48b-a3b-ep16.long-decode"]}]
+
+
+@pytest.mark.parametrize("expanded,want", [
+    ((0.0, 0.0), 0.0),       # nothing long: 0, not nothing
+    ((600.0, 0.0), 50.0),    # of ALL pairs, the decode dispatches' too
+    (None, None),            # a program without the counter: left out
+])
+def test_the_benchmark_reads_the_expanded_share_of_all_pairs(expanded, want):
+    import types
+
+    from chipbench import layers, prom
+
+    def scrape(scored, expanded):
+        lines = [f'vllm:mla_scored_pairs_total{{model_name="m",kind="{k}"}} '
+                 f'{v}' for k, v in zip(("ragged", "decode"), scored)]
+        if expanded is not None:
+            lines += [
+                f'vllm:mla_expanded_pairs_total{{model_name="m",kind="{k}"}}'
+                f' {v}' for k, v in zip(("ragged", "decode"), expanded)]
+        return prom.parse("\n".join(lines) + "\n")
+
+    ctx = types.SimpleNamespace(
+        prom_open=scrape((100.0, 50.0), expanded and (0.0, 0.0)),
+        prom_close=scrape((900.0, 450.0), expanded), manifest={})
+    assert layers.read("mla_expanded_pairs_pct", ctx) == want
