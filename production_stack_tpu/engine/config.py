@@ -40,6 +40,7 @@ class ModelConfig:
     name: str = "tiny-llama"
     # "llama" | "mixtral" | "olmoe" | "gemma" | "gemma2" | "phi3" | "ouro"
     # | "solar_open2" | "pangu_ultra_moe" | "phi4flash" | "kimi_linear"
+    # | "falcon_h1"
     # — Mistral and Qwen run as "llama" (their deltas are knobs:
     # sliding_window, qkv_bias, qk_norm); "phi3" differs only in its fused
     # HF weight layout, "mixtral" and "olmoe" in their HF tensor names
@@ -51,7 +52,9 @@ class ModelConfig:
     # > 0: state-space, window, full and cross-attention layers, gated
     # memory units), differential attention and LayerNorm, "kimi_linear" in
     # KDA and latent-attention layers in one stack (mla_layers) behind a
-    # leading dense layer
+    # leading dense layer, "falcon_h1" in a state-space mixer with heads
+    # and grouped-query attention side by side in every layer (ssd_heads
+    # > 0) and the family's multipliers
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -123,6 +126,33 @@ class ModelConfig:
     mamba_conv: int = 4      # width of the causal depthwise convolution
     mamba_expand: int = 2    # inner width over hidden_size
     mamba_dt_rank: int = 0   # low-rank width of the Delta projection
+    # the parallel hybrid stack ("falcon_h1"): EVERY layer runs a Mamba-2
+    # state-space mixer with heads (SSD; ops/ssd.py) and rotated
+    # grouped-query attention on the same normed row and adds the two
+    # (``layer_kinds``: "parallel"), so every layer owns a cache layer AND
+    # a per-slot state: (ssd_heads, ssd_state, ssd_head_dim) float32, one
+    # scalar decay a head and token, B and C shared by the heads of a
+    # group, beside a conv tail over ssd_conv_dim channels. 0 = no such
+    # stack
+    ssd_heads: int = 0
+    ssd_head_dim: int = 0    # P, channels a head
+    ssd_state: int = 0       # N, values of state a channel
+    ssd_groups: int = 1      # groups of heads that share B and C
+    ssd_conv: int = 4        # width of the causal depthwise convolution
+    # the family's published scalars (muP multipliers), each applied where
+    # the published code applies it; they come from the file and are 1 for
+    # every other family. ssd_multipliers scales the sections [z | x | B |
+    # C | dt] of the state-space mixer's input projection
+    ssd_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssd_in_multiplier: float = 1.0
+    ssd_out_multiplier: float = 1.0
+    attn_in_multiplier: float = 1.0
+    attn_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    mlp_gate_multiplier: float = 1.0
+    mlp_down_multiplier: float = 1.0
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
     # differential attention: query and key heads pair up (2i, 2i + 1),
     # a pair's two softmaxes weigh the pair's values (2 x head_dim wide)
     # and the second is subtracted, lambda times; then RMSNorm and W_o.
@@ -260,8 +290,12 @@ class ModelConfig:
         """What each layer's token mixer is, the one description of a
         stack's pattern: "attn" (every layer of the shared stack); "gqa" /
         "kda" (attn_period); "mla" / "kda" (mla_layers); "mamba", "swa"
-        (window attention), "full", "gmu" and "cross" (mamba_period)."""
+        (window attention), "full", "gmu" and "cross" (mamba_period);
+        "parallel" (ssd_heads: a state-space mixer AND attention, counted
+        among the recurrent layers and among the attention layers)."""
         n = self.num_layers
+        if self.ssd_heads:
+            return ("parallel",) * n
         if self.mla_layers:
             return tuple("mla" if l in self.mla_layers else "kda"
                          for l in range(n))
@@ -286,7 +320,7 @@ class ModelConfig:
         period that holds a leading dense layer is like no other and a run
         of its own; the last period may be short."""
         kinds = self.layer_kinds
-        period = self.mamba_period or self.attn_period
+        period = self.mamba_period or self.attn_period or 1
         runs = []
         for i in range(0, len(kinds), period):
             p = kinds[i:i + period]
@@ -303,7 +337,7 @@ class ModelConfig:
 
     @property
     def num_recurrent_layers(self) -> int:
-        return self.count_layers("kda", "mamba")
+        return self.count_layers("kda", "mamba", "parallel")
 
     @property
     def window_binds(self) -> bool:
@@ -314,6 +348,16 @@ class ModelConfig:
     @property
     def mamba_inner(self) -> int:
         return self.mamba_expand * self.hidden_size
+
+    @property
+    def ssd_inner(self) -> int:
+        """Channels of the state-space mixer with heads (``mamba_d_ssm``)."""
+        return self.ssd_heads * self.ssd_head_dim
+
+    @property
+    def ssd_conv_dim(self) -> int:
+        """Channels its convolution runs over: [x | B | C]."""
+        return self.ssd_inner + 2 * self.ssd_groups * self.ssd_state
 
     @property
     def is_latent(self) -> bool:
@@ -341,7 +385,8 @@ class ModelConfig:
     def num_attn_layers(self) -> int:
         """Attention layers that own keys and values (a cross-attention
         layer reads another's)."""
-        return self.count_layers("attn", "gqa", "mla", "swa", "full")
+        return self.count_layers("attn", "gqa", "mla", "swa", "full",
+                                 "parallel")
 
     @property
     def num_kda_layers(self) -> int:
@@ -388,14 +433,18 @@ class ModelConfig:
     def recurrent_state_bytes(self, slots: int) -> int:
         """What the recurrent layers keep for ``slots`` decode slots: a
         float32 state and a conv tail in the model dtype, a KDA layer's per
-        head, a state-space layer's per channel."""
+        head, a state-space layer's per channel (with heads: per head and
+        channel, the tail over [x | B | C])."""
         item = jnp.dtype(self.jax_dtype).itemsize
         h, d = self.kda_heads, self.kda_head_dim
         kda = h * d * d * 4 + (self.kda_conv - 1) * 3 * h * d * item
         mamba = self.mamba_inner * (self.mamba_state * 4
                                     + (self.mamba_conv - 1) * item)
+        ssd = (self.ssd_inner * self.ssd_state * 4
+               + (self.ssd_conv - 1) * self.ssd_conv_dim * item)
         return slots * (self.num_kda_layers * kda
-                        + self.count_layers("mamba") * mamba)
+                        + self.count_layers("mamba") * mamba
+                        + self.count_layers("parallel") * ssd)
 
     @staticmethod
     def from_hf_config(cfg: dict[str, Any], name: str = "") -> "ModelConfig":
@@ -447,6 +496,8 @@ class ModelConfig:
             return ModelConfig._phi4flash_from_hf(cfg, name)
         elif cfg.get("model_type") == "kimi_linear":
             return ModelConfig._kimi_linear_from_hf(cfg, name)
+        elif cfg.get("model_type") == "falcon_h1":
+            return ModelConfig._falcon_h1_from_hf(cfg, name)
         elif any("Phi3" in a for a in archs):
             # only the standard Phi-3 maps onto the fused-Llama layout;
             # Phi-3-small (query_key_value naming, gegelu, blocksparse)
@@ -868,6 +919,87 @@ class ModelConfig:
             mamba_dt_rank=int(cfg.get("mamba_dt_rank") or -(-hidden // 16)),
             diff_attn=True,
             layer_norm=True,
+        )
+
+    @staticmethod
+    def _falcon_h1_from_hf(cfg: dict, name: str = "") -> "ModelConfig":
+        """``model_type: falcon_h1``: see ``ssd_heads``. The file states
+        every width, the state-space sizes and the fourteen scalars. What
+        is not computed is refused by name."""
+        what = "falcon_h1"
+        hidden = int(cfg["hidden_size"])
+        heads, p = int(cfg["mamba_n_heads"]), int(cfg["mamba_d_head"])
+        groups = int(cfg.get("mamba_n_groups", 1))
+        d_ssm = cfg.get("mamba_d_ssm")
+        d_ssm = (int(cfg.get("mamba_expand", 2) * hidden) if d_ssm is None
+                 else int(d_ssm))
+        refused = [
+            why for bad, why in (
+                (not cfg.get("mamba_use_mlp", True),
+                 "mamba_use_mlp: false (a layer without its MLP)"),
+                (cfg.get("mamba_norm_before_gate", False),
+                 "mamba_norm_before_gate: true (only the gate before the "
+                 "grouped norm)"),
+                (not cfg.get("mamba_rms_norm", True),
+                 "mamba_rms_norm: false (only the gated grouped RMSNorm)"),
+                (cfg.get("attn_layer_indices") is not None,
+                 f"attn_layer_indices={cfg.get('attn_layer_indices')!r} "
+                 "(only attention in every layer: null)"),
+                (cfg.get("rope_scaling") is not None,
+                 f"rope_scaling={cfg.get('rope_scaling')!r} (only null)"),
+                *((bool(cfg.get(k)), f"{k}: true (no projection has a bias)")
+                  for k in ("attention_bias", "mlp_bias", "mamba_proj_bias",
+                            "projectors_bias")),
+                (not cfg.get("mamba_conv_bias", True),
+                 "mamba_conv_bias: false (the convolution has a bias)"),
+                (heads * p != d_ssm,
+                 f"mamba_n_heads {heads} x mamba_d_head {p} != mamba_d_ssm "
+                 f"{d_ssm}"),
+                (groups < 1 or heads % groups != 0,
+                 f"mamba_n_heads {heads} is no multiple of mamba_n_groups "
+                 f"{groups}"),
+                ((cfg.get("hidden_act") or "silu") != "silu",
+                 f"hidden_act={cfg.get('hidden_act')!r} (only silu)"),
+                (len(cfg.get("ssm_multipliers") or [1] * 5) != 5
+                 or len(cfg.get("mlp_multipliers") or [1] * 2) != 2,
+                 "ssm_multipliers / mlp_multipliers of another length than "
+                 "5 / 2"),
+            ) if bad]
+        if refused:
+            raise ValueError(f"{what} is not supported with: "
+                             + "; ".join(refused))
+        n_heads = int(cfg["num_attention_heads"])
+        gate, down = (float(m) for m in cfg.get("mlp_multipliers") or (1, 1))
+        return ModelConfig(
+            name=name or cfg.get("_name_or_path", "hf-model"),
+            architecture="falcon_h1",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=hidden,
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=int(cfg["num_hidden_layers"]),
+            num_heads=n_heads,
+            num_kv_heads=cfg.get("num_key_value_heads", n_heads),
+            head_dim=cfg.get("head_dim") or hidden // n_heads,
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_model_len=cfg.get("max_position_embeddings", 4096),
+            tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+            ssd_heads=heads,
+            ssd_head_dim=p,
+            ssd_state=int(cfg["mamba_d_state"]),
+            ssd_groups=groups,
+            ssd_conv=int(cfg.get("mamba_d_conv", 4)),
+            ssd_multipliers=tuple(
+                float(m) for m in cfg.get("ssm_multipliers") or (1,) * 5),
+            ssd_in_multiplier=float(cfg.get("ssm_in_multiplier", 1)),
+            ssd_out_multiplier=float(cfg.get("ssm_out_multiplier", 1)),
+            attn_in_multiplier=float(cfg.get("attention_in_multiplier", 1)),
+            attn_out_multiplier=float(cfg.get("attention_out_multiplier", 1)),
+            key_multiplier=float(cfg.get("key_multiplier", 1)),
+            mlp_gate_multiplier=gate,
+            mlp_down_multiplier=down,
+            embedding_multiplier=float(cfg.get("embedding_multiplier", 1)),
+            lm_head_multiplier=float(cfg.get("lm_head_multiplier", 1)),
         )
 
     @staticmethod

@@ -133,7 +133,8 @@ class LLMEngine:
             self.recurrent = RecurrentCounters(
                 config.model.num_recurrent_layers,
                 config.model.recurrent_state_bytes(slots),
-                kind="mamba" if config.model.mamba_period else "kda")
+                kind=("mamba" if config.model.mamba_period
+                      else "ssd" if config.model.ssd_heads else "kda"))
             logging.getLogger(__name__).info(
                 "%s keeps recurrent state per decode slot (%d %s layers x "
                 "%d slots, %.2f GB): prefix-cache lookups are served as "

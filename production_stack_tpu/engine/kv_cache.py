@@ -125,6 +125,16 @@ def init_kv_cache(
                 caches["win"] = jnp.zeros(model.kv_pool_shape(
                     window_blocks, cache.block_size, window=True), dt)
             return caches
+        if model.ssd_heads:
+            ls = model.count_layers("parallel")
+            return {
+                "kv": pool,
+                "state": jnp.zeros((ls, slots, model.ssd_heads,
+                                    model.ssd_state, model.ssd_head_dim),
+                                   jnp.float32),
+                "conv": jnp.zeros((ls, slots, model.ssd_conv - 1,
+                                   model.ssd_conv_dim), dt),
+            }
         h, d = model.kda_heads, model.kda_head_dim
         lk = model.num_kda_layers
         return {

@@ -436,6 +436,30 @@ class EngineStatsCollector:
                 "channel block",
                 s["mamba_chunk_spans_total"],
             )
+        # state-space mixers with heads (RecurrentCounters, kind "ssd"):
+        # the same three meanings under their own names
+        if "ssd_decode_calls_total" in s:
+            yield counter(
+                "vllm:ssd_decode_calls",
+                "State-space decode steps the decode program ran (decode "
+                "dispatches x fused iterations x layers, each of which "
+                "holds a mixer with heads); a ragged dispatch runs one more "
+                "a layer for its decode rows",
+                s["ssd_decode_calls_total"],
+            )
+            yield counter(
+                "vllm:ssd_chunk_tokens",
+                "Rows of the ragged dispatches that the state-space "
+                "mixers' span scan carried: every span's but the decode "
+                "rows'",
+                s["ssd_chunk_tokens_total"],
+            )
+            yield counter(
+                "vllm:ssd_chunk_spans",
+                "Spans of the ragged dispatches that the span scan carried: "
+                "each loads and stores its slot's state once a layer",
+                s["ssd_chunk_spans_total"],
+            )
         # recurrent-state layers (engine/tracing.py RecurrentCounters):
         # exported by hybrid stacks only
         if "kda_decode_calls_total" in s:

@@ -339,7 +339,8 @@ class ModelRunner:
             self._mh_gate_all = {}
 
         recurrent = self.cfg.has_recurrent_state
-        recur = self._recur_mamba if self.cfg.mamba_period else self._recur
+        recur = (self._recur_mamba if self.cfg.mamba_period
+                 else self._recur_ssd if self.cfg.ssd_heads else self._recur)
         self._decode_multi = jax.jit(
             _named_partial(
                 _decode_multi_step, self.cfg, self._attend_decode,
@@ -519,6 +520,10 @@ class ModelRunner:
             # span kernel takes and returns them, the gated product
             hidden += (T * self.cfg.mamba_inner * (4 * 2 + 5 * 4)
                        if self.cfg.mamba_period else 0) // 8
+            # a mixer with heads: [z | xBC | dt] and the convolved xBC in
+            # the model dtype; x, d x, y and the gated product in float32,
+            # head-major copies of d x and y for the span kernel
+            hidden += (T * self.cfg.ssd_conv_dim * (4 * 2 + 7 * 4)) // 8
         if self.cfg.is_latent:
             # a latent layer's rows of all heads: the two parts of the
             # query, the absorbed query at the pool's lanes, the
@@ -913,6 +918,32 @@ class ModelRunner:
         return (jnp.expand_dims(o, axis),
                 {**caches, "state": state, "conv": conv})
 
+    def _state_space(self, forms, mix, caches, idx, step_inputs):
+        """A state-space layer's stateful part over this runner's caches:
+        ``forms`` = (the convolution, the scan in XLA, the scan's Pallas
+        kernel) of the step form, ``mix(conv, scan)`` the family's mixer
+        (``ops/mamba.py`` / ``ops/ssd.py`` ``mix``) given the two calls,
+        each over its own state at the layer's index ``idx``. Returns (y,
+        caches)."""
+        conv_impl, xla, pallas = forms
+        new = {}
+
+        def conv(x, taps):
+            tail = jax.lax.dynamic_index_in_dim(caches["conv"], idx, 0,
+                                                False)
+            out, new["tail"] = conv_impl(x, taps, tail, *step_inputs)
+            return out
+
+        def scan(*rows):
+            y, new["state"] = (pallas if self.use_pallas else xla)(
+                caches["state"], idx, *rows, *step_inputs)
+            return y
+
+        y = mix(conv, scan)
+        conv_state = jax.lax.dynamic_update_index_in_dim(
+            caches["conv"], new["tail"], idx, 0)
+        return y, {**caches, "state": new["state"], "conv": conv_state}
+
     def _recur_mamba(self, ragged: bool, mp, xs, caches, m_idx,
                      *step_inputs):
         """A state-space layer's stateful call (a ``sambay.MambaFn`` with
@@ -922,29 +953,34 @@ class ModelRunner:
         from production_stack_tpu.ops import mamba, mamba_pallas
 
         axis = 0 if ragged else 1  # of the axis the step form lacks
-        conv_impl, xla, pallas = (
+        y, caches = self._state_space(
             (kda.conv_ragged, mamba.scan_ragged, mamba_pallas.mamba_ragged)
             if ragged else (kda.conv_decode, mamba.scan_decode,
-                            mamba_pallas.mamba_decode_step))
-        new = {}
+                            mamba_pallas.mamba_decode_step),
+            lambda conv, scan: mamba.mix(
+                mp, jnp.squeeze(xs, axis), self.cfg.mamba_state, conv, scan),
+            caches, m_idx, step_inputs)
+        return jnp.expand_dims(y, axis), caches
 
-        def conv(x, taps):
-            tail = jax.lax.dynamic_index_in_dim(caches["conv"], m_idx, 0,
-                                                False)
-            out, new["tail"] = conv_impl(x, taps, tail, *step_inputs)
-            return out
+    def _recur_ssd(self, ragged: bool, sp, xbc, dt, caches, idx,
+                   *step_inputs):
+        """The stateful call of a state-space mixer with heads (a
+        ``falcon_h1.SsdFn`` with the step's inputs bound behind it):
+        ``xbc`` (1, T, H*P + 2*G*N) and ``dt`` (1, T, H) the packed
+        stream, or (B, 1, ...) one row a slot."""
+        from production_stack_tpu.ops import ssd, ssd_pallas
 
-        def scan(A, *rows):
-            y, new["state"] = (pallas if self.use_pallas else xla)(
-                caches["state"], m_idx, A, *rows, *step_inputs)
-            return y
-
-        y = mamba.mix(mp, jnp.squeeze(xs, axis), self.cfg.mamba_state, conv,
-                      scan)
-        conv_state = jax.lax.dynamic_update_index_in_dim(
-            caches["conv"], new["tail"], m_idx, 0)
-        return (jnp.expand_dims(y, axis),
-                {**caches, "state": new["state"], "conv": conv_state})
+        axis = 0 if ragged else 1  # of the axis the step form lacks
+        y, caches = self._state_space(
+            (kda.conv_ragged, ssd.scan_ragged, ssd_pallas.ssd_ragged)
+            if ragged else (kda.conv_decode, ssd.scan_decode,
+                            ssd_pallas.ssd_decode_step),
+            lambda conv, scan: ssd.mix(
+                sp, jnp.squeeze(xbc, axis), jnp.squeeze(dt, axis),
+                self.cfg.ssd_heads, self.cfg.ssd_groups, self.cfg.ssd_state,
+                conv, scan),
+            caches, idx, step_inputs)
+        return jnp.expand_dims(y, axis), caches
 
     # -- public step API (host numpy in, device out) -------------------------
     def _ensure_counts(self):

@@ -588,9 +588,11 @@ class RecurrentCounters:
 
     def __init__(self, kda_layers: int, state_bytes: int,
                  kind: str = "kda"):
-        """``kind``: "kda", or "mamba" for a stack of state-space layers,
-        whose span kernel takes rows one by one (no blocks to count) and
-        whose counters carry that name."""
+        """``kind``: "kda"; "mamba" for a stack of state-space layers,
+        whose span kernel takes rows one by one (no blocks to count); "ssd"
+        for a stack whose every layer holds a state-space mixer with heads
+        (blocks of its own size, not counted). The counters carry the
+        name."""
         self.kda_layers, self.state_bytes = kda_layers, state_bytes
         self.kind = kind
         self.decode_calls = 0   # decode dispatches x iterations x layers

@@ -35,11 +35,12 @@ from production_stack_tpu.engine.quant import (
     quant_einsum,
     ragged_quant_dot,
 )
-from production_stack_tpu.models import sambay
+from production_stack_tpu.models import falcon_h1, sambay
 from production_stack_tpu.ops import kda
 from production_stack_tpu.ops.attention import dense_causal_attention
 from production_stack_tpu.ops.norms import layer_norm, rms_norm
 from production_stack_tpu.ops.rope import apply_rope
+from production_stack_tpu.ops.scalars import over, times
 from production_stack_tpu.parallel import shardings as lax_names
 
 # AttendFn: (q, k, v, layer_cache, layer_idx) -> (attn_out, new_layer_cache);
@@ -175,6 +176,12 @@ def param_specs(cfg: ModelConfig) -> dict:
         layer.update({"attn_norm_b": (L.LAYERS, L.EMBED),
                       "mlp_norm_b": (L.LAYERS, L.EMBED)})
         mixers = sambay.param_specs(cfg)
+    elif cfg.ssd_heads:
+        # every layer has both mixers, each a stack over the layers
+        # (models/falcon_h1.py); norms and the MLP stay in "layers"
+        for k in ("wq", "wk", "wv", "wo"):
+            del layer[k]
+        mixers = falcon_h1.param_specs(cfg)
     elif cfg.has_recurrent_state:
         # the token mixers differ by layer kind and are stacked by kind:
         # "gqa" over the periods, "kda" over the KDA layers; what every
@@ -444,6 +451,11 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         # SAMBAY_INIT: block 0 writes at the usual size
         mixers["mamba"]["w_out"] = _first_unscaled(
             mixers["mamba"]["w_out"], out)
+    elif cfg.ssd_heads:
+        for k in ("wq", "wk", "wv", "wo"):
+            del layers[k]
+        mixers = falcon_h1.init_params(cfg, keys[13], normal, out,
+                                       (KDA_DT_MIN, KDA_DT_MAX))
     elif cfg.has_recurrent_state:
         Pn = cfg.num_attn_layers
         attn = {k: layers.pop(k) for k in ("wq", "wk", "wv", "wo")}
@@ -498,16 +510,21 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     else:
         layers.update(
             {
-                "w_gate": normal(keys[5], (Ln, E, F), E),
+                # ``over``: models/falcon_h1.py's one rule for a family
+                # with published scalars (1, and nothing, for the others)
+                "w_gate": over(normal(keys[5], (Ln, E, F), E),
+                               cfg.mlp_gate_multiplier),
                 "w_up": normal(keys[6], (Ln, E, F), E),
-                "w_down": normal(keys[7], (Ln, F, E), F * out),
+                "w_down": over(normal(keys[7], (Ln, F, E), F * out),
+                               cfg.mlp_down_multiplier),
             }
         )
         if cfg.mamba_period:
             layers["w_down"] = _first_unscaled(layers["w_down"], out)
     params = {
-        "embed": normal(keys[8], (V, E),
-                        1 if hybrid and not cfg.mamba_period else E),
+        "embed": over(normal(keys[8], (V, E),
+                             1 if hybrid and not cfg.mamba_period else E),
+                      cfg.embedding_multiplier),
         "layers": layers,
         **mixers,
         "final_norm": jnp.full((E,), norm_one, dt),
@@ -515,7 +532,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     if cfg.layer_norm:
         params["final_norm_b"] = jnp.zeros((E,), dt)
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = normal(keys[9], (E, V), E)
+        params["lm_head"] = over(normal(keys[9], (E, V), E),
+                                 cfg.lm_head_multiplier)
     return params
 
 
@@ -641,11 +659,11 @@ def _mlp(cfg: ModelConfig, lp: dict, x: jnp.ndarray, lb=None,
             gate = gate + _lora_delta(x, onehot, *lb["w_gate"])
         if "w_up" in lb:
             up = up + _lora_delta(x, onehot, *lb["w_up"])
-    hidden2 = _act(cfg)(gate) * up
+    hidden2 = _act(cfg)(times(gate, cfg.mlp_gate_multiplier)) * up
     out = quant_einsum("...tf,fe->...te", hidden2, lp["w_down"])
     if lb is not None and "w_down" in lb:
         out = out + _lora_delta(hidden2, onehot, *lb["w_down"])
-    return out
+    return times(out, cfg.mlp_down_multiplier)
 
 
 def _act(cfg: ModelConfig):
@@ -817,7 +835,7 @@ def embed_tokens(cfg: ModelConfig, params: dict, tokens: jnp.ndarray) -> jnp.nda
     x = embed_lookup(params["embed"], tokens, cfg.jax_dtype)
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.hidden_size ** 0.5, cfg.jax_dtype)
-    return x
+    return times(x, cfg.embedding_multiplier)
 
 
 def forward_hidden(
@@ -890,8 +908,10 @@ def forward_hidden(
         x, new_caches, hists = _forward_hybrid(
             cfg, params, layers, experts, x, attend,
             recur or (functools.partial(sambay.mamba_dense, cfg)
-                      if cfg.mamba_period else _recur_dense),
-            kv_caches, live, pre_norm, grouped_matmul)
+                      if cfg.mamba_period
+                      else functools.partial(falcon_h1.ssd_dense, cfg)
+                      if cfg.ssd_heads else _recur_dense),
+            kv_caches, live, pre_norm, grouped_matmul, positions)
         out = (x, new_caches)
         if moe_hist:
             out += (hists,)
@@ -1151,7 +1171,8 @@ def _kda_mixer(cfg: ModelConfig, kp: dict, x: jnp.ndarray, recur: RecurFn,
 
 def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
                     experts: dict, x, attend: AttendFn,
-                    recur, caches, live, pre_norm, grouped_matmul=None):
+                    recur, caches, live, pre_norm, grouped_matmul=None,
+                    positions=None):
     """A patterned stack (``cfg.layer_kinds``): one scan over the periods
     of each run of like periods (``cfg.stack_segments``; a run of one
     period is not scanned). Solar-Open2 is one run of (gqa, kda, kda, kda):
@@ -1163,7 +1184,10 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
     attention rotates nothing and shares ``caches["kv"]``, a latent pool.
     A SambaY stack (models/sambay.py) is
     three: (mamba, swa) periods, one (mamba, full), (gmu, cross) periods,
-    every block followed by the MLP; what the second run hands the third
+    every block followed by the MLP. Falcon-H1 is one run of ("parallel",):
+    a state-space mixer with heads and rotated grouped-query attention
+    (``positions``: only this kind rotates) on one normed row, summed
+    (models/falcon_h1.py); what the second run hands the third
     (``m``: the state-space layer's scan output; the full layer's keys and
     values, which a dense forward's cross layers attend over, a paged one's
     read from the cache) enters the third's scan as constants.
@@ -1186,8 +1210,44 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
     (periods, layers a period, ...) made the compiler materialise a whole
     period's slice first: 3 x 200 MB of copies a period in the decode
     step, counted by the TPU compiler at the published widths."""
-    stack_of = {"gqa": "gqa", "kda": "kda", "mla": "mla", **sambay.STACK_OF}
+    stack_of = {"gqa": "gqa", "kda": "kda", "mla": "mla", "parallel": "gqa",
+                **sambay.STACK_OF}
     dense = cfg.dense_layers
+
+    def gqa(gp, normed, caches, i, rotate=False):
+        """Grouped-query attention over cache layer ``i``: Solar-Open2's
+        (nothing rotated, a sigmoid gate) or Falcon-H1's (``rotate``: rope
+        on q and k, the keys times ``key_multiplier``; no gate)."""
+        if "wq_t" in gp:
+            # W_q, W_k, W_v lie transposed, (H * D, E): the layout the TPU
+            # compiler gives them for a decode step's 64 rows; handed (E,
+            # H * D) it copied all three stacks whole, 220 MB, at the start
+            # of every decode step (PERF.md section 6, PR 52). Solar-Open2's
+            # (E, H * D) stacks below ARE copied so: its decode program,
+            # compiled for the described v5e at the published widths, copies
+            # wq and wg (134 MB each) and wk, wv (17 MB each) whole at the
+            # start of a step, 347.6 MiB of temporaries (PR 52). One layout
+            # and no branch once its cells are measured on it: ROADMAP S20
+            q, k, v = (quant_einsum("...te,fe->...tf", normed, gp[w])
+                       for w in ("wq_t", "wk_t", "wv_t"))
+            k, v = (y.reshape(*y.shape[:-1], cfg.num_kv_heads, cfg.head_dim)
+                    for y in (k, v))
+        else:
+            q = quant_einsum("...te,ef->...tf", normed, gp["wq"])
+            k = quant_einsum("...te,ehd->...thd", normed, gp["wk"])
+            v = quant_einsum("...te,ehd->...thd", normed, gp["wv"])
+        q = q.reshape(*q.shape[:-1], cfg.num_heads, cfg.head_dim)
+        if rotate:
+            k = times(k, cfg.key_multiplier)
+            q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+            k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+        kv = None if caches is None else caches["kv"]
+        attn, kv = attend(q, k, v, kv, i)
+        if caches is not None:
+            caches = {**caches, "kv": kv}
+        if cfg.attn_gate:
+            attn = _gated(attn, normed, gp["wg"])
+        return quant_einsum("...thd,hde->...te", attn, gp["wo"]), caches
 
     def at(tree, i):
         return jax.tree.map(
@@ -1218,18 +1278,19 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
                     before[stacks[j]] + stacks[:j].count(stacks[j]))
                 i = p if (i, first) == (1, 0) else p * i + first
                 if kind == "gqa":
-                    gp = at(params["gqa"], i)
-                    q = quant_einsum("...te,ef->...tf", normed, gp["wq"])
-                    q = q.reshape(*q.shape[:-1], cfg.num_heads, cfg.head_dim)
-                    k = quant_einsum("...te,ehd->...thd", normed, gp["wk"])
-                    v = quant_einsum("...te,ehd->...thd", normed, gp["wv"])
-                    kv = None if caches is None else caches["kv"]
-                    attn, kv = attend(q, k, v, kv, i)
-                    if caches is not None:
-                        caches = {**caches, "kv": kv}
-                    if cfg.attn_gate:
-                        attn = _gated(attn, normed, gp["wg"])
-                    o = quant_einsum("...thd,hde->...te", attn, gp["wo"])
+                    o, caches = gqa(at(params["gqa"], i), normed, caches, i)
+                elif kind == "parallel":  # both mixers read ``normed``
+                    with jax.named_scope("ssd"):
+                        o, caches = falcon_h1.ssd_mixer(
+                            cfg, at(params["ssd"], i), normed, recur,
+                            caches, i)
+                    with jax.named_scope("gqa"):
+                        a, caches = gqa(
+                            at(params["gqa"], i),
+                            times(normed, cfg.attn_in_multiplier), caches, i,
+                            rotate=True)
+                    o = (times(o, cfg.ssd_out_multiplier)
+                         + times(a, cfg.attn_out_multiplier))
                 elif kind == "kda":
                     o, caches = _kda_mixer(cfg, at(params["kda"], i),
                                            normed, recur, caches, i)
@@ -1312,7 +1373,8 @@ def logits_from_hidden(cfg: ModelConfig, params: dict, hidden: jnp.ndarray) -> j
             else params["lm_head"])
     if not is_quantized(head):
         head = head.astype(cfg.jax_dtype)
-    logits = quant_einsum("...te,ev->...tv", hidden, head, jnp.float32)
+    logits = times(quant_einsum("...te,ev->...tv", hidden, head,
+                                jnp.float32), cfg.lm_head_multiplier)
     if cfg.final_logit_softcap:  # Gemma-2
         cap = cfg.final_logit_softcap
         logits = cap * jnp.tanh(logits / cap)

@@ -56,6 +56,14 @@ _REGISTRY: dict[str, ModuleType] = {
     # chip as one of sixteen: chipbench cell
     # kimi-linear-48b-a3b-ep16.long-decode (PR 49)
     "kimi_linear": llama,
+    # Falcon-H1: the same walker over ONE kind of layer that holds two
+    # mixers (cfg.ssd_heads > 0): a Mamba-2 state-space mixer with heads
+    # (models/falcon_h1.py, ops/ssd.py) and rotated grouped-query attention
+    # on the same normed row, summed; every layer owns a cache layer and a
+    # per-slot state, and the family's fourteen scalars lie on every path.
+    # Served with six whole layers and the whole vocabulary on one chip:
+    # chipbench cell falcon-h1-34b-l6.decode-heavy (PR 52)
+    "falcon_h1": llama,
     # encoder-decoder audio transcription: exposes its own forward
     # surface (encode/cross_kv/decode_tokens) instead of the decoder-only
     # protocol; shares param_specs/init_params so weights.py works

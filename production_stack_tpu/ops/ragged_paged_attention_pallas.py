@@ -67,6 +67,7 @@ with -1 skips — the packed stream is its native input.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -91,8 +92,19 @@ def q_tile_for(group: int) -> int:
     and float32 accumulators grow with them: 128 tokens at G = 8 ask for
     40 MiB of scoped VMEM (the compiler's count for KH = 8, D = 128), over
     what the cells' 32 MiB flag allows. Past G = 4 the tile shrinks so that
-    it keeps G = 4's 512 rows; every geometry up to G = 4 keeps 128."""
-    return Q_TILE if group <= 4 else max(Q_TILE * 4 // group, 16)
+    it keeps at most G = 4's 512 rows; every geometry up to G = 4 keeps
+    128. Where the group does not divide 512 the tile shrinks further, to
+    rows that are whole ``ROW_ALIGN`` tiles (G = 5: 96 tokens, 480 rows; a
+    power of two keeps what it had): a tile of 510 rows has no narrow
+    block at all (``narrow_walk``), and every decode row of a ragged step
+    would take the whole tile's body."""
+    if group <= 4:
+        return Q_TILE
+    tokens = Q_TILE * 4 // group
+    return max(tokens - tokens % (ROW_ALIGN // math.gcd(group, ROW_ALIGN)),
+               16)
+
+
 # Rows a narrow walk computes on: a one-token span at any G <= 16 and a
 # 1 + 4 verify span at G = 4 (20 rows from a multiple of 4) fit it at any
 # offset. The MXU streams these rows past each (128 x 128) key tile, so

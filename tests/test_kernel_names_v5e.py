@@ -674,3 +674,217 @@ def test_ten_head_pairs_unpadded_are_refused_by_the_tpu_compiler(one_chip):
     with pytest.raises(Exception, match="aligned to tiling"):
         jax.jit(lambda q, c, bt, cl: paged_decode_attention_pallas(
             q, c, bt, cl, layer_idx=0)).lower(*args).compile()
+
+
+# -- Falcon-H1-34B's six layers on one chip (chipbench
+# falcon-h1-34b-l6.decode-heavy): the state-space kernels at 6 layers x 64
+# slots of 32 heads of a (256, 128) float32 state, under the names
+# chipbench/layer_metrics/ssd_*.json match on, and both attention kernels
+# and the KV write at a geometry no other cell has, KH = 4, G = 5: a
+# token's slab is ONE 8-row tile, and the group divides neither 16 nor 512.
+# Everything under the DEFAULT 16 MiB of scoped VMEM (the manifest sets no
+# libtpu flag); the span kernel sets its own limit -------------------------
+
+SSD_STATE = ((6, 64, 32, 256, D), jnp.float32)
+G5_KH, G5 = 4, 5
+G5_CACHE = ((6, 8192, BS, 2 * G5_KH, D), jnp.bfloat16)
+# what the ragged kernel asks at its 480-row tile of 4 KV heads, and
+# ssd_decode_step at 16 states a cell (the compiler's own counts)
+G5_RAGGED_VMEM_MIB, SSD_DECODE_VMEM_MIB = 9.20, 8.03
+
+
+def _ssd_cases():
+    from production_stack_tpu.ops.ssd_pallas import (
+        ssd_decode_step,
+        ssd_ragged,
+    )
+
+    def rows(t):
+        return [((t, 32), jnp.float32), ((t, 32, D), jnp.float32),
+                ((t, 2, 256), jnp.float32), ((t, 2, 256), jnp.float32)]
+
+    def ragged(width):  # the ragged program's stream widths
+        return (
+            lambda st, g, dx, b, c, cu, ctx: ssd_ragged(
+                st, 3, g, dx, b, c, cu, ctx),
+            (SSD_STATE, *rows(width), ((65,), I32), ((64,), I32)))
+
+    return {
+        "ssd_decode_step": (
+            lambda st, g, dx, b, c, act: ssd_decode_step(
+                st, 3, g, dx, b, c, act),
+            (SSD_STATE, *rows(64), ((64,), jnp.bool_))),
+        "ssd_chunk_scan": ragged(2048),
+        "ssd_chunk_scan@512": ragged(512),
+    }
+
+
+def _g5_cases():
+    h = G5_KH * G5
+    return {
+        "ragged_paged_attention": (
+            lambda q, c, bt, cu, cl: ragged_paged_attention_pallas(
+                q, c, bt, cu, cl, layer_idx=1),
+            (((2048, h, D), jnp.bfloat16), G5_CACHE, ((64, 512), I32),
+             ((65,), I32), ((64,), I32))),
+        "ragged_paged_attention@512": (
+            lambda q, c, bt, cu, cl: ragged_paged_attention_pallas(
+                q, c, bt, cu, cl, layer_idx=1),
+            (((512, h, D), jnp.bfloat16), G5_CACHE, ((64, 512), I32),
+             ((65,), I32), ((64,), I32))),
+        "paged_decode_attention": (
+            lambda q, c, bt, cl: paged_decode_attention_pallas(
+                q, c, bt, cl, layer_idx=1),
+            (((64, h, D), jnp.bfloat16), G5_CACHE, ((64, 512), I32),
+             ((64,), I32))),
+        "kv_cache_write": (
+            lambda c, new, sm: kv_cache_write_pallas(c, new, sm, layer_idx=1),
+            (G5_CACHE, ((2048, 2 * G5_KH, D), jnp.bfloat16), ((2048,), I32))),
+    }
+
+
+def _asks_at_most(lowered, mib):
+    """The compiler names the size where the limit is just short."""
+    with pytest.raises(Exception, match="Scoped allocation") as refusal:
+        lowered.compile(compiler_options={
+            "xla_tpu_scoped_vmem_limit_kib": int((mib - 0.5) * 1024)})
+    size = re.search(r"Scoped allocation with size ([\d.]+)M",
+                     str(refusal.value))
+    return bool(size) and float(size.group(1)) <= mib
+
+
+@pytest.mark.parametrize(
+    "case", ["ssd_chunk_scan", "ssd_chunk_scan@512", "ssd_decode_step"])
+def test_ssd_kernel_is_a_named_custom_call_at_the_cells_shapes(one_chip,
+                                                               case):
+    fn, shapes = _ssd_cases()[case]
+    name = case.partition("@")[0]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    lowered = jax.jit(fn, donate_argnums=0).lower(*args)
+    compiled = lowered.compile()  # default options: 16 MiB
+    text = compiled.as_text()
+    assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = .*? custom-call\(",
+                     text, flags=re.M)
+    # a ragged step runs BOTH kernels, its decode rows through the one-row
+    # kernel; the state of all layers (1.6 GB) is updated in place: no
+    # second copy of it among the program's temporaries
+    if name == "ssd_chunk_scan":
+        assert re.search(r"^\s*(?:ROOT )?%ssd_decode_step[.\d]* = ", text,
+                         flags=re.M)
+    else:
+        assert _asks_at_most(lowered, SSD_DECODE_VMEM_MIB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 300 * 2 ** 20
+
+
+@pytest.mark.parametrize("case", sorted(_g5_cases()))
+def test_attention_kernels_compile_at_kh4_g5_under_default_vmem(one_chip,
+                                                                case):
+    fn, shapes = _g5_cases()[case]
+    name = case.partition("@")[0]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = .*? custom-call\(",
+                     lowered.compile().as_text(), flags=re.M)
+    if name == "ragged_paged_attention":
+        assert _asks_at_most(lowered, G5_RAGGED_VMEM_MIB)
+
+
+def test_a_group_that_divides_no_power_of_two_keeps_a_narrow_block():
+    """G = 5: 128 x 4 // 5 = 102 tokens would be 510 rows a tile, no
+    multiple of ROW_ALIGN, so ``narrow_walk`` would answer False for every
+    walk and every decode row of a ragged step would take the whole tile's
+    body. The tile shrinks to whole ROW_ALIGN tiles instead (96 tokens, 480
+    rows); the powers of two keep what they had; the decode kernel's
+    grouped body takes all 20 query rows a block where 16 % G != 0."""
+    import numpy as np
+
+    from production_stack_tpu.ops.paged_attention_pallas import (
+        decode_window_body,
+    )
+    from production_stack_tpu.ops.ragged_paged_attention_pallas import (
+        ROW_ALIGN,
+        count_walks,
+        narrow_walk,
+        q_tile_for,
+    )
+
+    assert {g: q_tile_for(g) for g in (5, 6, 7, 8, 12, 16)} == {
+        5: 96, 6: 80, 7: 64, 8: 64, 12: 40, 16: 32}
+    assert all(q_tile_for(g) * g % ROW_ALIGN == 0 and q_tile_for(g) * g <= 512
+               for g in range(5, 33))
+    assert narrow_walk(0, 1, 5, 102 * 5) == (False, 0)  # the tile it was
+    narrow, r0 = narrow_walk(np.int64(3), np.int64(4), 5, 96 * 5, xp=np)
+    assert narrow and r0 == 0
+    # a decode step's 64 one-row spans in the 512-wide stream: all narrow
+    cu = np.minimum(np.arange(65), 64)
+    assert count_walks(cu, 512, 5) == (64, 64)
+    assert decode_window_body(G5_KH, G5, D, jnp.bfloat16) == "grouped"
+
+
+def test_falcon_h1s_decode_program_copies_no_weight_stack(one_chip):
+    """The engine's own decode program at the published widths, whole, for
+    the described v5e. The TPU compiler lays a projection's stack out as
+    the step's 64 rows like it and, handed another, copies the WHOLE stack
+    at the start of every decode step, outside the layer scan: W_in at its
+    published 9248 columns (no whole 128-lane tiles) 568 MB, W_q / W_k / W_v
+    as (E, H D) 220 MB (PERF.md section 6, PR 52: 7 % of the chip's busy
+    time went to the first). So W_in lies as its [z | xBC] and its dt
+    columns and the three lie transposed (models/falcon_h1.py): the
+    program's temporaries are a step's rows, nothing more."""
+    import functools
+    import json
+    import os
+    import types
+
+    import numpy as np
+
+    from production_stack_tpu.engine import model_runner as mr
+    from production_stack_tpu.engine.config import ModelConfig
+    from production_stack_tpu.models import llama
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", "falcon-h1-34b-l6",
+                           "config.json")) as f:
+        cfg = ModelConfig.from_hf_config(json.load(f), "falcon")
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))))
+    slots, width = 64, 512
+    caches = {"kv": sds(cfg.kv_pool_shape(8192, BS), jnp.bfloat16),
+              "state": sds(SSD_STATE[0], jnp.float32),
+              "conv": sds((6, slots, 3, cfg.ssd_conv_dim), jnp.bfloat16)}
+    # the runner's two stateful calls without a runner (no weights made)
+    me = types.SimpleNamespace(cfg=cfg, use_pallas=True, tp=1,
+                               _sharded=lambda inner: inner)
+    me._state_space = functools.partial(mr.ModelRunner._state_space, me)
+    arrays = [np.zeros(s, dt) for s, dt in (
+        (slots, np.int32), (slots, np.int32), ((slots, width), np.int32),
+        (slots, np.int32), (slots, np.int32), (slots, np.float32),
+        (slots, np.float32), (slots, np.int32), (slots, np.uint32),
+        (slots, np.int32), (1, np.int32))]
+    layout = mr.StepLayout.of(mr._DECODE_INPUTS, arrays)
+    step = functools.partial(
+        mr._decode_multi_step, cfg,
+        functools.partial(mr.ModelRunner._attend_decode, me), 1, 0,
+        recur_impl=functools.partial(mr.ModelRunner._recur_ssd, me, False))
+    compiled = jax.jit(
+        step, donate_argnums=(1,),
+        static_argnames=("layout", "block_size", "greedy_only",
+                         "want_logprobs")).lower(
+        params, caches, sds(layout.pack(arrays).shape, I32),
+        sds((slots, 1), I32), layout=layout, block_size=BS,
+        greedy_only=True, want_logprobs=False).compile()
+    text = compiled.as_text()
+    for name in ("ssd_decode_step", "paged_decode_attention",
+                 "kv_cache_write"):
+        assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = ", text,
+                         flags=re.M), name
+    # 781 MiB with W_in whole and the three as (E, H D); the weights are
+    # 10.5 GB, the state 1.6 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20

@@ -187,7 +187,8 @@ FAMILIES = ("tiny-gemma", "tiny-gemma2", "tiny-qwen3", "tiny-phi3",
 # the families that joined after the first six, as their own test files
 # build them: the 64-expert block, the looped stack, the KDA hybrid, the
 # latent cache, the Mamba / window / shared-cache stack, KDA state beside a
-# latent pool (the scatter into a pool that rides a cache pytree).
+# latent pool (the scatter into a pool that rides a cache pytree), two
+# mixers a layer (a state AND a cache layer from every layer, G = 5).
 # (model, block size)
 
 
@@ -195,6 +196,12 @@ def _solar_open2():
     from tests.test_solar_open2 import tiny_cfg
 
     return tiny_cfg()
+
+
+def _falcon_h1():
+    from tests.test_falcon_h1 import CFG
+
+    return CFG
 
 
 LATER_FAMILIES = {
@@ -206,6 +213,7 @@ LATER_FAMILIES = {
         lambda: ModelConfig.from_pretrained("tiny-phi4flash"), 4),
     "tiny-kimi-linear": (
         lambda: ModelConfig.from_pretrained("tiny-kimi-linear"), 16),
+    "tiny-falcon-h1": (_falcon_h1, 4),
 }
 
 
