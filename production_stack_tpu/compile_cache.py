@@ -18,6 +18,7 @@ from __future__ import annotations
 import os
 
 _ENV = "JAX_COMPILATION_CACHE_DIR"
+_MIN_SECS_ENV = "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"
 _DEFAULT = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     ".jax_compile_cache",
@@ -25,11 +26,19 @@ _DEFAULT = os.path.join(
 
 
 def configure_compile_cache() -> str:
-    """Point jax at the persistent compile cache; return its directory."""
+    """Point jax at the persistent compile cache; return its directory.
+    Every program is kept there, however quickly it compiled: jax's own
+    threshold keeps only those that took a second, and a step program that
+    compiles in less (Ouro's do, since nothing in them copies a weight
+    stack: 22 signatures of ~0.7 s, PR 53) was compiled again by every
+    start, ~10 s of warm-up and probe. The operator's
+    ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` holds where it is set."""
+    import jax
+
+    if _MIN_SECS_ENV not in os.environ:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     placed = os.environ.get(_ENV)
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", _DEFAULT)
     return _DEFAULT

@@ -55,7 +55,7 @@ from production_stack_tpu.engine.tracing import (
     StepClock,
 )
 from production_stack_tpu.ops import kda
-from production_stack_tpu.engine.weights import init_or_load
+from production_stack_tpu.engine.weights import init_or_load, lay_out
 from production_stack_tpu.models.registry import get_model
 from production_stack_tpu.ops.paged_attention import (
     combine_kv,
@@ -265,13 +265,13 @@ class ModelRunner:
         # what `step.launch` says of such a stack in a trace
         self._launch_attrs = ({"passes": self.cfg.loop_passes}
                               if self.loop is not None else {})
-        with jax.set_mesh(mesh):
-            self.params = maybe_quantize(
-                self.cfg,
-                params
-                if params is not None
-                else init_or_load(self.cfg, mesh, self.rules, config.seed),
-            )
+        # a tree handed in is served as it is (the caller keeps it); one
+        # loaded here is laid out as the step programs read it
+        if params is None:
+            self.params = self._loaded_params()
+        else:
+            with jax.set_mesh(mesh):
+                self.params = maybe_quantize(self.cfg, params)
         self.use_pallas = _pallas_ok(self.cfg, mesh, config.cache.block_size)
         # what runs the MoE block's grouped matmuls in every step program
         # built here: the Pallas kernel, or None for jax.lax.ragged_dot
@@ -1204,12 +1204,19 @@ class ModelRunner:
     def drop_params(self) -> None:
         self.params = None
 
+    def _loaded_params(self) -> dict:
+        """The weights from the checkpoint or the seed, quantized where the
+        model says so, then in the order of bytes the step programs read
+        (engine/weights.py ``lay_out``; a quantized stack stays as it is)."""
+        with jax.set_mesh(self.mesh):
+            return lay_out(
+                self.cfg, maybe_quantize(self.cfg, init_or_load(
+                    self.cfg, self.mesh, self.rules, self.config.seed)),
+                self.mesh, self.rules)
+
     def restore_params(self) -> None:
         if self.params is None:
-            with jax.set_mesh(self.mesh):
-                self.params = maybe_quantize(self.cfg, init_or_load(
-                    self.cfg, self.mesh, self.rules, self.config.seed
-                ))
+            self.params = self._loaded_params()
 
     @property
     def params_alive(self) -> bool:

@@ -109,6 +109,16 @@ def dequantize_array(w: dict) -> jnp.ndarray:
     return w["q"].astype(jnp.float32) * w["s"]
 
 
+def as_stored(p: dict, name: str) -> Tuple[str, Any]:
+    """(the einsum equation that takes x (..., T, E) through it, the
+    matrix) for the projection ``name`` of ``p``: ``p[name]`` (E, F) as it
+    is made, or ``p[name + "_t"]`` (F, E) as a runner keeps it
+    (engine/weights.py ``lay_out``)."""
+    if name + "_t" in p:
+        return "...te,fe->...tf", p[name + "_t"]
+    return "...te,ef->...tf", p[name]
+
+
 def quant_einsum(eq: str, x: jnp.ndarray, w: Any,
                  out_dtype=None) -> jnp.ndarray:
     """``jnp.einsum(eq, x, w)`` accepting a quantized ``w``.

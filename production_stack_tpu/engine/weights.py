@@ -19,6 +19,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from production_stack_tpu.engine.config import ModelConfig
+from production_stack_tpu.engine.quant import is_quantized
 from production_stack_tpu.models.registry import get_model
 from production_stack_tpu.parallel.shardings import (
     ShardingRules,
@@ -90,6 +91,10 @@ def load_orbax(cfg: ModelConfig, mesh: Mesh, rules: ShardingRules,
 
 
 def init_random(cfg: ModelConfig, mesh: Mesh, rules: ShardingRules, seed: int) -> dict:
+    """Random weights made on the device, each leaf straight into its
+    sharding, in the logical tree ``param_specs`` describes (the
+    benchmark's references read it by shape). A runner keeps some leaves
+    in another order of bytes: ``lay_out``."""
     model = get_model(cfg)
     specs = model.param_specs(cfg)
     out_shardings = jax.tree_util.tree_map(
@@ -102,6 +107,54 @@ def init_random(cfg: ModelConfig, mesh: Mesh, rules: ShardingRules, seed: int) -
     # their shardings (no host round-trip), and runs once per process
     init_fn = jax.jit(model.init_params, static_argnums=0, out_shardings=out_shardings)
     return init_fn(cfg, jax.random.PRNGKey(seed))
+
+
+def lay_out(cfg: ModelConfig, params: dict, mesh: Optional[Mesh] = None,
+            rules: Optional[ShardingRules] = None) -> dict:
+    """A loaded tree as a runner keeps it: each leaf the model names an
+    order of axes for (``param_layouts``: the attention projections'
+    stacks, the axis a step contracts last, so that no step program copies
+    a stack whole or re-lays a layer's slice) becomes ``<name>_t``,
+    transposed to that order with the axes between the stack's and the
+    last as one. Every other leaf, and a quantized container (its einsum
+    contracts the container's own axes), is the same object. With a mesh
+    each leaf is transposed on the device by a program of its own, lands
+    in the loaded leaf's sharding by its axes, and the loaded leaf is
+    deleted (no second resident copy; the transient is one leaf): ``params``
+    is spent. Without a mesh the transposes are plain operations (shapes
+    by ``jax.eval_shape``)."""
+    model = get_model(cfg)
+
+    def kept(w, order, axes):
+        def transpose(a):
+            t = jnp.transpose(a, order)
+            return t.reshape(t.shape[0], -1, t.shape[-1])
+
+        if mesh is None:
+            return transpose(w)
+        axes = [axes[i] for i in order]
+        # stackcheck: disable=jit-cache-hygiene — one-shot at model load
+        # (and wake): one program a laid-out leaf, called once
+        t = jax.jit(transpose, out_shardings=logical_to_sharding(
+            (axes[0], axes[1], axes[-1]), mesh, rules))(w)
+        # a transpose cannot write over what it reads, so donating would
+        # free nothing; the loaded leaf goes as soon as the program has
+        # read it, before the next leaf's runs
+        w.delete()
+        return t
+
+    def walk(tree, orders, specs):
+        out = {}
+        for k, v in tree.items():
+            if isinstance(v, dict) and not is_quantized(v):
+                out[k] = walk(v, orders[k], specs[k])
+            elif orders.get(k) is None or is_quantized(v):
+                out[k] = v
+            else:
+                out[k + "_t"] = kept(v, orders[k], specs[k])
+        return out
+
+    return walk(params, model.param_layouts(cfg), model.param_specs(cfg))
 
 
 # --- HF checkpoint mapping (Llama/Mixtral family) ---------------------------

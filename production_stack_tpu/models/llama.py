@@ -29,6 +29,7 @@ from jax import lax
 
 from production_stack_tpu.engine.config import ModelConfig
 from production_stack_tpu.engine.quant import (
+    as_stored,
     embed_lookup,
     head_from_embed,
     is_quantized,
@@ -114,7 +115,11 @@ def _latent_layer_specs(cfg: ModelConfig, sparse: bool) -> dict:
 
 
 def param_specs(cfg: ModelConfig) -> dict:
-    """Pytree of logical-axes tuples mirroring the param pytree."""
+    """Pytree of logical-axes tuples mirroring the param pytree, as
+    ``init_params`` makes it and a checkpoint loads into it. Which leaves a
+    runner then keeps in another order of bytes, and why, is
+    ``param_layouts``: the attention projections from the embedding onto
+    heads (``wq``, ``wk``, ``wv``; a hybrid stack's ``gqa.wq``, ``wg``)."""
     L = lax_names
     if cfg.is_latent and not cfg.has_recurrent_state:
         # the leading dense layers are a stack of their own, "dense",
@@ -190,7 +195,10 @@ def param_specs(cfg: ModelConfig) -> dict:
         # (E, H*D): handed a (E, H, D) stack indexed by layer, the TPU
         # compiler chose a per-head layout for it and copied the whole
         # stack at the start of every decode step (5 copies, 1.5 GB, at
-        # the published widths)
+        # the published widths; PR 34). That cured the KDA stacks; the
+        # attention's (E, H*D) stacks were still copied (to E minor-most:
+        # ``wq`` and ``wg`` 268 MB a step) until a runner kept them so
+        # (``param_layouts``, PR 53)
         attn = {k: layer.pop(k) for k in ("wq", "wk", "wv", "wo")}
         if cfg.is_latent:
             # "mla" over the latent-attention layers: _latent_layer_specs'
@@ -268,6 +276,54 @@ def param_specs(cfg: ModelConfig) -> dict:
     if not cfg.tie_word_embeddings:
         specs["lm_head"] = (L.EMBED, L.VOCAB)
     return specs
+
+
+# the projections from the embedding onto heads, by stack: the leaves a
+# decode step's 64 rows multiply whole, a layer at a time
+_ATTN_PROJECTIONS = {"layers": ("wq", "wk", "wv"),
+                     "gqa": ("wq", "wk", "wv", "wg")}
+
+
+def param_layouts(cfg: ModelConfig) -> dict:
+    """Pytree shaped like ``param_specs(cfg)``: the order of its axes,
+    major to minor, in which a runner keeps a leaf on the device, or None
+    for the order ``init_params`` makes it in. ``engine/weights.py``
+    ``lay_out`` turns a loaded tree into the kept one: a leaf ``w`` with an
+    order becomes ``w_t``, transposed to it, the axes between the stack's
+    and the last as one: ``wq`` (L, E, H, D) -> ``wq_t`` (L, H * D, E), the
+    form Falcon-H1's stacks are made in. ``init_params``, the checkpoint
+    loaders and whoever reads their tree by shape (the benchmark's
+    references) keep the logical tree; the forward takes either
+    (``_onto_heads``, ``_forward_hybrid``'s ``gqa``).
+
+    One rule: a stack of projections from the embedding onto heads lies
+    with its contracted axis (EMBED, found in the leaf's spec) minor-most.
+    That is how the TPU compiler wants a matrix that a decode step's 64
+    rows multiply; handed the stack in another order it copied it WHOLE at
+    the start of every step, outside the layer scan: W_q, W_k and W_v of
+    Qwen3's sixteen layers 805 MB a step, Ouro's 1.2 GB (in its 512-wide
+    ragged program too), OLMoE's 201 MB, Solar-Open2's W_q and W_g 268 MB,
+    Phi-4-mini-flash's 328 MB; the ragged programs re-laid a layer's slice
+    inside the scan (PERF.md section 5, PR 53). Why a stored transpose and
+    not ``jax.experimental.layout``: an array made or placed with a
+    ``Format`` by a program that jax 0.9.0 loads from its persistent
+    compile cache comes back DESCRIBED row-major while its bytes are not,
+    and every program that then reads it computes on the wrong values (my
+    chip runs, PR 53). The sub-modules answer for their own stacks."""
+    L = lax_names
+    specs = param_specs(cfg)
+    layouts = jax.tree.map(lambda _: None, specs,
+                           is_leaf=lambda x: isinstance(x, tuple))
+    for stack, names in _ATTN_PROJECTIONS.items():
+        for name in names:
+            axes = specs.get(stack, {}).get(name)
+            if axes is not None and L.EMBED in axes:
+                e = axes.index(L.EMBED)
+                layouts[stack][name] = (
+                    *(i for i in range(len(axes)) if i != e), e)
+    if cfg.mamba_period:
+        layouts.update(sambay.param_layouts(cfg))
+    return layouts
 
 
 # Random stand-in weights of a looped stack (cfg.loop_passes > 1): the gain
@@ -792,6 +848,19 @@ def _rms_norm_heads(x: jnp.ndarray, weight: jnp.ndarray,
             * weight.astype(jnp.float32)).astype(x.dtype)
 
 
+def _onto_heads(x: jnp.ndarray, lp: dict, name: str, head_dim: int
+                ) -> jnp.ndarray:
+    """x (..., T, E) through the projection ``name`` of ``lp`` onto heads,
+    (..., T, heads, head_dim): ``lp[name]`` (E, heads, head_dim) as
+    ``init_params`` makes it (or its quantized container), or
+    ``lp[name + "_t"]`` (heads * head_dim, E) as a runner keeps it
+    (``param_layouts``): one product over E, the PRODUCT reshaped."""
+    if name + "_t" not in lp:
+        return quant_einsum("...te,ehd->...thd", x, lp[name])
+    y = quant_einsum("...te,fe->...tf", x, lp[name + "_t"])
+    return y.reshape(*y.shape[:-1], -1, head_dim)
+
+
 def _lora_delta(x: jnp.ndarray, onehot: jnp.ndarray, A: jnp.ndarray,
                 B: jnp.ndarray) -> jnp.ndarray:
     """Per-token LoRA delta with a bank of N adapters.
@@ -928,9 +997,8 @@ def forward_hidden(
                 o, caches = _mla_mixer(cfg, lp, normed, positions, attend,
                                        caches, cache_layer)
             return mlp_half(h, o, lp, lb, layer_idx, caches, sparse)
-        q = quant_einsum("...te,ehd->...thd", normed, lp["wq"])
-        k = quant_einsum("...te,ehd->...thd", normed, lp["wk"])
-        v = quant_einsum("...te,ehd->...thd", normed, lp["wv"])
+        q, k, v = (_onto_heads(normed, lp, w, cfg.head_dim)
+                   for w in ("wq", "wk", "wv"))
         if lb is not None:
             if "wq" in lb:
                 q = q + _lora_delta(normed, onehot, *lb["wq"])
@@ -1127,10 +1195,12 @@ def _sparse_block(cfg: ModelConfig, lp: dict, experts: dict, layer_idx,
     return out, hist
 
 
-def _gated(attn: jnp.ndarray, x: jnp.ndarray, wg) -> jnp.ndarray:
+def _gated(attn: jnp.ndarray, x: jnp.ndarray, gp: dict) -> jnp.ndarray:
     """The attention output times sigmoid(W_g x), elementwise over heads
-    and head_dim, before the output projection."""
-    gate = quant_einsum("...te,ef->...tf", x, wg).reshape(attn.shape)
+    and head_dim, before the output projection; W_g as made, (E, H * D),
+    or as a runner keeps it, ``wg_t`` (``param_layouts``)."""
+    eq, wg = as_stored(gp, "wg")
+    gate = quant_einsum(eq, x, wg).reshape(attn.shape)
     return (attn.astype(jnp.float32)
             * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(attn.dtype)
 
@@ -1219,15 +1289,17 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
         (nothing rotated, a sigmoid gate) or Falcon-H1's (``rotate``: rope
         on q and k, the keys times ``key_multiplier``; no gate)."""
         if "wq_t" in gp:
-            # W_q, W_k, W_v lie transposed, (H * D, E): the layout the TPU
-            # compiler gives them for a decode step's 64 rows; handed (E,
-            # H * D) it copied all three stacks whole, 220 MB, at the start
-            # of every decode step (PERF.md section 6, PR 52). Solar-Open2's
-            # (E, H * D) stacks below ARE copied so: its decode program,
-            # compiled for the described v5e at the published widths, copies
-            # wq and wg (134 MB each) and wk, wv (17 MB each) whole at the
-            # start of a step, 347.6 MiB of temporaries (PR 52). One layout
-            # and no branch once its cells are measured on it: ROADMAP S20
+            # W_q, W_k, W_v lie transposed, (H * D, E): the order of bytes
+            # the TPU compiler wants for a decode step's 64 rows; handed
+            # (E, H * D) it copied all three stacks whole, 220 MB, at the
+            # start of every decode step (PERF.md section 6, PR 52).
+            # Falcon-H1's stacks are MADE so (models/falcon_h1.py);
+            # Solar-Open2's are made as below, (E, H * D), the shape the
+            # benchmark's references read (chipbench/reference/*.py take
+            # ``init_random``'s tree by shape), and a runner keeps them
+            # transposed (``param_layouts``, PR 53): its decode program
+            # copied wq and wg whole, 268 MB a step. One made form once a
+            # ``benchmark`` issue lets the references read it: ROADMAP S20
             q, k, v = (quant_einsum("...te,fe->...tf", normed, gp[w])
                        for w in ("wq_t", "wk_t", "wv_t"))
             k, v = (y.reshape(*y.shape[:-1], cfg.num_kv_heads, cfg.head_dim)
@@ -1246,7 +1318,7 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
         if caches is not None:
             caches = {**caches, "kv": kv}
         if cfg.attn_gate:
-            attn = _gated(attn, normed, gp["wg"])
+            attn = _gated(attn, normed, gp)
         return quant_einsum("...thd,hde->...te", attn, gp["wo"]), caches
 
     def at(tree, i):

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from production_stack_tpu.engine.config import ModelConfig
-from production_stack_tpu.engine.quant import quant_einsum
+from production_stack_tpu.engine.quant import as_stored, quant_einsum
 from production_stack_tpu.ops import kda, mamba
 from production_stack_tpu.ops.norms import rms_norm
 from production_stack_tpu.parallel import shardings as lax_names
@@ -77,6 +77,20 @@ def param_specs(cfg: ModelConfig) -> dict:
         "gmu": {"w_in": (L, None, None), "w_out": (L, None, None)},
         "cross": attn,
     }
+
+
+def param_layouts(cfg: ModelConfig) -> dict:
+    """The order of axes in which a runner keeps each leaf of
+    ``param_specs`` (models/llama.py ``param_layouts``): the projections
+    from the embedding onto all heads, (n, E, H * D), with the embedding
+    axis last, as ``wq_t`` (n, H * D, E). A decode step copied the four
+    stacks whole as they are made, 328 MB (PERF.md section 5, PR 53)."""
+    layouts = jax.tree.map(lambda _: None, param_specs(cfg),
+                           is_leaf=lambda x: isinstance(x, tuple))
+    for stack, names in (("attn", ("wq", "wk", "wv")), ("cross", ("wq",))):
+        layouts[stack] = {**layouts[stack],
+                          **dict.fromkeys(names, (0, 2, 1))}
+    return layouts
 
 
 # Random stand-in weights of a state-space layer: ``dt`` log-uniform in
@@ -175,8 +189,8 @@ def packed_queries(cfg: ModelConfig, ap: dict, x: jnp.ndarray) -> jnp.ndarray:
     """(..., T, H, 2 D): head 2i as ``[q, 0]``, head 2i + 1 as ``[0, q]``,
     times sqrt(2) (see the header)."""
     H, D = cfg.num_heads, cfg.head_dim
-    q = (jnp.einsum("...te,ef->...tf", x, ap["wq"],
-                    preferred_element_type=F32)
+    eq, wq = as_stored(ap, "wq")
+    q = (jnp.einsum(eq, x, wq, preferred_element_type=F32)
          + ap["bq"].astype(F32)) * 2.0 ** 0.5
     q = q.reshape(*q.shape[:-1], H, D).astype(x.dtype)
     first = (jnp.arange(H) % 2 == 0)[:, None]
@@ -198,7 +212,8 @@ def packed_keys_values(cfg: ModelConfig, ap: dict, x: jnp.ndarray):
     """k', v' (..., T, cache_kv_heads, 2 D): a pair's two heads side by
     side, then the empty heads."""
     def heads(w, b):
-        y = quant_einsum("...te,ef->...tf", x, ap[w]) + ap[b]
+        eq, matrix = as_stored(ap, w)
+        y = quant_einsum(eq, x, matrix) + ap[b]
         return _pad_heads(
             y.reshape(*y.shape[:-1], -1, cfg.cache_head_dim),
             cfg.cache_kv_heads)

@@ -823,68 +823,243 @@ def test_a_group_that_divides_no_power_of_two_keeps_a_narrow_block():
     assert decode_window_body(G5_KH, G5, D, jnp.bfloat16) == "grouped"
 
 
-def test_falcon_h1s_decode_program_copies_no_weight_stack(one_chip):
-    """The engine's own decode program at the published widths, whole, for
-    the described v5e. The TPU compiler lays a projection's stack out as
-    the step's 64 rows like it and, handed another, copies the WHOLE stack
-    at the start of every decode step, outside the layer scan: W_in at its
-    published 9248 columns (no whole 128-lane tiles) 568 MB, W_q / W_k / W_v
-    as (E, H D) 220 MB (PERF.md section 6, PR 52: 7 % of the chip's busy
-    time went to the first). So W_in lies as its [z | xBC] and its dt
-    columns and the three lie transposed (models/falcon_h1.py): the
-    program's temporaries are a step's rows, nothing more."""
-    import functools
+# The engine's own step programs at the published widths, whole, for the
+# described v5e: configuration (chipbench/configs/<name>) -> the blocks its
+# pool holds on the chip (the ledger's kv_cache_write shapes; a pool larger
+# than the chip's memory is refused at compile time).
+STEP_CONFIGS = {
+    "qwen3-8b-l16": 4859,
+    "olmoe-1b-7b-l8": 8192,
+    "ouro-2.6b": 330,
+    "solar-open2-250b-ep16-l8": 4096,
+    "openpangu-ultra-moe-718b-ep16-l5": 4096,
+    "phi-4-mini-flash-reasoning": 4096,
+    "kimi-linear-48b-a3b-ep16": 4096,
+    "falcon-h1-34b-l6": 8192,
+}
+SLOTS_SERVED = 64
+# a whole weight stack is tens of MiB and up; a step's own rows are not
+COPY_FLOOR = 16 * 2 ** 20
+
+
+def _chipbench_cfg(config):
+    """(the configuration as its cell's engine reads it: the published
+    config.json, ``--max-model-len`` from the manifest's engine flags; the
+    compiler options its manifest sets in the engine's environment)."""
+    import dataclasses
     import json
     import os
+
+    from production_stack_tpu.engine.config import ModelConfig
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "configs", config)
+    with open(os.path.join(root, "config.json")) as f:
+        cfg = ModelConfig.from_hf_config(json.load(f), config)
+    with open(os.path.join(root, "manifest.json")) as f:
+        manifest = json.load(f)
+    flags = manifest["engine_flags"]
+    # LIBTPU_INIT_ARGS=--xla_tpu_scoped_vmem_limit_kib=N, as an option
+    options = {
+        k.lstrip("-"): int(v) for k, _, v in (
+            a.partition("=") for a in manifest.get("engine_env", {}).get(
+                "LIBTPU_INIT_ARGS", "").split())}
+    return dataclasses.replace(
+        cfg, max_model_len=int(flags[flags.index("--max-model-len") + 1])
+    ), options
+
+
+def _cache_shapes(cfg, blocks, slots, one_chip):
+    """The cache pytree ``engine/kv_cache.py`` ``init_kv_cache`` makes (it
+    allocates, so it cannot be asked for shapes alone)."""
+    from production_stack_tpu.engine import kv_cache as kvmod
+
+    def sds(shape, dt=cfg.jax_dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds(cfg.kv_pool_shape(blocks, BS))
+    if not cfg.has_recurrent_state:
+        return pool
+    if cfg.mamba_period:
+        n, di = cfg.count_layers("mamba"), cfg.mamba_inner
+        state, conv = (cfg.mamba_state, di), (cfg.mamba_conv - 1, di)
+    elif cfg.ssd_heads:
+        n = cfg.count_layers("parallel")
+        state = (cfg.ssd_heads, cfg.ssd_state, cfg.ssd_head_dim)
+        conv = (cfg.ssd_conv - 1, cfg.ssd_conv_dim)
+    else:
+        n, h, d = cfg.num_kda_layers, cfg.kda_heads, cfg.kda_head_dim
+        state, conv = (h, d, d), (cfg.kda_conv - 1, 3 * h * d)
+    caches = {"kv": pool, "state": sds((n, slots, *state), jnp.float32),
+              "conv": sds((n, slots, *conv))}
+    if cfg.window_binds:
+        caches["win"] = sds(cfg.kv_pool_shape(
+            kvmod.window_pool_blocks(cfg, BS, slots, 2048), BS, window=True))
+    return caches
+
+
+def _step_program(one_chip, config, program, layouts=True):
+    """``_decode_multi_step`` ("decode") or ``_ragged_step`` ("ragged512",
+    "ragged2048": the stream's width) of a chipbench configuration,
+    compiled as the runner jits it, without a runner: no weights are made
+    and no cache. The parameters are the tree a runner keeps
+    (``engine/weights.py`` ``lay_out``; ``layouts`` False: the tree as it is
+    made, what every runner kept before PR 53 and PERF.md section 5's
+    survey lists under "parent")."""
+    import functools
     import types
 
     import numpy as np
 
     from production_stack_tpu.engine import model_runner as mr
-    from production_stack_tpu.engine.config import ModelConfig
-    from production_stack_tpu.models import llama
+    from production_stack_tpu.engine import weights
+    from production_stack_tpu.models.registry import get_model
+    from production_stack_tpu.ops.moe_grouped_matmul_pallas import (
+        grouped_kernel_path,
+        moe_grouped_matmul,
+    )
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "chipbench", "configs", "falcon-h1-34b-l6",
-                           "config.json")) as f:
-        cfg = ModelConfig.from_hf_config(json.load(f), "falcon")
+    (cfg, options), blocks, slots = (
+        _chipbench_cfg(config), STEP_CONFIGS[config], SLOTS_SERVED)
 
-    def sds(shape, dt):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    def made():
+        tree = get_model(cfg).init_params(cfg, jax.random.PRNGKey(0))
+        return weights.lay_out(cfg, tree) if layouts else tree
 
+    shapes = jax.eval_shape(made)
     params = jax.tree.map(
-        lambda a: sds(a.shape, a.dtype),
-        jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))))
-    slots, width = 64, 512
-    caches = {"kv": sds(cfg.kv_pool_shape(8192, BS), jnp.bfloat16),
-              "state": sds(SSD_STATE[0], jnp.float32),
-              "conv": sds((6, slots, 3, cfg.ssd_conv_dim), jnp.bfloat16)}
-    # the runner's two stateful calls without a runner (no weights made)
-    me = types.SimpleNamespace(cfg=cfg, use_pallas=True, tp=1,
-                               _sharded=lambda inner: inner)
-    me._state_space = functools.partial(mr.ModelRunner._state_space, me)
-    arrays = [np.zeros(s, dt) for s, dt in (
-        (slots, np.int32), (slots, np.int32), ((slots, width), np.int32),
-        (slots, np.int32), (slots, np.int32), (slots, np.float32),
-        (slots, np.float32), (slots, np.int32), (slots, np.uint32),
-        (slots, np.int32), (1, np.int32))]
-    layout = mr.StepLayout.of(mr._DECODE_INPUTS, arrays)
-    step = functools.partial(
-        mr._decode_multi_step, cfg,
-        functools.partial(mr.ModelRunner._attend_decode, me), 1, 0,
-        recur_impl=functools.partial(mr.ModelRunner._recur_ssd, me, False))
-    compiled = jax.jit(
-        step, donate_argnums=(1,),
-        static_argnames=("layout", "block_size", "greedy_only",
-                         "want_logprobs")).lower(
-        params, caches, sds(layout.pack(arrays).shape, I32),
-        sds((slots, 1), I32), layout=layout, block_size=BS,
-        greedy_only=True, want_logprobs=False).compile()
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes)
+    caches = _cache_shapes(cfg, blocks, slots, one_chip)
+    # the runner's stateful calls without a runner: one chip, so no
+    # shard_map (``tp`` reads the rules and finds no axis split)
+    me = object.__new__(mr.ModelRunner)
+    me.cfg, me.use_pallas = cfg, True
+    me.rules = types.SimpleNamespace(rules={})
+    recur = (me._recur_mamba if cfg.mamba_period
+             else me._recur_ssd if cfg.ssd_heads else me._recur)
+    grouped = (moe_grouped_matmul if cfg.is_moe and all(
+        grouped_kernel_path(*shapes["layers"][w].shape[-2:], 2)
+        for w in ("w_gate", "w_down")) else None)
+    table = (slots, (-(-cfg.max_model_len // BS) + 7) // 8 * 8)
+    i32, f32, u32 = np.int32, np.float32, np.uint32
+    if program == "decode":
+        arrays = [np.zeros(s, dt) for s, dt in (
+            (slots, i32), (slots, i32), (table, i32), (slots, i32),
+            (slots, i32), (slots, f32), (slots, f32), (slots, i32),
+            (slots, u32), (slots, i32), (1, i32))]
+        spec, window = mr._DECODE_INPUTS, [np.zeros(table, i32),
+                                           np.zeros(slots, i32)]
+        step = functools.partial(
+            mr._decode_multi_step, cfg, me._attend_decode, 1, 0,
+            recur_impl=(functools.partial(recur, False)
+                        if cfg.has_recurrent_state else None),
+            grouped_matmul=grouped)
+        static = ("layout", "block_size", "greedy_only", "want_logprobs")
+        # the device tokens of a chained dispatch
+        behind = [jax.ShapeDtypeStruct((slots, 1), I32, sharding=one_chip)]
+        flags = {"block_size": BS, "want_logprobs": False}
+    else:
+        width = int(program.removeprefix("ragged"))
+        arrays = [np.zeros(s, dt) for s, dt in (
+            ((1, width), i32), ((1, width), i32), (table, i32),
+            (slots, i32), (slots + 1, i32), (width, i32), (slots, i32),
+            (slots, f32), (slots, f32), (slots, f32), (slots, i32),
+            (slots, u32), (slots, i32))]
+        spec, window = mr._RAGGED_INPUTS[:len(arrays)], [
+            np.zeros(table, i32), np.zeros(width, i32)]
+        step = functools.partial(
+            mr._ragged_step, cfg, me._attend_ragged, 0, 0,
+            recur_impl=(functools.partial(recur, True)
+                        if cfg.has_recurrent_state else None),
+            grouped_matmul=grouped)
+        static = ("layout", "greedy_only")
+        behind, flags = [], {}
+    if cfg.window_binds:
+        arrays, spec = arrays + window, spec + mr._WINDOW_INPUTS
+    layout = mr.StepLayout.of(spec, arrays)
+    packed = jax.ShapeDtypeStruct(layout.pack(arrays).shape, I32,
+                                  sharding=one_chip)
+    return jax.jit(step, donate_argnums=(1,), static_argnames=static).lower(
+        params, caches, packed, *behind, layout=layout, greedy_only=True,
+        **flags).compile(compiler_options=options)
+
+
+def _parameter_copies(text):
+    """[(instruction, its operand, bytes, layout)] of every ``copy`` of a
+    leaf of the program's ``params`` argument in compiled HLO text (the
+    entry computation names it by its path, ``%params__layers____wq__``):
+    what a step does to a weight stack that does not lie as the program
+    reads it."""
+    sizes = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1, "f16": 2}
+    found = []
+    for m in re.finditer(
+            r"^\s*%(copy[.\d]*) = (\w+)\[([\d,]*)\](\{[^}]*\})? "
+            r"copy\([^%]*%(params__[\w.]*)\)", text, flags=re.M):
+        name, dt, dims, lay, operand = m.groups()
+        n = sizes.get(dt, 4)
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        found.append((name, operand, n, lay))
+    return found
+
+
+# temporaries a program may hold, MiB: a step's own rows. Before PR 53
+# Qwen3's decode program held 768.8 (W_q, W_k, W_v copied whole), Ouro's
+# 1,152.9 and its 512-wide ragged program 1,157.0, OLMoE's 199.1,
+# Solar-Open2's 365.6; Falcon-H1's 781 before PR 52
+STEP_TEMP_MIB = {
+    ("qwen3-8b-l16", "decode"): 32,
+    ("ouro-2.6b", "decode"): 32,
+    ("ouro-2.6b", "ragged512"): 32,
+    ("olmoe-1b-7b-l8", "decode"): 32,
+    ("solar-open2-250b-ep16-l8", "decode"): 100,
+    ("falcon-h1-34b-l6", "decode"): 32,
+}
+# whole stacks still copied (ROADMAP S20): a width that is no whole 128-lane
+# tiles (the router's 320 experts, W_x's 192 columns), as Falcon-H1's W_in
+# was before it was split; and the one leading dense layer's rotated query
+# columns of a latent stack, 24 MiB of a ~150 ms step, which
+# ``param_layouts`` does not name
+STILL_COPIED = {
+    ("solar-open2-250b-ep16-l8", "decode"): "params__layers____router__",
+    ("phi-4-mini-flash-reasoning", "decode"): "params__mamba____w_x__",
+    ("openpangu-ultra-moe-718b-ep16-l5", "ragged2048"):
+        "params__dense____wq_rope__",
+}
+STEP_CASES = [(c, p) for c in STEP_CONFIGS for p in ("decode", "ragged512")]
+# the configurations whose cells fill the wide stream (prompts of 1024
+# tokens and more)
+STEP_CASES += [(c, "ragged2048") for c in (
+    "qwen3-8b-l16", "solar-open2-250b-ep16-l8",
+    "openpangu-ultra-moe-718b-ep16-l5", "phi-4-mini-flash-reasoning",
+    "kimi-linear-48b-a3b-ep16")]
+
+
+@pytest.mark.parametrize("config,program", STEP_CASES,
+                         ids=[f"{c}-{p}" for c, p in STEP_CASES])
+def test_step_program_copies_no_weight_stack(one_chip, config, program):
+    """The TPU compiler lays a projection's stack out as the step's rows
+    like it and, handed another, copies the WHOLE stack at the start of
+    every step, outside the layer scan: Falcon-H1's W_in at its published
+    9248 columns (no whole 128-lane tiles) 568 MB and W_q / W_k / W_v as
+    (E, H D) 220 MB (PERF.md section 6, PR 52: 7 % of the chip's busy time
+    went to the first); Qwen3's W_q / W_k / W_v as (E, H, D) 805 MB, Ouro's
+    1.2 GB (PR 53). So a runner keeps an attention projection's stack
+    with its contracted axis last (``llama.param_layouts``,
+    ``weights.lay_out``): the program's temporaries are a step's rows,
+    nothing more."""
+    compiled = _step_program(one_chip, config, program)
     text = compiled.as_text()
-    for name in ("ssd_decode_step", "paged_decode_attention",
-                 "kv_cache_write"):
-        assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = ", text,
-                         flags=re.M), name
-    # 781 MiB with W_in whole and the three as (E, H D); the weights are
-    # 10.5 GB, the state 1.6 GB
-    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
+    if (config, program) == ("falcon-h1-34b-l6", "decode"):
+        for name in ("ssd_decode_step", "paged_decode_attention",
+                     "kv_cache_write"):
+            assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = ", text,
+                             flags=re.M), name
+    whole = [c for c in _parameter_copies(text) if c[2] >= COPY_FLOOR
+             and not c[1].startswith(STILL_COPIED.get((config, program), "-"))]
+    assert not whole, whole
+    bound = STEP_TEMP_MIB.get((config, program))
+    if bound is not None:
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < bound * 2 ** 20, temp / 2 ** 20

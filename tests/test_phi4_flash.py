@@ -36,6 +36,7 @@ from production_stack_tpu.engine.sampling import SamplingParams
 from production_stack_tpu.engine.scheduler import Scheduler
 from production_stack_tpu.engine.sequence import Sequence
 from production_stack_tpu.engine.tracing import WindowCounters
+from production_stack_tpu.engine.weights import init_random
 from production_stack_tpu.models import llama, sambay
 from production_stack_tpu.ops import kda, mamba, mamba_pallas
 from production_stack_tpu.ops.attention import dense_causal_attention
@@ -98,6 +99,14 @@ def serve(eng, prompts, max_tokens=12, watch=None):
 
 def prompt(n, seed=0):
     return np.random.default_rng(seed).integers(0, CFG.vocab_size, n).tolist()
+
+
+def made(eng):
+    """The tree as it is made, which the reference reads by shape; the
+    runner keeps its own laid out (engine/weights.py ``lay_out``), made
+    from the same seed: what chipbench/reference/compare.py does."""
+    r = eng.runner
+    return init_random(r.cfg, r.mesh, r.rules, r.config.seed)
 
 
 def errors(params, ids, toks, lps, hf=HF):
@@ -434,7 +443,7 @@ def served():
 def test_served_logprobs_match_the_reference_at_every_row(served, name):
     eng, prompts, out = served
     toks, lps = out[name]
-    err = errors(eng.runner.params, prompts[name], toks, lps)
+    err = errors(made(eng), prompts[name], toks, lps)
     assert len(toks) == 24 and err.max() < LOGPROB_TOL, err
 
 
@@ -446,7 +455,7 @@ def test_chunk_block_and_window_edges(budget, plen):
     eng = engine(budget=budget)
     ids = prompt(plen, plen)
     toks, lps = serve(eng, {"a": ids}, max_tokens=10)["a"]
-    assert errors(eng.runner.params, ids, toks, lps).max() < LOGPROB_TOL
+    assert errors(made(eng), ids, toks, lps).max() < LOGPROB_TOL
 
 
 def test_the_decode_program_and_the_ragged_one_agree(served):
@@ -672,7 +681,7 @@ def _served_errors(params=None, plen=41):
     eng = engine(params=params if params is not None else make_params(0))
     ids = prompt(plen, 10)
     toks, lps = serve(eng, {"a": ids}, max_tokens=24)["a"]
-    return errors(eng.runner.params, ids, toks, lps)
+    return errors(eng.runner.params, ids, toks, lps)  # the handed tree
 
 
 def test_fault_state_not_carried_across_a_chunk(monkeypatch):

@@ -33,6 +33,7 @@ from production_stack_tpu.engine.kv_cache import (
 from production_stack_tpu.engine.metrics import EngineStatsCollector
 from production_stack_tpu.engine.sampling import SamplingParams
 from production_stack_tpu.engine.scheduler import Scheduler
+from production_stack_tpu.engine.weights import init_random
 from production_stack_tpu.models import llama
 from production_stack_tpu.ops import kda, kda_pallas
 from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -85,6 +86,14 @@ def serve(eng, prompts, max_tokens=6):
     return {n: (toks[n], lps[n]) for n in prompts}
 
 
+def made(eng):
+    """The tree as it is made, which the reference reads by shape; the
+    runner keeps its own laid out (engine/weights.py ``lay_out``), made
+    from the same seed: what chipbench/reference/compare.py does."""
+    r = eng.runner
+    return init_random(r.cfg, r.mesh, r.rules, r.config.seed)
+
+
 def errors(hf, params, prompt, toks, lps, **control):
     """|served - reference| log-probability of each generated token."""
     ids = list(prompt) + toks
@@ -116,7 +125,7 @@ def test_served_logprobs_match_the_reference(served, name):
     cache and the recurrent state."""
     eng, out = served
     toks, lps = out[name]
-    err = errors(HF, eng.runner.params, PROMPTS[name], toks, lps)
+    err = errors(HF, made(eng), PROMPTS[name], toks, lps)
     assert len(toks) == 6 and err.max() < LOGPROB_TOL, err
 
 
@@ -128,11 +137,11 @@ def over_a_limit(err, margin=1.5) -> bool:
 
 
 def _beta_not_doubled(eng):
-    return {**HF, "kda_allow_neg_eigval": False}, eng.runner.params
+    return {**HF, "kda_allow_neg_eigval": False}, made(eng)
 
 
 def _an_expert_dropped(eng):
-    params = jax.tree.map(lambda a: a, eng.runner.params)
+    params = jax.tree.map(lambda a: a, made(eng))
     layers = dict(params["layers"])
     # the second held expert of every layer answers nothing
     layers["w_down"] = layers["w_down"].at[:, 1].set(0.0)
@@ -167,7 +176,7 @@ def test_state_dropped_at_a_chunk_boundary_reads_over_the_limits(
     monkeypatch.setattr(kda, "stream_spans", forgetful)
     eng = engine(params=served[0].runner.params)
     toks, lps = serve(eng, {"long": PROMPTS["long"]})["long"]
-    err = errors(HF, eng.runner.params, PROMPTS["long"], toks, lps)
+    err = errors(HF, made(eng), PROMPTS["long"], toks, lps)
     assert over_a_limit(err, 2.0), err  # reads 0.35 / 0.149
 
 
@@ -181,7 +190,7 @@ def test_a_reference_in_lower_precision_reads_as_not_correct(served, control):
     activations: what the cell's probe reads on the chip is in PERF.md."""
     eng, out = served
     toks, lps = out["long"]
-    err = errors(HF, eng.runner.params, PROMPTS["long"], toks, lps,
+    err = errors(HF, made(eng), PROMPTS["long"], toks, lps,
                  **{control: "bfloat16"})
     assert err.max() > 10 * LOGPROB_TOL, err
     assert not over_a_limit(err, 1.0), err
@@ -258,7 +267,7 @@ def test_a_slot_reused_by_a_new_sequence_starts_from_zero(served):
     prompt = [int(t) for t in np.random.default_rng(9).integers(0, 512, 19)]
     toks, lps = serve(eng, {"again": prompt})["again"]
     assert float(jnp.abs(eng.runner.kv["state"]).max()) > 0
-    assert errors(HF, eng.runner.params, prompt, toks, lps).max() < LOGPROB_TOL
+    assert errors(HF, made(eng), prompt, toks, lps).max() < LOGPROB_TOL
     assert eng.stats()["recurrent_state_resets_total"] == resets + 1
 
 
