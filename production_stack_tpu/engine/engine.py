@@ -30,7 +30,7 @@ from production_stack_tpu.engine.sequence import (
     SequenceStatus,
 )
 from production_stack_tpu.engine.tokenizer import get_tokenizer
-from production_stack_tpu.engine.tracing import StepClock
+from production_stack_tpu.engine.tracing import StartClock, StepClock
 from production_stack_tpu.ops.kda import continues_one_row
 from production_stack_tpu.ops.ragged_paged_attention_pallas import (
     count_walks,
@@ -79,10 +79,30 @@ class LLMEngine:
         mesh: Optional[Mesh] = None,
         params: Optional[dict] = None,
         num_blocks: Optional[int] = None,
+        start: Optional[StartClock] = None,
     ):
+        # where this replica's start goes (engine/tracing.py): `main()`'s
+        # clock, which already holds `process` and `backend_open`, or one
+        # of this engine's own; building the engine is one span of it,
+        # `engine_build`, whose children the runner opens
+        self.start = start if start is not None else StartClock()
+        if config.perf.enabled:
+            # before the first program is built: the weights' and the
+            # pool's programs are builds too (kind `other`)
+            from production_stack_tpu.engine.perf_accounting import (
+                build_stages,
+            )
+
+            build_stages()
+        with self.start.span("engine_build"):
+            self._build(config, mesh, params, num_blocks)
+
+    def _build(self, config: EngineConfig, mesh: Optional[Mesh],
+               params: Optional[dict], num_blocks: Optional[int]) -> None:
         self.config = config
         self.mesh = mesh if mesh is not None else build_mesh(config.mesh)
-        self.tokenizer = get_tokenizer(config.model.tokenizer)
+        with self.start.span("tokenizer"):
+            self.tokenizer = get_tokenizer(config.model.tokenizer)
         from production_stack_tpu.parallel.mesh import AXIS_SEQ, AXIS_STAGE
 
         for axis in (AXIS_STAGE, AXIS_SEQ):
@@ -93,7 +113,8 @@ class LLMEngine:
                     "removed, shard a model over the tensor axis")
         if config.model.is_latent:
             ModelRunner._refuse_for_latent_cache(config, self.mesh)
-        self.runner = ModelRunner(config, self.mesh, params, num_blocks)
+        self.runner = ModelRunner(config, self.mesh, params, num_blocks,
+                                  start=self.start)
         # where this thread's time goes, always on (engine/tracing.py);
         # the runner switches its own phases (snapshot, commit, launch)
         self.clock = self.runner.clock = StepClock()
@@ -319,11 +340,17 @@ class LLMEngine:
 
             self.perf = PerfAccountant.from_runner(config, self.runner)
             self.runner.install_compile_observer(self._on_compile)
+        # the weights' and the pool's programs were dispatched and their
+        # spans closed at the host's return: how long the device still
+        # runs them is this one wait (the first request needs both)
+        with self.start.span("device_drain"):
+            jax.block_until_ready((self.runner.params, self.runner.kv))
 
-    def _on_compile(self, kind: str, bucket: str, seconds: float) -> None:
-        # the step clock names a slow step's cause `compile` if this moved
-        self.clock.compiles += 1
-        self.perf.on_compile(kind, bucket, seconds)
+    def _on_compile(self, kind: str, bucket: str, seconds: float,
+                    build: dict) -> None:
+        # the step clock names a slow step's cause `compile` after this
+        self.clock.note_build(build)
+        self.perf.on_compile(kind, bucket, seconds, build)
 
     # -- request intake ------------------------------------------------------
     def add_request(

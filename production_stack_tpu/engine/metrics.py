@@ -607,6 +607,60 @@ class EngineStatsCollector:
                 "(first-call time per new program signature)",
                 perf["compile_seconds_total"],
             )
+            # a program's first call in stages (perf_accounting.py
+            # BuildStages): the tracked programs by kind, the others as
+            # kind `other`
+            by_stage = CounterMetricFamily(
+                "vllm:program_build_seconds",
+                "Seconds of programs' first calls by program kind and "
+                "stage: trace, lower, compile (the persistent cache did "
+                "not answer), cache_load (it did), first_run (the rest of "
+                "the call: transfer, dispatch, execution)",
+                labels=["model_name", "kind", "stage"],
+            )
+            # a stage over all kinds is a family of its own beside it
+            stage_families = (
+                ("vllm:program_trace_seconds", "trace"),
+                ("vllm:program_lower_seconds", "lower"),
+                ("vllm:program_compile_seconds", "compile"),
+                ("vllm:program_cache_load_seconds", "cache_load"),
+                ("vllm:program_first_run_seconds", "first_run"))
+            totals = {stage: 0.0 for _, stage in stage_families}
+            for kind, stages in sorted(
+                    perf["program_build_seconds"].items()):
+                for stage, sec in stages.items():
+                    by_stage.add_metric([self.model_name, kind, stage], sec)
+                    totals[stage] += sec
+            yield by_stage
+            for name, stage in stage_families:
+                yield counter(
+                    name,
+                    f"Seconds of programs' first calls spent in the stage "
+                    f"{stage}, all kinds (vllm:program_build_seconds)",
+                    totals[stage],
+                )
+            built = CounterMetricFamily(
+                "vllm:program_builds",
+                "Programs built (first calls of a new signature) by kind; "
+                "`other`: backend compile requests of untracked programs",
+                labels=["model_name", "kind"],
+            )
+            for kind, n in sorted(perf["program_builds"].items()):
+                built.add_metric([self.model_name, kind], n)
+            yield built
+            yield counter(
+                "vllm:compile_cache_hits",
+                "Backend compile requests the persistent compile cache "
+                "answered",
+                perf["compile_cache_hits"],
+            )
+            yield counter(
+                "vllm:compile_cache_misses",
+                "Backend compile requests it did not answer: the program "
+                "was compiled (a cold cache, a program it did not keep or "
+                "did not find again, or no cache)",
+                perf["compile_cache_misses"],
+            )
             yield counter(
                 "vllm:unexpected_recompiles",
                 "Compiles observed after warmup marked the engine steady "
@@ -853,11 +907,52 @@ class LifecycleCollector:
             "of rotation until this clears)",
             1.0 if s.get("warming") else 0.0,
         )
+        # a replica's start in parts (engine/tracing.py StartClock): plain
+        # floats, fixed once the replica is ready
+        start = s["start_seconds"]
         yield gauge(
             "vllm:engine_warmup_seconds",
             "Wall time the completed warmup (all shape variants) took; "
             "0 until it finishes",
-            s.get("warmup_seconds", 0.0),
+            start["warmup"],
+        )
+        phases = GaugeMetricFamily(
+            "vllm:engine_start_seconds",
+            "Seconds of this replica's start by phase: process (creation "
+            "to main()), backend_open, engine_build, server_bind and "
+            "warmup follow each other; tokenizer, weights.make, "
+            "weights.quantize, weights.lay_out, kv_pool, device_drain and "
+            "engine_build.self (the rest) make up engine_build. A span "
+            "ends at the host's return; device_drain is the one wait for "
+            "the device",
+            labels=["model_name", "phase"],
+        )
+        for phase, seconds in start.items():
+            phases.add_metric([self.model_name, phase], seconds)
+        yield phases
+        # the phases a benchmark metric reads alone, each a family of its
+        # own (a scraper that sums a family over its labels can read them)
+        for name, what, parts in (
+                ("vllm:engine_start_process_seconds", "the process's "
+                 "creation to main()'s entry: the interpreter and the "
+                 "imports", ("process",)),
+                ("vllm:engine_start_backend_open_seconds", "jax.devices(): "
+                 "the backend's opening", ("backend_open",)),
+                ("vllm:engine_start_weights_seconds", "making (or "
+                 "loading), quantizing and laying out the weights, to the "
+                 "host's return",
+                 ("weights.make", "weights.quantize", "weights.lay_out")),
+                ("vllm:engine_start_kv_pool_seconds", "sizing and "
+                 "allocating the KV pool, a window pool and recurrent "
+                 "state, to the host's return", ("kv_pool",))):
+            yield gauge(name,
+                        f"Seconds of this replica's start spent in {what}",
+                        sum(start[p] for p in parts))
+        yield gauge(
+            "vllm:engine_start_to_ready_seconds",
+            "The process's creation to the instant /ready first would "
+            "answer 200; 0 until then",
+            s["start_to_ready_seconds"],
         )
 
 

@@ -52,6 +52,7 @@ from production_stack_tpu.engine.sampling import sample_tokens
 from production_stack_tpu.engine.tracing import (
     LoopCounters,
     MoeCounters,
+    StartClock,
     StepClock,
 )
 from production_stack_tpu.ops import kda
@@ -236,10 +237,15 @@ class ModelRunner:
         mesh: Mesh,
         params: Optional[dict] = None,
         num_blocks: Optional[int] = None,
+        start: Optional[StartClock] = None,
     ):
         self.config = config
         self.cfg = config.model
         self.mesh = mesh
+        # the start clock of whoever builds this runner (the engine's:
+        # these spans are children of its `engine_build`), else one of its
+        # own that nobody reads
+        self.start = start if start is not None else StartClock()
         # the engine's step clock (engine/tracing.py); the engine replaces
         # this one with its own, a runner driven alone keeps it
         self.clock = StepClock()
@@ -270,7 +276,7 @@ class ModelRunner:
         if params is None:
             self.params = self._loaded_params()
         else:
-            with jax.set_mesh(mesh):
+            with jax.set_mesh(mesh), self.start.span("weights.quantize"):
                 self.params = maybe_quantize(self.cfg, params)
         self.use_pallas = _pallas_ok(self.cfg, mesh, config.cache.block_size)
         # what runs the MoE block's grouped matmuls in every step program
@@ -303,8 +309,11 @@ class ModelRunner:
             self.cfg, config.cache.block_size,
             config.scheduler.max_num_seqs,
             config.scheduler.max_num_batched_tokens)
-        self.num_blocks = self._resolve_num_blocks(num_blocks)
-        self.kv = self._init_cache()
+        # sizing reads the device's memory_stats(), which waits for nothing:
+        # the weights' programs may still run (the engine's `device_drain`)
+        with self.start.span("kv_pool"):
+            self.num_blocks = self._resolve_num_blocks(num_blocks)
+            self.kv = self._init_cache()
         # block-table width padded to a multiple of the kernels' DMA window
         # (they read whole windows; tables are 0-padded past the live blocks)
         mbs = -(-self.cfg.max_model_len // config.cache.block_size)
@@ -1208,11 +1217,15 @@ class ModelRunner:
         """The weights from the checkpoint or the seed, quantized where the
         model says so, then in the order of bytes the step programs read
         (engine/weights.py ``lay_out``; a quantized stack stays as it is)."""
+        span = self.start.span
         with jax.set_mesh(self.mesh):
-            return lay_out(
-                self.cfg, maybe_quantize(self.cfg, init_or_load(
-                    self.cfg, self.mesh, self.rules, self.config.seed)),
-                self.mesh, self.rules)
+            with span("weights.make"):
+                params = init_or_load(self.cfg, self.mesh, self.rules,
+                                      self.config.seed)
+            with span("weights.quantize"):
+                params = maybe_quantize(self.cfg, params)
+            with span("weights.lay_out"):
+                return lay_out(self.cfg, params, self.mesh, self.rules)
 
     def restore_params(self) -> None:
         if self.params is None:

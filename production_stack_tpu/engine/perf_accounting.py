@@ -28,10 +28,11 @@ chip's peak is not a utilization:
   single-chip peak would report 4x the truth).
 * ``CompileTracker`` — wraps each jitted program; a never-seen argument
   signature (shapes/dtypes + static kwargs) is exactly what makes XLA
-  compile a new executable, so the first call per signature is counted
-  as a compile event (its wall time approximates compile seconds). After
-  ``mark_steady()`` (warmup complete) any new signature also ticks the
-  unexpected-recompile counter the alert rules treat as a bug signal.
+  build a new executable, so the first call per signature is one *build*:
+  a compile event, its wall time split into trace / lower / compile or
+  cache load / first run by jax's own monitoring events (``BuildStages``).
+  After ``mark_steady()`` (warmup complete) any new signature also ticks
+  the unexpected-recompile counter the alert rules treat as a bug signal.
 
 Token counts are LIVE tokens, not padded — padding waste is supposed to
 show up as lost MFU; that is the goodput story.
@@ -39,12 +40,14 @@ show up as lost MFU; that is the goodput story.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import threading
 import time
 from collections import deque
 from typing import Callable, Dict, Optional, Tuple
 
+from production_stack_tpu.engine.tracing import build_annotation
 from production_stack_tpu.tenancy import OTHER, fold_records, split_shares
 
 # Published per-chip peaks keyed by jax's ``device_kind``:
@@ -57,7 +60,7 @@ DEVICE_PEAKS: Dict[str, Tuple[float, float, float]] = {
 }
 
 _log = logging.getLogger(__name__)
-_EVENT_TAIL = 64  # compile events kept verbatim for /debug/perf
+_EVENT_TAIL = 256  # builds kept verbatim for /debug/perf
 
 
 def _stack_param_count(model_cfg) -> int:
@@ -104,23 +107,197 @@ def _dtype_bytes(dtype: str) -> int:
     return 4 if "32" in str(dtype) else 2
 
 
+# -- a program's first call, in stages ----------------------------------------
+# jax times its own stages of building a program and says so through
+# `jax.monitoring` (jax 0.9.0: `dispatch.log_elapsed_time` records a scalar
+# at a stage's entry and a duration at its exit; `compiler.
+# compile_or_get_cached`, which `backend_compile_duration` wraps, records
+# `cache_hits` and the retrieval time when the persistent cache answered,
+# and nothing of the kind when it compiled). A stage can open inside another
+# (a jitted helper traced while the step program is: `trace` in `trace`), so
+# a stage is charged its own time minus what opened inside it.
+BUILD_STAGES = ("trace", "lower", "compile", "cache_load", "first_run")
+_JAX_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile_or_load",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def new_build(kind: str, bucket: str) -> dict:
+    """One build as /debug/perf `builds` shows it. ``seconds`` is the first
+    call's wall time and ``stages`` adds up to it; ``cache_hits`` /
+    ``cache_misses`` count the backend's compile requests the persistent
+    cache answered / did not (a build can make more than one: the program
+    and a helper), ``cache_retrieval_seconds`` is the part of `cache_load`
+    spent reading and deserializing (the rest: the key's hash); ``at`` is
+    its opening on `StepClock.now()`'s clock, ``ts`` the same on the
+    wall's."""
+    return {"kind": kind, "bucket": bucket, "at": time.monotonic(),
+            "seconds": 0.0,
+            "stages": dict.fromkeys(BUILD_STAGES, 0.0),
+            "cache_hits": 0, "cache_misses": 0, "cache_hit": False,
+            "cache_retrieval_seconds": 0.0, "engine_step": None,
+            "unexpected": False, "ts": time.time()}
+
+
+class BuildStages:
+    """jax's monitoring events, charged to the build open on the thread
+    they fire on (`building`), else to the programs nobody tracks: kind
+    `other` (`init_params`, `lay_out`'s transposes, the pool's zeros, the
+    sampling helpers, every eager operation's small program), one entry a
+    backend compile request, named by jax's own name for the function.
+    One instance a process (`build_stages`): jax keeps listeners for good.
+    The listeners run only while jax builds something; a call of a
+    program already built fires none."""
+
+    def __init__(self):
+        self._local = threading.local()
+        self._lock = threading.Lock()
+        self.other: deque = deque(maxlen=_EVENT_TAIL)  # entries, `other`
+        self.other_seconds = dict.fromkeys(BUILD_STAGES, 0.0)
+        self.other_builds = self.other_hits = self.other_misses = 0
+        self._other_names: dict = {}
+
+    def register(self) -> "BuildStages":
+        import jax.monitoring as monitoring
+
+        monitoring.register_scalar_listener(self._on_enter)
+        monitoring.register_event_duration_secs_listener(self._on_exit)
+        monitoring.register_event_listener(self._on_event)
+        return self
+
+    def _state(self):
+        tl = self._local
+        if not hasattr(tl, "stack"):
+            tl.stack, tl.build, tl.hit = [], None, False
+            tl.pending = new_build("other", "")
+        return tl
+
+    @contextlib.contextmanager
+    def building(self, build: dict):
+        """``build`` takes this thread's events until the block ends."""
+        tl = self._state()
+        tl.build = build
+        try:
+            yield
+        finally:
+            tl.build = None
+
+    def _on_enter(self, event: str, value, **kwargs) -> None:
+        if event in _JAX_STAGES:
+            self._state().stack.append(0.0)  # seconds opened inside it
+
+    def _on_event(self, event: str, **kwargs) -> None:
+        if event == _CACHE_HIT:
+            self._state().hit = True
+
+    def _on_exit(self, event: str, seconds: float, **kwargs) -> None:
+        stage = _JAX_STAGES.get(event)
+        if stage is None and event != _CACHE_RETRIEVAL:
+            return
+        tl = self._state()
+        build = tl.build or tl.pending
+        if stage is None:
+            build["cache_retrieval_seconds"] += seconds
+            return
+        own = max(seconds - (tl.stack.pop() if tl.stack else 0.0), 0.0)
+        if tl.stack:
+            tl.stack[-1] += seconds
+        if stage == "compile_or_load":
+            stage = "cache_load" if tl.hit else "compile"
+            build["cache_hits" if tl.hit else "cache_misses"] += 1
+            tl.hit = False
+        build["stages"][stage] += own
+        if tl.build is None and stage in ("compile", "cache_load"):
+            self._close_other(tl, str(kwargs.get("fun_name", "?")))
+
+    def _close_other(self, tl, fun_name: str) -> None:
+        """A program nobody tracks has reached the backend: what this
+        thread traced and lowered since the last one is its entry."""
+        entry, tl.pending = tl.pending, new_build("other", "")
+        entry["seconds"] = sum(entry["stages"].values())
+        entry["cache_hit"] = entry["cache_misses"] == 0
+        # when it opened, about: its stages may lie apart
+        entry["at"] = time.monotonic() - entry["seconds"]
+        entry["ts"] = time.time() - entry["seconds"]
+        with self._lock:
+            n = self._other_names[fun_name] = (
+                self._other_names.get(fun_name, 0) + 1)
+            entry["bucket"] = fun_name if n == 1 else f"{fun_name}#{n}"
+            self.other.append(entry)
+            self.other_builds += 1
+            self.other_hits += entry["cache_hits"]
+            self.other_misses += entry["cache_misses"]
+            for stage, sec in entry["stages"].items():
+                self.other_seconds[stage] += sec
+
+
+_build_stages: Optional[BuildStages] = None
+
+
+def build_stages() -> BuildStages:
+    """The process's one `BuildStages`, registered with jax at first use."""
+    global _build_stages
+    if _build_stages is None:
+        _build_stages = BuildStages().register()
+    return _build_stages
+
+
+# the step programs' static flags that select a variant beside `greedy_only`
+_VARIANT_FLAGS = ("want_logprobs", "use_grammar", "use_penalties",
+                  "use_controls")
+
+
+def program_bucket(args: tuple, kwargs: dict) -> str:
+    """What tells a program's signature from the others of its kind, from
+    what the caller already knows: ``w<stream width>`` (the packed step
+    input's `tokens` field by the ``layout`` it is passed with: a ragged
+    program's stream, a decode program's slots; else the shape of the first
+    argument that has one) and the static flags that select a variant, e.g.
+    ``w512:greedy``, ``w64:sampled+logprobs``."""
+    layout = kwargs.get("layout")
+    if layout is not None:
+        width = "w" + str(max(layout.fields[0][1]))
+    else:
+        shape = next((a.shape for a in args if getattr(a, "shape", None)), ())
+        width = "x".join(str(int(d)) for d in shape) or "-"
+    flags = []
+    if "greedy_only" in kwargs:
+        flags.append("greedy" if kwargs["greedy_only"] else "sampled")
+    flags += [k.split("_", 1)[1] for k in _VARIANT_FLAGS if kwargs.get(k)]
+    if kwargs.get("lora_bank") is not None:
+        flags.append("lora")
+    # a static flag of a program this module does not know
+    flags += sorted(k for k, v in kwargs.items() if v is True
+                    and k not in (*_VARIANT_FLAGS, "greedy_only"))
+    return width + (":" + "+".join(flags) if flags else "")
+
+
 class CompileTracker:
-    """Wrap a jitted callable and surface compile events.
+    """Wrap a jitted callable and surface its builds.
 
     The signature key mirrors jax's compilation-cache key closely enough
     for accounting: per-argument (shape, dtype) for arrays, literal
     values for hashable statics, structural markers for pytrees. A new
-    key means XLA builds a new executable; the wall time of that first
-    call upper-bounds compile+first-run seconds (steady-state calls of a
-    seen signature are dispatch-only and are not timed)."""
+    key means jax builds a new executable: that first call is timed and
+    its wall time split by stage (``BuildStages``; `first_run` is what is
+    left: the arguments' transfer, the dispatch, the execution where the
+    caller waits for it); it runs inside a `build` profiler annotation
+    (engine/tracing.py). Steady-state calls of a seen signature are
+    dispatch-only and are not timed. A build is named ``kind:bucket``
+    (``program_bucket``); a second signature of the same name gets
+    ``#2``, so a name is one program."""
 
-    def __init__(self, kind: str, fn: Callable, observer: Callable,
-                 bucket_argidx: int = 2):
+    def __init__(self, kind: str, fn: Callable, observer: Callable):
         self.kind = kind
         self.fn = fn
         self.observer = observer
-        self.bucket_argidx = bucket_argidx
         self._seen: set = set()
+        self._names: dict = {}
+        self._stages = build_stages()
 
     def _sig(self, v):
         shape = getattr(v, "shape", None)
@@ -134,22 +311,28 @@ class CompileTracker:
             return ("map", tuple(sorted(str(k) for k in v)))
         return type(v).__name__
 
-    def _bucket(self, args) -> str:
-        if len(args) > self.bucket_argidx:
-            shape = getattr(args[self.bucket_argidx], "shape", None)
-            if shape:
-                return "x".join(str(int(d)) for d in shape)
-        return "-"
-
     def __call__(self, *args, **kwargs):
         key = (tuple(self._sig(a) for a in args),
                tuple((k, self._sig(v)) for k, v in sorted(kwargs.items())))
         if key in self._seen:
             return self.fn(*args, **kwargs)
-        t0 = time.monotonic()
-        out = self.fn(*args, **kwargs)
+        bucket = program_bucket(args, kwargs)
+        n = self._names.get(bucket, 0) + 1
+        build = new_build(self.kind, bucket if n == 1 else f"{bucket}#{n}")
+        t0 = build["at"]
+        with build_annotation(self.kind, build["bucket"]) as ann, \
+                self._stages.building(build):
+            out = self.fn(*args, **kwargs)
+            build["cache_hit"] = (build["cache_hits"] > 0
+                                  and build["cache_misses"] == 0)
+            ann.set_metadata(cache_hit=int(build["cache_hit"]))
+        stages = build["stages"]
+        build["seconds"] = time.monotonic() - t0
+        stages["first_run"] = max(
+            build["seconds"] - sum(stages.values()), 0.0)
         self._seen.add(key)
-        self.observer(self.kind, self._bucket(args), time.monotonic() - t0)
+        self._names[bucket] = n
+        self.observer(self.kind, build["bucket"], build["seconds"], build)
         return out
 
 
@@ -247,7 +430,12 @@ class PerfAccountant:
         self._collective = {"all_reduce": 0.0, "all_gather": 0.0}
         # compile tracking
         self._compile_counts: dict = {}
-        self._compile_events: deque = deque(maxlen=_EVENT_TAIL)
+        # the tracked programs' builds, newest last (`compile.recent`,
+        # and with the untracked ones `builds` of /debug/perf)
+        self._builds: deque = deque(maxlen=_EVENT_TAIL)
+        self._build_seconds: dict = {}  # kind -> stage -> seconds
+        self._cache_hits = self._cache_misses = 0
+        self._stages = build_stages()
         self._compile_seconds = 0.0
         self._unexpected = 0
         self._steady = False
@@ -344,22 +532,48 @@ class PerfAccountant:
         return acct
 
     # -- compile events ------------------------------------------------------
-    def on_compile(self, kind: str, bucket: str, seconds: float) -> None:
+    def on_compile(self, kind: str, bucket: str, seconds: float,
+                   build: Optional[dict] = None) -> None:
+        """One build of a tracked program (``CompileTracker``'s observer):
+        ``build`` as ``new_build`` shapes it, or None from a caller that
+        knows the first call's wall time alone (then all of it is
+        `first_run`)."""
+        if build is None:
+            build = new_build(kind, bucket)
+            build["seconds"] = build["stages"]["first_run"] = seconds
         with self._lock:
             key = (kind, bucket)
             self._compile_counts[key] = self._compile_counts.get(key, 0) + 1
             self._compile_seconds += seconds
-            unexpected = self._steady
-            if unexpected:
+            build["unexpected"] = self._steady
+            if self._steady:
                 self._unexpected += 1
-            event = {
-                "kind": kind, "bucket": bucket,
-                "seconds": round(seconds, 4),
-                "unexpected": unexpected, "ts": time.time(),
-            }
-            self._compile_events.append(event)
-        if unexpected and self.anomaly_hook is not None:
-            self.anomaly_hook("unexpected_recompile", dict(event))
+            self._builds.append(build)
+            by_stage = self._build_seconds.setdefault(
+                kind, dict.fromkeys(BUILD_STAGES, 0.0))
+            for stage, sec in build["stages"].items():
+                by_stage[stage] += sec
+            self._cache_hits += build["cache_hits"]
+            self._cache_misses += build["cache_misses"]
+        if build["unexpected"] and self.anomaly_hook is not None:
+            self.anomaly_hook("unexpected_recompile", dict(build))
+
+    def _build_fields(self) -> dict:
+        """Seconds by kind and stage, programs built by kind and the
+        persistent cache's answers, over the tracked programs' builds and
+        the process's untracked ones (kind `other`); under the lock."""
+        other = self._stages
+        seconds = {kind: dict(by_stage)
+                   for kind, by_stage in self._build_seconds.items()}
+        seconds["other"] = dict(other.other_seconds)
+        builds = {"other": other.other_builds}
+        for (kind, _), n in self._compile_counts.items():
+            builds[kind] = builds.get(kind, 0) + n
+        return {"program_build_seconds": seconds,
+                "program_builds": builds,
+                "compile_cache_hits": self._cache_hits + other.other_hits,
+                "compile_cache_misses": (self._cache_misses
+                                         + other.other_misses)}
 
     def mark_steady(self) -> None:
         """Warmup pre-compiled every serving variant: from here on a fresh
@@ -697,6 +911,7 @@ class PerfAccountant:
                 "compile_seconds_total": self._compile_seconds,
                 "unexpected_recompiles": self._unexpected,
                 "dispatches_total": self._totals["dispatches"],
+                **self._build_fields(),
             }
 
     def snapshot(self) -> dict:
@@ -749,6 +964,8 @@ class PerfAccountant:
                     "unexpected_recompiles": self._unexpected,
                     "counts": {f"{k}:{b}": n for (k, b), n
                                in sorted(self._compile_counts.items())},
-                    "recent": list(self._compile_events),
+                    "recent": list(self._builds),
                 },
+                # every build, the untracked programs' (`other`) first
+                "builds": [*self._stages.other, *self._builds],
             }

@@ -240,6 +240,13 @@ class EngineServer:
         self.warmup_on_start = warmup_on_start
         self.model_name = config.model.name
         self.engine = engine or LLMEngine(config)
+        # this replica's start in parts (engine/tracing.py StartClock; the
+        # engine's own, or `main()`'s handed through it): from here to the
+        # end of `_on_start`, which the socket's binding follows at once,
+        # is `server_bind`; the warm-up's seconds and `startup_seconds` of
+        # /debug/perf are read from it and kept nowhere else
+        self.start = self.engine.start
+        self._bind_span: Optional[int] = self.start.begin("server_bind")
         self.async_engine = AsyncEngine(self.engine)
         self.metrics = ServerMetrics(self.engine, self.model_name)
         self.async_engine.step_observer = self.metrics.observe_step
@@ -309,14 +316,9 @@ class EngineServer:
         # scale-ups) never cuts a cold replica into the ring. /health
         # stays 200 the whole time: the pod is alive, just not ready.
         self.warming = False
-        self.warmup_seconds = 0.0
         # a warm-up that raised: /ready stays 503 for good and main() exits
         # non-zero once run_app unwinds
         self.warmup_error: Optional[str] = None
-        # wall seconds of the start-up phases before the loop runs (main()
-        # fills backend_open and engine_build), shown by /debug/perf
-        self.startup_seconds: dict = {}
-        self._warmup_t0: Optional[float] = None
         self._warmup_task: Optional[asyncio.Task] = None
         # main() flips this on before run_app so SIGTERM drains instead of
         # killing the loop; in-process test servers leave it off.
@@ -445,36 +447,40 @@ class EngineServer:
         self.watchdog.start()
         if self.drain_on_sigterm:
             self._install_signal_drain()
+        if self.brownout is not None and self.brownout.config.enabled:
+            self._brownout_task = asyncio.ensure_future(
+                self._brownout_worker())
+        if self._bind_span is not None:  # an app built twice binds twice
+            self.start.end(self._bind_span)
+            self._bind_span = None
         if self.warmup_on_start:
             # warm in the background so the server binds immediately and
             # /ready can answer 503 {"status": "warming"} while the
             # compiles run — discovery and the autoscaler need to SEE the
             # warming state, not a connection-refused socket
             self.warming = True
-            self._warmup_t0 = time.monotonic()
-            self._warmup_task = asyncio.ensure_future(self._run_warmup())
-        if self.brownout is not None and self.brownout.config.enabled:
-            self._brownout_task = asyncio.ensure_future(
-                self._brownout_worker())
+            self._warmup_task = asyncio.ensure_future(
+                self._run_warmup(self.start.begin("warmup")))
+        else:
+            self.start.mark_ready()
 
-    async def _run_warmup(self) -> None:
-        assert self._warmup_t0 is not None
+    async def _run_warmup(self, span: int) -> None:
         try:
             await self.async_engine.run_on_engine(lambda eng: eng.warmup())
         except Exception as e:
             # a program that does not compile or run here would fail every
             # request the same way: stay un-ready and take the process down
             # rather than serve 500s behind a 200 /ready
-            self.warmup_seconds = time.monotonic() - self._warmup_t0
             self.warmup_error = f"{type(e).__name__}: {e}"
             _log.critical("engine warm-up failed after %.1fs; exiting",
-                          self.warmup_seconds, exc_info=True)
+                          self.start.end(span), exc_info=True)
             asyncio.get_running_loop().call_soon(self._exit)
             return
-        self.warmup_seconds = time.monotonic() - self._warmup_t0
+        seconds = self.start.end(span)
+        self.start.mark_ready()
         self.warming = False
         print(f"engine warmup (all shape variants) done in "
-              f"{self.warmup_seconds:.1f}s", flush=True)
+              f"{seconds:.1f}s", flush=True)
 
     async def _on_stop(self, app) -> None:
         if self._warmup_task is not None:
@@ -519,7 +525,8 @@ class EngineServer:
             "watchdog_stalled": self.watchdog.stalled,
             "watchdog_stalls_total": self.watchdog.stalls_total,
             "warming": self.warming,
-            "warmup_seconds": self.warmup_seconds,
+            "start_seconds": self.start.seconds(),
+            "start_to_ready_seconds": self.start.to_ready,
         }
 
     # -- staged brownout (engine/overload.py) --------------------------------
@@ -767,9 +774,7 @@ class EngineServer:
                 status=503,
             )
         if self.warming:
-            elapsed = 0.0
-            if self._warmup_t0 is not None:
-                elapsed = time.monotonic() - self._warmup_t0
+            elapsed = self.start.open_since("warmup") or 0.0
             return web.json_response(
                 {"status": "warming", "warming_for": round(elapsed, 3)},
                 status=503,
@@ -1965,8 +1970,12 @@ class EngineServer:
                     * model.window_kv_bytes_per_token)}
         snap["tenants"] = self.engine.tenant_stats()
         snap["fingerprint"] = self._perf_fingerprint()
+        # the start in parts (engine/tracing.py StartClock): the spans as
+        # recorded, and seconds by phase (vllm:engine_start_seconds)
+        snap["start"] = self.start.snapshot()
         snap["startup_seconds"] = {
-            **self.startup_seconds, "warmup": round(self.warmup_seconds, 2)}
+            phase: round(sec, 2)
+            for phase, sec in snap["start"]["seconds"].items()}
         return web.json_response(snap)
 
     async def debug_tenants(self, request: web.Request) -> web.Response:
@@ -3646,6 +3655,8 @@ def main(argv=None) -> None:
     import os
     import signal
 
+    # the process's creation to here, `process`, is the start's first span
+    start = etracing.StartClock()
     from production_stack_tpu.yaml_args import parse_with_yaml_config
 
     args = parse_with_yaml_config(build_parser(), argv)
@@ -3725,16 +3736,17 @@ def main(argv=None) -> None:
 
     import jax
 
-    t0 = time.monotonic()
-    jax.devices()  # opens the backend; fails here if the chip is held
-    t1 = time.monotonic()
-    engine = LLMEngine(config)
-    startup_seconds = {"backend_open": round(t1 - t0, 2),
-                       "engine_build": round(time.monotonic() - t1, 2)}
+    with start.span("backend_open"):
+        jax.devices()  # opens the backend; fails here if the chip is held
+    engine = LLMEngine(config, start=start)
+    took = start.seconds()
     print(f"engine startup: {jax.default_backend()} x{jax.device_count()} "
-          f"({jax.devices()[0].device_kind}), backend open "
-          f"{startup_seconds['backend_open']}s, params + KV pool "
-          f"{startup_seconds['engine_build']}s", flush=True)
+          f"({jax.devices()[0].device_kind}), process {took['process']:.2f}s, "
+          f"backend open {took['backend_open']:.2f}s, engine build "
+          f"{took['engine_build']:.2f}s (weights "
+          f"{took['weights.make']:.2f} + {took['weights.quantize']:.2f} + "
+          f"{took['weights.lay_out']:.2f}s, KV pool {took['kv_pool']:.2f}s, "
+          f"device drain {took['device_drain']:.2f}s)", flush=True)
     broadcaster = None
     if dist.enabled:
         from production_stack_tpu.engine.multihost import (
@@ -3768,7 +3780,6 @@ def main(argv=None) -> None:
     # the real process drains on SIGTERM instead of dying mid-stream;
     # in-process test servers keep run_app semantics untouched
     server.drain_on_sigterm = True
-    server.startup_seconds.update(startup_seconds)
     web.run_app(server.build_app(), host=args.host, port=args.port,
                 access_log=None)
     if broadcaster is not None:

@@ -1,6 +1,8 @@
-"""Engine-side tracing. Two things live here: the OpenTelemetry request span
-(below), and the step clock (`StepClock`, at the end), which says where the
-engine thread's time goes, as counters and as profiler annotations.
+"""Engine-side tracing. Three things live here: the OpenTelemetry request
+span (below), the step clock (`StepClock`), which says where the engine
+thread's time goes, as counters and as profiler annotations, and the start
+clock (`StartClock`), which says where a replica's start went, on the same
+clock.
 
 Distributed tracing: the same graceful-degradation layering
 as router/experimental/tracing.py, so engine spans JOIN the router's trace
@@ -17,7 +19,9 @@ image (init degrades gracefully otherwise).
 
 from __future__ import annotations
 
+import contextlib
 import logging
+import os
 import random
 import time
 from collections import deque
@@ -204,7 +208,7 @@ class StepClock:
         self.last_width = 0
         self._waited: Optional[str] = None  # by the open step, first
         self.launch_t = 0.0      # stamp of the last `launch`
-        self.compiles = 0        # XLA compiles seen (the engine counts)
+        self.compiles = 0        # programs built (`note_build`)
         # kind -> phase -> [wall seconds, on-CPU seconds]
         self.seconds = {k: {p: [0.0, 0.0] for p in (*HOST_PHASES, "wait")}
                         for k in STEP_KINDS}
@@ -225,6 +229,7 @@ class StepClock:
         self._phase: Optional[str] = None  # None: not started
         self._t = self._cpu = self._t_begin = 0.0
         self._compiles_at_begin = 0
+        self._builds: list = []  # programs built inside the open step
         self._ann = self._step_ann = None
         self._launch: dict = {}
         # (stamp, phase) of the last switches, newest at _n - 1
@@ -313,6 +318,17 @@ class StepClock:
         self._hand_over(ann, self._step_ann.__enter__)
         self._at_begin = {p: acc[0] for p, acc in self._scratch.items()}
         self._compiles_at_begin = self.compiles
+        self._builds = []
+
+    def note_build(self, build: dict) -> None:
+        """A program was built (`perf_accounting.CompileTracker`: its
+        first call, in stages) on this thread: the step it ran in goes
+        into the build, and the build into the slow-step ring if the step
+        turns out slow (cause `compile`)."""
+        self.compiles += 1
+        build["engine_step"] = self.step_num if self.in_step else None
+        if self.in_step:
+            self._builds.append(build)
 
     def describe(self, kind: str, rows: int, tokens: int,
                  width: int = 0) -> None:
@@ -386,12 +402,19 @@ class StepClock:
                         [by.get(p, 0.0) for _, by in recent]))
                 by_cause = self.slow_seconds[self.kind]
                 by_cause[cause] = by_cause.get(cause, 0.0) + seconds
-                self.slow_steps.append({
+                entry = {
                     "step": self.step_num, **self._launch,
                     "kind": self.kind, "after": self.last_kind,
                     "after_width": self.last_width,
                     "seconds": seconds,
-                    "reference": ref, "phases": in_step, "cause": cause})
+                    "reference": ref, "phases": in_step, "cause": cause}
+                if cause == "compile":
+                    # what was built, each with its seconds by stage
+                    entry["builds"] = [
+                        {k: b[k] for k in ("kind", "bucket", "seconds",
+                                           "stages", "cache_hit")}
+                        for b in self._builds]
+                self.slow_steps.append(entry)
         recent.append((seconds, in_step))
 
     def snapshot(self) -> dict:
@@ -422,6 +445,147 @@ def _median(values: list) -> float:
     mid = len(values) // 2
     return (values[mid] if len(values) % 2
             else (values[mid - 1] + values[mid]) / 2)
+
+
+def build_annotation(kind: str, bucket: str) -> TraceAnnotation:
+    """The profiler annotation of a program's build (`perf_accounting.
+    CompileTracker`), to be entered around the first call: it opens inside
+    `step.launch`, so a program built in a profiled window names its own
+    idle gap on the device trace's clock; a no-op without a session. The
+    tracker adds `cache_hit` (`set_metadata`) before it leaves."""
+    return TraceAnnotation("build", kind=kind, bucket=bucket)
+
+
+# -- a replica's start, in parts ------------------------------------------------
+# Where the time from the process's creation to `/ready` goes, always on:
+# some twenty stamps in a process's life, on the clock of `StepClock` and of
+# a request's `timeline`, kept as spans and read as plain floats at scrape
+# time (vllm:engine_start_seconds{phase}). The phases, top level first, then
+# `engine_build`'s children in the order they run; `engine_build.self` is
+# the parent's time outside its children (mesh, model lookup, scheduler,
+# the step programs' `jax.jit` wrappers: nothing compiles there).
+START_TOP = ("process", "backend_open", "engine_build", "server_bind",
+             "warmup")
+START_PHASES = START_TOP + (
+    "tokenizer", "weights.make", "weights.quantize", "weights.lay_out",
+    "kv_pool", "device_drain", "engine_build.self")
+
+
+def _process_created(now: float) -> float:
+    """The stamp, on `time.monotonic()`'s clock, of this process's
+    creation: its start time in `/proc/self/stat` (field 22, clock ticks
+    since boot) against `CLOCK_BOOTTIME`. ``now`` where that cannot be
+    read or reads as the future."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return now
+    return now - age if age > 0.0 else now
+
+
+class StartClock:
+    """Spans of (name, start, end, parent) on `StepClock.now()`'s clock.
+    Created at `main()`'s entry (by `LLMEngine` where no `main()` ran: a
+    test's engine), which closes the first span, `process`: the
+    interpreter and the imports. A span opened while another is open is
+    its child; children of one parent follow each other, so a parent's
+    self time (its own minus its children's) is what ran between them and
+    is never negative. Spans open and close on whichever thread runs the
+    start (the main thread, then the event loop's), one after the other:
+    no lock. A span that covers asynchronous dispatches ends at the host's
+    return; `device_drain` is the one wait for the device."""
+
+    def __init__(self):
+        entry = time.monotonic()
+        self.created = _process_created(entry)
+        self.spans: list = []    # [name, start, end (None: open), parent]
+        self._open: list = []    # indices into `spans`, innermost last
+        self.ready_at: Optional[float] = None
+        self.end(self.begin("process", at=self.created), at=entry)
+
+    def begin(self, name: str, at: Optional[float] = None) -> int:
+        parent = self.spans[self._open[-1]][0] if self._open else None
+        self.spans.append(
+            [name, time.monotonic() if at is None else at, None, parent])
+        self._open.append(len(self.spans) - 1)
+        return self._open[-1]
+
+    def end(self, index: int, at: Optional[float] = None) -> float:
+        """Close the span ``index`` (and any left open inside it, as
+        after an exception); returns its seconds."""
+        at = time.monotonic() if at is None else at
+        while self._open:
+            i = self._open.pop()
+            self.spans[i][2] = at
+            if i == index:
+                break
+        span = self.spans[index]
+        return span[2] - span[1]
+
+    @contextlib.contextmanager
+    def span(self, name: str):
+        index = self.begin(name)
+        try:
+            yield
+        finally:
+            self.end(index)
+
+    def mark_ready(self) -> None:
+        """The instant `/ready` first would answer 200; the first call
+        holds."""
+        if self.ready_at is None:
+            self.ready_at = time.monotonic()
+
+    def open_since(self, name: str) -> Optional[float]:
+        """Seconds the open span ``name`` has run, None if none is open."""
+        for i in self._open:
+            if self.spans[i][0] == name:
+                return time.monotonic() - self.spans[i][1]
+        return None
+
+    def seconds(self) -> dict:
+        """{phase: seconds} for every name of `START_PHASES`: the closed
+        spans begun before `ready` (weights made again at a wake are in
+        `spans` and not here: the numbers are fixed once ready); a phase
+        that never ran reads 0.0."""
+        out = dict.fromkeys(START_PHASES, 0.0)
+        inside: dict = {}
+        for name, start, end, parent in self.spans:
+            if end is None or (self.ready_at is not None
+                               and start > self.ready_at):
+                continue
+            out[name] = out.get(name, 0.0) + end - start
+            if parent is not None:
+                inside[parent] = inside.get(parent, 0.0) + end - start
+        built = out["engine_build"]  # 0.0 while it is open
+        out["engine_build.self"] = (
+            built - inside.get("engine_build", 0.0) if built else 0.0)
+        return out
+
+    @property
+    def to_ready(self) -> float:
+        """Creation to `ready`, 0.0 until then."""
+        return (0.0 if self.ready_at is None
+                else self.ready_at - self.created)
+
+    def snapshot(self) -> dict:
+        """`start` of /debug/perf: the spans as recorded (stamps on
+        `StepClock.now()`'s clock), seconds by phase, creation to ready,
+        and what of that no top-level span covers (`main()` between its
+        entry and the backend's opening: arguments, configuration)."""
+        seconds = self.seconds()
+        return {
+            "created": self.created, "ready_at": self.ready_at,
+            "to_ready_seconds": self.to_ready,
+            "seconds": seconds,
+            "outside_spans_seconds": (
+                self.to_ready - sum(seconds[p] for p in START_TOP)
+                if self.ready_at is not None else None),
+            "spans": [{"name": n, "start": s, "end": e, "parent": p}
+                      for n, s, e, p in self.spans]}
 
 
 # -- the event loop's heartbeat ------------------------------------------------

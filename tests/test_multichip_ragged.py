@@ -203,8 +203,10 @@ def test_zero_unexpected_recompiles_at_both_stream_widths_tp4():
     eng.warmup()
     fields = eng.perf.stats_fields()
     assert fields["unexpected_recompiles"] == 0
-    assert sorted(n for k, n in fields["compile_counts"].items()
-                  if k[0] == "ragged") == [6, 6]
+    # a program's name is ``w<width>:<variant>``: six variants a width
+    widths = [bucket.split(":")[0] for (kind, bucket), n
+              in fields["compile_counts"].items() if kind == "ragged"]
+    assert sorted(widths) == ["w128"] * 6 + ["w512"] * 6
     rng = np.random.default_rng(5)
     narrow = eng.ragged_narrow_dispatches
     for i, n in enumerate((20, 200)):

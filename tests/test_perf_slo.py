@@ -132,7 +132,7 @@ def test_estimate_param_count_matches_geometry():
 def test_compile_tracker_counts_new_signatures_only():
     events = []
     tracker = CompileTracker("prefill", lambda *a, **k: 42,
-                             lambda k, b, s: events.append((k, b)))
+                             lambda k, b, s, build: events.append((k, b)))
     a28 = np.zeros((2, 8), np.int32)
     assert tracker(None, None, a28) == 42
     assert events == [("prefill", "2x8")]

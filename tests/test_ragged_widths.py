@@ -332,16 +332,33 @@ def live_traffic(eng):
                 assert steps == [(NARROW if n <= NARROW else BUDGET, n)]
 
 
+def builds_by_width(compile_counts: dict, kind: str = "ragged") -> dict:
+    """{stream width: programs built} of one kind. A program's name is
+    ``w<width>:<variant>`` (perf_accounting.program_bucket) and one name is
+    one program, so every count is 1."""
+    out: dict = {}
+    for (k, bucket), n in compile_counts.items():
+        if k == kind:
+            assert n == 1, (bucket, n)
+            width = int(bucket.split(":")[0][1:])
+            out[width] = out.get(width, 0) + 1
+    return out
+
+
 def test_warmup_compiles_both_widths_of_every_variant(weights):
     cfg, mesh, params = weights("dense")
     eng = make_engine(cfg, mesh, params)
     eng.warmup()
     fields = eng.perf.stats_fields()
     assert fields["unexpected_recompiles"] == 0
-    ragged = {k: n for k, n in fields["compile_counts"].items()
-              if k[0] == "ragged"}
     # {greedy, sampled} x {plain, grammar, controls}, once a width
+    ragged = builds_by_width(fields["compile_counts"])
     assert sorted(ragged.values()) == [6, 6], fields["compile_counts"]
+    narrow = min(ragged)
+    assert {b.split(":")[1] for (k, b) in fields["compile_counts"]
+            if k == "ragged" and b.startswith(f"w{narrow}:")} == {
+        "greedy", "sampled", "greedy+grammar", "sampled+grammar",
+        "greedy+controls", "sampled+controls"}
     assert 0 < eng.ragged_narrow_dispatches < eng.ragged_dispatches
     live_traffic(eng)
     fields = eng.perf.stats_fields()
@@ -355,8 +372,7 @@ def test_warmup_with_one_width_compiles_what_it_did(weights):
     assert eng.config.scheduler.ragged_stream_widths == (64,)
     eng.warmup()
     fields = eng.perf.stats_fields()
-    assert [n for k, n in fields["compile_counts"].items()
-            if k[0] == "ragged"] == [6]
+    assert builds_by_width(fields["compile_counts"]) == {64: 6}
     assert eng.ragged_narrow_dispatches == 0 < eng.ragged_dispatches
     serve(eng, REQUESTS[:2], ARRIVE_AT[:1])
     assert eng.perf.stats_fields()["unexpected_recompiles"] == 0
@@ -498,9 +514,8 @@ def test_warmup_of_a_default_configuration_builds_what_the_chip_builds():
     fields = eng.perf.stats_fields()
     assert {kind for kind, _ in fields["compile_counts"]} == {
         "ragged", "decode_multi"}
-    ragged = [n for k, n in fields["compile_counts"].items()
-              if k[0] == "ragged"]
-    assert sorted(ragged) == [6, 6], fields["compile_counts"]
+    assert builds_by_width(fields["compile_counts"]) == {
+        512: 6, 2048: 6}, fields["compile_counts"]
     assert fields["unexpected_recompiles"] == 0
     # six prompts that arrive together fill a budget-wide step, the
     # stragglers join the others' decode rows in narrow ones
