@@ -451,35 +451,27 @@ def _gpt2_unicode_to_byte() -> dict:
     return {chr(c): b for b, c in zip(keep, chars)}
 
 
-def _hf_token_byte_images(tk, vocab_size: int) -> list[bytes]:
-    """Byte image per id from the RAW vocab pieces of a HF tokenizer.
+def _hf_token_byte_images(tokenizer, vocab_size: int) -> list[bytes]:
+    """Byte image per id from the RAW vocab pieces of a model directory's
+    tokenizer (`engine/tokenizer.py` `HFTokenizer.vocab_pieces`).
 
     Why not ``decode([i])`` per id: SentencePiece/Metaspace tokenizers
     strip the word-leading space when a piece is decoded alone
     (decode('▁Hello') == 'Hello'), and byte-fallback / partial-UTF-8
     byte-level pieces decode to U+FFFD — either desynchronizes the token
     FSM from the actually-emitted text (r2 advisor, high). Instead read
-    ``convert_ids_to_tokens`` and undo the piece encoding directly:
+    the pieces as stored and undo the piece encoding directly:
     Metaspace '▁'→' ', byte-level via the GPT-2 unicode↔byte alphabet,
-    ``<0xNN>`` byte-fallback pieces → that raw byte."""
-    n = len(tk)
-    special = set(getattr(tk, "all_special_ids", None) or [])
-    added = {}
-    for i, t in (getattr(tk, "added_tokens_decoder", None) or {}).items():
-        added[int(i)] = getattr(t, "content", str(t))
-        # tokens flagged special=True in added_tokens_decoder (Llama-3-style
-        # <|reserved_...|> control tokens) are dropped by
-        # decode(skip_special_tokens=True) even when they're missing from
-        # all_special_ids — a literal byte image would advance the FSM with
-        # text that never appears in output (r3 advisor)
-        if getattr(t, "special", False):
-            special.add(int(i))
-    vocab = tk.get_vocab()
-    metaspace = any("▁" in p for p in vocab)
-    byte_level = not metaspace and any("Ġ" in p for p in vocab)
+    ``<0xNN>`` byte-fallback pieces → that raw byte. The special tokens,
+    which decode(skip_special_tokens=True) drops, get no image: a literal
+    one would advance the FSM with text that never appears in output
+    (r3 advisor)."""
+    pieces, added, special = tokenizer.vocab_pieces()
+    n = len(pieces)
+    metaspace = any("▁" in p for p in pieces if p)
+    byte_level = not metaspace and any("Ġ" in p for p in pieces if p)
     u2b = _gpt2_unicode_to_byte() if byte_level else None
 
-    pieces = tk.convert_ids_to_tokens(list(range(n)))
     images: list[bytes] = []
     for i in range(vocab_size):
         if i >= n or i in special:
@@ -516,16 +508,18 @@ def token_byte_images(tokenizer, vocab_size: int) -> list[bytes]:
     HF tokenizers take the raw-vocab-piece path (exact, incl. leading
     spaces and byte fallback). The dependency-free ByteTokenizer's
     id-by-id decode is exact by construction (ids ARE bytes)."""
-    from production_stack_tpu.engine.tokenizer import ByteTokenizer
+    from production_stack_tpu.engine.tokenizer import (
+        ByteTokenizer,
+        HFTokenizer,
+    )
 
     if isinstance(tokenizer, ByteTokenizer):
         # ids ARE bytes; going through decode() would mangle 0x80-0xFF
         # into U+FFFD. Specials (bos/eos/pad and any padding) are b''.
         return ([bytes([i]) for i in range(min(256, vocab_size))]
                 + [b""] * max(0, vocab_size - 256))
-    tk = getattr(tokenizer, "tk", None)
-    if tk is not None and hasattr(tk, "convert_ids_to_tokens"):
-        return _hf_token_byte_images(tk, vocab_size)
+    if isinstance(tokenizer, HFTokenizer):
+        return _hf_token_byte_images(tokenizer, vocab_size)
     return [
         tokenizer.decode([i]).encode("utf-8", errors="ignore")
         for i in range(vocab_size)

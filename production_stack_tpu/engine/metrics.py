@@ -938,6 +938,10 @@ class LifecycleCollector:
                  "imports", ("process",)),
                 ("vllm:engine_start_backend_open_seconds", "jax.devices(): "
                  "the backend's opening", ("backend_open",)),
+                ("vllm:engine_start_tokenizer_seconds", "reading the "
+                 "model directory's tokenizer, the import of the library "
+                 "that reads it included (engine/tokenizer.py "
+                 "load_tokenizer_dir)", ("tokenizer",)),
                 ("vllm:engine_start_weights_seconds", "making (or "
                  "loading), quantizing and laying out the weights, to the "
                  "host's return",

@@ -1972,7 +1972,9 @@ class EngineServer:
         snap["fingerprint"] = self._perf_fingerprint()
         # the start in parts (engine/tracing.py StartClock): the spans as
         # recorded, and seconds by phase (vllm:engine_start_seconds)
-        snap["start"] = self.start.snapshot()
+        snap["start"] = {
+            **self.start.snapshot(),
+            "tokenizer_loader": self.engine.tokenizer.loader}
         snap["startup_seconds"] = {
             phase: round(sec, 2)
             for phase, sec in snap["start"]["seconds"].items()}
@@ -2198,11 +2200,9 @@ class EngineServer:
 
     # -- completions -----------------------------------------------------------
     def _render_chat(self, messages: list[dict]) -> str:
-        tk = self.engine.tokenizer
-        if hasattr(tk, "tk") and getattr(tk.tk, "chat_template", None):
-            return tk.tk.apply_chat_template(
-                messages, tokenize=False, add_generation_prompt=True
-            )
+        prompt = self.engine.tokenizer.render_chat(messages)
+        if prompt is not None:
+            return prompt
         parts = [f"<|{m.get('role', 'user')}|>\n{m.get('content', '')}" for m in messages]
         return "\n".join(parts) + "\n<|assistant|>\n"
 
@@ -3743,7 +3743,8 @@ def main(argv=None) -> None:
     print(f"engine startup: {jax.default_backend()} x{jax.device_count()} "
           f"({jax.devices()[0].device_kind}), process {took['process']:.2f}s, "
           f"backend open {took['backend_open']:.2f}s, engine build "
-          f"{took['engine_build']:.2f}s (weights "
+          f"{took['engine_build']:.2f}s (tokenizer {took['tokenizer']:.2f}s "
+          f"through {engine.tokenizer.loader}, weights "
           f"{took['weights.make']:.2f} + {took['weights.quantize']:.2f} + "
           f"{took['weights.lay_out']:.2f}s, KV pool {took['kv_pool']:.2f}s, "
           f"device drain {took['device_drain']:.2f}s)", flush=True)

@@ -12,8 +12,6 @@ hub access) and check the recovered bytes.
 
 from __future__ import annotations
 
-import types
-
 import pytest
 
 from production_stack_tpu.engine.grammar import (
@@ -22,14 +20,15 @@ from production_stack_tpu.engine.grammar import (
     compile_regex,
     token_byte_images,
 )
-from production_stack_tpu.engine.tokenizer import ByteTokenizer
+from production_stack_tpu.engine.tokenizer import (
+    ByteTokenizer,
+    TransformersAuto,
+)
 
 
 def _wrap(hf):
-    """Mimic engine HFTokenizer's shape (.tk holds the transformers obj)."""
-    return types.SimpleNamespace(
-        tk=hf, bos_id=hf.bos_token_id, eos_id=hf.eos_token_id
-    )
+    """The engine's wrapper of a transformers tokenizer object."""
+    return TransformersAuto(hf)
 
 
 @pytest.fixture(scope="module")

@@ -302,10 +302,10 @@ def test_the_metric_reads_the_counters_and_nothing_where_there_are_none():
 
 def _word_tokenizer(tmp_path, vocab=64):
     """chipbench's one-word-per-id tokenizer (chipbench/run.py
-    prepare_model_dir), through the engine's HF wrapper."""
+    prepare_model_dir), as the engine loads it."""
     import json
 
-    from production_stack_tpu.engine.tokenizer import HFTokenizer
+    from production_stack_tpu.engine.tokenizer import load_tokenizer_dir
 
     with open(tmp_path / "tokenizer.json", "w") as f:
         json.dump({"version": "1.0", "truncation": None, "padding": None,
@@ -317,7 +317,7 @@ def _word_tokenizer(tmp_path, vocab=64):
                              "unk_token": "t0"}}, f)
     with open(tmp_path / "tokenizer_config.json", "w") as f:
         json.dump({"tokenizer_class": "PreTrainedTokenizerFast"}, f)
-    return HFTokenizer(str(tmp_path))
+    return load_tokenizer_dir(str(tmp_path))
 
 
 def test_the_windowed_decoder_reads_what_a_whole_decode_reads(tmp_path):

@@ -169,12 +169,15 @@ def test_startup_seconds_keeps_its_three_keys_and_ready_is_stamped(served):
     for family, parts in (
             ("vllm:engine_start_process_seconds", ("process",)),
             ("vllm:engine_start_backend_open_seconds", ("backend_open",)),
+            ("vllm:engine_start_tokenizer_seconds", ("tokenizer",)),
             ("vllm:engine_start_weights_seconds",
              ("weights.make", "weights.quantize", "weights.lay_out")),
             ("vllm:engine_start_kv_pool_seconds", ("kv_pool",))):
         assert list(_samples(text, family).values()) == [
             pytest.approx(sum(sec[p] for p in parts))]
     assert list(_samples(text, "vllm:engine_warmup_seconds").values()) == [0.0]
+    # a preset has no tokenizer directory: the byte tokenizer, and said so
+    assert start["tokenizer_loader"] == "bytes"
 
 
 @pytest.mark.parametrize("stage", pa.BUILD_STAGES)
