@@ -40,7 +40,7 @@ class ModelConfig:
     name: str = "tiny-llama"
     # "llama" | "mixtral" | "olmoe" | "gemma" | "gemma2" | "phi3" | "ouro"
     # | "solar_open2" | "pangu_ultra_moe" | "phi4flash" | "kimi_linear"
-    # | "falcon_h1"
+    # | "falcon_h1" | "olmo_hybrid"
     # — Mistral and Qwen run as "llama" (their deltas are knobs:
     # sliding_window, qkv_bias, qk_norm); "phi3" differs only in its fused
     # HF weight layout, "mixtral" and "olmoe" in their HF tensor names
@@ -54,7 +54,9 @@ class ModelConfig:
     # KDA and latent-attention layers in one stack (mla_layers) behind a
     # leading dense layer, "falcon_h1" in a state-space mixer with heads
     # and grouped-query attention side by side in every layer (ssd_heads
-    # > 0) and the family's multipliers
+    # > 0) and the family's multipliers, "olmo_hybrid" in Gated DeltaNet
+    # layers named layer by layer (gdn_layers) beside full attention, and
+    # norms AFTER each sublayer alone (norms "post")
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -111,6 +113,19 @@ class ModelConfig:
     # is walked in (the first MLA layer closes the first), and the last
     # period may be short. () = the attn_period rule
     mla_layers: tuple = ()
+    # a hybrid stack whose recurrent layers are Gated DeltaNet
+    # ("olmo_hybrid"; ops/gdn.py): the 0-based layers that are "gdn", every
+    # other layer full attention ("gqa": nothing rotated, no gate), walked
+    # in periods of attn_period. A GDN layer is the delta rule with ONE
+    # scalar decay a head and token and a state that need not be square,
+    # (gdn_heads, gdn_key_dim, gdn_value_dim) float32 a decode slot, beside
+    # a conv tail over 2 H d_k + H d_v channels. () = no such stack
+    gdn_layers: tuple = ()
+    gdn_heads: int = 0
+    gdn_key_dim: int = 0     # d_k: q and k of a head
+    gdn_value_dim: int = 0   # d_v: v and the output of a head
+    gdn_conv: int = 4        # width of the causal depthwise convolution
+    gdn_neg_eigval: bool = False  # beta in (0, 2) instead of (0, 1)
     # the decoder-hybrid-decoder stack (SambaY, "phi4flash"): layer l is a
     # Mamba-1 state-space layer where l % mamba_period == 0 and attention
     # elsewhere, with a window of sliding_window rows, up to layer
@@ -176,7 +191,13 @@ class ModelConfig:
     embed_scale: bool = False  # multiply embeddings by sqrt(hidden_size)
     attn_logit_softcap: float = 0.0  # cap*tanh(s/cap) on attention scores
     final_logit_softcap: float = 0.0  # same on the LM-head logits
-    post_norms: bool = False  # Gemma-2 post-attention/post-MLP norms
+    # where a block's norms sit, the one description of it: "pre", a norm
+    # BEFORE each sublayer (the Llama block); "both", before and after
+    # (Gemma-2, Ouro, openPangu: ``post_attn_norm`` / ``post_mlp_norm``
+    # beside ``attn_norm`` / ``mlp_norm``); "post", AFTER each sublayer
+    # alone (the Olmo 3 family: x + norm(f(x)); the sublayer reads the
+    # stream as it is and the layer has the two post norms only)
+    norms: str = "pre"
     query_scale: float = 0.0  # score scale; 0 → head_dim**-0.5
     # local-attention window (Gemma-2 alternates local/global layers). We
     # serve such models exactly ONLY within the window: max_model_len is
@@ -253,6 +274,14 @@ class ModelConfig:
         ]
 
     @property
+    def pre_norms(self) -> bool:
+        return self.norms != "post"
+
+    @property
+    def post_norms(self) -> bool:
+        return self.norms != "pre"
+
+    @property
     def q_per_kv(self) -> int:
         """Query heads a head of the cache serves (the attention kernels'
         group)."""
@@ -262,16 +291,19 @@ class ModelConfig:
     @property
     def cache_kv_heads(self) -> int:
         """Key / value heads as the cache holds them: a differential pair
-        is one head, and the pairs are filled up with empty heads to a
-        multiple of four: a token's slab of keys and values, (2 x heads,
-        head size), is then whole 8-row tiles, which the kernels' DMAs
-        need (10 pairs' 20 rows are refused by the TPU compiler: "Slice
-        shape along dimension 3 must be aligned to tiling (8)"; 12 heads'
-        24 rows pass). The empty heads hold zeros and their (zero) queries
-        read zeros."""
-        if not self.diff_attn:
-            return self.num_kv_heads
-        return -(-(self.num_kv_heads // 2) // 4) * 4
+        is one head, and more than four heads are filled up with empty
+        heads to a multiple of four: a token's slab of keys and values,
+        (2 x heads, head size), is then whole 8-row tiles, which the
+        kernels' DMAs need (10 pairs' 20 rows are refused by the TPU
+        compiler: "Slice shape along dimension 3 must be aligned to tiling
+        (8)"; 12 heads' 24 rows pass; Olmo-Hybrid's 30 heads' 60 rows are
+        held as 32 heads' 64). Up to four heads lie as they are (a slab
+        under a tile high is a tile of its own height). The empty heads
+        hold zeros and their (zero) queries read zeros."""
+        heads = self.num_kv_heads // 2 if self.diff_attn else self.num_kv_heads
+        if heads <= 4 and not self.diff_attn:
+            return heads
+        return -(-heads // 4) * 4
 
     @property
     def cache_head_dim(self) -> int:
@@ -292,10 +324,14 @@ class ModelConfig:
         "kda" (attn_period); "mla" / "kda" (mla_layers); "mamba", "swa"
         (window attention), "full", "gmu" and "cross" (mamba_period);
         "parallel" (ssd_heads: a state-space mixer AND attention, counted
-        among the recurrent layers and among the attention layers)."""
+        among the recurrent layers and among the attention layers); "gdn" /
+        "gqa" (gdn_layers)."""
         n = self.num_layers
         if self.ssd_heads:
             return ("parallel",) * n
+        if self.gdn_layers:
+            return tuple("gdn" if l in self.gdn_layers else "gqa"
+                         for l in range(n))
         if self.mla_layers:
             return tuple("mla" if l in self.mla_layers else "kda"
                          for l in range(n))
@@ -337,7 +373,7 @@ class ModelConfig:
 
     @property
     def num_recurrent_layers(self) -> int:
-        return self.count_layers("kda", "mamba", "parallel")
+        return self.count_layers("kda", "mamba", "parallel", "gdn")
 
     @property
     def window_binds(self) -> bool:
@@ -358,6 +394,11 @@ class ModelConfig:
     def ssd_conv_dim(self) -> int:
         """Channels its convolution runs over: [x | B | C]."""
         return self.ssd_inner + 2 * self.ssd_groups * self.ssd_state
+
+    @property
+    def gdn_conv_dim(self) -> int:
+        """Channels a GDN layer's convolution runs over: [q | k | v]."""
+        return self.gdn_heads * (2 * self.gdn_key_dim + self.gdn_value_dim)
 
     @property
     def is_latent(self) -> bool:
@@ -432,9 +473,9 @@ class ModelConfig:
 
     def recurrent_state_bytes(self, slots: int) -> int:
         """What the recurrent layers keep for ``slots`` decode slots: a
-        float32 state and a conv tail in the model dtype, a KDA layer's per
-        head, a state-space layer's per channel (with heads: per head and
-        channel, the tail over [x | B | C])."""
+        float32 state and a conv tail in the model dtype, a KDA or GDN
+        layer's per head, a state-space layer's per channel (with heads:
+        per head and channel, the tail over [x | B | C])."""
         item = jnp.dtype(self.jax_dtype).itemsize
         h, d = self.kda_heads, self.kda_head_dim
         kda = h * d * d * 4 + (self.kda_conv - 1) * 3 * h * d * item
@@ -442,9 +483,14 @@ class ModelConfig:
                                     + (self.mamba_conv - 1) * item)
         ssd = (self.ssd_inner * self.ssd_state * 4
                + (self.ssd_conv - 1) * self.ssd_conv_dim * item)
+        # a GDN layer's: d_k x d_v a head (not square), the tail over
+        # 2 H d_k + H d_v channels
+        gdn = (self.gdn_heads * self.gdn_key_dim * self.gdn_value_dim * 4
+               + (self.gdn_conv - 1) * self.gdn_conv_dim * item)
         return slots * (self.num_kda_layers * kda
                         + self.count_layers("mamba") * mamba
-                        + self.count_layers("parallel") * ssd)
+                        + self.count_layers("parallel") * ssd
+                        + self.count_layers("gdn") * gdn)
 
     @staticmethod
     def from_hf_config(cfg: dict[str, Any], name: str = "") -> "ModelConfig":
@@ -498,6 +544,8 @@ class ModelConfig:
             return ModelConfig._kimi_linear_from_hf(cfg, name)
         elif cfg.get("model_type") == "falcon_h1":
             return ModelConfig._falcon_h1_from_hf(cfg, name)
+        elif cfg.get("model_type") == "olmo_hybrid":
+            return ModelConfig._olmo_hybrid_from_hf(cfg, name)
         elif any("Phi3" in a for a in archs):
             # only the standard Phi-3 maps onto the fused-Llama layout;
             # Phi-3-small (query_key_value naming, gegelu, blocksparse)
@@ -597,7 +645,7 @@ class ModelConfig:
                 cfg.get("attn_logit_softcapping") or 0.0),
             final_logit_softcap=float(
                 cfg.get("final_logit_softcapping") or 0.0),
-            post_norms=arch in ("gemma2", "ouro"),
+            norms="both" if arch in ("gemma2", "ouro") else "pre",
             query_scale=(qpas ** -0.5) if qpas else 0.0,
             sliding_window=window,
             loop_passes=(int(cfg.get("total_ut_steps", 1))
@@ -749,7 +797,7 @@ class ModelConfig:
             routed_scaling=float(cfg.get("routed_scaling_factor", 1.0)),
             shared_expert_size=(int(cfg.get("n_shared_experts", 0))
                                 * cfg["moe_intermediate_size"]),
-            post_norms=True,
+            norms="both",
             kv_lora_rank=int(cfg["kv_lora_rank"]),
             q_lora_rank=int(cfg["q_lora_rank"]),
             qk_nope_head_dim=nope,
@@ -1003,6 +1051,87 @@ class ModelConfig:
         )
 
     @staticmethod
+    def _olmo_hybrid_from_hf(cfg: dict, name: str = "") -> "ModelConfig":
+        """``model_type: olmo_hybrid``: Gated DeltaNet layers and full
+        multi-head attention, named layer by layer in ``layer_types`` (one
+        period repeated: the full layer closes it), in the Olmo 3 family's
+        block: a norm AFTER each sublayer and none before, RMSNorm over the
+        whole q and k projections, and nothing rotated
+        (``rope_parameters.rope_theta`` null). What is not computed is
+        refused by name."""
+        what = "olmo_hybrid"
+        layers = int(cfg["num_hidden_layers"])
+        types = list(cfg.get("layer_types") or ())
+        known = {"linear_attention": "gdn", "full_attention": "gqa"}
+        kinds = [known.get(t) for t in types]
+        # one period, repeated: up to and with the first full layer
+        period = (types.index("full_attention") + 1
+                  if "full_attention" in types else 0)
+        whole = (period >= 2 and layers % period == 0
+                 and types == types[:period] * (layers // period))
+        heads, kv = (int(cfg[k]) for k in ("linear_num_key_heads",
+                                           "linear_num_value_heads"))
+        theta = (cfg.get("rope_parameters") or {}).get(
+            "rope_theta", cfg.get("rope_theta"))
+        refused = [
+            why for bad, why in (
+                ("sliding_attention" in types,
+                 "a sliding_attention entry in layer_types (only "
+                 "linear_attention and full_attention layers)"),
+                (None in kinds and "sliding_attention" not in types,
+                 f"layer_types entries {sorted(set(types) - set(known))} "
+                 "(only linear_attention and full_attention layers)"),
+                (len(types) != layers,
+                 f"layer_types of {len(types)} entries for "
+                 f"num_hidden_layers={layers}"),
+                (not whole,
+                 "a layer_types that is no whole number of one period "
+                 "(linear_attention layers closed by one full_attention "
+                 "layer)"),
+                (heads != kv,
+                 f"linear_num_key_heads {heads} != linear_num_value_heads "
+                 f"{kv} (a key head a value head)"),
+                (heads % 2 != 0,
+                 f"linear_num_value_heads {kv} odd (the state lies two "
+                 "heads side by side: ops/gdn.py)"),
+                (theta is not None,
+                 f"rope_theta={theta!r} (only null: nothing is rotated)"),
+                (bool(cfg.get("attention_bias")),
+                 "attention_bias: true (no projection has a bias)"),
+                (bool(cfg.get("tie_word_embeddings")),
+                 "tie_word_embeddings: true (only an untied head)"),
+                ((cfg.get("hidden_act") or "silu") != "silu",
+                 f"hidden_act={cfg.get('hidden_act')!r} (only silu)"),
+            ) if bad]
+        if refused:
+            raise ValueError(f"{what} is not supported with: "
+                             + "; ".join(refused))
+        hidden, n_heads = int(cfg["hidden_size"]), cfg["num_attention_heads"]
+        return ModelConfig(
+            name=name or cfg.get("_name_or_path", "hf-model"),
+            architecture="olmo_hybrid",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=hidden,
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=layers,
+            num_heads=n_heads,
+            num_kv_heads=cfg.get("num_key_value_heads", n_heads),
+            head_dim=cfg.get("head_dim") or hidden // n_heads,
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+            max_model_len=cfg.get("max_position_embeddings", 4096),
+            qk_norm=True,
+            qk_norm_kind="full",
+            norms="post",
+            attn_period=period,
+            gdn_layers=tuple(l for l, k in enumerate(kinds) if k == "gdn"),
+            gdn_heads=heads,
+            gdn_key_dim=int(cfg["linear_key_head_dim"]),
+            gdn_value_dim=int(cfg["linear_value_head_dim"]),
+            gdn_conv=int(cfg.get("linear_conv_kernel_dim", 4)),
+            gdn_neg_eigval=bool(cfg.get("linear_allow_neg_eigval", False)),
+        )
+
+    @staticmethod
     def _whisper_from_hf(cfg: dict, name: str = "") -> "ModelConfig":
         """WhisperForConditionalGeneration config.json → ModelConfig.
 
@@ -1131,7 +1260,7 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         hidden_size=128, intermediate_size=256, num_layers=2, num_heads=4,
         num_kv_heads=2, head_dim=32, max_model_len=512, dtype="float32",
         tie_word_embeddings=True, act="gelu_tanh", norm_offset=1.0,
-        embed_scale=True, post_norms=True, attn_logit_softcap=50.0,
+        embed_scale=True, norms="both", attn_logit_softcap=50.0,
         final_logit_softcap=30.0, query_scale=64.0 ** -0.5,
         sliding_window=512,  # query_pre_attn_scalar 64 ≠ head_dim 32
     ),
@@ -1151,7 +1280,7 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         hidden_size=3584, intermediate_size=14336, num_layers=42,
         num_heads=16, num_kv_heads=8, head_dim=256, max_model_len=4096,
         tie_word_embeddings=True, act="gelu_tanh", norm_offset=1.0,
-        embed_scale=True, post_norms=True, attn_logit_softcap=50.0,
+        embed_scale=True, norms="both", attn_logit_softcap=50.0,
         final_logit_softcap=30.0, query_scale=256.0 ** -0.5,
         sliding_window=4096, rms_norm_eps=1e-6,
     ),
@@ -1216,7 +1345,7 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         name="tiny-ouro", architecture="ouro", vocab_size=512,
         hidden_size=128, intermediate_size=256, num_layers=3, num_heads=4,
         num_kv_heads=4, head_dim=32, rope_theta=1000000.0,
-        rms_norm_eps=1e-6, max_model_len=512, post_norms=True,
+        rms_norm_eps=1e-6, max_model_len=512, norms="both",
         loop_passes=4, residual_f32=True, dtype="float32",
     ),
     "ouro-2.6b": ModelConfig(
@@ -1225,7 +1354,7 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         name="ouro-2.6b", architecture="ouro", vocab_size=49152,
         hidden_size=2048, intermediate_size=5632, num_layers=48,
         num_heads=16, num_kv_heads=16, head_dim=128, rope_theta=1000000.0,
-        rms_norm_eps=1e-6, max_model_len=65536, post_norms=True,
+        rms_norm_eps=1e-6, max_model_len=65536, norms="both",
         loop_passes=4, residual_f32=True,
     ),
     "tiny-pangu": ModelConfig(
@@ -1238,7 +1367,7 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         num_kv_heads=4, head_dim=48, rope_theta=25600000.0,
         max_model_len=512, num_experts=8, num_experts_per_tok=2,
         moe_scoring="sigmoid", routed_scaling=2.5, shared_expert_size=64,
-        post_norms=True, kv_lora_rank=32, q_lora_rank=48,
+        norms="both", kv_lora_rank=32, q_lora_rank=48,
         qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
         dense_layers=1, dense_intermediate_size=256, dtype="float32",
     ),

@@ -35,6 +35,7 @@ from production_stack_tpu.ops.kda import continues_one_row
 from production_stack_tpu.ops.ragged_paged_attention_pallas import (
     count_walks,
     count_windows,
+    q_tile_for,
 )
 from production_stack_tpu.parallel.mesh import build_mesh
 from production_stack_tpu.tenancy import split_shares
@@ -155,7 +156,8 @@ class LLMEngine:
                 config.model.num_recurrent_layers,
                 config.model.recurrent_state_bytes(slots),
                 kind=("mamba" if config.model.mamba_period
-                      else "ssd" if config.model.ssd_heads else "kda"))
+                      else "ssd" if config.model.ssd_heads
+                      else "gdn" if config.model.gdn_heads else "kda"))
             logging.getLogger(__name__).info(
                 "%s keeps recurrent state per decode slot (%d %s layers x "
                 "%d slots, %.2f GB): prefix-cache lookups are served as "
@@ -1000,19 +1002,21 @@ class LLMEngine:
             self.latent.record("ragged", np.diff(self._r_cu),
                                self._context_lens)
         else:
-            walks, narrow = count_walks(self._r_cu, W,
-                                        self.config.model.q_per_kv)
+            group = self.config.model.q_per_kv
+            # the kernel's own tile (it shrinks past 16 KV heads)
+            tile = q_tile_for(group, self.config.model.cache_kv_heads)
+            walks, narrow = count_walks(self._r_cu, W, group, q_tile=tile)
             self.ragged_attn_walks += walks
             self.ragged_attn_narrow_walks += narrow
             windows, interior = count_windows(
-                self._r_cu, self._context_lens, W,
-                self.config.model.q_per_kv, self.config.cache.block_size)
+                self._r_cu, self._context_lens, W, group,
+                self.config.cache.block_size, q_tile=tile)
             if self.window_counters is not None:
                 self.window_counters.record_ragged(
                     windows, count_windows(
-                        self._r_cu, self._context_lens, W,
-                        self.config.model.q_per_kv,
-                        self.config.cache.block_size, window=self.window)[0])
+                        self._r_cu, self._context_lens, W, group,
+                        self.config.cache.block_size, q_tile=tile,
+                        window=self.window)[0])
             self.ragged_attn_windows += windows
             self.ragged_attn_interior_windows += interior
 

@@ -19,7 +19,10 @@ TPU-first design:
   of the latent-attention layers alone where those are its attention
   layers. State-space (Mamba) layers keep
   ``state`` (layers, slots, N, d_i) float32 and ``conv`` (layers, slots,
-  K-1, d_i) the same way. Where a model's window binds
+  K-1, d_i) the same way, and Gated DeltaNet layers ``state`` (GDN layers,
+  slots, H / 2, d_k, 2 d_v) float32, two heads side by side on the lanes
+  (ops/gdn.py), and ``conv`` (GDN layers, slots, K-1, 2 H d_k + H d_v).
+  Where a model's window binds
   (``ModelConfig.window_binds``) the window layers' keys and values are a
   second pool, ``"win"``, of blocks of the same size from an allocator of
   its own (``window_pool_blocks``): a sequence holds a window block only
@@ -134,6 +137,17 @@ def init_kv_cache(
                                    jnp.float32),
                 "conv": jnp.zeros((ls, slots, model.ssd_conv - 1,
                                    model.ssd_conv_dim), dt),
+            }
+        if model.gdn_heads:
+            # two heads side by side on the lanes (ops/gdn.py: 2 x 192 =
+            # three whole lane tiles where a head alone would pad to 256)
+            lg, h = model.count_layers("gdn"), model.gdn_heads
+            return {
+                "kv": pool,
+                "state": jnp.zeros((lg, slots, h // 2, model.gdn_key_dim,
+                                    2 * model.gdn_value_dim), jnp.float32),
+                "conv": jnp.zeros((lg, slots, model.gdn_conv - 1,
+                                   model.gdn_conv_dim), dt),
             }
         h, d = model.kda_heads, model.kda_head_dim
         lk = model.num_kda_layers

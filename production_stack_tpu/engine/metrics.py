@@ -460,6 +460,31 @@ class EngineStatsCollector:
                 "each loads and stores its slot's state once a layer",
                 s["ssd_chunk_spans_total"],
             )
+        # Gated DeltaNet layers (RecurrentCounters, kind "gdn"): the same
+        # three meanings under their own names
+        if "gdn_decode_calls_total" in s:
+            yield counter(
+                "vllm:gdn_decode_calls",
+                "Gated DeltaNet decode steps the decode program ran (decode "
+                "dispatches x fused iterations x GDN layers); a ragged "
+                "dispatch runs one more a GDN layer for its decode rows",
+                s["gdn_decode_calls_total"],
+            )
+            yield counter(
+                "vllm:gdn_chunk_tokens",
+                "Rows of the ragged dispatches that the Gated DeltaNet "
+                "layers' span scan carried: every span's but the decode "
+                "rows' (one row that continues a state: the decode step "
+                "takes those)",
+                s["gdn_chunk_tokens_total"],
+            )
+            yield counter(
+                "vllm:gdn_chunk_spans",
+                "Spans of the ragged dispatches that the span scan carried: "
+                "each loads and stores its slot's state once a layer and "
+                "pair of heads",
+                s["gdn_chunk_spans_total"],
+            )
         # recurrent-state layers (engine/tracing.py RecurrentCounters):
         # exported by hybrid stacks only
         if "kda_decode_calls_total" in s:

@@ -533,6 +533,11 @@ class ModelRunner:
             # the model dtype; x, d x, y and the gated product in float32,
             # head-major copies of d x and y for the span kernel
             hidden += (T * self.cfg.ssd_conv_dim * (4 * 2 + 7 * 4)) // 8
+            # a GDN layer's rows: [q | k | v] before and after the
+            # convolution and the gate in the model dtype; the five float32
+            # inputs of the recurrence, head-major copies of them for the
+            # kernel, its output both ways and the gated product
+            hidden += (T * self.cfg.gdn_conv_dim * (3 * 2 + 9 * 4)) // 8
         if self.cfg.is_latent:
             # a latent layer's rows of all heads: the two parts of the
             # query, the absorbed query at the pool's lanes, the
@@ -899,25 +904,42 @@ class ModelRunner:
     # are updated in place at the KDA layer's index
     def _recur(self, ragged: bool, conv_w, qkv, g, beta, caches, k_idx,
                *step_inputs, neg_eigval):
-        """The packed stream (``ragged``: qkv (1, T, 3*H*D), ``step_inputs``
-        the span offsets and context lengths; a span continues its slot's
-        state and conv tail, or starts from zeros at position 0), or one
-        row a slot (qkv (B, 1, 3*H*D), ``step_inputs`` the live-slot mask;
-        idle slots keep what they hold)."""
-        from production_stack_tpu.ops import kda_pallas
+        """The packed stream (``ragged``: qkv (1, T, [q | k | v]),
+        ``step_inputs`` the span offsets and context lengths; a span
+        continues its slot's state and conv tail, or starts from zeros at
+        position 0), or one row a slot (qkv (B, 1, .), ``step_inputs`` the
+        live-slot mask; idle slots keep what they hold). One call for both
+        delta rules: KDA's (``g`` (.., H, D), a decay a key channel; a
+        square state a head) and Gated DeltaNet's (``g`` (.., H, 1), one a
+        head; ``ops/gdn.py``'s state of two heads side by side), which
+        share the convolutions, ``prepare`` and the span bookkeeping."""
+        cfg = self.cfg
+        if cfg.gdn_heads:
+            from production_stack_tpu.ops import gdn as rule, gdn_pallas
 
+            ragged_kernel, decode_kernel = (gdn_pallas.gdn_ragged,
+                                            gdn_pallas.gdn_decode_step)
+            split = functools.partial(rule.split_heads, heads=cfg.gdn_heads,
+                                      key_dim=cfg.gdn_key_dim)
+        else:
+            from production_stack_tpu.ops import kda_pallas
+
+            rule = kda
+            ragged_kernel, decode_kernel = (kda_pallas.kda_ragged,
+                                            kda_pallas.kda_decode_step)
+            split = functools.partial(kda.split_heads, heads=g.shape[-2])
         axis = 0 if ragged else 1  # of the axis the step form lacks
         conv, xla, pallas = (
-            (kda.conv_ragged, kda.recurrence_ragged, kda_pallas.kda_ragged)
-            if ragged else (kda.conv_decode, kda.recurrence_decode,
-                            kda_pallas.kda_decode_step))
+            (kda.conv_ragged, rule.recurrence_ragged, ragged_kernel)
+            if ragged else (kda.conv_decode, rule.recurrence_decode,
+                            decode_kernel))
         tail = jax.lax.dynamic_index_in_dim(caches["conv"], k_idx, 0, False)
         x, tail = conv(jnp.squeeze(qkv, axis), conv_w, tail, *step_inputs)
         g = jnp.squeeze(g, axis)
-        a, *prep = kda.prepare(*kda.split_heads(x, g.shape[-2]), g,
-                               jnp.squeeze(beta, axis), neg_eigval)
+        a, *prep = kda.prepare(*split(x), g, jnp.squeeze(beta, axis),
+                               neg_eigval)
         if ragged and self.use_pallas:
-            # the span kernel sums the log-decay itself (a ratio of two
+            # the span kernels sum the log-decay themselves (a ratio of two
             # products of ``a`` would overflow): ops/kda_pallas.py
             a = g.astype(jnp.float32)
         o, state = (pallas if self.use_pallas else xla)(

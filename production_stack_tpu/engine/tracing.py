@@ -755,8 +755,8 @@ class RecurrentCounters:
         """``kind``: "kda"; "mamba" for a stack of state-space layers,
         whose span kernel takes rows one by one (no blocks to count); "ssd"
         for a stack whose every layer holds a state-space mixer with heads
-        (blocks of its own size, not counted). The counters carry the
-        name."""
+        and "gdn" for Gated DeltaNet layers (blocks of their own size, not
+        counted). The counters carry the name."""
         self.kda_layers, self.state_bytes = kda_layers, state_bytes
         self.kind = kind
         self.decode_calls = 0   # decode dispatches x iterations x layers

@@ -199,7 +199,7 @@ def _hf_key_map(cfg: ModelConfig, i: int) -> dict:
             "post_attn_norm", "copy")
         m[f"model.layers.{i}.post_attention_layernorm_2.weight"] = (
             "post_mlp_norm", "copy")
-    elif cfg.post_norms:
+    elif cfg.norms == "both":
         # Gemma-2 block: HF "post_attention_layernorm" is the norm on the
         # ATTENTION OUTPUT (our post_attn_norm); the pre-MLP norm is
         # "pre_feedforward_layernorm" and the MLP output norm
@@ -381,11 +381,11 @@ def _check_ouro_names(cfg: ModelConfig, index: dict) -> None:
 
 def load_safetensors(cfg: ModelConfig, mesh: Mesh, rules: ShardingRules) -> dict:
     if cfg.architecture in ("solar_open2", "pangu_ultra_moe", "phi4flash",
-                            "kimi_linear", "falcon_h1"):
-        # the published tensor names (the KDA layers' conv, decay and gate
-        # tensors, the router's bias; the latent paths' projections and
-        # norms; the state-space layers' and the fused attention and MLP
-        # tensors) are not known here and there is no
+                            "kimi_linear", "falcon_h1", "olmo_hybrid"):
+        # the published tensor names (the KDA and GDN layers' conv, decay
+        # and gate tensors, the router's bias; the latent paths'
+        # projections and norms; the state-space layers' and the fused
+        # attention and MLP tensors) are not known here and there is no
         # network to read them from: a guessed map would load silently
         # wrong or die mid-load, so a checkpoint is refused up front
         raise ValueError(

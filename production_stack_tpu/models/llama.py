@@ -36,7 +36,7 @@ from production_stack_tpu.engine.quant import (
     quant_einsum,
     ragged_quant_dot,
 )
-from production_stack_tpu.models import falcon_h1, sambay
+from production_stack_tpu.models import falcon_h1, olmo_hybrid, sambay
 from production_stack_tpu.ops import kda
 from production_stack_tpu.ops.attention import dense_causal_attention
 from production_stack_tpu.ops.norms import layer_norm, rms_norm
@@ -114,6 +114,24 @@ def _latent_layer_specs(cfg: ModelConfig, sparse: bool) -> dict:
     return layer
 
 
+def _kda_specs() -> dict:
+    """The KDA mixers' stack, (KDA layers, ...)."""
+    L = lax_names
+    return {
+        "w_qkv": (L.LAYERS, L.EMBED, None),  # [q | k | v], each H*D
+        "wo": (L.LAYERS, L.HEADS, L.HEAD_DIM, L.EMBED),
+        "conv": (L.LAYERS, None, None),  # (K, 3*H*D), tap 0 = current
+        "a_log": (L.LAYERS, L.HEADS),
+        "dt_bias": (L.LAYERS, L.HEADS, L.HEAD_DIM),
+        "f_down": (L.LAYERS, L.EMBED, None),
+        "f_up": (L.LAYERS, None, L.HEADS, L.HEAD_DIM),
+        "w_beta": (L.LAYERS, L.EMBED, L.HEADS),
+        "g_down": (L.LAYERS, L.EMBED, None),
+        "g_up": (L.LAYERS, None, L.HEADS, L.HEAD_DIM),
+        "o_norm": (L.LAYERS, L.HEAD_DIM),
+    }
+
+
 def param_specs(cfg: ModelConfig) -> dict:
     """Pytree of logical-axes tuples mirroring the param pytree, as
     ``init_params`` makes it and a checkpoint loads into it. Which leaves a
@@ -173,6 +191,8 @@ def param_specs(cfg: ModelConfig) -> dict:
                 "post_mlp_norm": (L.LAYERS, L.EMBED),
             }
         )
+    if not cfg.pre_norms:  # the Olmo 3 block: those two alone
+        del layer["attn_norm"], layer["mlp_norm"]
     mixers = {}
     if cfg.mamba_period:
         # every block's norms (LayerNorm: a bias each) and MLP stay in
@@ -212,19 +232,13 @@ def param_specs(cfg: ModelConfig) -> dict:
             }
         else:
             mixers["gqa"] = {**attn, "wq": (L.LAYERS, L.EMBED, L.HEADS)}
-        mixers["kda"] = {
-            "w_qkv": (L.LAYERS, L.EMBED, None),  # [q | k | v], each H*D
-            "wo": (L.LAYERS, L.HEADS, L.HEAD_DIM, L.EMBED),
-            "conv": (L.LAYERS, None, None),  # (K, 3*H*D), tap 0 = current
-            "a_log": (L.LAYERS, L.HEADS),
-            "dt_bias": (L.LAYERS, L.HEADS, L.HEAD_DIM),
-            "f_down": (L.LAYERS, L.EMBED, None),
-            "f_up": (L.LAYERS, None, L.HEADS, L.HEAD_DIM),
-            "w_beta": (L.LAYERS, L.EMBED, L.HEADS),
-            "g_down": (L.LAYERS, L.EMBED, None),
-            "g_up": (L.LAYERS, None, L.HEADS, L.HEAD_DIM),
-            "o_norm": (L.LAYERS, L.HEAD_DIM),
-        }
+            if cfg.qk_norm:  # over the whole projections, as made above
+                mixers["gqa"].update(
+                    {k: layer.pop(k) for k in ("q_norm", "k_norm")})
+        if cfg.gdn_heads:
+            mixers["gdn"] = olmo_hybrid.param_specs(cfg)
+        else:
+            mixers["kda"] = _kda_specs()
         if cfg.attn_gate:
             mixers["gqa"]["wg"] = (L.LAYERS, L.EMBED, L.HEADS)
         if cfg.dense_layers:
@@ -490,13 +504,16 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         )
     if cfg.post_norms:
         # Gemma stores zero-centred norm weights (forward adds norm_offset)
-        gain = LOOPED_POST_NORM_GAIN if cfg.loop_passes > 1 else 1.0
+        gain = (LOOPED_POST_NORM_GAIN if cfg.loop_passes > 1
+                else out ** -0.5 if not cfg.pre_norms else 1.0)
         layers.update(
             {
                 "post_attn_norm": jnp.full((Ln, E), gain - cfg.norm_offset, dt),
                 "post_mlp_norm": jnp.full((Ln, E), gain - cfg.norm_offset, dt),
             }
         )
+    if not cfg.pre_norms:
+        del layers["attn_norm"], layers["mlp_norm"]
     mixers = {}
     if cfg.mamba_period:
         layers = {k: layers[k] for k in ("attn_norm", "mlp_norm")}
@@ -520,7 +537,20 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         else:
             mixers["gqa"] = {k: v[:Pn] for k, v in attn.items()}
             mixers["gqa"]["wq"] = mixers["gqa"]["wq"].reshape(Pn, E, H * D)
-        mixers["kda"] = _init_kda(cfg, keys[13], normal, out)
+            if cfg.qk_norm:
+                mixers["gqa"].update({k: layers.pop(k)[:Pn]
+                                      for k in ("q_norm", "k_norm")})
+        if cfg.gdn_heads:
+            mixers["gdn"] = olmo_hybrid.init_params(
+                cfg, keys[13], normal, out, (KDA_DT_MIN, KDA_DT_MAX))
+            # the norm after a GDN mixer weighs more (STANDIN_MIXER_GAIN)
+            is_gdn = jnp.asarray([k == "gdn" for k in cfg.layer_kinds])
+            layers["post_attn_norm"] = (
+                layers["post_attn_norm"].astype(jnp.float32) * jnp.where(
+                    is_gdn, olmo_hybrid.STANDIN_MIXER_GAIN, 1.0)[:, None]
+            ).astype(dt)
+        else:
+            mixers["kda"] = _init_kda(cfg, keys[13], normal, out)
         if cfg.attn_gate:
             mixers["gqa"]["wg"] = normal(keys[14], (Pn, E, H * D), E)
         if cfg.dense_layers:
@@ -631,6 +661,10 @@ KDA_DT_MIN, KDA_DT_MAX = 1e-3, 1e-1
 # largest difference read 0.066 / 0.072 / 0.078 / 0.114 on four seeds on
 # the chip (mean 0.012-0.015; limit 0.15) and 0.061-0.088 on the CPU at
 # width 512, with the term 0.046-0.055 there: Solar-Open2's 1 / 8.
+# Where a block's norms come AFTER its sublayers and none before
+# (``cfg.norms`` "post", Olmo-Hybrid), a sublayer's size is its norm's gain
+# whatever its matrices are, so the 1 / sqrt(2 x layers) goes on the gain of
+# those norms (``init_params``), as ``_latent_post_norm_gain`` does.
 
 
 # SAMBAY_INIT. Random stand-in weights of a SambaY stack, whose head is
@@ -979,7 +1013,9 @@ def forward_hidden(
             recur or (functools.partial(sambay.mamba_dense, cfg)
                       if cfg.mamba_period
                       else functools.partial(falcon_h1.ssd_dense, cfg)
-                      if cfg.ssd_heads else _recur_dense),
+                      if cfg.ssd_heads
+                      else functools.partial(olmo_hybrid.recur_dense, cfg)
+                      if cfg.gdn_heads else _recur_dense),
             kv_caches, live, pre_norm, grouped_matmul, positions)
         out = (x, new_caches)
         if moe_hist:
@@ -1025,7 +1061,8 @@ def forward_hidden(
             )
         q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
-        attn, caches = attend(q, k, v, caches, cache_layer)
+        attn, caches = _attend_filled(cfg, attend, q, k, v, caches,
+                                      cache_layer)
         o = quant_einsum("...thd,hde->...te", attn, lp["wo"])
         if lb is not None and "wo" in lb:
             flat = attn.reshape(*attn.shape[:-2], -1)  # (..., T, H*D)
@@ -1239,6 +1276,20 @@ def _kda_mixer(cfg: ModelConfig, kp: dict, x: jnp.ndarray, recur: RecurFn,
     return quant_einsum("...thd,hde->...te", o, kp["wo"]), caches
 
 
+def _attend_filled(cfg: ModelConfig, attend: AttendFn, q, k, v, kv, i):
+    """``attend`` over the heads as the cache holds them: where it fills
+    the KV heads up with empty ones (``ModelConfig.cache_kv_heads``: 30
+    heads lie as 32), zero heads behind the real ones on q, k and v, and
+    the real heads' outputs back. Without filling, the call as it is."""
+    held = cfg.cache_kv_heads
+    if held == cfg.num_kv_heads or cfg.diff_attn:  # a packed stack fills
+        return attend(q, k, v, kv, i)              # its own heads
+    attn, kv = attend(sambay._pad_heads(q, held * cfg.q_per_kv),
+                      sambay._pad_heads(k, held), sambay._pad_heads(v, held),
+                      kv, i)
+    return attn[..., :cfg.num_heads, :], kv
+
+
 def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
                     experts: dict, x, attend: AttendFn,
                     recur, caches, live, pre_norm, grouped_matmul=None,
@@ -1281,13 +1332,15 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
     period's slice first: 3 x 200 MB of copies a period in the decode
     step, counted by the TPU compiler at the published widths."""
     stack_of = {"gqa": "gqa", "kda": "kda", "mla": "mla", "parallel": "gqa",
-                **sambay.STACK_OF}
+                "gdn": "gdn", **sambay.STACK_OF}
     dense = cfg.dense_layers
 
     def gqa(gp, normed, caches, i, rotate=False):
         """Grouped-query attention over cache layer ``i``: Solar-Open2's
-        (nothing rotated, a sigmoid gate) or Falcon-H1's (``rotate``: rope
-        on q and k, the keys times ``key_multiplier``; no gate)."""
+        (nothing rotated, a sigmoid gate), Falcon-H1's (``rotate``: rope
+        on q and k, the keys times ``key_multiplier``; no gate) or
+        Olmo-Hybrid's (nothing rotated, no gate, RMSNorm over the whole q
+        and k projections)."""
         if "wq_t" in gp:
             # W_q, W_k, W_v lie transposed, (H * D, E): the order of bytes
             # the TPU compiler wants for a decode step's 64 rows; handed
@@ -1309,12 +1362,15 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
             k = quant_einsum("...te,ehd->...thd", normed, gp["wk"])
             v = quant_einsum("...te,ehd->...thd", normed, gp["wv"])
         q = q.reshape(*q.shape[:-1], cfg.num_heads, cfg.head_dim)
+        if cfg.qk_norm:  # Olmo-Hybrid: over the whole projections (OLMoE's)
+            q = _rms_norm_heads(q, gp["q_norm"], cfg.rms_norm_eps)
+            k = _rms_norm_heads(k, gp["k_norm"], cfg.rms_norm_eps)
         if rotate:
             k = times(k, cfg.key_multiplier)
             q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
             k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
         kv = None if caches is None else caches["kv"]
-        attn, kv = attend(q, k, v, kv, i)
+        attn, kv = _attend_filled(cfg, attend, q, k, v, kv, i)
         if caches is not None:
             caches = {**caches, "kv": kv}
         if cfg.attn_gate:
@@ -1324,6 +1380,18 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
     def at(tree, i):
         return jax.tree.map(
             lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
+
+    def stream_in(h):
+        """The stream as a sublayer without a norm before it reads it."""
+        return h.astype(cfg.jax_dtype) if cfg.residual_f32 else h
+
+    def sublayer_out(o, lp, norm):
+        """A sublayer's output as the stream takes it: through the norm
+        AFTER it where the block has one (``cfg.norms`` "post"; the other
+        patterned stacks have none and add ``o`` as it is)."""
+        if cfg.pre_norms:
+            return o
+        return rms_norm(o, lp[norm], cfg.rms_norm_eps, cfg.norm_offset)
 
     def period_fn(kinds, l0, before, shared, carry, p):
         """Period ``p`` of a run of ``kinds`` periods that starts at layer
@@ -1342,7 +1410,10 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
             is_dense = l0 + j < dense
             lp = (at(params["dense"], l0 + j) if is_dense
                   else at(layers, depth - dense if dense else depth))
-            normed = pre_norm(h, lp["attn_norm"], lp.get("attn_norm_b"))
+            # the Olmo 3 block has no norm before a sublayer: it reads the
+            # stream as it is, and the norm follows it (``sublayer_out``)
+            normed = (pre_norm(h, lp["attn_norm"], lp.get("attn_norm_b"))
+                      if cfg.pre_norms else stream_in(h))
             with jax.named_scope(kind):
                 # this layer's place in its kind's stack: the period's own
                 # layers of the kind, behind those of the periods before
@@ -1366,6 +1437,9 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
                 elif kind == "kda":
                     o, caches = _kda_mixer(cfg, at(params["kda"], i),
                                            normed, recur, caches, i)
+                elif kind == "gdn":
+                    o, caches = olmo_hybrid.gdn_mixer(
+                        cfg, at(params["gdn"], i), normed, recur, caches, i)
                 elif kind == "mla":  # rotates nothing: no positions
                     kv = None if caches is None else caches["kv"]
                     o, kv = _mla_mixer(cfg, at(params["mla"], i), normed,
@@ -1395,8 +1469,10 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
                                           i if kind == "swa" else 0,
                                           kind=kind)
                     o = sambay.diff_combine(cfg, ap, attn, depth)
-            h = h + o
-            if is_dense:
+            h = h + sublayer_out(o, lp, "post_attn_norm")
+            if not cfg.pre_norms:
+                mlp_out = _mlp(cfg, lp, stream_in(h))
+            elif is_dense:
                 with jax.named_scope("dense_mlp"):
                     mlp_out = _mlp(cfg, lp, pre_norm(h, lp["mlp_norm"]))
             elif cfg.is_moe:
@@ -1408,7 +1484,7 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
             else:
                 mlp_out = _mlp(cfg, lp, pre_norm(h, lp["mlp_norm"],
                                                  lp.get("mlp_norm_b")))
-            h = h + mlp_out
+            h = h + sublayer_out(mlp_out, lp, "post_mlp_norm")
         return (h, p + 1, caches), (jnp.stack(hists) if hists else None)
 
     l0, before, shared, hists = 0, dict.fromkeys(stack_of.values(), 0), {}, []

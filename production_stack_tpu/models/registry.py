@@ -64,6 +64,15 @@ _REGISTRY: dict[str, ModuleType] = {
     # Served with six whole layers and the whole vocabulary on one chip:
     # chipbench cell falcon-h1-34b-l6.decode-heavy (PR 52)
     "falcon_h1": llama,
+    # Olmo-Hybrid: the same walker over Gated DeltaNet layers named layer
+    # by layer (cfg.gdn_layers; models/olmo_hybrid.py, ops/gdn.py: one
+    # scalar decay a head, a 96 x 192 state) and full multi-head attention
+    # that rotates nothing, QK-norm over the whole projections, in the Olmo
+    # 3 block: a norm AFTER each sublayer and none before (cfg.norms
+    # "post"). 30 KV heads lie in the cache as 32. Served with sixteen
+    # whole layers and the whole vocabulary on one chip: chipbench cell
+    # olmo-hybrid-7b-l16.decode-heavy (PR 57)
+    "olmo_hybrid": llama,
     # encoder-decoder audio transcription: exposes its own forward
     # surface (encode/cross_kv/decode_tokens) instead of the decoder-only
     # protocol; shares param_specs/init_params so weights.py works

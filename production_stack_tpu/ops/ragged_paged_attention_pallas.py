@@ -86,8 +86,13 @@ LANES = 128
 Q_TILE = 128  # stream tokens a grid step owns, at up to 4 query heads a KV head
 
 
-def q_tile_for(group: int) -> int:
-    """Stream tokens a grid step owns at ``group`` query heads a KV head.
+# KV heads up to which a tile keeps its tokens (OLMoE's and Ouro's 16)
+TILE_KV_HEADS = 16
+
+
+def q_tile_for(group: int, kv_heads: int = TILE_KV_HEADS) -> int:
+    """Stream tokens a grid step owns at ``group`` query heads a KV head
+    and ``kv_heads`` KV heads.
     A tile's rows are tokens x group, and its query block, output block
     and float32 accumulators grow with them: 128 tokens at G = 8 ask for
     40 MiB of scoped VMEM (the compiler's count for KH = 8, D = 128), over
@@ -97,10 +102,19 @@ def q_tile_for(group: int) -> int:
     rows that are whole ``ROW_ALIGN`` tiles (G = 5: 96 tokens, 480 rows; a
     power of two keeps what it had): a tile of 510 rows has no narrow
     block at all (``narrow_walk``), and every decode row of a ragged step
-    would take the whole tile's body."""
-    if group <= 4:
+    would take the whole tile's body.
+
+    The same blocks grow with the KV heads too, and the landed windows of
+    the cache besides: KH = 16, G = 1 asks 10.60 MiB of the default 16, and
+    32 heads at the same 128 tokens 21.18 (the compiler's counts;
+    tests/test_kernel_names_v5e.py). Past ``TILE_KV_HEADS`` the tile
+    shrinks so that tokens x KV heads stay what 16 heads have (32 heads: 64
+    tokens); every geometry up to 16 KV heads keeps its tile."""
+    tokens = Q_TILE if group <= 4 else Q_TILE * 4 // group
+    if kv_heads > TILE_KV_HEADS:
+        tokens = tokens * TILE_KV_HEADS // kv_heads
+    elif group <= 4:
         return Q_TILE
-    tokens = Q_TILE * 4 // group
     return max(tokens - tokens % (ROW_ALIGN // math.gcd(group, ROW_ALIGN)),
                16)
 
@@ -527,7 +541,7 @@ def ragged_paged_attention_pallas(
     L, N, bs, KH2, _ = kv_cache.shape
     KH = KH2 // 2
     G = H // KH
-    TQ = min(q_tile or q_tile_for(G), T)
+    TQ = min(q_tile or q_tile_for(G, KH), T)
     Tp = -(-T // TQ) * TQ
     if Tp != T:  # tail-pad the stream to a tile multiple (rows → zeros)
         q = jnp.pad(q, ((0, Tp - T), (0, 0), (0, 0)))

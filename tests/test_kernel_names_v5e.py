@@ -401,6 +401,30 @@ def test_the_ragged_tile_is_keyed_by_the_group():
     assert (q_tile_for(8), q_tile_for(16)) == (64, 32)
 
 
+def test_the_accepted_geometries_ragged_tiles_are_what_they_were():
+    """The tile is keyed by the KV heads too since Olmo-Hybrid's 32 held
+    heads (PR 57): past 16 it shrinks so that tokens x KV heads stay what
+    16 heads have. Every cell the benchmark had keeps its tile: (KV heads
+    as the cache holds them, group) -> tokens."""
+    from production_stack_tpu.ops.ragged_paged_attention_pallas import (
+        ROW_ALIGN,
+        q_tile_for,
+    )
+
+    accepted = {(8, 4): 128,    # qwen3-8b-l16
+                (16, 1): 128,   # olmoe-1b-7b-l8, ouro-2.6b
+                (8, 8): 64,     # solar-open2-250b-ep16-l8
+                (12, 4): 128,   # phi-4-mini-flash-reasoning (packed pairs)
+                (4, 5): 96,     # falcon-h1-34b-l6
+                (8, 3): 128}    # the kernel check's own geometry
+    assert {k: q_tile_for(k[1], k[0]) for k in accepted} == accepted
+    # keyed by the group alone the answers are the same
+    assert all(q_tile_for(g) == q_tile_for(g, kh) for kh, g in accepted)
+    assert q_tile_for(1, 32) == 64  # olmo-hybrid-7b-l16: 30 heads held as 32
+    assert all(q_tile_for(g, kh) * g % ROW_ALIGN == 0
+               for kh in (20, 24, 32, 64) for g in (1, 2, 4, 8))
+
+
 # -- the ragged kernel's two window bodies at the cells' three geometries ----
 # A 2048-token stream over 64 slots of 8192 positions, as the engines run
 # it: KH=8, G=4 (qwen3-8b-l16) and KH=8, G=8 (solar-open2-250b-ep16-l8)
@@ -823,6 +847,120 @@ def test_a_group_that_divides_no_power_of_two_keeps_a_narrow_block():
     assert decode_window_body(G5_KH, G5, D, jnp.bfloat16) == "grouped"
 
 
+# -- Olmo-Hybrid on one chip (chipbench olmo-hybrid-7b-l16): the two Gated
+# DeltaNet kernels at 12 layers x 64 slots of 15 pairs of (96, 384) states,
+# and both attention kernels and the KV write at KH 30 held as 32, G 1,
+# D 128, 4 cache layers: all under the DEFAULT 16 MiB of scoped VMEM (the
+# configuration sets no libtpu flag; gdn_chunk_scan sets its own limit) ------
+
+GDN_STATE = ((12, 64, 15, 96, 384), jnp.float32)
+KH32_CACHE = ((4, 2560, BS, 2 * 32, D), jnp.bfloat16)
+# what each asks (the compiler's own counts): the ragged kernel at the
+# 64-token tile 32 KV heads get (at 128 tokens: 21.18, refused), the decode
+# kernel's slab body, gdn_decode_step at 15 pairs a cell
+KH32_RAGGED_VMEM_MIB, KH32_DECODE_VMEM_MIB, GDN_DECODE_VMEM_MIB = (
+    12.51, 8.06, 8.44)
+
+
+def _gdn_cases():
+    from production_stack_tpu.ops.gdn_pallas import (
+        gdn_decode_step,
+        gdn_ragged,
+    )
+
+    def rows(t):
+        return [((t, 30, 1), jnp.float32), *[((t, 30, 96), jnp.float32)] * 3,
+                ((t, 30, 192), jnp.float32)]
+
+    def ragged(width):  # the ragged program's stream widths
+        return (
+            lambda st, g, kb, k, q, vb, cu, ctx: gdn_ragged(
+                st, 3, g, kb, k, q, vb, cu, ctx),
+            (GDN_STATE, *rows(width), ((65,), I32), ((64,), I32)))
+
+    return {
+        "gdn_decode_step": (
+            lambda st, a, kb, k, q, vb, act: gdn_decode_step(
+                st, 3, a, kb, k, q, vb, act),
+            (GDN_STATE, *rows(64), ((64,), jnp.bool_))),
+        "gdn_chunk_scan": ragged(2048),
+        "gdn_chunk_scan@512": ragged(512),
+    }
+
+
+def _kh32_cases():
+    def ragged(width):
+        return (
+            lambda q, c, bt, cu, cl: ragged_paged_attention_pallas(
+                q, c, bt, cu, cl, layer_idx=1),
+            (((width, 32, D), jnp.bfloat16), KH32_CACHE, ((64, 512), I32),
+             ((65,), I32), ((64,), I32)))
+
+    return {
+        "ragged_paged_attention": ragged(2048),
+        "ragged_paged_attention@512": ragged(512),
+        "paged_decode_attention": (
+            lambda q, c, bt, cl: paged_decode_attention_pallas(
+                q, c, bt, cl, layer_idx=1),
+            (((64, 32, D), jnp.bfloat16), KH32_CACHE, ((64, 512), I32),
+             ((64,), I32))),
+        "kv_cache_write": (
+            lambda c, new, sm: kv_cache_write_pallas(c, new, sm, layer_idx=1),
+            (KH32_CACHE, ((2048, 2 * 32, D), jnp.bfloat16), ((2048,), I32))),
+    }
+
+
+@pytest.mark.parametrize(
+    "case", ["gdn_chunk_scan", "gdn_chunk_scan@512", "gdn_decode_step"])
+def test_gdn_kernel_is_a_named_custom_call_at_the_cells_shapes(one_chip,
+                                                               case):
+    fn, shapes = _gdn_cases()[case]
+    name = case.partition("@")[0]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    lowered = jax.jit(fn, donate_argnums=0).lower(*args)
+    compiled = lowered.compile()  # default options: 16 MiB
+    text = compiled.as_text()
+    assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = .*? custom-call\(",
+                     text, flags=re.M)
+    # a ragged step runs BOTH kernels, its decode rows through the one-row
+    # kernel; the state of all layers (1.7 GB) is updated in place: no
+    # second copy of it among the program's temporaries
+    if name == "gdn_chunk_scan":
+        assert re.search(r"^\s*(?:ROOT )?%gdn_decode_step[.\d]* = ", text,
+                         flags=re.M)
+    else:
+        assert _asks_at_most(lowered, GDN_DECODE_VMEM_MIB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 300 * 2 ** 20
+
+
+@pytest.mark.parametrize("case", sorted(_kh32_cases()))
+def test_attention_kernels_compile_at_kh32_g1_under_default_vmem(one_chip,
+                                                                 case):
+    fn, shapes = _kh32_cases()[case]
+    name = case.partition("@")[0]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = .*? custom-call\(",
+                     lowered.compile().as_text(), flags=re.M)
+    ask = {"ragged_paged_attention": KH32_RAGGED_VMEM_MIB,
+           "paged_decode_attention": KH32_DECODE_VMEM_MIB}.get(name)
+    if ask:
+        assert _asks_at_most(lowered, ask)
+
+
+def test_the_ragged_kernel_at_kh32_and_the_old_tile_is_refused(one_chip):
+    """Why the tile shrinks: 128 tokens of 32 KV heads ask 21.18 MiB."""
+    fn, shapes = _kh32_cases()["ragged_paged_attention"]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    with pytest.raises(Exception, match="Scoped allocation with size 21"):
+        jax.jit(lambda q, c, bt, cu, cl: ragged_paged_attention_pallas(
+            q, c, bt, cu, cl, layer_idx=1, q_tile=128)).lower(
+                *args).compile()
+
+
 # The engine's own step programs at the published widths, whole, for the
 # described v5e: configuration (chipbench/configs/<name>) -> the blocks its
 # pool holds on the chip (the ledger's kv_cache_write shapes; a pool larger
@@ -836,6 +974,7 @@ STEP_CONFIGS = {
     "phi-4-mini-flash-reasoning": 4096,
     "kimi-linear-48b-a3b-ep16": 4096,
     "falcon-h1-34b-l6": 8192,
+    "olmo-hybrid-7b-l16": 2560,
 }
 SLOTS_SERVED = 64
 # a whole weight stack is tens of MiB and up; a step's own rows are not
@@ -887,6 +1026,10 @@ def _cache_shapes(cfg, blocks, slots, one_chip):
         n = cfg.count_layers("parallel")
         state = (cfg.ssd_heads, cfg.ssd_state, cfg.ssd_head_dim)
         conv = (cfg.ssd_conv - 1, cfg.ssd_conv_dim)
+    elif cfg.gdn_heads:
+        n = cfg.count_layers("gdn")
+        state = (cfg.gdn_heads // 2, cfg.gdn_key_dim, 2 * cfg.gdn_value_dim)
+        conv = (cfg.gdn_conv - 1, cfg.gdn_conv_dim)
     else:
         n, h, d = cfg.num_kda_layers, cfg.kda_heads, cfg.kda_head_dim
         state, conv = (h, d, d), (cfg.kda_conv - 1, 3 * h * d)
@@ -1015,6 +1158,7 @@ STEP_TEMP_MIB = {
     ("olmoe-1b-7b-l8", "decode"): 32,
     ("solar-open2-250b-ep16-l8", "decode"): 100,
     ("falcon-h1-34b-l6", "decode"): 32,
+    ("olmo-hybrid-7b-l16", "decode"): 32,
 }
 # whole stacks still copied (ROADMAP S20): a width that is no whole 128-lane
 # tiles (the router's 320 experts, W_x's 192 columns), as Falcon-H1's W_in
@@ -1051,11 +1195,18 @@ def test_step_program_copies_no_weight_stack(one_chip, config, program):
     nothing more."""
     compiled = _step_program(one_chip, config, program)
     text = compiled.as_text()
-    if (config, program) == ("falcon-h1-34b-l6", "decode"):
-        for name in ("ssd_decode_step", "paged_decode_attention",
-                     "kv_cache_write"):
-            assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = ", text,
-                             flags=re.M), name
+    kernels = {
+        ("falcon-h1-34b-l6", "decode"): (
+            "ssd_decode_step", "paged_decode_attention", "kv_cache_write"),
+        ("olmo-hybrid-7b-l16", "decode"): (
+            "gdn_decode_step", "paged_decode_attention", "kv_cache_write"),
+        ("olmo-hybrid-7b-l16", "ragged512"): (
+            "gdn_decode_step", "gdn_chunk_scan", "ragged_paged_attention",
+            "kv_cache_write"),
+    }.get((config, program), ())
+    for name in kernels:
+        assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = ", text,
+                         flags=re.M), name
     whole = [c for c in _parameter_copies(text) if c[2] >= COPY_FLOOR
              and not c[1].startswith(STILL_COPIED.get((config, program), "-"))]
     assert not whole, whole

@@ -524,7 +524,7 @@ def test_a_fault_in_the_mixer_as_the_benchmark_would_read_it(
 
 
 def _a_post_norm_skipped(params):
-    return dataclasses.replace(tiny_cfg(), post_norms=False), params, HF
+    return dataclasses.replace(tiny_cfg(), norms="pre"), params, HF
 
 
 def _layer_0_run_sparse(params):

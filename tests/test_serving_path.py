@@ -204,6 +204,12 @@ def _falcon_h1():
     return CFG
 
 
+def _olmo_hybrid():
+    from tests.test_olmo_hybrid import CFG
+
+    return CFG
+
+
 LATER_FAMILIES = {
     "tiny-olmoe": (lambda: ModelConfig.from_pretrained("tiny-olmoe"), 4),
     "tiny-ouro": (lambda: ModelConfig.from_pretrained("tiny-ouro"), 4),
@@ -214,6 +220,7 @@ LATER_FAMILIES = {
     "tiny-kimi-linear": (
         lambda: ModelConfig.from_pretrained("tiny-kimi-linear"), 16),
     "tiny-falcon-h1": (_falcon_h1, 4),
+    "tiny-olmo-hybrid": (_olmo_hybrid, 4),
 }
 
 
