@@ -14,12 +14,14 @@ import concurrent.futures
 import logging
 import queue
 import threading
+import time
 import uuid
 from typing import AsyncIterator, Optional, Sequence as Seq
 
 from production_stack_tpu.engine.engine import LLMEngine
 from production_stack_tpu.engine.sampling import SamplingParams
 from production_stack_tpu.engine.sequence import RequestOutput
+from production_stack_tpu.engine.tracing import StepClock
 
 
 class RequestAborted(Exception):
@@ -29,6 +31,13 @@ class RequestAborted(Exception):
     consumer first and never see this; it exists so an abort from
     anywhere else can never leave a consumer blocked on q.get()
     forever."""
+
+
+def _sleep_until(stamp: float) -> None:
+    """Block the engine thread until ``stamp`` of the step clock."""
+    delay = stamp - StepClock.now()
+    if delay > 0:
+        time.sleep(delay)
 
 
 class AsyncEngine:
@@ -51,9 +60,12 @@ class AsyncEngine:
         if self.thread is not None and self.thread.is_alive():
             return
         self.running = True
-        # asked at a decode dispatch's landing, with the next one prepared:
-        # what has arrived goes into a ragged step first
+        # asked before a decode program joins the device's queue: what
+        # has arrived goes into a ragged step first. With the wait beside
+        # it the engine may ask a lead before a landing, and queue the
+        # next decode program there (LLMEngine._launch_ahead)
         self.engine.arrival_probe = lambda: not self.intake.empty()
+        self.engine.landing_wait = _sleep_until
         self.thread = threading.Thread(target=self._worker, daemon=True)
         self.thread.start()
 
@@ -62,7 +74,8 @@ class AsyncEngine:
         if self.thread is not None:
             self.thread.join(timeout=2.0)
             self.thread = None
-        self.engine.arrival_probe = None  # step() driven by hand again
+        # step() driven by hand again
+        self.engine.arrival_probe = self.engine.landing_wait = None
 
     # -- worker thread -------------------------------------------------------
     def _worker(self) -> None:

@@ -13,6 +13,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import time
+from collections import deque
 from typing import Optional, Sequence as Seq
 
 import jax
@@ -39,6 +40,24 @@ from production_stack_tpu.ops.ragged_paged_attention_pallas import (
 )
 from production_stack_tpu.parallel.mesh import build_mesh
 from production_stack_tpu.tenancy import split_shares
+
+
+# How long before the landing it foresees `LLMEngine._launch_ahead` asks
+# the probe and queues the next decode program. It covers what the host
+# takes from there to the program standing on the device (the thread's
+# wake-up from its sleep, ~0.4 ms in the mean beside the server's loop,
+# and the launch call, 0.6-0.7 ms) and how much later than the device
+# ends a program the host sees it landed once the fetch follows a launch
+# (0.5-0.8 ms), and no more: a request that reaches the intake inside the
+# lead waits the queued program out, and those are the requests that had
+# the shortest wait of all. Measured on one v5e in OLMoE's `decode-heavy`
+# cell (PERF.md section 6, PR 58): 1.0 ms closes a quarter of the ~2 ms a
+# prepared step stands between two decode programs when it launches at the
+# landing, 2.0 ms three quarters, 2.5 ms nearly all of it, at 16 % of the
+# arrivals behind a queued program and the median first token 2 % later.
+DECODE_AHEAD_LEAD_S = 0.002
+# the runs of one kind of decode dispatch `_landing_expected` looks back on
+DECODE_RUNS_KEPT = 8
 
 
 class GrammarBankFull(ValueError):
@@ -270,7 +289,10 @@ class LLMEngine:
         # the decode dispatch in flight (`_run_decode`): the next one, over
         # the same slots or fewer, takes its input tokens DEVICE-side (the
         # last sampled row, un-fetched), and is launched when this one's
-        # (K, B) samples have landed. Stop checks come after that launch:
+        # (K, B) samples have landed or a lead before (`_launch_ahead`:
+        # this is then the one queued, and the one it stands behind is
+        # fetched before the step returns). Stop checks come after that
+        # launch:
         # the surplus tokens a finished sequence generates
         # land only in its own uncommitted tail blocks (prefix hashes cover
         # full blocks of host-side token_ids), and any dispatch issued after
@@ -328,11 +350,38 @@ class LLMEngine:
         # of them, launched from inputs built and committed while the
         # dispatch before was still running (`_run_decode`)
         self.decode_prepared_launches = 0
-        # asked when a decode dispatch lands and the next stands prepared:
-        # has something arrived that the next step should take in first?
-        # The async worker sets it (its intake queue is not empty); None,
-        # as when step() is driven by hand: nothing is ever pending
+        # and of those, queued behind the dispatch before a lead ahead of
+        # its landing (`_launch_ahead`), with the time from such a launch
+        # to the landing it went ahead of, summed and at its largest
+        self.decode_ahead_launches = 0
+        self.decode_ahead_lead_seconds = 0.0
+        self.decode_ahead_lead_max_seconds = 0.0
+        # asked before a decode program joins the device's queue: has
+        # something arrived that the next step should take in first? At a
+        # ragged step's landing (`_step`), at a decode dispatch's landing
+        # with the next one prepared (`_arrival_first`), and a lead before
+        # such a landing (`_launch_ahead`). The async worker sets it (its
+        # intake queue is not empty); None, as when step() is driven by
+        # hand: nothing is ever pending
         self.arrival_probe = None
+        # sleeps until a stamp of the step clock, for `_launch_ahead`. The
+        # async worker sets it beside the probe; None, as when step() is
+        # driven by hand: a prepared step is launched at the landing
+        self.landing_wait = None
+        # (`like` of the last decode dispatch landed, how long the last
+        # few like it ran, each from its launch, or from the landing of
+        # the one it was queued behind, to its landing): when the next
+        # one like it will land (`_landing_expected`)
+        self._decode_runs = (None, ())
+        # the last landing at which the next decode program already stood
+        # queued: a request that reached the intake before it would have
+        # had its ragged step launched at that landing
+        self._queued_landing_t = 0.0
+        self.intake_requests = 0  # taken in from the intake queue
+        self.arrivals_behind_queued_decode = 0  # of them, before such a landing
+        # ragged steps at whose landing something had arrived, so that no
+        # decode program was launched after them (`_step`)
+        self.ragged_landing_arrivals = 0
         # goodput accounting + compile tracking (perf_accounting.py)
         self.perf = None
         if config.perf.enabled:
@@ -418,6 +467,9 @@ class LLMEngine:
         seq.arrival_after = self.clock.last_wait
         if enqueued is not None:
             seq.enqueue_time, seq.enqueue_step = enqueued
+            self.intake_requests += 1
+            self.arrivals_behind_queued_decode += (
+                seq.enqueue_time < self._queued_landing_t)
 
     def abort_request(self, request_id: str) -> bool:
         seq = self.scheduler.abort(request_id)
@@ -537,7 +589,19 @@ class LLMEngine:
         # step must land before decode inputs are built — and resolving
         # may FINISH sequences (max_tokens=1) the scheduler already put in
         # this step's decode batch
+        ragged_landed = self._pending_ragged is not None
         outputs = self._resolve_pending_ragged()
+        if (ragged_landed and self.arrival_probe is not None
+                and self.arrival_probe()):
+            # a request reached the intake while the ragged step ran. No
+            # decode program is queued in front of it: the worker takes
+            # it in and the next step is a ragged one with its prompt and
+            # every decode row (nothing schedule() handed out for this
+            # step has reached the sequences, as where `_arrival_first`
+            # drops a prepared step). A request waiting in the scheduler
+            # is no reason here: schedule() has just left it waiting
+            self.ragged_landing_arrivals += 1
+            return outputs
         if self._spec is not None:
             # drafts are proposed from whole token histories
             outputs.extend(self._resolve_pending_decode())
@@ -1174,16 +1238,21 @@ class LLMEngine:
         """One decode dispatch over ``decodes``, launched and not waited
         for: its tokens land in the step after. That step schedules,
         builds, packs and commits its own inputs while this dispatch
-        runs, waits for it, and launches at the landing from the
-        ``next_tok`` this one left on the device: only the launch stands
-        between two decode programs, and one is launched only once the
-        one before has landed, so that an arriving prompt waits for the
-        running program and no other (`_arrival_first` lets its ragged
-        step go first). A dispatch in flight that cannot feed this one is
-        resolved before the build, and the tokens come from the host, in
-        order: its results are wanted there (log-probabilities, the state
-        of a grammar), or a member was not in it, slot for slot.
-        ``outputs`` collects what the step resolves."""
+        runs, and launches from the ``next_tok`` this one leaves on the
+        device, a future of the program in flight: the device orders the
+        two. No launch joins the device's queue without the arrival probe
+        having been asked. Where the landing is foreseen and nothing
+        speaks against it the launch is made a lead before the landing
+        (`_launch_ahead`), so that the device goes from one program to
+        the next without the host; otherwise at the landing, once
+        `_arrival_first` has let no ragged step go first. At most one
+        program ever stands queued behind the one in flight: a launch
+        ahead is followed by the wait for the landing it went ahead of.
+        A dispatch in flight that cannot feed this one is resolved before
+        the build, and the tokens come from the host, in order: its
+        results are wanted there (log-probabilities, the state of a
+        grammar), or a member was not in it, slot for slot. ``outputs``
+        collects what the step resolves."""
         bs = self.config.cache.block_size
         use_logprobs = any(s.sampling.logprobs is not None for s in decodes)
         use_grammar = any(s.grammar_slot >= 0 for s in decodes)
@@ -1274,7 +1343,9 @@ class LLMEngine:
             **({"window": (self._window_tables, self._window_slot_mapping)}
                if self.window else {}),
         )
-        if pending is not None:
+        ahead = pending is not None and self._launch_ahead(
+            pending, len(decodes))
+        if pending is not None and not ahead:
             self._pending_decode = None
             self._fetch_decode(pending)  # the device's time ends here
             if self._arrival_first(pending, len(decodes)):
@@ -1282,14 +1353,19 @@ class LLMEngine:
                 # the sequences: the next step() schedules the ragged step
                 outputs.extend(self._finish_decode(pending))
                 return
+        if pending is not None:
             t_call = self.clock.now()  # its pack and commit are in the wait
         pend = {"rows": {s.slot: s for s in decodes},
-                "ctx": int(self._context_lens.sum())}
+                "ctx": int(self._context_lens.sum()), "wait_s": 0.0,
+                "like": (len(decodes), greedy_only, use_penalties, use_lora,
+                         use_controls)}
         pend["sampled"], pend["next_tok"], pend["counters"], *pend["lp"] = (
             launch(pending["next_tok"] if pending else None))
-        pend["launch_s"] = self.clock.enter("postprocess") - t_call
+        pend["start"] = self.clock.enter("postprocess")
+        pend["launch_s"] = pend["start"] - t_call
         self.decode_dispatches += 1
         self.decode_prepared_launches += pending is not None
+        self.decode_ahead_launches += ahead
         if self.recurrent is not None:
             self.recurrent.record_decode(K)
         if self.window_counters is not None:
@@ -1311,13 +1387,77 @@ class LLMEngine:
         for seq in decodes:
             seq.num_computed_tokens += K
         self._pending_decode = pend
+        if ahead:
+            # the landing it went ahead of, with `pend` queued behind: a
+            # row that stopped in `pending` has surplus tokens in `pend`,
+            # dropped at its landing as an abort's are; blocks freed here
+            # may be handed out at once, whatever writes them next is
+            # queued behind the program that still touches them
+            self._fetch_decode(pending)
+            pend["start"] = self._queued_landing_t = pending["landed"]
+            lead = pending["landed"] - t_call
+            self.decode_ahead_lead_seconds += lead
+            self.decode_ahead_lead_max_seconds = max(
+                self.decode_ahead_lead_max_seconds, lead)
         if pending is not None:
             outputs.extend(self._finish_decode(pending))
 
+    def _launch_ahead(self, pending, n_next: int) -> bool:
+        """Asked with ``pending`` in flight and the next decode step
+        prepared (``n_next`` rows, all of them ``pending``'s): may it be
+        queued behind ``pending`` now? Only where nothing `_arrival_first`
+        would need the landed tokens for can matter: no request waits in
+        the scheduler, or the next step has every row of the one in
+        flight and no token can stop one. Then the thread sleeps until a
+        lead before the landing `_landing_expected` foresees and asks
+        the probe: an empty intake lets the launch go. A request that
+        reaches the intake from then to the landing waits the queued
+        program out (`arrivals_behind_queued_decode`); what is queued
+        cannot be taken back. Every runner takes the not yet landed
+        ``next_tok`` (the mirrored one chains its followers' own copy),
+        so none is held to the landing here."""
+        if self.landing_wait is None:
+            return False
+        expected = self._landing_expected(pending)
+        if expected is None:
+            return False
+        rows = pending["rows"]
+        if self.scheduler.waiting and (
+                n_next < len(rows) or self._stop_tokens(rows)):
+            return False
+        t0 = self.clock.wait("decode")
+        self.landing_wait(expected - DECODE_AHEAD_LEAD_S)
+        pending["wait_s"] += self.clock.now() - t0
+        return self.arrival_probe is None or not self.arrival_probe()
+
+    def _landing_expected(self, pending) -> Optional[float]:
+        """The stamp at which ``pending`` should be seen to land: its
+        start (its launch or, queued ahead, the landing before it) and as
+        long as the shortest of the last few dispatches like it ran (the
+        same rows and variant, so the same program over the same work but
+        a token a row). The shortest, because a run can only read long: a
+        dispatch that reached the device late ran from the landing before
+        it through the device's idle time, and a landing foreseen from
+        such a run makes the next launch late in turn. None where the
+        dispatch landed last was of another kind: after a ragged step
+        that changed the rows the first landing is waited for."""
+        like, runs = self._decode_runs
+        if like != pending["like"]:
+            return None
+        return pending["start"] + min(runs)
+
+    def _stop_tokens(self, rows: dict) -> set:
+        """The tokens that would stop one of ``rows`` ({slot: sequence})."""
+        stops = {t for s in rows.values() for t in s.sampling.stop_token_ids}
+        if (self.tokenizer.eos_id is not None
+                and not all(s.sampling.ignore_eos for s in rows.values())):
+            stops.add(self.tokenizer.eos_id)
+        return stops
+
     def _arrival_first(self, pending, n_next: int) -> bool:
         """Asked at the landing of ``pending`` with the next decode step
-        prepared (``n_next`` rows, all of them ``pending``'s): should a
-        ragged step run before it? Where something has reached the intake
+        prepared (``n_next`` rows, all of them ``pending``'s) and not
+        queued ahead: should a ragged step run before it? Where something has reached the intake
         queue, or a request waits in the scheduler for a slot or for
         blocks and this landing frees some: a row gone from the next step
         (a completion bound reached, which the scheduler knew; an abort),
@@ -1330,12 +1470,8 @@ class LLMEngine:
         rows = pending["rows"]
         if n_next < len(rows):
             return True
-        stops = {t for s in rows.values() for t in s.sampling.stop_token_ids}
-        if (self.tokenizer.eos_id is not None
-                and not all(s.sampling.ignore_eos for s in rows.values())):
-            stops.add(self.tokenizer.eos_id)
         return bool(np.isin(pending["sampled"][:, list(rows)],
-                            list(stops)).any())
+                            list(self._stop_tokens(rows))).any())
 
     def _resolve_pending_decode(self) -> list[RequestOutput]:
         if self._pending_decode is None:
@@ -1350,10 +1486,17 @@ class LLMEngine:
         ``pending`` in place of the device arrays (sampled tokens (K, B),
         the log-probability arrays where the variant returns them, what
         an MoE model or a looped stack counted), with the seconds
-        blocked."""
-        (sampled, pending["counters"], *lp), pending["wait_s"] = self._fetch(
+        blocked, the stamp of the landing and, for `_landing_expected`,
+        how long the dispatch ran."""
+        (sampled, pending["counters"], *lp), wait_s = self._fetch(
             (pending["sampled"], pending["counters"], *pending["lp"]),
             "decode")
+        pending["wait_s"] += wait_s  # to what `_launch_ahead` slept of it
+        pending["landed"] = self.clock.now()
+        if self._decode_runs[0] != pending["like"]:
+            self._decode_runs = (pending["like"],
+                                 deque(maxlen=DECODE_RUNS_KEPT))
+        self._decode_runs[1].append(pending["landed"] - pending["start"])
         pending["sampled"] = np.asarray(sampled)
         pending["lp"] = [np.asarray(x) for x in lp]
 
@@ -1662,6 +1805,11 @@ class LLMEngine:
             "decode_attn_calls_total": self.decode_attn_calls,
             "decode_attn_slab_calls_total": self.decode_attn_slab_calls,
             "decode_prepared_launches_total": self.decode_prepared_launches,
+            "decode_ahead_launches_total": self.decode_ahead_launches,
+            "intake_requests_total": self.intake_requests,
+            "arrivals_behind_queued_decode_total":
+                self.arrivals_behind_queued_decode,
+            "ragged_landing_arrivals_total": self.ragged_landing_arrivals,
             "step_phases": self.clock.snapshot(),
             "slow_step_seconds": {k: dict(v) for k, v
                                   in self.clock.slow_seconds.items()},

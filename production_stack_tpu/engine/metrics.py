@@ -212,6 +212,31 @@ class EngineStatsCollector:
             s.get("decode_prepared_launches_total", 0),
         )
         yield counter(
+            "vllm:decode_ahead_launches",
+            "Of those, queued behind the dispatch before a lead ahead of "
+            "its landing, the intake asked and empty",
+            s.get("decode_ahead_launches_total", 0),
+        )
+        yield counter(
+            "vllm:engine_intake_requests",
+            "Requests the engine thread took in from its intake queue",
+            s.get("intake_requests_total", 0),
+        )
+        yield counter(
+            "vllm:arrivals_behind_queued_decode",
+            "Of them, those that reached the intake before a landing at "
+            "which the next decode program already stood queued: their "
+            "ragged step would have been launched at that landing",
+            s.get("arrivals_behind_queued_decode_total", 0),
+        )
+        yield counter(
+            "vllm:ragged_landing_arrivals",
+            "Ragged steps at whose landing a request had reached the "
+            "intake, so that no decode program was launched before the "
+            "ragged step that takes it in",
+            s.get("ragged_landing_arrivals_total", 0),
+        )
+        yield counter(
             "vllm:decode_attn_calls",
             "Attention calls of the decode dispatches (fused iterations x "
             "cache layers a dispatch)",

@@ -1882,12 +1882,31 @@ class EngineServer:
         request_path = {"slow_steps": self.engine.clock.slow_snapshot(),
                         "ttft_parts": self.ttft_parts.snapshot(),
                         "loop_lag": self.loop_lag.snapshot()}
-        # decode dispatches, and those launched from inputs prepared under
-        # the dispatch before (vllm:decode_dispatches_total,
-        # vllm:decode_prepared_launches_total)
+        # decode dispatches, those launched from inputs prepared under
+        # the dispatch before and, of those, the ones queued a lead ahead
+        # of its landing (vllm:decode_dispatches_total,
+        # vllm:decode_prepared_launches_total,
+        # vllm:decode_ahead_launches_total), with the lead as it came out:
+        # from such a launch to the landing it went ahead of. What the
+        # lead cost: of the requests taken in, those that reached the
+        # intake before a landing with the next program queued
+        # (vllm:arrivals_behind_queued_decode_total over
+        # vllm:engine_intake_requests_total); and the ragged landings at
+        # which an arrival kept a decode program from being launched
+        # (vllm:ragged_landing_arrivals_total)
+        eng = self.engine
         step_loop = {
-            "decode_dispatches": self.engine.decode_dispatches,
-            "decode_prepared_launches": self.engine.decode_prepared_launches}
+            "decode_dispatches": eng.decode_dispatches,
+            "decode_prepared_launches": eng.decode_prepared_launches,
+            "decode_ahead_launches": eng.decode_ahead_launches,
+            "decode_ahead_lead_seconds": {
+                "mean": (eng.decode_ahead_lead_seconds
+                         / max(1, eng.decode_ahead_launches)),
+                "max": eng.decode_ahead_lead_max_seconds},
+            "intake_requests": eng.intake_requests,
+            "arrivals_behind_queued_decode":
+                eng.arrivals_behind_queued_decode,
+            "ragged_landing_arrivals": eng.ragged_landing_arrivals}
         # the ragged attention kernel's walks, and those on its narrow
         # row block (vllm:ragged_attn_walks_total, ..._narrow_walks_total)
         walks = {"ragged_dispatches": self.engine.ragged_dispatches,

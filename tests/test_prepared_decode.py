@@ -1,11 +1,20 @@
 """A decode step is built while the one before it runs and launched when
-that one lands (engine/engine.py ``_run_decode``): the same tokens as the
-engine that builds each step after the landing of the one before, for a
-tiny model of every family the benchmark's decode cells serve; never a
-decode program launched ahead of an arrival's ragged step; the batches
-that need the last results on the host stay in order; what a request is
-charged is its own dispatch; and the counter that says how often the
-order engages, through the benchmark's reader.
+that one lands, or a lead before where the landing is foreseen
+(engine/engine.py ``_run_decode``, ``_launch_ahead``): the same tokens as
+the engine that builds each step after the landing of the one before,
+for a tiny model of every family the benchmark's decode cells serve;
+never a decode program launched without the arrival probe asked, at a
+decode program's landing, at a ragged step's and a lead before a
+landing, and never more than one queued behind the one in flight; the
+batches that need the last results on the host stay in order; what a
+request is charged is its own dispatch; and the counters that say how
+often the order engages and what it costs, through the benchmark's
+reader.
+
+"With the lead" here is the engine given a wait that returns at once and
+a landing foreseen in the past: the question whether the prepared step
+may be queued is then asked as soon as it is prepared, at every step,
+whatever the clock says.
 
 "In order" here is the same engine with its arrival probe held true: a
 prepared step is then dropped at every landing, and every decode dispatch
@@ -61,8 +70,14 @@ def mesh():
     return build_mesh(MeshConfig(), devices=jax.devices()[:1])
 
 
+def with_lead(eng) -> LLMEngine:
+    eng.landing_wait = lambda stamp: None
+    eng._landing_expected = lambda pending: 0.0
+    return eng
+
+
 def make_engine(mesh, family="dense_gqa", params=None, num_blocks=256,
-                slots=4, in_order=False, prefix_caching=True,
+                slots=4, in_order=False, prefix_caching=True, lead=False,
                 **sched) -> LLMEngine:
     model, block = FAMILIES[family]
     cfg = EngineConfig(
@@ -75,7 +90,7 @@ def make_engine(mesh, family="dense_gqa", params=None, num_blocks=256,
     eng = LLMEngine(cfg, mesh=mesh, params=params, num_blocks=num_blocks)
     if in_order:
         eng.arrival_probe = lambda: True
-    return eng
+    return with_lead(eng) if lead else eng
 
 
 def sp(max_tokens, **kw):
@@ -130,12 +145,13 @@ def _mixed_run(eng, stop_id):
     return drive(eng, script)
 
 
+@pytest.mark.parametrize("lead", [False, True], ids=["at_landing", "lead"])
 @pytest.mark.parametrize("family,multi_step", [
     *((f, 1) for f in FAMILIES), ("dense_gqa", 2), ("mamba_window_cross", 2)])
-def test_prepared_equals_in_order(mesh, family, multi_step):
+def test_prepared_equals_in_order(mesh, family, multi_step, lead):
     """One engine, the run twice: in order (the probe held true), then as
-    it serves. Without a prefix cache, so that the second run computes
-    what the first did."""
+    it serves, launching at the landing or with the lead. Without a
+    prefix cache, so that the second run computes what the first did."""
     eng = make_engine(mesh, family, num_blocks=32 // FAMILIES[family][1],
                       in_order=True, multi_step=multi_step,
                       prefix_caching=False)
@@ -152,9 +168,14 @@ def test_prepared_equals_in_order(mesh, family, multi_step):
     assert eng.decode_prepared_launches == 0 and preempted
     dispatches, eng.arrival_probe = eng.decode_dispatches, None
     del preempted[:]
+    if lead:
+        with_lead(eng)
     got = _mixed_run(eng, stop_id)
     assert eng.decode_prepared_launches > (
         eng.decode_dispatches - dispatches) // 2
+    # the stop token and the tight pool hold some launches to the landing
+    assert (0 < eng.decode_ahead_launches < eng.decode_prepared_launches
+            ) if lead else eng.decode_ahead_launches == 0
     for rid in ("bound", "stops", "arrives"):
         assert got[rid] == want[rid], rid
     assert len(got["bound"]) == 7 and len(got["arrives"]) == 12
@@ -170,9 +191,11 @@ def test_prepared_equals_in_order(mesh, family, multi_step):
 
 # -- (d) a prefix-cache hit after a stop with a row in flight ------------------
 
-@pytest.mark.parametrize("in_order", [False, True],
-                         ids=["prepared", "in_order"])
-def test_a_prefix_hit_after_a_stop_reads_what_was_committed(mesh, in_order):
+@pytest.mark.parametrize("in_order,lead", [
+    (False, False), (True, False), (False, True)],
+    ids=["prepared", "in_order", "lead"])
+def test_a_prefix_hit_after_a_stop_reads_what_was_committed(mesh, in_order,
+                                                            lead):
     """The stopped sequence's surplus row was in flight when its blocks
     were committed and released; a prompt that continues it hits those
     blocks and must read the rows the in-order engine wrote there."""
@@ -196,8 +219,10 @@ def test_a_prefix_hit_after_a_stop_reads_what_was_committed(mesh, in_order):
 
     want = two_rounds(make_engine(mesh, params=base.runner.params,
                                   in_order=True))
-    eng = make_engine(mesh, params=base.runner.params, in_order=in_order)
+    eng = make_engine(mesh, params=base.runner.params, in_order=in_order,
+                      lead=lead)
     got = two_rounds(eng)
+    assert (eng.decode_ahead_launches > 0) is lead
     assert got[:2] == want[:2] and got[0] == cut
     # a real hit; the prepared order's surplus row has written the stop
     # token's own row by the time anything reads it, so its blocks may
@@ -251,15 +276,160 @@ def test_an_arrival_at_the_landing_goes_before_the_prepared_step(mesh):
     assert len(toks["new"]) == 4 and eng.decode_prepared_launches > prepared
 
 
-def test_an_in_flight_program_is_never_followed_by_a_queued_one(mesh):
-    """A decode program is launched only from a thread that has fetched
-    the one before: at every launch nothing else is in flight."""
+def test_an_arrival_at_a_ragged_landing_goes_before_the_decode_step(mesh):
+    """The mirror at a ragged step's landing: what reached the intake
+    while the ragged program ran keeps the decode program after it from
+    being launched, and the next program is its own ragged step."""
     eng = make_engine(mesh)
-    in_flight = []
+    eng.add_request("r0", prompt_token_ids=prompt(8, 1), sampling=sp(30))
+    eng.step()
+    assert eng._pending_ragged is not None and eng._pending_decode is None
+    log = _dispatch_log(eng)
+    arrived = []
+
+    def probe():  # the request reaches the intake while the thread waits
+        arrived.append(eng._pending_ragged)
+        return True
+
+    eng.arrival_probe = probe
+    out = eng.step()
+    # the ragged step landed and was finished (asked after the landing);
+    # the decode step scheduled for after it was not launched
+    assert [o.request_id for o in out] == ["r0"] and arrived == [None]
+    assert log == [] and eng._pending_decode is None
+    assert (eng.ragged_landing_arrivals, eng.decode_dispatches) == (1, 0)
+    eng.arrival_probe = None
+    eng.add_request("new", prompt_token_ids=prompt(5, 2), sampling=sp(4))
+    eng.step()
+    assert log == ["ragged"]  # no decode program between the two
+    toks = drive(eng)
+    assert len(toks["new"]) == 4 and len(toks["r0"]) == 29
+    # a probe that stays true is asked once a ragged landing: the step
+    # after launches the decode program, as the in-order engine does
+    eng.arrival_probe = lambda: True
+    eng.add_request("r1", prompt_token_ids=prompt(7, 3), sampling=sp(3))
+    assert len(drive(eng)["r1"]) == 3 and eng.ragged_landing_arrivals == 2
+
+
+def _two_decoding(mesh):
+    """Two requests decoding and a decode dispatch in flight with nothing
+    queued behind it; the lead from here on."""
+    eng = make_engine(mesh)
+    for i in range(2):
+        eng.add_request(f"r{i}", prompt_token_ids=prompt(7 + i, i),
+                        sampling=sp(30))
+    for _ in range(5):
+        eng.step()
+    assert eng._pending_decode is not None and eng.decode_ahead_launches == 0
+    return with_lead(eng)
+
+
+def test_a_probe_true_at_the_ahead_check_leaves_nothing_queued(mesh):
+    eng = _two_decoding(mesh)
+    log = _dispatch_log(eng)
+    asked = []
+
+    def probe():
+        asked.append(len(log))
+        return True
+
+    eng.arrival_probe = probe
+    dispatches = eng.decode_dispatches
+    out = eng.step()
+    # asked a lead before the landing and again at it, nothing launched
+    # at either; the step in flight landed and was finished
+    assert asked == [0, 0] and log == [] and eng._pending_decode is None
+    assert sorted(o.request_id for o in out) == ["r0", "r1"]
+    assert (eng.decode_ahead_launches, eng.decode_dispatches) == (
+        0, dispatches)
+    eng.arrival_probe = None
+    eng.add_request("new", prompt_token_ids=prompt(5, 2), sampling=sp(4))
+    eng.step()
+    assert log == ["ragged"]  # the ragged step goes first
+    toks = drive(eng)
+    assert len(toks["new"]) == 4 and eng.decode_ahead_launches > 0
+    assert eng.arrivals_behind_queued_decode == 0
+
+
+def test_an_arrival_after_the_ahead_launch_is_counted_and_loses_no_token(
+        mesh):
+    want = drive(_two_decoding(mesh))
+    eng = _two_decoding(mesh)
+    log = _dispatch_log(eng)
+    checks = []
+
+    def probe():  # empty when asked; the request comes in right after
+        checks.append(eng.clock.now())
+        return False
+
+    eng.arrival_probe = probe
+    got: dict = {}
+    for o in eng.step():
+        got.setdefault(o.request_id, []).extend(o.new_token_ids)
+    # queued at the check, with the dispatch before still in flight; the
+    # landing was seen after it and found the next one queued
+    assert log == ["decode"] and len(checks) == 1
+    assert eng.decode_ahead_launches == 1
+    assert eng._queued_landing_t > checks[0]
+    eng.arrival_probe = None
+    eng.add_request("new", prompt_token_ids=prompt(5, 2), sampling=sp(4),
+                    enqueued=(checks[0], eng.clock.step_num))
+    assert (eng.intake_requests, eng.arrivals_behind_queued_decode) == (1, 1)
+    # one that reaches the intake after that landing waits for the
+    # program in flight, as it always did: not counted
+    eng.add_request("later", prompt_token_ids=prompt(5, 3), sampling=sp(4),
+                    enqueued=(eng.clock.now(), eng.clock.step_num))
+    assert (eng.intake_requests, eng.arrivals_behind_queued_decode) == (2, 1)
+    for o in eng.step():  # finishes the program that was queued
+        got.setdefault(o.request_id, []).extend(o.new_token_ids)
+    assert log == ["decode", "ragged"]
+    for rid, toks in drive(eng).items():
+        got.setdefault(rid, []).extend(toks)
+    assert len(got.pop("new")) == len(got.pop("later")) == 4
+    # the queued program's tokens reached their sequences, every one
+    assert got == want and all(len(t) == 26 for t in got.values())
+    # the lead as it came out, for /debug/perf: launch to landing seen
+    assert 0 < eng.decode_ahead_lead_max_seconds <= (
+        eng.decode_ahead_lead_seconds)
+
+
+@pytest.mark.parametrize("stoppable", [True, False],
+                         ids=["a_token_can_stop_a_row", "no_token_can"])
+def test_a_waiting_request_holds_the_launch_where_a_stop_could_free_a_slot(
+        mesh, stoppable):
+    """Two slots, three requests, the lead on. While one waits for a slot
+    and a landed token could stop a row (a stop token, or end-of-sequence
+    not ignored), the landing is waited for and `_arrival_first` reads
+    the tokens; where none can, the prepared step goes ahead."""
+    eng = make_engine(mesh, slots=2, lead=True)
+    kw = {"ignore_eos": False} if stoppable else {}
+    assert eng.tokenizer.eos_id is not None
+    eng.add_request("a", prompt_token_ids=prompt(7, 1), sampling=sp(12, **kw))
+    eng.add_request("b", prompt_token_ids=prompt(9, 2), sampling=sp(12))
+    eng.add_request("waits", prompt_token_ids=prompt(5, 3), sampling=sp(5))
+    prepared = 0
+    while eng.scheduler.num_waiting:
+        assert (eng.decode_ahead_launches == 0) is (
+            stoppable or eng.decode_prepared_launches == 0)
+        prepared = eng.decode_prepared_launches
+        eng.step()
+    assert prepared >= 6
+    toks = drive(eng)  # nobody waits any more: the lead engages
+    assert len(toks["waits"]) == 5 and eng.decode_ahead_launches > 0
+
+
+@pytest.mark.parametrize("probe", ["never", "always"])
+def test_at_most_one_program_is_queued_behind_the_one_in_flight(mesh, probe):
+    """A decode program is launched by a thread that has fetched the one
+    before, or a lead before that fetch with the probe asked and false:
+    never more than one stands queued behind the one in flight, and none
+    where the probe said true."""
+    eng = make_engine(mesh, lead=True)
+    in_flight, queued = [], []
     real_launch, real_fetch = eng.runner._decode_multi, eng._fetch
 
     def launch(*a, **kw):
-        assert not in_flight
+        queued.append(len(in_flight))
         in_flight.append(1)
         return real_launch(*a, **kw)
 
@@ -269,11 +439,19 @@ def test_an_in_flight_program_is_never_followed_by_a_queued_one(mesh):
         return real_fetch(result, kind)
 
     eng.runner._decode_multi, eng._fetch = launch, fetch
+    if probe == "always":
+        eng.arrival_probe = lambda: True
     for i in range(3):
         eng.add_request(f"r{i}", prompt_token_ids=prompt(6 + i, i),
                         sampling=sp(10 + 3 * i))
-    drive(eng)
-    assert eng.decode_prepared_launches > 10 and not in_flight
+    toks = drive(eng)
+    assert [len(toks[f"r{i}"]) for i in range(3)] == [10, 13, 16]
+    assert not in_flight and len(queued) == eng.decode_dispatches > 10
+    if probe == "always":
+        assert set(queued) == {0} and eng.decode_ahead_launches == 0
+    else:
+        assert set(queued) == {0, 1} and eng.decode_prepared_launches > 10
+        assert queued.count(1) == eng.decode_ahead_launches
 
 
 @pytest.mark.parametrize("how", ["bound", "stop_token"])
@@ -380,31 +558,66 @@ def test_chip_seconds_are_the_launch_plus_the_own_wait(mesh):
     assert 0 <= ragged < 10.0  # the one sequence is charged all of each
 
 
-# -- (f) the counter, and the benchmark's metric over it -----------------------
+# -- (f) the counters, and the benchmark's metrics over them -------------------
 
 def test_the_counters_are_exported_and_say_what_ran(mesh):
     from production_stack_tpu.engine.metrics import EngineStatsCollector
 
-    eng = make_engine(mesh)
-    eng.add_request("r", prompt_token_ids=prompt(6, 1), sampling=sp(9))
+    eng = make_engine(mesh, lead=True)
+    eng.arrival_probe = lambda: False
+    eng.add_request("r", prompt_token_ids=prompt(6, 1), sampling=sp(9),
+                    enqueued=(eng.clock.now(), 0))
+    eng.step()
+    eng.arrival_probe = lambda: True  # at the ragged step's landing
+    eng.step()
+    eng.arrival_probe = lambda: False
     drive(eng)
+    # a second comes in with its stamp before a landing that had the next
+    # program queued, a third with its stamp after every landing
+    eng.add_request("s", prompt_token_ids=prompt(6, 2), sampling=sp(2),
+                    enqueued=(eng._queued_landing_t - 1e-4, 0))
+    eng.add_request("t", prompt_token_ids=prompt(6, 3), sampling=sp(2),
+                    enqueued=(eng.clock.now(), 0))
     stats = eng.stats()
-    assert (stats["decode_dispatches_total"],
-            stats["decode_prepared_launches_total"]) == (8, 7)
+    want = {"decode_dispatches": 8, "decode_prepared_launches": 7,
+            "decode_ahead_launches": 7, "engine_intake_requests": 3,
+            "arrivals_behind_queued_decode": 1, "ragged_landing_arrivals": 1}
+    assert {k: stats[k.removeprefix("engine_") + "_total"]
+            for k in want} == want
     fams = {f.name: f for f in EngineStatsCollector(eng, "m").collect()}
-    assert fams["vllm:decode_prepared_launches"].samples[0].value == 7
-    assert fams["vllm:decode_dispatches"].samples[0].value == 8
-    # and the benchmark's reader takes them from the text a scrape gets
+    assert {k: fams["vllm:" + k].samples[0].value for k in want} == want
+    # and the benchmark's readers take them from the text a scrape gets
     from prometheus_client import CollectorRegistry, generate_latest
 
     registry = CollectorRegistry()
     registry.register(EngineStatsCollector(eng, "m"))
-    close = generate_latest(registry).decode()
-    zero = SCRAPE % {"dispatches": 0.0, "prepared": 0.0}
-    assert layers.read(NAME, _ctx(zero, close)) == 100.0 * 7 / 8
+    ctx = _ctx(SCRAPE % dict.fromkeys(COUNTERS, 0.0),
+               generate_latest(registry).decode())
+    assert [layers.read(name, ctx) for name in METRICS] == [
+        100.0 * 7 / 8, 100.0 * 7 / 8, 100.0 / 3]
 
 
-NAME = "decode_prepared_launch_pct"
+# metric -> (the counter over the counter, better, moves, the cells that
+# list it beside the eight that run decode-only steps)
+DECODE_CELLS = {
+    "qwen3-8b-l16.decode-heavy", "olmoe-1b-7b-l8.decode-heavy",
+    "ouro-2.6b.decode-pool-bound", "solar-open2-250b-ep16-l8.decode-heavy",
+    "phi-4-mini-flash-reasoning.long-decode",
+    "kimi-linear-48b-a3b-ep16.long-decode", "falcon-h1-34b-l6.decode-heavy",
+    "olmo-hybrid-7b-l16.decode-heavy"}
+METRICS = {
+    "decode_prepared_launch_pct": (
+        "prepared", "dispatches", "higher", "tpot_p50_ms", DECODE_CELLS),
+    "decode_ahead_launch_pct": (
+        "ahead", "dispatches", "higher", "tpot_p50_ms", DECODE_CELLS),
+    # the decode cells that report a time to first token
+    "arrival_behind_queued_decode_pct": (
+        "behind", "intake", "lower", "ttft_p50_ms", {
+            "qwen3-8b-l16.decode-heavy", "olmoe-1b-7b-l8.decode-heavy",
+            "ouro-2.6b.decode-pool-bound",
+            "solar-open2-250b-ep16-l8.decode-heavy"}),
+}
+COUNTERS = ("dispatches", "prepared", "ahead", "intake", "behind")
 SCRAPE = """\
 # HELP vllm:decode_dispatches_total decode_multi dispatches issued (decode-only steps)
 # TYPE vllm:decode_dispatches_total counter
@@ -412,6 +625,15 @@ vllm:decode_dispatches_total{model_name="m"} %(dispatches)s
 # HELP vllm:decode_prepared_launches_total Of them, launched at the landing of the dispatch before from inputs built and committed while it ran, its tokens left on the device
 # TYPE vllm:decode_prepared_launches_total counter
 vllm:decode_prepared_launches_total{model_name="m"} %(prepared)s
+# HELP vllm:decode_ahead_launches_total Of those, queued behind the dispatch before a lead ahead of its landing, the intake asked and empty
+# TYPE vllm:decode_ahead_launches_total counter
+vllm:decode_ahead_launches_total{model_name="m"} %(ahead)s
+# HELP vllm:engine_intake_requests_total Requests the engine thread took in from its intake queue
+# TYPE vllm:engine_intake_requests_total counter
+vllm:engine_intake_requests_total{model_name="m"} %(intake)s
+# HELP vllm:arrivals_behind_queued_decode_total Of them, those that reached the intake before a landing at which the next decode program already stood queued
+# TYPE vllm:arrivals_behind_queued_decode_total counter
+vllm:arrivals_behind_queued_decode_total{model_name="m"} %(behind)s
 """
 
 
@@ -421,34 +643,45 @@ def _ctx(open_text, close_text):
         manifest={})
 
 
+@pytest.mark.parametrize("name", METRICS)
 @pytest.mark.parametrize("open_, close, want", [
     ((10, 8), (110, 88), 80.0),
-    ((0, 0), (90, 0), 0.0),     # every step after a ragged one: 0, not None
+    ((0, 0), (90, 0), 0.0),     # none of them: 0, not None
     ((5, 5), (55, 55), 100.0),
 ])
-def test_the_metric_reads_the_share_from_recorded_metrics(open_, close, want):
-    texts = [SCRAPE % {"dispatches": float(d), "prepared": float(p)}
-             for d, p in (open_, close)]
-    value = layers.read(NAME, _ctx(*texts))
+def test_the_metric_reads_the_share_from_recorded_metrics(name, open_, close,
+                                                          want):
+    num, den = METRICS[name][:2]
+    texts = [SCRAPE % {**dict.fromkeys(COUNTERS, 3.0), den: float(d),
+                       num: float(n)} for d, n in (open_, close)]
+    value = layers.read(name, _ctx(*texts))
     assert value == want and value is not None
 
 
-def test_the_metric_reads_nothing_from_a_program_without_the_counter():
+@pytest.mark.parametrize("name", METRICS)
+def test_the_metric_reads_nothing_from_a_program_without_the_counter(name):
     old = 'vllm:decode_dispatches_total{model_name="m"} 5.0\n'
-    assert layers.read(NAME, _ctx(old, old)) is None
+    assert layers.read(name, _ctx(old, old)) is None
 
 
-def test_the_metrics_file_matches_its_benchmark_entry():
-    spec = layers.load_spec(NAME)
+@pytest.mark.parametrize("name", METRICS)
+def test_the_metrics_file_matches_its_benchmark_entry(name):
+    _, _, better, moves, cells = METRICS[name]
+    spec = layers.load_spec(name)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bm = json.load(f)
-    (entry,) = [m for m in bm["per_layer"] if m["name"] == NAME]
+    (entry,) = [m for m in bm["per_layer"] if m["name"] == name]
     assert (spec["layer"], spec["unit"], spec["source"], spec["reader"]) == (
         entry["layer"], entry["unit"], entry["source"], "prom_ratio")
-    assert entry["layer"] == "engine step loop"
-    assert entry["moves"] == "tpot_p50_ms" and entry["better"] == "higher"
-    decode_cells = {w["name"] for w in bm["workloads"]} - {
+    assert (entry["layer"], entry["unit"]) == ("engine step loop", "%")
+    assert (entry["moves"], entry["better"]) == (moves, better)
+    assert set(entry["workloads"]) == cells
+    # every cell that runs decode-only steps, and no other
+    assert DECODE_CELLS == {w["name"] for w in bm["workloads"]} - {
         "qwen3-8b-l16.prefill-heavy", "solar-open2-250b-ep16-l8.prefill-heavy",
         "openpangu-ultra-moe-718b-ep16-l5.long-prompt"}
-    assert set(entry["workloads"]) == decode_cells
-    assert not os.path.exists(os.path.join(layers.DIR, NAME + ".py"))
+    reported = {w for m in bm["end_to_end"] if m["name"] == moves
+                for w in m["workloads"]}
+    assert cells <= reported
+    # data alone: the reader is the one the benchmark has
+    assert not os.path.exists(os.path.join(layers.DIR, name + ".py"))

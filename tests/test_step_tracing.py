@@ -123,16 +123,23 @@ def test_phases_sum_to_the_worker_wall_time_and_each_step_has_one_kind(order):
     outs, wall, step_count = asyncio.run(fn())
     assert outs == PARENT_TOKENS
     # a decode step waits for the program before it with its own inputs
-    # committed, and launches straight after the landing: between the
-    # wait's end and the launch the thread does nothing but decide
-    # (`postprocess`: the probe, one compare)
+    # committed, and launches a lead before the landing it foresees (the
+    # worker gave the engine its wait: `launch` falls inside the wait) or
+    # straight after the landing: between the wait's end and the launch
+    # the thread does nothing but decide (`postprocess`: the probe, one
+    # compare)
+    phases = [p for i, p in enumerate(phases) if p != phases[i - 1] or not i]
     waits = [i for i, p in enumerate(phases) if p == "wait"]
     prepared = [i for i in waits if phases[i - 1] == "commit"]
     assert len(prepared) >= eng.decode_prepared_launches
-    launched = [i for i in prepared
-                if phases[i + 1:i + 3] == ["postprocess", "launch"]]
+    ahead = [i for i in prepared
+             if phases[i + 1:i + 4] == ["launch", "postprocess", "wait"]]
+    assert len(ahead) == eng.decode_ahead_launches
+    launched = ahead + [i for i in prepared
+                        if phases[i + 1:i + 3] == ["postprocess", "launch"]]
     assert len(launched) == eng.decode_prepared_launches
     assert (len(launched) >= 16) == (order == "prepared")
+    assert bool(ahead) == (order == "prepared")
     if order == "in_order":
         assert not launched and len(prepared) >= 10
     # the worker's whole life is in some phase: host + wait + idle = wall
