@@ -40,7 +40,7 @@ class ModelConfig:
     name: str = "tiny-llama"
     # "llama" | "mixtral" | "olmoe" | "gemma" | "gemma2" | "phi3" | "ouro"
     # | "solar_open2" | "pangu_ultra_moe" | "phi4flash" | "kimi_linear"
-    # | "falcon_h1" | "olmo_hybrid"
+    # | "falcon_h1" | "olmo_hybrid" | "afmoe"
     # — Mistral and Qwen run as "llama" (their deltas are knobs:
     # sliding_window, qkv_bias, qk_norm); "phi3" differs only in its fused
     # HF weight layout, "mixtral" and "olmoe" in their HF tensor names
@@ -56,7 +56,11 @@ class ModelConfig:
     # and grouped-query attention side by side in every layer (ssd_heads
     # > 0) and the family's multipliers, "olmo_hybrid" in Gated DeltaNet
     # layers named layer by layer (gdn_layers) beside full attention, and
-    # norms AFTER each sublayer alone (norms "post")
+    # norms AFTER each sublayer alone (norms "post"), "afmoe" in attention
+    # layers that differ by kind (window_layers: a window that binds and
+    # rope on those, all rows and no rope on the others), every one gated,
+    # with norms "both", an embedding factor, a leading dense layer and
+    # the sigmoid-routed sparse block
     architecture: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -126,6 +130,16 @@ class ModelConfig:
     gdn_value_dim: int = 0   # d_v: v and the output of a head
     gdn_conv: int = 4        # width of the causal depthwise convolution
     gdn_neg_eigval: bool = False  # beta in (0, 2) instead of (0, 1)
+    # a stack of grouped-query attention layers that differ in what a row
+    # sees, named one by one ("afmoe"): the 0-based layers that are "swa"
+    # (row t sees rows t - sliding_window + 1 .. t, from the window pool:
+    # ``window_binds``), every other layer "full" (all rows, the other
+    # pool), walked in periods of attn_period. rope_kinds says which kinds
+    # rotate q and k: both unless the family says otherwise (afmoe: the
+    # window layers alone; a full layer applies no positional encoding).
+    # () = no such stack
+    window_layers: tuple = ()
+    rope_kinds: tuple = ("swa", "full")
     # the decoder-hybrid-decoder stack (SambaY, "phi4flash"): layer l is a
     # Mamba-1 state-space layer where l % mamba_period == 0 and attention
     # elsewhere, with a window of sliding_window rows, up to layer
@@ -325,8 +339,12 @@ class ModelConfig:
         (window attention), "full", "gmu" and "cross" (mamba_period);
         "parallel" (ssd_heads: a state-space mixer AND attention, counted
         among the recurrent layers and among the attention layers); "gdn" /
-        "gqa" (gdn_layers)."""
+        "gqa" (gdn_layers); "swa" / "full" (window_layers: grouped-query
+        attention within the window or over all rows)."""
         n = self.num_layers
+        if self.window_layers:
+            return tuple("swa" if l in self.window_layers else "full"
+                         for l in range(n))
         if self.ssd_heads:
             return ("parallel",) * n
         if self.gdn_layers:
@@ -372,6 +390,13 @@ class ModelConfig:
         return self.num_recurrent_layers > 0
 
     @property
+    def patterned(self) -> bool:
+        """Whether the layers differ by kind and the stack is walked by
+        periods (models/llama.py ``_forward_hybrid``), its mixers stacked
+        by kind beside what every layer has."""
+        return self.has_recurrent_state or bool(self.window_layers)
+
+    @property
     def num_recurrent_layers(self) -> int:
         return self.count_layers("kda", "mamba", "parallel", "gdn")
 
@@ -379,7 +404,8 @@ class ModelConfig:
     def window_binds(self) -> bool:
         """Whether the window layers are served as windows (past
         ``sliding_window`` positions), from a block pool of their own."""
-        return self.mamba_period > 0 and self.sliding_window > 0
+        return self.sliding_window > 0 and (
+            self.mamba_period > 0 or bool(self.window_layers))
 
     @property
     def mamba_inner(self) -> int:
@@ -546,6 +572,8 @@ class ModelConfig:
             return ModelConfig._falcon_h1_from_hf(cfg, name)
         elif cfg.get("model_type") == "olmo_hybrid":
             return ModelConfig._olmo_hybrid_from_hf(cfg, name)
+        elif cfg.get("model_type") == "afmoe":
+            return ModelConfig._afmoe_from_hf(cfg, name)
         elif any("Phi3" in a for a in archs):
             # only the standard Phi-3 maps onto the fused-Llama layout;
             # Phi-3-small (query_key_value naming, gegelu, blocksparse)
@@ -1129,6 +1157,105 @@ class ModelConfig:
             gdn_value_dim=int(cfg["linear_value_head_dim"]),
             gdn_conv=int(cfg.get("linear_conv_kernel_dim", 4)),
             gdn_neg_eigval=bool(cfg.get("linear_allow_neg_eigval", False)),
+        )
+
+    @staticmethod
+    def _afmoe_from_hf(cfg: dict, name: str = "") -> "ModelConfig":
+        """``model_type: afmoe`` (Arcee Trinity): grouped-query attention
+        in every layer, named layer by layer in ``layer_types``: a
+        ``sliding_attention`` layer rotates q and k and sees
+        ``sliding_window`` rows, a ``full_attention`` layer (the last of
+        every ``global_attn_every_n_layers``) rotates nothing and sees all
+        rows; per-head RMSNorm on q and k, a sigmoid gate on the attention
+        output, a norm before AND after each sublayer, the embedding times
+        sqrt(hidden) (``mup_enabled``), ``num_dense_layers`` leading dense
+        layers and then sparse blocks with sigmoid routing, a selection
+        bias and a shared expert. ``n_routed_experts_held`` /
+        ``routed_expert_offset`` state the chip's share of the routed
+        experts, as for solar_open2. What is not computed is refused by
+        name."""
+        what = "afmoe"
+        layers = int(cfg["num_hidden_layers"])
+        types = list(cfg.get("layer_types") or ())
+        period = int(cfg.get("global_attn_every_n_layers", 4))
+        want = ["full_attention" if l % period == period - 1
+                else "sliding_attention" for l in range(layers)]
+        dense = int(cfg.get("num_dense_layers", 0))
+        window = int(cfg.get("sliding_window") or 0)
+        other = sorted(set(types) - set(want))
+        refused = [
+            why for bad, why in (
+                (any(int(cfg.get(k, 1) or 1) > 1 for k in (
+                    "n_group", "num_expert_groups", "topk_group",
+                    "num_limited_groups")),
+                 f"n_group={cfg.get('n_group')} / num_expert_groups="
+                 f"{cfg.get('num_expert_groups')} (no group-limited "
+                 "routing)"),
+                (cfg.get("rope_scaling") is not None,
+                 f"rope_scaling={cfg.get('rope_scaling')!r} (plain rotary "
+                 "frequencies from rope_theta only)"),
+                (bool(other),
+                 f"layer_types entries {other} (only sliding_attention and "
+                 "full_attention layers)"),
+                (period < 2 or types != want,
+                 f"a layer_types that is not num_hidden_layers={layers} "
+                 "entries of sliding_attention layers closed by one "
+                 f"full_attention layer in every global_attn_every_n_layers"
+                 f"={period}"),
+                (window <= 0, f"sliding_window={cfg.get('sliding_window')!r} "
+                 "(the window layers need a window)"),
+                (cfg.get("score_func", "sigmoid") != "sigmoid",
+                 f"score_func={cfg.get('score_func')!r} (sigmoid scores "
+                 "with a selection bias)"),
+                (not 0 <= dense < layers,
+                 f"num_dense_layers={dense} leaves no expert layer of "
+                 f"num_hidden_layers={layers}"),
+                (bool(cfg.get("attention_bias")),
+                 "attention_bias: true (no projection has a bias)"),
+                (bool(cfg.get("tie_word_embeddings")),
+                 "tie_word_embeddings: true (only an untied head)"),
+                ((cfg.get("hidden_act") or "silu") != "silu",
+                 f"hidden_act={cfg.get('hidden_act')!r} (only silu)"),
+            ) if bad]
+        if refused:
+            raise ValueError(f"{what} is not supported with: "
+                             + "; ".join(refused))
+        experts, held, offset = _expert_share(what, cfg, "num_experts")
+        hidden, heads = int(cfg["hidden_size"]), cfg["num_attention_heads"]
+        return ModelConfig(
+            name=name or cfg.get("_name_or_path", "hf-model"),
+            architecture="afmoe",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=hidden,
+            intermediate_size=cfg["moe_intermediate_size"],
+            num_layers=layers,
+            num_heads=heads,
+            num_kv_heads=cfg.get("num_key_value_heads", heads),
+            head_dim=cfg.get("head_dim") or hidden // heads,
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_model_len=cfg.get("max_position_embeddings", 4096),
+            num_experts=experts,
+            num_experts_per_tok=cfg.get("num_experts_per_tok", 4),
+            norm_topk_prob=bool(cfg.get("route_norm", True)),
+            experts_held=held if held < experts else 0,
+            expert_offset=offset,
+            moe_scoring="sigmoid",
+            routed_scaling=float(cfg.get("route_scale", 1.0)),
+            shared_expert_size=(int(cfg.get("num_shared_experts", 0))
+                                * cfg["moe_intermediate_size"]),
+            attn_period=period,
+            window_layers=tuple(l for l, t in enumerate(types)
+                                if t == "sliding_attention"),
+            rope_kinds=("swa",),
+            sliding_window=window,
+            attn_gate=True,
+            qk_norm=True,
+            qk_norm_kind="head",
+            norms="both",
+            embed_scale=bool(cfg.get("mup_enabled", False)),
+            dense_layers=dense,
+            dense_intermediate_size=cfg["intermediate_size"] if dense else 0,
         )
 
     @staticmethod

@@ -1080,7 +1080,8 @@ class LLMEngine:
                     windows, count_windows(
                         self._r_cu, self._context_lens, W, group,
                         self.config.cache.block_size, q_tile=tile,
-                        window=self.window)[0])
+                        window=self.window)[0],
+                    np.diff(self._r_cu), self._context_lens)
             self.ragged_attn_windows += windows
             self.ragged_attn_interior_windows += interior
 
@@ -1780,6 +1781,7 @@ class LLMEngine:
             ),
             "aborted_seqs_total": self.aborted_seqs,
             "spliced_seqs_total": self.spliced_seqs,
+            "num_preemptions_total": self.scheduler.preemptions,
             # per-step occupancy / KV-pool utilization (observability layer)
             "batch_occupancy": (self.scheduler.num_running
                                 / max(1, self.config.scheduler.max_num_seqs)),
@@ -1788,8 +1790,11 @@ class LLMEngine:
             "kv_pool_bytes": (self.runner.num_blocks
                               * self.config.cache.block_size
                               * self.config.model.kv_bytes_per_token),
-            **(self.window_counters.snapshot(self.scheduler.window_allocator)
+            **(self.window_counters.snapshot(self.scheduler)
                if self.window_counters is not None else {}),
+            **({"prefix_lookups_bypassed_total":
+                self.scheduler.allocator.lookups_bypassed}
+               if self.scheduler.bypass_prefix else {}),
             # unified ragged path: dispatch counts + live tokens over the
             # token budget (engine/metrics.py turns these into
             # vllm:ragged_* series)
@@ -1935,7 +1940,7 @@ class LLMEngine:
         self.scheduler.allocator = PrefixCachingBlockAllocator(
             self.runner.num_blocks, self.config.cache.block_size,
             self.config.cache.enable_prefix_caching,
-            bypass_prefix=self.scheduler.recurrent_state,
+            bypass_prefix=self.scheduler.bypass_prefix,
         )
         self.scheduler.allocator.lookups_bypassed = bypassed
         if self.window:

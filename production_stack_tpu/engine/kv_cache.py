@@ -27,7 +27,9 @@ TPU-first design:
   second pool, ``"win"``, of blocks of the same size from an allocator of
   its own (``window_pool_blocks``): a sequence holds a window block only
   while a row to come can still see it (engine/scheduler.py), and ``"kv"``
-  holds the layers whose rows live for the whole context. A slot's state
+  holds the layers whose rows live for the whole context; a stack of
+  attention layers alone whose window binds has the dict ``{"kv", "win"}``.
+  A slot's state
   belongs to the
   sequence that holds the slot and is taken as zeros by a span that
   starts at position 0, so nothing on the host resets it. Block tables
@@ -103,17 +105,25 @@ def init_kv_cache(
     shape = model.kv_pool_shape(n, cache.block_size)
     dt = model.jax_dtype
 
-    def _zeros():
-        return jnp.zeros(shape, dt)
+    def _pool(shape):
+        def _zeros():
+            return jnp.zeros(shape, dt)
 
-    with jax.set_mesh(mesh):
         # stackcheck: disable=jit-cache-hygiene — one-shot pool
         # allocation at engine startup: the wrapper exists only to apply
         # out_shardings and is called exactly once, so there is no trace
         # cache to lose
-        pool = jax.jit(_zeros, out_shardings=sharding)()
+        return jax.jit(_zeros, out_shardings=sharding)()
+
+    with jax.set_mesh(mesh):
+        pool = _pool(shape)
         if not model.has_recurrent_state:
-            return pool
+            if not model.window_binds:
+                return pool
+            # attention layers of two kinds and no per-slot state: the two
+            # pools, sharded alike
+            return {"kv": pool, "win": _pool(model.kv_pool_shape(
+                window_blocks, cache.block_size, window=True))}
         if slots <= 0:
             raise ValueError("a recurrent-state model needs its slot count")
         if model.mamba_period:
@@ -176,6 +186,45 @@ def window_pool_blocks(model: ModelConfig, block_size: int, slots: int,
     per_slot = -(-model.sliding_window // block_size) + 3
     return slots * per_slot + -(-(token_budget + model.sliding_window)
                                 // block_size)
+
+
+def refuse_if_window_pool_starves(model: ModelConfig, cache: CacheConfig,
+                                  sched, free_bytes: int) -> None:
+    """``free_bytes``: what is left for the other pool once the window
+    layers' pool (``window_pool_blocks``: sized by the rule that no
+    sequence ever waits for a window block) is taken. Where that holds
+    under one sequence of ``max_model_len`` tokens, the engine would start
+    and preempt or starve every long request: refused by name, with both
+    pools' bytes and the slot count that would fit. Nothing where no
+    window binds."""
+    if not model.window_binds:
+        return
+    bs, slots = cache.block_size, sched.max_num_seqs
+    budget = sched.max_num_batched_tokens
+
+    def window_bytes(s: int) -> int:
+        return (window_pool_blocks(model, bs, s, budget) * bs
+                * model.window_kv_bytes_per_token)
+
+    # one sequence's blocks of the other pool, before hbm_utilization
+    need = int(-(-model.max_model_len // bs) * bs * model.kv_bytes_per_token
+               / cache.hbm_utilization)
+    if free_bytes >= need:
+        return
+    before = free_bytes + window_bytes(slots)  # what both pools share
+    fit = max((s for s in range(1, slots) if before - window_bytes(s) >= need),
+              default=0)
+    raise ValueError(
+        f"{model.name}: at --max-num-seqs {slots} the window layers' pool "
+        f"takes {window_bytes(slots) / 1e9:.2f} GB ({slots} slots x "
+        f"({model.sliding_window} / {bs} + 3) blocks + a step's "
+        f"{budget} + {model.sliding_window} rows, "
+        f"{model.window_kv_bytes_per_token} B a row: no sequence ever waits "
+        "for a window block) and leaves the other layers' pool "
+        f"{max(free_bytes, 0) / 1e9:.2f} GB, under the {need / 1e9:.2f} GB "
+        f"of one sequence of --max-model-len {model.max_model_len}; "
+        + (f"--max-num-seqs {fit} would fit" if fit else
+           "no slot count fits: lower --max-model-len"))
 
 
 def resolve_num_blocks(

@@ -119,6 +119,12 @@ class EngineStatsCollector:
             s.get("aborted_seqs_total", 0),
         )
         yield counter(
+            "vllm:num_preemptions",
+            "Sequences preempted because a block pool ran dry: their "
+            "blocks freed, recomputed from position 0 on re-admission",
+            s.get("num_preemptions_total", 0),
+        )
+        yield counter(
             "vllm:spliced_seqs",
             "Pushed P→D transfers attached as decode-ready sequences "
             "(disaggregated serving: each one is a skipped re-prefill)",
@@ -430,6 +436,45 @@ class EngineStatsCollector:
                 "Free blocks of the window layers' pool",
                 s["window_kv_blocks_free"],
             )
+            yield counter(
+                "vllm:window_kv_blocks_released",
+                "Window blocks that live sequences gave back because no "
+                "row to come can see them",
+                s["window_kv_blocks_released_total"],
+            )
+            yield counter(
+                "vllm:window_kv_block_waits",
+                "Times a sequence found the window layers' pool dry (0 by "
+                "the pool's size rule, kv_cache.window_pool_blocks)",
+                s["window_kv_block_waits_total"],
+            )
+            # the same by step program, its name in the series' name (the
+            # benchmark's reader sums a family over its labels): what each
+            # program's attention calls stream and have to
+            for program, by in s["window_attn_by_program"].items():
+                for series, key, doc in (
+                    ("window_attn_context_tokens", "context_tokens",
+                     "vllm:window_attn_context_tokens_total of the "
+                     f"{program} program alone"),
+                    ("window_attn_read_tokens", "read_tokens",
+                     "vllm:window_attn_read_tokens_total of the "
+                     f"{program} program alone"),
+                    ("full_attn_read_tokens", "full_read_tokens",
+                     "Tokens of context the attention calls that see every "
+                     f"row stream in the {program} program (the full "
+                     "layers'; a cross-attention layer's), each once a "
+                     "call"),
+                    ("attn_rows_needed", "rows_needed",
+                     f"Rows the {program} program's attention calls have "
+                     "to read whatever implements them: a window layer's "
+                     "from its first query's floor to its reach, a full "
+                     "layer's its reach, each once a call"),
+                    ("attn_pairs_needed", "pairs_needed",
+                     f"(query, key) pairs the {program} program's "
+                     "attention calls have to score: inside the window or "
+                     "the causal reach, all attention layers"),
+                ):
+                    yield counter(f"vllm:{program}_{series}", doc, by[key])
             yield gauge(
                 "vllm:kv_bytes_per_token",
                 "Bytes one token of context holds in the paged pool for "
@@ -554,10 +599,12 @@ class EngineStatsCollector:
                 "tails hold on the device",
                 s["recurrent_state_bytes"],
             )
+        if "prefix_lookups_bypassed_total" in s:
             yield counter(
                 "vllm:prefix_lookups_bypassed",
                 "Prefix-cache lookups answered as misses because the "
-                "model keeps recurrent state no cached block can restore",
+                "model keeps recurrent state no cached block can restore, "
+                "or window layers that hold the last rows alone",
                 s["prefix_lookups_bypassed_total"],
             )
         # looped stacks (engine/tracing.py LoopCounters): exported by

@@ -262,6 +262,8 @@ class ModelRunner:
             )
         if self.cfg.has_recurrent_state:
             self._refuse_for_recurrent_state(config, mesh)
+        elif self.cfg.window_binds:
+            self._refuse_for_two_pools(config, mesh)
         self.rules = rules_for_model(self.cfg, mesh)
         self.model = get_model(self.cfg)
         # a looped stack's step programs return the passes they made, one
@@ -271,6 +273,11 @@ class ModelRunner:
         # what `step.launch` says of such a stack in a trace
         self._launch_attrs = ({"passes": self.cfg.loop_passes}
                               if self.loop is not None else {})
+        if self.cfg.window_layers:  # attention layers of two kinds: how
+            # many of each the step's program walks
+            self._launch_attrs.update(
+                {"layers_" + kind: self.cfg.count_layers(kind)
+                 for kind in ("swa", "full")})
         # a tree handed in is served as it is (the caller keeps it); one
         # loaded here is laid out as the step programs read it
         if params is None:
@@ -442,6 +449,45 @@ class ModelRunner:
                 "supported with it: " + "; ".join(refused))
 
     @staticmethod
+    def _refuse_for_two_pools(config: EngineConfig, mesh: Mesh,
+                              lora: bool = False) -> None:
+        """A stack of attention layers whose window binds keeps blocks of
+        two kinds, each pool with an allocator and a block table of its
+        own, that only ONE chip's ragged and decode step programs read and
+        write. Whatever would frame, move, draft over or guess at one pool
+        as if it were the cache is refused here by name, not served
+        wrongly (``lora``: an adapter is being loaded)."""
+        name = config.model.name
+        refused = [
+            what for bad, what in (
+                (mesh.devices.size > 1,
+                 f"a mesh of {mesh.devices.size} devices (tensor "
+                 "parallelism): no test holds two pools sharded"),
+                (config.model.quant is not None,
+                 f"quant={config.model.quant}: the gate's projection and "
+                 "the patterned stack's mixers are not quantized"),
+                (lora,
+                 "LoRA adapters: the stack walker of a patterned stack "
+                 "applies none"),
+                (config.scheduler.spec_ngram_k > 0,
+                 "n-gram speculative decoding (spec_ngram_k > 0): a draft's "
+                 "rows reserve blocks of one pool alone"),
+                (config.role != "unified",
+                 f"role={config.role}: a P->D transfer frames one pool's "
+                 "blocks, and the window layers hold the last rows alone"),
+                (bool(config.cache.host_offload_blocks
+                      or config.cache.kv_host_cache_bytes
+                      or config.cache.remote_kv_url),
+                 "a host or remote KV tier: a block fetched back has no "
+                 "window rows beside it"),
+            ) if bad]
+        if refused:
+            raise ValueError(
+                f"{name} keeps blocks of two kinds (a window pool beside "
+                "the full layers' pool); not supported with it: "
+                + "; ".join(refused))
+
+    @staticmethod
     def _refuse_for_latent_cache(config: EngineConfig, mesh: Mesh,
                                  lora: bool = False) -> None:
         """A latent-attention (MLA) model keeps one row a token that every
@@ -597,6 +643,8 @@ class ModelRunner:
                    * self.cfg.window_kv_bytes_per_token))
         n_dev = max(self.mesh.devices.size, 1)
         total_free = free * n_dev  # cache is sharded over the mesh
+        kvmod.refuse_if_window_pool_starves(
+            self.cfg, self.config.cache, self.config.scheduler, total_free)
         return max(int(total_free * self.config.cache.hbm_utilization) // per_block, 16)
 
     # -- attention backends -------------------------------------------------
@@ -1428,6 +1476,8 @@ class ModelRunner:
         if self.cfg.has_recurrent_state:
             self._refuse_for_recurrent_state(self.config, self.mesh,
                                              lora=True)
+        elif self.cfg.window_binds:
+            self._refuse_for_two_pools(self.config, self.mesh, lora=True)
         N = self.config.max_loras
         dt = self.cfg.jax_dtype
         if self.lora_bank is None:

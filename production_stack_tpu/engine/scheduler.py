@@ -70,9 +70,13 @@ class Scheduler:
         # a model with recurrent layers: prefix lookups are served as
         # misses (see PrefixCachingBlockAllocator.bypass_prefix)
         self.recurrent_state = recurrent_state
+        # ... or window layers that hold the last ``window`` rows alone: a
+        # hit would be exact only where they still hold the rows below it,
+        # so every lookup is a miss there too
+        self.bypass_prefix = recurrent_state or bool(window)
         self.allocator = PrefixCachingBlockAllocator(
             num_blocks, cache.block_size, cache.enable_prefix_caching,
-            bypass_prefix=recurrent_state,
+            bypass_prefix=self.bypass_prefix,
         )
         # a model whose window binds: its window layers' blocks come from a
         # pool of their own, taken as a sequence's rows reach them and
@@ -82,6 +86,13 @@ class Scheduler:
             PrefixCachingBlockAllocator(window_blocks, cache.block_size,
                                         bypass_prefix=True)
             if window else None)
+        # window blocks live sequences gave back (``_trim_window``), and
+        # the times a sequence found the window pool dry (the pool's size
+        # rules it out: kv_cache.window_pool_blocks)
+        self.window_blocks_released = 0
+        self.window_block_waits = 0
+        # sequences sent back to the queue to be recomputed (a pool dry)
+        self.preemptions = 0
         self.waiting: collections.deque[Sequence] = collections.deque()
         self.seqs: dict[str, Sequence] = {}  # admitted, not finished
         self.free_slots = list(range(sched.max_num_seqs - 1, -1, -1))
@@ -226,6 +237,7 @@ class Scheduler:
         seq.status = status
 
     def _preempt(self, victim: Sequence) -> None:
+        self.preemptions += 1
         self._release(victim)
         victim.status = SequenceStatus.PREEMPTED
         victim.num_computed_tokens = 0
@@ -243,6 +255,7 @@ class Scheduler:
         if dead > seq.window_released:
             self.window_allocator.free_blocks(
                 seq.window_block_ids[seq.window_released:dead])
+            self.window_blocks_released += dead - seq.window_released
             seq.window_released = dead
 
     def _extend(self, seq: Sequence, target: int) -> bool:
@@ -257,6 +270,8 @@ class Scheduler:
             while len(ids) * bs < target:
                 bid = allocator.append_block()
                 if bid is None:
+                    self.window_block_waits += (
+                        allocator is self.window_allocator)
                     return False
                 ids.append(bid)
         return True
@@ -353,6 +368,7 @@ class Scheduler:
                     self.window_allocator.num_free_blocks * bs
                     < min(len(seq.token_ids),
                           self.config.max_num_batched_tokens)):
+                self.window_block_waits += 1
                 break  # no window blocks for its first chunk
             got = self.allocator.allocate_sequence(seq.token_ids)
             if got is None:

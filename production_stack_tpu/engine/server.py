@@ -1980,7 +1980,7 @@ class EngineServer:
             # without a window, the cross-attention layers' calls
             model = self.engine.config.model
             snap["window_pool"] = {
-                **window.snapshot(self.engine.scheduler.window_allocator),
+                **window.snapshot(self.engine.scheduler),
                 "sliding_window": model.sliding_window,
                 "window_kv_bytes_per_token": model.window_kv_bytes_per_token,
                 "window_pool_bytes": (

@@ -803,7 +803,18 @@ class WindowCounters:
     a call: a decode step's call streams a live slot's blocks from the one
     that holds its floor (``ctx - window``) to its last, a ragged step's
     walks the context windows ``count_windows`` counts, with the floor and
-    without. Both already times the window layers."""
+    without. Both already times the window layers, and both kept by
+    program (``PROGRAMS``) beside their totals; the calls of the layers
+    that see every row (the full layer; a cross-attention layer reads its
+    rows) the same way, ``full_read``. Beside what the calls stream as
+    they are built, what any implementation of them has to do at least, by
+    program and both kinds of layer together: ``rows_needed`` (a window
+    layer's call the rows from its first query's floor to its reach, a
+    full layer's its reach, exact, not rounded to blocks) and
+    ``pairs_needed`` ((query, key) pairs inside the window or the causal
+    reach)."""
+
+    PROGRAMS = ("ragged", "decode")
 
     def __init__(self, cfg, block_size: int):
         from production_stack_tpu.ops.ragged_paged_attention_pallas import (
@@ -814,33 +825,101 @@ class WindowCounters:
         self.kv_bytes_per_token = cfg.kv_bytes_per_token
         self.window_layers = cfg.count_layers("swa")
         self.cross_layers = cfg.count_layers("cross")
+        self.full_layers = cfg.count_layers("full") + self.cross_layers
         self.win_tokens = WINDOWS * block_size
-        self.context_tokens = 0  # streamed if no window bound
-        self.read_tokens = 0     # streamed
+        self.context = dict.fromkeys(self.PROGRAMS, 0)  # if no window bound
+        self.read = dict.fromkeys(self.PROGRAMS, 0)     # streamed
+        # streamed by the calls that see all rows
+        self.full_read = dict.fromkeys(self.PROGRAMS, 0)
+        self.rows_needed = dict.fromkeys(self.PROGRAMS, 0)
+        self.pairs_needed = dict.fromkeys(self.PROGRAMS, 0)
         self.shared_kv_calls = 0
 
+    @property
+    def context_tokens(self) -> int:
+        return sum(self.context.values())
+
+    @property
+    def read_tokens(self) -> int:
+        return sum(self.read.values())
+
+    def _spans_needed(self, q_len, context_lens) -> None:
+        """A ragged dispatch's spans of ``q_len`` rows that end contexts of
+        ``context_lens``: with S(n) = sum over v = 1..n of min(v, window),
+        a window layer scores S(ctx) - S(ctx - q) pairs of a span and reads
+        the rows from its first query's floor, a full layer the same with
+        no window."""
+        q = np.asarray(q_len, np.int64)
+        ctx = np.where(q > 0, np.asarray(context_lens, np.int64), 0)
+        w = self.window
+
+        def tri(n):
+            return n * (n + 1) // 2
+
+        def s(n):
+            return np.where(n <= w, tri(n), tri(w) + (n - w) * w)
+
+        pairs = (self.window_layers * (s(ctx) - s(ctx - q))
+                 + self.full_layers * (tri(ctx) - tri(ctx - q)))
+        rows = (self.window_layers * (ctx - np.maximum(ctx - q + 1 - w, 0))
+                + self.full_layers * ctx)
+        self.pairs_needed["ragged"] += int(pairs.sum())
+        self.rows_needed["ragged"] += int(rows[q > 0].sum())
+
     def record_decode(self, context_lens, iterations: int) -> None:
+        """``context_lens`` (slots,): the live contexts a dispatch's first
+        iteration attends over (0: an idle slot); the few rows the later
+        iterations add are not counted."""
         ctx = np.asarray(context_lens, np.int64)
         end = -(-ctx // self.bs) * self.bs
         floor = np.maximum(ctx - self.window, 0) // self.bs * self.bs
         n = iterations * self.window_layers
-        self.context_tokens += n * int(end.sum())
-        self.read_tokens += n * int((end - floor).sum())
+        self.context["decode"] += n * int(end.sum())
+        self.read["decode"] += n * int((end - floor).sum())
+        self.full_read["decode"] += (iterations * self.full_layers
+                                     * int(end.sum()))
+        # a row scores one pair a row it sees: its window's, its context's
+        rows = iterations * (
+            self.window_layers * int(np.minimum(ctx, self.window).sum())
+            + self.full_layers * int(ctx.sum()))
+        self.rows_needed["decode"] += rows
+        self.pairs_needed["decode"] += rows
         self.shared_kv_calls += iterations * self.cross_layers
 
-    def record_ragged(self, windows: int, windows_read: int) -> None:
+    def record_ragged(self, windows: int, windows_read: int, q_len,
+                      context_lens) -> None:
+        """``windows`` / ``windows_read``: ``count_windows`` of the
+        dispatch without and with the window; ``q_len``, ``context_lens``
+        (slots,): its spans."""
         n = self.window_layers * self.win_tokens
-        self.context_tokens += n * windows
-        self.read_tokens += n * windows_read
+        self.context["ragged"] += n * windows
+        self.read["ragged"] += n * windows_read
+        self.full_read["ragged"] += (self.full_layers * self.win_tokens
+                                     * windows)
+        self._spans_needed(q_len, context_lens)
         self.shared_kv_calls += self.cross_layers
 
-    def snapshot(self, allocator) -> dict:
+    def snapshot(self, scheduler) -> dict:
+        """``scheduler``: the engine's, for the window pool's allocator
+        and what it counted of it (blocks given back by live sequences;
+        times a sequence found the pool dry, 0 by the pool's size rule)."""
+        allocator = scheduler.window_allocator
         return {"window_attn_context_tokens_total": self.context_tokens,
                 "window_attn_read_tokens_total": self.read_tokens,
+                "window_attn_by_program": {
+                    p: {"context_tokens": self.context[p],
+                        "read_tokens": self.read[p],
+                        "full_read_tokens": self.full_read[p],
+                        "rows_needed": self.rows_needed[p],
+                        "pairs_needed": self.pairs_needed[p]}
+                    for p in self.PROGRAMS},
                 "shared_kv_attn_calls_total": self.shared_kv_calls,
                 "kv_bytes_per_token": self.kv_bytes_per_token,
                 "window_kv_blocks_total": allocator.num_blocks,
-                "window_kv_blocks_free": allocator.num_free_blocks}
+                "window_kv_blocks_free": allocator.num_free_blocks,
+                "window_kv_blocks_released_total":
+                    scheduler.window_blocks_released,
+                "window_kv_block_waits_total": scheduler.window_block_waits}
 
 
 # -- latent attention ---------------------------------------------------------

@@ -381,7 +381,8 @@ def _check_ouro_names(cfg: ModelConfig, index: dict) -> None:
 
 def load_safetensors(cfg: ModelConfig, mesh: Mesh, rules: ShardingRules) -> dict:
     if cfg.architecture in ("solar_open2", "pangu_ultra_moe", "phi4flash",
-                            "kimi_linear", "falcon_h1", "olmo_hybrid"):
+                            "kimi_linear", "falcon_h1", "olmo_hybrid",
+                            "afmoe"):
         # the published tensor names (the KDA and GDN layers' conv, decay
         # and gate tensors, the router's bias; the latent paths'
         # projections and norms; the state-space layers' and the fused

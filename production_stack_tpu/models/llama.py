@@ -207,9 +207,11 @@ def param_specs(cfg: ModelConfig) -> dict:
         for k in ("wq", "wk", "wv", "wo"):
             del layer[k]
         mixers = falcon_h1.param_specs(cfg)
-    elif cfg.has_recurrent_state:
+    elif cfg.patterned:
         # the token mixers differ by layer kind and are stacked by kind:
-        # "gqa" over the periods, "kda" over the KDA layers; what every
+        # "gqa" over the periods (over every layer where all are attention
+        # and differ in what a row sees: cfg.window_layers), "kda" over
+        # the KDA layers; what every
         # layer has (norms, the sparse block) stays in "layers"
         # projections onto all heads at once are kept as plain matrices,
         # (E, H*D): handed a (E, H, D) stack indexed by layer, the TPU
@@ -232,12 +234,12 @@ def param_specs(cfg: ModelConfig) -> dict:
             }
         else:
             mixers["gqa"] = {**attn, "wq": (L.LAYERS, L.EMBED, L.HEADS)}
-            if cfg.qk_norm:  # over the whole projections, as made above
+            if cfg.qk_norm:  # of the kind made above
                 mixers["gqa"].update(
                     {k: layer.pop(k) for k in ("q_norm", "k_norm")})
         if cfg.gdn_heads:
             mixers["gdn"] = olmo_hybrid.param_specs(cfg)
-        else:
+        elif cfg.kda_heads:
             mixers["kda"] = _kda_specs()
         if cfg.attn_gate:
             mixers["gqa"]["wg"] = (L.LAYERS, L.EMBED, L.HEADS)
@@ -252,6 +254,10 @@ def param_specs(cfg: ModelConfig) -> dict:
                 "w_up": (L.LAYERS, L.EMBED, L.MLP),
                 "w_down": (L.LAYERS, L.MLP, L.EMBED),
             }
+            if cfg.post_norms:
+                mixers["dense"].update({
+                    "post_attn_norm": (L.LAYERS, L.EMBED),
+                    "post_mlp_norm": (L.LAYERS, L.EMBED)})
     if cfg.moe_scoring == "sigmoid":
         layer["router_bias"] = (L.LAYERS, L.EXPERTS)
     if cfg.shared_expert_size:
@@ -353,6 +359,31 @@ def param_layouts(cfg: ModelConfig) -> dict:
 # looped model's is under its own: bf16 reads 0.03-0.04 / 0.009 against
 # the float32 reference on the chip, under half of them.
 LOOPED_POST_NORM_GAIN = 0.05
+
+# Random stand-in weights of a sigmoid router: the standard deviation of the
+# selection bias, by architecture (0: zeros, a router whose balancing never
+# ran). A trained afmoe router carries a nonzero one; at 0.02, a step or two
+# of the spacing of the top sigmoid scores of 256, the experts chosen by
+# score + bias differ from the top scores' in a share of the rows, so that a
+# bias that weighed as well as chose reads in the log-probabilities
+# (tests/test_afmoe.py), on the chip's probe as in the tests.
+STANDIN_ROUTER_BIAS = {"afmoe": 0.02}
+
+# Random stand-in weights of a stack whose attention layers differ by kind
+# (``cfg.window_layers``): the norm after an attention sublayer weighs this
+# many times the norm after an MLP (0.25 beside 0.0625 at eight layers), so
+# that the attention sublayers together move the stream by 0.7 of the
+# embedding's size. At one gain for both, 0.0625, all sixteen sublayers were
+# a quarter of the stream and a reference whose window layers saw every row
+# read 0.153 / 0.041 against the chip's served 6,208-token probe, over
+# chipbench/run.py's 0.15 by a hair; at 4 x it reads 0.58-0.70 / 0.12-0.14
+# while the bfloat16 path's own reading stays where it was (0.03-0.045 /
+# 0.007: it comes from the stream's and the head's rounding, not from the
+# attention sublayers' size). The MLP's norm stays small for the latent
+# stacks' reason (a near-tied expert picked the other way turns the sparse
+# block's output: at 0.25 for both, the reference with bfloat16 activations
+# read up to 0.116 on one row). PERF.md section 6, PR 59.
+STANDIN_WINDOW_ATTN_GAIN = 4.0
 
 
 # Random stand-in weights of a latent-attention stack with norms after
@@ -467,7 +498,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
 
     # a hybrid stack's stand-in: see HYBRID_INIT (a SambaY stack's:
     # SAMBAY_INIT)
-    hybrid = cfg.has_recurrent_state
+    hybrid = cfg.patterned
     out = 2 * LN if hybrid else 1  # x the fan-in of what writes the stream
 
     # stored norm weight giving an effective scale of 1 (Gemma stores
@@ -475,13 +506,17 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     norm_one = 1.0 - cfg.norm_offset
     # "layers" of a patterned stack with leading dense layers holds the
     # expert layers alone (no other family here has dense layers)
+    # (a patterned stack's attention mixers are a stack of their own, of
+    # which there are more than expert layers where every layer is
+    # attention and the first is dense: La)
+    Ln = cfg.num_expert_layers
+    La = max(Ln, cfg.num_attn_layers) if hybrid else Ln
     layers = {
-        "attn_norm": jnp.full((Ln := cfg.num_expert_layers, E), norm_one,
-                              dt),
-        "wq": normal(keys[0], (Ln, E, H, D), E),
-        "wk": normal(keys[1], (Ln, E, KH, D), E),
-        "wv": normal(keys[2], (Ln, E, KH, D), E),
-        "wo": normal(keys[3], (Ln, H, D, E), H * D * out),
+        "attn_norm": jnp.full((Ln, E), norm_one, dt),
+        "wq": normal(keys[0], (La, E, H, D), E),
+        "wk": normal(keys[1], (La, E, KH, D), E),
+        "wv": normal(keys[2], (La, E, KH, D), E),
+        "wo": normal(keys[3], (La, H, D, E), H * D * out),
         "mlp_norm": jnp.full((Ln, E), norm_one, dt),
     }
     if cfg.qkv_bias:
@@ -496,22 +531,26 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         full = cfg.qk_norm_kind == "full"
         layers.update(
             {
-                "q_norm": jnp.full((Ln, H, D) if full else (Ln, D),
+                "q_norm": jnp.full((La, H, D) if full else (La, D),
                                    norm_one, dt),
-                "k_norm": jnp.full((Ln, KH, D) if full else (Ln, D),
+                "k_norm": jnp.full((La, KH, D) if full else (La, D),
                                    norm_one, dt),
             }
         )
     if cfg.post_norms:
         # Gemma stores zero-centred norm weights (forward adds norm_offset)
+        # (a patterned stack with norms on both sides: the latent stacks'
+        # gain, for the latent stacks' reason, a sparse block behind a norm)
         gain = (LOOPED_POST_NORM_GAIN if cfg.loop_passes > 1
-                else out ** -0.5 if not cfg.pre_norms else 1.0)
-        layers.update(
-            {
-                "post_attn_norm": jnp.full((Ln, E), gain - cfg.norm_offset, dt),
-                "post_mlp_norm": jnp.full((Ln, E), gain - cfg.norm_offset, dt),
-            }
-        )
+                else out ** -0.5 if not cfg.pre_norms
+                else _latent_post_norm_gain(cfg) if hybrid else 1.0)
+        # (where the attention layers differ by kind: STANDIN_WINDOW_ATTN_GAIN)
+        post_gains = {
+            "post_attn_norm": gain * (STANDIN_WINDOW_ATTN_GAIN
+                                      if cfg.window_layers else 1.0),
+            "post_mlp_norm": gain}
+        layers.update({k: jnp.full((Ln, E), g - cfg.norm_offset, dt)
+                       for k, g in post_gains.items()})
     if not cfg.pre_norms:
         del layers["attn_norm"], layers["mlp_norm"]
     mixers = {}
@@ -529,7 +568,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
             del layers[k]
         mixers = falcon_h1.init_params(cfg, keys[13], normal, out,
                                        (KDA_DT_MIN, KDA_DT_MAX))
-    elif cfg.has_recurrent_state:
+    elif hybrid:
         Pn = cfg.num_attn_layers
         attn = {k: layers.pop(k) for k in ("wq", "wk", "wv", "wo")}
         if cfg.is_latent:
@@ -549,7 +588,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
                 layers["post_attn_norm"].astype(jnp.float32) * jnp.where(
                     is_gdn, olmo_hybrid.STANDIN_MIXER_GAIN, 1.0)[:, None]
             ).astype(dt)
-        else:
+        elif cfg.kda_heads:
             mixers["kda"] = _init_kda(cfg, keys[13], normal, out)
         if cfg.attn_gate:
             mixers["gqa"]["wg"] = normal(keys[14], (Pn, E, H * D), E)
@@ -563,9 +602,18 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
                 "w_up": normal(ks[1], (Dn, E, Fd), E),
                 "w_down": normal(ks[2], (Dn, Fd, E), Fd * out),
             }
+            if cfg.post_norms:
+                mixers["dense"].update({
+                    k: jnp.full((Dn, E), g - cfg.norm_offset, dt)
+                    for k, g in post_gains.items()})
     if cfg.moe_scoring == "sigmoid":
-        # the selection bias (balancing state of a trained router): zeros
-        layers["router_bias"] = jnp.zeros((Ln, cfg.num_experts), jnp.float32)
+        # the selection bias (balancing state of a trained router): zeros,
+        # but for the family whose stand-in draws it (STANDIN_ROUTER_BIAS)
+        std = STANDIN_ROUTER_BIAS.get(cfg.architecture)
+        layers["router_bias"] = (
+            std * jax.random.normal(jax.random.fold_in(keys[4], 1),
+                                    (Ln, cfg.num_experts), jnp.float32)
+            if std else jnp.zeros((Ln, cfg.num_experts), jnp.float32))
     if cfg.shared_expert_size:
         Fs = cfg.shared_expert_size
         ks = jax.random.split(keys[15], 3)
@@ -608,8 +656,11 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         if cfg.mamba_period:
             layers["w_down"] = _first_unscaled(layers["w_down"], out)
     params = {
+        # (unit RMS as the stack reads it: a family that multiplies by
+        # sqrt(E) draws rows of RMS E^-1/2)
         "embed": over(normal(keys[8], (V, E),
-                             1 if hybrid and not cfg.mamba_period else E),
+                             1 if hybrid and not (cfg.mamba_period
+                                                  or cfg.embed_scale) else E),
                       cfg.embedding_multiplier),
         "layers": layers,
         **mixers,
@@ -1004,7 +1055,7 @@ def forward_hidden(
         # a float32 stream feeds the matmuls in the model dtype
         return normed.astype(cfg.jax_dtype) if cfg.residual_f32 else normed
 
-    if cfg.has_recurrent_state:
+    if cfg.patterned:
         if lora is not None:
             raise ValueError("LoRA on a hybrid (recurrent-state) stack is "
                              "not supported")
@@ -1276,17 +1327,19 @@ def _kda_mixer(cfg: ModelConfig, kp: dict, x: jnp.ndarray, recur: RecurFn,
     return quant_einsum("...thd,hde->...te", o, kp["wo"]), caches
 
 
-def _attend_filled(cfg: ModelConfig, attend: AttendFn, q, k, v, kv, i):
+def _attend_filled(cfg: ModelConfig, attend: AttendFn, q, k, v, kv, i,
+                   **how):
     """``attend`` over the heads as the cache holds them: where it fills
     the KV heads up with empty ones (``ModelConfig.cache_kv_heads``: 30
     heads lie as 32), zero heads behind the real ones on q, k and v, and
-    the real heads' outputs back. Without filling, the call as it is."""
+    the real heads' outputs back. Without filling, the call as it is.
+    ``how``: the call's keywords (a layer's ``kind``)."""
     held = cfg.cache_kv_heads
     if held == cfg.num_kv_heads or cfg.diff_attn:  # a packed stack fills
-        return attend(q, k, v, kv, i)              # its own heads
+        return attend(q, k, v, kv, i, **how)       # its own heads
     attn, kv = attend(sambay._pad_heads(q, held * cfg.q_per_kv),
                       sambay._pad_heads(k, held), sambay._pad_heads(v, held),
-                      kv, i)
+                      kv, i, **how)
     return attn[..., :cfg.num_heads, :], kv
 
 
@@ -1303,7 +1356,10 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
     mla) once with its leading dense layer (an MLP in place of the sparse
     block), the same scanned, and a short (kda, kda, mla); the latent
     attention rotates nothing and shares ``caches["kv"]``, a latent pool.
-    A SambaY stack (models/sambay.py) is
+    An afmoe stack is runs of (swa, swa, swa, full), grouped-query attention
+    within the window (rotated) or over all rows (nothing rotated), gated,
+    norms on both sides of every sublayer, the periods that hold leading
+    dense layers runs of their own. A SambaY stack (models/sambay.py) is
     three: (mamba, swa) periods, one (mamba, full), (gmu, cross) periods,
     every block followed by the MLP. Falcon-H1 is one run of ("parallel",):
     a state-space mixer with heads and rotated grouped-query attention
@@ -1333,14 +1389,19 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
     step, counted by the TPU compiler at the published widths."""
     stack_of = {"gqa": "gqa", "kda": "kda", "mla": "mla", "parallel": "gqa",
                 "gdn": "gdn", **sambay.STACK_OF}
+    if cfg.window_layers:  # grouped-query attention of two kinds
+        stack_of.update(swa="gqa", full="gqa")
     dense = cfg.dense_layers
 
-    def gqa(gp, normed, caches, i, rotate=False):
+    def gqa(gp, normed, caches, i, rotate=False, kind=None):
         """Grouped-query attention over cache layer ``i``: Solar-Open2's
         (nothing rotated, a sigmoid gate), Falcon-H1's (``rotate``: rope
-        on q and k, the keys times ``key_multiplier``; no gate) or
+        on q and k, the keys times ``key_multiplier``; no gate),
         Olmo-Hybrid's (nothing rotated, no gate, RMSNorm over the whole q
-        and k projections)."""
+        and k projections) or afmoe's (``kind`` "swa" / "full": ``i`` is
+        the layer's place among its kind's, ``attend`` is handed the whole
+        cache pytree and the kind and finds the pool and the window;
+        RMSNorm a head on q and k, a gate)."""
         if "wq_t" in gp:
             # W_q, W_k, W_v lie transposed, (H * D, E): the order of bytes
             # the TPU compiler wants for a decode step's 64 rows; handed
@@ -1362,17 +1423,25 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
             k = quant_einsum("...te,ehd->...thd", normed, gp["wk"])
             v = quant_einsum("...te,ehd->...thd", normed, gp["wv"])
         q = q.reshape(*q.shape[:-1], cfg.num_heads, cfg.head_dim)
-        if cfg.qk_norm:  # Olmo-Hybrid: over the whole projections (OLMoE's)
+        if cfg.qk_norm and cfg.qk_norm_kind == "full":
+            # Olmo-Hybrid: over the whole projections (OLMoE's)
             q = _rms_norm_heads(q, gp["q_norm"], cfg.rms_norm_eps)
             k = _rms_norm_heads(k, gp["k_norm"], cfg.rms_norm_eps)
+        elif cfg.qk_norm:  # a head at a time (Qwen3's)
+            q = rms_norm(q, gp["q_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+            k = rms_norm(k, gp["k_norm"], cfg.rms_norm_eps, cfg.norm_offset)
         if rotate:
             k = times(k, cfg.key_multiplier)
             q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
             k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
-        kv = None if caches is None else caches["kv"]
-        attn, kv = _attend_filled(cfg, attend, q, k, v, kv, i)
-        if caches is not None:
-            caches = {**caches, "kv": kv}
+        if kind is not None:
+            attn, caches = _attend_filled(cfg, attend, q, k, v, caches, i,
+                                          kind=kind)
+        else:
+            kv = None if caches is None else caches["kv"]
+            attn, kv = _attend_filled(cfg, attend, q, k, v, kv, i)
+            if caches is not None:
+                caches = {**caches, "kv": kv}
         if cfg.attn_gate:
             attn = _gated(attn, normed, gp)
         return quant_einsum("...thd,hde->...te", attn, gp["wo"]), caches
@@ -1388,14 +1457,16 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
     def sublayer_out(o, lp, norm):
         """A sublayer's output as the stream takes it: through the norm
         AFTER it where the block has one (``cfg.norms`` "post"; the other
-        patterned stacks have none and add ``o`` as it is)."""
-        if cfg.pre_norms:
+        and "both"; the other patterned stacks have none and add ``o`` as
+        it is)."""
+        if not cfg.post_norms:
             return o
         return rms_norm(o, lp[norm], cfg.rms_norm_eps, cfg.norm_offset)
 
-    def period_fn(kinds, l0, before, shared, carry, p):
+    def period_fn(kinds, l0, before, seen, shared, carry, p):
         """Period ``p`` of a run of ``kinds`` periods that starts at layer
-        ``l0`` with ``before[stack]`` layers of each stack ahead of it."""
+        ``l0`` with ``before[stack]`` layers of each stack, and
+        ``seen[kind]`` of each kind, ahead of it."""
         h, _, caches = carry
         hists = []
         stacks = [stack_of[k] for k in kinds]
@@ -1422,6 +1493,13 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
                 i = p if (i, first) == (1, 0) else p * i + first
                 if kind == "gqa":
                     o, caches = gqa(at(params["gqa"], i), normed, caches, i)
+                elif stacks[j] == "gqa" and kind in ("swa", "full"):
+                    # its cache layer: its place among its KIND's layers,
+                    # in that kind's pool
+                    ik = (p * kinds.count(kind) + seen[kind]
+                          + kinds[:j].count(kind))
+                    o, caches = gqa(at(params["gqa"], i), normed, caches, ik,
+                                    rotate=kind in cfg.rope_kinds, kind=kind)
                 elif kind == "parallel":  # both mixers read ``normed``
                     with jax.named_scope("ssd"):
                         o, caches = falcon_h1.ssd_mixer(
@@ -1488,8 +1566,10 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
         return (h, p + 1, caches), (jnp.stack(hists) if hists else None)
 
     l0, before, shared, hists = 0, dict.fromkeys(stack_of.values(), 0), {}, []
+    seen = dict.fromkeys(stack_of, 0)
     for kinds, count in cfg.stack_segments:
-        run = functools.partial(period_fn, kinds, l0, dict(before), shared)
+        run = functools.partial(period_fn, kinds, l0, dict(before),
+                                dict(seen), shared)
         if count == 1:
             (x, _, caches), hist = run((x, 0, caches), 0)
             hist = None if hist is None else hist[None]
@@ -1501,10 +1581,12 @@ def _forward_hybrid(cfg: ModelConfig, params: dict, layers: dict,
         l0 += count * len(kinds)
         for k in kinds:
             before[stack_of[k]] += count
+            seen[k] += count
     if not cfg.is_moe:
         return x, caches, None
-    # a row a sparse layer: a period with a dense layer has one fewer
-    hists = [h.reshape(-1, h.shape[-1]) for h in hists]
+    # a row a sparse layer: a period with a dense layer has one fewer, a
+    # period of dense layers alone none
+    hists = [h.reshape(-1, h.shape[-1]) for h in hists if h is not None]
     return x, caches, hists[0] if len(hists) == 1 else jnp.concatenate(hists)
 
 

@@ -73,6 +73,16 @@ _REGISTRY: dict[str, ModuleType] = {
     # whole layers and the whole vocabulary on one chip: chipbench cell
     # olmo-hybrid-7b-l16.decode-heavy (PR 57)
     "olmo_hybrid": llama,
+    # afmoe (Arcee Trinity): the same walker over grouped-query attention
+    # layers of two kinds named layer by layer (cfg.window_layers): a
+    # window that binds and rope on three of four, all rows and no rope on
+    # the fourth, each with QK-norm a head and a sigmoid gate, norms on
+    # both sides of every sublayer, the embedding times sqrt(hidden), a
+    # leading dense layer and Solar-Open2's sparse block. Two block pools,
+    # "win" and "kv", and no per-slot state. Served with eight layers as
+    # one chip of sixteen: chipbench cell
+    # trinity-large-preview-ep16-l8.long-context (PR 59)
+    "afmoe": llama,
     # encoder-decoder audio transcription: exposes its own forward
     # surface (encode/cross_kv/decode_tokens) instead of the decoder-only
     # protocol; shares param_specs/init_params so weights.py works

@@ -534,6 +534,14 @@ def _pick_seqs_per_cell(B: int, bs: int, KH2: int, D: int, windows: int,
     return int(min(spb, B))
 
 
+# behind ``jax.jit`` (as ``moe_grouped_matmul`` is) so that the calls of like
+# layers of one program share a trace and a lowering: a stack whose layers
+# are unrolled and not scanned traced and lowered each kernel once a LAYER
+# (PR 59: 308 -> 27.5 s of an eight-layer start on the chip's host). The
+# compiled program is the same: the call is inlined, as many instructions
+# under the same names, the pool still updated in place.
+@functools.partial(
+    jax.jit, static_argnames=("windows", "interpret", "soft_cap", "window"))
 def paged_decode_attention_pallas(
     q: jnp.ndarray,  # (B, H, D)
     kv_cache: jnp.ndarray,  # (L, N, bs, 2KH, D)
@@ -645,6 +653,7 @@ def _kv_write_kernel(
             dma(i).wait()
 
 
+@functools.partial(jax.jit, static_argnames="interpret")
 def kv_cache_write_pallas(
     kv_cache: jnp.ndarray,  # (L, N, bs, 2KH, D) — donated, updated in place
     newkv: jnp.ndarray,  # (T, 2KH, D) combined update (see combine_kv)

@@ -524,6 +524,9 @@ def tile_metadata(
     return first, cnt
 
 
+@functools.partial(  # one trace for like layers: see the decode kernel's
+    jax.jit,
+    static_argnames=("q_tile", "windows", "interpret", "soft_cap", "window"))
 def ragged_paged_attention_pallas(
     q: jnp.ndarray,  # (T, H, D) packed query stream
     kv_cache: jnp.ndarray,  # (L, N, bs, 2KH, D)

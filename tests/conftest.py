@@ -34,3 +34,21 @@ def tp_mesh():
     from production_stack_tpu.parallel.mesh import MeshConfig, build_mesh
 
     return build_mesh(MeshConfig(tensor=-1))
+
+
+@pytest.fixture(scope="module", autouse=True)
+def compiled_programs_freed_with_their_module():
+    """Every program XLA:CPU compiles stays mapped (three mappings a
+    module) while a cache holds it, and a process may hold 65,530 mappings
+    (``vm.max_map_count``): ``tests/test_serving_path.py`` alone leaves a
+    worker at 62,000, and the next file's compile then died inside
+    ``backend_compile_and_load`` (PR 59: a segfault or an abort in whatever
+    test came next). Instantiated first in its module, so finalised after
+    the module's own fixtures have let their engines go."""
+    yield
+    import gc
+
+    import jax
+
+    jax.clear_caches()
+    gc.collect()

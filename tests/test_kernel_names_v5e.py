@@ -389,6 +389,77 @@ def test_kernel_compiles_at_kh8_g8_under_the_manifests_vmem_limit(
                      text, flags=re.M)
 
 
+# -- Trinity-Large-Preview's share on one chip (chipbench
+# trinity-large-preview-ep16-l8.long-context): KH = 8, G = 6, D = 128, a
+# geometry no other cell has, with a 4096-row window and without, 16 slots
+# of 20,480 positions, a 4096-token stream and its 1024-token narrow one;
+# six layers in the window pool. The decode kernel (from the slab as
+# stored) and the KV write fit the DEFAULT 16 MiB; the ragged kernel's tile
+# is 80 tokens = 480 rows and it asks 19.26 MiB at the wide stream (17.39
+# at the narrow one), window or none: Qwen3's 32 MiB flag, in the
+# manifest's engine_env. Under the names every accepted metric matches.
+
+G6_KH, G6 = 8, 6
+G6_CACHE = ((6, 4656, BS, 2 * G6_KH, D), jnp.bfloat16)
+G6_RAGGED_VMEM_MIB = {4096: 19.26, 1024: 17.39}
+
+
+def _g6_cases(window):
+    h, how = G6_KH * G6, ({"window": window} if window else {})
+    table = ((16, 1280), I32)
+
+    def ragged(width):
+        return (
+            lambda q, c, bt, cu, cl: ragged_paged_attention_pallas(
+                q, c, bt, cu, cl, layer_idx=1, **how),
+            (((width, h, D), jnp.bfloat16), G6_CACHE, table, ((17,), I32),
+             ((16,), I32)))
+
+    return {
+        "ragged_paged_attention@4096": ragged(4096),
+        "ragged_paged_attention@1024": ragged(1024),
+        "paged_decode_attention": (
+            lambda q, c, bt, cl: paged_decode_attention_pallas(
+                q, c, bt, cl, layer_idx=1, **how),
+            (((16, h, D), jnp.bfloat16), G6_CACHE, table, ((16,), I32))),
+        "kv_cache_write": (
+            lambda c, new, sm: kv_cache_write_pallas(c, new, sm, layer_idx=1),
+            (G6_CACHE, ((4096, 2 * G6_KH, D), jnp.bfloat16), ((4096,), I32))),
+    }
+
+
+@pytest.mark.parametrize("window", [0, 4096])
+@pytest.mark.parametrize("case", sorted(_g6_cases(0)))
+def test_attention_kernels_compile_at_kh8_g6(one_chip, case, window):
+    fn, shapes = _g6_cases(window)[case]
+    name, _, width = case.partition("@")
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    head = rf"^\s*(?:ROOT )?%{name}[.\d]* = .*? custom-call\("
+    if name != "ragged_paged_attention":
+        assert re.search(head, lowered.compile().as_text(), flags=re.M)
+        return
+    text = lowered.compile(compiler_options={
+        "xla_tpu_scoped_vmem_limit_kib": 32768}).as_text()
+    assert re.search(head, text, flags=re.M)
+    assert _asks_at_most(lowered, G6_RAGGED_VMEM_MIB[int(width)])
+
+
+def test_the_g6_geometry_takes_the_slab_path_and_an_80_token_tile():
+    from production_stack_tpu.ops.paged_attention_pallas import (
+        decode_slab_path,
+    )
+    from production_stack_tpu.ops.ragged_paged_attention_pallas import (
+        ROW_ALIGN,
+        q_tile_for,
+    )
+
+    assert q_tile_for(G6, G6_KH) == 80 and 80 * G6 % ROW_ALIGN == 0
+    assert all(decode_slab_path(G6_KH, G6, D, jnp.bfloat16, window)
+               for window in (0, 4096))
+
+
 def test_the_ragged_tile_is_keyed_by_the_group():
     """128 stream tokens a tile up to G = 4 (Qwen3's and OLMoE's programs
     are what they were), 512 rows a tile past it."""
@@ -975,8 +1046,10 @@ STEP_CONFIGS = {
     "kimi-linear-48b-a3b-ep16": 4096,
     "falcon-h1-34b-l6": 8192,
     "olmo-hybrid-7b-l16": 2560,
+    # the full layers' pool: what 0.9 of the memory left beside 8.29 GB of
+    # weights and the 1.83 GB window pool holds, ~3 GB of 131,072 B blocks
+    "trinity-large-preview-ep16-l8": 23040,
 }
-SLOTS_SERVED = 64
 # a whole weight stack is tens of MiB and up; a step's own rows are not
 COPY_FLOOR = 16 * 2 ** 20
 
@@ -1005,10 +1078,10 @@ def _chipbench_cfg(config):
                 "LIBTPU_INIT_ARGS", "").split())}
     return dataclasses.replace(
         cfg, max_model_len=int(flags[flags.index("--max-model-len") + 1])
-    ), options
+    ), options, (manifest["decode_slots"], manifest["token_budget"])
 
 
-def _cache_shapes(cfg, blocks, slots, one_chip):
+def _cache_shapes(cfg, blocks, slots, one_chip, budget=2048):
     """The cache pytree ``engine/kv_cache.py`` ``init_kv_cache`` makes (it
     allocates, so it cannot be asked for shapes alone)."""
     from production_stack_tpu.engine import kv_cache as kvmod
@@ -1018,7 +1091,11 @@ def _cache_shapes(cfg, blocks, slots, one_chip):
 
     pool = sds(cfg.kv_pool_shape(blocks, BS))
     if not cfg.has_recurrent_state:
-        return pool
+        if not cfg.window_binds:
+            return pool
+        return {"kv": pool, "win": sds(cfg.kv_pool_shape(
+            kvmod.window_pool_blocks(cfg, BS, slots, budget), BS,
+            window=True))}
     if cfg.mamba_period:
         n, di = cfg.count_layers("mamba"), cfg.mamba_inner
         state, conv = (cfg.mamba_state, di), (cfg.mamba_conv - 1, di)
@@ -1037,7 +1114,8 @@ def _cache_shapes(cfg, blocks, slots, one_chip):
               "conv": sds((n, slots, *conv))}
     if cfg.window_binds:
         caches["win"] = sds(cfg.kv_pool_shape(
-            kvmod.window_pool_blocks(cfg, BS, slots, 2048), BS, window=True))
+            kvmod.window_pool_blocks(cfg, BS, slots, budget), BS,
+            window=True))
     return caches
 
 
@@ -1062,8 +1140,8 @@ def _step_program(one_chip, config, program, layouts=True):
         moe_grouped_matmul,
     )
 
-    (cfg, options), blocks, slots = (
-        _chipbench_cfg(config), STEP_CONFIGS[config], SLOTS_SERVED)
+    (cfg, options, (slots, budget)), blocks = (
+        _chipbench_cfg(config), STEP_CONFIGS[config])
 
     def made():
         tree = get_model(cfg).init_params(cfg, jax.random.PRNGKey(0))
@@ -1073,7 +1151,7 @@ def _step_program(one_chip, config, program, layouts=True):
     params = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         shapes)
-    caches = _cache_shapes(cfg, blocks, slots, one_chip)
+    caches = _cache_shapes(cfg, blocks, slots, one_chip, budget)
     # the runner's stateful calls without a runner: one chip, so no
     # shard_map (``tp`` reads the rules and finds no axis split)
     me = object.__new__(mr.ModelRunner)
@@ -1171,7 +1249,11 @@ STILL_COPIED = {
     ("openpangu-ultra-moe-718b-ep16-l5", "ragged2048"):
         "params__dense____wq_rope__",
 }
-STEP_CASES = [(c, p) for c in STEP_CONFIGS for p in ("decode", "ragged512")]
+TRINITY = "trinity-large-preview-ep16-l8"
+STEP_CASES = [(c, p) for c in STEP_CONFIGS if c != TRINITY
+              for p in ("decode", "ragged512")]
+# 16 slots and a 4096-token budget: its streams are 1024 and 4096 wide
+STEP_CASES += [(TRINITY, "decode"), (TRINITY, "ragged4096")]
 # the configurations whose cells fill the wide stream (prompts of 1024
 # tokens and more)
 STEP_CASES += [(c, "ragged2048") for c in (
@@ -1203,6 +1285,10 @@ def test_step_program_copies_no_weight_stack(one_chip, config, program):
         ("olmo-hybrid-7b-l16", "ragged512"): (
             "gdn_decode_step", "gdn_chunk_scan", "ragged_paged_attention",
             "kv_cache_write"),
+        (TRINITY, "decode"): (
+            "paged_decode_attention", "kv_cache_write", "moe_grouped_matmul"),
+        (TRINITY, "ragged4096"): (
+            "ragged_paged_attention", "kv_cache_write", "moe_grouped_matmul"),
     }.get((config, program), ())
     for name in kernels:
         assert re.search(rf"^\s*(?:ROOT )?%{name}[.\d]* = ", text,

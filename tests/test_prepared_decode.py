@@ -604,7 +604,8 @@ DECODE_CELLS = {
     "ouro-2.6b.decode-pool-bound", "solar-open2-250b-ep16-l8.decode-heavy",
     "phi-4-mini-flash-reasoning.long-decode",
     "kimi-linear-48b-a3b-ep16.long-decode", "falcon-h1-34b-l6.decode-heavy",
-    "olmo-hybrid-7b-l16.decode-heavy"}
+    "olmo-hybrid-7b-l16.decode-heavy",
+    "trinity-large-preview-ep16-l8.long-context"}
 METRICS = {
     "decode_prepared_launch_pct": (
         "prepared", "dispatches", "higher", "tpot_p50_ms", DECODE_CELLS),

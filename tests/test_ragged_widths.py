@@ -105,7 +105,11 @@ def test_the_suites_tiny_manifests_keep_one_width():
         with open(path) as f:
             m = json.load(f)
         w = widths(m["token_budget"], m["decode_slots"])
-        assert w == ((m["token_budget"],) if path in tiny else (512, 2048))
+        # a quarter of the budget and the budget: (512, 2048) for every
+        # configuration but the one whose budget is 4096 (PR 59)
+        assert w == ((m["token_budget"],) if path in tiny
+                     else (m["token_budget"] // 4, m["token_budget"]))
+        assert path in tiny or m["token_budget"] in (2048, 4096)
 
 
 # -- engines ------------------------------------------------------------------
