@@ -389,6 +389,12 @@ class EngineStatsCollector:
                  "step that the kernel scored them in the published, "
                  "expanded form (ops/latent_paged_attention_pallas.py "
                  "EXPAND_ROWS); none in a decode step"),
+                ("vllm:mla_decode_body_pairs", "mla_decode_body_pairs_total",
+                 "Of those pairs, the ones of decode dispatches whose "
+                 "program is the kernel's decode body (one-token spans, "
+                 "several sequences a grid cell, 512-row windows); none "
+                 "in a ragged step, whose decode rows walk the stream's "
+                 "tile, nor where the programs are the XLA form"),
                 ("vllm:mla_context_rows", "mla_context_rows_total",
                  "Context rows the spans of the latent attention kernel "
                  "reach, each once a span and cache layer, by step kind"),

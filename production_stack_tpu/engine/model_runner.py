@@ -766,19 +766,22 @@ class ModelRunner:
                        expand=None):
         """Latent attention over a packed stream: absorbed queries q
         (T, H, latent_lanes), the tokens' own rows (T, latent_lanes) to
-        write first, spans by cu_q_lens (S+1,). Returns ((T, H,
+        write first, spans by cu_q_lens (S+1,), or None for the decode
+        program's one-token spans, a slot a row. Returns ((T, H,
         kv_lora_rank), caches). The rows go in by the XLA scatter on the
         chip too: a (16, lanes) bf16 block is whole tiles, two tokens a
         sublane, so no DMA writes one token's row, and the scatter keeps
         the donated pool in place (tests/test_kernel_names_v5e.py).
 
         Two forms of the same scores (models/llama.py _mla_mixer). A
-        decode step and the XLA path are absorbed throughout. The ragged
-        program's kernel, given ``expand`` (the heads' own (T, H, nope) and
-        (T, H, rope) queries, ``W_UK``, ``W_UV``), scores the spans of
-        ``EXPAND_ROWS`` query rows or more in the published form in the
-        same call, and ``llama.ExpandedRows`` comes back in the array's
-        place."""
+        decode step and the XLA path are absorbed throughout; on the chip
+        the decode program's call takes the kernel's decode body (several
+        sequences a grid cell, 512-row windows), by the shape of its call
+        and nothing else. The ragged program's kernel, given ``expand``
+        (the heads' own (T, H, nope) and (T, H, rope) queries, ``W_UK``,
+        ``W_UV``), scores the spans of ``EXPAND_ROWS`` query rows or more
+        in the published form in the same call, and ``llama.ExpandedRows``
+        comes back in the array's place."""
         from production_stack_tpu.ops.paged_attention import (
             latent_ragged_paged_attention,
             write_latent,
@@ -787,14 +790,14 @@ class ModelRunner:
         C = self.cfg.kv_lora_rank
         caches = write_latent(caches, layer_idx, rows, slot_mapping)
         if self.use_pallas:
-            from production_stack_tpu.ops.latent_paged_attention_pallas import (  # noqa: E501
-                latent_paged_attention_pallas,
+            from production_stack_tpu.ops import (
+                latent_paged_attention_pallas as kernel,
             )
 
-            if expand is None:
-                return latent_paged_attention_pallas(
-                    q, caches, block_tables, cu_q_lens, context_lens,
-                    layer_idx, value_dim=C), caches
+            if cu_q_lens is None:
+                return kernel.latent_decode_attention_pallas(
+                    q, caches, block_tables, context_lens, layer_idx,
+                    value_dim=C), caches
             from production_stack_tpu.models.llama import ExpandedRows
 
             q_nope, q_rope, w_uk, w_uv = expand
@@ -804,12 +807,14 @@ class ModelRunner:
                     (*q_rope.shape[:-1],
                      q.shape[-1] - C - q_rope.shape[-1]), q_rope.dtype)],
                 axis=-1).swapaxes(0, 1)
-            o_lat, o_own, scored = latent_paged_attention_pallas(
+            o_lat, o_own, scored = kernel.latent_paged_attention_pallas(
                 q, caches, block_tables, cu_q_lens, context_lens, layer_idx,
                 value_dim=C, expand=(q_own, w_uk, w_uv,
                                      self.cfg.head_dim ** -0.5))
             return ExpandedRows(o_lat, o_own.swapaxes(0, 1), scored), caches
-        seq_ids = _owning_slots(cu_q_lens, q.shape[0], block_tables.shape[0])
+        S = block_tables.shape[0]
+        seq_ids = (jnp.arange(S, dtype=jnp.int32) if cu_q_lens is None
+                   else _owning_slots(cu_q_lens, q.shape[0], S))
         layer = jax.lax.dynamic_index_in_dim(caches, layer_idx, 0, False)
         return latent_ragged_paged_attention(
             q, layer, block_tables, context_lens, seq_ids, q_positions,
@@ -829,14 +834,13 @@ class ModelRunner:
                 block_tables, context_lens, q_positions, slot_mapping,
                 **window_inputs)
         if self.cfg.is_latent:
-            # the same kernel on one-token spans, a slot a token; an idle
-            # slot (context 0, position < 0) walks nothing
-            B = q.shape[0]
+            # one-token spans, a slot a row; an idle slot (context 0,
+            # position < 0) fetches nothing
             out, caches = self._attend_latent(
                 q[:, 0], k[:, 0, 0], caches, layer_idx, block_tables,
                 context_lens, jnp.where(context_lens > 0,
                                         q_positions[:, 0], -1),
-                slot_mapping, jnp.arange(B + 1, dtype=jnp.int32))
+                slot_mapping, None)
             return out[:, None], caches
         how = {"window": window} if window else {}
         if not self.use_pallas:

@@ -937,10 +937,12 @@ class LatentCounters:
     past whole, its own triangle.
 
     ``expand_rows``: the kernel's crossover (``EXPAND_ROWS``) where the
-    runner's ragged program is the kernel's, else None. A span of that
-    many tokens or more in a ragged dispatch is scored in the published,
-    expanded form, and its pairs are counted a second time as such; a
-    decode dispatch's one-token spans never are."""
+    runner's two programs are the kernel's, else None (the XLA form). A
+    span of that many tokens or more in a ragged dispatch is scored in the
+    published, expanded form, and its pairs are counted a second time as
+    such; a decode dispatch's one-token spans never are: they are the
+    kernel's decode body's (several sequences a grid cell, long windows),
+    and counted a second time as that."""
 
     KINDS = ("ragged", "decode")
 
@@ -952,6 +954,7 @@ class LatentCounters:
         self.query_tokens = dict.fromkeys(self.KINDS, 0)
         self.scored_pairs = dict.fromkeys(self.KINDS, 0)
         self.expanded_pairs = dict.fromkeys(self.KINDS, 0)
+        self.decode_body_pairs = dict.fromkeys(self.KINDS, 0)
         # context rows the spans reach (each once a span and layer): what
         # a kernel has to read of the pool at least
         self.context_rows = dict.fromkeys(self.KINDS, 0)
@@ -969,17 +972,23 @@ class LatentCounters:
         # for a one-token span, i pairs more a slot
         longer = int((q > 0).sum()) * (K * (K - 1) // 2)
         pairs = q * (ctx - q) + q * (q + 1) // 2
+        scored = (K * int(pairs.sum()) + longer) * L
         self.query_tokens[kind] += K * int(q.sum()) * L
-        self.scored_pairs[kind] += (K * int(pairs.sum()) + longer) * L
+        self.scored_pairs[kind] += scored
         self.context_rows[kind] += (K * int(ctx.sum()) + longer) * L
-        if kind == "ragged" and self.expand_rows is not None:
+        if self.expand_rows is None:
+            return
+        if kind == "ragged":
             self.expanded_pairs[kind] += int(
                 pairs[q >= self.expand_rows].sum()) * L
+        else:
+            self.decode_body_pairs[kind] += scored
 
     def snapshot(self) -> dict:
         return {"mla_query_tokens_total": dict(self.query_tokens),
                 "mla_scored_pairs_total": dict(self.scored_pairs),
                 "mla_expanded_pairs_total": dict(self.expanded_pairs),
+                "mla_decode_body_pairs_total": dict(self.decode_body_pairs),
                 "mla_context_rows_total": dict(self.context_rows),
                 "kv_bytes_per_token": self.kv_bytes_per_token}
 

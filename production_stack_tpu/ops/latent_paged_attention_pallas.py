@@ -1,7 +1,21 @@
-"""Latent paged attention (MLA) — ONE Pallas kernel for the ragged stream and
-for decode rows alike, with TWO forms of the same scores: absorbed for
-decode rows and short spans, expanded (the published form) for a span of
-``EXPAND_ROWS`` query rows or more in this step.
+"""Latent paged attention (MLA) — ONE Pallas call a cache layer and
+dispatch, named ``%latent_paged_attention`` in both step programs, with
+THREE bodies and two forms of the same scores.
+
+Which body a call takes follows its shape and nothing else:
+
+- the RAGGED program's call (``latent_paged_attention_pallas``: a packed
+  stream, spans by ``cu_q_lens``) walks the stream in tiles, ABSORBED
+  (``_absorbed_tile``: decode rows and short spans, one flash state a tile
+  that its overlapping spans take turns in), and scores a span of
+  ``EXPAND_ROWS`` query rows or more in this step EXPANDED, the published
+  form (``_expanded_heads``), in the same ``pallas_call``;
+- the DECODE program's call (``latent_decode_attention_pallas``: a
+  ``(B, H, lanes)`` query, a slot a row, no spans to describe) takes the
+  DECODE body (``_decode_cell``), absorbed too: several sequences a grid
+  cell, a flash state each, 512-row windows, a mask only where a context
+  ends. The bottom of this docstring says why one-token spans have a body
+  of their own.
 
 ABSORBED (all of this docstring down to "EXPANDED").
 
@@ -12,8 +26,7 @@ score the SAME row over all its lanes and take its first ``value_dim``
 lanes as the value (KH = 1, G = H, keys and values the same bytes), so a
 window of the context is two matmuls for a whole tile: ``(R, lanes) x
 (lanes, T)`` and ``(R, T) x (T, value_dim)`` with ``R = q_tile * H`` rows.
-No per-head loop and no slab slicing: at H = 128 one token already fills
-the MXU's rows, which is why a decode row needs no kernel of its own.
+No per-head loop and no slab slicing.
 
 The interface, the tiling of the stream, the walk of each overlapping span
 with one flash-softmax state a tile, the windowed double-buffered block
@@ -48,6 +61,25 @@ fetched once, expanded once a head (two ``(rows, value_dim) x (value_dim,
 ``EXPAND_Q_ROWS``, those above the diagonal skipped, those nothing can mask
 without a mask; one flash state a (head, stream row). The form follows what
 the kernel observes, a span's query rows in this step, and nothing else.
+
+DECODE. A decode step is 64 one-token spans of 1-4 k rows each, and what
+it costs is the rows' bytes. The stream's tile spent 0.70 ms a call there
+where the bytes cost 0.22 (Kimi-Linear's cell, H 32; PERF.md section 6,
+PR 60): it walks a tile's 16 spans one after another, each walk's first
+window hidden by nothing; a window is 128 rows, ~1,100 a call, each with
+eight guarded starts and waits; every window of a one-token walk runs
+masked; the state is read and written back a window. ``_decode_cell``
+keeps the arithmetic (``_flash_update``) and none of the control: a cell's
+sequences each have their own state and landing buffers; a window is
+``DECODE_WINDOWS`` blocks whose copies signal one semaphore, waited once;
+the window a context ends in is the only masked one; and where a window
+and the next are both whole, the next one's 32 starts stand unguarded in
+the block of code that scores this one, because the call is bound by the
+scalar core's descriptor work (~9 k block copies a call at ~30 ns:
+0.30 ms, the products 0.24, the bytes 0.23) and the scheduler can lay that
+beside the products only inside one block. 0.82 -> 0.38 ms a call at fixed
+inputs, 232 -> 507 GB/s of stored rows. The ragged program's decode rows
+still walk the tile: a stream tile's one state cannot be a sequence's.
 """
 
 from __future__ import annotations
@@ -94,7 +126,9 @@ EXPAND_HEADS = 4
 
 
 def _latent_kernel(*refs, **static):
-    """The absorbed form alone: a decode step's program."""
+    """The stream's tiles alone, every span absorbed (``expand`` None): no
+    program's call since the decode program has a body of its own; the
+    tests hold the tile body against the XLA form through it."""
     _absorbed_tile(pl.program_id(0), *refs, **static)
 
 
@@ -228,13 +262,9 @@ def _absorbed_tile(
         live = nwin > 0
         if expand_rows is not None:
             live &= q_len < expand_rows
-        if TQ == 1:
-            pl.when(live)(lambda: walk(R, 0))
-        else:
-            one = hi - lo == 1
-            pl.when(live & one)(
-                lambda: walk(H, pl.multiple_of(lo * H, H)))
-            pl.when(live & jnp.logical_not(one))(lambda: walk(R, 0))
+        one = hi - lo == 1
+        pl.when(live & one)(lambda: walk(H, pl.multiple_of(lo * H, H)))
+        pl.when(live & jnp.logical_not(one))(lambda: walk(R, 0))
         return 0
 
     jax.lax.fori_loop(0, cnt, seq_body, 0)
@@ -454,9 +484,10 @@ def latent_paged_attention_pallas(
     expand_heads: int = EXPAND_HEADS,
     interpret: bool = False,
 ):
-    """Returns (T, H, value_dim). A decode step calls it with one-token
-    spans (``cu_q_lens = arange(S + 1)``; an idle slot's context 0 walks
-    nothing and reads zeros).
+    """Returns (T, H, value_dim): the ragged program's call. (A decode
+    step's one-token spans, a slot a row, are
+    ``latent_decode_attention_pallas``'s; an idle slot's context 0 walks
+    nothing and reads zeros here too.)
 
     ``expand``: (the heads' own queries (H, T, nope + lanes - value_dim),
     each ``[q_nope; q_rope; zeros]`` as the pool's row is ``[c; r;
@@ -582,3 +613,249 @@ def latent_paged_attention_pallas(
     if expand is None:
         return o_lat
     return o_lat, out[1][:, :T], rows[:T]
+
+
+# -- the decode program's body ------------------------------------------------
+
+# KV blocks a window of the decode body holds: 512 rows at block 16 (ms a
+# call at 64 spans of 1024-3584 rows, H 32: 128 / 256 / 512 / 1024 rows
+# 0.72 / 0.48 / 0.38 / 0.39; at H 128 and on 64-256-row contexts 1024 rows
+# lose 3-32 %; PERF.md section 6, PR 60)
+DECODE_WINDOWS = 32
+# sequences a grid cell owns at most: the cell's walk is unrolled over them
+# (three bodies a sequence and slot); 8 read worse than 4 everywhere, and
+# at 128 heads seven times worse
+DECODE_SEQS = 4
+# what a cell's sequences may hold of VMEM together: an eighth of the call's
+# ``VMEM_LIMIT_BYTES`` (ops/paged_attention_pallas.py
+# ``_pick_seqs_per_cell``'s budget too)
+DECODE_VMEM_BYTES = 8 * 2 ** 20
+
+
+def _decode_seqs_per_cell(heads: int, lanes: int, value_dim: int,
+                          window_rows: int, itemsize: int) -> int:
+    """Sequences a grid cell of the decode body owns: the most, a power of
+    two up to ``DECODE_SEQS``, whose landing buffers (two slots a
+    sequence), score and weight temporaries, flash state and double-
+    buffered query and output blocks fit ``DECODE_VMEM_BYTES``: 4 at 32
+    heads, 2 at 128, where the fixed inputs read best too."""
+    per_seq = (2 * window_rows * lanes * itemsize
+               + 3 * heads * window_rows * 4
+               + heads * (value_dim + 2 * LANES) * 4
+               + 2 * heads * (lanes + value_dim) * itemsize)
+    fit = max(DECODE_VMEM_BYTES // per_seq, 1)
+    return min(1 << (int(fit).bit_length() - 1), DECODE_SEQS)
+
+
+def _decode_cell(
+    # scalar prefetch
+    bt_ref,  # (B, M) SMEM
+    cl_ref,  # (B,) SMEM — a slot's context, its query row the last; 0: dead
+    layer_ref,  # (1,) SMEM
+    # inputs
+    q_ref,  # (SPB, H, lanes) VMEM
+    kv_hbm,  # (L, N, bs, lanes) ANY
+    # outputs
+    o_ref,  # (SPB, H, value_dim) VMEM
+    # scratch
+    buf,  # (2, SPB, W, bs, lanes) VMEM
+    sems,  # (2, SPB) DMA sems: every block of a sequence's window signals one
+    m_ref,  # (SPB, H, LANES) f32 — flash running max, lane 0
+    l_ref,  # (SPB, H, LANES) f32 — flash running sum, lane 0
+    acc_ref,  # (SPB, H, value_dim) f32
+    *,
+    block_size: int,
+    windows: int,
+    scale: float,
+):
+    """One-token spans, ``SPB`` sequences a grid cell, each with a flash
+    state of its own: the decode program's call. The cell makes as many
+    steps as its longest context has windows; a step is one window of every
+    sequence that still has one, the next window's copies started before
+    this one is scored, per block and only as far as the context reaches. A
+    sequence past its last window (a dead slot from the first) starts,
+    waits for and computes nothing. The query row is its context's last, so
+    only the window the context ends in is masked."""
+    layer = layer_ref[0]
+    W, bs = windows, block_size
+    Tw = W * bs
+    SPB, H, lanes = q_ref.shape
+    V = o_ref.shape[-1]
+    base = pl.program_id(0) * SPB
+
+    def start(s, slot, w, j):
+        pltpu.make_async_copy(
+            kv_hbm.at[layer, bt_ref[base + s, w * W + j]],
+            buf.at[slot, s, j], sems.at[slot, s]).start()
+
+    def start_window(s, slot, w):
+        """All of window ``w``'s blocks, unguarded and unrolled (20 ns a
+        copy where a loop's are 30)."""
+        for j in range(W):
+            start(s, slot, w, j)
+
+    def wait_window(s, slot):
+        """One wait for the bytes of a whole window's blocks."""
+        pltpu.make_async_copy(buf.at[slot, s], buf.at[slot, s],
+                              sems.at[slot, s]).wait()
+
+    def each(n, do):
+        """``do(j)`` for the first ``n`` blocks of a window, where they are
+        not all of it: a loop whose trip count is the predicate (a guard a
+        block would be the trace and the compile of ROADMAP.md S10 item
+        3)."""
+        def body(j, _):
+            do(j)
+            return 0
+
+        jax.lax.fori_loop(0, jnp.where(n == W, 0, n), body, 0)
+
+    def start_some(s, slot, w, n):
+        pl.when(n == W)(lambda: start_window(s, slot, w))
+        each(n, lambda j: start(s, slot, w, j))
+
+    def wait_some(s, slot, n):
+        """Every block signals the window's one semaphore: a block's bytes
+        a wait."""
+        pl.when(n == W)(lambda: wait_window(s, slot))
+        each(n, lambda j: pltpu.make_async_copy(
+            kv_hbm.at[layer, 0], buf.at[slot, s, 0],
+            sems.at[slot, s]).wait())
+
+    def blocks(rows):
+        """Blocks of a window that ``rows`` rows of context reach into."""
+        return jnp.clip(pl.cdiv(rows, bs), 0, W)
+
+    def update(s, slot, valid):
+        k = buf[slot, s].reshape(Tw, lanes)
+        sc = jax.lax.dot_general(
+            q_ref[s], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (H, Tw)
+        _flash_update(m_ref, l_ref, acc_ref, (s, slice(None)), sc, k[:, :V],
+                      valid)
+
+    def tail_mask(s, slot, left):
+        """A window the context ends in, ``left`` rows of it: its other
+        rows (never fetched, or the tail block's own) zeroed in the landing
+        buffer (0 x NaN), and the mask of its scores."""
+        fetched = buf[slot, s]
+        row = (jax.lax.broadcasted_iota(jnp.int32, fetched.shape, 0) * bs
+               + jax.lax.broadcasted_iota(jnp.int32, fetched.shape, 1))
+        buf[slot, s] = jnp.where(row < left, fetched, jnp.zeros_like(fetched))
+        return jax.lax.broadcasted_iota(jnp.int32, (1, Tw), 1) < left
+
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    nwin = 0  # the cell's steps: its longest context's windows
+    for s in range(SPB):
+        ctx = cl_ref[base + s]
+        nwin = jnp.maximum(nwin, pl.cdiv(ctx, Tw))
+        start_some(s, 0, 0, blocks(ctx))
+
+    def window(s, w, slot):
+        """Sequence ``s`` at window ``w`` of its context, landed in
+        ``slot``: static, so that the buffer read and the buffer the next
+        window's copies go to are different ones to the compiler too."""
+        left = cl_ref[base + s] - w * Tw  # rows from this window on
+
+        # this window whole and the next one too: the 32 starts stand
+        # unguarded in ONE block of code with the two products, and the
+        # scheduler lays the scalar core's descriptor work (~30 ns a copy,
+        # 0.30 ms a call: more than the products' 0.24) beside them
+        @pl.when(left >= 2 * Tw)
+        def _():
+            wait_window(s, slot)
+            start_window(s, 1 - slot, w + 1)
+            update(s, slot, None)
+
+        @pl.when((left > Tw) & (left < 2 * Tw))
+        def _():
+            start_some(s, 1 - slot, w + 1, blocks(left - Tw))
+            wait_window(s, slot)
+            update(s, slot, None)
+
+        @pl.when((left > 0) & (left <= Tw))
+        def _():
+            wait_some(s, slot, blocks(left))
+            update(s, slot, tail_mask(s, slot, left))
+
+    def pair(i, _):
+        for slot in range(2):
+            for s in range(SPB):
+                window(s, 2 * i + slot, slot)
+        return 0
+
+    jax.lax.fori_loop(0, pl.cdiv(nwin, 2), pair, 0)
+    # a dead slot kept l = 0: it reads zeros
+    o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[:, :, 0:1], 1e-30)
+                  ).astype(o_ref.dtype)
+
+
+# behind ``jax.jit`` so that the decode program's call sites (the scanned
+# periods', the first and the last segments') share a trace and a lowering,
+# as ops/paged_attention_pallas.py paged_decode_attention_pallas does
+@functools.partial(jax.jit, static_argnames=(
+    "value_dim", "windows", "seqs_per_cell", "interpret"))
+def latent_decode_attention_pallas(
+    q: jnp.ndarray,  # (B, H, lanes) a slot's one absorbed query row
+    kv_cache: jnp.ndarray,  # (L, N, bs, lanes)
+    block_tables: jnp.ndarray,  # (B, M)
+    context_lens: jnp.ndarray,  # (B,) int32, the query's own row included
+    layer_idx: jnp.ndarray | int = 0,
+    *,
+    value_dim: int,
+    windows: int = DECODE_WINDOWS,
+    seqs_per_cell: int | None = None,
+    interpret: bool = False,
+):
+    """The decode program's call: one-token spans, a slot a row. Returns
+    (B, H, value_dim); an idle slot (context 0) fetches nothing and reads
+    zeros. The scores, the form (absorbed) and the precision are those
+    ``latent_paged_attention_pallas`` gives a one-token span.
+    ``seqs_per_cell``: a test's; the call works it out."""
+    B, H, lanes = q.shape
+    L, N, bs, _ = kv_cache.shape
+    spb = min(B, seqs_per_cell or _decode_seqs_per_cell(
+        H, lanes, value_dim, windows * bs,
+        jnp.dtype(kv_cache.dtype).itemsize))
+    Bp = -(-B // spb) * spb
+    bt = jnp.asarray(block_tables, jnp.int32)
+    ctx = jnp.asarray(context_lens, jnp.int32)
+    if Bp != B:  # whole cells: the slots added are dead
+        q = jnp.pad(q, ((0, Bp - B), (0, 0), (0, 0)))
+        bt = jnp.pad(bt, ((0, Bp - B), (0, 0)))
+        ctx = jnp.pad(ctx, (0, Bp - B))
+
+    def cell(c, *_):
+        return c, 0, 0
+
+    out = pl.pallas_call(
+        functools.partial(_decode_cell, block_size=bs, windows=windows,
+                          scale=lanes ** -0.5),
+        out_shape=jax.ShapeDtypeStruct((Bp, H, value_dim), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(Bp // spb,),
+            in_specs=[
+                pl.BlockSpec((spb, H, lanes), cell, memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((spb, H, value_dim), cell,
+                                   memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((2, spb, windows, bs, lanes), kv_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, spb)),
+                pltpu.VMEM((spb, H, LANES), jnp.float32),
+                pltpu.VMEM((spb, H, LANES), jnp.float32),
+                pltpu.VMEM((spb, H, value_dim), jnp.float32),
+            ],
+        ),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        # the ragged program's name: the trace reductions find both by it
+        name="latent_paged_attention",
+    )(bt, ctx, jnp.asarray(layer_idx, jnp.int32).reshape(1), q, kv_cache)
+    return out[:B]

@@ -13,6 +13,8 @@ The topology is described inside a fixture, never at import: only one
 process may load libtpu, and every xdist worker imports this file.
 """
 
+import json
+import os
 import re
 
 import jax
@@ -580,6 +582,20 @@ def _latent_call(q, c, bt, cu, cl, *expand):
         expand=(*expand, 192 ** -0.5) if expand else None)
 
 
+def _latent_decode_call(q, c, bt, cu, cl):
+    """The decode program's: a slot a row, the decode body."""
+    return _latent().latent_decode_attention_pallas(
+        q, c, bt, cl, layer_idx=1, value_dim=512)
+
+
+# what the three trace reductions of the kernel match an instruction by
+# (chipbench/layer_metrics/mla_attn_busy_pct.json, mla_attn_roofline_pct.py,
+# hybrid_mla_attn_roofline_pct.py), dividing by the ragged dispatches x
+# layers + the decode calls: a decode call under another name would stay in
+# the denominator and leave the numerator, and the share read impossible
+LATENT_OP = r"^%latent_paged_attention[.\d]* = "
+
+
 @pytest.mark.parametrize("tokens,heads,expand", [
     (2048, 128, True), (512, 128, True), (64, 128, False),
     (2048, 32, True), (512, 32, True), (64, 32, False)])
@@ -589,15 +605,73 @@ def test_latent_kernel_is_a_named_custom_call_at_the_cells_shapes(
     in ONE custom call under the one name the trace reductions match on:
     a second call, under this name or another, would halve or hide the
     time ``mla_attn_roofline_pct`` divides by. The decode program's call
-    is the absorbed kernel alone, one output, as before."""
-    text = _compiled_text(_latent_call, one_chip,
-                          *_latent_shapes(tokens, heads, expand))
+    (64 one-token spans: the decode body, PR 60) is one custom call too,
+    one output, under the SAME name: renamed, it fails here."""
+    text = _compiled_text(_latent_call if expand else _latent_decode_call,
+                          one_chip, *_latent_shapes(tokens, heads, expand))
     calls = re.findall(
         r"^\s*(?:ROOT )?(%[\w.-]+) = (\(?).*? custom-call\(.*"
         r"custom_call_target=\"tpu_custom_call\"", text, flags=re.M)
     assert len(calls) == 1 and re.fullmatch(
         r"%latent_paged_attention[.\d]*", calls[0][0]), calls
     assert (calls[0][1] == "(") == expand  # two outputs, or the one
+    with open(os.path.join(os.path.dirname(__file__), "..", "chipbench",
+                           "layer_metrics", "mla_attn_busy_pct.json")) as f:
+        assert json.load(f)["op"] == LATENT_OP
+    assert re.match(LATENT_OP, calls[0][0] + " = ")
+
+
+# the decode body's cells: 4 sequences of 32 heads, 2 of 128 (two 512-row
+# landing buffers a sequence, its state, the query and output blocks):
+# under the default 16 MiB and far inside the call's own limit
+LATENT_DECODE_VMEM_MIB = {32: 5.38, 128: 3.25}
+
+
+@pytest.mark.parametrize("heads", sorted(LATENT_DECODE_VMEM_MIB))
+def test_latent_decode_call_asks_for_scoped_vmem_inside_its_limit(
+        one_chip, monkeypatch, heads):
+    """As the ragged program's call below: no libtpu flag, the call's own
+    limit, and what it asks read from the compiler's refusal of less."""
+    k, mib = _latent(), LATENT_DECODE_VMEM_MIB[heads]
+    assert k.VMEM_LIMIT_BYTES >= 4 * mib * 2 ** 20
+    monkeypatch.setattr(k, "VMEM_LIMIT_BYTES", int((mib - 0.5) * 2 ** 20))
+    with pytest.raises(Exception, match="Scoped allocation") as refusal:
+        # unjitted, under a function of its own: jit would hand back the
+        # other test's trace, made under the real limit
+        _compiled_text(
+            lambda q, c, bt, cu, cl:
+            k.latent_decode_attention_pallas.__wrapped__(
+                q, c, bt, cl, 1, value_dim=512),
+            one_chip, *_latent_shapes(64, heads, False))
+    size = re.search(r"Scoped allocation with size ([\d.]+)M",
+                     str(refusal.value))
+    assert size and float(size.group(1)) <= mib
+
+
+@pytest.mark.parametrize("heads", [32, 128])
+def test_latent_decode_layer_leaves_the_donated_pool_in_place(one_chip,
+                                                              heads):
+    """A cache layer of the decode program: the slots' rows scattered into
+    the donated pool, then the one call that reads it. Nothing the size of
+    the pool is copied."""
+    from production_stack_tpu.ops.paged_attention import write_latent
+
+    def layer(c, rows, sm, q, bt, cl):
+        c = write_latent(c, 1, rows, sm)
+        return c, _latent().latent_decode_attention_pallas(
+            q, c, bt, cl, layer_idx=1, value_dim=512)
+
+    q, pool, bt, _, cl = (
+        jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+        for s, dt in _latent_shapes(64, heads, False))
+    rows, sm = (jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                for s, dt in (((64, 640), jnp.bfloat16), ((64,), I32)))
+    compiled = jax.jit(layer, donate_argnums=0).lower(
+        pool, rows, sm, q, bt, cl).compile()
+    nbytes = 2 * 640 * BS * pool.shape[0] * pool.shape[1]
+    assert compiled.memory_analysis().temp_size_in_bytes < nbytes // 100
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          compiled.as_text())) == 1
 
 
 @pytest.mark.parametrize("expand,mib", [(False, LATENT_VMEM_MIB),
@@ -1285,6 +1359,12 @@ def test_step_program_copies_no_weight_stack(one_chip, config, program):
         ("olmo-hybrid-7b-l16", "ragged512"): (
             "gdn_decode_step", "gdn_chunk_scan", "ragged_paged_attention",
             "kv_cache_write"),
+        # the latent cells' decode programs: the decode body, by the name
+        # the trace reductions match (``LATENT_OP``)
+        ("kimi-linear-48b-a3b-ep16", "decode"): (
+            "latent_paged_attention", "kda_decode_step"),
+        ("openpangu-ultra-moe-718b-ep16-l5", "decode"): (
+            "latent_paged_attention",),
         (TRINITY, "decode"): (
             "paged_decode_attention", "kv_cache_write", "moe_grouped_matmul"),
         (TRINITY, "ragged4096"): (

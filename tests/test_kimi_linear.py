@@ -199,6 +199,11 @@ def test_served_through_the_kernels_matches_the_reference(served,
         lat, "latent_paged_attention_pallas",
         functools.partial(lat.latent_paged_attention_pallas,
                           interpret=True, q_tile=4, windows=2, **how))
+    # the decode program's call: the kernel's decode body
+    monkeypatch.setattr(
+        lat, "latent_decode_attention_pallas",
+        functools.partial(lat.latent_decode_attention_pallas,
+                          interpret=True, windows=2))
     for name in ("kda_ragged", "kda_decode_step"):
         monkeypatch.setattr(kda_pallas, name, functools.partial(
             getattr(kda_pallas, name), interpret=True))

@@ -160,23 +160,30 @@ def test_served_through_the_kernel_matches_the_reference(served, monkeypatch,
     form, the later ones over the earlier ones' rows, then over a cached
     prefix."""
     first, _ = served
-    traced = []  # (stream rows, given the heads' own queries) a trace
+    traced = []  # (rows, given the heads' own queries; None: decode) a trace
 
     def call(q, *args, expand=None, **kw):
         traced.append((q.shape[0], expand is not None))
         return kernel_call(q, *args, expand=expand, interpret=True,
                            q_tile=4, windows=2, **how, **kw)
 
+    def decode_call(q, *args, **kw):
+        traced.append((q.shape[0], None))
+        return decode_kernel_call(q, *args, interpret=True, windows=2, **kw)
+
     kernel_call = kernel.latent_paged_attention_pallas
+    decode_kernel_call = kernel.latent_decode_attention_pallas
     monkeypatch.setattr(kernel, "latent_paged_attention_pallas", call)
+    monkeypatch.setattr(kernel, "latent_decode_attention_pallas", decode_call)
     eng = engine(params=first.runner.params)
     eng.runner.use_pallas = True  # read where the programs are traced
     prompts = {"long": PROMPTS["long"], "short": PROMPTS["short"]}
     out = serve(eng, prompts, max_tokens=6)
-    # the ragged program hands the kernel both forms' inputs, the decode
-    # program (a row a slot) the absorbed one's alone
-    assert {rows for rows, both in traced if not both} == {4}
-    assert {rows for rows, both in traced if both} == {BUDGET}
+    # the ragged program hands the kernel both forms' inputs; the decode
+    # program (a row a slot) calls the decode body and nothing else
+    assert {rows for rows, both in traced if both is None} == {4}
+    assert {rows for rows, both in traced if both is not None} == {BUDGET}
+    assert all(both for _, both in traced if both is not None)
     for name, (toks, lps) in out.items():
         err = errors(HF, eng.runner.params, prompts[name], toks, lps)
         assert len(toks) == 6 and err.max() < LOGPROB_TOL, (name, err)
@@ -264,6 +271,94 @@ def test_the_kernel_equals_its_xla_form(q_tile, windows):
     live = pos >= 0
     np.testing.assert_allclose(got[live], want[live], atol=3e-6)
     assert float(jnp.abs(got[~live]).max()) == 0.0  # padding reads zeros
+
+
+def _decode_rows(ctxs, H, seed=0):
+    """A decode dispatch: slot s one query row at the end of ``ctxs[s]``
+    rows (0: a dead slot). Every pool block no live row reaches holds NaN
+    (but block 0, which the table's padding names and the XLA form
+    gathers): a kernel that fetched and summed one would read it."""
+    rng = np.random.default_rng(seed)
+    width, lanes, V, bs = 48, 128, 32, 16
+    # one table width and pool size for every case: cases of one batch and
+    # cell size then share a trace
+    B, M, N = len(ctxs), 16, 64
+    bt = np.zeros((B, M), np.int32)
+    free = iter(rng.permutation(N - 1) + 1)
+    for s, c in enumerate(ctxs):
+        nb = -(-c // bs)
+        bt[s, :nb] = [next(free) for _ in range(nb)]
+    pool = rng.normal(size=(2, N, bs, lanes)).astype(np.float32)
+    pool[..., width:] = 0
+    pool[:, np.setdiff1d(np.arange(1, N), bt)] = np.nan
+    q = jnp.asarray(rng.normal(size=(B, H, lanes)), jnp.float32)
+    return q, jnp.asarray(pool), bt, np.array(ctxs, np.int32), V
+
+
+# a window is two blocks (32 rows). (heads, contexts a slot, sequences a
+# cell)
+DECODE_CASES = {
+    "a dead slot among live ones": (4, [33, 0, 1, 100], 2),
+    "every slot dead": (4, [0, 0, 0], 2),
+    "one row of context": (4, [1, 1], 2),
+    "one short of, at and one past a block": (4, [15, 16, 17], 1),
+    "one short of, at and one past a window": (4, [31, 32, 33], 1),
+    "one short of, at and one past two windows": (4, [63, 64, 65], 1),
+    "three windows and more: whole ones ahead": (4, [96, 97, 130], 1),
+    "very different lengths in one cell": (4, [200, 3, 17, 65], 4),
+    "a batch that is no multiple of the cell": (4, [40, 70, 9, 0, 64], 2),
+    "a batch smaller than the cell": (4, [75], 4),
+    "32 heads": (32, [50, 0, 129, 32], 2),
+    "128 heads": (128, [34, 95], 2),
+    "heads that are no multiple of 8": (6, [47, 0, 64, 10, 111], 2),
+    "the call's own sequences a cell": (4, [90, 5, 0, 64, 33, 128], None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DECODE_CASES))
+def test_the_decode_body_equals_its_xla_form(name):
+    """The decode program's call (one-token spans, several sequences a
+    grid cell, long windows, a mask only where the context ends) against
+    the XLA form on the same rows; a dead slot reads zeros; the NaN that
+    fills every block no live row reaches is never fetched into a sum."""
+    H, ctxs, spb = DECODE_CASES[name]
+    q, pool, bt, ctx, V = _decode_rows(ctxs, H)
+    B = len(ctxs)
+    pos = np.where(ctx > 0, ctx - 1, -1).astype(np.int32)
+    want = latent_ragged_paged_attention(
+        q, pool[1], bt, ctx, np.arange(B, dtype=np.int32), pos, V)
+    got = kernel.latent_decode_attention_pallas(
+        q, pool, bt, ctx, 1, value_dim=V, windows=2, seqs_per_cell=spb,
+        interpret=True)
+    assert got.shape == (B, H, V)
+    live = ctx > 0
+    np.testing.assert_allclose(got[live], want[live], atol=3e-6)
+    assert float(jnp.abs(got[~live]).max(initial=0.0)) == 0.0
+
+
+def test_the_decode_body_reads_what_the_stream_kernel_reads():
+    """The same one-token spans through both bodies: the decode body and
+    the stream's tile (``cu_q_lens = arange``), which the ragged program's
+    decode rows still walk."""
+    q, pool, bt, ctx, V = _decode_rows([33, 0, 1, 100, 64, 17], 4)
+    got = kernel.latent_decode_attention_pallas(
+        q, pool, bt, ctx, 1, value_dim=V, windows=2, interpret=True)
+    tile = kernel.latent_paged_attention_pallas(
+        q, pool, bt, np.arange(len(ctx) + 1, dtype=np.int32), ctx, 1,
+        value_dim=V, q_tile=4, windows=2, interpret=True)
+    np.testing.assert_allclose(got, tile, atol=3e-6)
+
+
+def test_sequences_a_cell_follow_the_calls_shapes():
+    """From the heads, the lanes and the window's bytes against a VMEM
+    budget: 4 at Kimi-Linear's 32 heads, 2 at Pangu's 128 (where the fixed
+    inputs read best, PERF.md section 6, PR 60), never more than
+    ``DECODE_SEQS``, never fewer than one."""
+    pick = kernel._decode_seqs_per_cell
+    assert pick(32, 640, 512, 512, 2) == 4
+    assert pick(128, 640, 512, 512, 2) == 2
+    assert pick(4, 128, 32, 32, 4) == kernel.DECODE_SEQS
+    assert pick(128, 640, 512, 8192, 2) == 1
 
 
 def _published_form(q_nope, q_rope, w_uk, w_uv, layer, bt, ctx, seq_ids, pos,
@@ -670,7 +765,13 @@ def test_latent_counters_count_pairs_from_spans(expand_rows, expanded):
     assert c.scored_pairs["decode"] == 5 * (27 + 29 + 31)
     assert c.query_tokens["decode"] == 5 * 2 * 3
     assert c.expanded_pairs["decode"] == 0  # one-token spans never are
+    # the decode body's where the programs are the kernel's: all of a
+    # decode dispatch's, fused iterations and all, none of a ragged one's
+    assert c.decode_body_pairs == {
+        "ragged": 0,
+        "decode": 0 if expand_rows is None else c.scored_pairs["decode"]}
     snap = c.snapshot()
+    assert snap["mla_decode_body_pairs_total"] == c.decode_body_pairs
     assert snap["kv_bytes_per_token"] == 6400
     assert snap["mla_scored_pairs_total"]["ragged"] == 5 * pairs
     assert snap["mla_expanded_pairs_total"] == c.expanded_pairs
@@ -694,20 +795,22 @@ def test_the_counters_move_in_both_step_kinds_and_are_exported(served):
         for sample in m.samples)
     for name in ("vllm:mla_query_tokens_total", "vllm:mla_scored_pairs_total",
                  "vllm:mla_expanded_pairs_total",
+                 "vllm:mla_decode_body_pairs_total",
                  "vllm:mla_context_rows_total", "vllm:kv_bytes_per_token"):
         assert name in text
     assert "'kind': 'ragged'" in text and "'kind': 'decode'" in text
     # the CPU's ragged program is the XLA form: absorbed throughout
     assert eng.latent.expand_rows is None
     assert s["mla_expanded_pairs_total"] == {"ragged": 0, "decode": 0}
+    assert s["mla_decode_body_pairs_total"] == {"ragged": 0, "decode": 0}
 
 
 def test_the_engine_counts_the_pairs_of_its_long_spans(served, monkeypatch):
     """Where the runner's ragged program is the kernel's, the engine counts
     by the kernel's own crossover: the long prompt's chunks of 32 tokens
     over a crossover moved to 16 rows are counted whole, its last 6-token
-    chunk and the decode dispatches not at all; and the benchmark's metric
-    is the share of the two counters."""
+    chunk and the decode dispatches not at all; the decode dispatches'
+    pairs are the decode body's, all of them."""
     from production_stack_tpu.engine import model_runner
 
     first, _ = served
@@ -717,6 +820,10 @@ def test_the_engine_counts_the_pairs_of_its_long_spans(served, monkeypatch):
         functools.partial(kernel.latent_paged_attention_pallas,
                           interpret=True, q_tile=4, windows=2,
                           **EXPANDED_AT_16))
+    monkeypatch.setattr(
+        kernel, "latent_decode_attention_pallas",
+        functools.partial(kernel.latent_decode_attention_pallas,
+                          interpret=True, windows=2))
     monkeypatch.setattr(model_runner, "_pallas_ok", lambda *a: True)
     eng = engine(params=first.runner.params)
     assert eng.latent.expand_rows == 16
@@ -727,43 +834,53 @@ def test_the_engine_counts_the_pairs_of_its_long_spans(served, monkeypatch):
                                              "decode": 0}
     assert (s["mla_scored_pairs_total"]["ragged"]
             >= 3 * (chunks + 6 * 64 + 6 * 7 // 2))
+    # the decode program is the kernel's decode body: its pairs are
+    assert s["mla_decode_body_pairs_total"] == {
+        "ragged": 0, "decode": s["mla_scored_pairs_total"]["decode"]}
+    assert s["mla_scored_pairs_total"]["decode"] > 0
+
+
+@pytest.mark.parametrize("metric,counter", [
+    ("mla_expanded_pairs_pct", "vllm:mla_expanded_pairs_total"),
+    ("mla_decode_body_pairs_pct", "vllm:mla_decode_body_pairs_total")])
+@pytest.mark.parametrize("counted,want", [
+    ((0.0, 0.0), 0.0),       # nothing long, no decode body: 0, not nothing
+    ((600.0, 0.0), 50.0),    # of ALL pairs, the other step kind's too
+    (None, None),            # a program without the counter: left out
+])
+def test_the_benchmark_reads_a_counters_share_of_all_pairs(metric, counter,
+                                                           counted, want):
+    """Both shares of the kernel's pairs, each a data file: the ragged
+    program's expanded spans and the decode program's decode body (PR 60;
+    the parent has no such counter, and the line leaves the metric out)."""
+    import types
+
+    from chipbench import layers, prom
+
     with open(os.path.join(ROOT, "chipbench", "layer_metrics",
-                           "mla_expanded_pairs_pct.json")) as f:
+                           metric + ".json")) as f:
         spec = json.load(f)
     assert (spec["reader"], spec["scale"], spec["num"], spec["den"]) == (
-        "prom_ratio", 100.0, ["vllm:mla_expanded_pairs_total"],
-        ["vllm:mla_scored_pairs_total"])
+        "prom_ratio", 100.0, [counter], ["vllm:mla_scored_pairs_total"])
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        entry = [m for m in json.load(f)["per_layer"]
-                 if m["name"] == "mla_expanded_pairs_pct"]
+        entry = [m for m in json.load(f)["per_layer"] if m["name"] == metric]
     assert entry == [{
-        "name": "mla_expanded_pairs_pct", "unit": "%", "better": "higher",
+        "name": metric, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "kernels",
         "moves": "tpot_p50_ms", "workloads": [
             "openpangu-ultra-moe-718b-ep16-l5.long-prompt",
             "kimi-linear-48b-a3b-ep16.long-decode"]}]
 
-
-@pytest.mark.parametrize("expanded,want", [
-    ((0.0, 0.0), 0.0),       # nothing long: 0, not nothing
-    ((600.0, 0.0), 50.0),    # of ALL pairs, the decode dispatches' too
-    (None, None),            # a program without the counter: left out
-])
-def test_the_benchmark_reads_the_expanded_share_of_all_pairs(expanded, want):
-    import types
-
-    from chipbench import layers, prom
-
-    def scrape(scored, expanded):
+    def scrape(scored, counted):
         lines = [f'vllm:mla_scored_pairs_total{{model_name="m",kind="{k}"}} '
                  f'{v}' for k, v in zip(("ragged", "decode"), scored)]
-        if expanded is not None:
+        if counted is not None:
             lines += [
-                f'vllm:mla_expanded_pairs_total{{model_name="m",kind="{k}"}}'
-                f' {v}' for k, v in zip(("ragged", "decode"), expanded)]
+                f'{counter}{{model_name="m",kind="{k}"}}'
+                f' {v}' for k, v in zip(("ragged", "decode"), counted)]
         return prom.parse("\n".join(lines) + "\n")
 
     ctx = types.SimpleNamespace(
-        prom_open=scrape((100.0, 50.0), expanded and (0.0, 0.0)),
-        prom_close=scrape((900.0, 450.0), expanded), manifest={})
-    assert layers.read("mla_expanded_pairs_pct", ctx) == want
+        prom_open=scrape((100.0, 50.0), counted and (0.0, 0.0)),
+        prom_close=scrape((900.0, 450.0), counted), manifest={})
+    assert layers.read(metric, ctx) == want
